@@ -100,6 +100,10 @@ Apex (reference: /root/reference, see SURVEY.md):
   sharding-rules outcome for cross-mesh resharded restores.
 - :mod:`apex_tpu.data` — native C++ threaded data loader + device
   prefetcher (ref role: DALI / torch DataLoader workers).
+- :mod:`apex_tpu.chip` — what a program settles before it says it ran
+  on the chip: ``require_tpu`` (no TPU, no run) and
+  ``compile_cache_dir`` (the persistent compile cache, placed from
+  outside); used by ``chip_smoke.py`` and ``bench.py``'s chip metrics.
 """
 
 __version__ = "0.5.0"

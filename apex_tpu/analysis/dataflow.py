@@ -30,7 +30,7 @@ proves the property at TRACE time, devices-free: walk the jaxpr, map
 set intersects the donated set.
 
 Scope notes, honestly stated: the pass tracks the donated *invars
-themselves* (plus positional flow through ``pjit``/``closed_call``
+themselves* (plus positional flow through ``jit``/``closed_call``
 sub-jaxprs and nested scan bodies) — a donated leaf laundered through
 an arithmetic op before capture produces a fresh var and is NOT
 flagged.  That copy genuinely breaks the alias, so the silence is
@@ -51,7 +51,8 @@ __all__ = [
 ]
 
 # primitives whose sub-jaxpr invars map positionally onto eqn.invars
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "xla_call")
+# (a nested ``jax.jit`` is the ``jit`` primitive in the installed jax 0.9)
+_CALL_PRIMS = ("jit", "closed_call", "core_call")
 
 
 class ScanCaptureError(Exception):

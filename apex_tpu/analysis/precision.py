@@ -46,6 +46,7 @@ from typing import Any, Callable, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = [
     "PrecisionError",
@@ -126,9 +127,9 @@ def _sub_jaxprs(params):
     for v in params.values():
         items = v if isinstance(v, (list, tuple)) else (v,)
         for item in items:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 yield item
 
 

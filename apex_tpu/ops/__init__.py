@@ -14,7 +14,7 @@ Every kernel ships with a pure-jnp reference implementation and is tested
 kernel-vs-reference under identical inputs (the reference's L1 "extensions
 vs Python build must match" harness, tests/L1/common/run_test.sh).
 """
-from apex_tpu.ops._common import force_pallas  # noqa: F401
+from apex_tpu.ops._common import force_pallas, mosaic_call_count  # noqa: F401
 from apex_tpu.ops.layer_norm import layer_norm, layer_norm_ref  # noqa: F401
 from apex_tpu.ops.softmax_xentropy import (  # noqa: F401
     softmax_cross_entropy,

@@ -21,7 +21,9 @@ def pallas_default(shape_ok: bool) -> bool:
 
     Auto-selects the kernel ONLY on TPU — must agree with pallas_call's
     interpret condition below, or non-TPU backends would silently run the
-    Pallas interpreter on the hot path."""
+    Pallas interpreter on the hot path.  Off-TPU the op is therefore its
+    jnp reference: right for the CPU test suite, wrong for anything that
+    reports a device number — see :func:`mosaic_call_count`."""
     if _FORCE_PALLAS is not None:
         return _FORCE_PALLAS and shape_ok
     return shape_ok and jax.default_backend() == "tpu"
@@ -42,8 +44,24 @@ def force_pallas(value: Optional[bool]):
 def pallas_call(*args, **kw):
     """pl.pallas_call, in interpreter mode off-TPU so the kernel-vs-reference
     parity tests run on CPU (the reference's Python-fallback testing trick,
-    SURVEY §4)."""
+    SURVEY §4).  An interpreted kernel lowers to plain XLA ops, so a
+    program that landed on the CPU by accident still completes — slowly;
+    :func:`mosaic_call_count` is how a caller refuses that."""
     return pl.pallas_call(*args, interpret=jax.default_backend() != "tpu", **kw)
+
+
+def mosaic_call_count(compiled) -> int:
+    """Mosaic kernels in a compiled executable (``jit(f).lower(...)
+    .compile()``): the ``tpu_custom_call``s in its text.
+
+    The two gates above pick the reference or the interpreter off-TPU
+    without a word, and a shape gate can pick the reference on it.  What
+    was COMPILED is the one place that shows the choice, so a program
+    that claims the chip (``chip_smoke.py``, ``bench.py``'s chip
+    metrics) checks the device up front (:func:`apex_tpu.chip.
+    require_tpu`) and then this count: zero where a kernel was promised
+    means it ran interpreted or as its reference."""
+    return compiled.as_text().count("tpu_custom_call")
 
 
 def auto_block(dim: int, cap: int, floor: int = 128) -> int:

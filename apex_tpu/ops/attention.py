@@ -455,7 +455,7 @@ def _paged_fused_kernel(
         q32 = q_ref[0].astype(jnp.float32) * scale   # (H, T, D)
         kn = kn_ref[0].astype(jnp.float32)
         vn = vn_ref[0].astype(jnp.float32)
-        pos = pos_ref[0].astype(jnp.int32)           # (T,)
+        pos = pos_ref[0, 0].astype(jnp.int32)        # (T,)
         pos_q = pos.reshape(t, 1)
         pos_k = pos.reshape(1, t)
         ln = len_ref[b]
@@ -575,8 +575,12 @@ def paged_fused_attention(
             pl.BlockSpec((1, 1, h, page_len), _pool_scale),
         ]
         args += [pool_k_scale, pool_v_scale]
-    in_specs.append(pl.BlockSpec((1, t), lambda bi, pi, pt, ln: (bi, 0)))
-    args.append(positions.astype(jnp.int32))
+    # positions ride as (B, 1, T): a (1, T) block of a (B, T) array
+    # breaks the TPU rule that a block's last two dims be (8, 128)
+    # multiples or the array's own (Mosaic refuses it at every shape)
+    in_specs.append(
+        pl.BlockSpec((1, 1, t), lambda bi, pi, pt, ln: (bi, 0, 0)))
+    args.append(positions.astype(jnp.int32)[:, None, :])
     if masked:
         in_specs.append(
             pl.BlockSpec((t, t), lambda bi, pi, pt, ln: (0, 0)))

@@ -95,19 +95,6 @@ class DistributedDataParallel:
         per-shard and :meth:`allreduce` performs the one explicit collective
         (the moral twin of the reference's hook-driven NCCL allreduce).
         """
-        if not hasattr(jax.lax, "pcast"):
-            # jax < 0.7 has no varying-axis cast; under shard_map with
-            # check_vma/check_rep=False grads of replicated params already
-            # stay per-shard, so the identity is the correct no-op there.
-            from apex_tpu.amp import warn_once
-
-            warn_once(
-                "ddp.local_params.pcast",
-                "apex_tpu DDP: jax.lax.pcast unavailable on this jax; "
-                "local_params is the identity (use check_vma=False so "
-                "grads stay per-shard).",
-            )
-            return params
         return jax.tree_util.tree_map(
             lambda p: jax.lax.pcast(p, self.axis_name, to="varying"), params
         )

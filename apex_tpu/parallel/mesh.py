@@ -26,33 +26,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def shard_map_compat(f, mesh: Mesh, *, in_specs, out_specs, check_vma: bool = True):
-    """``shard_map`` across the supported jax versions.
-
-    Newer jax exposes ``jax.shard_map`` with a ``check_vma`` flag; older
-    releases keep it in ``jax.experimental.shard_map`` where the same knob
-    is spelled ``check_rep``.  Library code (and the fused train driver)
-    must run on both, so this is the ONE place the difference lives.
-    """
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # jax < 0.6: experimental module, check_rep spelling
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_vma=check_vma)
+    """``jax.shard_map`` with this library's argument order (the name is
+    kept for its callers: library code and the fused train driver all
+    map through here)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis_name: str):
-    """Size of a mesh axis from inside a mapped region, on any jax version.
-
-    ``jax.lax.axis_size`` is recent; the portable spelling is the classic
-    ``psum(1, axis)`` (constant-folded by XLA, so it costs nothing).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a mesh axis from inside a mapped region."""
+    return jax.lax.axis_size(axis_name)
 
 
 def data_parallel_mesh(

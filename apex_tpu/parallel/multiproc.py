@@ -21,6 +21,13 @@ job disappears.  This module keeps three useful pieces:
 - ``python -m apex_tpu.parallel.multiproc script.py ...`` — the CLI
   over :func:`launch`, for exercising the multi-process (DCN) code
   path without hardware.
+
+This is the HARDWARE-FREE rehearsal of the multi-host path, not a chip
+launcher: :func:`launch` defaults every worker to ``JAX_PLATFORMS=cpu``.
+A chip belongs to one process at a time — N local workers cannot share
+one host's chips — so on a TPU host ONE process drives all of its chips
+(a mesh over ``jax.devices()``), and on a pod the runtime starts that
+one process per host.
 """
 from __future__ import annotations
 
@@ -187,6 +194,9 @@ def launch(
                 ),
                 WORLD_SIZE=str(world_size),
                 RANK=str(rank),
+                # CPU unless the caller says otherwise: local workers
+                # rehearse the multi-host path, they never share a
+                # host's chips (one process per chip — module docstring)
                 JAX_PLATFORMS=wenv.get("JAX_PLATFORMS", "cpu"),
             )
             # ref appends --rank i (multiproc.py:28-31); we export RANK

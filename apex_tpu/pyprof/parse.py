@@ -19,13 +19,7 @@ So the three reference stages map to:
   reporting measured time per scope and achieved vs analytic FLOP/s
 
 No TensorFlow/TensorBoard dependency: ``jax.profiler.ProfileData`` (ships
-with jaxlib) reads the serialized XSpace directly.  On jax versions
-without ``ProfileData`` (absent in 0.4.x), a built-in trace-proto reader
-(:func:`_xspace_planes`) decodes the XSpace wire format directly — the
-schema is four nested messages and the reader needs only plane/line
-names plus per-event metadata ids and durations, so a generic
-protobuf-wire walk with pinned field numbers replaces the dependency
-(capability-probed, not version-pinned: the real API wins when present).
+with jaxlib) reads the serialized XSpace directly.
 
 Typical use::
 
@@ -120,121 +114,6 @@ class KernelTime:
     count: int = 0
 
 
-# -- XSpace trace-proto fallback (jax without jax.profiler.ProfileData) ----
-#
-# tsl/profiler/protobuf/xplane.proto, the fields this module consumes
-# (verified against a captured trace — see tests/test_pyprof.py):
-#   XSpace.planes = 1
-#   XPlane{ name = 2, lines = 3, event_metadata = 4 (map: key=1, value=2) }
-#   XLine{ name = 2, events = 4 }
-#   XEvent{ metadata_id = 1, duration_ps = 3 }
-#   XEventMetadata{ id = 1, name = 2 }
-
-@dataclasses.dataclass
-class _XEvent:
-    name: str
-    duration_ns: float
-
-
-@dataclasses.dataclass
-class _XLine:
-    name: str
-    events: List[_XEvent]
-
-
-@dataclasses.dataclass
-class _XPlane:
-    name: str
-    lines: List[_XLine]
-
-
-def _read_varint(buf: bytes, i: int) -> Tuple[int, int]:
-    shift = v = 0
-    while True:
-        b = buf[i]
-        i += 1
-        v |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return v, i
-        shift += 7
-
-
-def _wire_fields(buf: bytes) -> Dict[int, list]:
-    """One-level protobuf wire decode: {field_number: [values]} with
-    varints as ints and length-delimited fields as bytes (fixed32/64
-    skipped — the XSpace subset uses neither)."""
-    i, n = 0, len(buf)
-    fields: Dict[int, list] = {}
-    while i < n:
-        tag, i = _read_varint(buf, i)
-        fn, wt = tag >> 3, tag & 7
-        if wt == 0:
-            v, i = _read_varint(buf, i)
-            fields.setdefault(fn, []).append(v)
-        elif wt == 2:
-            ln, i = _read_varint(buf, i)
-            fields.setdefault(fn, []).append(buf[i:i + ln])
-            i += ln
-        elif wt == 5:
-            i += 4
-        elif wt == 1:
-            i += 8
-        else:
-            raise ValueError(f"unsupported protobuf wire type {wt}")
-    return fields
-
-
-def _xspace_planes(path: str) -> List[_XPlane]:
-    """Decode an ``*.xplane.pb`` into the (plane -> line -> event)
-    skeleton :func:`parse_xplane` walks — the ProfileData stand-in."""
-    with open(path, "rb") as f:
-        space = _wire_fields(f.read())
-    planes = []
-    for plane_buf in space.get(1, ()):
-        p = _wire_fields(plane_buf)
-        meta: Dict[int, str] = {}
-        for entry in p.get(4, ()):  # event_metadata map entries
-            e = _wire_fields(entry)
-            if 1 in e and 2 in e:
-                val = _wire_fields(e[2][0])
-                meta[e[1][0]] = val.get(2, [b""])[0].decode(
-                    "utf-8", "replace"
-                )
-        lines = []
-        for line_buf in p.get(3, ()):
-            ln = _wire_fields(line_buf)
-            events = [
-                _XEvent(
-                    name=meta.get(ev.get(1, [0])[0], ""),
-                    duration_ns=ev.get(3, [0])[0] / 1e3,  # ps -> ns
-                )
-                for ev in map(_wire_fields, ln.get(4, ()))
-            ]
-            lines.append(
-                _XLine(
-                    name=ln.get(2, [b""])[0].decode("utf-8", "replace"),
-                    events=events,
-                )
-            )
-        planes.append(
-            _XPlane(
-                name=p.get(2, [b""])[0].decode("utf-8", "replace"),
-                lines=lines,
-            )
-        )
-    return planes
-
-
-def _load_planes(path: str):
-    """ProfileData when this jax ships it, else the wire-format reader —
-    a capability probe, not a version pin."""
-    try:
-        from jax.profiler import ProfileData
-    except ImportError:
-        return _xspace_planes(path)
-    return ProfileData.from_file(path).planes
-
-
 def find_xplane(trace_dir: str) -> str:
     """Newest ``*.xplane.pb`` under a ``jax.profiler.trace`` directory."""
     files = sorted(glob.glob(
@@ -254,7 +133,9 @@ def parse_xplane(path: str) -> Dict[str, KernelTime]:
     train step traced for k iterations reports k x per-step time; the
     ``count`` field lets callers normalize).
     """
-    planes = _load_planes(path)
+    from jax.profiler import ProfileData
+
+    planes = ProfileData.from_file(path).planes
     per_device: Dict[str, Dict[str, KernelTime]] = {}
     host: Dict[str, KernelTime] = {}
 

@@ -1350,6 +1350,13 @@ class GPTDecoder:
         One compiled program per (B, C) bucket; the cache is donated —
         rebind it.
         """
+        prog, args = self._chunk_call(cache, slot_tables, slots,
+                                      input_ids, base, valid)
+        return prog(*args)
+
+    def _chunk_call(self, cache, slot_tables, slots, input_ids, base,
+                    valid):
+        """The chunk program for these shapes and its call arguments."""
         slot_tables = jnp.asarray(slot_tables, jnp.int32)
         slots = jnp.asarray(slots, jnp.int32)
         input_ids = jnp.asarray(input_ids, jnp.int32)
@@ -1359,8 +1366,20 @@ class GPTDecoder:
             ("pchunk", input_ids.shape, slot_tables.shape[1],
              cache.page_len, cache.quantized)
         )
-        return prog(self.params, cache, slot_tables, slots, input_ids,
-                    base, valid)
+        return prog, (self.params, cache, slot_tables, slots, input_ids,
+                      base, valid)
+
+    def lower_prefill_chunk(
+        self, cache: PagedKVCache, slot_tables, slots, input_ids,
+        base, valid,
+    ):
+        """``jax.jit(...).lower(...)`` of the :meth:`prefill_chunk`
+        program for these shapes (the cache is not consumed) — the
+        prefill twin of :meth:`lower_paged_window`, for asking what was
+        compiled into it (``chip_smoke.py`` counts its Mosaic calls)."""
+        prog, args = self._chunk_call(cache, slot_tables, slots,
+                                      input_ids, base, valid)
+        return prog.lower(*args)
 
     def paged_decode_window(
         self, cache: PagedKVCache, tables, tokens, active, key,

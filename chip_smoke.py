@@ -1,0 +1,800 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the program still starts on the chip.
+
+Drives the train→serve main path once, in ONE process, through the entry
+points a user calls, at the full width of GPT-2 small (12 layers, d 768,
+12 heads, ctx 1024, V 50304; weights random from ``--seed``):
+
+- ``kernels`` — each main-path Pallas kernel (flash attention, fused
+  LayerNorm with the dgamma/dbeta epilogue, fused softmax-xentropy),
+  COMPILED, forward and gradients, against its own jnp reference at the
+  tolerance tiers of the kernels' tests;
+- ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, built
+  the way ``bench.py``'s GPT-2 metric builds it: three windows on a fixed
+  seeded batch;
+- ``serve`` — the params that phase produced, through ``GPTDecoder`` +
+  ``ServeEngine`` with the engine's own defaults (paged, bf16 cache, the
+  default K): six requests across the prefill buckets, two sharing a
+  long prefix, the short ones checked against ``reference_generate``.
+
+``--chips 4`` runs instead — and only — the path across chips and what it
+is compared with: ``dp``, the fused driver on a 4-device ``data`` mesh
+with the DDP allreduce against the same windows on one device, and
+``tp``, ``ServeEngine`` tensor-parallel over 4 chips against TP = 1.
+
+Nothing here hides the device.  The script sets no platform: it asks
+``jax.devices()`` and exits nonzero at once unless that is a TPU.  Every
+compiled program whose kernels' shape gates promise Mosaic calls is held
+to them (``apex_tpu.ops.mosaic_call_count``), so a kernel that ran
+interpreted or as its reference fails the run.  Any failed check or
+exception in any phase ends the process nonzero; nothing is caught and
+carried on from.  No utilisation is computed: the chip's peak rates are
+not this script's to assume.
+
+Output: one JSON line per phase, then as the LAST line exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+Needs no network and nothing outside the checkout.
+"""
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import sys
+import time
+from typing import Callable, Dict, List, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from apex_tpu.chip import compile_cache_dir, require_tpu
+from apex_tpu.ops import mosaic_call_count
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+# A greedy token may differ from the full-recompute reference only where
+# the reference itself calls it a tie: its logit within this much of the
+# reference's maximum.  bf16 keeps 8 bits — one ulp at the logit scale of
+# a barely-trained GPT-2 (|logit| < 8) is 2^-5 — and the cached and the
+# recomputed hidden states round differently through 12 layers.
+LOGIT_TIE_TOL = 2.0 ** -4
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """What the smoke runs at.  :data:`FULL` is the only size the command
+    line runs; ``tests/test_chip_smoke.py`` rehearses the control flow on
+    the CPU with a tiny one (and its own device check)."""
+
+    model: Dict[str, int]            # overrides of GPTConfig.small()
+    ctx: int                         # max_position, and the serve max_len
+    kernel_batch: int                # flash (b, heads, ctx, d); b*ctx LN rows
+    train_batch: int
+    steps_per_dispatch: int
+    windows: int
+    slots: int
+    prompt_lens: Tuple[int, ...]     # spanning the prefill buckets
+    prefix_len: int                  # two more requests share this prefix
+    prefix_tails: Tuple[int, int]
+    new_tokens: int
+    compared: int                    # first N requests vs reference_generate
+    dp_batch: int                    # --chips 4: the global batch, both sides
+    dp_steps_per_dispatch: int
+    tp_prompt_lens: Tuple[int, ...]
+    tp_new_tokens: int
+
+
+FULL = Sizes(
+    model={}, ctx=1024, kernel_batch=8,
+    # batch / steps_per_dispatch as bench.py's GPT-2 metric (GPT_BATCH,
+    # GPT_SCAN)
+    train_batch=16, steps_per_dispatch=10, windows=3,
+    slots=8, prompt_lens=(5, 64, 200, 700), prefix_len=256,
+    prefix_tails=(17, 40), new_tokens=32, compared=2,
+    # fp32 at batch 16 leaves the one-chip side of the comparison under
+    # 1 GiB of the chip's 16 (sandbox compile for the described v5e:
+    # 13.4 GiB temporaries + 1.4 GiB state), so the comparison runs at 8
+    dp_batch=8, dp_steps_per_dispatch=2,
+    tp_prompt_lens=(5, 64, 200), tp_new_tokens=16,
+)
+
+
+class SmokeFailure(RuntimeError):
+    """A check of a phase did not hold."""
+
+
+def _require(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+def _require_mosaic(compiled, at_least: int, record: Dict, key: str) -> None:
+    """Record the program's Mosaic call count under ``record[key]`` and
+    hold it to what its kernels' shape gates promise — fewer means a
+    kernel ran interpreted or was replaced by its reference."""
+    record[key] = n = mosaic_call_count(compiled)
+    _require(n >= at_least,
+             f"{key}: {n} tpu_custom_call(s) compiled in, expected >= "
+             f"{at_least}")
+
+
+# ---------------------------------------------------------------------------
+# metering: wall, compile events, persistent-cache hits — one JSON line/phase
+# ---------------------------------------------------------------------------
+
+class _Meter:
+    """Counts what JAX's monitoring reports while a phase runs: backend
+    compiles (``analysis.CompileMonitor`` — a persistent-cache hit still
+    fires the event, with the retrieval time) and the persistent cache's
+    hits and misses."""
+
+    _HIT = "/jax/compilation_cache/cache_hits"
+    _MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self, cache_dir: str):
+        self.cache_dir = cache_dir
+        self.cache_from_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+        self.cache_entries_at_start = (
+            len(sorted(os.listdir(cache_dir)))
+            if os.path.isdir(cache_dir) else 0
+        )
+        self.hits = self.misses = 0
+        self.active = True
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, name: str, **_):
+        if self.active:
+            self.hits += name == self._HIT
+            self.misses += name == self._MISS
+
+    def phase(self, name: str, fn: Callable[[Dict], None]) -> None:
+        """Run one phase — ``fn(facts)`` records what it measured, then
+        checks it — and print its line.  Whatever ``fn`` raises ends the
+        run: the line then carries ``"failed"`` and the facts recorded
+        up to that point, and the exception goes on up."""
+        from apex_tpu.analysis import CompileMonitor
+
+        compile_s: List[float] = []
+        facts: Dict = {}
+        failed = None
+        hits0, misses0 = self.hits, self.misses
+        t0 = time.perf_counter()
+        try:
+            with CompileMonitor(on_compile=compile_s.append):
+                fn(facts)
+        except BaseException as e:
+            failed = f"{type(e).__name__}: {e}"[:600]
+            raise
+        finally:
+            line = {
+                "phase": name,
+                "wall_s": round(time.perf_counter() - t0, 3),
+                "compile_s": round(sum(compile_s), 3),
+                "compiles": len(compile_s),
+                "cache": {
+                    "dir": self.cache_dir,
+                    "from_env": self.cache_from_env,
+                    "warm": self.cache_entries_at_start > 0,
+                    "hits": self.hits - hits0,
+                    "misses": self.misses - misses0,
+                },
+            }
+            if failed:
+                line["failed"] = failed
+            line.update(facts)
+            print(json.dumps(line), flush=True)
+
+
+@jax.jit
+def _err_stats(got, ref):
+    """(max |got - ref|, max |ref|, all finite), in fp32 — one program
+    per shape, not one per operator."""
+    got32, ref32 = got.astype(jnp.float32), ref.astype(jnp.float32)
+    return (jnp.max(jnp.abs(got32 - ref32)), jnp.max(jnp.abs(ref32)),
+            jnp.all(jnp.isfinite(got32)))
+
+
+def _compare(name: str, got, ref, tol: float, out: Dict) -> None:
+    """Hold ``got`` to ``ref`` at ``tol`` (scaled by the reference's
+    magnitude where that exceeds 1) and record the max error."""
+    err, scale, finite = (x.item() for x in _err_stats(got, ref))
+    out[name] = {"max_err": err, "ref_max": scale, "tol": tol}
+    _require(finite, f"kernel parity {name}: non-finite output")
+    _require(err <= tol * max(1.0, scale),
+             f"kernel parity {name}: max_err {err:.3e} > tol {tol:.1e} x "
+             f"max(1, {scale:.3e})")
+
+
+# ---------------------------------------------------------------------------
+# the model, built the way bench.py's GPT-2 metric builds it
+# ---------------------------------------------------------------------------
+
+def _init_params(model, seed: int, *args, **kw):
+    """``model.init(...)["params"]`` as ONE program (eagerly it is a
+    hundred small ones, each a compile of its own on the chip)."""
+    return jax.jit(
+        lambda key: model.init(key, *args, **kw)["params"]
+    )(jax.random.PRNGKey(seed))
+
+
+def _gpt_config(sizes: Sizes, **kw):
+    from apex_tpu.models.gpt import GPTConfig
+
+    return dataclasses.replace(
+        GPTConfig.small(**kw), max_position=sizes.ctx, **sizes.model
+    )
+
+
+def _train_setup(sizes: Sizes, seed: int, opt_level: str, *, batch: int,
+                 dropout: bool, ddp=None):
+    """``(step_fn, carry, (ids, labels), cfg, amp_)`` for one GPT-2 causal-LM
+    train step: AMP ``opt_level`` + ``fused_adam``, a fixed seeded batch.
+    ``step_fn(carry, batch)`` trains on ``batch`` — or on the closure's
+    batch when the driver passes None, as bench.py's metric does.  With
+    ``ddp`` the per-shard grads go through its allreduce."""
+    import apex_tpu.amp as amp
+    from apex_tpu.models.gpt import GPTLM
+    from apex_tpu.optimizers import fused_adam
+
+    amp_ = amp.initialize(opt_level)
+    rates = {} if dropout else {"dropout_rate": 0.0, "attn_dropout_rate": 0.0}
+    cfg = _gpt_config(sizes, compute_dtype=amp_.policy.compute_dtype, **rates)
+    model = GPTLM(cfg)
+    opt = amp.AmpOptimizer(fused_adam(6e-4, weight_decay=0.1), amp_)
+    rng = np.random.RandomState(seed)
+    b, s = batch, sizes.ctx
+    ids = rng.randint(0, cfg.vocab_size, size=(b, s)).astype(np.int32)
+    labels = np.concatenate(
+        [ids[:, 1:], np.full((b, 1), -100, np.int32)], axis=1
+    )
+    probe = min(128, s)
+    params = _init_params(
+        model, seed, ids[:1, :probe], labels=labels[:1, :probe]
+    )
+    state = jax.jit(opt.init)(params)
+    ids, labels = jnp.asarray(ids), jnp.asarray(labels)
+
+    def step(carry, batch):
+        params, state, key = carry
+        x, y = (ids, labels) if batch is None else batch
+        key, dkey = jax.random.split(key)
+
+        def scaled(mp):
+            _, loss = model.apply(
+                {"params": opt.model_params(mp)}, x, labels=y,
+                deterministic=not dropout, rngs={"dropout": dkey},
+            )
+            return amp_.scale_loss(loss, state.scaler[0]), loss
+
+        grads, loss = jax.grad(scaled, has_aux=True)(params)
+        if ddp is not None:
+            grads = ddp.allreduce(grads)
+            loss = jax.lax.pmean(loss, ddp.axis_name)
+        params, state, _ = opt.step(grads, state, params)
+        return (params, state, key), {"loss": loss}
+
+    carry = (params, state, jax.random.PRNGKey(seed + 1))
+    return step, carry, (ids, labels), cfg, amp_
+
+
+def _train_calls_expected(cfg, opt_level: str) -> int:
+    """Mosaic calls the train step's shape gates promise: per layer the
+    flash forward + combined backward and two LayerNorms forward +
+    backward, the final LayerNorm, and (half-precision logits only) the
+    xentropy forward + backward."""
+    xent = 2 if opt_level != "O0" else 0
+    return cfg.num_layers * (2 + 4) + 2 + xent
+
+
+# ---------------------------------------------------------------------------
+# phase: kernels
+# ---------------------------------------------------------------------------
+
+def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
+    """Each main-path kernel, compiled, vs its jnp reference: forward
+    and gradients at the model's widths.  The references run fp32 at
+    ``highest`` matmul precision (the TPU's default would round their
+    operands to bf16 and make them the less exact side)."""
+    from apex_tpu.ops import (
+        attention_ref, flash_attention, layer_norm, layer_norm_ref,
+        softmax_cross_entropy, softmax_cross_entropy_ref,
+    )
+
+    cfg = _gpt_config(sizes)
+    b, h, s = sizes.kernel_batch, cfg.num_heads, sizes.ctx
+    d, n, v = cfg.hidden_size // h, cfg.hidden_size, cfg.vocab_size
+    rows = b * s
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 3))
+    calls: Dict[str, int] = {}
+    parity: Dict[str, Dict] = {}
+    facts.update(
+        shapes={"flash": [b, h, s, d], "layer_norm": [rows, n],
+                "xentropy": [rows, v]},
+        mosaic_calls=calls, parity=parity,
+    )
+
+    def seeded(make):
+        """``make(k1, k2, k3, k4)`` -> a kernel's inputs, as one program."""
+        return jax.jit(lambda key: make(*jax.random.split(key, 4)))(next(keys))
+
+    def run(name, kernel_loss, ref_loss, args, ref_args, n_calls, tols):
+        """Value and grads of both sides.  ``tols`` = (forward tol,
+        ((grad name, tol), ...)): one grad per leading argument; the
+        arguments after those are constants (passed, not closed over —
+        a closed-over array would be baked into the program's text)."""
+        argnums = tuple(range(len(tols[1])))
+        kfn = jax.jit(jax.value_and_grad(kernel_loss, argnums, has_aux=True))
+        compiled = kfn.lower(*args).compile()
+        _require_mosaic(compiled, n_calls, calls, name)
+        (_, out), grads = compiled(*args)
+        with jax.default_matmul_precision("highest"):
+            (_, rout), rgrads = jax.jit(
+                jax.value_and_grad(ref_loss, argnums, has_aux=True)
+            )(*ref_args)
+        _compare(f"{name}.fwd", out, rout, tols[0], parity)
+        for (gname, tol), g, rg in zip(tols[1], grads, rgrads):
+            _compare(f"{name}.{gname}", g, rg, tol, parity)
+
+    # flash attention, causal, in-kernel dropout on — as the train step
+    # calls it.  bf16 in, fp32 reference of the same bf16 values: the
+    # kernel tests' bf16 tier (tests/test_ops_attention.py::test_bf16)
+    normal = jax.random.normal
+    q, k, vv, w_out = seeded(lambda *ks: [
+        (normal(ki, (b, h, s, d), f32) * scale).astype(dt)
+        for ki, scale, dt in zip(ks, (0.3, 0.3, 0.3, 1.0),
+                                 (bf16, bf16, bf16, f32))
+    ])
+    drop_seed = np.int32(seed + 11)
+
+    def flash_loss(fn):
+        def loss(q, k, v, w, drop_seed):
+            out = fn(q, k, v, causal=True, dropout_rate=0.1,
+                     dropout_seed=drop_seed)
+            return jnp.sum(out.astype(f32) * w), out
+        return loss
+
+    run("flash", flash_loss(flash_attention), flash_loss(attention_ref),
+        (q, k, vv, w_out, drop_seed),
+        tuple(t.astype(f32) for t in (q, k, vv)) + (w_out, drop_seed), 2,
+        (3e-2, (("dq", 3e-2), ("dk", 3e-2), ("dv", 3e-2))))
+
+    # fused LayerNorm, fp32 rows as the model feeds it; the backward is
+    # the dx pass with the dgamma/dbeta epilogue (default-on since r5,
+    # first checked on hardware here).  tests/test_ops_layer_norm.py
+    # tiers: forward 1e-5, grads 1e-3
+    ln_args = seeded(lambda kx, kg, kb, kw: (
+        normal(kx, (rows, n), f32) * 2.0 + 0.5,        # x
+        1.0 + 0.1 * normal(kg, (n,), f32),             # gamma
+        0.1 * normal(kb, (n,), f32),                   # beta
+        normal(kw, (rows, n), f32),                    # cotangent
+    ))
+
+    def ln_loss(fn):
+        def loss(x, g, b_, w):
+            out = fn(x, g, b_)
+            return jnp.sum(out * w), out
+        return loss
+
+    run("layer_norm", ln_loss(layer_norm), ln_loss(layer_norm_ref),
+        ln_args, ln_args, 2,
+        (1e-5, (("dx", 1e-3), ("dgamma", 1e-3), ("dbeta", 1e-3))))
+
+    # fused softmax-xentropy on compute-dtype logits at the full vocab
+    # (the model's loss path).  Both sides upcast the same bf16 logits:
+    # losses at the fp32 tier of tests/test_ops_xentropy.py (1e-4); the
+    # gradient comes back in bf16, so one bf16 ulp at 1.0 (2^-7)
+    xent_args = seeded(lambda kl, kt, *_: (
+        (normal(kl, (rows, v), f32) * 3.0).astype(bf16),     # logits
+        jax.random.randint(kt, (rows,), 0, v, jnp.int32),    # labels
+    ))
+
+    def xent_loss(fn):
+        def loss(lg, lb):
+            per_row = fn(lg, lb)
+            return jnp.sum(per_row), per_row
+        return loss
+
+    run("xentropy", xent_loss(softmax_cross_entropy),
+        xent_loss(softmax_cross_entropy_ref), xent_args, xent_args, 2,
+        (1e-4, (("dlogits", 2.0 ** -7),)))
+
+    facts["max_err"] = max(p["max_err"] for p in parity.values())
+
+
+# ---------------------------------------------------------------------------
+# phase: train
+# ---------------------------------------------------------------------------
+
+def phase_train(sizes: Sizes, seed: int, facts: Dict, handoff: Dict) -> None:
+    """Three fused-driver windows of AMP O2 + fused_adam on a fixed
+    seeded batch; leaves the trained masters and config in ``handoff``
+    for the serve phase."""
+    from apex_tpu.analysis import CompileMonitor
+    from apex_tpu.ops.layer_norm import fused_dgamma_active
+    from apex_tpu.train import FusedTrainDriver, read_metrics
+
+    step, carry, _, cfg, amp_ = _train_setup(
+        sizes, seed, "O2", batch=sizes.train_batch, dropout=True
+    )
+    driver = FusedTrainDriver(
+        step, steps_per_dispatch=sizes.steps_per_dispatch,
+        metrics={"loss": "mean"},
+    )
+    losses: List[float] = []
+    window_s: List[float] = []
+    facts.update(
+        model={"layers": cfg.num_layers, "hidden": cfg.hidden_size,
+               "heads": cfg.num_heads, "ctx": cfg.max_position,
+               "vocab": cfg.vocab_size},
+        batch=sizes.train_batch, steps_per_dispatch=sizes.steps_per_dispatch,
+        windows=sizes.windows, ln_fused_dgamma=fused_dgamma_active(),
+        loss_per_window=losses, warm_window_s=window_s,
+    )
+    # what is compiled into the window: flash, LN and xentropy all engaged
+    _require_mosaic(driver.lower(carry).compile(),
+                    _train_calls_expected(cfg, "O2"), facts, "mosaic_calls")
+    _require(fused_dgamma_active(), "LN dgamma/dbeta epilogue is switched off")
+
+    def window(carry):
+        t0 = time.perf_counter()
+        carry, res = driver.run_window(carry)
+        loss = read_metrics(res.metrics)["loss"]     # the host fetch
+        losses.append(round(float(loss), 4))
+        return carry, round(time.perf_counter() - t0, 3)
+
+    carry, _ = window(carry)         # runs the executable compiled above
+    with CompileMonitor() as mon:
+        for _ in range(sizes.windows - 1):
+            carry, dt = window(carry)
+            window_s.append(dt)
+    facts["compiles_after_first_window"] = mon.compiles
+    _require(mon.compiles == 0,
+             f"train: {mon.compiles} compile(s) after the first window")
+    _require(all(np.isfinite(losses)), f"train: non-finite loss {losses}")
+    _require(losses[-1] < losses[0],
+             f"train: loss did not fall over {sizes.windows} windows: {losses}")
+    params = carry[0]
+    dtypes = {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)}
+    _require(dtypes == {"float32"}, f"train: master params are {dtypes}")
+    handoff.update(params=params, cfg=cfg, policy=amp_.policy)
+
+
+# ---------------------------------------------------------------------------
+# phase: serve
+# ---------------------------------------------------------------------------
+
+def _prompts(vocab: int, seed: int, lens: Sequence[int], prefix_len: int = 0,
+             prefix_tails: Sequence[int] = ()) -> List[List[int]]:
+    """Seeded prompts: one per length in ``lens``, then one per tail in
+    ``prefix_tails`` that all share one ``prefix_len``-token prefix."""
+    rng = np.random.RandomState(seed + 2)
+    draw = lambda n: [int(t) for t in rng.randint(0, vocab, size=(n,))]
+    prompts = [draw(n) for n in lens]
+    prefix = draw(prefix_len)
+    return prompts + [prefix + draw(n) for n in prefix_tails]
+
+
+def _drain(decoder, sizes: Sizes, prompts: Sequence[Sequence[int]],
+           new_tokens: int, late: int = 0):
+    """A fresh engine on ``decoder`` (programs are cached per decoder),
+    the engine's own defaults, driven by submit/step/run to drained.
+    The last ``late`` prompts arrive late: submitted once the request
+    before them has its first token, i.e. has prefilled and published
+    its prompt pages — a prefix is shared with a request that is being
+    served, not with one that is still queued."""
+    from apex_tpu.serve import ServeEngine
+
+    engine = ServeEngine(decoder, slots=sizes.slots, max_len=sizes.ctx)
+    submit = lambda p: engine.submit(p, max_new_tokens=new_tokens)
+    early = len(prompts) - late
+    uids = [submit(p) for p in prompts[:early]]
+    if late:
+        while not engine.progress()[uids[-1]][0]:
+            engine.step()
+        uids += [submit(p) for p in prompts[early:]]
+    out = engine.run()
+    return engine, [out[u] for u in uids]
+
+
+def _reference_logits(decoder) -> Callable[[Sequence[int]], np.ndarray]:
+    """``seq -> (len(seq), V)`` fp32 logits of the full-recompute forward
+    of ``decoder``'s model (the training forward, no cache — what
+    ``reference_generate`` runs per token), padded to a power-of-two
+    width so a few programs serve every length."""
+    forward = jax.jit(lambda p, x: decoder.model.apply({"params": p}, x))
+
+    def logits(seq: Sequence[int]) -> np.ndarray:
+        width = 8
+        while width < len(seq):
+            width *= 2
+        ids = np.zeros((1, min(width, decoder.cfg.max_position)), np.int32)
+        ids[0, :len(seq)] = seq
+        return np.asarray(forward(decoder.params, ids),
+                          np.float32)[0, :len(seq)]
+
+    return logits
+
+
+def _check_greedy(reference_logits, prompt, served, expected,
+                  record: List[Dict]) -> None:
+    """Served greedy tokens vs the expected ones: identical, or — where
+    bf16 flipped a near-tie — every served token within
+    :data:`LOGIT_TIE_TOL` of the full-recompute reference's own maximum
+    at that position (``prompt + served`` teacher-forced; the margin is
+    0.0 where the reference picks the same token).  The comparison is
+    never dropped, only restated: the entry appended to ``record`` says
+    which of the two held."""
+    rows = reference_logits(list(prompt) + list(served))
+    rows = rows[len(prompt) - 1:-1]               # row i predicts served[i]
+    picked = rows[np.arange(len(served)), np.asarray(served)]
+    margin = float(np.max(rows.max(axis=-1) - picked))
+    identical = list(served) == list(expected)
+    record.append({"prompt_len": len(prompt), "tokens_identical": identical,
+                   "logit_margin": margin, "tol": LOGIT_TIE_TOL})
+    _require(identical or margin <= LOGIT_TIE_TOL,
+             f"request {len(record) - 1}: tokens differ from the expected "
+             f"ones and the served choice is {margin:.4f} below the "
+             f"reference's maximum logit (tol {LOGIT_TIE_TOL})")
+
+
+def _serve_programs_calls(decoder, engine, expected: int,
+                          calls: Dict[str, int]) -> None:
+    """Mosaic calls compiled into the two serve programs the drain ran —
+    one prefill chunk (the widest bucket) and the decode window — from
+    the engine's own cache and page tables (lowering consumes nothing)."""
+    slots = engine.cache.slots
+    chunk = decoder.lower_prefill_chunk(
+        engine.cache, engine.pool.tables[:1], np.zeros((1,), np.int32),
+        np.zeros((1, engine.prefill_chunk), np.int32),
+        np.zeros((1,), np.int32), np.ones((1,), np.int32),
+    ).compile()
+    _require_mosaic(chunk, expected, calls, "prefill_chunk")
+    window = decoder.lower_paged_window(
+        engine.cache, engine.pool.tables, np.zeros((slots,), np.int32),
+        np.ones((slots,), bool), jax.random.PRNGKey(0),
+    ).compile()
+    _require_mosaic(window, expected, calls, "decode_window")
+
+
+def phase_serve(sizes: Sizes, seed: int, facts: Dict, handoff: Dict) -> None:
+    """The trained params through GPTDecoder + ServeEngine: a warm-up
+    drain compiles every shape the traffic uses, a second drain of the
+    same traffic must compile nothing, and the short requests are held
+    to ``reference_generate``."""
+    from apex_tpu.analysis import CompileMonitor
+    from apex_tpu.serve import GPTDecoder, reference_generate
+
+    cfg, params = handoff["cfg"], handoff["params"]
+    decoder = GPTDecoder(cfg, params, policy=handoff["policy"])
+    prompts = _prompts(cfg.vocab_size, seed, sizes.prompt_lens,
+                       sizes.prefix_len, sizes.prefix_tails)
+    facts.update(requests=len(prompts), prompt_lens=[len(p) for p in prompts])
+
+    t0 = time.perf_counter()
+    _drain(decoder, sizes, prompts, sizes.new_tokens, late=1)  # warm-up
+    facts["warmup_drain_s"] = round(time.perf_counter() - t0, 3)
+    with CompileMonitor() as mon:
+        t0 = time.perf_counter()
+        engine, served = _drain(decoder, sizes, prompts, sizes.new_tokens,
+                                late=1)
+        facts["warm_drain_s"] = round(time.perf_counter() - t0, 3)
+    stats = engine.stats()
+    facts.update(
+        compiles_after_warmup=mon.compiles,
+        tokens=sum(len(t) for t in served),
+        **{k: stats[k] for k in (
+            "kv_dtype", "page_len", "tokens_per_dispatch",
+            "prefill_dispatches", "decode_dispatches", "prefix_hit_tokens")},
+    )
+    _require(mon.compiles == 0,
+             f"serve: {mon.compiles} compile(s) after warm-up of the same "
+             "shapes")
+    _require(all(len(t) == sizes.new_tokens for t in served),
+             f"serve: token counts {[len(t) for t in served]}, expected "
+             f"{sizes.new_tokens} each")
+    _require(stats["prefix_hit_tokens"] > 0,
+             "serve: the shared-prefix pair reused no cached page")
+
+    # fused LN at least (models/gpt.py: two per layer + the final one)
+    calls = facts["mosaic_calls"] = {}
+    _serve_programs_calls(decoder, engine, 2 * cfg.num_layers + 1, calls)
+
+    compared = facts["reference"] = []
+    reference_logits = _reference_logits(decoder)
+    for prompt, tokens in zip(prompts[:sizes.compared], served):
+        expected = reference_generate(cfg, params, prompt, sizes.new_tokens)
+        _check_greedy(reference_logits, prompt, tokens, expected, compared)
+    facts["max_err"] = max(c["logit_margin"] for c in compared)
+
+
+# ---------------------------------------------------------------------------
+# --chips 4: the path across chips, and what it is compared with
+# ---------------------------------------------------------------------------
+
+def _require_spread(tree, n: int, record: Dict, key: str) -> None:
+    """Every array leaf of ``tree`` really sits on ``n`` distinct devices
+    (the smallest device set of any leaf goes to ``record[key]``)."""
+    record[key] = got = min(
+        len(leaf.sharding.device_set)
+        for leaf in jax.tree_util.tree_leaves(tree)
+    )
+    _require(got == n, f"{key}: a leaf sits on {got} device(s), not {n}")
+
+
+def phase_dp(sizes: Sizes, seed: int, facts: Dict, chips: int) -> None:
+    """Data-parallel training across the chips vs one chip: the same two
+    fused-driver windows of the same global batch, on a ``chips``-device
+    ``data`` mesh through ``FusedTrainDriver(mesh=...)`` with the DDP
+    allreduce, and on one device.
+
+    Compared the way ``__graft_entry__``'s dry run compares — at fp32
+    (O0), dropout off: the graph has the identical collective structure
+    as O2, and its tiers (loss rtol 1e-5; params rtol 1e-3, atol 3e-4)
+    only mean something where the two sides differ by summation order
+    alone.  On the chip that also needs ``highest`` matmul precision: at
+    the default, fp32 operands are rounded to bf16 at every matmul, a
+    last-bit difference upstream flips such a rounding by a whole bf16
+    ulp, and Adam's normalised update turns a gradient that changed
+    sign into a +-lr step (first chip run of this phase, default
+    precision: per-step losses within 1e-5, yet 4.9e-4 of the params
+    outside the tier; at ``highest``: none of 124.5M, losses within
+    2e-7).  The per-step loss is the sharp instrument — a mis-reduced
+    gradient moves the next step's loss by far more than 1e-5; the
+    params are held to the tier on all but 1e-5 of the elements (a ~0
+    gradient may still change sign), and the whole update to a relative
+    L2 error."""
+    from apex_tpu.parallel import DistributedDataParallel, replicate
+    from apex_tpu.parallel.mesh import data_parallel_mesh
+    from apex_tpu.train import FusedTrainDriver
+
+    k, windows = sizes.dp_steps_per_dispatch, 2
+    calls = facts["mosaic_calls"] = {}
+    facts.update(opt_level="O0", matmul_precision="highest",
+                 global_batch=sizes.dp_batch, steps=k * windows)
+
+    def run(mesh, tag):
+        ddp = None if mesh is None else DistributedDataParallel(
+            axis_name="data", allreduce_always_fp32=True
+        )
+        step, carry, (ids, labels), cfg, _ = _train_setup(
+            sizes, seed, "O0", batch=sizes.dp_batch, dropout=False, ddp=ddp
+        )
+        start = jax.tree_util.tree_map(np.asarray, carry[0])
+        window = tuple(np.broadcast_to(t, (k,) + t.shape)
+                       for t in (ids, labels))
+        if mesh is not None:
+            carry = replicate(carry, mesh)
+        driver = FusedTrainDriver(
+            step, steps_per_dispatch=k, mesh=mesh, check_vma=False,
+            metrics={"loss": "mean"}, per_step=("loss",),
+        )
+        _require_mosaic(driver.lower(carry, window).compile(),
+                        _train_calls_expected(cfg, "O0"), calls, tag)
+        losses = facts[f"loss_per_step_{tag}"] = []
+        for _ in range(windows):
+            carry, res = driver.run_window(carry, window)
+            losses += [float(x) for x in np.asarray(res.per_step["loss"])]
+        if mesh is not None:
+            _require_spread(carry, chips, facts, "devices_holding_carry")
+        return jax.tree_util.tree_map(np.asarray, carry[0]), losses, start
+
+    with jax.default_matmul_precision("highest"):
+        params_n, losses_n, start = run(data_parallel_mesh(chips), "mesh")
+        params_1, losses_1, _ = run(None, "one_chip")
+
+    loss_rel = max(abs(a - b) / abs(b) for a, b in zip(losses_n, losses_1))
+    outside = total = 0
+    max_abs = diff_sq = upd_sq = 0.0
+    for a, b, p0 in zip(*(jax.tree_util.tree_leaves(t)
+                          for t in (params_n, params_1, start))):
+        d = np.abs(a - b)
+        outside += int(np.sum(d > 3e-4 + 1e-3 * np.abs(b)))
+        total += d.size
+        max_abs = max(max_abs, float(d.max()))
+        diff_sq += float(np.sum(np.square(d, dtype=np.float64)))
+        upd_sq += float(np.sum(np.square(b - p0, dtype=np.float64)))
+    frac = outside / total
+    upd_rel = (diff_sq / upd_sq) ** 0.5
+    facts.update(
+        loss_max_rel_diff=loss_rel, params_max_abs_diff=max_abs,
+        params_outside_tier=outside, params_total=total,
+        update_rel_l2_diff=upd_rel, max_err=loss_rel,
+    )
+    _require(all(np.isfinite(losses_n + losses_1)), "dp: non-finite loss")
+    _require(loss_rel <= 1e-5,
+             f"dp: per-step losses differ by rel {loss_rel:.2e} > 1e-5")
+    _require(frac <= 1e-5,
+             f"dp: {outside} of {total} updated params outside rtol 1e-3 / "
+             f"atol 3e-4 (fraction {frac:.2e} > 1e-5)")
+    _require(upd_rel <= 1e-3,
+             f"dp: the {chips}-chip update differs from the 1-chip update "
+             f"by relative L2 {upd_rel:.3e} > 1e-3")
+
+
+def phase_tp(sizes: Sizes, seed: int, facts: Dict, chips: int) -> None:
+    """ServeEngine tensor-parallel over the chips (heads and KV pool
+    sharded, ``serve/sharding.py``) vs TP = 1: identical greedy tokens
+    (or, per :func:`_check_greedy`, reference-tied ones)."""
+    import apex_tpu.amp as amp
+    from apex_tpu.models.gpt import GPTLM
+    from apex_tpu.serve import GPTDecoder
+    from apex_tpu.serve.sharding import serve_mesh
+
+    policy = amp.initialize("O2").policy
+    cfg = _gpt_config(sizes, compute_dtype=policy.compute_dtype)
+    params = _init_params(GPTLM(cfg), seed, np.zeros((1, 8), np.int32))
+    prompts = _prompts(cfg.vocab_size, seed, sizes.tp_prompt_lens)
+    facts.update(tp=chips, heads_per_chip=cfg.num_heads // chips,
+                 requests=len(prompts))
+
+    sharded = GPTDecoder(cfg, params, policy=policy, mesh=serve_mesh(chips))
+    engine, tokens_n = _drain(sharded, sizes, prompts, sizes.tp_new_tokens)
+    pool = (engine.cache.k, engine.cache.v)
+    _require_spread(pool, chips, facts, "devices_holding_kv_pool")
+    heads = {x.addressable_shards[0].data.shape[2] for x in pool}
+    _require(heads == {cfg.num_heads // chips},
+             f"tp: {heads} heads per chip, expected {cfg.num_heads // chips}")
+    del engine, pool
+
+    single = GPTDecoder(cfg, params, policy=policy)
+    _, tokens_1 = _drain(single, sizes, prompts, sizes.tp_new_tokens)
+    facts["tokens"] = sum(map(len, tokens_n))
+    compared = facts["compared"] = []
+    reference_logits = _reference_logits(single)
+    for prompt, tn, t1 in zip(prompts, tokens_n, tokens_1):
+        _check_greedy(reference_logits, prompt, tn, t1, compared)
+    facts.update(
+        tokens_identical=all(c["tokens_identical"] for c in compared),
+        max_err=max(c["logit_margin"] for c in compared),
+    )
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None, sizes: Sizes = FULL) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the weights, the batch and the prompts")
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run ONLY the data-parallel and tensor-parallel "
+                         "comparisons across four chips")
+    args = ap.parse_args(argv)
+
+    device = require_tpu()      # exits nonzero unless JAX reports a TPU
+    if device["count"] < args.chips:
+        raise SystemExit(
+            f"--chips {args.chips} needs {args.chips} devices; JAX reports "
+            f"{device['count']}"
+        )
+    cache_dir = compile_cache_dir(HERE)
+    # Cache every program, however quick its compile.  JAX's default keeps
+    # out whatever compiled in under a second — on the chip that is most
+    # of a run's programs (the engine's eager sampling ops, ~0.1 s each),
+    # and together a quarter of a cold run's compile time.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    meter = _Meter(cache_dir)
+    try:
+        if args.chips == 1:
+            handoff: Dict = {}
+            meter.phase("kernels",
+                        lambda f: phase_kernels(sizes, args.seed, f))
+            meter.phase("train",
+                        lambda f: phase_train(sizes, args.seed, f, handoff))
+            meter.phase("serve",
+                        lambda f: phase_serve(sizes, args.seed, f, handoff))
+        else:
+            meter.phase("dp",
+                        lambda f: phase_dp(sizes, args.seed, f, args.chips))
+            meter.phase("tp",
+                        lambda f: phase_tp(sizes, args.seed, f, args.chips))
+    finally:
+        meter.active = False
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

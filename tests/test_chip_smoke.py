@@ -1,0 +1,212 @@
+"""chip_smoke.py's control flow, rehearsed on the CPU at a tiny size.
+
+This is rehearsal 1 of the on-chip-measurement guide (§2): wrong paths,
+arguments and control flow are found here at no chip time.  The only
+things that differ from the chip are the two device facts — which device
+JAX reports, and whether Mosaic calls are compiled in — and they differ
+HERE, in the harness (``rehearse``), not through an option of the script
+that could ship a CPU pass.  A pass of these tests is not a chip run.
+"""
+import json
+import os
+import subprocess
+import sys
+import types
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import chip_smoke  # noqa: E402
+
+TINY = chip_smoke.Sizes(
+    model=dict(vocab_size=1024, hidden_size=128, num_layers=2, num_heads=4),
+    ctx=128, kernel_batch=1, train_batch=2,
+    steps_per_dispatch=2, windows=3, slots=6,
+    prompt_lens=(5, 20, 70), prefix_len=32, prefix_tails=(3, 9),
+    new_tokens=20, compared=2, dp_batch=4, dp_steps_per_dispatch=2,
+    tp_prompt_lens=(5, 20), tp_new_tokens=6,
+)
+FAKE_TPU = {"platform": "tpu", "kind": "rehearsal (CPU)", "count": 1}
+
+
+@pytest.fixture
+def rehearse(monkeypatch, tmp_path):
+    """Stand in for the chip's two device facts, and keep the persistent
+    cache out of the checkout: with ``JAX_COMPILATION_CACHE_DIR`` set the
+    script sets no directory, so this process's jax config is untouched."""
+    import jax
+
+    monkeypatch.setattr(chip_smoke, "require_tpu", lambda: dict(FAKE_TPU))
+    monkeypatch.setattr(chip_smoke, "mosaic_call_count", lambda compiled: 10**6)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    threshold = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", threshold)
+
+
+def _lines(capsys):
+    return [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("{")]
+
+
+def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
+    assert chip_smoke.main([], sizes=TINY) == 0
+    lines = _lines(capsys)
+    assert [ln["phase"] for ln in lines[:-1] if "phase" in ln] == \
+        ["kernels", "train", "serve"]
+    assert lines[-1] == {"ok": True, "device": FAKE_TPU}
+    assert list(lines[-1]) == ["ok", "device"]          # nothing more
+    kernels, train, serve = (ln for ln in lines if "phase" in ln)
+    for ln in (kernels, train, serve):
+        assert {"wall_s", "compile_s", "compiles", "cache",
+                "mosaic_calls"} <= set(ln)
+        assert ln["cache"]["from_env"] is True
+    assert set(kernels["mosaic_calls"]) == {"flash", "layer_norm", "xentropy"}
+    assert train["loss_per_window"][-1] < train["loss_per_window"][0]
+    assert train["compiles_after_first_window"] == 0
+    assert serve["compiles_after_warmup"] == 0
+    assert serve["tokens"] == 5 * TINY.new_tokens
+    assert serve["prefix_hit_tokens"] > 0
+    assert all(c["tokens_identical"] for c in serve["reference"])
+
+
+def test_planted_failure_gives_nonzero_exit_and_no_ok_line(
+        rehearse, monkeypatch, capsys):
+    """A check that fails in a phase raises out of main (a nonzero exit
+    from the command line): its line says ``failed`` and keeps what was
+    measured up to there, later phases never start, no ok line."""
+    ran = []
+    monkeypatch.setattr(
+        chip_smoke, "phase_kernels",
+        lambda sizes, seed, facts: ran.append("kernels"),
+    )
+
+    def failing_train(sizes, seed, facts, handoff):
+        ran.append("train")
+        facts["loss_per_window"] = [9.0]
+        chip_smoke._require(False, "planted")
+
+    monkeypatch.setattr(chip_smoke, "phase_train", failing_train)
+    monkeypatch.setattr(
+        chip_smoke, "phase_serve", lambda *a: ran.append("serve"),
+    )
+    with pytest.raises(chip_smoke.SmokeFailure, match="planted"):
+        chip_smoke.main([], sizes=TINY)
+    assert ran == ["kernels", "train"]
+    lines = _lines(capsys)
+    assert [ln["phase"] for ln in lines] == ["kernels", "train"]
+    assert "failed" not in lines[0]
+    assert lines[1]["failed"] == "SmokeFailure: planted"
+    assert lines[1]["loss_per_window"] == [9.0]
+    assert not any("ok" in ln for ln in lines)
+
+
+def test_missing_mosaic_call_fails_the_phase(rehearse, monkeypatch):
+    """On the CPU the kernels are their references: without the
+    harness's stand-in the very first compiled program is refused."""
+    from apex_tpu.ops import mosaic_call_count
+
+    monkeypatch.setattr(chip_smoke, "mosaic_call_count", mosaic_call_count)
+    with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
+        chip_smoke.main([], sizes=TINY)
+
+
+def test_device_check_refuses_the_cpu():
+    """The shipped command, no harness: no TPU, no run — a nonzero exit
+    within seconds and no result line."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "no TPU" in proc.stderr
+
+
+def test_four_chip_option_needs_four_devices(rehearse):
+    with pytest.raises(SystemExit, match="--chips 4 needs 4 devices"):
+        chip_smoke.main(["--chips", "4"], sizes=TINY)
+
+
+def test_four_chip_phases_on_virtual_devices(rehearse, monkeypatch, capsys):
+    """Rehearsal 2: the path across chips on four of conftest's virtual
+    CPU devices — only the two comparisons run, and the last line carries
+    the count the device check reported."""
+    four = dict(FAKE_TPU, count=4)
+    monkeypatch.setattr(chip_smoke, "require_tpu", lambda: dict(four))
+    assert chip_smoke.main(["--chips", "4"], sizes=TINY) == 0
+    lines = _lines(capsys)
+    assert [ln["phase"] for ln in lines if "phase" in ln] == ["dp", "tp"]
+    dp, tp = (ln for ln in lines if "phase" in ln)
+    assert dp["devices_holding_carry"] == 4
+    assert dp["loss_per_step_mesh"] != [] and dp["loss_max_rel_diff"] <= 1e-5
+    assert tp["devices_holding_kv_pool"] == 4 and tp["tokens_identical"]
+    assert lines[-1] == {"ok": True, "device": four}
+
+
+def test_compile_cache_dir_honours_env_else_fixed_checkout_path(monkeypatch):
+    import jax
+
+    from apex_tpu.chip import compile_cache_dir
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache_dir(ROOT) == "/somewhere/else"
+    assert updates == []                 # no other directory set in code
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = os.path.join(ROOT, ".jax_cache")
+    assert compile_cache_dir(ROOT) == fixed
+    assert compile_cache_dir(ROOT + "/") == fixed      # same path each time
+    assert updates == [("jax_compilation_cache_dir", fixed)] * 2
+
+
+@pytest.mark.parametrize("failing,rc", [(None, 0), ("bert", 4)])
+def test_bench_orchestrator_exit_code_follows_chip_children(
+        monkeypatch, tmp_path, failing, rc):
+    """bench.py's orchestrator with stub children: every child 'runs',
+    and a chip child that fails (twice — it is retried once) makes the
+    orchestrator exit nonzero AFTER it flushed a complete artifact."""
+    import bench
+
+    def fake_run(cmd, **kw):
+        if "--only" in cmd:
+            name = cmd[cmd.index("--only") + 1]
+            if name == failing:
+                return types.SimpleNamespace(
+                    returncode=1, stdout="", stderr="RuntimeError: boom")
+            return types.SimpleNamespace(
+                returncode=0, stderr="",
+                stdout=json.dumps({"metric": name, "value": 1.0}) + "\n")
+        if "-c" in cmd:                               # probe_backend
+            return types.SimpleNamespace(returncode=0, stdout="tpu 1\n",
+                                         stderr="")
+        kw["stdout"].write("1 configs compared\n")    # the L1 sweep
+        return types.SimpleNamespace(returncode=0)
+
+    # the orchestrator resolves every path from its own file's directory
+    (tmp_path / "tests" / "L1").mkdir(parents=True)
+    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--budget", "100000"])
+    # the orchestrator must be off JAX; in this (JAX-laden) test process
+    # that is simulated, and its refusal checked first
+    with pytest.raises(RuntimeError, match="orchestrator imported jax"):
+        bench.main()
+    monkeypatch.delitem(sys.modules, "jax")
+    if rc:
+        with pytest.raises(SystemExit) as exc:
+            bench.main()
+        assert exc.value.code == rc
+    else:
+        bench.main()
+    artifact = json.loads((tmp_path / "BENCH_partial.json").read_text())
+    assert artifact["complete"] is True
+    banked = {m["metric"] for m in artifact["metrics"]}
+    assert banked >= set(bench.CHIP_METRICS) - {failing}
+    assert failing not in banked
+    assert any("without a result" in n for n in artifact["notes"]) == bool(rc)

@@ -28,6 +28,7 @@ ADVERTISED = [
     "apex_tpu.contrib.groupbn",
     "apex_tpu.contrib.sparsity",
     "apex_tpu.checkpoint",
+    "apex_tpu.chip",
     "apex_tpu.data",
     "apex_tpu.parallel.ring_attention",
     "apex_tpu.parallel.ulysses",

@@ -133,10 +133,10 @@ def test_fused_dgamma_ragged_rows_eps0(rng):
         )
 
 
-def test_fused_dgamma_probe_fallback(rng, monkeypatch):
-    """A Mosaic compile failure in the dgamma/dbeta epilogue must degrade
-    to the bit-exact XLA-reduction backward inside the library (moved
-    from bench.py's r5 retry), and be visible via fused_dgamma_active()."""
+def test_fused_dgamma_env_kill_switch(rng, monkeypatch):
+    """APEX_TPU_LN_FUSED_DGAMMA=0 pins the XLA-reduction backward: the
+    epilogue kernel is never reached (the flag is the only gate — there
+    is no compile probe behind it) and fused_dgamma_active() says so."""
     import importlib
 
     from apex_tpu.ops._common import force_pallas
@@ -147,10 +147,11 @@ def test_fused_dgamma_probe_fallback(rng, monkeypatch):
     ln = importlib.import_module("apex_tpu.ops.layer_norm")
 
     def boom(*a, **k):
-        raise RuntimeError("synthetic Mosaic compile failure")
+        raise AssertionError("epilogue kernel reached with the switch off")
 
+    monkeypatch.setattr(ln, "_FUSED_DGAMMA", False)
     monkeypatch.setattr(ln, "_ln_bwd_dx_dwdb_pallas", boom)
-    monkeypatch.setattr(ln, "_fused_dgamma_probe", {})
+    assert not ln.fused_dgamma_active()
 
     n = 128
     x = jnp.asarray(rng.randn(64, n).astype(np.float32))
@@ -162,21 +163,7 @@ def test_fused_dgamma_probe_fallback(rng, monkeypatch):
 
     with force_pallas(True):
         gk = jax.grad(loss(ln.layer_norm), argnums=(0, 1, 2))(x, w, b)
-    assert not ln.fused_dgamma_active()  # the failed probe is recorded
     gr = jax.grad(loss(layer_norm_ref), argnums=(0, 1, 2))(x, w, b)
     for a, r in zip(gk, gr):
-        assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    atol=2e-4, rtol=1e-4)
-
-
-def test_fused_dgamma_env_kill_switch(monkeypatch):
-    """APEX_TPU_LN_FUSED_DGAMMA=0 pins the XLA-reduction path."""
-    import importlib
-
-    ln = importlib.import_module("apex_tpu.ops.layer_norm")
-    monkeypatch.setattr(ln, "_FUSED_DGAMMA", False)
-    assert not ln._fused_dgamma_ok(
-        jnp.zeros((8, 128)), jnp.zeros((128,)), jnp.zeros((8, 128)),
-        1e-5, 256,
-    )

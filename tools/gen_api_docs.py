@@ -61,6 +61,7 @@ MODULES = [
     "apex_tpu.sharding.apply",
     "apex_tpu.remat",
     "apex_tpu.checkpoint",
+    "apex_tpu.chip",
     "apex_tpu.data",
     "apex_tpu.pyprof.parse",
     "apex_tpu.pyprof.prof",
