@@ -220,7 +220,10 @@ class Amp:
                 return x.astype(jnp.float32)
             return x.astype(dtype)
 
-        return jax.tree_util.tree_map_with_path(cast, params)
+        # masters -> compute dtype, under one name in the device trace
+        # (and, transposed, the gradients' way back to float32)
+        with jax.named_scope("apex_amp_cast"):
+            return jax.tree_util.tree_map_with_path(cast, params)
 
     def cast_output(self, out: PyTree) -> PyTree:
         """ref _initialize.py:190-201 patched-forward output cast."""
@@ -328,6 +331,7 @@ class AmpOptimizer:
         """The half model copy (pure cast; identity under O0/O1)."""
         return self.amp.cast_model(master_params)
 
+    @jax.named_scope("apex_amp_step")
     def step(
         self,
         scaled_grads: PyTree,
@@ -340,7 +344,10 @@ class AmpOptimizer:
 
         Returns (new_master_params, new_state, stats).  On overflow the
         params and optimizer state are returned unchanged and the scale is
-        backed off — all under jit, no host sync.
+        backed off — all under jit, no host sync.  The whole pipeline
+        (unscale, inf check, norms, the update under its own
+        ``named_update_scope``) is the ``apex_amp_step`` scope of a
+        device trace.
         """
         scaler = self.amp.scalers[loss_id]
         sstate = state.scaler[loss_id]

@@ -142,12 +142,13 @@ class BertEncoder(nn.Module):
                  deterministic: bool = True):
         cfg = self.cfg
         b, s = input_ids.shape
-        x = self.word_embeddings(input_ids) + self.position_embeddings(
-            jnp.arange(s)[None, :]
-        )
-        if token_type_ids is not None:
-            x = x + self.token_type_embeddings(token_type_ids)
-        x = self.embed_ln(x)
+        with jax.named_scope("embed"):
+            x = self.word_embeddings(input_ids) + self.position_embeddings(
+                jnp.arange(s)[None, :]
+            )
+            if token_type_ids is not None:
+                x = x + self.token_type_embeddings(token_type_ids)
+            x = self.embed_ln(x)
         mask_bias = None
         if attention_mask is not None:
             # additive key-padding mask (B, Sk): 0 keep, -1e9 drop
@@ -184,29 +185,35 @@ class BertForMLM(nn.Module):
         x = encoder(
             input_ids, attention_mask=attention_mask, deterministic=deterministic
         )
-        x = Dense(cfg.hidden_size, dtype=cfg.compute_dtype,
-                  name="mlm_transform")(x.astype(cfg.compute_dtype))
-        x = jax.nn.gelu(x)
-        x = FusedLayerNorm(cfg.hidden_size, name="mlm_ln")(x)
-        if cfg.tie_word_embeddings:
-            logits = encoder.attend(x) + self.param(
-                "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,), jnp.float32
-            )
-        else:
-            logits = Dense(cfg.vocab_size, dtype=cfg.compute_dtype,
-                           name="mlm_head")(x)
+        # ``lm_head`` / ``lm_loss`` (and the encoder's ``embed``): the
+        # phases a device trace sums time by, as in GPTLM
+        with jax.named_scope("lm_head"):
+            x = Dense(cfg.hidden_size, dtype=cfg.compute_dtype,
+                      name="mlm_transform")(x.astype(cfg.compute_dtype))
+            x = jax.nn.gelu(x)
+            x = FusedLayerNorm(cfg.hidden_size, name="mlm_ln")(x)
+            if cfg.tie_word_embeddings:
+                logits = encoder.attend(x) + self.param(
+                    "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,),
+                    jnp.float32
+                )
+            else:
+                logits = Dense(cfg.vocab_size, dtype=cfg.compute_dtype,
+                               name="mlm_head")(x)
         if labels is None:
             return logits
-        # fused softmax-xentropy; ignore label -100 (masked-out positions).
-        valid = labels >= 0
-        safe_labels = jnp.where(valid, labels, 0)
-        # Under half-precision policies the loss takes the logits in
-        # compute dtype and upcasts INSIDE (the reference xentropy
-        # kernel's half_to_float=True mode) — at V=30592 the logits are
-        # the model's largest activation, and halving their bytes is the
-        # loss path's main cost; the softmax/lse math is fp32 either way.
-        losses = softmax_cross_entropy(
-            logits.astype(cfg.compute_dtype), safe_labels
-        )
-        loss = jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1)
+        with jax.named_scope("lm_loss"):
+            # fused softmax-xentropy; ignore label -100 (masked-out
+            # positions).
+            valid = labels >= 0
+            safe_labels = jnp.where(valid, labels, 0)
+            # Under half-precision policies the loss takes the logits in
+            # compute dtype and upcasts INSIDE (the reference xentropy
+            # kernel's half_to_float=True mode) — at V=30592 the logits are
+            # the model's largest activation, and halving their bytes is the
+            # loss path's main cost; the softmax/lse math is fp32 either way.
+            losses = softmax_cross_entropy(
+                logits.astype(cfg.compute_dtype), safe_labels
+            )
+            loss = jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1)
         return logits, loss

@@ -335,26 +335,31 @@ class GPTLM(nn.Module):
     def __call__(self, input_ids, labels=None, deterministic: bool = True):
         cfg = self.cfg
         b, s = input_ids.shape
-        x = self.wte(input_ids) + self.wpe(jnp.arange(s)[None, :])
-        if not deterministic and cfg.dropout_rate > 0:
-            x = self.embed_drop(x, deterministic=False)
-        x = x.astype(cfg.compute_dtype)
+        # ``embed`` / ``lm_head`` / ``lm_loss``: the phases of the step
+        # that no flax module names (blocks are ``layer_i``); a device
+        # trace sums time by these scopes (docs/observability.md)
+        with jax.named_scope("embed"):
+            x = self.wte(input_ids) + self.wpe(jnp.arange(s)[None, :])
+            if not deterministic and cfg.dropout_rate > 0:
+                x = self.embed_drop(x, deterministic=False)
+            x = x.astype(cfg.compute_dtype)
         for layer in self.layers:
             x = layer(x, deterministic)
         x = self.ln_f(x.astype(jnp.float32))
         logits = self._logits(x)
         if labels is None:
             return logits
-        valid = labels >= 0
-        safe = jnp.where(valid, labels, 0)
-        # loss path takes compute-dtype logits (the reference xentropy
-        # kernel's half_to_float mode): at V=50k the logits are the
-        # biggest activation, and the fused loss upcasts internally
-        per_tok = softmax_cross_entropy(
-            logits.astype(cfg.compute_dtype), safe
-        )
-        n = jnp.maximum(jnp.sum(valid), 1)
-        loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
+        with jax.named_scope("lm_loss"):
+            valid = labels >= 0
+            safe = jnp.where(valid, labels, 0)
+            # loss path takes compute-dtype logits (the reference xentropy
+            # kernel's half_to_float mode): at V=50k the logits are the
+            # biggest activation, and the fused loss upcasts internally
+            per_tok = softmax_cross_entropy(
+                logits.astype(cfg.compute_dtype), safe
+            )
+            n = jnp.maximum(jnp.sum(valid), 1)
+            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
         return logits, loss
 
     def _logits(self, x):
@@ -373,15 +378,16 @@ class GPTLM(nn.Module):
         bitwise the training forward's.
         """
         cfg = self.cfg
-        if cfg.tie_word_embeddings:
-            dt = cfg.compute_dtype
-            logits = F.matmul(
-                x.astype(dt), self.wte.embedding.T.astype(dt),
-                preferred_element_type=jnp.float32,
-            )
-        else:
-            logits = self.head(x)
-        return logits.astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            if cfg.tie_word_embeddings:
+                dt = cfg.compute_dtype
+                logits = F.matmul(
+                    x.astype(dt), self.wte.embedding.T.astype(dt),
+                    preferred_element_type=jnp.float32,
+                )
+            else:
+                logits = self.head(x)
+            return logits.astype(jnp.float32)
 
     # -- serving paths (apex_tpu.serve) ---------------------------------
 

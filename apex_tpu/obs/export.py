@@ -72,6 +72,7 @@ def write_jsonl(tracer, path: str,
         header = {
             "type": "meta", "schema": SCHEMA,
             "clock": "perf_counter_ns", "compiles": tracer.compiles,
+            "dropped": tracer.dropped,
         }
         if extra_meta:
             header.update(extra_meta)
@@ -178,7 +179,8 @@ def write_chrome_trace(tracer, path: str,
                 **({"args": payload} if payload else {}),
             })
     doc = {"traceEvents": events, "displayTimeUnit": "ms",
-           "otherData": {"schema": SCHEMA, "compiles": tracer.compiles}}
+           "otherData": {"schema": SCHEMA, "compiles": tracer.compiles,
+                         "dropped": tracer.dropped}}
     if registry is not None:
         doc["otherData"]["metrics"] = registry.snapshot()
     tmp = path + ".tmp"

@@ -15,6 +15,17 @@ actually happen.  This tracer does, under hard constraints:
 - **Allocation-light.** One ``Span`` object (``__slots__``) and two
   clock reads per span; disabled tracing (``APEX_TPU_OBS=0``) costs a
   single truthiness check and returns a shared no-op span.
+- **On the profiler's clock too.** Every span is also a
+  ``jax.profiler.TraceAnnotation("apex/" + name, **scalar attrs)`` for
+  its lifetime: while a profiler session is open the span is an event of
+  the host plane of that trace, nested as the tracer nests it, its attrs
+  as event stats, next to the device's operations — so an idle gap of
+  the device can be put down to what the program was doing.  With no
+  session open a span pays one check for it.
+- **Bounded.** ``spans`` and ``events`` are rings of
+  :data:`DEFAULT_CAPACITY` entries each (like the flight recorder's): a
+  process that never ends keeps the newest and counts the rest in
+  ``dropped``.
 - **Compile-attributed.** The tracer keeps a PR 4
   :class:`~apex_tpu.analysis.recompile.CompileMonitor` entered for its
   lifetime with an ``on_compile`` callback: every XLA backend compile
@@ -38,9 +49,12 @@ one ambient destination; ``APEX_TPU_OBS=0`` (or
 """
 from __future__ import annotations
 
+import collections
 import os
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from apex_tpu.analysis.recompile import CompileMonitor
 from apex_tpu.obs.metrics import MetricsRegistry
@@ -57,6 +71,11 @@ __all__ = [
 ]
 
 _ENABLED_OVERRIDE: Optional[bool] = None
+
+#: ring slots for finished spans, and as many for instant/counter events
+DEFAULT_CAPACITY = 16384
+#: what every span's event in a profiler trace is named by
+PROFILER_PREFIX = "apex/"
 
 
 def enabled() -> bool:
@@ -137,19 +156,33 @@ _NULL_SPAN = _NullSpan()
 
 class _SpanCtx:
     """Context manager pairing one span's enter/exit with the tracer's
-    open-span stack (kept separate from :class:`Span` so finished spans
-    carry no manager state)."""
+    open-span stack and with the span's annotation in the profiler's
+    trace (kept separate from :class:`Span` so finished spans carry no
+    manager state)."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
+        self._annotation = None
+        if TraceAnnotation.is_enabled():    # a profiler session is open
+            # the attrs known when the span opens, scalars only, become
+            # the event's stats (``sp.set`` later reaches only the tracer)
+            attrs = span.attrs or {}
+            self._annotation = TraceAnnotation(
+                PROFILER_PREFIX + span.name,
+                **{k: v for k, v in attrs.items()
+                   if isinstance(v, (bool, int, float, str))})
 
     def __enter__(self) -> Span:
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self._span
 
     def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         self._tracer._finish(self._span)
         return False
 
@@ -165,9 +198,11 @@ class Tracer:
         tracer's lifetime so spans carry compile attribution (default
         on; pointless for fake-clock unit tracers).
 
-    Finished spans accumulate in ``.spans`` (order = finish order,
-    Chrome-trace convention); instant/counter events in ``.events`` as
-    ``(ts, kind, name, payload)`` tuples.  ``close()`` detaches the
+    Finished spans land in the ring ``.spans`` (order = finish order,
+    Chrome-trace convention); instant/counter events in the ring
+    ``.events`` as ``(ts, kind, name, payload)`` tuples.  Each ring keeps
+    its newest :data:`DEFAULT_CAPACITY` entries; ``recorded`` counts all
+    ever made and ``dropped`` those that fell off.  ``close()`` detaches the
     compile listener; tracers are single-threaded like the schedulers
     they instrument.
     """
@@ -176,8 +211,10 @@ class Tracer:
                  monitor_compiles: bool = True):
         self.enabled = _enabled_default() if enabled is None else enabled
         self.clock = clock or time.perf_counter_ns
-        self.spans: List[Span] = []
-        self.events: List[Tuple[int, str, str, Any]] = []
+        self.spans: Deque[Span] = collections.deque(maxlen=DEFAULT_CAPACITY)
+        self.events: Deque[Tuple[int, str, str, Any]] = collections.deque(
+            maxlen=DEFAULT_CAPACITY)
+        self.recorded = 0
         self.compiles = 0
         self._stack: List[Span] = []
         self._monitor: Optional[CompileMonitor] = None
@@ -204,6 +241,7 @@ class Tracer:
         elif sp in self._stack:
             self._stack.remove(sp)
         self.spans.append(sp)
+        self.recorded += 1
 
     def instant(self, name: str, **attrs) -> None:
         """Zero-duration event (retirement, preemption, anomaly)."""
@@ -211,12 +249,14 @@ class Tracer:
             self.events.append(
                 (self.clock(), "instant", name, attrs or None)
             )
+            self.recorded += 1
 
     def counter(self, name: str, value) -> None:
         """Timestamped counter sample — the timeline primitive
         (page-pool utilization, active slots, queue depth)."""
         if self.enabled:
             self.events.append((self.clock(), "counter", name, value))
+            self.recorded += 1
 
     def _on_compile(self, dur_s: float) -> None:
         self.compiles += 1
@@ -235,12 +275,18 @@ class Tracer:
         """Drop recorded spans/events (open spans stay open)."""
         self.spans.clear()
         self.events.clear()
+        self.recorded = 0
         self.compiles = 0
 
     # -- queries -------------------------------------------------------
 
+    @property
+    def dropped(self) -> int:
+        """Spans and events that fell off the rings."""
+        return self.recorded - len(self.spans) - len(self.events)
+
     def span_names(self) -> Dict[str, int]:
-        """``{name: count}`` over finished spans (sorted)."""
+        """``{name: count}`` over the finished spans kept (sorted)."""
         out: Dict[str, int] = {}
         for sp in self.spans:
             out[sp.name] = out.get(sp.name, 0) + 1

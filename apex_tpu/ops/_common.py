@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import contextlib
+import re
 from typing import Optional
 
 import jax
@@ -41,13 +42,66 @@ def force_pallas(value: Optional[bool]):
         _FORCE_PALLAS = prev
 
 
-def pallas_call(*args, **kw):
-    """pl.pallas_call, in interpreter mode off-TPU so the kernel-vs-reference
-    parity tests run on CPU (the reference's Python-fallback testing trick,
-    SURVEY §4).  An interpreted kernel lowers to plain XLA ops, so a
-    program that landed on the CPU by accident still completes — slowly;
-    :func:`mosaic_call_count` is how a caller refuses that."""
-    return pl.pallas_call(*args, interpret=jax.default_backend() != "tpu", **kw)
+#: Every Pallas kernel of the library, by the ``name`` its ``pallas_call``
+#: carries: ``apex_<op>_<pass>[_<variant>]``.  The compiled custom call's
+#: HLO instruction — and so its event in a profiler trace — bears this
+#: name whatever scope called the kernel.  Readers of a trace match the
+#: family prefixes (``apex_flash_fwd``, ``apex_flash_bwd``, ``apex_ln_``,
+#: ``apex_xent_``), so a variant can be added without touching them.
+KERNEL_NAMES = (
+    "apex_paged_attn",
+    "apex_flash_fwd",
+    "apex_flash_bwd_fused_acc",
+    "apex_flash_bwd_fused",
+    "apex_flash_bwd_dkdv",
+    "apex_flash_bwd_dq_dbias",
+    "apex_flash_bwd_dq",
+    "apex_ln_fwd",
+    "apex_ln_bwd_dx",
+    "apex_ln_bwd_dx_dwdb",
+    "apex_xent_fwd",
+    "apex_xent_bwd",
+    "apex_lamb_stage1",
+    "apex_conv_bn_matmul_stats",
+    "apex_conv_bn_relu_matmul",
+    "apex_conv_bn_matmul_bwd",
+)
+
+
+def pallas_call(*args, name: str, **kw):
+    """pl.pallas_call under a stable ``name`` (one of :data:`KERNEL_NAMES`;
+    a kernel without one cannot be found in a device trace), in interpreter
+    mode off-TPU so the kernel-vs-reference parity tests run on CPU (the
+    reference's Python-fallback testing trick, SURVEY §4).  An interpreted
+    kernel lowers to plain XLA ops, so a program that landed on the CPU by
+    accident still completes — slowly; :func:`mosaic_call_count` is how a
+    caller refuses that."""
+    if name not in KERNEL_NAMES:
+        raise ValueError(
+            f"pallas_call name {name!r} is not listed in KERNEL_NAMES")
+    return pl.pallas_call(*args, name=name,
+                          interpret=jax.default_backend() != "tpu", **kw)
+
+
+_MOSAIC_INSTR_RE = re.compile(
+    r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"')
+
+
+def mosaic_call_names(text: str):
+    """The HLO instruction names of the Mosaic custom calls in a compiled
+    program's text (``compiled.as_text()``), in order."""
+    return _MOSAIC_INSTR_RE.findall(text)
+
+
+def unnamed_mosaic_calls(text: str):
+    """Those of :func:`mosaic_call_names` that bear no name from
+    :data:`KERNEL_NAMES`: a kernel that would be anonymous in a device
+    trace.  Called from inside any scope — a model always is — a kernel's
+    instruction is its own name plus XLA's ``.N``; differentiated with no
+    scope around it JAX wraps the name itself (``jvp_apex_xent_fwd_``),
+    so the test is containment."""
+    return [n for n in mosaic_call_names(text)
+            if not any(k in n for k in KERNEL_NAMES)]
 
 
 def mosaic_call_count(compiled) -> int:

@@ -593,6 +593,7 @@ def paged_fused_attention(
     )
     fn = _pallas_call(
         kernel,
+        name="apex_paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_pages),
@@ -1009,6 +1010,7 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
     )
     out, lse = _pallas_call(
         kernel,
+        name="apex_flash_fwd",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -1119,6 +1121,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                     h_map=h_map, probs_bf16=probs_bf16,
                     interp_copy_through=_FUSED_DQ_COPY_THROUGH,
                 ),
+                name="apex_flash_bwd_fused_acc",
                 grid=(bh, nk, nq),
                 in_specs=in_specs + [
                     pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
@@ -1143,6 +1146,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                 block_k=block_k, nq=nq, dropout_rate=dropout_rate,
                 h_map=h_map, probs_bf16=probs_bf16,
             ),
+            name="apex_flash_bwd_fused",
             grid=(bh, nk, nq),
             in_specs=in_specs,
             out_specs=dkv_out_specs + [
@@ -1180,6 +1184,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
             dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
         ),
+        name="apex_flash_bwd_dkdv",
         grid=(bh, nk, nq),
         in_specs=in_specs,
         out_specs=[
@@ -1215,6 +1220,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                 nk=nk, dropout_rate=dropout_rate, h_map=h_map,
                 probs_bf16=probs_bf16,
             ),
+            name="apex_flash_bwd_dq_dbias",
             grid=(bh, nq, nk),
             in_specs=in_specs,
             out_specs=[
@@ -1234,6 +1240,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k, nk=nk,
             dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
         ),
+        name="apex_flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),

@@ -185,6 +185,7 @@ def _matmul_stats_fwd(x, w, bm, bn, bk):
     nn, nm, nk = grid
     y, s, ss = _pallas_call(
         functools.partial(_matmul_stats_kernel, nm=nm, nk=nk),
+        name="apex_conv_bn_matmul_stats",
         grid=grid,
         in_specs=[x_spec, w_spec],
         out_specs=[y_spec, stat_spec, stat_spec],
@@ -214,6 +215,7 @@ def _bn_relu_matmul_fwd(x, mean, rstd, gamma, beta, w, bm, bn, bk, relu):
         functools.partial(
             _bn_relu_matmul_kernel, nm=nm, nk=nk, relu=relu,
         ),
+        name="apex_conv_bn_relu_matmul",
         grid=grid,
         in_specs=[x_spec, kparam_spec, kparam_spec, kparam_spec,
                   kparam_spec, w_spec],
@@ -465,6 +467,7 @@ def matmul_bwd_dual(
     nm = m // block_m
     dx, dw = _pallas_call(
         functools.partial(_matmul_bwd_dual_kernel, nm=nm),
+        name="apex_conv_bn_matmul_bwd",
         grid=(nm,),
         in_specs=[
             pl.BlockSpec((block_m, n), lambda i: (i, 0)),

@@ -158,6 +158,7 @@ def lamb_stage1(
             _lamb_stage1_kernel, rows=rows, block_rows=br,
             b1=b1, b2=b2, eps=eps, wd=wd, adam_w=adam_w,
         ),
+        name="apex_lamb_stage1",
         grid=(ngrid,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

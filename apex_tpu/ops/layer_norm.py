@@ -172,6 +172,7 @@ def _ln_fwd_pallas(x2, weight, bias, eps, block_rows):
     b = (bias if bias is not None else jnp.zeros((n,), w.dtype)).reshape(1, n)
     out = _pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps, affine=affine),
+        name="apex_ln_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
@@ -193,6 +194,7 @@ def _ln_bwd_dx_pallas(x2, weight, dy2, eps, block_rows):
     w = (weight if affine else jnp.zeros((n,), x2.dtype)).reshape(1, n)
     dx = _pallas_call(
         functools.partial(_ln_bwd_dx_kernel, eps=eps, affine=affine),
+        name="apex_ln_bwd_dx",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
@@ -216,6 +218,7 @@ def _ln_bwd_dx_dwdb_pallas(x2, weight, dy2, eps, block_rows):
     dx, acc = _pallas_call(
         functools.partial(_ln_bwd_dx_dwdb_kernel, eps=eps, affine=affine,
                           rows=m, block_rows=block_rows),
+        name="apex_ln_bwd_dx_dwdb",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),

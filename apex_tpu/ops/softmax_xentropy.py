@@ -220,6 +220,7 @@ def _xent_fwd_impl(logits2, labels1, smoothing, block_rows, block_v,
             _xent_fwd_kernel, smoothing=smoothing, v_real=v,
             block_v=block_v, nv=nv, ragged=ragged,
         ),
+        name="apex_xent_fwd",
         grid=(nblocks, nv),
         in_specs=[
             pl.BlockSpec((block_rows, block_v), lambda i, j: (i, j)),
@@ -279,6 +280,7 @@ def _xent_bwd_rule(smoothing, block_rows, block_v, use_pallas, res, g):
             _xent_bwd_kernel, smoothing=smoothing, v_real=v,
             block_v=block_v, ragged=ragged,
         ),
+        name="apex_xent_bwd",
         grid=(nblocks, nv),
         in_specs=[
             pl.BlockSpec((block_rows, block_v), lambda i, j: (i, j)),

@@ -16,8 +16,15 @@ the full attribution chain:
    scoping) stamp every HLO instruction's ``metadata.op_name`` with the
    scope path — the moral NVTX range.  :func:`annotate` /
    :func:`annotate_function` re-export that in the reference's vocabulary,
-   and the library's hot paths (DDP allreduce, SyncBatchNorm, optimizer
-   steps) are pre-annotated.
+   and the library's hot paths are pre-annotated: the phases of a train
+   step (flax's ``layer_i`` blocks plus ``embed``, ``lm_head``,
+   ``lm_loss`` in the models, ``apex_amp_cast`` / ``apex_amp_step`` in
+   ``amp``, each optimizer's ``named_update_scope`` inside that,
+   ``apex_train_meters`` in the driver's scan body,
+   ``apex_ddp_allreduce``, ``apex_sync_bn_stats``), and every Pallas
+   kernel under its entry of ``apex_tpu.ops._common.KERNEL_NAMES``
+   (``apex_flash_fwd``, ``apex_ln_bwd_dx``, ... — the custom call's own
+   instruction name, whatever scope calls the kernel).
 2. **Parse**: the compiled executable's optimized HLO text *is* the joined
    database — each instruction line has opcode, shapes, and the marker in
    ``metadata={op_name=...}``.  :func:`apex_tpu.pyprof.prof.parse_hlo`

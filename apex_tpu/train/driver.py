@@ -107,8 +107,11 @@ def read_metrics(tree: PyTree, registry=None,
     With a ``registry`` (an :class:`apex_tpu.obs.MetricsRegistry`),
     every scalar additionally lands in a ``<prefix><name>`` histogram —
     the host-side meter plumbing that used to be per-caller print/append
-    code now accumulates where the trace artifact snapshots it."""
-    host = jax.device_get(tree)
+    code now accumulates where the trace artifact snapshots it.  The
+    fetch is the ``train/fetch_metrics`` span: the host waiting for the
+    window it dispatched."""
+    with obs.default_tracer().span("train/fetch_metrics"):
+        host = jax.device_get(tree)
     out = jax.tree_util.tree_map(
         lambda x: float(x) if getattr(x, "ndim", 1) == 0 else x, host
     )
@@ -278,10 +281,11 @@ class FusedTrainDriver:
             def body(sc, xs):
                 c, acc = sc
                 c, m = step_fn(c, xs)
-                acc = {
-                    n: _acc_update(acc[n], m[n], r)
-                    for n, r in reductions.items()
-                }
+                with jax.named_scope("apex_train_meters"):
+                    acc = {
+                        n: _acc_update(acc[n], m[n], r)
+                        for n, r in reductions.items()
+                    }
                 return (c, acc), {n: m[n] for n in per_step}
 
             (carry, acc), traces = jax.lax.scan(

@@ -26,7 +26,8 @@ Nothing here hides the device.  The script sets no platform: it asks
 ``jax.devices()`` and exits nonzero at once unless that is a TPU.  Every
 compiled program whose kernels' shape gates promise Mosaic calls is held
 to them (``apex_tpu.ops.mosaic_call_count``), so a kernel that ran
-interpreted or as its reference fails the run.  Any failed check or
+interpreted or as its reference fails the run, and so does a Mosaic call
+that bears no name from ``KERNEL_NAMES``.  Any failed check or
 exception in any phase ends the process nonzero; nothing is caught and
 carried on from.  No utilisation is computed: the chip's peak rates are
 not this script's to assume.
@@ -51,6 +52,7 @@ import numpy as np
 
 from apex_tpu.chip import compile_cache_dir, require_tpu
 from apex_tpu.ops import mosaic_call_count
+from apex_tpu.ops._common import unnamed_mosaic_calls
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -113,11 +115,17 @@ def _require(ok: bool, what: str) -> None:
 def _require_mosaic(compiled, at_least: int, record: Dict, key: str) -> None:
     """Record the program's Mosaic call count under ``record[key]`` and
     hold it to what its kernels' shape gates promise — fewer means a
-    kernel ran interpreted or was replaced by its reference."""
+    kernel ran interpreted or was replaced by its reference — and every
+    such call to a name of ``KERNEL_NAMES``."""
     record[key] = n = mosaic_call_count(compiled)
     _require(n >= at_least,
              f"{key}: {n} tpu_custom_call(s) compiled in, expected >= "
              f"{at_least}")
+    nameless = unnamed_mosaic_calls(compiled.as_text())
+    _require(not nameless,
+             f"{key}: tpu_custom_call(s) {nameless} bear no name from "
+             "apex_tpu.ops._common.KERNEL_NAMES — a device trace could not "
+             "tell them from the scope that called them")
 
 
 # ---------------------------------------------------------------------------

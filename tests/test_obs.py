@@ -155,7 +155,7 @@ class TestTracer:
             sp.set("x", 1)
         tr.instant("i")
         tr.counter("c", 1)
-        assert tr.spans == [] and tr.events == []
+        assert not tr.spans and not tr.events and tr.recorded == 0
 
     def test_env_kill_switch(self, monkeypatch, clean_default):
         monkeypatch.setenv("APEX_TPU_OBS", "0")
@@ -449,6 +449,177 @@ class TestDriverObs:
         names = obs.default_tracer().span_names()
         assert names.get("train/checkpoint_save") == 1
         assert names.get("train/checkpoint_restore") == 1
+
+
+# ---------------------------------------------------------------------------
+# the ring: an ambient tracer that never ends stays bounded
+# ---------------------------------------------------------------------------
+
+class TestRing:
+    def test_spans_and_events_keep_the_newest_and_count_the_rest(
+            self, tmp_path):
+        from apex_tpu.obs.trace import DEFAULT_CAPACITY
+
+        clk = FakeClock()
+        tr = obs.Tracer(enabled=True, clock=clk, monitor_compiles=False)
+        extra = 5
+        for i in range(DEFAULT_CAPACITY + extra):
+            with tr.span("serve/decode_window", i=i):
+                clk.advance_ms(1)
+        for i in range(DEFAULT_CAPACITY + 2):
+            tr.counter("serve/pages_in_use", i)
+        assert len(tr.spans) == len(tr.events) == DEFAULT_CAPACITY
+        assert tr.spans[0].attrs == {"i": extra}       # the oldest kept
+        assert tr.spans[-1].attrs == {"i": DEFAULT_CAPACITY + extra - 1}
+        assert tr.recorded == 2 * DEFAULT_CAPACITY + extra + 2
+        assert tr.dropped == extra + 2
+        assert tr.span_names() == {"serve/decode_window": DEFAULT_CAPACITY}
+        events, _ = obs.read_jsonl(tr.export_jsonl(str(tmp_path / "t.jsonl")))
+        assert events[0]["dropped"] == extra + 2
+        assert sum(e["type"] == "span" for e in events) == DEFAULT_CAPACITY
+        with open(tr.export_chrome(str(tmp_path / "t.json"))) as f:
+            assert json.load(f)["otherData"]["dropped"] == extra + 2
+        tr.clear()
+        assert tr.recorded == tr.dropped == 0 and not tr.spans
+
+    def test_nothing_dropped_reads_zero(self):
+        tr = obs.Tracer(enabled=True, clock=FakeClock(),
+                        monitor_compiles=False)
+        with tr.span("a"):
+            tr.instant("i")
+        assert (tr.recorded, tr.dropped) == (2, 0)
+
+
+# ---------------------------------------------------------------------------
+# the profiler bridge: spans on the profiler's clock, scopes in the step
+# ---------------------------------------------------------------------------
+
+def _apex_events(trace_dir):
+    """``[(name, start_ns, end_ns, stats)]`` of the ``apex/`` events in
+    the profile written under ``trace_dir``, in start order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert len(paths) == 1, paths
+    out = []
+    for plane in ProfileData.from_file(paths[0]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("apex/"):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+class TestProfilerBridge:
+    def test_span_is_an_event_of_the_profile_nested_with_its_attrs(
+            self, tmp_path):
+        tr = obs.Tracer(enabled=True, monitor_compiles=False)
+        off = obs.Tracer(enabled=False)
+        with jax.profiler.trace(str(tmp_path)):
+            with tr.span("train/dispatch", k=3, microbatches=1,
+                         shape=(2, 2)) as sp:
+                with tr.span("train/prefetch", depth=2):
+                    jnp.ones(4).block_until_ready()
+                sp.set("late", 1)
+            with off.span("train/fetch_metrics"):
+                pass
+        (outer, o0, o1, ostats), (inner, i0, i1, istats) = \
+            _apex_events(tmp_path)
+        assert (outer, inner) == ("apex/train/dispatch",
+                                  "apex/train/prefetch")
+        assert o0 <= i0 and i1 <= o1            # enclosed, one clock
+        # scalar attrs known at open are the event's stats; the rest
+        # (a tuple, a late ``set``) stay with the tracer's own span
+        assert ostats == {"k": 3, "microbatches": 1}
+        assert istats == {"depth": 2}
+        assert tr.spans[-1].attrs["late"] == 1 and not off.spans
+
+    def test_no_profiler_open_no_annotation(self):
+        from apex_tpu.obs.trace import _SpanCtx
+
+        tr = obs.Tracer(enabled=True, monitor_compiles=False)
+        ctx = tr.span("train/dispatch", k=1)
+        assert isinstance(ctx, _SpanCtx) and ctx._annotation is None
+        with ctx:
+            pass
+        assert tr.span_names() == {"train/dispatch": 1}
+
+    def test_train_window_and_fetch_are_in_the_profile(self, tmp_path,
+                                                       clean_default):
+        from apex_tpu.train import FusedTrainDriver, read_metrics
+
+        obs.set_enabled_override(True)
+        driver = FusedTrainDriver(
+            lambda c, _: (c + 1.0, {"loss": jnp.sum(c)}),
+            steps_per_dispatch=3, metrics={"loss": "last"})
+        carry, res = driver.run_window(jnp.zeros(()))      # warm
+        with jax.profiler.trace(str(tmp_path)):
+            carry, res = driver.run_window(carry)
+            read_metrics(res.metrics)
+        got = {name: stats for name, _, _, stats in _apex_events(tmp_path)}
+        assert got == {"apex/train/dispatch": {"k": 3, "microbatches": 1},
+                       "apex/train/fetch_metrics": {}}
+
+    def test_engine_step_is_in_the_profile(self, dec4, lm, tmp_path):
+        _, _, pool = lm
+        tracer = obs.Tracer(enabled=True, monitor_compiles=False)
+        eng = ServeEngine(dec4, slots=2, max_len=64, paged=True,
+                          page_len=8, prefill_chunk=8, tracer=tracer)
+        eng.submit([int(t) for t in pool[:6]], max_new_tokens=6)
+        eng.step()                      # admits and prefills, warm
+        with jax.profiler.trace(str(tmp_path)):
+            eng.step()
+        names = {name for name, *_ in _apex_events(tmp_path)}
+        assert "apex/serve/decode_window" in names, names
+
+
+@pytest.mark.parametrize("family", ["gpt", "bert"])
+def test_train_step_carries_the_phase_scopes(family):
+    """The lowered window program of a tiny model through ``AmpOptimizer``
+    holds the phases' scopes in its ``op_name``s — what a device trace
+    sums time by (metadata only: no op is added)."""
+    import re
+
+    import apex_tpu.amp as amp
+    from apex_tpu.models.bert import BertConfig, BertForMLM
+    from apex_tpu.optimizers import fused_adam
+    from apex_tpu.train import FusedTrainDriver
+
+    model = (GPTLM(GPTConfig.tiny()) if family == "gpt"
+             else BertForMLM(BertConfig.tiny()))
+    amp_ = amp.initialize("O2")
+    opt = amp.AmpOptimizer(fused_adam(1e-3), amp_)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+
+    def step(carry, batch):
+        params, state = carry
+
+        def scaled(mp):
+            _, loss = model.apply({"params": opt.model_params(mp)}, batch,
+                                  labels=batch)
+            return amp_.scale_loss(loss, state.scaler[0]), loss
+
+        grads, loss = jax.grad(scaled, has_aux=True)(params)
+        params, state, _ = opt.step(grads, state, params)
+        return (params, state), {"loss": loss}
+
+    text = FusedTrainDriver(step, steps_per_dispatch=2).lower(
+        (params, opt.init(params)), jnp.zeros((2, 2, 16), jnp.int32)
+    ).as_text(debug_info=True)
+    op_names = set(re.findall(r'loc\("([^"]+)"', text))
+    for scope in ("embed", "layer_0", "lm_head", "lm_loss", "apex_amp_step",
+                  "apex_amp_cast", "apex_train_meters"):
+        pat = re.compile(r"(^|[/(])%s[/)]" % scope)
+        assert any(pat.search(n) for n in op_names), scope
+    # forward and backward of the head are both under its scope
+    assert any(n.startswith("transpose(jvp(") and "/lm_head/" in n
+               for n in op_names)
 
 
 # ---------------------------------------------------------------------------

@@ -72,6 +72,115 @@ def _mosaic_calls(chip, fn, *avals) -> int:
     return mosaic_call_count(jax.jit(fn).lower(*args).compile())
 
 
+def _mosaic_names(chip, fn, *avals):
+    """Compile ``fn`` for the described chip; the HLO instruction names of
+    its Mosaic calls, per-instance ``.N`` suffix taken off."""
+    import re
+
+    from apex_tpu.ops._common import mosaic_call_names
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in avals]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+
+
+# -- every kernel under its own name in the compiled program ----------------
+
+def _flash_grad(shape, causal, **kw):
+    from apex_tpu.ops import flash_attention
+
+    def loss(q, k, v, *bias):
+        with jax.named_scope("layer_0"):    # a caller's scope, as a model's
+            out = flash_attention(q, k, v, *bias, causal=causal, **kw)
+        return jnp.sum(out.astype(F32))
+
+    return loss, [(shape, BF16)] * 3
+
+
+def _named_case(kernel):
+    """``(fn, avals)`` whose compiled program holds ``kernel``."""
+    if kernel in ("apex_flash_fwd", "apex_flash_bwd_fused"):
+        # GPT-2 small's own shape: one key block, the combined backward
+        loss, avals = _flash_grad((8, 12, 1024, 64), True)
+        return jax.grad(loss, argnums=(0, 1, 2)), avals
+    if kernel in ("apex_flash_bwd_dkdv", "apex_flash_bwd_dq"):
+        # more key blocks than the combined backward takes: two passes
+        loss, avals = _flash_grad((1, 4, 8192, 64), True)
+        return jax.grad(loss, argnums=(0, 1, 2)), avals
+    if kernel == "apex_flash_bwd_dq_dbias":
+        # a learned bias wants its gradient: the dq pass writes it
+        loss, avals = _flash_grad((2, 4, 512, 64), False, bias_grad=True)
+        return (jax.grad(loss, argnums=(0, 1, 2, 3)),
+                avals + [((2, 512, 512), F32)])
+    if kernel.startswith("apex_ln_"):
+        fn = getattr(_ln, "_ln_" + kernel[len("apex_ln_"):] + "_pallas")
+        rows, vec = ((_LN_ROWS, 768), F32), ((768,), F32)
+        if kernel == "apex_ln_fwd":
+            return (lambda x, w, b: fn(x, w, b, 1e-5, _ln.DEFAULT_BLOCK_ROWS),
+                    [rows, vec, vec])
+        return (lambda x, w, dy: fn(x, w, dy, 1e-5, _ln.DEFAULT_BLOCK_ROWS),
+                [rows, vec, rows])
+    if kernel == "apex_paged_attn":
+        return _paged_fused_case(12, 8, False)
+    if kernel.startswith("apex_xent_"):
+        from apex_tpu.ops import softmax_cross_entropy
+
+        def loss(logits, labels):
+            with jax.named_scope("lm_loss"):
+                return jnp.sum(softmax_cross_entropy(logits, labels))
+
+        return jax.grad(loss), [((8192, 50304), BF16), ((8192,), I32)]
+    raise KeyError(kernel)
+
+
+@pytest.mark.parametrize("kernel", [
+    "apex_flash_fwd", "apex_flash_bwd_fused", "apex_flash_bwd_dkdv",
+    "apex_flash_bwd_dq", "apex_flash_bwd_dq_dbias", "apex_ln_fwd",
+    "apex_ln_bwd_dx", "apex_ln_bwd_dx_dwdb", "apex_xent_fwd",
+    "apex_xent_bwd", "apex_paged_attn",
+])
+def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
+    """The custom call's HLO instruction — what a device trace names the
+    kernel's events by — bears the kernel's entry of ``KERNEL_NAMES``,
+    not the scope that called it, and no Mosaic call is anonymous."""
+    from apex_tpu.ops._common import KERNEL_NAMES
+
+    assert kernel in KERNEL_NAMES
+    fn, avals = _named_case(kernel)
+    names = _mosaic_names(chip, fn, *avals)
+    assert kernel in names, names
+    assert set(names) <= set(KERNEL_NAMES), names
+
+
+def test_a_kernel_differentiated_outside_any_scope_still_bears_its_name(
+        chip, as_tpu):
+    """With no scope around it JAX wraps the kernel's own name in the
+    transformation's (``jvp_apex_xent_fwd_``): still found, by containment."""
+    from apex_tpu.ops import softmax_cross_entropy
+    from apex_tpu.ops._common import mosaic_call_names, unnamed_mosaic_calls
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip)
+            for s, d in (((1024, 50304), BF16), ((1024,), I32))]
+    text = jax.jit(jax.grad(
+        lambda l, y: jnp.sum(softmax_cross_entropy(l, y))
+    )).lower(*args).compile().as_text()
+    names = mosaic_call_names(text)
+    assert len(names) == 2 and unnamed_mosaic_calls(text) == []
+    assert "apex_xent_fwd" in names[0] and "apex_xent_bwd" in names[1]
+    assert unnamed_mosaic_calls(text.replace("apex_xent_fwd", "lm_loss")) \
+        == [names[0].replace("apex_xent_fwd", "lm_loss")]
+
+
+def test_pallas_call_without_a_name_raises():
+    from apex_tpu.ops._common import pallas_call
+
+    spec = dict(out_shape=jax.ShapeDtypeStruct((8, 128), F32))
+    with pytest.raises(TypeError, match="name"):
+        pallas_call(lambda x_ref, o_ref: None, **spec)
+    with pytest.raises(ValueError, match="KERNEL_NAMES"):
+        pallas_call(lambda x_ref, o_ref: None, name="nameless", **spec)
+
+
 # -- flash attention: forward + fused backward ------------------------------
 
 @pytest.mark.parametrize(
@@ -136,9 +245,9 @@ def test_xentropy_fwd_bwd_compiles(chip, as_tpu):
 
 # -- fused paged-attention serving kernel -----------------------------------
 
-def _paged_fused_calls(chip, heads: int, t: int, int8: bool, *,
-                       slots: int = 8, ctx: int = 1024, page_len: int = 16,
-                       d: int = 64, layers: int = 2) -> int:
+def _paged_fused_case(heads: int, t: int, int8: bool, *,
+                      slots: int = 8, ctx: int = 1024, page_len: int = 16,
+                      d: int = 64, layers: int = 2):
     from apex_tpu.ops.attention import paged_fused_attention
 
     n_pages = ctx // page_len
@@ -159,6 +268,11 @@ def _paged_fused_calls(chip, heads: int, t: int, int8: bool, *,
             pool_k_scale=ks, pool_v_scale=vs, layer=1,
         )
 
+    return call, avals
+
+
+def _paged_fused_calls(chip, heads: int, t: int, int8: bool) -> int:
+    call, avals = _paged_fused_case(heads, t, int8)
     return _mosaic_calls(chip, call, *avals)
 
 

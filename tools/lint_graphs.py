@@ -1549,7 +1549,7 @@ def check_obs_instrumentation(canonical: CanonicalPrograms) -> List[str]:
         return []
     dec = canonical.get("paged_k8").meta["decoder"]
     tracer = obs.default_tracer()
-    n0 = len(tracer.spans)
+    n0 = tracer.recorded
     with CompileMonitor() as mon:
         _drive_paged_workload(dec)
     errs = []
@@ -1559,7 +1559,7 @@ def check_obs_instrumentation(canonical: CanonicalPrograms) -> List[str]:
             "new program(s) — telemetry must never touch the compiled "
             "programs (host-side spans only)"
         )
-    if len(tracer.spans) <= n0:
+    if tracer.recorded <= n0:
         errs.append(
             "obs instrumentation recorded no spans over the paged "
             "workload — the engine's tracer hookup is dead"
