@@ -19,8 +19,12 @@ drops by the same factor.  This is the canonical Pallas attention:
   directly when nk == 1, else summed fp32 partials); past
   ``_FUSED_BWD_MAX_NK`` k-blocks, and for the learned-bias path, the
   classic two-pass (dkv then dq) backward runs instead;
-- supports causal masking (block-skipped: fully-masked k-blocks are never
-  visited) and an optional additive bias/mask (B, Sq, Sk) — the reference's
+- supports causal masking — the masked half is not computed: grid tiles
+  wholly above the diagonal are never visited, and where a head is ONE
+  grid tile (GPT-2's S = 1024, where the grid has nothing to skip) each
+  query sub-tile is taken against just the keys its rows reach, the mask
+  applied only to the sub-tiles the diagonal crosses (``_for_pieces``) —
+  and an optional additive bias/mask (B, Sq, Sk) — the reference's
   additive-mask / key-padding-mask path — indexed per head group in-kernel
   (never broadcast-materialized to (B*H, Sq, Sk));
 - in-kernel attention-probability dropout (ref fused masked-softmax-dropout,
@@ -75,7 +79,9 @@ def _env_flag(name: str, default: bool) -> bool:
 
 # combined dk+dv+dq backward (one s/p recompute) vs the two-pass flash-v2
 # backward — switch for A/B measurement (tools/, PERF.md r4); the env
-# override makes the A/B a subprocess flag flip, no module mutation
+# override makes the A/B a subprocess flag flip, no module mutation.
+# (This and the three switches below are read when a call is TRACED: they
+# are part of the key of the shared trace, see _trace_key.)
 _USE_FUSED_BWD = _env_flag("APEX_TPU_FUSED_BWD", True)
 # the fused pass accumulates dq across k blocks; past this many k blocks
 # the accumulation traffic outweighs the saved recompute (long-context
@@ -617,12 +623,128 @@ def paged_fused_attention(
 # forward kernel
 # ---------------------------------------------------------------------------
 
-def _causal_tile_visited(qi, ki, block_q, block_k):
-    """True iff the (qi, ki) tile intersects the causal lower triangle —
-    the ONE definition of the backward kernels' ``run`` predicate and the
-    host-side dq-partials validity mask (they must never drift: a tile
-    the kernel skips is garbage the mask must zero)."""
-    return qi * block_q + block_q - 1 >= ki * block_k
+# Causal sub-tiles: where a causal head is ONE grid tile (one query block,
+# one key block: GPT-2's S = 1024) the kernel body takes the tile in query
+# sub-tiles of sub_q rows, each against the key sub-tiles (sub_k wide) its
+# rows reach: nothing is done for the ones wholly above the diagonal, and
+# only the ones the diagonal crosses are masked.  The grid cannot skip
+# there: with one key block every grid tile reaches the triangle.  The
+# grid indices are 0, so the pieces are static and the walk is
+# straight-line code.  A grid of several tiles a head keeps the one-piece
+# masked body and the grid-level skip (no benchmark cell has one yet).
+# Width from chip runs (PERF.md section 6, PR 25).
+_CAUSAL_SUB = 128
+
+
+def _causal_key_bounds(row0, rows, col0, width, n, xp=jnp):
+    """``(n_full, n_vis)`` for query rows ``[row0, row0 + rows)`` against
+    ``n`` key sub-tiles of ``width`` columns starting at ``col0``: of the
+    sub-tiles, ``[0, n_full)`` lie wholly at or below the causal diagonal
+    (no mask needed), ``[n_full, n_vis)`` are crossed by it (masked), and
+    ``[n_vis, n)`` lie wholly above it (no work).
+
+    Coordinates are the call's LOCAL ones, top-left aligned (row i sees
+    columns <= i) — see the _fwd_kernel comment.  The ONE definition of
+    what a causal kernel visits: the grid-level ``run`` predicate
+    (:func:`_causal_tile_visited`), the pieces the kernel bodies take
+    (:func:`_for_pieces`), the host-side dq-partials validity mask
+    and the census (:func:`flash_tile_census`) all come from here, so
+    they cannot drift.
+    ``xp`` is ``jnp`` inside a kernel, ``numpy`` on the host.
+    """
+    n_vis = xp.minimum(xp.maximum(row0 + rows - 1 - col0 + width, 0) // width, n)
+    n_full = xp.minimum(xp.maximum(row0 - col0 + 1, 0) // width, n)
+    return n_full, n_vis
+
+
+def _causal_tile_visited(qi, ki, block_q, block_k, xp=jnp):
+    """True iff the (qi, ki) grid tile intersects the causal lower
+    triangle: the tile taken as one sub-tile of its own width."""
+    return _causal_key_bounds(
+        qi * block_q, block_q, ki * block_k, block_k, 1, xp)[1] > 0
+
+
+def _causal_subtile(block_q, block_k, nq, nk, causal):
+    """``(sub_q, sub_k)``: the sub-tiles a kernel body takes a grid tile
+    in.  The tile itself — one piece, masked whole when causal — unless
+    the call is causal with one grid tile a head that divides into
+    several sub-tiles."""
+    sub_q, sub_k = min(block_q, _CAUSAL_SUB), min(block_k, _CAUSAL_SUB)
+    if (not causal or nq != 1 or nk != 1
+            or block_q % sub_q or block_k % sub_k):
+        return block_q, block_k
+    return sub_q, sub_k
+
+
+def flash_tile_census(sq, sk, block_q, block_k, causal):
+    """``(total, visited, masked)`` sub-tiles of ONE head's (sq, sk) score
+    matrix under a flash kernel with these blocks: how many there are,
+    how many the kernel computes, and on how many of those it applies the
+    causal mask.  From the same bounds the kernels' pieces come from.  A
+    causal tile that is a single sub-tile is one piece, masked whenever it
+    runs: there ``masked == visited``."""
+    import numpy as np
+
+    sub_q, sub_k = _causal_subtile(
+        block_q, block_k, sq // block_q, sk // block_k, causal)
+    total = (sq // sub_q) * (sk // sub_k)
+    if not causal:
+        return total, total, 0
+    row0 = np.arange(sq // sub_q)[:, None] * sub_q
+    col0 = np.arange(sk // block_k)[None, :] * block_k
+    n_full, n_vis = _causal_key_bounds(
+        row0, sub_q, col0, sub_k, block_k // sub_k, np)
+    visited = int(n_vis.sum())
+    if (sub_q, sub_k) == (block_q, block_k):
+        return total, visited, visited
+    return total, visited, visited - int(n_full.sum())
+
+
+def _count_tiles(bh, sq, sk, block_q, block_k, causal):
+    """The engagement counter of the causal skip: every call of
+    :func:`flash_attention` that takes the kernels adds its census x
+    ``bh`` — when it is TRACED, since the skip is static and there is
+    nothing to count at run time.  The backward kernels walk the same
+    sub-tiles and are not counted again."""
+    from apex_tpu import obs
+
+    reg = obs.default_registry()
+    census = flash_tile_census(sq, sk, block_q, block_k, causal)
+    for name, n in zip(("total", "visited", "masked"), census):
+        reg.counter("ops.flash.tiles_" + name).inc(bh * n)
+
+
+def _causal_mask_tail(s, start, row0, col0):
+    """The causal mask on columns ``[start, width)`` of the score piece
+    ``s`` alone (``row0``/``col0``: local position of its element (0, 0));
+    the columns before ``start`` lie wholly below the diagonal."""
+    tail = s if start == 0 else s[:, start:]
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, tail.shape, 0)
+    col = col0 + start + jax.lax.broadcasted_iota(jnp.int32, tail.shape, 1)
+    tail = jnp.where(row >= col, tail, _NEG_INF)
+    return tail if start == 0 else jnp.concatenate([s[:, :start], tail], axis=1)
+
+
+def _for_pieces(block_q, block_k, nq, nk, causal, piece):
+    """Inside a visited grid tile: run ``piece(r0, rows, width, mask_from)``
+    — all four static — for each of the tile's query sub-tiles (``rows``
+    rows from row ``r0`` of the tile) on the tile's first ``width`` key
+    columns, the ones those rows reach, of which ``[mask_from, width)``
+    are crossed by the causal diagonal.  Nothing is done for the columns
+    past ``width``.  A tile that is not sub-tiled (:func:`_causal_subtile`)
+    is one piece, masked whole when causal."""
+    import numpy as np
+
+    sub_q, sub_k = _causal_subtile(block_q, block_k, nq, nk, causal)
+    if (sub_q, sub_k) == (block_q, block_k):
+        piece(0, block_q, block_k, 0 if causal else block_k)
+        return
+    # the one tile of its head: grid indices (0, 0), so the bounds are
+    # host numbers; row 0 reaches column 0, so no piece is empty
+    for r0 in range(0, block_q, sub_q):
+        n_full, n_vis = _causal_key_bounds(
+            r0, sub_q, 0, sub_k, block_k // sub_k, np)
+        piece(r0, sub_q, int(n_vis) * sub_k, int(n_full) * sub_k)
 
 
 def _drop_bh(seed_ref, h_map):
@@ -643,8 +765,8 @@ def _drop_bh(seed_ref, h_map):
 def _fwd_kernel(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     m_scr, l_scr, acc_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int, nk: int,
-    dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
+    *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
+    nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
 ):
     bh = _drop_bh(seed_ref, h_map)
     qi = pl.program_id(1)
@@ -656,9 +778,11 @@ def _fwd_kernel(
     # deliberately stays in LOCAL block coordinates: a dynamic (SMEM-
     # dependent) `run` predicate would defeat Mosaic's static grid
     # pruning — skipped blocks would still be DMA'd (measured 1.5x SLOWER
-    # on the ring bench).  Ring callers get global-causal semantics for
-    # free anyway: the diagonal block has row0 == col0 (local == global
-    # masking) and off-diagonal visible blocks need no mask at all.
+    # on the ring bench) — and the pieces a tile is taken in (_for_pieces)
+    # could not be worked out on the host.  Ring callers get global-causal
+    # semantics for free anyway: the diagonal block has row0 == col0
+    # (local == global masking) and off-diagonal visible blocks need no
+    # mask at all.
 
     @pl.when(ki == 0)
     def _init():
@@ -669,52 +793,66 @@ def _fwd_kernel(
     run = True
     if causal:
         # skip blocks strictly above the diagonal (static predicate:
-        # Mosaic prunes the whole grid step, DMAs included)
+        # Mosaic prunes the whole grid step, DMAs included).  That saves
+        # something only where nk > 1: with one key block (GPT-2's
+        # S = 1024) every grid tile reaches the triangle, and the masked
+        # half is skipped by the pieces below instead
         run = _causal_tile_visited(qi, ki, block_q, block_k)
 
-    @pl.when(run)
-    def _body():
+    def update(r0, rows, width, mask_from):
+        """One online-softmax step of the tile's query rows ``[r0, r0 +
+        rows)`` against its first ``width`` keys, masked from column
+        ``mask_from`` on."""
+        rows, cols = slice(r0, r0 + rows), slice(0, width)
         # q/k stay in their input dtype: a bf16xbf16 MXU dot with fp32
         # accumulation (preferred_element_type) is bit-identical to the
         # fp32 dot of the same bf16 values and runs at 2x rate
-        q = q_ref[0]  # (bq, d)
-        k = k_ref[0]  # (bk, d)
+        q = q_ref[0, rows]  # (bq, d)
+        k = k_ref[0, cols]  # (bk, d)
         # p@v: fp32 probabilities by default (the accumulator-precision
         # dot); probs_bf16 keeps v native and rounds p to the input dtype
         # so the dot runs at full MXU rate (the reference's own fused-MHA
         # softmax emits half-precision probabilities — see flash_attention)
-        v = v_ref[0] if probs_bf16 else v_ref[0].astype(jnp.float32)
+        v = v_ref[0, cols] if probs_bf16 else v_ref[0, cols].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
         if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(row >= col, s, _NEG_INF)
-        m_prev = m_scr[:, :1]  # (bq, 1)
+            s = s + bias_ref[0, rows, cols].astype(jnp.float32)
+        if mask_from < width:
+            s = _causal_mask_tail(
+                s, mask_from, qi * block_q + r0, ki * block_k)
+        m_prev = m_scr[rows, :1]  # (bq, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = alpha * l_scr[rows, :1] + jnp.sum(p, axis=-1, keepdims=True)
         if dropout_rate > 0.0:
             # dropout AFTER the l accumulation: the softmax normalizer is
             # the full sum; only the p@v accumulation is masked
             keep = _keep_mask(
-                seed_ref[0], bh, seed_ref[1] + qi * block_q,
+                seed_ref[0], bh, seed_ref[1] + qi * block_q + r0,
                 seed_ref[2] + ki * block_k, p.shape,
                 dropout_rate,
             )
             p = jnp.where(keep, p, 0.0)
         p_dot = p.astype(v.dtype) if probs_bf16 else p
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot_general(
             p_dot, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        stat = (s.shape[0], m_scr.shape[1])
+        m_scr[rows] = jnp.broadcast_to(m_new, stat)
+        l_scr[rows] = jnp.broadcast_to(l_new, stat)
+
+    @pl.when(run)
+    def _body():
+        # a query sub-tile's keys of this tile in ONE step.  Keys ascend
+        # over the grid, so every row has met a visible column (column 0
+        # of the call) before any stretch masked whole: m is finite by
+        # then and exp(-1e30 - m) an exact 0
+        _for_pieces(block_q, block_k, nq, nk, causal, update)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -734,7 +872,7 @@ def _bwd_dkv_body(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     dqin_ref, dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
-    dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
+    nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
     interp_copy_through: bool = False,
 ):
     """Shared dk/dv(+dq) backward body — grid (bh, k_blocks, q_blocks),
@@ -770,36 +908,39 @@ def _bwd_dkv_body(
     if causal:
         run = _causal_tile_visited(qi, ki, block_q, block_k)
 
-    @pl.when(run)
-    def _body():
+    def tile(r0, rows, width, mask_from):
+        """The tile's query rows ``[r0, r0 + rows)`` against its first
+        ``width`` keys, masked from column ``mask_from`` on: their dk/dv
+        into the scratch and, in the combined backward, the rows' dq —
+        whole, since the tile's other keys lie above the rows' diagonal."""
+        rows, cols = slice(r0, r0 + rows), slice(0, width)
         # native-dtype operands for the input-sourced dots (see _fwd_kernel
         # note: bf16 MXU dot + fp32 accumulate == fp32 dot of bf16 values)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
         # fp32 partner for the accumulator-precision dots; probs_bf16
         # instead rounds the probability/ds operands to the input dtype
         # (full MXU rate, documented tolerance cost — see flash_attention)
         do32 = do if probs_bf16 else do.astype(jnp.float32)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
+        lse = lse_ref[0, rows][:, :1]
+        delta = delta_ref[0, rows][:, :1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(row >= col, s, _NEG_INF)
+            s = s + bias_ref[0, rows, cols].astype(jnp.float32)
+        if mask_from < width:
+            s = _causal_mask_tail(
+                s, mask_from, qi * block_q + r0, ki * block_k)
         p = jnp.exp(s - lse)  # (bq, bk) — normalized probabilities
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         if dropout_rate > 0.0:
             keep = _keep_mask(
-                seed_ref[0], bh, seed_ref[1] + qi * block_q,
+                seed_ref[0], bh, seed_ref[1] + qi * block_q + r0,
                 seed_ref[2] + ki * block_k, p.shape,
                 dropout_rate,
             )
@@ -810,7 +951,7 @@ def _bwd_dkv_body(
             pd = p
         if probs_bf16:
             pd = pd.astype(q.dtype)
-        dv_scr[:] += jax.lax.dot_general(
+        dv_scr[cols] += jax.lax.dot_general(
             pd, do32, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -818,7 +959,7 @@ def _bwd_dkv_body(
         q_dot = q if probs_bf16 else q.astype(jnp.float32)
         if probs_bf16:
             ds = ds.astype(q.dtype)
-        dk_scr[:] += jax.lax.dot_general(
+        dk_scr[cols] += jax.lax.dot_general(
             ds, q_dot, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -829,9 +970,13 @@ def _bwd_dkv_body(
                 preferred_element_type=jnp.float32,
             )
             if dqin_ref is None:
-                dqp_ref[0, 0] = contrib.astype(dqp_ref.dtype)
+                dqp_ref[0, 0, rows] = contrib.astype(dqp_ref.dtype)
             else:
-                dqp_ref[0] = dqin_ref[0] + contrib
+                dqp_ref[0, rows] = dqin_ref[0, rows] + contrib
+
+    @pl.when(run)
+    def _body():
+        _for_pieces(block_q, block_k, nq, nk, causal, tile)
 
     if dqin_ref is not None and causal and interp_copy_through:
         # escape hatch (default OFF): explicitly carry the running dq
@@ -898,8 +1043,8 @@ def _bwd_fused_acc_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dq_kernel(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dbias_ref, dq_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int, nk: int,
-    dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
+    *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
+    nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
 ):
     bh = _drop_bh(seed_ref, h_map)
     qi = pl.program_id(1)
@@ -913,31 +1058,34 @@ def _bwd_dq_kernel(
     if causal:
         run = _causal_tile_visited(qi, ki, block_q, block_k)
 
-    @pl.when(run)
-    def _body():
+    def tile(r0, rows, width, mask_from):
+        if dbias_ref is not None and width < block_k:
+            # the keys above the rows' diagonal are never entered
+            dbias_ref[0, r0:r0 + rows, width:] = jnp.zeros(
+                (rows, block_k - width), dbias_ref.dtype)
+        rows, cols = slice(r0, r0 + rows), slice(0, width)
         # native-dtype operands for the input-sourced dots (see _fwd_kernel)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
+        lse = lse_ref[0, rows][:, :1]
+        delta = delta_ref[0, rows][:, :1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(row >= col, s, _NEG_INF)
+            s = s + bias_ref[0, rows, cols].astype(jnp.float32)
+        if mask_from < width:
+            s = _causal_mask_tail(
+                s, mask_from, qi * block_q + r0, ki * block_k)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         if dropout_rate > 0.0:
             keep = _keep_mask(
-                seed_ref[0], bh, seed_ref[1] + qi * block_q,
+                seed_ref[0], bh, seed_ref[1] + qi * block_q + r0,
                 seed_ref[2] + ki * block_k, p.shape,
                 dropout_rate,
             )
@@ -948,16 +1096,20 @@ def _bwd_dq_kernel(
             # QK^T scaling, so the tile gradient is p*(dp - delta) without
             # the scale factor; each tile is visited exactly once in this
             # grid, so a plain write (no accumulation) is correct
-            dbias_ref[0] = (p * (dp - delta)).astype(dbias_ref.dtype)
+            dbias_ref[0, rows, cols] = (p * (dp - delta)).astype(dbias_ref.dtype)
         if probs_bf16:
             ds = ds.astype(q.dtype)
             k_dot = k
         else:
             k_dot = k.astype(jnp.float32)
-        dq_scr[:] += jax.lax.dot_general(
+        dq_scr[rows] += jax.lax.dot_general(
             ds, k_dot, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    @pl.when(run)
+    def _body():
+        _for_pieces(block_q, block_k, nq, nk, causal, tile)
 
     if causal and dbias_ref is not None:
         @pl.when(jnp.logical_not(run))
@@ -1005,8 +1157,8 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
         inputs.append(bias)
     kernel = functools.partial(
         _fwd_kernel if bias is not None else _fwd_kernel_nobias,
-        scale=scale, causal=causal, block_q=block_q, block_k=block_k, nk=nk,
-        dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+        scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
+        nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
     )
     out, lse = _pallas_call(
         kernel,
@@ -1117,7 +1269,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                     _bwd_fused_acc_kernel if with_bias
                     else _bwd_fused_acc_nobias,
                     scale=scale, causal=causal, block_q=block_q,
-                    block_k=block_k, nq=nq, dropout_rate=dropout_rate,
+                    block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate,
                     h_map=h_map, probs_bf16=probs_bf16,
                     interp_copy_through=_FUSED_DQ_COPY_THROUGH,
                 ),
@@ -1143,7 +1295,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             functools.partial(
                 _bwd_fused_kernel if with_bias else _bwd_fused_nobias,
                 scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k, nq=nq, dropout_rate=dropout_rate,
+                block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate,
                 h_map=h_map, probs_bf16=probs_bf16,
             ),
             name="apex_flash_bwd_fused",
@@ -1169,7 +1321,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
 
             valid = _causal_tile_visited(
                 np.arange(nq)[None, :], np.arange(nk)[:, None],
-                block_q, block_k,
+                block_q, block_k, np,
             )
             mask = jnp.asarray(
                 np.repeat(valid, block_q, axis=1)[:, None, :, None]
@@ -1182,7 +1334,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
         functools.partial(
             _bwd_dkv_kernel if with_bias else _bwd_dkv_nobias,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
-            dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+            nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
         ),
         name="apex_flash_bwd_dkdv",
         grid=(bh, nk, nq),
@@ -1217,7 +1369,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             functools.partial(
                 _bwd_dq_kernel,
                 scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-                nk=nk, dropout_rate=dropout_rate, h_map=h_map,
+                nq=nq, nk=nk, dropout_rate=dropout_rate, h_map=h_map,
                 probs_bf16=probs_bf16,
             ),
             name="apex_flash_bwd_dq_dbias",
@@ -1237,8 +1389,8 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
     dq = _pallas_call(
         functools.partial(
             _bwd_dq_bias if with_bias else _bwd_dq_nobias,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k, nk=nk,
-            dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
+            nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
         ),
         name="apex_flash_bwd_dq",
         grid=(bh, nq, nk),
@@ -1304,6 +1456,30 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, dropout_rate, bias_grad,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _trace_key():
+    """What a trace of ``_flash`` reads besides its arguments: the
+    backend (interpreter or Mosaic, the ``_FUSED_DQ_ACC`` gate), the
+    module's backward switches and the sub-tile width.  Tools and tests
+    flip these between calls of one signature; any new trace-time read
+    of module state belongs here."""
+    return (jax.default_backend(), _USE_FUSED_BWD, _FUSED_BWD_MAX_NK,
+            _FUSED_DQ_ACC, _FUSED_DQ_COPY_THROUGH, _CAUSAL_SUB)
+
+
+# Called through jit, so that the layers of a model — every one the same
+# call — share ONE trace and ONE lowering of the kernels, forward and
+# backward.  The causal bodies are unrolled over their query sub-tiles, and
+# traced once a layer they doubled GPT-2 small's warm set-up on the chip
+# (55 s against 27 s, PERF.md section 6, PR 25).  XLA inlines the call.
+# ``trace_key`` (_trace_key()) is there only to be part of the cache key.
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 14)))
+def _flash_jit(q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
+               dropout_rate, bias_grad, h_map, probs_bf16, trace_key):
+    del trace_key
+    return _flash(q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
+                  dropout_rate, bias_grad, h_map, probs_bf16)
+
+
 def _pack_seed(dropout_seed, row_offset, col_offset, head_offset=0):
     """SMEM scalar block: [dropout seed, dropout row offset, dropout col
     offset, dropout head offset].  The offsets locate the call's tile
@@ -1342,8 +1518,26 @@ def flash_attention(
 
     ``block_q``/``block_k`` default to auto-picked sizes (the largest
     power-of-two tile of the sequence up to 512/1024 — ~2x faster than
-    fixed 128 tiles on v5e, see PERF.md).  The dropout mask is keyed on
-    GLOBAL positions, so results are invariant to the block choice.
+    fixed 128 tiles on v5e, see PERF.md; a causal self-attention of up
+    to 1024 positions takes its queries as ONE block as well).  The
+    dropout mask is keyed on GLOBAL positions, so results are invariant
+    to the block choice.
+
+    ``causal=True`` does not compute the masked half.  Grid tiles wholly
+    above the diagonal are skipped by a predicate on the grid indices;
+    with one key block — every call up to S = 1024, GPT-2's among them —
+    there is no such tile.  Where a head is ONE grid tile (one query
+    block too: what the auto blocks pick for a causal self-attention up
+    to S = 1024) the skipping happens inside the kernel body: each
+    128-row query sub-tile is taken against only the keys its rows
+    reach, and the mask is applied only to the key sub-tiles the
+    diagonal crosses (36 of 64 sub-tiles computed and 8 masked at
+    S = 1024).  The skipped elements were exact zeros, so only the
+    float32 summation order changes.  A grid of several tiles a head
+    keeps the grid-level skip alone.  Each call that takes the kernels
+    adds its sub-tile census x batch*heads to the
+    ``ops.flash.tiles_{total,visited,masked}`` counters when it is traced
+    (:func:`flash_tile_census`).
 
     Differentiable in q/k/v, and in ``bias`` when ``bias_grad=True``: the
     dq backward pass then also emits the per-tile dL/dbias, summed over
@@ -1398,7 +1592,12 @@ def flash_attention(
     if scale is None:
         scale = d ** -0.5
     if block_q is None:
-        block_q = _auto_block(sq, MAX_AUTO_BLOCK_Q)
+        # a causal head that fits one key block takes its queries as one
+        # block too: with one grid tile a head the causal walk inside the
+        # kernel is straight-line code (see _CAUSAL_SUB)
+        one_tile = causal and sq == sk and sq <= MAX_AUTO_BLOCK_K
+        block_q = _auto_block(
+            sq, MAX_AUTO_BLOCK_K if one_tile else MAX_AUTO_BLOCK_Q)
     if block_k is None:
         block_k = _auto_block(sk, MAX_AUTO_BLOCK_K)
     if dropout_rate > 0.0 and dropout_seed is None:
@@ -1436,9 +1635,10 @@ def flash_attention(
         h_total, head0 = dropout_heads
         h_map = (h, int(h_total))
         seed3 = _pack_seed(dropout_seed, 0, 0, head0)
-    out = _flash(
+    _count_tiles(b * h, sq, sk, block_q, block_k, causal)
+    out = _flash_jit(
         q3, k3, v3, bias3, seed3, float(scale), bool(causal), block_q,
         block_k, float(dropout_rate), bool(bias_grad), h_map,
-        bool(probs_bf16),
+        bool(probs_bf16), _trace_key(),
     )
     return out.reshape(b, h, sq, d)

@@ -296,3 +296,300 @@ class TestFusedBackwardMultiBlock:
                 np.asarray(a), np.asarray(b_), atol=2e-4, rtol=1e-3,
                 err_msg=f"{nk_label} causal={causal} d{n}",
             )
+
+
+# ---------------------------------------------------------------------------
+# causal sub-tiles: the masked half is skipped INSIDE the kernel body (PR 25)
+# ---------------------------------------------------------------------------
+
+import apex_tpu.ops.attention as attention_mod  # noqa: E402
+from apex_tpu.ops.attention import flash_tile_census  # noqa: E402
+
+# (sq, sk, block_q, block_k): one grid tile a head, square, with more keys
+# than queries and with more queries than keys — the sub-tiled layouts —
+# and a grid of several tiles a head, which keeps the one-piece masked
+# body and the grid-level skip (fp32 dq partials at nk = 2)
+_SUBTILE_LAYOUTS = {
+    "one_tile": (256, 256, 256, 256),
+    "many_tiles": (512, 512, 256, 256),
+    "sq_lt_sk": (256, 512, 256, 512),
+    "sq_gt_sk": (512, 256, 512, 256),
+}
+
+
+@pytest.fixture
+def sub_width(monkeypatch, request):
+    """A sub-tile width small enough to engage at interpreter sizes."""
+    monkeypatch.setattr(attention_mod, "_CAUSAL_SUB", request.param)
+    return request.param
+
+
+@pytest.mark.parametrize(
+    "dropout,dropout_heads",
+    [(0.0, None), (0.2, None), (0.2, (4, 1))],
+    ids=["nodrop", "drop", "drop_heads"],
+)
+@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("layout", sorted(_SUBTILE_LAYOUTS))
+@pytest.mark.parametrize("sub_width", [64, 128], indirect=True)
+def test_causal_subtiles_match_ref(rng, sub_width, layout, with_bias,
+                                   dropout, dropout_heads):
+    """Forward and dq, dk, dv of the sub-tiled causal kernels against the
+    reference.  fp32 at 5e-5: one flipped bit of the dropout mask would
+    move an output by ~1e-2, so passing means the kernel's mask is the
+    reference's, sub-tile offsets included."""
+    sq, sk, bq, bk = _SUBTILE_LAYOUTS[layout]
+    d = 64
+    q = jnp.asarray(rng.randn(B, H, sq, d).astype(np.float32) * 0.3)
+    k = jnp.asarray(rng.randn(B, H, sk, d).astype(np.float32) * 0.3)
+    v = jnp.asarray(rng.randn(B, H, sk, d).astype(np.float32) * 0.3)
+    bias = (jnp.asarray(rng.randn(B, sq, sk).astype(np.float32) * 0.5)
+            if with_bias else None)
+    dy = jnp.asarray(rng.randn(B, H, sq, d).astype(np.float32))
+    kw = dict(causal=True, dropout_rate=dropout,
+              dropout_seed=jnp.int32(11) if dropout else None,
+              dropout_heads=dropout_heads)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, bias, block_q=bq, block_k=bk,
+                               use_pallas=True, **kw)
+
+    def ref(q, k, v):
+        return attention_ref(q, k, v, bias, **kw)
+
+    # the traced bodies, not the census, say which width is engaged: two
+    # dots forward and five backward for each query sub-tile
+    pieces = bq // sub_width if (sq, sk) == (bq, bk) else 1
+    bodies = _kernel_primitives(
+        lambda q, k, v: jax.vjp(kernel, q, k, v)[1](dy), q, k, v)
+    assert bodies["apex_flash_fwd"].count("dot_general") == 2 * pieces
+    assert bodies["apex_flash_bwd_fused"].count("dot_general") == 5 * pieces
+
+    out_k, vjp_k = jax.vjp(kernel, q, k, v)
+    out_r, vjp_r = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=5e-5)
+    for a, r in zip(vjp_k(dy), vjp_r(dy)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=5e-5)
+
+
+@pytest.mark.parametrize("sub_width", [64], indirect=True)
+def test_causal_subtiles_learned_bias(rng, sub_width):
+    """bias_grad=True takes the two-pass backward: the dq pass walks the
+    same pieces and writes zeros into dbias above the diagonal."""
+    sq = sk = 256
+    q, k, v = qkv(rng, s=sq, d=64)
+    bias = jnp.asarray(rng.randn(B, sq, sk).astype(np.float32) * 0.5)
+
+    def lk(q, k, v, bias):
+        return jnp.sum(jnp.sin(flash_attention(
+            q, k, v, bias, causal=True, bias_grad=True, block_q=256,
+            block_k=256, use_pallas=True)))
+
+    def lr(q, k, v, bias):
+        return jnp.sum(jnp.sin(attention_ref(q, k, v, bias, causal=True)))
+
+    gk = jax.grad(lk, argnums=(0, 1, 2, 3))(q, k, v, bias)
+    gr = jax.grad(lr, argnums=(0, 1, 2, 3))(q, k, v, bias)
+    for a, r in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=5e-5)
+    assert not np.asarray(gk[3])[:, 0, 1:].any()  # row 0 sees column 0 alone
+
+
+@pytest.mark.parametrize("sub_width", [64], indirect=True)
+def test_ring_diagonal_block_subtiled(rng, sub_width):
+    """parallel/ring_attention.py hands its diagonal block to _flash_fwd /
+    _flash_bwd with causal=True and the shard's offsets in the seed block:
+    sub-tiled (256-token shards in 64-wide pieces), the masks stay local
+    and the dropout draw global."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from apex_tpu.parallel.mesh import shard_map_compat as shard_map
+    from apex_tpu.parallel.ring_attention import ring_attention
+
+    mesh = Mesh(np.array(jax.devices()[:2]), axis_names=("data",))
+    s_glob, d = 2 * 256, 64
+    q, k, v = (jnp.asarray(rng.randn(1, 1, s_glob, d).astype(np.float32) * 0.3)
+               for _ in range(3))
+    dy = jnp.asarray(rng.randn(1, 1, s_glob, d).astype(np.float32))
+    seed = jnp.int32(5)
+    spec = P(None, None, "data")
+
+    def ring(q, k, v):
+        return shard_map(
+            lambda qb, kb, vb: ring_attention(
+                qb, kb, vb, axis_name="data", causal=True, use_pallas=True,
+                dropout_rate=0.2, dropout_seed=seed),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
+        )(q, k, v)
+
+    def full(q, k, v):
+        return attention_ref(q, k, v, causal=True, dropout_rate=0.2,
+                             dropout_seed=seed)
+
+    out_k, vjp_k = jax.vjp(ring, q, k, v)
+    out_r, vjp_r = jax.vjp(full, q, k, v)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                               atol=2e-5, rtol=1e-5)
+    for a, r in zip(vjp_k(dy), vjp_r(dy)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=5e-5, rtol=1e-4)
+
+
+def _sub_jaxprs(eqn):
+    for p in eqn.params.values():
+        for sub in (p if isinstance(p, (list, tuple)) else [p]):
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _kernel_primitives(fn, *args):
+    """Primitive names of every flash kernel body traced by ``fn``, by
+    kernel name, sub-jaxprs (pl.when branches, loops) included."""
+    found = {}
+
+    def walk(jaxpr, names):
+        for eqn in jaxpr.eqns:
+            if names is None and eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = walk(eqn.params["jaxpr"], [])
+                continue
+            if names is not None:
+                names.append(eqn.primitive.name)
+            for inner in _sub_jaxprs(eqn):
+                walk(inner, names)
+        return names
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return found
+
+
+def test_noncausal_kernel_bodies_unchanged(rng):
+    """causal=False (BERT-large's call, ring attention's off-diagonal
+    blocks) never enters the sub-tile path: two dots forward, five in the
+    combined backward, one piece, no loop."""
+    q, k, v = qkv(rng, s=512, d=64)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, use_pallas=True))
+
+    bodies = _kernel_primitives(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert sorted(bodies) == ["apex_flash_bwd_fused", "apex_flash_fwd"]
+    assert bodies["apex_flash_fwd"].count("dot_general") == 2
+    assert bodies["apex_flash_bwd_fused"].count("dot_general") == 5
+    for prims in bodies.values():
+        assert not {"while", "scan", "concatenate"} & set(prims)
+    # the same shapes, causal: 4 x 4 sub-tiles, a forward piece a query
+    # sub-tile, the keys above the diagonal in none of them
+    causal = _kernel_primitives(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        use_pallas=True), q, k, v)
+    assert causal["apex_flash_fwd"].count("dot_general") == 2 * 4
+
+
+def _brute_census(sq, sk, sub_q, sub_k):
+    visible = np.tril(np.ones((sq, sk), bool))
+    tiles = visible.reshape(sq // sub_q, sub_q, sk // sub_k, sub_k)
+    any_ = tiles.any(axis=(1, 3))
+    all_ = tiles.all(axis=(1, 3))
+    return any_.size, int(any_.sum()), int((any_ & ~all_).sum())
+
+
+@pytest.mark.parametrize(
+    "sq,sk,block_q,block_k,sub",
+    [
+        # gpt2-small.train's call (auto blocks: one grid tile a head)
+        (1024, 1024, 1024, 1024, (128, 128)),
+        (512, 512, 512, 512, (128, 128)),
+        (512, 1024, 512, 1024, (128, 128)),
+        (1024, 256, 1024, 256, (128, 128)),
+        # several grid tiles a head are not sub-tiled: the same sequence
+        # under the old 512 x 1024 blocks, and longer ones
+        (1024, 1024, 512, 1024, (512, 1024)),
+        (2048, 2048, 512, 1024, (512, 1024)),
+        (2048, 512, 1024, 256, (1024, 256)),
+    ],
+)
+def test_census_matches_brute_force(sq, sk, block_q, block_k, sub):
+    assert attention_mod._causal_subtile(
+        block_q, block_k, sq // block_q, sk // block_k, True) == sub
+    total, visited, masked = _brute_census(sq, sk, *sub)
+    if sub == (block_q, block_k):
+        masked = visited  # one piece a tile, masked whenever it runs
+    assert flash_tile_census(sq, sk, block_q, block_k, True) == \
+        (total, visited, masked)
+
+
+def test_census_of_the_cells():
+    # gpt2-small.train: 36 of 64 sub-tiles visited, 8 of them masked
+    assert flash_tile_census(1024, 1024, 1024, 1024, True) == (64, 36, 8)
+    # bert-large.train: bidirectional, one piece, nothing skipped or masked
+    assert flash_tile_census(512, 512, 512, 512, False) == (1, 1, 0)
+    # a causal tile that is one sub-tile is masked whenever it runs
+    assert flash_tile_census(256, 256, 128, 128, True) == (4, 3, 3)
+
+
+def test_tracing_moves_the_tile_counters(rng):
+    from apex_tpu import obs
+
+    q, k, v = qkv(rng, s=512, d=64)
+    reg = obs.default_registry()
+    names = ["ops.flash.tiles_" + n for n in ("total", "visited", "masked")]
+
+    def moved_by(fn):
+        before = [reg.counter(n).snapshot()["value"] for n in names]
+        jax.make_jaxpr(fn)(q, k, v)
+        return [reg.counter(n).snapshot()["value"] - b
+                for n, b in zip(names, before)]
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, use_pallas=True)
+
+    census = flash_tile_census(512, 512, 512, 512, True)
+    assert census == (16, 10, 4)
+    want = [B * H * n for n in census]
+    assert moved_by(fwd) == want
+    # every traced call counts (a new function, or make_jaxpr would not
+    # trace again), whether or not the kernels' trace is shared
+    # (attention._flash_jit); the backward is not counted again
+    assert moved_by(lambda q, k, v: fwd(q, k, v)) == want
+    assert moved_by(jax.grad(lambda q, k, v: jnp.sum(fwd(q, k, v)))) == want
+    # bidirectional: everything visited, nothing masked
+    assert moved_by(lambda q, k, v: flash_attention(
+        q, k, v, use_pallas=True)) == [B * H, B * H, 0]
+
+
+def test_shared_trace_keyed_on_module_switches(rng, monkeypatch):
+    """The kernels' trace is shared between calls of one signature
+    (attention._flash_jit); what it reads from the module at trace time
+    is part of the key, so the A/B tools that flip a switch between two
+    calls (tools/check_fused_dq_acc.py, tools/bench_fused_exclusions.py)
+    get the other path, not the first trace again."""
+    q, k, v = qkv(rng, s=512, d=64)
+
+    def kernels():
+        # a new function each time, as the tools make one: make_jaxpr
+        # itself keeps the trace of a function it has seen
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, causal=True, block_q=256, block_k=256,
+                use_pallas=True))
+
+        return sorted(_kernel_primitives(
+            jax.grad(loss, argnums=(0, 1, 2)), q, k, v))
+
+    fused = ["apex_flash_bwd_fused", "apex_flash_fwd"]
+    two_pass = ["apex_flash_bwd_dkdv", "apex_flash_bwd_dq", "apex_flash_fwd"]
+    assert kernels() == fused
+    monkeypatch.setattr(attention_mod, "_USE_FUSED_BWD", False)
+    assert kernels() == two_pass
+    monkeypatch.setattr(attention_mod, "_USE_FUSED_BWD", True)
+    assert kernels() == fused
+    monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 1)
+    assert kernels() == two_pass  # nk = 2 is past the fused limit now
+    monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 4)
+    # the aliased dq accumulation is taken on the TPU alone
+    monkeypatch.setattr(attention_mod, "_FUSED_DQ_ACC", True)
+    assert kernels() == fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernels() == ["apex_flash_bwd_fused_acc", "apex_flash_fwd"]
