@@ -21,5 +21,6 @@ from apex_tpu.ops.softmax_xentropy import (  # noqa: F401
     softmax_cross_entropy_ref,
 )
 from apex_tpu.ops.attention import attention_ref, flash_attention  # noqa: F401
+from apex_tpu.ops.grouped_mm import grouped_matmul  # noqa: F401
 from apex_tpu.ops.mlp import mlp, mlp_ref  # noqa: F401
 from apex_tpu.ops.conv_bn import bn_relu_matmul, matmul_stats  # noqa: F401

@@ -47,7 +47,8 @@ def force_pallas(value: Optional[bool]):
 #: HLO instruction — and so its event in a profiler trace — bears this
 #: name whatever scope called the kernel.  Readers of a trace match the
 #: family prefixes (``apex_flash_fwd``, ``apex_flash_bwd``, ``apex_ln_``,
-#: ``apex_xent_``), so a variant can be added without touching them.
+#: ``apex_xent_``, ``apex_gmm``), so a variant can be added without
+#: touching them.
 KERNEL_NAMES = (
     "apex_paged_attn",
     "apex_flash_fwd",
@@ -65,6 +66,8 @@ KERNEL_NAMES = (
     "apex_conv_bn_matmul_stats",
     "apex_conv_bn_relu_matmul",
     "apex_conv_bn_matmul_bwd",
+    "apex_gmm_dw",
+    "apex_gmm",
 )
 
 

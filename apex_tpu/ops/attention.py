@@ -165,6 +165,14 @@ def _keep_mask(seed, bh, row0, col0, shape, rate: float):
 # jnp reference
 # ---------------------------------------------------------------------------
 
+def _head_group(h_q: int, h_k: int, h_v: int) -> int:
+    """How many consecutive query heads share one key/value head."""
+    if h_k != h_v or h_k < 1 or h_q % h_k:
+        raise ValueError(
+            f"{h_q} query heads cannot share {h_k} key / {h_v} value heads")
+    return h_q // h_k
+
+
 def attention_ref(
     q: jax.Array,
     k: jax.Array,
@@ -175,8 +183,12 @@ def attention_ref(
     dropout_rate: float = 0.0,
     dropout_seed: Optional[jax.Array] = None,
     dropout_heads=None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain attention.  q,k,v: (B, H, S, D); bias: (B, Sq, Sk) additive.
+    ``k``/``v`` may hold H / G heads (each serves G consecutive query
+    heads); ``window`` keeps, under ``causal``, only keys ``j`` with
+    ``i - j < window``.
 
     ``dropout_rate`` > 0 applies probability dropout with the SAME
     counter-based mask the Pallas kernel uses (exact parity).
@@ -187,14 +199,22 @@ def attention_ref(
         scale = q.shape[-1] ** -0.5
     b, h, sq, _ = q.shape
     sk = k.shape[2]
+    group = _head_group(h, k.shape[1], v.shape[1])
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
     s = s * scale
     if bias is not None:
         s = s + bias[:, None, :, :].astype(jnp.float32)
+    if window is not None and not causal:
+        raise ValueError("window requires causal=True")
     if causal:
         row = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        s = jnp.where(row >= col, s, _NEG_INF)
+        keep = row >= col
+        if window is not None:
+            keep = keep & (row - col < window)
+        s = jnp.where(keep, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate > 0.0:
         if dropout_seed is None:
@@ -657,39 +677,72 @@ def _causal_key_bounds(row0, rows, col0, width, n, xp=jnp):
     return n_full, n_vis
 
 
-def _causal_tile_visited(qi, ki, block_q, block_k, xp=jnp):
+def _causal_tile_visited(qi, ki, block_q, block_k, xp=jnp, window=None):
     """True iff the (qi, ki) grid tile intersects the causal lower
-    triangle: the tile taken as one sub-tile of its own width."""
-    return _causal_key_bounds(
+    triangle — the tile taken as one sub-tile of its own width — and,
+    under a ``window``, the band ``i - j < window`` below the diagonal
+    (its nearest corner: first row, last column)."""
+    visited = _causal_key_bounds(
         qi * block_q, block_q, ki * block_k, block_k, 1, xp)[1] > 0
+    if window is not None:
+        visited = visited & ((ki + 1) * block_k - 1 > qi * block_q - window)
+    return visited
 
 
-def _causal_subtile(block_q, block_k, nq, nk, causal):
+def _visited_key_blocks(qi, block_q, block_k, nk, window):
+    """``(lo, hi)``: the key blocks ``[lo, hi]`` that
+    :func:`_causal_tile_visited` holds true for at query block ``qi``
+    (a contiguous run).  The index maps clamp to it, so that a skipped
+    grid step names the block its neighbour fetched and moves nothing."""
+    hi = jnp.minimum((qi * block_q + block_q - 1) // block_k, nk - 1)
+    if window is None:
+        return 0, hi
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k, hi
+
+
+def _visited_query_blocks(ki, block_q, block_k, nq, window):
+    """The same run from a key block's side: the query blocks
+    ``[lo, hi]`` that visit key block ``ki``."""
+    lo = jnp.minimum((ki * block_k) // block_q, nq - 1)
+    if window is None:
+        return lo, nq - 1
+    return lo, jnp.minimum(
+        ((ki + 1) * block_k + window - 2) // block_q, nq - 1)
+
+
+def _causal_subtile(block_q, block_k, nq, nk, causal, window=None):
     """``(sub_q, sub_k)``: the sub-tiles a kernel body takes a grid tile
     in.  The tile itself — one piece, masked whole when causal — unless
     the call is causal with one grid tile a head that divides into
-    several sub-tiles."""
+    several sub-tiles.  A windowed call keeps the one piece (its band is
+    cut by the grid, where there is one)."""
     sub_q, sub_k = min(block_q, _CAUSAL_SUB), min(block_k, _CAUSAL_SUB)
-    if (not causal or nq != 1 or nk != 1
+    if (not causal or window is not None or nq != 1 or nk != 1
             or block_q % sub_q or block_k % sub_k):
         return block_q, block_k
     return sub_q, sub_k
 
 
-def flash_tile_census(sq, sk, block_q, block_k, causal):
+def flash_tile_census(sq, sk, block_q, block_k, causal, window=None):
     """``(total, visited, masked)`` sub-tiles of ONE head's (sq, sk) score
     matrix under a flash kernel with these blocks: how many there are,
     how many the kernel computes, and on how many of those it applies the
     causal mask.  From the same bounds the kernels' pieces come from.  A
     causal tile that is a single sub-tile is one piece, masked whenever it
-    runs: there ``masked == visited``."""
+    runs: there ``masked == visited``.  Under a ``window`` the visited
+    tiles are those of the band."""
     import numpy as np
 
     sub_q, sub_k = _causal_subtile(
-        block_q, block_k, sq // block_q, sk // block_k, causal)
+        block_q, block_k, sq // block_q, sk // block_k, causal, window)
     total = (sq // sub_q) * (sk // sub_k)
     if not causal:
         return total, total, 0
+    if window is not None:
+        visited = int(_causal_tile_visited(
+            np.arange(sq // block_q)[:, None], np.arange(sk // block_k)[None, :],
+            block_q, block_k, np, window).sum())
+        return total, visited, visited
     row0 = np.arange(sq // sub_q)[:, None] * sub_q
     col0 = np.arange(sk // block_k)[None, :] * block_k
     n_full, n_vis = _causal_key_bounds(
@@ -700,32 +753,36 @@ def flash_tile_census(sq, sk, block_q, block_k, causal):
     return total, visited, visited - int(n_full.sum())
 
 
-def _count_tiles(bh, sq, sk, block_q, block_k, causal):
+def _count_tiles(bh, sq, sk, block_q, block_k, causal, window=None):
     """The engagement counter of the causal skip: every call of
     :func:`flash_attention` that takes the kernels adds its census x
-    ``bh`` — when it is TRACED, since the skip is static and there is
-    nothing to count at run time.  The backward kernels walk the same
-    sub-tiles and are not counted again."""
+    ``bh`` (query heads) — when it is TRACED, since the skip is static
+    and there is nothing to count at run time.  The backward kernels walk
+    the same sub-tiles and are not counted again."""
     from apex_tpu import obs
 
     reg = obs.default_registry()
-    census = flash_tile_census(sq, sk, block_q, block_k, causal)
+    census = flash_tile_census(sq, sk, block_q, block_k, causal, window)
     for name, n in zip(("total", "visited", "masked"), census):
         reg.counter("ops.flash.tiles_" + name).inc(bh * n)
 
 
-def _causal_mask_tail(s, start, row0, col0):
+def _causal_mask_tail(s, start, row0, col0, window=None):
     """The causal mask on columns ``[start, width)`` of the score piece
     ``s`` alone (``row0``/``col0``: local position of its element (0, 0));
-    the columns before ``start`` lie wholly below the diagonal."""
+    the columns before ``start`` lie wholly below the diagonal.  Under a
+    ``window`` (always with ``start`` 0) also the band's lower edge."""
     tail = s if start == 0 else s[:, start:]
     row = row0 + jax.lax.broadcasted_iota(jnp.int32, tail.shape, 0)
     col = col0 + start + jax.lax.broadcasted_iota(jnp.int32, tail.shape, 1)
-    tail = jnp.where(row >= col, tail, _NEG_INF)
+    keep = row >= col
+    if window is not None:
+        keep = keep & (row - col < window)
+    tail = jnp.where(keep, tail, _NEG_INF)
     return tail if start == 0 else jnp.concatenate([s[:, :start], tail], axis=1)
 
 
-def _for_pieces(block_q, block_k, nq, nk, causal, piece):
+def _for_pieces(block_q, block_k, nq, nk, causal, piece, window=None):
     """Inside a visited grid tile: run ``piece(r0, rows, width, mask_from)``
     — all four static — for each of the tile's query sub-tiles (``rows``
     rows from row ``r0`` of the tile) on the tile's first ``width`` key
@@ -735,7 +792,7 @@ def _for_pieces(block_q, block_k, nq, nk, causal, piece):
     is one piece, masked whole when causal."""
     import numpy as np
 
-    sub_q, sub_k = _causal_subtile(block_q, block_k, nq, nk, causal)
+    sub_q, sub_k = _causal_subtile(block_q, block_k, nq, nk, causal, window)
     if (sub_q, sub_k) == (block_q, block_k):
         piece(0, block_q, block_k, 0 if causal else block_k)
         return
@@ -747,15 +804,17 @@ def _for_pieces(block_q, block_k, nq, nk, causal, piece):
         piece(r0, sub_q, int(n_vis) * sub_k, int(n_full) * sub_k)
 
 
-def _drop_bh(seed_ref, h_map):
-    """The batch*head index the DROPOUT hash is keyed on.
+def _drop_bh(seed_ref, h_map, bh=None):
+    """The batch*head index the DROPOUT hash is keyed on (``bh``: the
+    call's local query head where the grid's first index is not it).
 
     ``h_map=(h_local, h_total)`` maps the local grid index to the GLOBAL
     head coordinate (seed_ref[3] = traced head offset of this shard's
     head group) so a head-sharded call (Ulysses) draws the bitwise-same
     mask as the unsharded one.  None = identity (the common case; no
     SMEM read, no div/mod)."""
-    bh = pl.program_id(0)
+    if bh is None:
+        bh = pl.program_id(0)
     if h_map is None:
         return bh
     h_local, h_total = h_map
@@ -767,6 +826,7 @@ def _fwd_kernel(
     m_scr, l_scr, acc_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
     nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
+    window: Optional[int] = None,
 ):
     bh = _drop_bh(seed_ref, h_map)
     qi = pl.program_id(1)
@@ -797,7 +857,7 @@ def _fwd_kernel(
         # something only where nk > 1: with one key block (GPT-2's
         # S = 1024) every grid tile reaches the triangle, and the masked
         # half is skipped by the pieces below instead
-        run = _causal_tile_visited(qi, ki, block_q, block_k)
+        run = _causal_tile_visited(qi, ki, block_q, block_k, window=window)
 
     def update(r0, rows, width, mask_from):
         """One online-softmax step of the tile's query rows ``[r0, r0 +
@@ -821,7 +881,7 @@ def _fwd_kernel(
             s = s + bias_ref[0, rows, cols].astype(jnp.float32)
         if mask_from < width:
             s = _causal_mask_tail(
-                s, mask_from, qi * block_q + r0, ki * block_k)
+                s, mask_from, qi * block_q + r0, ki * block_k, window)
         m_prev = m_scr[rows, :1]  # (bq, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -851,8 +911,12 @@ def _fwd_kernel(
         # a query sub-tile's keys of this tile in ONE step.  Keys ascend
         # over the grid, so every row has met a visible column (column 0
         # of the call) before any stretch masked whole: m is finite by
-        # then and exp(-1e30 - m) an exact 0
-        _for_pieces(block_q, block_k, nq, nk, causal, update)
+        # then and exp(-1e30 - m) an exact 0.  Under a window a row may
+        # meet a tile masked whole FIRST (the band's lower edge cuts the
+        # tile below the row): p reads 1 there, and the row's first visible
+        # column, which its own diagonal guarantees, wipes that with
+        # alpha = exp(-1e30 - m) = 0
+        _for_pieces(block_q, block_k, nq, nk, causal, update, window)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -873,10 +937,15 @@ def _bwd_dkv_body(
     dqin_ref, dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
     nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
-    interp_copy_through: bool = False,
+    interp_copy_through: bool = False, window: Optional[int] = None,
+    group: int = 1,
 ):
     """Shared dk/dv(+dq) backward body — grid (bh, k_blocks, q_blocks),
-    q inner; dk/dv accumulate in VMEM scratch across the q loop.
+    q inner; dk/dv accumulate in VMEM scratch across the q loop.  With
+    ``group`` query heads to a key/value head the grid is (key/value
+    heads, k_blocks, group * q_blocks): the inner loop walks the group's
+    query heads one after the other, so dk/dv come out summed over the
+    group from the same scratch.
 
     ``dqp_ref``/``dqin_ref`` select the variant at trace time:
 
@@ -895,18 +964,26 @@ def _bwd_dkv_body(
       skipped-but-unpruned tiles copy through.  No nk x partials buffer,
       no host-side sum/mask pass.
     """
-    bh = _drop_bh(seed_ref, h_map)
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    if group == 1:
+        bh = _drop_bh(seed_ref, h_map)
+        ki = pl.program_id(1)
+        qi = step = pl.program_id(2)
+    else:
+        # the inner axis walks the group's query heads, each through its
+        # query blocks; the dropout hash is keyed on the QUERY head
+        ki, step = pl.program_id(1), pl.program_id(2)
+        qi = step % nq
+        bh = _drop_bh(seed_ref, h_map,
+                      pl.program_id(0) * group + step // nq)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     run = True
     if causal:
-        run = _causal_tile_visited(qi, ki, block_q, block_k)
+        run = _causal_tile_visited(qi, ki, block_q, block_k, window=window)
 
     def tile(r0, rows, width, mask_from):
         """The tile's query rows ``[r0, r0 + rows)`` against its first
@@ -933,7 +1010,7 @@ def _bwd_dkv_body(
             s = s + bias_ref[0, rows, cols].astype(jnp.float32)
         if mask_from < width:
             s = _causal_mask_tail(
-                s, mask_from, qi * block_q + r0, ki * block_k)
+                s, mask_from, qi * block_q + r0, ki * block_k, window)
         p = jnp.exp(s - lse)  # (bq, bk) — normalized probabilities
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -976,7 +1053,7 @@ def _bwd_dkv_body(
 
     @pl.when(run)
     def _body():
-        _for_pieces(block_q, block_k, nq, nk, causal, tile)
+        _for_pieces(block_q, block_k, nq, nk, causal, tile, window)
 
     if dqin_ref is not None and causal and interp_copy_through:
         # escape hatch (default OFF): explicitly carry the running dq
@@ -991,7 +1068,7 @@ def _bwd_dkv_body(
         def _copy_through():
             dqp_ref[0] = dqin_ref[0]
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == group * nq - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -1045,6 +1122,7 @@ def _bwd_dq_kernel(
     dq_ref, dbias_ref, dq_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
     nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
+    window: Optional[int] = None,
 ):
     bh = _drop_bh(seed_ref, h_map)
     qi = pl.program_id(1)
@@ -1056,7 +1134,7 @@ def _bwd_dq_kernel(
 
     run = True
     if causal:
-        run = _causal_tile_visited(qi, ki, block_q, block_k)
+        run = _causal_tile_visited(qi, ki, block_q, block_k, window=window)
 
     def tile(r0, rows, width, mask_from):
         if dbias_ref is not None and width < block_k:
@@ -1078,7 +1156,7 @@ def _bwd_dq_kernel(
             s = s + bias_ref[0, rows, cols].astype(jnp.float32)
         if mask_from < width:
             s = _causal_mask_tail(
-                s, mask_from, qi * block_q + r0, ki * block_k)
+                s, mask_from, qi * block_q + r0, ki * block_k, window)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -1109,7 +1187,7 @@ def _bwd_dq_kernel(
 
     @pl.when(run)
     def _body():
-        _for_pieces(block_q, block_k, nq, nk, causal, tile)
+        _for_pieces(block_q, block_k, nq, nk, causal, tile, window)
 
     if causal and dbias_ref is not None:
         @pl.when(jnp.logical_not(run))
@@ -1125,10 +1203,36 @@ def _bwd_dq_kernel(
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
 
-def _specs(block_q, block_k, d, sq, sk, with_bias, h):
+def _grouped_route(window, group):
+    """True for the calls this module gained with windows and grouped
+    key/value heads.  They alone clamp their index maps to the visited
+    blocks (:func:`_visited_key_blocks`); every other call keeps the maps,
+    and so the programs, it had."""
+    return window is not None or group > 1
+
+
+def _kv_spec_by_query(block_q, block_k, d, nk, causal, window, group):
+    """The key/value BlockSpec of a grid ``(query head, q block, k
+    block)``: query head ``b`` reads key/value head ``b // group``; a step
+    the causal band skips names the nearest visited block, so nothing
+    moves for it."""
+    if not _grouped_route(window, group):
+        return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    if not causal:
+        return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0))
+
+    def index(b, i, j):
+        lo, hi = _visited_key_blocks(i, block_q, block_k, nk, window)
+        return b // group, jnp.clip(j, lo, hi), 0
+    return pl.BlockSpec((1, block_k, d), index)
+
+
+def _specs(block_q, block_k, d, sq, sk, with_bias, h, causal=False,
+           window=None, group=1):
     """Common BlockSpecs: arrays are reshaped to (BH, S, D) / bias (B, Sq, Sk)."""
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    k_spec = _kv_spec_by_query(block_q, block_k, d, sk // block_k, causal,
+                               window, group)
     bias_spec = (
         pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, i, j))
         if with_bias
@@ -1138,9 +1242,10 @@ def _specs(block_q, block_k, d, sq, sk, with_bias, h):
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
-               dropout_rate, h_map=None, probs_bf16=False):
+               dropout_rate, h_map=None, probs_bf16=False, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
+    group = bh // k.shape[0]
     # bias stays UNEXPANDED at (B, Sq, Sk); the BlockSpec index maps divide
     # the batch*head grid index by h, so no (B*H, Sq, Sk) broadcast is ever
     # materialized in HBM (callers may still pass a pre-expanded (B*H, ...)
@@ -1148,7 +1253,9 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
     h = 1 if bias is None else bh // bias.shape[0]
     nq = sq // block_q
     nk = sk // block_k
-    q_spec, k_spec, bias_spec = _specs(block_q, block_k, d, sq, sk, bias is not None, h)
+    q_spec, k_spec, bias_spec = _specs(
+        block_q, block_k, d, sq, sk, bias is not None, h, causal, window,
+        group)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [seed_spec, q_spec, k_spec, k_spec]
     inputs = [seed, q, k, v]
@@ -1159,6 +1266,7 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
         _fwd_kernel if bias is not None else _fwd_kernel_nobias,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
         nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+        **_window_kw(window),
     )
     out, lse = _pallas_call(
         kernel,
@@ -1180,6 +1288,17 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
         ],
     )(*inputs)
     return out, lse[:, :, 0]
+
+
+def _window_kw(window, group=1):
+    """The kernels' keywords for a window and grouped heads — none for a
+    call that has neither, whose partial is then the one it always was."""
+    kw = {}
+    if window is not None:
+        kw["window"] = window
+    if group > 1:
+        kw["group"] = group
+    return kw
 
 
 def _fwd_kernel_nobias(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -1208,9 +1327,11 @@ def _bwd_dq_bias(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                block_k, dropout_rate, bias_grad=False, h_map=None,
-               probs_bf16=False):
+               probs_bf16=False, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
+    bhk = k.shape[0]          # key/value heads: bh // group
+    group = bh // bhk
     h = 1 if bias is None else bh // bias.shape[0]  # unexpanded-bias divisor
     nq = sq // block_q
     nk = sk // block_k
@@ -1221,8 +1342,23 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
     with_bias = bias is not None
 
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))  # dkv: q inner
-    stat_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, j, 0))
+    if not _grouped_route(window, group):
+        q_index = dqp_index = lambda b, i, j: (b, j, 0)   # dkv: q inner
+    else:
+        # grid (key/value head, k block, group x q block): step j is query
+        # block j % nq of the group's query head j // nq; a step the
+        # causal band skips names the nearest visited query block
+        def dqp_index(b, i, j):
+            return b * group + j // nq, j % nq, 0
+
+        def q_index(b, i, j):
+            qi = j % nq
+            if causal:
+                qi = jnp.clip(qi, *_visited_query_blocks(
+                    i, block_q, block_k, nq, window))
+            return b * group + j // nq, qi, 0
+    q_spec = pl.BlockSpec((1, block_q, d), q_index)
+    stat_spec = pl.BlockSpec((1, block_q, 128), q_index)
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
     bias_spec = pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, j, i))
     in_specs = [seed_spec, q_spec, k_spec, k_spec]
@@ -1240,14 +1376,15 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
         ]
         dkv_out_shape = [
-            jax.ShapeDtypeStruct((bh, sk, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), q.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
         ]
         scratch = [
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ]
         if (nk > 1 and _FUSED_DQ_ACC and nq > 1
+                and not _grouped_route(window, group)
                 and jax.default_backend() == "tpu"):
             # combined dk+dv+dq with dq ACCUMULATED IN HBM (r5): the dq
             # block is an aliased input/output pair — each visited (ki, qi)
@@ -1297,12 +1434,14 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                 scale=scale, causal=causal, block_q=block_q,
                 block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate,
                 h_map=h_map, probs_bf16=probs_bf16,
+                **_window_kw(window, group),
             ),
             name="apex_flash_bwd_fused",
-            grid=(bh, nk, nq),
+            grid=(bhk, nk, group * nq),
             in_specs=in_specs,
             out_specs=dkv_out_specs + [
-                pl.BlockSpec((1, 1, block_q, d), lambda b, i, j: (i, b, j, 0)),
+                pl.BlockSpec((1, 1, block_q, d),
+                             lambda b, i, j: (i, *dqp_index(b, i, j))),
             ],
             out_shape=dkv_out_shape + [
                 # nk == 1 (BERT S=512, GPT S=1024 with block_k=1024): each
@@ -1321,7 +1460,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
 
             valid = _causal_tile_visited(
                 np.arange(nq)[None, :], np.arange(nk)[:, None],
-                block_q, block_k, np,
+                block_q, block_k, np, window,
             )
             mask = jnp.asarray(
                 np.repeat(valid, block_q, axis=1)[:, None, :, None]
@@ -1335,17 +1474,18 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             _bwd_dkv_kernel if with_bias else _bwd_dkv_nobias,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
             nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+            **_window_kw(window, group),
         ),
         name="apex_flash_bwd_dkdv",
-        grid=(bh, nk, nq),
+        grid=(bhk, nk, group * nq),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), q.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -1355,7 +1495,8 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
 
     q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     stat_spec2 = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    k_spec2 = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    k_spec2 = _kv_spec_by_query(block_q, block_k, d, nk, causal, window,
+                                group)
     bias_spec2 = pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, i, j))
     in_specs = [seed_spec, q_spec2, k_spec2, k_spec2]
     inputs = [seed, q, k, v]
@@ -1370,7 +1511,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                 _bwd_dq_kernel,
                 scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                 nq=nq, nk=nk, dropout_rate=dropout_rate, h_map=h_map,
-                probs_bf16=probs_bf16,
+                probs_bf16=probs_bf16, **_window_kw(window),
             ),
             name="apex_flash_bwd_dq_dbias",
             grid=(bh, nq, nk),
@@ -1391,6 +1532,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             _bwd_dq_bias if with_bias else _bwd_dq_nobias,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
             nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+            **_window_kw(window),
         ),
         name="apex_flash_bwd_dq",
         grid=(bh, nq, nk),
@@ -1406,34 +1548,35 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
 # custom_vjp + public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13))
 def _flash(q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
-           dropout_rate, bias_grad, h_map, probs_bf16):
+           dropout_rate, bias_grad, h_map, probs_bf16, window=None):
     out, _ = _flash_fwd(
         q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
-        dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+        dropout_rate, h_map=h_map, probs_bf16=probs_bf16, window=window,
     )
     return out
 
 
 def _flash_fwd_rule(q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
-                    dropout_rate, bias_grad, h_map, probs_bf16):
+                    dropout_rate, bias_grad, h_map, probs_bf16, window):
     out, lse = _flash_fwd(
         q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
-        dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
+        dropout_rate, h_map=h_map, probs_bf16=probs_bf16, window=window,
     )
     return out, (q3, k3, v3, bias3, seed1, out, lse)
 
 
 def _flash_bwd_rule(scale, causal, block_q, block_k, dropout_rate, bias_grad,
-                    h_map, probs_bf16, res, do):
+                    h_map, probs_bf16, window, res, do):
     import numpy as np
 
     q3, k3, v3, bias3, seed1, out, lse = res
     dq, dk, dv, dbias3 = _flash_bwd(
         q3, k3, v3, bias3, seed1, out, lse, do, scale, causal, block_q,
         block_k, dropout_rate, bias_grad=bias_grad, h_map=h_map,
-        probs_bf16=probs_bf16,
+        probs_bf16=probs_bf16, window=window,
     )
     if bias3 is None:
         dbias = None
@@ -1472,12 +1615,12 @@ def _trace_key():
 # traced once a layer they doubled GPT-2 small's warm set-up on the chip
 # (55 s against 27 s, PERF.md section 6, PR 25).  XLA inlines the call.
 # ``trace_key`` (_trace_key()) is there only to be part of the cache key.
-@functools.partial(jax.jit, static_argnums=tuple(range(5, 14)))
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 15)))
 def _flash_jit(q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
-               dropout_rate, bias_grad, h_map, probs_bf16, trace_key):
+               dropout_rate, bias_grad, h_map, probs_bf16, window, trace_key):
     del trace_key
     return _flash(q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
-                  dropout_rate, bias_grad, h_map, probs_bf16)
+                  dropout_rate, bias_grad, h_map, probs_bf16, window)
 
 
 def _pack_seed(dropout_seed, row_offset, col_offset, head_offset=0):
@@ -1513,8 +1656,24 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     use_pallas: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention.  q,k,v: (B, H, S, D); optional additive bias (B, Sq, Sk).
+
+    ``k`` and ``v`` may hold fewer heads than ``q``, ``H / G``: each then
+    serves ``G`` consecutive query heads (grouped-query attention).  The
+    kernels read key/value head ``h // G`` through their BlockSpecs —
+    nothing is repeated in memory — and the backward sums ``dk``/``dv``
+    over the group inside the kernel (its inner loop walks the group's
+    query heads over one scratch accumulator).
+
+    ``window`` (with ``causal=True``) keeps for query ``i`` only the keys
+    ``j`` with ``i - j < window``.  Grid tiles wholly below the band are
+    skipped like those above the diagonal, the tiles the band's two edges
+    cross are masked, and a skipped step moves no key block
+    (:func:`_visited_key_blocks`).  A window that covers every key is
+    plain causal attention and takes its programs.  Neither grouped heads
+    nor a window go with ``bias``.
 
     ``block_q``/``block_k`` default to auto-picked sizes (the largest
     power-of-two tile of the sequence up to 512/1024 — ~2x faster than
@@ -1583,6 +1742,16 @@ def flash_attention(
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    group = _head_group(h, k.shape[1], v.shape[1])
+    if window is not None:
+        if not causal:
+            raise ValueError("window requires causal=True")
+        if window < 1:
+            raise ValueError(f"window must be positive, got {window}")
+        if window >= sk:
+            window = None       # every key a query may see is in the band
+    if bias is not None and _grouped_route(window, group):
+        raise ValueError("bias with a window or grouped heads is not supported")
     if bias is not None and bias.shape != (b, sq, sk):
         # validate eagerly: the kernel path indexes bias via b // h and
         # would read silently-wrong blocks for a mis-shaped bias
@@ -1617,11 +1786,11 @@ def flash_attention(
         return attention_ref(
             q, k, v, bias_, causal, scale,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
-            dropout_heads=dropout_heads,
+            dropout_heads=dropout_heads, window=window,
         )
     q3 = q.reshape(b * h, sq, d)
-    k3 = k.reshape(b * h, sk, d)
-    v3 = v.reshape(b * h, sk, d)
+    k3 = k.reshape(b * h // group, sk, d)
+    v3 = v.reshape(b * h // group, sk, d)
     bias3 = None
     if bias is not None:
         # UNEXPANDED (B, Sq, Sk): the kernels' BlockSpec index maps divide
@@ -1635,10 +1804,10 @@ def flash_attention(
         h_total, head0 = dropout_heads
         h_map = (h, int(h_total))
         seed3 = _pack_seed(dropout_seed, 0, 0, head0)
-    _count_tiles(b * h, sq, sk, block_q, block_k, causal)
+    _count_tiles(b * h, sq, sk, block_q, block_k, causal, window)
     out = _flash_jit(
         q3, k3, v3, bias3, seed3, float(scale), bool(causal), block_q,
         block_k, float(dropout_rate), bool(bias_grad), h_map,
-        bool(probs_bf16), _trace_key(),
+        bool(probs_bf16), window, _trace_key(),
     )
     return out.reshape(b, h, sq, d)

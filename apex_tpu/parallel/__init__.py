@@ -47,7 +47,11 @@ from apex_tpu.parallel.tensor_parallel import (  # noqa: F401
     sync_replicated_grads,
 )
 from apex_tpu.parallel.ulysses import ulysses_attention  # noqa: F401
-from apex_tpu.parallel.moe import MoEMLP, top_k_routing  # noqa: F401
+from apex_tpu.parallel.moe import (  # noqa: F401
+    ExpertShardMLP,
+    MoEMLP,
+    top_k_routing,
+)
 from apex_tpu.parallel.pipeline import (  # noqa: F401
     pipeline_apply,
     stack_stage_params,

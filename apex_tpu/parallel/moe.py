@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-__all__ = ["MoEMLP", "top_k_routing", "moe_mlp_ref"]
+__all__ = ["MoEMLP", "top_k_routing", "moe_mlp_ref", "ExpertShardMLP",
+           "SwiGLU", "sigmoid_topk_routing", "shard_dispatch"]
 
 
 def top_k_routing(
@@ -184,3 +185,244 @@ def moe_mlp_ref(x, params, num_experts, k, activation=nn.gelu):
     return jnp.einsum("ted,te->td", y_all.astype(jnp.float32), w).astype(
         x.dtype
     )
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of an expert-parallel layer: no capacity, no dropped token
+# ---------------------------------------------------------------------------
+#
+# ``MoEMLP`` above gives every expert a fixed capacity and drops what does
+# not fit.  ``ExpertShardMLP`` is the other construction (sigmoid scores,
+# top-k, a shared expert: DeepSeek-V3 / Trinity style): it is told which
+# experts it HOLDS, routes every token over ALL ``num_experts``, keeps the
+# token-slots whose expert it holds, sorts them by expert into a row buffer
+# sized for the worst case, runs the grouped SwiGLU over the held experts
+# (``ops/grouped_mm.py``) and combines the rows back, weighted, per token.
+# What the experts it does not hold would have added is simply not in its
+# result: on one chip there is no exchange, and nothing stands in for one.
+
+def sigmoid_topk_routing(logits, bias, k: int, route_norm: bool,
+                         route_scale: float):
+    """``(sel (T, k) int32, weights (T, k) float32)``: sigmoid scores over
+    all experts; the ``k`` largest of ``scores + bias`` are selected (the
+    bias steers the selection only); the weights are the selected SCORES,
+    normalised to sum to one where ``route_norm``, times ``route_scale``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * route_scale
+
+
+def shard_dispatch(sel, held: Tuple[int, int], capacity: int, tile_rows: int):
+    """Where the token-slots ``sel`` (T, k) that pick an expert in
+    ``[held[0], held[1])`` go in a row buffer of ``capacity`` rows, grouped
+    by expert in the tile-aligned layout of ``ops/grouped_mm.py``.
+
+    Returns ``(layout, slot_row (T, k), row_slot (capacity,))``: the row of
+    each slot (``capacity`` for a slot whose expert is not held) and the
+    flat slot ``t * k + j`` of each row (``T * k`` for a row that holds
+    none).  Within an expert the rows keep the slots' order.  No sort by
+    value and no scatter: a running count gives each slot its rank, one
+    argsort of the rows inverts the map."""
+    from apex_tpu.ops.grouped_mm import group_layout
+
+    t, k = sel.shape
+    lo, hi = held
+    n_held, n = hi - lo, t * k
+    local = (sel - lo).reshape(n)
+    mine = (local >= 0) & (local < n_held)
+    onehot = ((local[:, None] == jnp.arange(n_held)[None, :])
+              & mine[:, None]).astype(jnp.int32)
+    count = jnp.cumsum(onehot, axis=0)
+    sizes = count[-1]
+    rank = jnp.sum((count - onehot) * onehot, axis=-1)
+    layout = group_layout(sizes, capacity, tile_rows)
+    slot_row = jnp.where(
+        mine, layout.row_start[jnp.clip(local, 0, n_held - 1)] + rank,
+        capacity)
+    order = jnp.argsort(slot_row)            # held slots first, by row
+    row = jnp.arange(capacity, dtype=jnp.int32)
+    group = layout.tile_group[row // tile_rows]
+    within = row - layout.row_start[group]
+    live = (within < sizes[group]) & (row // tile_rows < layout.tiles_used[0])
+    first = jnp.cumsum(sizes) - sizes        # a group's first sorted slot
+    row_slot = jnp.where(
+        live, order[jnp.clip(first[group] + within, 0, n - 1)], n)
+    return layout, slot_row.reshape(t, k), row_slot.astype(jnp.int32)
+
+
+def _take_rows(x, idx):
+    """``x[idx]`` by rows, zeros where ``idx`` is out of range."""
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _rows_from_tokens(x, row_token, slot_row):
+    """``(capacity, d)``: row r holds token ``row_token[r]`` (zeros where it
+    holds none).  The gradient comes back by gathers too — token t sums the
+    rows of its slots — where the transpose of a gather would scatter."""
+    return _take_rows(x, row_token)
+
+
+def _rows_from_tokens_fwd(x, row_token, slot_row):
+    return _take_rows(x, row_token), (row_token, slot_row)
+
+
+def _sum_slots(rows, slot_row, weights=None):
+    """``(T, d)`` float32: per token the sum over its slots of the slot's
+    row (times the slot's weight)."""
+    acc = 0.0
+    for j in range(slot_row.shape[1]):
+        part = _take_rows(rows, slot_row[:, j]).astype(jnp.float32)
+        acc = acc + (part if weights is None else part * weights[:, j, None])
+    return acc
+
+
+def _int_zeros(*arrays):
+    import numpy as np
+
+    return tuple(np.zeros(a.shape, jax.dtypes.float0) for a in arrays)
+
+
+def _rows_from_tokens_bwd(res, g):
+    row_token, slot_row = res
+    return (_sum_slots(g, slot_row).astype(g.dtype),
+            *_int_zeros(row_token, slot_row))
+
+
+_rows_from_tokens.defvjp(_rows_from_tokens_fwd, _rows_from_tokens_bwd)
+
+
+@jax.custom_vjp
+def _tokens_from_rows(rows, weights, row_token, row_slot, slot_row):
+    """``(T, d)`` float32: token t's slots' rows, each times its weight."""
+    return _sum_slots(rows, slot_row, weights)
+
+
+def _tokens_from_rows_fwd(rows, weights, row_token, row_slot, slot_row):
+    return (_sum_slots(rows, slot_row, weights),
+            (rows, weights, row_token, row_slot, slot_row))
+
+
+def _tokens_from_rows_bwd(res, g):
+    rows, weights, row_token, row_slot, slot_row = res
+    row_weight = _take_rows(weights.reshape(-1), row_slot)
+    d_rows = (_take_rows(g, row_token) * row_weight[:, None]).astype(rows.dtype)
+    d_weights = jnp.stack([
+        jnp.sum(g * _take_rows(rows, slot_row[:, j]).astype(jnp.float32),
+                axis=-1)
+        for j in range(slot_row.shape[1])], axis=-1)
+    return (d_rows, d_weights.astype(weights.dtype),
+            *_int_zeros(row_token, row_slot, slot_row))
+
+
+_tokens_from_rows.defvjp(_tokens_from_rows_fwd, _tokens_from_rows_bwd)
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))``, no biases: a dense gated MLP (the
+    shared expert; a model's dense layers).  ``gate`` and ``up`` are one
+    matrix ``gate_up`` (d, 2 d_ff), gate first."""
+
+    d_ff: int
+    compute_dtype: Any = jnp.float32
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        from apex_tpu.amp.layers import Dense
+
+        dense = lambda n, name: Dense(
+            n, use_bias=False, dtype=self.compute_dtype,
+            kernel_init=self.kernel_init, name=name)
+        gate, up = jnp.split(dense(2 * self.d_ff, "gate_up")(x), 2, axis=-1)
+        return dense(x.shape[-1], "down")(nn.silu(gate) * up)
+
+
+class ExpertShardMLP(nn.Module):
+    """One chip's share of an expert-parallel SwiGLU layer.
+
+    ``x`` (T, d) -> (T, d): the shared expert's output plus, for every
+    token, the weighted outputs of those of its ``k`` routed experts that
+    lie in ``experts_held = (first, past_last)``.  The router scores all
+    ``num_experts``.  No token is dropped: the row buffer holds
+    ``T * min(k, held)`` rows — every token picking only held experts —
+    plus a tile a held expert for the alignment, and the grouped products
+    touch only the tiles that hold rows.  Summed over the shares that
+    together hold all experts, the routed parts are the whole layer's.
+
+    Parameters: ``router`` (d, num_experts), ``expert_bias`` (num_experts,)
+    (added to the scores for the selection only; zero unless a loss-free
+    balancing update moves it), ``wi`` (held, d, 2 d_ff) gate then up,
+    ``wo`` (held, d_ff, d), and the shared expert ``shared`` (a
+    :class:`SwiGLU` of width ``shared_d_ff``; 0: none).  Scopes
+    ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_shared``.
+    """
+
+    num_experts: int
+    experts_held: Tuple[int, int]
+    d_ff: int
+    k: int
+    shared_d_ff: int = 0
+    route_norm: bool = True
+    route_scale: float = 1.0
+    compute_dtype: Any = jnp.float32
+    tile_rows: Optional[int] = None
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        from apex_tpu import obs
+        from apex_tpu.ops import grouped_mm
+
+        t, d = x.shape
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+        held = hi - lo
+        tile_rows = self.tile_rows or grouped_mm.DEFAULT_TILE_ROWS
+        capacity = grouped_mm.rows_capacity(
+            t * min(self.k, held), held, tile_rows)
+        reg = obs.default_registry()
+        reg.gauge("moe.experts_held").set(held)
+        reg.gauge("moe.experts_routed_over").set(self.num_experts)
+        dt = self.compute_dtype
+
+        router = self.param("router", self.kernel_init,
+                            (d, self.num_experts), jnp.float32)
+        bias = self.param("expert_bias", nn.initializers.zeros_init(),
+                          (self.num_experts,), jnp.float32)
+        wi = self.param("wi", self.kernel_init, (held, d, 2 * self.d_ff),
+                        jnp.float32)
+        wo = self.param("wo", self.kernel_init, (held, self.d_ff, d),
+                        jnp.float32)
+
+        with jax.named_scope("moe_router"):
+            # float32 scores at full precision: the selection is a
+            # discontinuity, so the router alone does not take the MXU's
+            # single bfloat16 pass
+            logits = jnp.matmul(x.astype(jnp.float32),
+                                router.astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST)
+            sel, weights = sigmoid_topk_routing(
+                logits, bias, self.k, self.route_norm, self.route_scale)
+        with jax.named_scope("moe_dispatch"):
+            layout, slot_row, row_slot = shard_dispatch(
+                jax.lax.stop_gradient(sel), (lo, hi), capacity, tile_rows)
+            row_token = jnp.where(row_slot < t * self.k, row_slot // self.k, t)
+            rows = _rows_from_tokens(x.astype(dt), row_token, slot_row)
+        with jax.named_scope("moe_experts"):
+            gate, up = jnp.split(grouped_mm.grouped_matmul(
+                rows, wi, layout, tile_rows=tile_rows), 2, axis=-1)
+            rows = grouped_mm.grouped_matmul(
+                nn.silu(gate) * up, wo, layout, tile_rows=tile_rows)
+        with jax.named_scope("moe_dispatch"):
+            y = _tokens_from_rows(rows, weights, row_token, row_slot, slot_row)
+        if self.shared_d_ff:
+            with jax.named_scope("moe_shared"):
+                y = y + SwiGLU(self.shared_d_ff, dt, self.kernel_init,
+                               name="shared")(x.astype(dt)).astype(jnp.float32)
+        return y.astype(x.dtype)

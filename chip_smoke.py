@@ -315,12 +315,17 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     d, n, v = cfg.hidden_size // h, cfg.hidden_size, cfg.vocab_size
     rows = b * s
     f32, bf16 = jnp.float32, jnp.bfloat16
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 3))
+    root_key = jax.random.PRNGKey(seed)
+    # the first three as they always were; the later checks fold theirs in
+    keys = iter(list(jax.random.split(root_key, 3))
+                + [jax.random.fold_in(root_key, i) for i in (3, 4)])
     calls: Dict[str, int] = {}
     parity: Dict[str, Dict] = {}
     facts.update(
         shapes={"flash": [b, h, s, d], "layer_norm": [rows, n],
-                "xentropy": [rows, v]},
+                "xentropy": [rows, v],
+                "flash_window_grouped": [1, 4, 8 * s, 128],
+                "grouped_mm": [rows, n, 2 * n]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -408,6 +413,54 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     run("xentropy", xent_loss(softmax_cross_entropy),
         xent_loss(softmax_cross_entropy_ref), xent_args, xent_args, 2,
         (1e-4, (("dlogits", 2.0 ** -7),)))
+
+    # the same flash kernels on the second decoder block's call
+    # (models/afmoe.py): a sliding window, four query heads to a key/value
+    # head, head size 128, eight query tiles a head — the banded grid and
+    # the two-pass backward, dk/dv summed over the group in the kernel
+    sw, hw = 8 * s, 4
+    qw, kw, vw, w_win = seeded(lambda *ks: [
+        (normal(ki, (1, heads, sw, 128), f32) * scale).astype(dt)
+        for ki, heads, scale, dt in zip(ks, (hw, 1, 1, hw),
+                                        (0.3, 0.3, 0.3, 1.0),
+                                        (bf16, bf16, bf16, f32))
+    ])
+
+    def window_loss(fn):
+        def loss(q, k, v, w):
+            out = fn(q, k, v, causal=True, window=sw // 4)
+            return jnp.sum(out.astype(f32) * w), out
+        return loss
+
+    run("flash_window_grouped", window_loss(flash_attention),
+        window_loss(attention_ref), (qw, kw, vw, w_win),
+        tuple(t.astype(f32) for t in (qw, kw, vw)) + (w_win,), 3,
+        (3e-2, (("dq", 3e-2), ("dk", 3e-2), ("dv", 3e-2))))
+
+    # the expert layer's grouped product (ops/grouped_mm.py) against
+    # jax.lax.ragged_dot: an empty group, a single row, a group that
+    # spans many tiles and one that ends inside a tile
+    from apex_tpu.ops import grouped_mm as gmm
+
+    sizes_e = jnp.asarray([0, 1, rows // 2 + 3, rows // 4], jnp.int32)
+    cap = gmm.rows_capacity(rows, 4)
+    layout = jax.jit(lambda z: gmm.group_layout(z, cap))(sizes_e)
+    xg, wg, w_rows = seeded(lambda kx, kw_, kc, _: (
+        (normal(kx, (cap, n), f32) * 0.5).astype(bf16),
+        (normal(kw_, (4, n, 2 * n), f32) * 0.05).astype(bf16),
+        normal(kc, (cap, 2 * n), f32),
+    ))
+
+    def gmm_loss(use_pallas):
+        def loss(x, w, lay, cot):
+            out = gmm.grouped_matmul(x, w, lay, use_pallas=use_pallas)
+            return jnp.sum(out.astype(f32) * cot), out
+        return loss
+
+    run("grouped_mm", gmm_loss(None), gmm_loss(False),
+        (xg, wg, layout, w_rows),
+        (xg.astype(f32), wg.astype(f32), layout, w_rows), 3,
+        (2e-2, (("dx", 2e-2), ("dw", 2e-2))))
 
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 

@@ -593,3 +593,122 @@ def test_shared_trace_keyed_on_module_switches(rng, monkeypatch):
     assert kernels() == fused
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert kernels() == ["apex_flash_bwd_fused_acc", "apex_flash_fwd"]
+
+
+# ---------------------------------------------------------------------------
+# a sliding window and grouped key/value heads (models/afmoe.py's call)
+# ---------------------------------------------------------------------------
+
+from apex_tpu.ops import attention_ref as _ref  # noqa: E402
+
+
+def _window_case(hq, hkv, s, d=64, b=1, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(k[0], (b, hq, s, d)),
+            jax.random.normal(k[1], (b, hkv, s, d)),
+            jax.random.normal(k[2], (b, hkv, s, d)),
+            jax.random.normal(k[3], (b, hq, s, d)))
+
+
+@pytest.mark.parametrize("two_pass", [False, True], ids=["fused", "two_pass"])
+@pytest.mark.parametrize("hq,hkv,s,window,bq,bk", [
+    (4, 2, 512, 200, 128, 128),      # S not a multiple of the window
+    (8, 2, 512, None, 128, 256),     # grouped heads alone, two rows
+    (4, 1, 1024, 300, 128, 128),     # one key/value head for all
+    (2, 2, 384, 100, 128, 128),      # a window alone
+    (4, 2, 256, 100, 256, 256),      # one grid tile a head: masked whole
+    (4, 2, 512, 128, 128, 256),      # window == a query block
+], ids=["win200_g2", "g4_b2", "win300_g4", "win100", "one_tile", "win128"])
+def test_window_and_grouped_heads_match_ref(monkeypatch, hq, hkv, s, window,
+                                            bq, bk, two_pass):
+    """Forward, dq, dk, dv against ``attention_ref`` on both backward
+    routes (the combined pass and dkdv + dq)."""
+    if two_pass:
+        monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 0)
+    b = 2 if window is None else 1
+    q, k, v, do = _window_case(hq, hkv, s, b=b)
+    kw = dict(causal=True, window=window)
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v) * do)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, block_q=bq, block_k=bk, use_pallas=True, **kw)
+    ref = lambda q, k, v: _ref(q, k, v, **kw)
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    assert got[1].shape == k.shape and got[2].shape == v.shape
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+
+def test_window_with_dropout_and_grouped_heads(monkeypatch):
+    """The dropout hash is keyed on the QUERY head on every route, so the
+    kernel's mask is the reference's."""
+    q, k, v, do = _window_case(4, 2, 512)
+    kw = dict(causal=True, window=200, dropout_rate=0.1,
+              dropout_seed=jnp.int32(7))
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v, **kw) * do)
+    flash = lambda *a, **kw_: flash_attention(
+        *a, block_q=128, block_k=128, use_pallas=True, **kw_)
+    want = jax.grad(loss(_ref), (0, 1, 2))(q, k, v)
+    for max_nk in (4, 0):
+        monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", max_nk)
+        for a, b_ in zip(jax.grad(loss(flash), (0, 1, 2))(q, k, v), want):
+            np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+
+def test_window_covering_every_key_is_plain_causal():
+    """``window >= S`` lowers to the causal program: the same jaxpr."""
+    q, k, v, _ = _window_case(2, 2, 256)
+    plain = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, use_pallas=True))(q, k, v)
+    wide = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=256, use_pallas=True))(q, k, v)
+    assert str(plain) == str(wide)
+    np.testing.assert_array_equal(
+        flash_attention(q, k, v, causal=True, window=4096, use_pallas=True),
+        flash_attention(q, k, v, causal=True, use_pallas=True))
+
+
+def test_window_and_groups_refuse_what_they_do_not_do():
+    q, k, v, _ = _window_case(4, 2, 256)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=64)
+    with pytest.raises(ValueError, match="bias"):
+        flash_attention(q, k, v, bias=jnp.zeros((1, 256, 256)), causal=True)
+    with pytest.raises(ValueError, match="share"):
+        flash_attention(q, k[:, :1], v, causal=True)
+    with pytest.raises(ValueError, match="share"):
+        flash_attention(q[:, :3], k, v, causal=True)
+
+
+@pytest.mark.parametrize("s,bq,bk,window", [
+    (8192, 512, 1024, 2048),     # trinity-mini's window layers
+    (8192, 512, 1024, None),     # and its full layer
+    (512, 128, 128, 200),
+    (512, 128, 128, 1),
+])
+def test_census_counts_the_band(s, bq, bk, window):
+    """Visited tiles are those that hold a (row, column) of the band; every
+    one of them is masked whole (the one-piece body)."""
+    total, visited, masked = flash_tile_census(s, s, bq, bk, True, window)
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    band = (j <= i) & ((i - j < window) if window else True)
+    tiles = band.reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3))
+    assert (total, visited, masked) == (tiles.size, tiles.sum(), tiles.sum())
+    if window == 2048 and s == 8192:
+        # 16 query tiles x 8 key tiles: the band reaches 2 or 3 key tiles
+        assert (total, visited) == (128, 42)
+
+
+def test_window_call_counts_its_band_for_query_heads():
+    from apex_tpu import obs
+
+    reg = obs.default_registry()
+    before = {n: reg.counter("ops.flash.tiles_" + n).value
+              for n in ("total", "visited", "masked")}
+    q, k, v, _ = _window_case(4, 2, 512)
+    flash_attention(q, k, v, causal=True, window=200, block_q=128,
+                    block_k=128, use_pallas=True)
+    total, visited, masked = flash_tile_census(512, 512, 128, 128, True, 200)
+    for n, want in zip(("total", "visited", "masked"), (total, visited, masked)):
+        assert reg.counter("ops.flash.tiles_" + n).value - before[n] == 4 * want

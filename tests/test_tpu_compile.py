@@ -122,6 +122,21 @@ def _named_case(kernel):
                 [rows, vec, rows])
     if kernel == "apex_paged_attn":
         return _paged_fused_case(12, 8, False)
+    if kernel.startswith("apex_gmm"):
+        # the expert layer's products at trinity-mini's widths: 16 held
+        # experts, the worst-case row buffer of one 8192-token row
+        from apex_tpu.ops import grouped_mm as gmm
+
+        cap = gmm.rows_capacity(8 * 8192, 16)
+
+        def loss(x, w, sizes):
+            with jax.named_scope("moe_experts"):
+                out = gmm.grouped_matmul(x, w, gmm.group_layout(sizes, cap))
+            return jnp.sum(out.astype(F32))
+
+        # value and gradients: the forward product, then dx and dw
+        return (jax.value_and_grad(loss, argnums=(0, 1)),
+                [((cap, 2048), BF16), ((16, 2048, 2048), BF16), ((16,), I32)])
     if kernel.startswith("apex_xent_"):
         from apex_tpu.ops import softmax_cross_entropy
 
@@ -137,7 +152,7 @@ def _named_case(kernel):
     "apex_flash_fwd", "apex_flash_bwd_fused", "apex_flash_bwd_dkdv",
     "apex_flash_bwd_dq", "apex_flash_bwd_dq_dbias", "apex_ln_fwd",
     "apex_ln_bwd_dx", "apex_ln_bwd_dx_dwdb", "apex_xent_fwd",
-    "apex_xent_bwd", "apex_paged_attn",
+    "apex_xent_bwd", "apex_paged_attn", "apex_gmm", "apex_gmm_dw",
 ])
 def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     """The custom call's HLO instruction — what a device trace names the
@@ -205,6 +220,25 @@ def test_flash_fwd_bwd_compiles(chip, as_tpu, shape, causal, dropout):
         (shape, BF16), (shape, BF16), (shape, BF16), ((), I32),
     )
     assert n >= 2  # the forward and the combined dk+dv+dq backward
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_window_grouped_heads_compiles(chip, as_tpu, window):
+    """Trinity-Mini's attention call: 32 query heads to 4 key/value heads
+    of size 128 at 8192 positions — the banded grid, key/value blocks read
+    through ``h // 8``, the two-pass backward with dk/dv summed over the
+    group inside the kernel (their shapes are the key/value heads')."""
+    from apex_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        with jax.named_scope("attn_window"):
+            out = flash_attention(q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(F32))
+
+    q, kv = ((1, 32, 8192, 128), BF16), ((1, 4, 8192, 128), BF16)
+    names = _mosaic_names(chip, jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert names == ["apex_flash_fwd", "apex_flash_bwd_dkdv",
+                     "apex_flash_bwd_dq"], names
 
 
 # -- fused LayerNorm: forward, dx, dx + dgamma/dbeta epilogue ---------------
