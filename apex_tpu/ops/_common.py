@@ -48,7 +48,7 @@ def force_pallas(value: Optional[bool]):
 #: name whatever scope called the kernel.  Readers of a trace match the
 #: family prefixes (``apex_flash_fwd``, ``apex_flash_bwd``, ``apex_ln_``,
 #: ``apex_xent_``, ``apex_gmm``), so a variant can be added without
-#: touching them.
+#: touching them — and a new family's names must contain none of them.
 KERNEL_NAMES = (
     "apex_paged_attn",
     "apex_flash_fwd",
@@ -68,6 +68,10 @@ KERNEL_NAMES = (
     "apex_conv_bn_matmul_bwd",
     "apex_gmm_dw",
     "apex_gmm",
+    "apex_moe_records",
+    "apex_moe_gather",
+    "apex_moe_combine_dw",
+    "apex_moe_combine",
 )
 
 

@@ -32,8 +32,9 @@ experts together.
 """
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -253,21 +254,55 @@ def shard_dispatch(sel, held: Tuple[int, int], capacity: int, tile_rows: int):
     return layout, slot_row.reshape(t, k), row_slot.astype(jnp.int32)
 
 
+def _block_starts(sel, held: Tuple[int, int], layout, block: int):
+    """``(T / block + 1, held)``: the rows ``starts[b, e] .. starts[b + 1,
+    e]`` of the buffer :func:`shard_dispatch` lays out are those of held
+    expert ``e`` whose token lies in ``[b * block, (b + 1) * block)`` — a
+    group's rows keep the token order, so a block of tokens owns one range
+    of each group (``ops/moe_rows.py::combine_rows`` walks them)."""
+    t, k = sel.shape
+    # experts leading, a block's slots along the lanes: one compare and one
+    # lane reduction
+    picks = (sel.reshape(1, t // block, block * k)
+             == jnp.arange(*held)[:, None, None]).astype(jnp.int32).sum(-1)
+    before = jnp.cumsum(picks.T, axis=0)
+    return layout.row_start[None] + jnp.concatenate(
+        [jnp.zeros_like(before[:1]), before])
+
+
 def _take_rows(x, idx):
     """``x[idx]`` by rows, zeros where ``idx`` is out of range."""
     return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
 
 
-@jax.custom_vjp
-def _rows_from_tokens(x, row_token, slot_row):
-    """``(capacity, d)``: row r holds token ``row_token[r]`` (zeros where it
-    holds none).  The gradient comes back by gathers too — token t sums the
-    rows of its slots — where the transpose of a gather would scatter."""
-    return _take_rows(x, row_token)
+# The two movements between the token order and the row buffer.  Each is a
+# ``custom_vjp`` so that its gradient comes back by gathers too (the transpose
+# of a gather would scatter).  ``tile_rows`` None: every pass is a
+# ``jnp.take`` over the whole buffer and every slot, rows that hold no token
+# come out zero — the path off the TPU, and the oracle of the kernels' tests.
+# ``tile_rows`` given: ``ops/moe_rows.py``'s kernels visit the live tiles and
+# the held slots only, and the tiles past ``layout.tiles_used`` are UNDEFINED.
+
+class _Routing(NamedTuple):
+    """Where the slots went (all int32; no gradient)."""
+    layout: Any              # ops/grouped_mm.py::GroupLayout
+    slot_row: jax.Array      # (T, k) row of each slot, capacity: not held
+    row_slot: jax.Array      # (capacity,) flat slot of each row, T k: none
+    row_token: jax.Array     # (capacity,) token of each row, T: none
+    starts: Optional[jax.Array]   # _block_starts, for the kernels alone
 
 
-def _rows_from_tokens_fwd(x, row_token, slot_row):
-    return _take_rows(x, row_token), (row_token, slot_row)
+def _route(sel, held: Tuple[int, int], capacity: int, tile_rows: int,
+           block: Optional[int] = None) -> _Routing:
+    """:func:`shard_dispatch` of ``sel`` and what follows from it;
+    ``block`` (``ops/moe_rows.py::combine_block``) where the kernels will
+    move the rows."""
+    t, k = sel.shape
+    layout, slot_row, row_slot = shard_dispatch(sel, held, capacity, tile_rows)
+    return _Routing(
+        layout, slot_row, row_slot,
+        jnp.where(row_slot < t * k, row_slot // k, t),
+        None if block is None else _block_starts(sel, held, layout, block))
 
 
 def _sum_slots(rows, slot_row, weights=None):
@@ -280,42 +315,92 @@ def _sum_slots(rows, slot_row, weights=None):
     return acc
 
 
-def _int_zeros(*arrays):
+def _no_grad(tree):
     import numpy as np
 
-    return tuple(np.zeros(a.shape, jax.dtypes.float0) for a in arrays)
+    return jax.tree_util.tree_map(
+        lambda a: np.zeros(a.shape, jax.dtypes.float0), tree)
 
 
-def _rows_from_tokens_bwd(res, g):
-    row_token, slot_row = res
-    return (_sum_slots(g, slot_row).astype(g.dtype),
-            *_int_zeros(row_token, slot_row))
+def _live_records(rows, routing: _Routing, tile_rows: int):
+    from apex_tpu.ops import moe_rows
+
+    return moe_rows.live_records(rows, routing.layout, tile_rows=tile_rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_from_tokens(x, routing: _Routing, tile_rows):
+    """``(capacity, d)``: row r holds token ``row_token[r]``; a row that
+    holds none is zero (``tile_rows`` None) or, past the live tiles,
+    undefined."""
+    if tile_rows is None:
+        return _take_rows(x, routing.row_token)
+    from apex_tpu.ops import moe_rows
+
+    return moe_rows.gather_rows(
+        moe_rows.records(x), routing.row_token, routing.layout,
+        tile_rows=tile_rows, out_dtype=x.dtype)
+
+
+def _rows_from_tokens_fwd(x, routing, tile_rows):
+    return _rows_from_tokens(x, routing, tile_rows), routing
+
+
+def _rows_from_tokens_bwd(tile_rows, routing, g):
+    if tile_rows is None:
+        dx = _sum_slots(g, routing.slot_row).astype(g.dtype)
+    else:
+        from apex_tpu.ops import moe_rows
+
+        dx = moe_rows.combine_rows(
+            _live_records(g, routing, tile_rows), routing.slot_row,
+            routing.row_slot, routing.starts, out_dtype=g.dtype)
+    return dx, _no_grad(routing)
 
 
 _rows_from_tokens.defvjp(_rows_from_tokens_fwd, _rows_from_tokens_bwd)
 
 
-@jax.custom_vjp
-def _tokens_from_rows(rows, weights, row_token, row_slot, slot_row):
-    """``(T, d)`` float32: token t's slots' rows, each times its weight."""
-    return _sum_slots(rows, slot_row, weights)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tokens_from_rows(rows, weights, routing: _Routing, tile_rows):
+    """``(T, d)`` float32: token t's held slots' rows, each times its
+    weight, summed in slot order."""
+    if tile_rows is None:
+        return _sum_slots(rows, routing.slot_row, weights)
+    from apex_tpu.ops import moe_rows
+
+    return moe_rows.combine_rows(
+        _live_records(rows, routing, tile_rows), routing.slot_row,
+        routing.row_slot, routing.starts, weights=weights)
 
 
-def _tokens_from_rows_fwd(rows, weights, row_token, row_slot, slot_row):
-    return (_sum_slots(rows, slot_row, weights),
-            (rows, weights, row_token, row_slot, slot_row))
+def _tokens_from_rows_fwd(rows, weights, routing, tile_rows):
+    return (_tokens_from_rows(rows, weights, routing, tile_rows),
+            (rows, weights, routing))
 
 
-def _tokens_from_rows_bwd(res, g):
-    rows, weights, row_token, row_slot, slot_row = res
-    row_weight = _take_rows(weights.reshape(-1), row_slot)
-    d_rows = (_take_rows(g, row_token) * row_weight[:, None]).astype(rows.dtype)
-    d_weights = jnp.stack([
-        jnp.sum(g * _take_rows(rows, slot_row[:, j]).astype(jnp.float32),
-                axis=-1)
-        for j in range(slot_row.shape[1])], axis=-1)
-    return (d_rows, d_weights.astype(weights.dtype),
-            *_int_zeros(row_token, row_slot, slot_row))
+def _tokens_from_rows_bwd(tile_rows, res, g):
+    rows, weights, routing = res
+    slot_row = routing.slot_row
+    if tile_rows is None:
+        row_weight = _take_rows(weights.reshape(-1), routing.row_slot)
+        d_rows = (_take_rows(g, routing.row_token)
+                  * row_weight[:, None]).astype(rows.dtype)
+        d_weights = jnp.stack([
+            jnp.sum(g * _take_rows(rows, slot_row[:, j]).astype(jnp.float32),
+                    axis=-1)
+            for j in range(slot_row.shape[1])], axis=-1)
+    else:
+        from apex_tpu.ops import moe_rows
+
+        d_rows = moe_rows.gather_rows(
+            moe_rows.records(g), routing.row_token, routing.layout,
+            tile_rows=tile_rows, out_dtype=rows.dtype,
+            weights=weights.reshape(-1), weight_index=routing.row_slot)
+        d_weights = moe_rows.slot_dots(
+            _live_records(rows, routing, tile_rows), slot_row,
+            routing.row_slot, routing.starts, g)
+    return d_rows, d_weights.astype(weights.dtype), _no_grad(routing)
 
 
 _tokens_from_rows.defvjp(_tokens_from_rows_fwd, _tokens_from_rows_bwd)
@@ -359,6 +444,17 @@ class ExpertShardMLP(nn.Module):
     ``wo`` (held, d_ff, d), and the shared expert ``shared`` (a
     :class:`SwiGLU` of width ``shared_d_ff``; 0: none).  Scopes
     ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_shared``.
+
+    Rows and tokens change places by ``ops/moe_rows.py``'s kernels on the
+    TPU where the shapes tile (``moe_rows.supported``), else by
+    ``jnp.take``; the gauge ``moe.dispatch.kernels`` says which was traced.
+    On the kernel path a pass costs what the routing made live, and the
+    buffer's tiles past ``layout.tiles_used`` are UNDEFINED, not zero, in
+    ``rows`` and in its gradient: no pass writes them.  Every reader
+    ignores them — ``apex_gmm`` fetches no tile past ``tiles_used`` and
+    writes zeros there, ``apex_gmm_dw`` skips them, the combine reads held
+    slots only — and whoever reads the buffer next has to as well.  (A live
+    tile's rows past its ``tile_valid`` are zeros on both paths.)
     """
 
     num_experts: int
@@ -375,7 +471,8 @@ class ExpertShardMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         from apex_tpu import obs
-        from apex_tpu.ops import grouped_mm
+        from apex_tpu.ops import grouped_mm, moe_rows
+        from apex_tpu.ops._common import pallas_default
 
         t, d = x.shape
         lo, hi = self.experts_held
@@ -386,10 +483,16 @@ class ExpertShardMLP(nn.Module):
         tile_rows = self.tile_rows or grouped_mm.DEFAULT_TILE_ROWS
         capacity = grouped_mm.rows_capacity(
             t * min(self.k, held), held, tile_rows)
+        dt = self.compute_dtype
+        kernels = pallas_default(
+            moe_rows.supported(t, self.k, d, tile_rows, dt))
+        rows_tile = tile_rows if kernels else None
         reg = obs.default_registry()
         reg.gauge("moe.experts_held").set(held)
         reg.gauge("moe.experts_routed_over").set(self.num_experts)
-        dt = self.compute_dtype
+        reg.gauge("moe.dispatch.rows_capacity").set(capacity)
+        reg.gauge("moe.dispatch.slots").set(t * self.k)
+        reg.gauge("moe.dispatch.kernels").set(int(kernels))
 
         router = self.param("router", self.kernel_init,
                             (d, self.num_experts), jnp.float32)
@@ -410,17 +513,18 @@ class ExpertShardMLP(nn.Module):
             sel, weights = sigmoid_topk_routing(
                 logits, bias, self.k, self.route_norm, self.route_scale)
         with jax.named_scope("moe_dispatch"):
-            layout, slot_row, row_slot = shard_dispatch(
-                jax.lax.stop_gradient(sel), (lo, hi), capacity, tile_rows)
-            row_token = jnp.where(row_slot < t * self.k, row_slot // self.k, t)
-            rows = _rows_from_tokens(x.astype(dt), row_token, slot_row)
+            routing = _route(
+                jax.lax.stop_gradient(sel), (lo, hi), capacity, tile_rows,
+                moe_rows.combine_block(t, self.k, d) if kernels else None)
+            layout = routing.layout
+            rows = _rows_from_tokens(x.astype(dt), routing, rows_tile)
         with jax.named_scope("moe_experts"):
             gate, up = jnp.split(grouped_mm.grouped_matmul(
                 rows, wi, layout, tile_rows=tile_rows), 2, axis=-1)
             rows = grouped_mm.grouped_matmul(
                 nn.silu(gate) * up, wo, layout, tile_rows=tile_rows)
         with jax.named_scope("moe_dispatch"):
-            y = _tokens_from_rows(rows, weights, row_token, row_slot, slot_row)
+            y = _tokens_from_rows(rows, weights, routing, rows_tile)
         if self.shared_d_ff:
             with jax.named_scope("moe_shared"):
                 y = y + SwiGLU(self.shared_d_ff, dt, self.kernel_init,

@@ -318,14 +318,15 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     root_key = jax.random.PRNGKey(seed)
     # the first three as they always were; the later checks fold theirs in
     keys = iter(list(jax.random.split(root_key, 3))
-                + [jax.random.fold_in(root_key, i) for i in (3, 4)])
+                + [jax.random.fold_in(root_key, i) for i in (3, 4, 5)])
     calls: Dict[str, int] = {}
     parity: Dict[str, Dict] = {}
     facts.update(
         shapes={"flash": [b, h, s, d], "layer_norm": [rows, n],
                 "xentropy": [rows, v],
                 "flash_window_grouped": [1, 4, 8 * s, 128],
-                "grouped_mm": [rows, n, 2 * n]},
+                "grouped_mm": [rows, n, 2 * n],
+                "moe_dispatch": [rows, -(-n // 1024) * 1024]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -462,7 +463,39 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
         (xg.astype(f32), wg.astype(f32), layout, w_rows), 3,
         (2e-2, (("dx", 2e-2), ("dw", 2e-2))))
 
+    # the expert layer's row movement (ops/moe_rows.py) against the
+    # jnp.take path of parallel/moe.py: tokens into the worst-case row
+    # buffer and back, weighted, under one uneven routing (4 of 16 experts
+    # held, 4 slots a token, skewed scores).  A record is whole tiles from
+    # 1024 features on
+    from apex_tpu.ops import moe_rows
+    from apex_tpu.parallel import moe
+
+    dm, slots, held, tile = -(-n // 1024) * 1024, 4, (0, 4), gmm.DEFAULT_TILE_ROWS
+    cap_m = gmm.rows_capacity(rows * slots, held[1], tile)
+    xm, wm, cot_m, routing = seeded(lambda kx, kw_, kc, kr: (
+        (normal(kx, (rows, dm), f32) * 0.5).astype(bf16),
+        jax.random.uniform(kw_, (rows, slots), f32),
+        normal(kc, (rows, dm), f32),
+        moe._route(jax.lax.top_k(
+            normal(kr, (rows, 16), f32) + jnp.arange(16.0) / 4, slots)[1],
+            held, cap_m, tile, moe_rows.combine_block(rows, slots, dm)),
+    ))
+
+    def dispatch_loss(tile_rows):
+        def loss(x, w, routing, cot):
+            out = moe._tokens_from_rows(
+                moe._rows_from_tokens(x, routing, tile_rows), w, routing,
+                tile_rows)
+            return jnp.sum(out * cot), out
+        return loss
+
+    run("moe_dispatch", dispatch_loss(tile), dispatch_loss(None),
+        (xm, wm, routing, cot_m), (xm.astype(f32), wm, routing, cot_m), 6,
+        (1e-5, (("dx", 2e-2), ("dweights", 2e-2))))
+
     facts["max_err"] = max(p["max_err"] for p in parity.values())
+
 
 
 # ---------------------------------------------------------------------------

@@ -68,7 +68,10 @@ def rel_gap(a, b):
 @pytest.mark.parametrize("remat", ["none", "full_block"])
 def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
     """Logits, loss and every leaf's gradient; with the Pallas kernels
-    (interpret mode) and with their off-TPU paths; with per-block remat."""
+    (interpret mode: the grouped products and the row movement of
+    ``ops/moe_rows.py``) and with their off-TPU paths; with per-block remat."""
+    from apex_tpu import obs
+
     cfg = tiny_cfg(remat_policy=remat)
     rcfg, w = seeded(cfg)
     ids, labels = batch()
@@ -86,6 +89,7 @@ def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
     with force_pallas(kernels):
         logits = model.apply({"params": params}, ids)
         loss, grads = jax.value_and_grad(program_loss)(params)
+    assert obs.default_registry().get("moe.dispatch.kernels").value == kernels
     assert rel_gap(logits, ref.logits(w, ids, rcfg)) < 1e-5
     want_loss, want = jax.value_and_grad(reference_loss)(w)
     assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
