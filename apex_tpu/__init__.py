@@ -103,7 +103,7 @@ Apex (reference: /root/reference, see SURVEY.md):
 - :mod:`apex_tpu.chip` — what a program settles before it says it ran
   on the chip: ``require_tpu`` (no TPU, no run) and
   ``compile_cache_dir`` (the persistent compile cache, placed from
-  outside); used by ``chip_smoke.py`` and ``bench.py``'s chip metrics.
+  outside); used by ``chip_smoke.py`` and the benchmark's harness.
 """
 
 __version__ = "0.5.0"

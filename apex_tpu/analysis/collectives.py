@@ -17,16 +17,15 @@ Two layers:
   :func:`collective_summary`, :func:`gradient_collective_bytes`) and
   the PR-2 boundary contract (:func:`assert_boundary_collectives`);
 - declarative :class:`CollectiveBudget` checks — per-program expected
-  counts/bytes per op class, consumed by ``tests/test_analysis.py``,
-  ``tools/lint_graphs.py`` and ``bench.py`` so a new program states its
+  counts/bytes per op class, consumed by ``tests/test_analysis.py``
+  and ``tools/lint_graphs.py`` so a new program states its
   communication contract as data instead of a bespoke assertion.
 
 Used by:
 - tests/test_inspect_hlo.py (tier-1): exactly one gradient all-reduce
   (or one reduce-scatter + all-gather pair for ``zero=True``) per
-  boundary, for M in {2, 4}.
-- bench.py's ``accum``/``lint`` metrics: collective-bytes-per-sample
-  and budget status in the artifact.
+  boundary, for M in {2, 4}, and collective bytes per sample falling
+  M-fold.
 
 CLI (via the shim)::
 

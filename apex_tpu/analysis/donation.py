@@ -268,6 +268,10 @@ class _DonatedLeaf:
             "BEFORE donating if the tree must be reused"
         )
 
+    # a property: jax 0.9 raises its own ValueError on any object that
+    # merely HAS ``__jax_array__`` as it abstractifies a jit argument,
+    # before calling it — the ``hasattr`` probe itself must raise
+    @property
     def __jax_array__(self):
         self._raise("__jax_array__")
 

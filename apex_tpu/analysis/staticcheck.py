@@ -73,7 +73,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(_HERE))
 
 #: the trees the analyzer sweeps (plus EXTRA_FILES at the repo root)
 SCAN_ROOTS: Tuple[str, ...] = ("apex_tpu", "tools", "tests")
-EXTRA_FILES: Tuple[str, ...] = ("bench.py", "chip_smoke.py")
+EXTRA_FILES: Tuple[str, ...] = ("chip_smoke.py",)
 
 #: modules whose ENTIRE content must be wall-clock-free: everything
 #: they emit feeds a digest, a byte-replayed postmortem, or a seeded

@@ -1,9 +1,8 @@
 """What a program settles before it says it ran on the chip.
 
 Two questions, asked once at start-up by ``chip_smoke.py`` and by the
-chip metrics of ``bench.py`` (the third — were the kernels really
-compiled in — is :func:`apex_tpu.ops.mosaic_call_count`, asked of each
-executable):
+benchmark's runs (the third — were the kernels really compiled in — is
+:func:`apex_tpu.ops.mosaic_call_count`, asked of each executable):
 
 - :func:`require_tpu` — which device is this?  Everything in the
   library runs on the CPU too (the test suite depends on it), so a run
@@ -16,8 +15,8 @@ executable):
 
 One process drives all the chips of a host: a process that has touched
 JAX holds them, and a child that needs them then fails or hangs.  So a
-parent either stays off JAX (``bench.py``'s orchestrator) or does
-everything itself (``chip_smoke.py``).
+parent either stays off JAX and leaves the chip to its children, or
+does everything itself (``chip_smoke.py``, ``benchmark/run.py``).
 """
 from __future__ import annotations
 

@@ -88,9 +88,6 @@ KNOBS: List[EnvKnob] = [
     EnvKnob("APEX_TPU_FUSED_DQ_COPY_THROUGH", "0",
             "1 makes causally-skipped tiles of the aliased-dq path "
             "explicitly copy the running dq block through."),
-    EnvKnob("APEX_TPU_PROBS_BF16", "0",
-            "1 opts benches into half-precision-probability flash "
-            "attention."),
     EnvKnob("APEX_TPU_PAGED_FUSED", "0",
             "1 enables the fused paged-attention serving kernel "
             "(page gather + int8 dequant + scores in one pass; "
@@ -199,10 +196,6 @@ KNOBS: List[EnvKnob] = [
     EnvKnob("APEX_TPU_DEPLOY_DRAIN_ROUNDS", None,
             "Per-host drain budget (fleet rounds) before a "
             "promotion's weight swap fires; unset = wait until calm."),
-    # -- bench ----------------------------------------------------------
-    EnvKnob("APEX_TPU_BENCH_BUDGET_S", "7200",
-            "bench.py wall-clock budget: the orchestrator stops "
-            "launching new metrics once spent."),
 ]
 
 REGISTRY: Dict[str, EnvKnob] = {k.name: k for k in KNOBS}

@@ -85,8 +85,8 @@ def flightrec_enabled() -> bool:
 
 def set_flightrec_override(value: Optional[bool]) -> None:
     """Force the recorder on/off regardless of the env (None = defer
-    to ``APEX_TPU_FLIGHTREC`` again).  The bench's A/B lever — the
-    obs master switch still wins when it is off."""
+    to ``APEX_TPU_FLIGHTREC`` again).  The obs master switch still
+    wins when it is off."""
     global _OVERRIDE
     _OVERRIDE = value
 

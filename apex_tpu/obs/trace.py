@@ -89,7 +89,7 @@ def enabled() -> bool:
 
 def set_enabled_override(value: Optional[bool]) -> None:
     """Force instrumentation on/off regardless of the env (None =
-    defer to ``APEX_TPU_OBS`` again).  The bench's A/B lever."""
+    defer to ``APEX_TPU_OBS`` again).  The tests' A/B lever."""
     global _ENABLED_OVERRIDE
     _ENABLED_OVERRIDE = value
 

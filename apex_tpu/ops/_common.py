@@ -118,10 +118,9 @@ def mosaic_call_count(compiled) -> int:
     The two gates above pick the reference or the interpreter off-TPU
     without a word, and a shape gate can pick the reference on it.  What
     was COMPILED is the one place that shows the choice, so a program
-    that claims the chip (``chip_smoke.py``, ``bench.py``'s chip
-    metrics) checks the device up front (:func:`apex_tpu.chip.
-    require_tpu`) and then this count: zero where a kernel was promised
-    means it ran interpreted or as its reference."""
+    that claims the chip (``chip_smoke.py``) checks the device up front
+    (:func:`apex_tpu.chip.require_tpu`) and then this count: zero where a
+    kernel was promised means it ran interpreted or as its reference."""
     return compiled.as_text().count("tpu_custom_call")
 
 

@@ -33,7 +33,8 @@ partials — psum them over the data axis exactly like
 the next ``bn_relu_matmul``.
 
 These kernels are NOT wired into models/resnet.py: the measured attempt
-(tools/bench_conv_bn.py, PERF.md r3 "Conv+BN epilogue fusion") landed at
+(round 3, on another machine; the script and its record are in git
+history before PR 28) landed at
 ~parity with XLA's own fusion at RN50 shapes on v5e, so the model keeps
 the plain XLA path.  The kernels stay as tested library building blocks
 for K-wide memory-bound matmul chains.

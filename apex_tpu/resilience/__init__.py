@@ -28,8 +28,8 @@ registry, PR 5) into actively self-healing wrappers:
 Every recovery lands in ``resilience.*`` obs counters and the
 ``resilience.recovery_ms`` histogram; ``tools/trace_report.py`` renders
 the recovery ledger, ``tools/lint_graphs.py`` pins the retry/replay
-paths compile-free, and ``bench.py``'s hardware-free ``resilience``
-metric records goodput + recovery latency under a seeded plan.
+paths compile-free, and ``tests/test_resilience.py`` holds a seeded
+chaos run to the clean run's tokens and bitwise-equal parameters.
 Kill switch: ``APEX_TPU_RESILIENCE=0`` (wrappers become transparent
 pass-throughs — no retries, no rollback, faults propagate).
 """

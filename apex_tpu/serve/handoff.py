@@ -23,8 +23,7 @@ to the recompute fallback — which is what makes a lost transfer
 recoverable.
 
 No jax import here: a handoff is plain host data (numpy + json), so
-the bench orchestrator's jax-free rule holds and the container can be
-parsed by a process that never touches a device.
+the container can be parsed by a process that never touches a device.
 """
 from __future__ import annotations
 

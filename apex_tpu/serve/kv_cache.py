@@ -83,7 +83,7 @@ class KVCache(NamedTuple):
 
 def cache_bytes_per_slot(cfg, max_len: int, dtype=None) -> int:
     """Shape-only bytes/slot for a :class:`GPTConfig` — the admission
-    planner's figure, no arrays needed (bench.py's ``decode`` metric)."""
+    planner's figure, no arrays needed."""
     d = cfg.hidden_size // cfg.num_heads
     per = cfg.num_layers * cfg.num_heads * max_len * d
     return 2 * per * jnp.dtype(dtype or cfg.compute_dtype).itemsize
@@ -638,8 +638,7 @@ class PagePool:
 
 def paged_cache_bytes(cfg, pages: int, page_len: int, dtype=None) -> int:
     """Shape-only bytes for ``pages`` pool pages — the paged analog of
-    :func:`cache_bytes_per_slot` (bench.py's ``decode`` metric compares
-    the two layouts' bytes per ACTIVE token with it).  int8 includes the
+    :func:`cache_bytes_per_slot`.  int8 includes the
     per-token fp32 scale columns, so the planner figure is honest about
     the quantization overhead (4/head_dim per stored byte)."""
     d = cfg.hidden_size // cfg.num_heads

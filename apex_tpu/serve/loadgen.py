@@ -36,7 +36,7 @@ dispatch boundary (jumping over idle gaps to the next arrival).  Every
 lifecycle timestamp — TTFT, ITL, queue delay, deadline expiry, the SLO
 tracker's window rotation — is then a pure function of the seed and
 the scheduling policy: two runs of the same plan produce byte-identical
-:class:`LoadReport`\\ s (pinned by the bench ``load`` metric), and a
+:class:`LoadReport`\\ s (pinned by ``tests/test_slo.py``), and a
 policy A/B (FIFO vs SLO-aware admission) is noise-free.
 
 The same generator drives a plain
@@ -46,8 +46,7 @@ or a :class:`~apex_tpu.fleet.FleetRouter` (per-host registries merge)
 — targets differ only in which ``submit`` keywords they accept, which
 :class:`LoadGen` inspects once.
 
-This module never imports jax: plans are plain host data, and the
-bench orchestrator's jax-free rule stays intact.
+This module never imports jax: plans are plain host data.
 """
 from __future__ import annotations
 
@@ -126,8 +125,8 @@ class TrafficPlan:
     Build one with :meth:`from_seed`; the plan is plain data
     (``requests`` is a list of :class:`LoadRequest`), serializes
     deterministically, and can be replayed against any number of
-    targets/policies — the A/B discipline every scheduling claim in
-    ``bench.py``'s ``load`` metric rests on.
+    targets/policies — the A/B discipline the scheduling tests
+    (``tests/test_slo.py``, ``tests/test_fleet.py``) rest on.
     """
 
     def __init__(self, requests: List[LoadRequest], meta: dict):

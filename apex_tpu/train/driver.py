@@ -452,8 +452,8 @@ class FusedTrainDriver:
 
     def lower(self, carry: PyTree, batches: Optional[PyTree] = None):
         """``jax.jit(...).lower(...)`` of the window program — for HLO
-        inspection (bench.py asserts Mosaic custom calls are present) and
-        AOT ``.compile()``."""
+        inspection (``chip_smoke.py`` counts the Mosaic custom calls in
+        it) and AOT ``.compile()``."""
         self._resolve_carry_spec(carry)
         if batches is None:
             return self._program(self.steps_per_dispatch, False).lower(

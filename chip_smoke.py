@@ -9,9 +9,8 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   LayerNorm with the dgamma/dbeta epilogue, fused softmax-xentropy),
   COMPILED, forward and gradients, against its own jnp reference at the
   tolerance tiers of the kernels' tests;
-- ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, built
-  the way ``bench.py``'s GPT-2 metric builds it: three windows on a fixed
-  seeded batch;
+- ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, with
+  dropout on: three windows on a fixed seeded batch;
 - ``serve`` — the params that phase produced, through ``GPTDecoder`` +
   ``ServeEngine`` with the engine's own defaults (paged, bf16 cache, the
   default K): six requests across the prefill buckets, two sharing a
@@ -90,8 +89,7 @@ class Sizes:
 
 FULL = Sizes(
     model={}, ctx=1024, kernel_batch=8,
-    # batch / steps_per_dispatch as bench.py's GPT-2 metric (GPT_BATCH,
-    # GPT_SCAN)
+    # batch / steps_per_dispatch as the `gpt2-small.train` cell
     train_batch=16, steps_per_dispatch=10, windows=3,
     slots=8, prompt_lens=(5, 64, 200, 700), prefix_len=256,
     prefix_tails=(17, 40), new_tokens=32, compared=2,
@@ -216,7 +214,7 @@ def _compare(name: str, got, ref, tol: float, out: Dict) -> None:
 
 
 # ---------------------------------------------------------------------------
-# the model, built the way bench.py's GPT-2 metric builds it
+# the model: GPT-2 small, AMP + fused_adam, a fixed seeded batch
 # ---------------------------------------------------------------------------
 
 def _init_params(model, seed: int, *args, **kw):
@@ -240,7 +238,7 @@ def _train_setup(sizes: Sizes, seed: int, opt_level: str, *, batch: int,
     """``(step_fn, carry, (ids, labels), cfg, amp_)`` for one GPT-2 causal-LM
     train step: AMP ``opt_level`` + ``fused_adam``, a fixed seeded batch.
     ``step_fn(carry, batch)`` trains on ``batch`` — or on the closure's
-    batch when the driver passes None, as bench.py's metric does.  With
+    batch when the driver passes None.  With
     ``ddp`` the per-shard grads go through its allreduce."""
     import apex_tpu.amp as amp
     from apex_tpu.models.gpt import GPTLM

@@ -176,19 +176,20 @@ class TestDonationChecker:
         assert analysis.assert_donated(donated, (c, b), (0,)).ok
 
     def test_seeded_unaliasable_leaf(self):
-        """A dtype-changing output silently drops ONE leaf's donation
-        (jax warns and keeps both buffers) — the checker pinpoints the
-        leaf by path."""
+        """A dtype-changing output silently drops ONE leaf's donation —
+        the checker pinpoints the leaf by path.  jax 0.9 says nothing:
+        an output of the same element count makes the unmatched buffer
+        an XLA ``buffer_donor`` instead of a warning, so the compiled
+        header is the only witness."""
         tree = {"w": jnp.ones((64, 64), jnp.float32),
                 "m": jnp.ones((64, 64), jnp.float32)}
 
         def narrowing(t):
             return {"w": t["w"] * 2, "m": t["m"].astype(jnp.bfloat16)}
 
-        with pytest.warns(UserWarning, match="donated buffers"):
-            compiled = jax.jit(
-                narrowing, donate_argnums=(0,)
-            ).lower(tree).compile()
+        compiled = jax.jit(
+            narrowing, donate_argnums=(0,)
+        ).lower(tree).compile()
         report = analysis.check_donation(compiled, (tree,), (0,))
         assert not report.ok
         assert report.aliased == 1 and report.expected == 2

@@ -39,6 +39,7 @@ from apex_tpu.fleet import FleetHost, FleetRouter
 from apex_tpu.models.gpt import GPTConfig, GPTLM
 from apex_tpu.obs.flightrec import read_flightrec
 from apex_tpu.train.accum import (
+    fsdp_init,
     reduction_carry_template,
     save_train_state,
     train_state_canonical,
@@ -276,6 +277,71 @@ class TestIdenticalFlip:
         cand = CheckpointWatcher(zero_ckpt).poll()
         with pytest.raises(PromotionError, match="no admitted"):
             PromotionController(router).promote(cand)
+
+
+class TestPromotionUnderLoad:
+    ROUNDS = (4, 9, 14)
+
+    def test_repeated_fsdp_promotions_replay_and_keep_every_token(
+            self, dec4, gpt_params, tmp_path):
+        """A seeded open-loop plan on the virtual clock with the fleet
+        rolled through THREE promotions of an fsdp@2 checkpoint of the
+        served weights while requests are in flight: the leg replays
+        byte-identically, streams the clean leg's tokens, and no flip
+        recomputed a request."""
+        mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+        fopt = DistributedFusedAdam(lr=1e-2, axis_name="data")
+        carry = fsdp_init(fopt, amp.initialize("O2"), gpt_params,
+                          fopt.make_spec(gpt_params, 2), mesh)
+        save_train_state(str(tmp_path), carry, 5, mode="fsdp", mesh=mesh)
+        cand = CheckpointWatcher(str(tmp_path)).poll()
+        assert cand.mode == "fsdp" and cand.world == 2
+        plan = serve.TrafficPlan.from_seed(
+            31, requests=24, rate_rps=200.0, arrival="poisson",
+            vocab_size=CFG.vocab_size, n_prefixes=3, prefix_len=8,
+            zipf_s=1.1, shared_frac=0.5, prompt_min=2, prompt_scale=5.0,
+            prompt_alpha=1.3, prompt_cap=32, output_min=4,
+            output_scale=8.0, output_alpha=1.1, output_cap=24,
+            priorities=(0, 2), interactive_max_prompt=24,
+        )
+
+        class PromoteAtRounds:
+            """The router, with a full rollout fired before the listed
+            rounds' steps."""
+
+            def __init__(self, router, rounds):
+                self.router, self.rounds = router, set(rounds)
+                self.ctl = PromotionController(router, drain_rounds=0)
+                self.round, self.promos = 0, []
+
+            def __getattr__(self, name):
+                return getattr(self.router, name)
+
+            def step(self):
+                self.round += 1
+                if self.round in self.rounds:
+                    self.promos.append(self.ctl.promote(cand))
+                return self.router.step()
+
+        def leg(rounds):
+            gen = serve.LoadGen(plan, step_cost_ms=4.0)
+            hosts = [FleetHost(i, dec4, clock=gen.clock, **ENG_KW)
+                     for i in range(2)]
+            target = PromoteAtRounds(
+                FleetRouter(hosts, registry=obs.MetricsRegistry(),
+                            clock=gen.clock), rounds)
+            return gen.run(target), target
+
+        clean, _ = leg(())
+        rep, target = leg(self.ROUNDS)
+        assert rep.to_json() == leg(self.ROUNDS)[0].to_json()
+        assert rep.tokens == clean.tokens
+        assert len(target.promos) == len(self.ROUNDS)
+        assert all(p["ok"] and p["identical"] and p["recomputed"] == 0
+                   for p in target.promos)
+        # the flips found requests in flight, or they proved nothing
+        assert any(s["kept"] for p in target.promos
+                   for s in p["swaps"].values())
 
 
 # ---------------------------------------------------------------------------

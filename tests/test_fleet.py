@@ -691,6 +691,14 @@ class TestAutoscale:
         rep = gen.run(router)
         return rep, router
 
+    def _static_leg(self, dec4):
+        gen = serve.LoadGen(self._plan(), step_cost_ms=4.0)
+        hosts = [FleetHost(i, dec4, clock=gen.clock, **ENG_KW)
+                 for i in range(3)]
+        router = FleetRouter(hosts, registry=obs.MetricsRegistry(),
+                             clock=gen.clock)
+        return gen.run(router), router
+
     def test_burn_scales_up_and_calm_drains(self, dec4):
         """TTFT burn admits standby hosts through preflight; calm
         rounds drain the most recent scale-up (engine released, pages
@@ -719,13 +727,20 @@ class TestAutoscale:
         """Scaling only changes WHERE requests run: greedy token
         streams equal the static 3-host fleet's."""
         rep_a, _ = self._auto_leg(dec4)
-        gen = serve.LoadGen(self._plan(), step_cost_ms=4.0)
-        hosts = [FleetHost(i, dec4, clock=gen.clock, **ENG_KW)
-                 for i in range(3)]
-        router = FleetRouter(hosts, registry=obs.MetricsRegistry(),
-                             clock=gen.clock)
-        rep_s = gen.run(router)
+        rep_s, _ = self._static_leg(dec4)
         assert rep_a.tokens == rep_s.tokens
+
+    def test_elastic_fleet_spends_fewer_host_boundaries_than_static(
+            self, dec4):
+        """What scaling buys: the plan a static fleet serves with three
+        hosts stepping every round, one host plus two standbys serve
+        on fewer host-rounds — hosts are admitted while TTFT burns and
+        drained when it is calm."""
+        rep_a, r_a = self._auto_leg(dec4)
+        rep_s, r_s = self._static_leg(dec4)
+        assert rep_a.completed == rep_s.completed == rep_s.submitted
+        assert 0 < r_a.stats()["host_boundaries"] \
+            < r_s.stats()["host_boundaries"]
 
     def test_autoscale_off_leaves_standby_untouched(self, dec4):
         """Without the opt-in, standby hosts are registered but never

@@ -1,8 +1,8 @@
 """Fleet-at-scale routing structures (ISSUE 17).
 
-The 100-host bench leg (``bench.py --only fleet100``) exercises the
-full router; these tests pin the underlying O(log H) structures in
-isolation so a regression is caught in seconds, not bench minutes:
+These tests pin the router's O(log H) structures in isolation, and the
+scale policies (rebalancer, streamed scrape, straggler scan, streaming
+KV handoff) on a seeded fleet small enough for tier-1:
 
 - the incrementally-maintained consistent-hash ring is EXACTLY the
   from-scratch rebuild after any admit/evict/readmit sequence (the
@@ -12,8 +12,16 @@ isolation so a regression is caught in seconds, not bench minutes:
   property that makes the ring worth having);
 - the live router's rings/heaps stay in lockstep with pool
   membership across evict/readmit, and FleetUnavailable diagnoses a
-  100-host fleet in a bounded, summarized message.
+  100-host fleet in a bounded, summarized message;
+- with every scale policy live, a seeded open-loop leg is a function
+  of its seed (report and flight recorder byte-identical across two
+  runs), and streaming the KV handoff changes no token while only the
+  tail chunk stays on the blocking hop.
 """
+import json
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -29,6 +37,9 @@ from apex_tpu.fleet.serve import (  # noqa: E402
     _stable_hash,
 )
 from apex_tpu.models.gpt import GPTConfig, GPTLM  # noqa: E402
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+from trace_report import CorrelationStitcher  # noqa: E402
 
 CFG = GPTConfig.tiny(compute_dtype=jnp.float32, dropout_rate=0.0,
                      attn_dropout_rate=0.0)
@@ -210,3 +221,114 @@ class TestRouterScaleStructures:
             if i % 4 == 3:
                 r.step()
         r.run()
+
+
+# ---------------------------------------------------------------------------
+# the scale policies live: a function of the seed; streaming handoff
+# ---------------------------------------------------------------------------
+
+class TestScalePolicies:
+    HOSTS, REQUESTS = 6, 60
+
+    def _leg(self, dec4):
+        """Seeded Zipf-shared-prefix traffic on a virtual clock over
+        six hosts with the rebalancer, the sharded streaming scrape and
+        the straggler scan all live.  Outputs span more than one
+        dispatch so prefix pages stay resident for the rebalancer."""
+        plan = serve.TrafficPlan.from_seed(
+            29, requests=self.REQUESTS, rate_rps=150.0,
+            arrival="poisson", vocab_size=CFG.vocab_size, n_prefixes=8,
+            prefix_len=16, zipf_s=1.2, shared_frac=0.7, prompt_min=2,
+            prompt_scale=4.0, prompt_alpha=1.4, prompt_cap=24,
+            output_min=6, output_scale=4.0, output_alpha=1.2,
+            output_cap=16, priorities=(0, 2), interactive_max_prompt=16,
+        )
+        gen = serve.LoadGen(plan, step_cost_ms=2.0)
+        hosts = [FleetHost(i, dec4, clock=gen.clock,
+                           **dict(ENG_KW, max_len=48))
+                 for i in range(self.HOSTS)]
+        fr = obs.FlightRecorder(enabled=True)
+        agg = obs.FleetAggregator()
+        router = FleetRouter(
+            hosts, registry=obs.MetricsRegistry(), clock=gen.clock,
+            aggregator=agg, scrape_every=4, scrape_stream=True,
+            rebalance=True, straggler_every=4, flightrec=fr,
+        )
+        return gen.run(router), router, fr, agg
+
+    @pytest.fixture(scope="class")
+    def two_runs(self, dec4):
+        return self._leg(dec4), self._leg(dec4)
+
+    def test_seeded_leg_replays_byte_identical(self, two_runs):
+        """Routing, rebalancing and scrape pacing are functions of the
+        seed and the virtual clock: two runs, one LoadReport — and the
+        leg did exercise what it names."""
+        (rep_a, router, _, agg), (rep_b, _, _, _) = two_runs
+        assert rep_a.to_json() == rep_b.to_json()
+        assert rep_a.completed == self.REQUESTS
+        assert router.stats()["rebalances"] >= 1
+        assert agg.scrapes > 0
+
+    def test_seeded_leg_flightrec_identical(self, two_runs):
+        """The postmortem of the same leg (routing decisions, prefix
+        migrations, straggler flags) is byte-identical too."""
+        (_, _, fr_a, _), (_, _, fr_b, _) = two_runs
+        assert fr_a.recorded > 0
+        assert json.dumps(fr_a.events()) == json.dumps(fr_b.events())
+
+    @pytest.fixture(scope="class")
+    def handoff_pair(self, dec4):
+        """Six long prompts (three 16-token prefill chunks each) through
+        a prefill/decode pair, monolithic then streamed."""
+        rng = np.random.RandomState(0)
+        pool = [int(t) for t in rng.randint(0, CFG.vocab_size,
+                                            size=(48,))]
+        prompts = [pool[s:s + n] for s, n in ((0, 40), (1, 44), (2, 38),
+                                               (3, 42), (5, 40), (6, 43))]
+        kw = dict(ENG_KW, slots=3)
+
+        def leg(stream):
+            hosts = [FleetHost(0, dec4, role="prefill", **kw),
+                     FleetHost(1, dec4, role="decode", **kw)]
+            router = FleetRouter(hosts, registry=obs.MetricsRegistry(),
+                                 tracer=obs.Tracer(enabled=True),
+                                 stream_handoff=stream)
+            uids = [router.submit(p, max_new_tokens=8, temperature=0.0)
+                    for p in prompts]
+            out = router.run()
+            return router, [out[u] for u in uids]
+
+        return leg(False), leg(True)
+
+    def test_streaming_handoff_tokens_match_monolithic(self, handoff_pair):
+        (mono, out_m), (streamed, out_s) = handoff_pair
+        assert out_s == out_m
+        assert streamed.stats()["handoffs"] == \
+            mono.stats()["handoffs"] == len(out_m)
+
+    def test_streaming_keeps_only_the_tail_on_the_blocking_hop(
+            self, handoff_pair):
+        """Interior chunks ship while prefill still runs: the bytes that
+        block the first decode step are a fraction of the handoff, and
+        no chunk was aborted into the recompute fallback."""
+        _, (streamed, _) = handoff_pair
+        st = streamed.stats()
+        assert st["handoff_chunks"] > 0
+        assert st["handoff_chunk_aborts"] == 0
+        assert 0 < streamed._stream_wire_bytes \
+            < 0.5 * streamed._stream_total_bytes
+
+    def test_streamed_flows_stitch_with_a_wire_segment(self, handoff_pair):
+        """`tools/trace_report.py` reads the blocking hop out of the
+        trace as each flow's `handoff_wire_ms`: a streamed handoff must
+        leave that segment on every request's timeline, as a monolithic
+        one does."""
+        for router, outs in handoff_pair:
+            stitcher = CorrelationStitcher()
+            for ts, kind, name, payload in router.tracer.events:
+                stitcher.feed_event({"type": kind, "name": name, "ts": ts,
+                                     "attrs": payload})
+            flows, _ = stitcher.finish()
+            wired = [f for f in flows.values() if "handoff_wire_ms" in f]
+            assert len(wired) == len(outs)

@@ -13,7 +13,8 @@ from apex_tpu.models import (
     ResNet,
     resnet50,
 )
-from apex_tpu.optimizers import fused_adam, fused_lamb
+from apex_tpu.optimizers import fused_adam, fused_lamb, fused_sgd
+from apex_tpu.train import FusedTrainDriver, read_metrics
 
 
 class TestResNet:
@@ -87,6 +88,55 @@ class TestResNet:
             losses.append(float(loss))
         assert losses[-1] < losses[0]
 
+    def test_o2_sgd_window_through_the_fused_driver(self, rng):
+        """The convolution + batch-norm training step of BASELINE.md's
+        config 2 (O2, FusedSGD with momentum and weight decay, the BN
+        statistics riding the donated carry, K steps a dispatch) at a
+        width tier-1 can afford: finite loss, no skipped step, masters
+        and BN statistics still fp32 after the window."""
+        from apex_tpu.ops import softmax_cross_entropy
+
+        amp_ = amp.initialize("O2")
+        m = ResNet(stage_sizes=(1, 1), num_classes=4, width=8,
+                   compute_dtype=amp_.policy.compute_dtype)
+        opt = amp.AmpOptimizer(
+            fused_sgd(0.1, momentum=0.9, weight_decay=1e-4), amp_)
+        x = jnp.asarray(rng.randn(4, 32, 32, 3).astype(np.float32))
+        y = jnp.asarray(rng.randint(0, 4, size=(4,)))
+        v = jax.jit(m.init)(jax.random.PRNGKey(0), x[:1])
+        params, bstats = v["params"], v["batch_stats"]
+
+        def step(carry, _):
+            params, bstats, state = carry
+
+            def scaled(mp):
+                logits, upd = m.apply(
+                    {"params": opt.model_params(mp),
+                     "batch_stats": bstats},
+                    x, train=True, mutable=["batch_stats"])
+                loss = jnp.mean(softmax_cross_entropy(logits, y))
+                return (amp_.scale_loss(loss, state.scaler[0]),
+                        (loss, upd["batch_stats"]))
+
+            grads, (loss, bstats) = jax.grad(scaled, has_aux=True)(params)
+            params, state, stats = opt.step(grads, state, params)
+            return (params, bstats, state), {
+                "loss": loss, "skipped": stats.found_inf}
+
+        driver = FusedTrainDriver(
+            step, steps_per_dispatch=3,
+            metrics={"loss": "last", "skipped": "sum"})
+        w0 = np.asarray(jax.tree_util.tree_leaves(params)[0]).copy()
+        carry, res = driver.run_window((params, bstats, opt.init(params)))
+        got = read_metrics(res.metrics)
+        assert np.isfinite(got["loss"]) and got["skipped"] == 0
+        params, bstats, state = carry
+        leaves = jax.tree_util.tree_leaves((params, bstats))
+        assert all(a.dtype == jnp.float32 for a in leaves)
+        assert not np.array_equal(
+            np.asarray(jax.tree_util.tree_leaves(params)[0]), w0)
+        assert int(state.scaler[0].unskipped) == 3
+
     def test_bf16_compute_fp32_logits(self, rng):
         m = ResNet(stage_sizes=(1,), num_classes=4, width=8,
                    compute_dtype=jnp.bfloat16)
@@ -147,6 +197,88 @@ class TestDCGAN:
         dv = d.init(jax.random.PRNGKey(1), img)
         logits, _ = d.apply(dv, img, mutable=["batch_stats"])
         assert logits.shape == (2,)
+
+    @pytest.mark.parametrize("opt_level", ["O2", "O0"])
+    def test_three_scaler_gan_window_through_the_fused_driver(
+            self, rng, opt_level):
+        """BASELINE.md's config 5 as `examples/dcgan` runs it: one
+        G + D iteration is three losses under three loss scalers
+        (`loss_id` 0/1/2) and two optimizers, the discriminator's two
+        gradients accumulated into one step, K iterations a dispatch.
+        Finite losses, both nets moved, and each scaler counted only
+        its own loss's clean steps."""
+        from apex_tpu.amp import F
+
+        amp_ = amp.initialize(opt_level, num_losses=3)
+        dt = amp_.policy.compute_dtype
+        netG = Generator(nz=16, ngf=8, compute_dtype=dt)
+        netD = Discriminator(ndf=8, compute_dtype=dt)
+        optG = amp.AmpOptimizer(fused_adam(2e-4, betas=(0.5, 0.999)), amp_)
+        optD = amp.AmpOptimizer(fused_adam(2e-4, betas=(0.5, 0.999)), amp_)
+        real = jnp.asarray(rng.rand(2, 64, 64, 3) * 2 - 1, jnp.float32)
+        z = jnp.asarray(rng.randn(2, 1, 1, 16), jnp.float32)
+        gv = jax.jit(netG.init)(jax.random.PRNGKey(0), z)
+        dv = jax.jit(netD.init)(jax.random.PRNGKey(1), real)
+        bce = F.binary_cross_entropy_with_logits
+
+        def step(carry, _):
+            gp, gs, gstate, dp, ds, dstate = carry
+            fake, _ = netG.apply({"params": gp, "batch_stats": gs}, z,
+                                 mutable=["batch_stats"])
+
+            def d_loss(p, stats, img, target, loss_id):
+                out, upd = netD.apply(
+                    {"params": optD.model_params(p), "batch_stats": stats},
+                    img, mutable=["batch_stats"])
+                loss = bce(out, jnp.full_like(out, target))
+                return (amp_.scale_loss(loss, dstate.scaler[loss_id],
+                                        loss_id=loss_id),
+                        (loss, upd["batch_stats"]))
+
+            g_real, (err_real, ds) = jax.grad(d_loss, has_aux=True)(
+                dp, ds, real, 1.0, 0)
+            g_fake, (err_fake, ds) = jax.grad(d_loss, has_aux=True)(
+                dp, ds, fake, 0.0, 1)
+            dstate = optD.accumulate(g_real, dstate, loss_id=0)
+            dp, dstate, _ = optD.step(g_fake, dstate, dp, loss_id=1)
+
+            def g_loss(p):
+                img, upd = netG.apply(
+                    {"params": optG.model_params(p), "batch_stats": gs},
+                    z, mutable=["batch_stats"])
+                out, _ = netD.apply({"params": dp, "batch_stats": ds},
+                                    img, mutable=["batch_stats"])
+                loss = bce(out, jnp.ones_like(out))
+                return (amp_.scale_loss(loss, gstate.scaler[2], loss_id=2),
+                        (loss, upd["batch_stats"]))
+
+            g_g, (err_g, gs) = jax.grad(g_loss, has_aux=True)(gp)
+            gp, gstate, _ = optG.step(g_g, gstate, gp, loss_id=2)
+            return (gp, gs, gstate, dp, ds, dstate), {
+                "errD": err_real + err_fake, "errG": err_g}
+
+        driver = FusedTrainDriver(
+            step, steps_per_dispatch=2,
+            metrics={"errD": "last", "errG": "last"})
+        g0 = np.asarray(jax.tree_util.tree_leaves(gv["params"])[0]).copy()
+        d0 = np.asarray(jax.tree_util.tree_leaves(dv["params"])[0]).copy()
+        carry, res = driver.run_window(
+            (gv["params"], gv["batch_stats"], optG.init(gv["params"]),
+             dv["params"], dv["batch_stats"], optD.init(dv["params"])))
+        got = read_metrics(res.metrics)
+        assert np.isfinite(got["errD"]) and np.isfinite(got["errG"])
+        gp, _, gstate, dp, _, dstate = carry
+        assert not np.array_equal(
+            np.asarray(jax.tree_util.tree_leaves(gp)[0]), g0)
+        assert not np.array_equal(
+            np.asarray(jax.tree_util.tree_leaves(dp)[0]), d0)
+        # each optimizer advanced only the scalers of its own losses
+        # (a static O0 scaler counts nothing), and none saw an overflow
+        clean = 2 if opt_level == "O2" else 0
+        assert [int(s.unskipped) for s in dstate.scaler] == [clean, clean, 0]
+        assert [int(s.unskipped) for s in gstate.scaler] == [0, 0, clean]
+        assert not any(int(s.overflows)
+                       for s in dstate.scaler + gstate.scaler)
 
 
 class TestGPT:

@@ -1,8 +1,8 @@
 """Flash attention kernel vs reference (ref apex/contrib/test/multihead_attn/
 test_*: fast fused impl vs default impl under identical inputs).
 
-Interpreter mode on CPU keeps shapes small; the real-TPU run is exercised by
-bench.py and the verify driver.
+Interpreter mode on CPU keeps shapes small; the real-TPU run is
+``chip_smoke.py``'s kernels phase and the benchmark's train cells.
 """
 import jax
 import jax.numpy as jnp
@@ -563,8 +563,8 @@ def test_shared_trace_keyed_on_module_switches(rng, monkeypatch):
     """The kernels' trace is shared between calls of one signature
     (attention._flash_jit); what it reads from the module at trace time
     is part of the key, so the A/B tools that flip a switch between two
-    calls (tools/check_fused_dq_acc.py, tools/bench_fused_exclusions.py)
-    get the other path, not the first trace again."""
+    calls (tools/check_fused_dq_acc.py) get the other path, not the first
+    trace again."""
     q, k, v = qkv(rng, s=512, d=64)
 
     def kernels():

@@ -244,6 +244,35 @@ class TestSpecIdentity:
         # the table resolved to a real spec tree after first dispatch
         assert not isinstance(driver.carry_spec, shd.RulesTable)
 
+    def test_rules_table_drives_the_programs_the_literal_spec_does(self):
+        """Handing the driver the rules table instead of the literal
+        `(P(), zero_state_spec())` changes nothing it runs: as many
+        compiled programs over two windows, and a bitwise-equal master
+        shard after them."""
+        mesh = _mesh(N_DEV)
+        amp_, grad_fn, params, xs, ys = _problem()
+        zopt = DistributedFusedAdam(lr=1e-2, axis_name="data")
+        spec = zopt.make_spec(params, N_DEV)
+
+        def leg(carry_spec):
+            step = zero_microbatch_step(grad_fn, zopt, amp_, spec,
+                                        microbatches=2)
+            driver = FusedTrainDriver(
+                step, steps_per_dispatch=2, mesh=mesh, check_vma=False,
+                carry_spec=carry_spec)
+            carry = (replicate(_copy(params), mesh),
+                     zero_init(zopt, amp_, _copy(params), spec, mesh))
+            for w in range(2):
+                sl = slice(4 * w, 4 * w + 4)
+                carry, _ = driver.run_window(carry, (xs[sl], ys[sl]))
+            master = carry[1].opt_state.master_shard
+            return np.asarray(jax.device_get(master)), len(driver._programs)
+
+        by_rules, n_rules = leg(shd.train_state_rules())
+        by_literal, n_literal = leg((P(), zero_state_spec()))
+        assert n_rules == n_literal == 1
+        assert np.array_equal(by_rules, by_literal)
+
     def test_gang_rules_env_round_trip(self, monkeypatch):
         from apex_tpu.fleet.train import (
             GANG_RULES_ENV,
@@ -393,6 +422,36 @@ class TestFsdpPolicy:
         assert not fc[0].sharding.is_fully_replicated
         assert fc[0].addressable_data(0).size == spec.padded // N_DEV
         assert not fc[1].opt_state.m_shard.sharding.is_fully_replicated
+
+    def test_state_bytes_per_replica(self, mesh8):
+        """The memory claim of the three reduction policies, as exact
+        byte math on what one device holds of a fresh Adam carry over
+        N fp32 parameters at world W (16 bytes of step and scaler
+        scalars in each): mean keeps params and both moments whole,
+        zero keeps params whole and master + moments at 1/W, fsdp keeps
+        the flat master and the moments at 1/W and nothing whole."""
+        from apex_tpu.optimizers import fused_adam
+
+        amp_ = amp.initialize("O2")
+        params = {"w": jnp.ones((64, 32), jnp.float32)}
+        n, scalars = 4 * 64 * 32, 16
+
+        def held(tree):
+            return sum(leaf.addressable_data(0).nbytes
+                       for leaf in jax.tree_util.tree_leaves(tree))
+
+        opt = amp.AmpOptimizer(fused_adam(1e-2), amp_)
+        zopt = DistributedFusedAdam(lr=1e-2, axis_name="data")
+        spec = zopt.make_spec(params, N_DEV)
+        assert spec.padded == 64 * 32
+        mean = (replicate(params, mesh8),
+                replicate(opt.init(params), mesh8))
+        zero = (replicate(params, mesh8),
+                zero_init(zopt, amp_, params, spec, mesh8))
+        fsdp = fsdp_init(zopt, amp_, params, spec, mesh8)
+        assert held(mean) == 3 * n + scalars
+        assert held(zero) == n + 3 * n // N_DEV + scalars
+        assert held(fsdp) == 3 * n // N_DEV + scalars
 
     def test_fsdp_rejects_lamb(self, mesh8):
         from apex_tpu.contrib.optimizers import DistributedFusedLAMB

@@ -501,6 +501,24 @@ class TestSloAdmission:
             n = min(len(a), len(b))
             assert a[:n] == b[:n], f"uid {uid} diverged"
 
+    def test_interactive_class_waits_less_than_under_fifo(
+            self, tiny_decoder):
+        """What the policy is for: on a seeded plan whose interactive
+        requests queue behind batch prompts for a page-starved pool,
+        admission by class with the TTFT tracker live cuts the
+        interactive class's p99 time to first token against FIFO.
+        Virtual clock: the direction is the claim, not the
+        milliseconds."""
+        dec, cfg = tiny_decoder
+        plan = _engine_plan(cfg, seed=9, requests=24)
+        objs = [SloObjective("ttft_ms", 0.9, 20.0, 200.0),
+                SloObjective("itl_ms", 0.99, 100.0, 200.0)]
+        fifo = _run_engine_leg(dec, plan, False, num_pages=1 + 10)
+        slo = _run_engine_leg(dec, plan, True, tracker_objs=objs,
+                              num_pages=1 + 10)
+        assert slo.ttft_ms_by_priority[2]["p99"] \
+            < fifo.ttft_ms_by_priority[2]["p99"]
+
     def test_env_knob_default_off(self, tiny_decoder, monkeypatch):
         dec, _ = tiny_decoder
         monkeypatch.delenv("APEX_TPU_SLO_ADMISSION", raising=False)

@@ -223,7 +223,10 @@ class TestSpecDecodeParity:
         cfg, params, pool = lm
         base = [int(t) for t in pool[:9]]
         prompts = [base, [int(t) for t in pool[3:8]], list(base)]
-        budgets = [8, 6, 8]
+        # two slots: the twin waits for one, and finds the base's pages
+        # only while the base still runs — a budget past one window's
+        # 16 tokens keeps it live when the short request retires
+        budgets = [24, 6, 8]
         refs = [reference_generate(cfg, params, p, n)
                 for p, n in zip(prompts, budgets)]
         eng = ServeEngine(spec_dec, slots=2, max_len=64, paged=True,
@@ -233,7 +236,7 @@ class TestSpecDecodeParity:
         out = eng.run()
         for uid, ref in zip(uids, refs):
             assert out[uid] == ref, uid
-        assert out[uids[0]] == out[uids[2]]  # identical twins
+        assert out[uids[0]][:8] == out[uids[2]]  # identical twins
         assert eng.pool.prefix_hits >= 1
 
     def test_bf16_policy_spec_parity(self):

@@ -386,7 +386,7 @@ class TestRealTree:
         assert "apex_tpu/analysis/staticcheck.py" in files
         assert "tools/apexlint.py" in files
         assert "tests/test_staticcheck.py" in files
-        assert "bench.py" in files
+        assert "chip_smoke.py" in files
 
 
 # ---------------------------------------------------------------------------

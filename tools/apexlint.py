@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """apexlint — run the repo's AST invariant analyzer (ISSUE 19).
 
-Sweeps ``apex_tpu/``, ``tools/``, ``tests/`` and ``bench.py`` with the
+Sweeps ``apex_tpu/``, ``tools/``, ``tests/`` and ``chip_smoke.py`` with the
 rule registry in :mod:`apex_tpu.analysis.staticcheck`: the repo's own
 bug classes (wall clock in deterministic paths, unseeded RNG,
 non-atomic JSON writes, unregistered/undocumented env knobs, clock

@@ -83,8 +83,7 @@ Exit status is nonzero on any violation::
 
 ``tests/test_analysis.py`` wraps this in tier-1 (sharing the lowered
 programs through the session-scoped ``canonical`` fixture in
-``tests/conftest.py``), and ``bench.py``'s hardware-free ``lint``
-metric records the same sweep in the artifact.  To add a program: add
+``tests/conftest.py``).  To add a program: add
 a ``_build_<name>`` returning a :class:`CanonicalProgram` with its
 declared :class:`~apex_tpu.analysis.collectives.CollectiveBudget`, and
 list it in ``LINT_PROGRAMS``.
@@ -132,7 +131,7 @@ D_IN, D_OUT = 64, 32  # w: 64x32 fp32 = 8192 B — well over min_bytes
 GRAD_BYTES = D_IN * D_OUT * 4
 MIN_BYTES = 1024
 
-# the canonical sweep (the tier-1 gate and the bench `lint` metric);
+# the canonical sweep (the tier-1 gate);
 # train_m2 exists for tests/test_inspect_hlo.py's M in {2, 4} contract.
 # spec_k8 / paged_int8_k8 (ISSUE 7): the self-speculative window and
 # the int8 page pool must hold the same contracts as their plain twins
@@ -219,7 +218,8 @@ class CanonicalProgram:
 
 
 # ISSUE 11: the compiled-cost pins, measured on this container's XLA
-# (jax 0.4.37 CPU, 8-device mesh) — FLOPs pinned EXACTLY (HLO cost
+# (jax 0.9.0 CPU, 8-device mesh; re-pinned in PR 28 from the 0.4.37
+# figures, old and new in CHANGES.md) — FLOPs pinned EXACTLY (HLO cost
 # analysis is deterministic for a fixed toolchain), bytes within 10%,
 # the peak-HBM bound (args + temps + outputs) within 25%.  A failing
 # pin means the program's compute or memory traffic changed: re-measure
@@ -227,39 +227,35 @@ class CanonicalProgram:
 # Note XLA counts a while/scan body once, not times its trip count —
 # which is why decode_k1 and decode_k8 pin nearly identical numbers.
 COST_PINS: Dict[str, CostBudget] = {
-    "train_m1": CostBudget(flops=41338.0, bytes_accessed=110909.0,
-                           peak_hbm_bytes=51348),
-    "train_m4": CostBudget(flops=99682.0, bytes_accessed=224925.0,
-                           peak_hbm_bytes=81236),
-    "train_zero_m2": CostBudget(flops=54234.0, bytes_accessed=175261.0,
-                                peak_hbm_bytes=56244),
-    "train_bf16_m2": CostBudget(flops=74440.0, bytes_accessed=157789.0,
-                                peak_hbm_bytes=61268),
-    "train_int8_m2": CostBudget(flops=99039.0, bytes_accessed=242357.0,
-                                peak_hbm_bytes=79908),
-    "train_dptp_m1": CostBudget(flops=26882834.0,
-                                bytes_accessed=15286667.0,
-                                peak_hbm_bytes=3606412),
-    "decode_k1": CostBudget(flops=2406483.0, bytes_accessed=4296836.0,
-                            peak_hbm_bytes=2574202),
-    "decode_k8": CostBudget(flops=2408530.0, bytes_accessed=4303933.0,
-                            peak_hbm_bytes=2577194),
-    "paged_k1": CostBudget(flops=2406769.0, bytes_accessed=4354532.0,
-                           peak_hbm_bytes=2598842),
-    "paged_k8": CostBudget(flops=2408672.0, bytes_accessed=4361789.0,
-                           peak_hbm_bytes=2601914),
-    "spec_k8": CostBudget(flops=9653863.0, bytes_accessed=5531379.0,
-                          peak_hbm_bytes=2687490),
-    "paged_int8_k8": CostBudget(flops=2479952.0,
-                                bytes_accessed=3657777.0,
-                                peak_hbm_bytes=2316890),
+    "train_m1": CostBudget(flops=41329.0, bytes_accessed=110909.0,
+                           peak_hbm_bytes=51284),
+    "train_m4": CostBudget(flops=99646.0, bytes_accessed=224925.0,
+                           peak_hbm_bytes=80596),
+    "train_zero_m2": CostBudget(flops=54216.0, bytes_accessed=175261.0,
+                                peak_hbm_bytes=59124),
+    "train_bf16_m2": CostBudget(flops=74422.0, bytes_accessed=157789.0,
+                                peak_hbm_bytes=61012),
+    "train_int8_m2": CostBudget(flops=99021.0, bytes_accessed=242357.0,
+                                peak_hbm_bytes=79524),
+    "train_dptp_m1": CostBudget(flops=27161094.0, bytes_accessed=14778988.0,
+                                peak_hbm_bytes=4859228),
+    "decode_k1": CostBudget(flops=2448143.0, bytes_accessed=4942264.0,
+                            peak_hbm_bytes=2627322),
+    "decode_k8": CostBudget(flops=2450187.0, bytes_accessed=5047761.0,
+                            peak_hbm_bytes=2744650),
+    "paged_k1": CostBudget(flops=2448429.0, bytes_accessed=5036824.0,
+                           peak_hbm_bytes=2684218),
+    "paged_k8": CostBudget(flops=2450329.0, bytes_accessed=5146577.0,
+                           peak_hbm_bytes=2773466),
+    "spec_k8": CostBudget(flops=9837879.0, bytes_accessed=6551975.0,
+                          peak_hbm_bytes=2853922),
+    "paged_int8_k8": CostBudget(flops=2521537.0, bytes_accessed=3931329.0,
+                                peak_hbm_bytes=2426234),
     # the fused read in INTERPRET mode (off-TPU the kernel body traces
     # as plain ops, so this census prices the interpreter's explicit
-    # page staging, not the Mosaic DMA schedule — the hardware bytes
-    # story lives in bench.py's decode gather-traffic accounting)
-    "paged_fused_k8": CostBudget(flops=2374740.0,
-                                 bytes_accessed=5861039.0,
-                                 peak_hbm_bytes=2795122),
+    # page staging, not the Mosaic DMA schedule)
+    "paged_fused_k8": CostBudget(flops=2416399.0, bytes_accessed=6506619.0,
+                                 peak_hbm_bytes=2914578),
 }
 
 # which tracer span each program's dispatches run under — the join key
@@ -971,8 +967,7 @@ def collect_census(canonical: Optional[CanonicalPrograms] = None,
     """The machine-readable census over ``names``: per-program
     FLOPs/bytes/peak (``census_partial`` flagged where the backend
     omits them) plus the dispatch-span join key the trace_report
-    roofline section consumes.  Written by ``--census-out`` and
-    recorded in bench.py's ``lint`` metric."""
+    roofline section consumes.  Written by ``--census-out``."""
     canonical = canonical or CanonicalPrograms()
     out: Dict[str, Dict[str, Any]] = {}
     for name in names:
@@ -1912,14 +1907,14 @@ def check_grad_compress(canonical: CanonicalPrograms) -> List[str]:
 
 #: the pinned apexlint census (ISSUE 19).  ``rules`` and
 #: ``suppressions`` are EXACT — adding a rule or a suppression is a
-#: deliberate act that re-pins here AND in PERF_BASELINE.json;
-#: ``files`` is a floor (the tree only grows); ``violations`` is zero,
+#: deliberate act that re-pins here; ``files`` is a floor, lowered only
+#: with the files a PR deletes (193 -> 184 in PR 28); ``violations`` is zero,
 #: always — a new violation is fixed or suppressed-with-reason, never
 #: ridden.
 APEXLINT_PINS: Dict[str, int] = {
     "rules": 10,
-    "files": 182,
-    "suppressions": 1,
+    "files": 184,
+    "suppressions": 0,
     "violations": 0,
 }
 
@@ -1946,8 +1941,8 @@ def check_apexlint() -> List[str]:
     if c["rules"] != pins["rules"]:
         errs.append(
             f"apexlint rule registry drifted: {c['rules']} rules vs "
-            f"pinned {pins['rules']} — re-pin APEXLINT_PINS (and "
-            "PERF_BASELINE.json) deliberately"
+            f"pinned {pins['rules']} — re-pin APEXLINT_PINS "
+            "deliberately"
         )
     if c["files"] < pins["files"]:
         errs.append(

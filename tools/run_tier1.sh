@@ -60,13 +60,6 @@ fi
 if [[ -n "$TRACE_DIR" && -f "$TRACE_DIR/trace.jsonl" ]]; then
     echo "TRACE_ARTIFACT=$TRACE_DIR/trace.jsonl"
 fi
-# Perf-regression gate banner (ISSUE 11): with a committed baseline,
-# print the one-line PERF_GATE= summary of the most recent bench
-# artifact vs PERF_BASELINE.json (tools/perf_gate.py is jax-free and
-# sub-second; --summary always exits 0, so the tier-1 rc is untouched).
-if [[ -f PERF_BASELINE.json ]]; then
-    python tools/perf_gate.py --summary 2>/dev/null || true
-fi
 # apexlint banner (ISSUE 19): the one-line census of the AST invariant
 # sweep (tools/apexlint.py is jax-free and ~2 s; --summary always
 # exits 0, so the tier-1 rc is untouched — the hard gate is the
