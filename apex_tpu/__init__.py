@@ -39,7 +39,8 @@ Apex (reference: /root/reference, see SURVEY.md):
 - :mod:`apex_tpu.remat` — named rematerialization policies
   (``none | dots_saveable | full_block``) threaded through the model zoo
   and ``ops.mlp`` — the activation-memory knob that converts freed HBM
-  into larger microbatches.
+  into larger microbatches; the block-recomputing policies keep what a
+  kernel declares dear to make again (the flash forward's output and lse).
 - :mod:`apex_tpu.analysis` — the graph sanitizer suite: hardware-free
   static proofs of the framework's invariants on traced/lowered
   programs — precision lint against the active amp policy, donation

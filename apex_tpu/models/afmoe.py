@@ -68,7 +68,8 @@ class AfmoeConfig:
     rms_norm_eps: float = 1e-5
     mup_enabled: bool = True
     initializer_range: float = 0.02
-    # activation rematerialization per block (apex_tpu.remat)
+    # activation rematerialization per block (apex_tpu.remat): full_block
+    # keeps a block's input and the flash kernel's output and lse
     remat_policy: str = "none"
     compute_dtype: Any = jnp.bfloat16
 

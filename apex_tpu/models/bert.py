@@ -47,7 +47,8 @@ class BertConfig:
     # philosophy applied in-kernel; see flash_attention's probs_bf16)
     probs_bf16: bool = False
     # activation rematerialization per encoder block: none | dots_saveable
-    # | full_block (apex_tpu.remat)
+    # | full_block (apex_tpu.remat; both keep the flash kernel's output
+    # and lse)
     remat_policy: str = "none"
     compute_dtype: Any = jnp.bfloat16
     tie_word_embeddings: bool = True  # MLPerf BERT ties decoder to embeddings

@@ -52,7 +52,8 @@ class GPTConfig:
     # opt-in half-precision-probability dots in the flash kernel
     probs_bf16: bool = False
     # activation rematerialization per decoder block: none | dots_saveable
-    # | full_block (apex_tpu.remat) — memory freed here + ZeRO sharding
+    # | full_block (apex_tpu.remat; both keep the flash kernel's output
+    # and lse) — memory freed here + ZeRO sharding
     # buys larger microbatches under the accumulation driver mode
     remat_policy: str = "none"
     compute_dtype: Any = jnp.bfloat16

@@ -49,9 +49,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from apex_tpu.ops._common import pallas_call as _pallas_call, pad_rows as _pad_rows
+from apex_tpu.remat import FLASH_LSE, FLASH_OUT
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
@@ -1565,6 +1567,12 @@ def _flash_fwd_rule(q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
         q3, k3, v3, bias3, seed1, scale, causal, block_q, block_k,
         dropout_rate, h_map=h_map, probs_bf16=probs_bf16, window=window,
     )
+    # declared to the block-recomputing policies (apex_tpu.remat): under
+    # them the backward reads THESE arrays and the kernel is not run a
+    # second time.  q3/k3/v3 stay unnamed — cheap to make again from the
+    # block's input.  Outside a jax.checkpoint the names lower to nothing.
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q3, k3, v3, bias3, seed1, out, lse)
 
 

@@ -4,19 +4,55 @@ The reference trades memory for compute per-module (torch checkpointing,
 the MLP extension's reserved-buffer economy); under XLA the equivalent
 lever is ``jax.checkpoint`` with a *saveable policy*.  One named knob
 (``remat_policy``) threads through the model zoo (``models/gpt.py``,
-``models/bert.py``) and :func:`apex_tpu.ops.mlp.mlp`, so memory freed by
-ZeRO sharding + remat converts directly into larger microbatches for the
-gradient-accumulation driver mode (docs/driver.md has the trade-off
-table):
+``models/bert.py``, ``models/afmoe.py``) and :func:`apex_tpu.ops.mlp.mlp`,
+so memory freed by ZeRO sharding + remat converts directly into larger
+microbatches for the gradient-accumulation driver mode (docs/driver.md
+has the trade-off table):
 
 - ``none``          — save all activations (fastest backward, most HBM).
-- ``dots_saveable`` — save matmul/dot outputs, recompute everything
-  elementwise (LN, gelu, softmax, residual adds).  The usual sweet spot:
-  backward re-runs only cheap VPU work while the MXU results stay
-  resident.
-- ``full_block``    — save nothing inside the wrapped block; the whole
-  forward re-runs in backward (max memory savings, ~1.3x step cost for
-  transformer blocks).
+- ``dots_saveable`` — save matmul/dot outputs and the declared kernel
+  residuals (below), recompute everything elementwise (LN, gelu,
+  residual adds).  The usual sweet spot: backward re-runs only cheap VPU
+  work while the MXU results — the flash kernel's among them, which is
+  no ``dot_general`` and which ``dots_saveable`` alone would make again
+  — stay resident.
+- ``full_block``    — save the wrapped block's input and the declared
+  kernel residuals, nothing else; the rest of the forward re-runs in
+  backward (max memory savings, ~1.3x step cost for transformer blocks).
+
+**Declared residuals.**  A kernel whose result is dear to make again and
+cheap to hold names it with ``jax.ad_checkpoint.checkpoint_name`` under
+an entry of :data:`KEPT_RESIDUAL_NAMES`, and both block-recomputing
+policies keep exactly those.  Today one kernel declares:
+``ops/attention.py``'s forward rule names its output (``batch*heads x
+seq x head_dim`` in the compute dtype) and its log-sum-exp (``batch*heads
+x seq`` float32) — what its backward reads besides q, k, v, which are
+cheap to make again from the block's input.  Without them the backward
+pass would run the whole attention forward a second time only to hand
+its backward those two arrays.  Outside a ``jax.checkpoint`` a name lowers
+to nothing.
+
+One rule at every shape, no threshold: per byte kept, the attention
+forward costs 2 x (keys a query sees) operations at a fifth to a third
+of the chip's roofline (PERF.md section 5), against a GEMM's ``d_in``
+operations a byte at two or three times that efficiency — from 512 keys
+up it is the dearest thing in a block to make again, and long context,
+where ``full_block`` is reached for, only widens that.  What a block
+keeps, by configuration (reckoned from the shapes; bf16 compute):
+
+====================  ==================  =====================  ===========
+configuration         block input         + ``out``              + ``lse``
+====================  ==================  =====================  ===========
+trinity-mini, 1x8192  33.6 MB (hidden     67.1 MB (32 heads x    1.05 MB
+                      2048)               128: twice the hidden)
+gpt2-small, 16x1024   25.2 MB             25.2 MB                0.79 MB
+bert-large, 12x512    12.6 MB             12.6 MB                0.39 MB
+====================  ==================  =====================  ===========
+
+For GPT and BERT heads x head size = hidden, so ``full_block`` keeps two
+arrays of the input's size a block where it kept one: about 1/9th of
+what ``none`` keeps (34 x rows x seq x hidden bytes a block by the usual
+count), up from about 1/17th.
 """
 from __future__ import annotations
 
@@ -26,23 +62,41 @@ import jax
 
 REMAT_POLICIES = ("none", "dots_saveable", "full_block")
 
+# The residuals kernels declare (``checkpoint_name``) and the
+# block-recomputing policies keep.  The kernels import the names from
+# here; this module imports only jax.
+FLASH_OUT = "apex_flash_out"
+FLASH_LSE = "apex_flash_lse"
+KEPT_RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE)
+
 
 def checkpoint_policy(policy: Optional[str]):
     """Map a policy name to the ``jax.checkpoint`` policy callable.
 
     Returns None for ``none``/``None`` — meaning "do not wrap at all"
-    (NOT ``jax.checkpoint``'s save-nothing default; use ``full_block``
-    for that).
+    (NOT ``jax.checkpoint``'s save-nothing default).  ``full_block``
+    keeps the residuals kernels declare under
+    :data:`KEPT_RESIDUAL_NAMES` and nothing else; ``dots_saveable`` keeps
+    them beside the dot outputs.  Building either sets the gauge
+    ``remat.kept_names`` (``obs.default_registry()``) to the count of
+    names it keeps.
     """
     if policy is None or policy == "none":
         return None
-    if policy == "dots_saveable":
-        return jax.checkpoint_policies.dots_saveable
+    if policy not in REMAT_POLICIES:
+        raise ValueError(
+            f"remat_policy must be one of {REMAT_POLICIES}, got {policy!r}"
+        )
+    from apex_tpu import obs
+
+    obs.default_registry().gauge("remat.kept_names").set(
+        len(KEPT_RESIDUAL_NAMES))
+    named = jax.checkpoint_policies.save_only_these_names(
+        *KEPT_RESIDUAL_NAMES)
     if policy == "full_block":
-        return jax.checkpoint_policies.nothing_saveable
-    raise ValueError(
-        f"remat_policy must be one of {REMAT_POLICIES}, got {policy!r}"
-    )
+        return named
+    return jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_saveable, named)
 
 
 def remat_fn(

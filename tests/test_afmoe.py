@@ -65,7 +65,7 @@ def rel_gap(a, b):
 
 
 @pytest.mark.parametrize("kernels", [False, True], ids=["ragged_dot", "pallas"])
-@pytest.mark.parametrize("remat", ["none", "full_block"])
+@pytest.mark.parametrize("remat", ["none", "dots_saveable", "full_block"])
 def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
     """Logits, loss and every leaf's gradient; with the Pallas kernels
     (interpret mode: the grouped products and the row movement of

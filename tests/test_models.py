@@ -353,21 +353,35 @@ class TestGPT:
         assert not np.allclose(np.asarray(base[:, 10:]),
                                np.asarray(pert[:, 10:]))
 
-    def test_remat_policy_preserves_params_and_grads(self, rng):
+    @pytest.mark.parametrize("kernels", [False, True],
+                             ids=["reference", "pallas"])
+    @pytest.mark.parametrize("family", ["gpt", "bert"])
+    def test_remat_policy_preserves_params_and_grads(self, rng, family,
+                                                     kernels):
         """remat_policy is a free A/B: every policy binds the same param
         structure as "none" and produces matching loss + grads — only
-        the backward's memory/compute schedule changes."""
-        from apex_tpu.models import GPTConfig, GPTLM
+        the backward's memory/compute schedule changes.  With the kernels
+        (interpret mode) the block-recomputing policies keep the flash
+        forward's output and lse (tests/test_remat.py) and the gradients
+        are still those of "none"."""
+        from apex_tpu.models import BertConfig, BertForMLM, GPTConfig, GPTLM
+        from apex_tpu.ops._common import force_pallas
 
         ids = jnp.asarray(rng.randint(0, 1024, size=(2, 32)))
-        labels = jnp.concatenate(
-            [ids[:, 1:], jnp.full((2, 1), -100)], axis=1
-        )
+        if family == "gpt":
+            config, lm = GPTConfig, GPTLM
+            labels = jnp.concatenate(
+                [ids[:, 1:], jnp.full((2, 1), -100)], axis=1
+            )
+        else:
+            config, lm = BertConfig, BertForMLM
+            labels = jnp.asarray(
+                np.where(rng.rand(2, 32) < 0.15, np.asarray(ids), -100))
 
         def loss_and_grads(policy, params=None):
-            cfg = GPTConfig.tiny(compute_dtype=jnp.float32,
-                                 remat_policy=policy)
-            model = GPTLM(cfg)
+            cfg = config.tiny(compute_dtype=jnp.float32,
+                              remat_policy=policy)
+            model = lm(cfg)
             if params is None:
                 params = model.init(jax.random.PRNGKey(0), ids,
                                     labels=labels)
@@ -376,38 +390,16 @@ class TestGPT:
             )(params)
             return params, float(loss), g
 
-        params, loss0, g0 = loss_and_grads("none")
-        for policy in ("dots_saveable", "full_block"):
-            p2, loss, g = loss_and_grads(policy, params)
-            assert loss == pytest.approx(loss0, rel=1e-6)
-            for a, b in zip(jax.tree_util.tree_leaves(g0),
-                            jax.tree_util.tree_leaves(g)):
-                np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
-                )
-
-    def test_bert_remat_policy_same_loss(self, rng):
-        from apex_tpu.models import BertConfig, BertForMLM
-
-        ids = jnp.asarray(rng.randint(0, 1024, size=(2, 32)))
-        labels = jnp.where(rng.rand(2, 32) < 0.15, np.asarray(ids), -100)
-        labels = jnp.asarray(labels)
-        params = BertForMLM(BertConfig.tiny(compute_dtype=jnp.float32)).init(
-            jax.random.PRNGKey(0), ids, labels=labels
-        )
-        losses = {}
-        for policy in ("none", "dots_saveable", "full_block"):
-            cfg = BertConfig.tiny(compute_dtype=jnp.float32,
-                                  remat_policy=policy)
-            _, losses[policy] = BertForMLM(cfg).apply(
-                params, ids, labels=labels
-            )
-        assert float(losses["dots_saveable"]) == pytest.approx(
-            float(losses["none"]), rel=1e-6
-        )
-        assert float(losses["full_block"]) == pytest.approx(
-            float(losses["none"]), rel=1e-6
-        )
+        with force_pallas(kernels):
+            params, loss0, g0 = loss_and_grads("none")
+            for policy in ("dots_saveable", "full_block"):
+                p2, loss, g = loss_and_grads(policy, params)
+                assert loss == pytest.approx(loss0, rel=1e-6)
+                for a, b in zip(jax.tree_util.tree_leaves(g0),
+                                jax.tree_util.tree_leaves(g)):
+                    np.testing.assert_allclose(
+                        np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
+                    )
 
     def test_ring_sharded_layer_matches_single_device(self, mesh8, rng):
         """The same GPTLayer params run with ring attention over a

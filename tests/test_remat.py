@@ -1,0 +1,148 @@
+"""What the block-recomputing policies keep (``apex_tpu/remat.py``): the
+residuals a kernel declares — the flash forward's output and log-sum-exp
+— beside the block's input, so that the backward pass does not run the
+attention forward a second time."""
+import collections
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax._src.ad_checkpoint import saved_residuals  # not exported by jax 0.9
+
+from apex_tpu import obs, remat
+from apex_tpu.analysis.precision import _sub_jaxprs
+from apex_tpu.models import GPTConfig, GPTLM
+from apex_tpu.models.afmoe import FULL, WINDOW, AfmoeConfig, AfmoeLM
+from apex_tpu.ops import attention
+from apex_tpu.ops._common import force_pallas
+
+ROWS, SEQ = 2, 128
+
+
+def tiny_lm(family, policy, layers):
+    """(loss of the parameters, parameters, (batch*heads, key/value
+    batch*heads, head size, hidden)) of a tiny model of ``layers`` blocks,
+    float32, the kernels taken (interpret mode)."""
+    if family == "gpt":
+        cfg = dataclasses.replace(
+            GPTConfig.tiny(compute_dtype=jnp.float32, remat_policy=policy),
+            num_layers=layers)
+        model = GPTLM(cfg)
+        heads = kv_heads = cfg.num_heads
+        head = cfg.hidden_size // cfg.num_heads
+    else:
+        cfg = AfmoeConfig.tiny(
+            compute_dtype=jnp.float32, remat_policy=policy,
+            layer_types=(WINDOW, WINDOW, FULL)[:layers])
+        model = AfmoeLM(cfg)
+        heads, kv_heads, head = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    ids = jax.random.randint(jax.random.PRNGKey(1), (ROWS, SEQ), 0, 250)
+    params = model.init(jax.random.PRNGKey(0), ids, labels=ids)
+
+    def loss(p):
+        return model.apply(p, ids, ids)[1]
+
+    return loss, params, (ROWS * heads, ROWS * kv_heads, head,
+                          cfg.hidden_size)
+
+
+def kept(loss, params):
+    """Shapes and dtypes of what the backward pass is handed, the
+    parameters themselves left out."""
+    return collections.Counter(
+        (a.shape, str(a.dtype)) for a, why in saved_residuals(loss, params)
+        if "from the argument" not in why)
+
+
+def flash_forward_calls(fn, *args):
+    """``apex_flash_fwd`` kernels in the jaxpr of ``fn``, the jaxprs of
+    ``remat``, ``pjit`` and the rest walked."""
+
+    def count(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                n += eqn.params["name"] == "apex_flash_fwd"
+            else:
+                n += sum(count(inner) for inner in _sub_jaxprs(eqn.params))
+        return n
+
+    return count(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("family,layers", [("gpt", 2), ("afmoe", 3)])
+def test_full_block_keeps_input_out_and_lse_a_layer(family, layers):
+    """One block more keeps three arrays more: its input, the flash
+    kernel's output and the (bh, sq) float32 lse — no second array of
+    q's shape, no k3 / v3, no 128-lane lse buffer."""
+    with force_pallas(True):
+        more = kept(*tiny_lm(family, "full_block", layers)[:2])
+        loss, params, (bh, bh_kv, d, hidden) = tiny_lm(
+            family, "full_block", layers - 1)
+        fewer = kept(loss, params)
+    assert not fewer - more
+    assert more - fewer == collections.Counter({
+        ((ROWS, SEQ, hidden), "float32"): 1,
+        ((bh, SEQ, d), "float32"): 1,
+        ((bh, SEQ), "float32"): 1,
+    })
+    assert more[((bh, SEQ, d), "float32")] == layers
+    assert more[((bh, SEQ), "float32")] == layers
+    assert not more[((bh, SEQ, 128), "float32")]
+    if bh_kv != bh:
+        assert not more[((bh_kv, SEQ, d), "float32")]
+
+
+@pytest.mark.parametrize("policy", ["none", "dots_saveable", "full_block"])
+@pytest.mark.parametrize("family,layers", [("gpt", 2), ("afmoe", 3)])
+def test_the_flash_forward_runs_once_a_layer(family, layers, policy):
+    """The gradient's jaxpr holds one ``apex_flash_fwd`` a layer under
+    every policy: the recomputed block finds ``out`` and ``lse`` kept."""
+    with force_pallas(True):
+        loss, params, _ = tiny_lm(family, policy, layers)
+        assert flash_forward_calls(jax.grad(loss), params) == layers
+
+
+def test_policy_without_the_names_runs_the_forward_twice(monkeypatch):
+    """The witness that the test above can fail: a policy that keeps no
+    name makes the forward kernel again in the backward pass."""
+    monkeypatch.setattr(remat, "KEPT_RESIDUAL_NAMES", ())
+    with force_pallas(True):
+        loss, params, _ = tiny_lm("gpt", "full_block", 2)
+        assert flash_forward_calls(jax.grad(loss), params) == 4
+
+
+def test_names_lower_to_nothing_outside_a_checkpoint(monkeypatch):
+    """``flash_attention``'s gradient with no ``jax.checkpoint`` around it
+    lowers to the text it has with the names taken out of the forward
+    rule: the cells that recompute nothing compile the program they had.
+    (The private function's symbol bears a counter, which is no part of
+    the program.)"""
+    q = jax.random.normal(jax.random.PRNGKey(0), (2, 2, 256, 64))
+
+    def lowered():
+        attention._flash_jit.clear_cache()
+        grad = jax.grad(lambda q: jnp.sum(attention.flash_attention(
+            q, q, q, causal=True, use_pallas=True)))
+        return re.sub(r"@_flash_jit_\d+", "@_flash_jit",
+                      jax.jit(grad).lower(q).as_text())
+
+    named = lowered()
+    monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
+    try:
+        assert lowered() == named
+    finally:
+        attention._flash_jit.clear_cache()
+    assert remat.FLASH_OUT not in named and remat.FLASH_LSE not in named
+
+
+@pytest.mark.parametrize("policy", ["dots_saveable", "full_block"])
+def test_gauge_counts_the_names_a_policy_keeps(policy):
+    gauge = obs.default_registry().gauge("remat.kept_names")
+    gauge.set(0)
+    assert remat.checkpoint_policy("none") is None
+    assert gauge.value == 0
+    assert remat.checkpoint_policy(policy) is not None
+    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 2
