@@ -4,8 +4,10 @@ These are the models the reference's examples and kernels exist to serve
 (SURVEY.md §6 benchmark configs): ResNet-50 (imagenet amp O0-O3 + DDP +
 SyncBN), BERT-large (FusedLAMB + fused attention + xentropy), DCGAN
 (multi-model multi-loss-scaler amp), a simple MLP (the minimum
-end-to-end slice), and the two decoders: GPT-2 (``gpt.py``) and the Arcee
-Trinity block with routed experts (``afmoe.py``, training path).
+end-to-end slice), and the three decoders: GPT-2 (``gpt.py``), the Arcee
+Trinity block with sigmoid-routed experts (``afmoe.py``, training path) and
+the Qwen3-Next block — gated-delta-rule linear attention beside gated full
+attention, softmax-routed experts (``qwen3_next.py``, training path).
 """
 from apex_tpu.models.resnet import ResNet, resnet50, resnet101, resnet152  # noqa: F401
 from apex_tpu.models.bert import (  # noqa: F401
@@ -17,4 +19,9 @@ from apex_tpu.models.bert import (  # noqa: F401
 from apex_tpu.models.dcgan import Discriminator, Generator  # noqa: F401
 from apex_tpu.models.gpt import GPTConfig, GPTLayer, GPTLM  # noqa: F401
 from apex_tpu.models.afmoe import AfmoeConfig, AfmoeLayer, AfmoeLM  # noqa: F401
+from apex_tpu.models.qwen3_next import (  # noqa: F401
+    Qwen3NextConfig,
+    Qwen3NextLayer,
+    Qwen3NextLM,
+)
 from apex_tpu.mlp import MLP  # noqa: F401

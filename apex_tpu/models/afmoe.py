@@ -1,6 +1,7 @@
 """Arcee ``afmoe`` decoder LM (Trinity family) on the training path.
 
-The second decoder block of the zoo (``models/gpt.py`` is the first): four
+The second decoder block of the zoo's three (``models/gpt.py`` is the first,
+``models/qwen3_next.py`` the third): four
 RMSNorms a block in sandwich position, grouped-query flash attention with
 an output gate and normed queries and keys, rotary positions on the
 sliding-window layers only (the full-attention layers carry no position

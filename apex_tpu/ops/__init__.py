@@ -6,6 +6,10 @@ TPU-native equivalents of the reference's CUDA kernel zoo (SURVEY.md §2.2):
 - :mod:`apex_tpu.ops.softmax_xentropy` — fused softmax CE (ref xentropy_cuda)
 - :mod:`apex_tpu.ops.attention` — flash attention (ref fast_*_multihead_attn)
 - :mod:`apex_tpu.ops.mlp` — whole-MLP fused chain (ref mlp_cuda)
+- :mod:`apex_tpu.ops.grouped_mm`, :mod:`apex_tpu.ops.moe_rows` — the expert
+  layer's grouped products and row movement (no reference counterpart)
+- :mod:`apex_tpu.ops.gated_delta` — the gated delta rule by chunks, the
+  first sequential operator (no reference counterpart)
 - :mod:`apex_tpu.ops.conv_bn` — fused matmul+BN-stats / BN-apply+matmul
   building blocks (ref groupbn/welford fused epilogues; library-only, see
   the module docstring for the measured RN50 verdict)
@@ -22,5 +26,6 @@ from apex_tpu.ops.softmax_xentropy import (  # noqa: F401
 )
 from apex_tpu.ops.attention import attention_ref, flash_attention  # noqa: F401
 from apex_tpu.ops.grouped_mm import grouped_matmul  # noqa: F401
+from apex_tpu.ops.gated_delta import causal_conv1d_silu, gated_delta_rule  # noqa: F401
 from apex_tpu.ops.mlp import mlp, mlp_ref  # noqa: F401
 from apex_tpu.ops.conv_bn import bn_relu_matmul, matmul_stats  # noqa: F401

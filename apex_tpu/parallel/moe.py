@@ -41,7 +41,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 __all__ = ["MoEMLP", "top_k_routing", "moe_mlp_ref", "ExpertShardMLP",
-           "SwiGLU", "sigmoid_topk_routing", "shard_dispatch"]
+           "SwiGLU", "sigmoid_topk_routing", "softmax_topk_routing",
+           "shard_dispatch"]
 
 
 def top_k_routing(
@@ -193,8 +194,10 @@ def moe_mlp_ref(x, params, num_experts, k, activation=nn.gelu):
 # ---------------------------------------------------------------------------
 #
 # ``MoEMLP`` above gives every expert a fixed capacity and drops what does
-# not fit.  ``ExpertShardMLP`` is the other construction (sigmoid scores,
-# top-k, a shared expert: DeepSeek-V3 / Trinity style): it is told which
+# not fit.  ``ExpertShardMLP`` is the other construction (top-k of sigmoid
+# scores steered by a selection bias, DeepSeek-V3 / Trinity style, or of a
+# softmax over all experts, Qwen3-Next style; a shared expert, with or
+# without a sigmoid gate of its own): it is told which
 # experts it HOLDS, routes every token over ALL ``num_experts``, keeps the
 # token-slots whose expert it holds, sorts them by expert into a row buffer
 # sized for the worst case, runs the grouped SwiGLU over the held experts
@@ -214,6 +217,20 @@ def sigmoid_topk_routing(logits, bias, k: int, route_norm: bool,
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return sel.astype(jnp.int32), w * route_scale
+
+
+def softmax_topk_routing(logits, k: int, norm_topk_prob: bool):
+    """``(sel (T, k) int32, weights (T, k) float32)``: a softmax over ALL
+    experts in float32; the ``k`` largest probabilities are selected and
+    are the weights, renormalised to sum to one where ``norm_topk_prob``.
+    No selection bias, no scale."""
+    w, sel = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), -1), k)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return sel.astype(jnp.int32), w
+
+
+SCORE_FUNCS = ("sigmoid", "softmax")
 
 
 def shard_dispatch(sel, held: Tuple[int, int], capacity: int, tile_rows: int):
@@ -438,12 +455,21 @@ class ExpertShardMLP(nn.Module):
     touch only the tiles that hold rows.  Summed over the shares that
     together hold all experts, the routed parts are the whole layer's.
 
+    ``score_func`` names the router's scores: ``sigmoid``
+    (:func:`sigmoid_topk_routing`: ``route_norm``, ``route_scale`` and the
+    selection bias apply) or ``softmax`` (:func:`softmax_topk_routing`: a
+    softmax over all ``num_experts``, ``route_norm`` renormalises the
+    picked probabilities; no bias, no scale).  ``shared_gate`` multiplies
+    the shared expert's output by ``sigmoid(x w_sg)``, a gate of its own a
+    token.
+
     Parameters: ``router`` (d, num_experts), ``expert_bias`` (num_experts,)
-    (added to the scores for the selection only; zero unless a loss-free
-    balancing update moves it), ``wi`` (held, d, 2 d_ff) gate then up,
-    ``wo`` (held, d_ff, d), and the shared expert ``shared`` (a
-    :class:`SwiGLU` of width ``shared_d_ff``; 0: none).  Scopes
-    ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_shared``.
+    (sigmoid scores only: added to the scores for the selection only; zero
+    unless a loss-free balancing update moves it), ``wi`` (held, d, 2 d_ff)
+    gate then up, ``wo`` (held, d_ff, d), the shared expert ``shared`` (a
+    :class:`SwiGLU` of width ``shared_d_ff``; 0: none) and its gate
+    ``shared_gate`` (d, 1).  Scopes ``moe_router``, ``moe_dispatch``,
+    ``moe_experts``, ``moe_shared``.
 
     Rows and tokens change places by ``ops/moe_rows.py``'s kernels on the
     TPU where the shapes tile (``moe_rows.supported``), else by
@@ -464,6 +490,8 @@ class ExpertShardMLP(nn.Module):
     shared_d_ff: int = 0
     route_norm: bool = True
     route_scale: float = 1.0
+    score_func: str = "sigmoid"
+    shared_gate: bool = False
     compute_dtype: Any = jnp.float32
     tile_rows: Optional[int] = None
     kernel_init: Callable = nn.initializers.lecun_normal()
@@ -476,6 +504,9 @@ class ExpertShardMLP(nn.Module):
 
         t, d = x.shape
         lo, hi = self.experts_held
+        if self.score_func not in SCORE_FUNCS:
+            raise ValueError(f"score_func must be one of {SCORE_FUNCS}, got "
+                             f"{self.score_func!r}")
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError(f"experts_held {self.experts_held} is not a "
                              f"range of the {self.num_experts} experts")
@@ -496,8 +527,10 @@ class ExpertShardMLP(nn.Module):
 
         router = self.param("router", self.kernel_init,
                             (d, self.num_experts), jnp.float32)
-        bias = self.param("expert_bias", nn.initializers.zeros_init(),
-                          (self.num_experts,), jnp.float32)
+        sigmoid = self.score_func == "sigmoid"
+        if sigmoid:
+            bias = self.param("expert_bias", nn.initializers.zeros_init(),
+                              (self.num_experts,), jnp.float32)
         wi = self.param("wi", self.kernel_init, (held, d, 2 * self.d_ff),
                         jnp.float32)
         wo = self.param("wo", self.kernel_init, (held, self.d_ff, d),
@@ -510,8 +543,12 @@ class ExpertShardMLP(nn.Module):
             logits = jnp.matmul(x.astype(jnp.float32),
                                 router.astype(jnp.float32),
                                 precision=jax.lax.Precision.HIGHEST)
-            sel, weights = sigmoid_topk_routing(
-                logits, bias, self.k, self.route_norm, self.route_scale)
+            if sigmoid:
+                sel, weights = sigmoid_topk_routing(
+                    logits, bias, self.k, self.route_norm, self.route_scale)
+            else:
+                sel, weights = softmax_topk_routing(
+                    logits, self.k, self.route_norm)
         with jax.named_scope("moe_dispatch"):
             routing = _route(
                 jax.lax.stop_gradient(sel), (lo, hi), capacity, tile_rows,
@@ -527,6 +564,12 @@ class ExpertShardMLP(nn.Module):
             y = _tokens_from_rows(rows, weights, routing, rows_tile)
         if self.shared_d_ff:
             with jax.named_scope("moe_shared"):
-                y = y + SwiGLU(self.shared_d_ff, dt, self.kernel_init,
-                               name="shared")(x.astype(dt)).astype(jnp.float32)
+                shared = SwiGLU(self.shared_d_ff, dt, self.kernel_init,
+                                name="shared")(x.astype(dt)).astype(jnp.float32)
+                if self.shared_gate:
+                    w_sg = self.param("shared_gate", self.kernel_init,
+                                      (d, 1), jnp.float32)
+                    shared = shared * jax.nn.sigmoid(jnp.matmul(
+                        x.astype(jnp.float32), w_sg.astype(jnp.float32)))
+                y = y + shared
         return y.astype(x.dtype)

@@ -23,14 +23,22 @@ has the trade-off table):
 **Declared residuals.**  A kernel whose result is dear to make again and
 cheap to hold names it with ``jax.ad_checkpoint.checkpoint_name`` under
 an entry of :data:`KEPT_RESIDUAL_NAMES`, and both block-recomputing
-policies keep exactly those.  Today one kernel declares:
+policies keep exactly those.  Today two kernels declare.
 ``ops/attention.py``'s forward rule names its output (``batch*heads x
 seq x head_dim`` in the compute dtype) and its log-sum-exp (``batch*heads
 x seq`` float32) — what its backward reads besides q, k, v, which are
 cheap to make again from the block's input.  Without them the backward
 pass would run the whole attention forward a second time only to hand
-its backward those two arrays.  Outside a ``jax.checkpoint`` a name lowers
-to nothing.
+its backward those two arrays.  ``ops/gated_delta.py``'s chain over the
+chunks names its output (``chunks x heads x chunk x d_v`` float32) and the
+state at each chunk's start (``chunks x heads x d_k x d_v`` float32) — what
+its backward reads besides the chunk-local arrays, which are batched
+products of the block's input — and, of those, the one that is dear:
+each chunk's triangular inverse (``chunks x heads x chunk x chunk``
+float32; ten dependent products at chunks of 64, each a pass over
+HBM).  The chain is the one SEQUENTIAL thing in a block (128 dependent
+steps at 8192 tokens): without the names it would be walked forward
+twice.  Outside a ``jax.checkpoint`` a name lowers to nothing.
 
 One rule at every shape, no threshold: per byte kept, the attention
 forward costs 2 x (keys a query sees) operations at a fifth to a third
@@ -48,6 +56,13 @@ trinity-mini, 1x8192  33.6 MB (hidden     67.1 MB (32 heads x    1.05 MB
 gpt2-small, 16x1024   25.2 MB             25.2 MB                0.79 MB
 bert-large, 12x512    12.6 MB             12.6 MB                0.39 MB
 ====================  ==================  =====================  ===========
+
+A gated-delta-net block (qwen3-next, 1x8192, 32 value heads of 128 x 128)
+keeps its input 33.6 MB, the chain's output 134 MB, the chunk states
+268 MB and the triangular inverses 67 MB; the compile-only rehearsal of that cell's window reads 0.83 GiB
+FEWER temporaries with them kept than without (5.34 against 6.17 GiB:
+the second forward walk's own temporaries were the larger), and three
+``apex_gdn_fwd`` calls a step for six (PERF.md section 6, PR 30).
 
 For GPT and BERT heads x head size = hidden, so ``full_block`` keeps two
 arrays of the input's size a block where it kept one: about 1/9th of
@@ -67,7 +82,10 @@ REMAT_POLICIES = ("none", "dots_saveable", "full_block")
 # here; this module imports only jax.
 FLASH_OUT = "apex_flash_out"
 FLASH_LSE = "apex_flash_lse"
-KEPT_RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE)
+GDN_OUT = "apex_gdn_out"
+GDN_STATES = "apex_gdn_states"
+GDN_TRI = "apex_gdn_tri"
+KEPT_RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_TRI)
 
 
 def checkpoint_policy(policy: Optional[str]):

@@ -1,0 +1,171 @@
+"""``ops/gated_delta.py``: the chunked gated delta rule against the token
+recurrence it is defined by (outputs and every gradient; the kernels in
+interpret mode and the ``lax.scan`` chain), the decays at their strongest,
+the triangular inverse, and the causal convolution in front of the rule."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.ops import gated_delta as gd
+from apex_tpu.ops._common import force_pallas
+
+
+def inputs(seed=0, b=2, s=40, h=3, dk=128, dv=128, a_max=16.0, beta_shift=0.0):
+    """q, k normalised as the model hands them over; head 0 decays at
+    ``a_max`` (the strongest ``A = exp(A_log)`` the initialisation draws),
+    the others at 1 and 0.01."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+    q = l2(jax.random.normal(ks[0], (b, s, h, dk))) * dk ** -0.5
+    k = l2(jax.random.normal(ks[1], (b, s, h, dk)))
+    v = jax.random.normal(ks[2], (b, s, h, dv))
+    a = 3.0 * jax.random.normal(ks[3], (b, s, h))
+    rates = jnp.array([a_max, 1.0, 0.01])[:h]
+    g = -rates * jax.nn.softplus(a + 1.0)
+    beta = jax.nn.sigmoid(4.0 * jax.random.normal(ks[4], (b, s, h)) + beta_shift)
+    return q, k, v, g, beta
+
+
+def gap(a, b):
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+CASES = {
+    "strongest_decay": dict(a_max=16.0),
+    "beta_near_0": dict(beta_shift=-12.0),
+    "beta_near_1": dict(beta_shift=12.0),
+    "not_whole_chunks": dict(s=37),
+    "one_short_chunk": dict(s=5),
+}
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["scan", "pallas"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_chunked_rule_matches_the_recurrence(case, kernels):
+    """Outputs and all five gradients, chunks of 16."""
+    args = inputs(**CASES[case])
+    want = gd.gated_delta_rule_recurrent(*args)
+    ct = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * ct),
+                                argnums=(0, 1, 2, 3, 4))(*args)
+    chunked = lambda *a: gd.gated_delta_rule(*a, chunk=16)
+    with force_pallas(kernels):
+        got = chunked(*args)
+        got_grads = grads(chunked)
+    assert gap(got, want) < 1e-5
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got_grads,
+                          grads(gd.gated_delta_rule_recurrent)):
+        assert bool(jnp.isfinite(a).all()), name
+        assert gap(a, b) < 1e-4, name
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["scan", "pallas"])
+def test_a_whole_chunk_at_the_strongest_decay_stays_finite(kernels):
+    """-21 a token, -1300 over a chunk of 64: ``exp(G_i) * exp(-G_j)`` would
+    overflow float32 inside the chunk; ``exp(G_i - G_j)`` for i >= j does
+    not, and the result is still the recurrence's."""
+    q, k, v, g, beta = inputs(b=1, s=128, h=2)
+    g = jnp.full_like(g, -21.0).at[..., 1].set(-0.5)
+    assert float(jnp.sum(g[0, :64, 0])) < -1300
+    with np.errstate(over="ignore"):
+        assert not np.isfinite(np.exp(np.float32(1300.0)))
+    loss = lambda fn: lambda *a: jnp.sum(jnp.square(fn(*a)))
+    with force_pallas(kernels):
+        got = gd.gated_delta_rule(q, k, v, g, beta, chunk=64)
+        grads = jax.grad(loss(lambda *a: gd.gated_delta_rule(*a, chunk=64)),
+                         argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    assert bool(jnp.isfinite(got).all())
+    assert all(bool(jnp.isfinite(x).all()) for x in grads)
+    assert gap(got, gd.gated_delta_rule_recurrent(q, k, v, g, beta)) < 1e-5
+    want = jax.grad(loss(gd.gated_delta_rule_recurrent),
+                    argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    for a, b in zip(grads, want):
+        assert gap(a, b) < 1e-4
+
+
+def test_kernels_and_scan_chain_agree():
+    """``apex_gdn_fwd`` / ``apex_gdn_bwd`` (interpret mode) against the
+    ``lax.scan`` chain on the same chunk-local arrays: outputs, the states
+    at the chunks' starts, and all six gradients."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 7)
+    n, bh, c, d = 3, 4, 8, 128
+    w, u0, qg, kd = (0.3 * jax.random.normal(k, (n, bh, c, d)) for k in ks[:4])
+    p = jnp.tril(jax.random.normal(ks[4], (n, bh, c, c)))
+    decay = jax.random.uniform(ks[5], (n, bh))
+    do = jax.random.normal(ks[6], (n, bh, c, d))
+    o_s, st_s = gd._chain_fwd_scan(w, u0, qg, p, kd, decay)
+    o_k, st_k = gd._chain_fwd_pallas(w, u0, qg, p, kd, decay)
+    assert gap(o_k, o_s) < 1e-5 and gap(st_k, st_s) < 1e-5
+    assert float(jnp.max(jnp.abs(st_s[0]))) == 0.0          # a zero start
+    for a, b in zip(gd._chain_bwd_pallas(w, u0, qg, p, kd, decay, st_s, do),
+                    gd._chain_bwd_scan(w, u0, qg, p, kd, decay, st_s, do)):
+        assert a.shape == b.shape and gap(a, b) < 1e-5
+
+
+def test_triangular_inverse_and_its_gradient():
+    a = jnp.tril(jax.random.normal(jax.random.PRNGKey(3), (2, 3, 16, 16)), -1)
+    t = gd.tri_inverse(a)
+    eye = jnp.eye(16)
+    np.testing.assert_allclose(t @ (eye + a), jnp.broadcast_to(eye, a.shape),
+                               atol=2e-4)
+    ct = jax.random.normal(jax.random.PRNGKey(4), a.shape)
+    got = jax.grad(lambda x: jnp.sum(gd.tri_inverse(x) * ct))(a)
+    want = jax.grad(lambda x: jnp.sum(
+        jnp.linalg.inv(eye + jnp.tril(x, -1)) * ct))(a)
+    assert gap(got, want) < 1e-4
+    assert float(jnp.max(jnp.abs(jnp.triu(got)))) == 0.0
+
+
+def test_convolution_is_causal_from_the_rows_start():
+    """Against the sum written out position by position: zeros before the
+    row's start, no tap reaches forward, rows do not see each other."""
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(5), (2, 9, 6)))
+    w = np.asarray(jax.random.normal(jax.random.PRNGKey(6), (6, 4)))
+    want = np.zeros_like(x)
+    for t in range(9):
+        for j in range(4):
+            if t - 3 + j >= 0:
+                want[:, t] += w[:, j] * x[:, t - 3 + j]
+    want = want / (1.0 + np.exp(-want))                       # SiLU
+    got = gd.causal_conv1d_silu(jnp.asarray(x), jnp.asarray(w))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # the first output sees the first input through the LAST tap alone
+    np.testing.assert_allclose(
+        got[:, 0], jax.nn.silu(x[:, 0] * w[:, 3]), rtol=1e-5, atol=1e-6)
+    later = x.copy()
+    later[:, 5:] += 1.0
+    np.testing.assert_array_equal(
+        gd.causal_conv1d_silu(jnp.asarray(later), jnp.asarray(w))[:, :5], got[:, :5])
+    assert gd.causal_conv1d_silu(jnp.asarray(x, jnp.bfloat16),
+                                 jnp.asarray(w)).dtype == jnp.bfloat16
+
+
+def test_rule_refuses_what_it_cannot_tile_and_sets_its_gauges():
+    from apex_tpu import obs
+
+    q, k, v, g, beta = inputs(b=1, s=40, h=2)
+    with pytest.raises(ValueError, match="power of two"):
+        gd.gated_delta_rule(q, k, v, g, beta, chunk=24)
+    with pytest.raises(ValueError, match="128 lanes"):
+        gd.gated_delta_rule(q[..., :64], k[..., :64], v, g, beta,
+                            use_pallas=True)
+    assert gd.supported(64, 128, 128) and not gd.supported(64, 64, 128)
+    gd.gated_delta_rule(q, k, v, g, beta, chunk=16)
+    reg = obs.default_registry()
+    assert reg.get("gdn.chunk").value == 16
+    assert reg.get("gdn.chunks_per_row").value == 3
+    assert reg.get("gdn.value_heads").value == 2
+    assert reg.get("gdn.kernels").value == 0
+    assert gd.gated_delta_rule(q.astype(jnp.bfloat16), k, v.astype(jnp.bfloat16),
+                               g, beta).dtype == jnp.bfloat16
+
+
+def test_kernel_names_keep_clear_of_the_other_families():
+    from apex_tpu.ops._common import KERNEL_NAMES
+
+    ours = [n for n in KERNEL_NAMES if n.startswith("apex_gdn_")]
+    assert sorted(ours) == ["apex_gdn_bwd", "apex_gdn_fwd"]
+    for name in ours:
+        assert not any(f in name for f in
+                       ("apex_gmm", "apex_flash", "apex_ln_", "apex_xent_"))

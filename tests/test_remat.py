@@ -56,15 +56,15 @@ def kept(loss, params):
         if "from the argument" not in why)
 
 
-def flash_forward_calls(fn, *args):
-    """``apex_flash_fwd`` kernels in the jaxpr of ``fn``, the jaxprs of
-    ``remat``, ``pjit`` and the rest walked."""
+def flash_forward_calls(fn, *args, kernel="apex_flash_fwd"):
+    """``apex_flash_fwd`` kernels (or another ``kernel``) in the jaxpr of
+    ``fn``, the jaxprs of ``remat``, ``pjit`` and the rest walked."""
 
     def count(jaxpr):
         n = 0
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                n += eqn.params["name"] == "apex_flash_fwd"
+                n += eqn.params["name"] == kernel
             else:
                 n += sum(count(inner) for inner in _sub_jaxprs(eqn.params))
         return n
@@ -114,6 +114,55 @@ def test_policy_without_the_names_runs_the_forward_twice(monkeypatch):
         assert flash_forward_calls(jax.grad(loss), params) == 4
 
 
+@pytest.mark.parametrize("keep,walks", [(True, 3), (False, 6)],
+                         ids=["names_kept", "names_dropped"])
+def test_the_delta_rules_chain_walks_forward_once_a_layer(monkeypatch, keep,
+                                                          walks):
+    """A gated-delta-net block under ``full_block`` finds the chain's
+    output and chunk states kept (``apex_gdn_out``, ``apex_gdn_states``):
+    one ``apex_gdn_fwd`` a linear-attention layer in the gradient, two
+    where a policy keeps no name; one ``apex_gdn_bwd`` either way."""
+    from apex_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
+
+    if not keep:
+        monkeypatch.setattr(remat, "KEPT_RESIDUAL_NAMES", ())
+    model = Qwen3NextLM(Qwen3NextConfig.tiny(
+        compute_dtype=jnp.float32, remat_policy="full_block"))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, SEQ), 0, 250)
+    with force_pallas(True):
+        params = model.init(jax.random.PRNGKey(0), ids, labels=ids)
+        grad = jax.grad(lambda p: model.apply(p, ids, ids)[1])
+        assert flash_forward_calls(grad, params, kernel="apex_gdn_fwd") == walks
+        assert flash_forward_calls(grad, params, kernel="apex_gdn_bwd") == 3
+        assert flash_forward_calls(grad, params) == (1 if keep else 2)
+
+
+def test_the_triangular_inverse_is_not_made_again(monkeypatch):
+    """``apex_gdn_tri`` kept: the recomputed block finds each chunk's ``(I +
+    A)^-1`` and leaves out its doubling steps — at chunks of 64, five steps
+    of two products in each of the three linear-attention layers."""
+    from apex_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
+
+    def products():
+        model = Qwen3NextLM(Qwen3NextConfig.tiny(
+            compute_dtype=jnp.float32, remat_policy="full_block"))
+        ids = jax.random.randint(jax.random.PRNGKey(1), (1, SEQ), 0, 250)
+        params = model.init(jax.random.PRNGKey(0), ids, labels=ids)
+
+        def count(jaxpr):
+            return sum(1 if eqn.primitive.name == "dot_general" else
+                       sum(count(inner) for inner in _sub_jaxprs(eqn.params))
+                       for eqn in jaxpr.eqns)
+
+        return count(jax.make_jaxpr(
+            jax.grad(lambda p: model.apply(p, ids, ids)[1]))(params).jaxpr)
+
+    kept = products()
+    monkeypatch.setattr(remat, "KEPT_RESIDUAL_NAMES", tuple(
+        n for n in remat.KEPT_RESIDUAL_NAMES if n != remat.GDN_TRI))
+    assert products() - kept == 3 * 5 * 2
+
+
 def test_names_lower_to_nothing_outside_a_checkpoint(monkeypatch):
     """``flash_attention``'s gradient with no ``jax.checkpoint`` around it
     lowers to the text it has with the names taken out of the forward
@@ -145,4 +194,4 @@ def test_gauge_counts_the_names_a_policy_keeps(policy):
     assert remat.checkpoint_policy("none") is None
     assert gauge.value == 0
     assert remat.checkpoint_policy(policy) is not None
-    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 2
+    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 5

@@ -160,6 +160,19 @@ def _named_case(kernel):
 
         return (jax.value_and_grad(loss, argnums=(0, 1)),
                 [((8192, 2048), BF16), ((8192, 8), F32), ((8192, 8), I32)])
+    if kernel.startswith("apex_gdn_"):
+        # the gated delta rule at qwen3-next.train-8k's own shape: one
+        # 8192-token row, 32 value heads of 128 x 128 float32 state, 128
+        # chunks of 64 (ops/gated_delta.py), forward and backward
+        from apex_tpu.ops.gated_delta import gated_delta_rule
+
+        def loss(q, k, v, g, beta):
+            with jax.named_scope("gdn_scan"):
+                return jnp.sum(gated_delta_rule(q, k, v, g, beta).astype(F32))
+
+        qkv, gb = ((1, 8192, 32, 128), F32), ((1, 8192, 32), F32)
+        return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                [qkv, qkv, qkv, gb, gb])
     if kernel.startswith("apex_xent_"):
         from apex_tpu.ops import softmax_cross_entropy
 
@@ -180,7 +193,7 @@ _NAMES_OF_CASE = {}
     "apex_ln_bwd_dx", "apex_ln_bwd_dx_dwdb", "apex_xent_fwd",
     "apex_xent_bwd", "apex_paged_attn", "apex_gmm", "apex_gmm_dw",
     "apex_moe_records", "apex_moe_gather", "apex_moe_combine",
-    "apex_moe_combine_dw",
+    "apex_moe_combine_dw", "apex_gdn_fwd", "apex_gdn_bwd",
 ])
 def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     """The custom call's HLO instruction — what a device trace names the
@@ -189,8 +202,10 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     from apex_tpu.ops._common import KERNEL_NAMES
 
     assert kernel in KERNEL_NAMES
-    # (the four apex_moe_* kernels are one program: compiled once)
-    case = "apex_moe_" if kernel.startswith("apex_moe_") else kernel
+    # (the four apex_moe_* kernels are one program, the two apex_gdn_*
+    # another: each compiled once)
+    case = next((f for f in ("apex_moe_", "apex_gdn_") if kernel.startswith(f)),
+                kernel)
     if case not in _NAMES_OF_CASE:
         fn, avals = _named_case(kernel)
         _NAMES_OF_CASE[case] = _mosaic_names(chip, fn, *avals)
@@ -271,6 +286,48 @@ def test_flash_window_grouped_heads_compiles(chip, as_tpu, window):
     names = _mosaic_names(chip, jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     assert names == ["apex_flash_fwd", "apex_flash_bwd_dkdv",
                      "apex_flash_bwd_dq"], names
+
+
+def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
+    """Qwen3-Next's attention call: 16 query heads to 2 key/value heads of
+    size 256 at 8192 positions — the grouped route at twice the head size
+    the auto blocks (512 x 1024) were sized for."""
+    from apex_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        with jax.named_scope("attn_full"):
+            return jnp.sum(flash_attention(q, k, v, causal=True).astype(F32))
+
+    q, kv = ((1, 16, 8192, 256), BF16), ((1, 2, 8192, 256), BF16)
+    names = _mosaic_names(chip, jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert names == ["apex_flash_fwd", "apex_flash_bwd_dkdv",
+                     "apex_flash_bwd_dq"], names
+
+
+def test_moe_row_movement_compiles_at_90112_rows_and_81920_slots(chip, as_tpu):
+    """qwen3-next.train-8k's expert layer: 8192 tokens x 10 slots, 32 held
+    experts, a buffer of (8192 * 10 / 256 + 32) * 256 = 90,112 rows — 30%
+    more indices and weights in scalar prefetch than trinity-mini's."""
+    from apex_tpu.ops import grouped_mm as gmm
+    from apex_tpu.ops import moe_rows
+    from apex_tpu.parallel import moe
+
+    cap = gmm.rows_capacity(10 * 8192, 32)
+    assert cap == 90112
+    assert moe_rows.supported(8192, 10, 2048, gmm.DEFAULT_TILE_ROWS, BF16)
+
+    def loss(x, w, sel):
+        routing = moe._route(sel, (0, 32), cap, gmm.DEFAULT_TILE_ROWS,
+                             moe_rows.combine_block(8192, 10, 2048))
+        rows = moe._rows_from_tokens(x, routing, gmm.DEFAULT_TILE_ROWS)
+        return jnp.sum(moe._tokens_from_rows(rows, w, routing,
+                                             gmm.DEFAULT_TILE_ROWS))
+
+    names = _mosaic_names(
+        chip, jax.value_and_grad(loss, argnums=(0, 1)),
+        ((8192, 2048), BF16), ((8192, 10), F32), ((8192, 10), I32))
+    assert {"apex_moe_records", "apex_moe_gather", "apex_moe_combine",
+            "apex_moe_combine_dw"} <= set(names), names
 
 
 # -- fused LayerNorm: forward, dx, dx + dgamma/dbeta epilogue ---------------
