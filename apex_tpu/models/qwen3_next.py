@@ -22,8 +22,9 @@ The equations (no biases anywhere; embeddings not scaled; head untied)::
       cat(q, k, v) -> causal depthwise conv (kernel 4, zeros before the
       row's start, no bias) -> SiLU -> split back
       beta = sigmoid(b);  g = -exp(A_log) * softplus(a + dt_bias)   float32
-      q, k repeated to the H_v value heads (repeat_interleave r), each
-      x * rsqrt(sum x^2 + 1e-6);  q times d_k^-0.5
+      q, k each x * rsqrt(sum x^2 + 1e-6);  q times d_k^-0.5;  value head
+      h reads key head h // r (the source's repeat_interleave r, made by the
+      rule's kernels through their index maps, never as an array)
       per head, float32, S_0 = 0 (d_k, d_v):
         S <- exp(g_t) S;  r = v_t - S^T k_t;  S <- S + k_t (beta_t r)^T;  o_t = S^T q_t
       o <- rsqrt(mean(o^2) + eps) o * w_norm * silu(z)   per head, w_norm init 1
@@ -50,8 +51,12 @@ Called as :class:`apex_tpu.models.gpt.GPTLM` and ``AfmoeLM`` are:
 ``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a
 sliced ``vocab_size``, as in ``models/afmoe.py``.  Scopes ``gdn_proj``,
 ``gdn_conv``, ``gdn_scan``, ``gdn_out``, ``attn_full``, the four ``moe_*``,
-``lm_head``, ``lm_loss``.  Serving methods are not part of this model yet:
-a recurrent state beside K/V pages is ROADMAP M6's other half.
+``lm_head``, ``lm_loss``.  Under ``gdn_scan`` XLA keeps what is a row's own
+(sigmoid, softplus, the two l2 norms, the casts) and the rule is two kernels
+that read q, k, v in the compute dtype as this model lays them out and write
+o (``ops/gated_delta.py``): no float32 array of q's size crosses HBM there.
+Serving methods are not part of this model yet: a recurrent state beside K/V
+pages is ROADMAP M6's other half.
 """
 from __future__ import annotations
 
@@ -199,13 +204,16 @@ class GatedDeltaNet(nn.Module):
             beta = jax.nn.sigmoid(f32(beta_in.reshape(b, s, hv)))
             g = -jnp.exp(f32(a_log)) * jax.nn.softplus(
                 f32(a.reshape(b, s, hv)) + f32(dt_bias))
+            # q and k stay at their 16 KEY heads (a value head reads its key
+            # head's rows inside the kernels: no repeat), normalised in
+            # float32; the kernels take them in v's dtype, a cast XLA fuses
+            # into the norm, so no float32 copy of q, k or v crosses HBM
             l2 = lambda t: t * jax.lax.rsqrt(
                 jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-            to_value_heads = lambda t: jnp.repeat(
-                l2(f32(t.reshape(b, s, hk, dk))), r, axis=2)
-            o = gated_delta_rule(
-                to_value_heads(q) * dk ** -0.5, to_value_heads(k),
-                f32(v.reshape(b, s, hv, dv)), g, beta)
+            key_heads = lambda t: l2(f32(t.reshape(b, s, hk, dk)))
+            o = f32(gated_delta_rule(
+                key_heads(q) * dk ** -0.5, key_heads(k),
+                v.reshape(b, s, hv, dv), g, beta))
         with jax.named_scope("gdn_out"):
             w_norm = self.param("norm", nn.initializers.ones_init(),
                                 (dv,), jnp.float32)

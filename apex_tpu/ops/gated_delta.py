@@ -1,5 +1,6 @@
-"""The gated delta rule over a sequence, by chunks — Pallas TPU kernels + a
-``lax.scan`` path — and the short causal depthwise convolution in front of it.
+"""The gated delta rule over a sequence, by chunks — two Pallas TPU kernels
+that hold everything between q, k, v, g, beta and o in VMEM, and a ``lax.scan``
+path — and the short causal depthwise convolution in front of it.
 
 The first sequential operator of ``ops/``: a linear-attention layer
 (``models/qwen3_next.py``'s gated delta net) keeps, per head, a float32
@@ -22,24 +23,58 @@ so with ``T = (I + A)^-1``, ``W = T (beta exp(G) K)`` and ``U0 = T (beta V)``::
     O  = (exp(G) Q) S_0 + (M . Q K^T) U,        M_ij = exp(G_i - G_j)  (j <= i)
     S' = exp(G_C) S_0 + (exp(G_C - G) K)^T U
 
-Everything but those three lines is local to a chunk and is computed for all
-chunks at once by batched products (:func:`_chunk_local`, plain ``jax.numpy``,
-differentiated by JAX; ``T`` by :func:`tri_inverse`).  The three lines touch
+Everything but those three lines is local to a chunk; the three lines touch
 the state by three ``(C, d_k) x (d_k, d_v)``-shaped products and chain the
-chunks: :func:`_chain`, a ``custom_vjp`` whose forward and backward each walk
-the chunks once — kernels ``apex_gdn_fwd`` / ``apex_gdn_bwd`` on the TPU
-(grid (heads, chunks), the state in VMEM scratch), ``lax.scan`` elsewhere and
-as the kernels' oracle (end to end in ``qwen3-next.train-8k`` the kernels
-are worth 1.3-1.7% tokens/s over the scan, PERF.md section 5).  The forward
-keeps the state at each chunk's START (``chunks x heads x d_k x d_v``
-float32), never a state per token.
+chunks.
+
+**On the TPU the whole rule is two kernels.**  ``apex_gdn_fwd`` (grid (rows,
+head groups, chunks), the float32 states of a group's value heads in VMEM
+scratch) reads a chunk's q and k at their KEY head, v, ``beta`` and the
+running sum ``G`` — q, k, v as blocks of the arrays the model has, ``(B, S,
+heads x d)`` in its compute dtype, no transposed and no float32 copy, a value
+head ``h`` reading key head ``h // r`` — makes the decays, ``K K^T``, ``A``,
+``T`` (the doubling steps of :func:`_tri_inverse` on 64 x 64 tiles), ``Q K^T``
+in VMEM, runs the three lines and writes o, the state at each chunk's START
+(``B x chunks x heads x d_k x d_v`` float32, never a state per token) and
+``T`` (in the operands' dtype: a product is all that ever reads it).
+``apex_gdn_bwd`` walks the chunks from the last with ``dS`` in scratch: from
+q, k, v, ``beta``, ``G``, the chunk's starting state, ``T`` and ``do`` it makes
+the forward's values again, transposes the three lines, and carries on —
+still in VMEM — through ``T`` (``dA = -T^T dT T^T``), ``A``, ``Q K^T`` and
+every decay to dq, dk (summed over a key head's value heads), dv, dbeta and
+dG.  So the rule's HBM traffic is its inputs, its outputs and one state and
+one ``T`` a chunk: what ``_chunk_local`` writes for the scan path — W, U0,
+Qg, Kd, P, float32 arrays of q's size — never exists (PERF.md section 6, PR
+31: 1.8 + 2.3 ms a layer against 10.5 + 12.2).  XLA keeps the (B, S, H_v)
+arrays: ``G`` is ``jnp.cumsum`` of ``g`` inside each chunk, laid out twice (a
+kernel reads a head's column (C, 1) from one and its row (1, C) from the
+other), and dg is dG summed back over the chunk — 1 MB each.  Both kernels
+are written a LINE of the arithmetic at a time over the heads of a grid
+step, because a head's products wait on each other.
+
+**Off the TPU, and as the kernels' oracle,** what is local to a chunk is
+computed for all chunks at once by batched products (:func:`_chunk_local`,
+plain ``jax.numpy``, differentiated by JAX; ``T`` by :func:`tri_inverse`) and
+the chunks are chained by ``lax.scan`` (:func:`_chain`, a ``custom_vjp``
+whose forward and backward each walk the chunks once), in float32, q and k
+repeated to the value heads.  The same path takes the shapes
+:func:`supported` refuses.
+
+**Precision.**  Float32 arithmetic with the products at default precision:
+on the TPU one bfloat16 rounding of each operand, float32 accumulation.  In
+the kernels an operand is cast to v's dtype — the model's compute dtype; q
+and k are cast to it on the way in — where it enters a product and nowhere
+else; ``S``, ``dS``, ``G``, every decay and ``T``'s entries between
+the doubling steps stay float32.  With float32 inputs (the tests, in
+interpret mode) the kernels compute in float32 throughout.
 
 **The trap.**  A head's log-decay reaches -21 a token (``A_log = log 16``,
 ``softplus`` of a large ``a``), -1300 over a chunk of 64.  Every decay here
 is built as ``exp(G_i - G_j)`` for ``i >= j`` ONLY — a difference of the
 running sum that is never positive, masked BEFORE the exponential.  Factored
 as ``exp(G_i) * exp(-G_j)`` the second factor overflows float32 inside one
-chunk (``tests/test_ops_gated_delta.py`` runs the strongest decay).
+chunk (``tests/test_ops_gated_delta.py`` runs the strongest decay, through
+the scan path and through both kernels).
 """
 from __future__ import annotations
 
@@ -59,9 +94,10 @@ __all__ = ["gated_delta_rule", "gated_delta_rule_recurrent",
            "causal_conv1d_silu", "tri_inverse", "DEFAULT_CHUNK"]
 
 DEFAULT_CHUNK = 64
-#: heads a grid step of the kernels takes together (the largest that
-#: divides batch x heads): a step's work is small, its fixed cost is not
-_HEADS_PER_STEP = (8, 4, 2, 1)
+#: value heads a grid step of the kernels takes together, at most: a step's
+#: work is small and its fixed cost is not, and the heads' dependent
+#: products hide each other's latency
+_HEADS_PER_STEP = 8
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +223,7 @@ def _chunk_local(q, k, v, g, beta):
 
 
 # ---------------------------------------------------------------------------
-# the chain over the chunks: lax.scan
+# the chain over the chunks (lax.scan): the path off the TPU, the oracle
 # ---------------------------------------------------------------------------
 
 def _chain_fwd_scan(w, u0, qg, p, kd, c):
@@ -222,7 +258,35 @@ def _chain_bwd_scan(w, u0, qg, p, kd, c, states, do):
 
 
 # ---------------------------------------------------------------------------
-# the chain over the chunks: kernels
+# the scan path's chain, differentiable
+# ---------------------------------------------------------------------------
+
+@jax.custom_vjp
+def _chain(w, u0, qg, p, kd, c):
+    """``O`` (N, BH, C, d_v) float32 of the chunks chained from a zero
+    state (the three lines of the module's docstring)."""
+    return _chain_fwd_scan(w, u0, qg, p, kd, c)[0]
+
+
+def _chain_fwd_rule(w, u0, qg, p, kd, c):
+    o, states = _chain_fwd_scan(w, u0, qg, p, kd, c)
+    # declared to the block-recomputing policies (apex_tpu.remat), as the
+    # flash kernel declares its output: where a policy keeps these names
+    # the backward pass does not walk the chunks forward a second time
+    o = checkpoint_name(o, GDN_OUT)
+    states = checkpoint_name(states, GDN_STATES)
+    return o, (w, u0, qg, p, kd, c, states)
+
+
+def _chain_bwd_rule(res, do):
+    return _chain_bwd_scan(*res, do.astype(jnp.float32))
+
+
+_chain.defvjp(_chain_fwd_rule, _chain_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
+# the rule in kernels: what is local to a chunk made in VMEM
 # ---------------------------------------------------------------------------
 
 _NN = (((1,), (0,)), ((), ()))      # a @ b
@@ -234,138 +298,329 @@ def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(c_ref, w_ref, u0_ref, qg_ref, p_ref, kd_ref, o_ref, s_ref,
-                state, *, heads: int, total_heads: int):
-    n = pl.program_id(1)
+def _heads_per_step(hk: int, hv: int):
+    """``(key heads, value heads)`` a grid step of the kernels takes: whole
+    key heads, each with its ``hv // hk`` value heads, the most that divide
+    ``hk`` and stay within :data:`_HEADS_PER_STEP` value heads (one key
+    head where its value heads alone are more)."""
+    r = hv // hk
+    kb = max((n for n in range(1, hk + 1)
+              if hk % n == 0 and n * r <= _HEADS_PER_STEP), default=1)
+    return kb, kb * r
 
-    @pl.when(n == 0)
+
+def _chunk_masks(c):
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return row, col
+
+
+def _decays(gc, gr, row, col):
+    """``(M, gamma, delta, gamma_C)`` of one head from its running sum as a
+    column ``gc`` (C, 1) and as a row ``gr`` (1, C): ``M_ij = exp(G_i -
+    G_j)`` for ``j <= i`` — the difference clamped at 0 BEFORE the
+    exponential, so the upper triangle's positive differences never reach
+    it —, ``exp(G)``, ``exp(G_C - G)`` and ``exp(G_C)``."""
+    c = gc.shape[0]
+    eye = (row == col).astype(jnp.float32)
+    decay = jnp.where(row > col, jnp.exp(jnp.minimum(gc - gr, 0.0)), eye)
+    last = gc[c - 1:c, :]
+    return decay, jnp.exp(gc), jnp.exp(jnp.minimum(last - gc, 0.0)), \
+        jnp.exp(last)
+
+
+def _tri_inverse_tiles(tiles, row, col, mx):
+    """:func:`_tri_inverse` on (C, C) tiles inside a kernel, the tiles'
+    doubling steps side by side: a step's two products wait on each other,
+    those of different tiles do not.  ``T`` stays float32 between the
+    steps; the products take ``mx``'s operands."""
+    c = tiles[0].shape[0]
+    quarter = lambda s: (((row >> (s + 1)) == (col >> (s + 1)))
+                         & ((row >> s) > (col >> s)))
+    eye = (row == col).astype(jnp.float32)
+    ts = [eye - jnp.where(quarter(0), a, 0.0) for a in tiles]
+    s = 1
+    while (1 << s) < c:
+        tes = [mx(_dot(mx(t), mx(jnp.where(quarter(s), a, 0.0)), _NN))
+               for t, a in zip(ts, tiles)]
+        ts = [t - _dot(te, mx(t), _NN) for t, te in zip(ts, tes)]
+        s += 1
+    return ts
+
+
+def _step_heads(q_ref, k_ref, gc_ref, gr_ref, bc_ref, dk, r, heads):
+    """What both kernels make first, for every value head of the grid step
+    (lists by head; a key head's entries shared by its ``r`` value heads):
+    ``q``, ``k`` (C, d_k), ``K K^T``, ``Q K^T``, the decays and ``beta``
+    (C, 1)."""
+    c = q_ref.shape[1]
+    row, col = _chunk_masks(c)
+    g_cols, g_rows, betas = gc_ref[0, 0], gr_ref[0, 0, 0], bc_ref[0, 0]
+    q = [q_ref[0, :, i * dk:(i + 1) * dk] for i in range(heads // r)]
+    k = [k_ref[0, :, i * dk:(i + 1) * dk] for i in range(heads // r)]
+    kk = [_dot(x, x, _NT) for x in k]
+    qk = [_dot(x, y, _NT) for x, y in zip(q, k)]
+    of_value_head = lambda xs: [xs[h // r] for h in range(heads)]
+    decays = [_decays(g_cols[:, h:h + 1], g_rows[h:h + 1, :], row, col)
+              for h in range(heads)]
+    return (row, col, *map(of_value_head, (q, k, kk, qk)), *zip(*decays),
+            [betas[:, h:h + 1] for h in range(heads)])
+
+
+def _each(fn, *lists):
+    return [fn(*xs) for xs in zip(*lists)]
+
+
+# Both kernels are written a LINE of the arithmetic at a time over all heads
+# of the grid step, not a head at a time: a head's products wait on each
+# other (ten in a row in the inverse, four from ``K S`` to the new state)
+# and a 64-row product alone leaves the MXU idle for most of its latency —
+# side by side the heads' chains fill it (one layer at qwen3-next's shape,
+# forward / with gradients: 1.83 / 4.15 ms against 5.50 / 9.41 a head at a
+# time, PERF.md section 5, PR 31).
+
+def _rule_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref,
+                     o_ref, s_ref, t_ref, state, *, key_heads: int, r: int):
+    @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
+    f32 = jnp.float32
+    dk, dv = state.shape[1:]
+    heads = key_heads * r
+    mx = lambda x: x.astype(q_ref.dtype)    # an operand, as it enters a product
+    (row, col, q, k, kk, qk, decay, gamma, delta, gamma_c,
+     beta) = _step_heads(q_ref, k_ref, gc_ref, gr_ref, bc_ref, dk, r, heads)
+    t = _tri_inverse_tiles(
+        _each(lambda b, m, x: jnp.where(row > col, b * m * x, 0.0),
+              beta, decay, kk), row, col, mx)
+    s = [state[h] for h in range(heads)]
+    sm = _each(mx, s)
+    v = [v_ref[0, :, h * dv:(h + 1) * dv].astype(f32) for h in range(heads)]
+    ks = _each(lambda x, y: _dot(x, y, _NN), k, sm)
+    qs = _each(lambda x, y: _dot(x, y, _NN), q, sm)
+    rhs = _each(lambda b, x, g, y: mx(b * (x - g * y)), beta, v, gamma, ks)
+    u = _each(lambda x, y: mx(_dot(mx(x), y, _NN)), t, rhs)
+    o = _each(lambda g, x, m, y, z: g * x + _dot(mx(m * y), z, _NN),
+              gamma, qs, decay, qk, u)
+    new = _each(lambda g, x, d, y, z: (g + jnp.zeros((1, dv), f32)) * x
+                + _dot(mx(d * y.astype(f32)), z, _TN),
+                gamma_c, s, delta, k, u)
     for h in range(heads):
-        s = state[h]
-        s_ref[0, h] = s
-        u = u0_ref[0, h] - _dot(w_ref[0, h], s, _NN)
-        o_ref[0, h] = _dot(qg_ref[0, h], s, _NN) + _dot(p_ref[0, h], u, _NN)
-        decay = c_ref[n * total_heads + pl.program_id(0) * heads + h]
-        state[h] = decay * s + _dot(kd_ref[0, h], u, _TN)
+        s_ref[0, 0, h] = s[h]
+        t_ref[0, 0, h] = mx(t[h])
+        o_ref[0, :, h * dv:(h + 1) * dv] = o[h].astype(o_ref.dtype)
+        state[h] = new[h]
 
 
-def _bwd_kernel(c_ref, w_ref, u0_ref, qg_ref, p_ref, kd_ref, s_ref, do_ref,
-                dw_ref, du_ref, dqg_ref, dp_ref, dkd_ref, dc_ref, dstate, *,
-                heads: int, total_heads: int, chunks: int):
-    i = pl.program_id(1)
-    n = chunks - 1 - i              # the chunks are walked from the last
-
-    @pl.when(i == 0)
+def _rule_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, s_ref,
+                     t_ref, do_ref, dq_ref, dk_ref, dv_ref, dgc_ref, dgr_ref,
+                     dbc_ref, dstate, *, key_heads: int, r: int):
+    @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
 
+    f32 = jnp.float32
+    c = q_ref.shape[1]
+    dk, dv = dstate.shape[1:]
+    heads = key_heads * r
+    mx = lambda x: x.astype(q_ref.dtype)
+    rows = lambda x: jnp.sum(x, axis=1, keepdims=True)
+    dot = lambda dims: lambda x, y: _dot(x, y, dims)
+    (row, col, q, k, kk, qk, decay, gamma, delta, gamma_c,
+     beta) = _step_heads(q_ref, k_ref, gc_ref, gr_ref, bc_ref, dk, r, heads)
+    strict = row > col
+    is_last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    k32 = _each(lambda x: x.astype(f32), k)
+    s = [s_ref[0, 0, h] for h in range(heads)]
+    ds_next = [dstate[h] for h in range(heads)]
+    t = [t_ref[0, 0, h] for h in range(heads)]
+    do = [do_ref[0, :, h * dv:(h + 1) * dv] for h in range(heads)]
+    do32 = _each(lambda x: x.astype(f32), do)
+    sm, dsm = _each(mx, s), _each(mx, ds_next)
+    # the forward's values, made again
+    ks, qs = _each(dot(_NN), k, sm), _each(dot(_NN), q, sm)
+    resid = [v_ref[0, :, h * dv:(h + 1) * dv].astype(f32) - gamma[h] * ks[h]
+             for h in range(heads)]
+    rhs = _each(lambda b, x: mx(b * x), beta, resid)
+    u = _each(lambda x, y: mx(_dot(x, y, _NN)), t, rhs)
+    # the three lines that touch the state
+    du = _each(lambda m, x, y, d, z, w: mx(_dot(mx(m * x), y, _TN)
+                                           + _dot(mx(d * z), w, _NN)),
+               decay, qk, do, delta, k32, dsm)
+    dp = _each(dot(_NT), do, u)
+    dkd = _each(dot(_NT), u, dsm)
+    # U = T (beta (V - gamma K S)),  T = (I + A)^-1:  dA = -T^T dT T^T
+    dr = _each(dot(_TN), t, du)
+    dtri = _each(lambda x, y, z: mx(_dot(mx(_dot(x, y, _NT)), z, _NT)),
+                 du, rhs, t)
+    da = _each(lambda x, y: jnp.where(strict, -_dot(x, y, _TN), 0.0), t, dtri)
+    dks = _each(lambda b, g, x: mx(-(b * g) * x), beta, gamma, dr)
+    dqs = _each(lambda g, x: mx(g * x), gamma, do32)
+    new = _each(lambda g, x, y, z, w, a: (g + jnp.zeros((1, dv), f32)) * x
+                + _dot(y, z, _TN) + _dot(w, a, _TN),
+                gamma_c, ds_next, q, dqs, k, dks)
+    dqk = _each(lambda m, x: mx(m * x), decay, dp)
+    dkk = _each(lambda x, b, m: mx(x * b * m), da, beta, decay)
+    dq = _each(lambda x, y, z, w: _dot(x, y, _NT) + _dot(z, w, _NN),
+               dqs, sm, dqk, k)
+    dk_ = _each(lambda x, y, z, w, a, b, d, e: _dot(x, y, _NT)
+                + _dot(z, w, _TN) + _dot(a, b, _NN) + _dot(a, b, _TN) + d * e,
+                dks, sm, dqk, q, dkk, k, delta, dkd)
+    # beta, and the running sum through every decay
+    e = _each(lambda x, y, a, b, z, m: (x * y + a * b * z) * m,
+              dp, qk, da, beta, kk, decay)
+    d_delta = _each(lambda x, y, d: rows(x * y) * d, dkd, k32, delta)
     for h in range(heads):
-        s, ds_next, do = s_ref[0, h], dstate[h], do_ref[0, h]
-        w, qg, p, kd = w_ref[0, h], qg_ref[0, h], p_ref[0, h], kd_ref[0, h]
-        u = u0_ref[0, h] - _dot(w, s, _NN)
-        du = _dot(p, do, _TN) + _dot(kd, ds_next, _NN)
-        decay = c_ref[n * total_heads + pl.program_id(0) * heads + h]
-        dstate[h] = _dot(qg, do, _TN) + decay * ds_next - _dot(w, du, _TN)
-        dw_ref[0, h] = -_dot(du, s, _NT)
-        du_ref[0, h] = du
-        dqg_ref[0, h] = _dot(do, s, _NT)
-        dp_ref[0, h] = _dot(do, u, _NT)
-        dkd_ref[0, h] = _dot(u, ds_next, _NT)
-        dc_ref[0, h] = jnp.full(dc_ref.shape[2:], jnp.sum(s * ds_next))
+        dstate[h] = new[h]
+        dv_ref[0, :, h * dv:(h + 1) * dv] = (beta[h] * dr[h]).astype(
+            dv_ref.dtype)
+        dbc_ref[0, 0, :, h:h + 1] = (rows(dr[h] * resid[h])
+                                     + rows(da[h] * decay[h] * kk[h]))
+        at_end = (jnp.sum(d_delta[h], axis=0, keepdims=True) + gamma_c[h]
+                  * jnp.sum(rows(s[h] * ds_next[h]), axis=0, keepdims=True))
+        dgc_ref[0, 0, :, h:h + 1] = (
+            rows(e[h]) + gamma[h] * (rows(do32[h] * qs[h])
+                                     - beta[h] * rows(dr[h] * ks[h]))
+            - d_delta[h] + jnp.where(is_last, at_end, 0.0))
+        dgr_ref[0, 0, 0, h:h + 1, :] = -jnp.sum(e[h], axis=0, keepdims=True)
+    for i in range(key_heads):      # a key head's r value heads, summed
+        dq_ref[0, :, i * dk:(i + 1) * dk] = sum(
+            dq[i * r:(i + 1) * r]).astype(dq_ref.dtype)
+        dk_ref[0, :, i * dk:(i + 1) * dk] = sum(
+            dk_[i * r:(i + 1) * r]).astype(dk_ref.dtype)
 
 
-def _heads_per_step(bh: int) -> int:
-    return next(h for h in _HEADS_PER_STEP if bh % h == 0)
+def _small_layouts(x, n, hb):
+    """``x`` (B, N C, H_v) as float32 columns (B, H_v / hb, N C, hb) and
+    rows (B, H_v / hb, N, hb, C): the two shapes a kernel reads a head's
+    (C, 1) and (1, C) from (1 MB arrays, XLA's to lay out)."""
+    b, s, hv = x.shape
+    x = x.astype(jnp.float32).reshape(b, n, s // n, hv // hb, hb)
+    return (x.transpose(0, 3, 1, 2, 4).reshape(b, hv // hb, s, hb),
+            x.transpose(0, 3, 1, 4, 2))
 
 
-def _block(shape, heads, index):
-    return pl.BlockSpec((1, heads) + tuple(shape[2:]), index)
+def _small_inputs(g, beta, n, hb):
+    """``(G as columns, G as rows, beta as columns)`` for the kernels, ``G``
+    each chunk's running sum of ``g``: ``jnp.cumsum`` over the (B, S, H_v)
+    array, the scan path's own — a prefix SHARED by ``G_i`` and ``G_j``
+    keeps their difference's roundoff to the additions between them, and
+    both layouts hold the same values.  (In the kernel a sum along sublanes
+    and lanes alike is a product with a triangle of ones: every ``G_i`` a
+    sum of its own, 3-5 times the scan path's error against float64 at
+    ``|G|`` ~ 80.)"""
+    b, s, hv = g.shape
+    big_g = jnp.cumsum(g.astype(jnp.float32).reshape(b, n, s // n, hv), axis=2)
+    return (*_small_layouts(big_g.reshape(b, s, hv), n, hb),
+            _small_layouts(beta, n, hb)[0])
 
 
-def _chain_fwd_pallas(w, u0, qg, p, kd, c):
-    n, bh, _, dk = w.shape
-    dv = u0.shape[3]
-    hb = _heads_per_step(bh)
-    at = lambda h, i, c_ref: (i, h, 0, 0)
-    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
-    return _pallas_call(
-        functools.partial(_fwd_kernel, heads=hb, total_heads=bh),
-        name="apex_gdn_fwd",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(bh // hb, n),
-            in_specs=[_block(x.shape, hb, at) for x in (w, u0, qg, p, kd)],
-            out_specs=[_block(u0.shape, hb, at),
-                       _block((n, bh, dk, dv), hb, at)],
-            scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)]),
-        out_shape=[f32(*u0.shape), f32(n, bh, dk, dv)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(c.reshape(-1), w, u0, qg, p, kd)
+def _blocks(c, kb, hb, dk, dv, chunk_of):
+    """The kernels' BlockSpecs over grid (rows, head groups, chunks), grid
+    step ``i`` walking chunk ``chunk_of(i)``: ``(q | k at kb key heads, v |
+    o | do at hb value heads — blocks of the (B, S, heads x d) arrays —, a
+    (B, S, H_v) array as columns, as rows, the states, the T's)``."""
+    wide = lambda heads, d: pl.BlockSpec(
+        (1, c, heads * d), lambda b, h, i: (b, chunk_of(i), h))
+    per_head = lambda *tile: pl.BlockSpec(
+        (1, 1, hb) + tile, lambda b, h, i: (b, chunk_of(i), h, 0, 0))
+    return (wide(kb, dk), wide(hb, dv),
+            pl.BlockSpec((1, 1, c, hb), lambda b, h, i: (b, h, chunk_of(i), 0)),
+            pl.BlockSpec((1, 1, 1, hb, c),
+                         lambda b, h, i: (b, h, chunk_of(i), 0, 0)),
+            per_head(dk, dv), per_head(c, c))
 
 
-def _chain_bwd_pallas(w, u0, qg, p, kd, c, states, do):
-    n, bh, _, _ = w.shape
-    hb = _heads_per_step(bh)
-    at = lambda h, i, c_ref: (n - 1 - i, h, 0, 0)
-    f32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32)
-    dc_shape = (n, bh, 8, 128)
-    outs = (w, u0, qg, p, kd)
-    *grads, dc = _pallas_call(
-        functools.partial(_bwd_kernel, heads=hb, total_heads=bh, chunks=n),
-        name="apex_gdn_bwd",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(bh // hb, n),
-            in_specs=[_block(x.shape, hb, at)
-                      for x in (w, u0, qg, p, kd, states, do)],
-            out_specs=[_block(x.shape, hb, at) for x in outs]
-            + [_block(dc_shape, hb, at)],
-            scratch_shapes=[pltpu.VMEM((hb,) + states.shape[2:],
-                                       jnp.float32)]),
-        out_shape=[f32(x.shape) for x in outs] + [f32(dc_shape)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(c.reshape(-1), w, u0, qg, p, kd, states, do)
-    return (*grads, dc[:, :, 0, 0])
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-# ---------------------------------------------------------------------------
-# the chain, differentiable
-# ---------------------------------------------------------------------------
+def _rule_fwd_pallas(q, k, v, g, beta, chunk):
+    """``q``, ``k`` (B, S, H_k, d_k), ``v`` (B, S, H_v, d_v), all in v's
+    dtype, ``g``, ``beta`` (B, S, H_v), ``S`` whole chunks.  ``(o (B, S, H_v,
+    d_v) in v's dtype, the state at each chunk's start (B, N, H_v, d_k, d_v)
+    float32, each chunk's T (B, N, H_v, C, C) in v's dtype)``."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    n, c = s // chunk, chunk
+    kb, hb = _heads_per_step(hk, hv)
+    keys, values, cols, rows, states, tri = _blocks(
+        c, kb, hb, dk, dv, lambda i: i)
+    o, states, tri = _pallas_call(
+        functools.partial(_rule_fwd_kernel, key_heads=kb, r=hv // hk),
+        name="apex_gdn_fwd", grid=(b, hk // kb, n),
+        in_specs=[keys, keys, values, cols, rows, cols],
+        out_specs=[values, states, tri],
+        out_shape=[jax.ShapeDtypeStruct((b, s, hv * dv), v.dtype),
+                   jax.ShapeDtypeStruct((b, n, hv, dk, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((b, n, hv, c, c), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+    )(q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk),
+      v.reshape(b, s, hv * dv), *_small_inputs(g, beta, n, hb))
+    return o.reshape(b, s, hv, dv), states, tri
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _chain(w, u0, qg, p, kd, c, kernels: bool):
-    """``O`` (N, BH, C, d_v) float32 of the chunks chained from a zero
-    state (the three lines of the module's docstring)."""
-    return _chain_fwd(w, u0, qg, p, kd, c, kernels)[0]
+
+def _rule_bwd_pallas(q, k, v, g, beta, states, tri, do, chunk):
+    """The gradients of :func:`_rule_fwd_pallas`'s ``o`` in its five inputs,
+    the chunks walked from the last."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    n, c = s // chunk, chunk
+    kb, hb = _heads_per_step(hk, hv)
+    keys, values, cols, rows, per_state, per_tri = _blocks(
+        c, kb, hb, dk, dv, lambda i: n - 1 - i)
+    small = _small_inputs(g, beta, n, hb)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    dq, dk_, dv_, dg_cols, dg_rows, dbeta_cols = _pallas_call(
+        functools.partial(_rule_bwd_kernel, key_heads=kb, r=hv // hk),
+        name="apex_gdn_bwd", grid=(b, hk // kb, n),
+        in_specs=[keys, keys, values, cols, rows, cols, per_state, per_tri,
+                  values],
+        out_specs=[keys, keys, values, cols, rows, cols],
+        out_shape=[jax.ShapeDtypeStruct((b, s, hk * dk), q.dtype),
+                   jax.ShapeDtypeStruct((b, s, hk * dk), k.dtype),
+                   jax.ShapeDtypeStruct((b, s, hv * dv), v.dtype),
+                   *map(like, small)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+    )(q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk),
+      v.reshape(b, s, hv * dv), *small, states, tri,
+      do.reshape(b, s, hv * dv))
+    from_cols = lambda x: x.reshape(b, hv // hb, n, c, hb).transpose(
+        0, 2, 3, 1, 4).reshape(b, s, hv)
+    from_rows = lambda x: x.transpose(0, 2, 4, 1, 3).reshape(b, s, hv)
+    # dG, the gradient of a chunk's running sum, summed back over the tokens
+    # that follow in the chunk (1 MB, XLA's)
+    big_dg = (from_cols(dg_cols) + from_rows(dg_rows)).reshape(b, n, c, hv)
+    dg = jnp.flip(jnp.cumsum(jnp.flip(big_dg, 2), axis=2), 2)
+    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
+            dg.reshape(b, s, hv).astype(g.dtype),
+            from_cols(dbeta_cols).astype(beta.dtype))
 
 
-def _chain_fwd(w, u0, qg, p, kd, c, kernels):
-    if kernels:
-        return _chain_fwd_pallas(w, u0, qg, p, kd, c)
-    return _chain_fwd_scan(w, u0, qg, p, kd, c)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule_kernels(q, k, v, g, beta, chunk: int):
+    return _rule_fwd_pallas(q, k, v, g, beta, chunk)[0]
 
 
-def _chain_fwd_rule(w, u0, qg, p, kd, c, kernels):
-    o, states = _chain_fwd(w, u0, qg, p, kd, c, kernels)
+def _rule_kernels_fwd(q, k, v, g, beta, chunk):
+    o, states, tri = _rule_fwd_pallas(q, k, v, g, beta, chunk)
     # declared to the block-recomputing policies (apex_tpu.remat), as the
     # flash kernel declares its output: where a policy keeps these names
-    # the backward pass does not walk the chunks forward a second time
+    # the recomputed block's forward rule is dead code
     o = checkpoint_name(o, GDN_OUT)
     states = checkpoint_name(states, GDN_STATES)
-    return o, (w, u0, qg, p, kd, c, states)
+    tri = checkpoint_name(tri, GDN_TRI)
+    return o, (q, k, v, g, beta, states, tri)
 
 
-def _chain_bwd_rule(kernels, res, do):
-    w, u0, qg, p, kd, c, states = res
-    do = do.astype(jnp.float32)
-    if kernels:
-        return _chain_bwd_pallas(w, u0, qg, p, kd, c, states, do)
-    return _chain_bwd_scan(w, u0, qg, p, kd, c, states, do)
+def _rule_kernels_bwd(chunk, res, do):
+    return _rule_bwd_pallas(*res, do, chunk)
 
 
-_chain.defvjp(_chain_fwd_rule, _chain_bwd_rule)
+_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)
 
 
 def supported(chunk: int, dk: int, dv: int) -> bool:
@@ -384,22 +639,30 @@ def _trace_key():
 @functools.partial(jax.jit, static_argnums=(5, 6, 7))
 def _rule_jit(q, k, v, g, beta, chunk, kernels, trace_key):
     del trace_key
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    b, s, hk, dk = q.shape
+    h, dv = v.shape[2:]
     pad = (-s) % chunk
     n = (s + pad) // chunk
+    # the padding tokens (zero k, beta, g) leave state and outputs as they are
+    padded = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+    if kernels:
+        # the products' operands are v's dtype: q and k are rounded to it
+        # once, here (a cast their producer absorbs), not in every product
+        args = (q.astype(v.dtype), k.astype(v.dtype), v, g, beta)
+        return _rule_kernels(*(map(padded, args) if pad else args),
+                             chunk)[:, :s]
 
     def chunks(t):
-        """(B, S, H, ...) -> (N, B H, C, ...), float32; the padding tokens
-        (zero k, beta, g) leave state and outputs as they are."""
-        t = jnp.pad(t.astype(jnp.float32),
-                    ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        """(B, S, H, ...) -> (N, B H, C, ...), float32."""
+        t = padded(t.astype(jnp.float32))
         t = t.reshape((b, n, chunk, h) + t.shape[3:])
         t = jnp.moveaxis(jnp.moveaxis(t, 3, 1), 2, 0)      # (N, B, H, C, ...)
         return t.reshape((n, b * h, chunk) + t.shape[4:])
 
+    if hk != h:                   # each value head beside its key head's q, k
+        q, k = (jnp.repeat(t, h // hk, axis=2) for t in (q, k))
     local = _chunk_local(*map(chunks, (q, k, v, g, beta)))
-    o = _chain(*local, kernels)                             # (N, BH, C, dv)
+    o = _chain(*local)                                      # (N, BH, C, dv)
     o = o.reshape(n, b, h, chunk, dv).transpose(1, 0, 3, 2, 4)
     return o.reshape(b, n * chunk, h, dv)[:, :s].astype(v.dtype)
 
@@ -408,20 +671,29 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
                      use_pallas: Optional[bool] = None):
     """The gated delta rule over every row of a batch, by chunks.
 
-    ``q``, ``k`` (B, S, H, d_k) — already normalised and scaled as the
-    model wants them —, ``v`` (B, S, H, d_v), ``g`` (B, S, H) the log-decay
-    (<= 0), ``beta`` (B, S, H).  Returns (B, S, H, d_v) in ``v``'s dtype;
-    the arithmetic is float32, the products at JAX's default precision (on
-    the TPU the MXU's bfloat16 pass with float32 accumulation, as the
-    model's other products).  Each row starts from a zero state; ``S`` need
-    not be whole chunks.  Differentiable in all five.
+    ``q``, ``k`` (B, S, H_k, d_k) — already normalised and scaled as the
+    model wants them —, ``v`` (B, S, H, d_v) with ``H`` a multiple of
+    ``H_k`` (value head ``h`` reads key head ``h // (H / H_k)``), ``g`` (B,
+    S, H) the log-decay (<= 0), ``beta`` (B, S, H).  Returns (B, S, H, d_v)
+    in ``v``'s dtype; the arithmetic is float32, the products at JAX's
+    default precision (on the TPU the MXU's bfloat16 pass with float32
+    accumulation, as the model's other products): an operand is rounded
+    once, where it enters a product, and the state, the running sums, the
+    decays and ``T`` stay float32 between products.  Each row starts from a
+    zero state; ``S`` need not be whole chunks.  Differentiable in all five.
 
-    The chunks are chained by the kernels ``apex_gdn_fwd`` / ``apex_gdn_bwd``
-    on the TPU where the shapes tile (:func:`supported`), else by
-    ``lax.scan``; the gauge ``gdn.kernels`` says which was traced, beside
+    On the TPU, where the shapes tile (:func:`supported`), the whole rule
+    runs in the kernels ``apex_gdn_fwd`` / ``apex_gdn_bwd``, whose products
+    take their operands in ``v``'s dtype (q and k are cast to it on the way
+    in); else by ``_chunk_local`` and ``lax.scan`` in float32.  The gauges
+    ``gdn.kernels`` and ``gdn.local_in_kernel`` say which was traced (both 1
+    for the kernels: they make what is local to a chunk themselves), beside
     ``gdn.chunk``, ``gdn.chunks_per_row`` and ``gdn.value_heads``."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
+    if v.shape[2] % q.shape[2] or k.shape != q.shape:
+        raise ValueError(f"value heads must be a multiple of the key heads "
+                         f"q and k share: got {q.shape}, {k.shape}, {v.shape}")
     ok = supported(chunk, q.shape[-1], v.shape[-1])
     if use_pallas is None:
         use_pallas = pallas_default(ok)
@@ -435,5 +707,5 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
     reg.gauge("gdn.chunks_per_row").set(-(-q.shape[1] // chunk))
     reg.gauge("gdn.value_heads").set(v.shape[2])
     reg.gauge("gdn.kernels").set(int(use_pallas))
+    reg.gauge("gdn.local_in_kernel").set(int(use_pallas))
     return _rule_jit(q, k, v, g, beta, chunk, bool(use_pallas), _trace_key())
-

@@ -29,16 +29,17 @@ seq x head_dim`` in the compute dtype) and its log-sum-exp (``batch*heads
 x seq`` float32) — what its backward reads besides q, k, v, which are
 cheap to make again from the block's input.  Without them the backward
 pass would run the whole attention forward a second time only to hand
-its backward those two arrays.  ``ops/gated_delta.py``'s chain over the
-chunks names its output (``chunks x heads x chunk x d_v`` float32) and the
-state at each chunk's start (``chunks x heads x d_k x d_v`` float32) — what
-its backward reads besides the chunk-local arrays, which are batched
-products of the block's input — and, of those, the one that is dear:
-each chunk's triangular inverse (``chunks x heads x chunk x chunk``
-float32; ten dependent products at chunks of 64, each a pass over
-HBM).  The chain is the one SEQUENTIAL thing in a block (128 dependent
-steps at 8192 tokens): without the names it would be walked forward
-twice.  Outside a ``jax.checkpoint`` a name lowers to nothing.
+its backward those two arrays.  ``ops/gated_delta.py``'s forward rule
+names the rule's output (``batch x seq x heads*d_v`` in the compute dtype:
+the kernels'; float32 ``chunks x heads x chunk x d_v`` on the scan path), the
+state at each chunk's start (``chunks x heads x d_k x d_v`` float32) and
+each chunk's triangular inverse (``chunks x heads x chunk x chunk``, the
+kernels' in the compute dtype) — what its backward reads besides q, k, v,
+g, beta, which are cheap to make again from the block's input.  The rule is
+the one SEQUENTIAL thing in a block (128 dependent steps at 8192 tokens,
+ten dependent products a chunk in the inverse): without the names it would
+be walked forward twice.  Outside a ``jax.checkpoint`` a name lowers to
+nothing.
 
 One rule at every shape, no threshold: per byte kept, the attention
 forward costs 2 x (keys a query sees) operations at a fifth to a third
@@ -58,11 +59,13 @@ bert-large, 12x512    12.6 MB             12.6 MB                0.39 MB
 ====================  ==================  =====================  ===========
 
 A gated-delta-net block (qwen3-next, 1x8192, 32 value heads of 128 x 128)
-keeps its input 33.6 MB, the chain's output 134 MB, the chunk states
-268 MB and the triangular inverses 67 MB; the compile-only rehearsal of that cell's window reads 0.83 GiB
-FEWER temporaries with them kept than without (5.34 against 6.17 GiB:
-the second forward walk's own temporaries were the larger), and three
-``apex_gdn_fwd`` calls a step for six (PERF.md section 6, PR 30).
+keeps its input 33.6 MB, the rule's output 67.1 MB (bf16 since PR 31; the
+scan path's float32 134 MB), the chunk states 268 MB and the triangular
+inverses 33.6 MB (bf16; 67 MB float32 before): with them kept the
+recomputed block's forward rule is dead code — three ``apex_gdn_fwd`` calls
+a step for six — and the compile-only rehearsal of that cell's window reads
+4.98 GiB of temporaries (5.33 before the rule's kernels made what is local
+to a chunk themselves, 6.17 with no name kept; PERF.md section 6, PR 30-31).
 
 For GPT and BERT heads x head size = hidden, so ``full_block`` keeps two
 arrays of the input's size a block where it kept one: about 1/9th of

@@ -316,7 +316,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     root_key = jax.random.PRNGKey(seed)
     # the first three as they always were; the later checks fold theirs in
     keys = iter(list(jax.random.split(root_key, 3))
-                + [jax.random.fold_in(root_key, i) for i in (3, 4, 5)])
+                + [jax.random.fold_in(root_key, i) for i in (3, 4, 5, 6)])
     calls: Dict[str, int] = {}
     parity: Dict[str, Dict] = {}
     facts.update(
@@ -324,7 +324,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 "xentropy": [rows, v],
                 "flash_window_grouped": [1, 4, 8 * s, 128],
                 "grouped_mm": [rows, n, 2 * n],
-                "moe_dispatch": [rows, -(-n // 1024) * 1024]},
+                "moe_dispatch": [rows, -(-n // 1024) * 1024],
+                "gated_delta": [1, 8 * s, [s // 64, s // 32], 128]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -491,6 +492,42 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     run("moe_dispatch", dispatch_loss(tile), dispatch_loss(None),
         (xm, wm, routing, cot_m), (xm.astype(f32), wm, routing, cot_m), 6,
         (1e-5, (("dx", 2e-2), ("dweights", 2e-2))))
+
+    # the gated delta rule (ops/gated_delta.py) as qwen3-next.train-8k calls
+    # it — one row of eight contexts, q and k at 16 key heads and v at 32
+    # value heads of 128 in bfloat16, chunks of 64, a head's decay rate drawn
+    # as the model's initialisation draws it — against the lax.scan path in
+    # float32: what is local to a chunk made inside the two kernels
+    from apex_tpu.ops.gated_delta import gated_delta_rule
+
+    hk_d, hv_d = s // 64, s // 32
+    l2 = lambda t: t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+    def delta_inputs(kq, kk, kv, kg):
+        ka, kb, kr, kc = jax.random.split(kg, 4)
+        rate = jnp.exp(jax.random.uniform(
+            kr, (hv_d,), f32, jnp.log(1e-4), jnp.log(16.0)))
+        return ((l2(normal(kq, (1, 8 * s, hk_d, 128), f32)) * 128 ** -0.5
+                 ).astype(bf16),
+                l2(normal(kk, (1, 8 * s, hk_d, 128), f32)).astype(bf16),
+                normal(kv, (1, 8 * s, hv_d, 128), f32).astype(bf16),
+                -rate * jax.nn.softplus(normal(ka, (1, 8 * s, hv_d), f32) + 1.0),
+                jax.nn.sigmoid(2.0 * normal(kb, (1, 8 * s, hv_d), f32)),
+                normal(kc, (1, 8 * s, hv_d, 128), f32))
+
+    qd, kd, vd, gd_, bd, w_delta = seeded(delta_inputs)
+
+    def delta_loss(use_pallas):
+        def loss(q, k, v, g, beta, w):
+            out = gated_delta_rule(q, k, v, g, beta, use_pallas=use_pallas)
+            return jnp.sum(out.astype(f32) * w), out
+        return loss
+
+    run("gated_delta", delta_loss(None), delta_loss(False),
+        (qd, kd, vd, gd_, bd, w_delta),
+        tuple(t.astype(f32) for t in (qd, kd, vd)) + (gd_, bd, w_delta), 2,
+        (2e-2, (("dq", 2e-2), ("dk", 2e-2), ("dv", 2e-2), ("dg", 2e-2),
+                ("dbeta", 2e-2))))
 
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 

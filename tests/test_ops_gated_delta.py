@@ -1,7 +1,9 @@
 """``ops/gated_delta.py``: the chunked gated delta rule against the token
-recurrence it is defined by (outputs and every gradient; the kernels in
-interpret mode and the ``lax.scan`` chain), the decays at their strongest,
-the triangular inverse, and the causal convolution in front of the rule."""
+recurrence it is defined by (outputs and every gradient; the kernels — which
+make what is local to a chunk themselves — in interpret mode, and the
+``lax.scan`` path), q and k at fewer heads than v, bfloat16 operands, the
+decays at their strongest, the triangular inverse, and the causal
+convolution in front of the rule."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,17 +13,20 @@ from apex_tpu.ops import gated_delta as gd
 from apex_tpu.ops._common import force_pallas
 
 
-def inputs(seed=0, b=2, s=40, h=3, dk=128, dv=128, a_max=16.0, beta_shift=0.0):
-    """q, k normalised as the model hands them over; head 0 decays at
-    ``a_max`` (the strongest ``A = exp(A_log)`` the initialisation draws),
-    the others at 1 and 0.01."""
+def inputs(seed=0, b=2, s=40, h=3, dk=128, dv=128, a_max=16.0, beta_shift=0.0,
+           r=1):
+    """q, k normalised as the model hands them over, at ``h`` key heads; v,
+    g, beta at ``h * r`` value heads; value head 0 decays at ``a_max`` (the
+    strongest ``A = exp(A_log)`` the initialisation draws), the others at 1
+    and 0.01 in turn."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
     q = l2(jax.random.normal(ks[0], (b, s, h, dk))) * dk ** -0.5
     k = l2(jax.random.normal(ks[1], (b, s, h, dk)))
+    h = h * r
     v = jax.random.normal(ks[2], (b, s, h, dv))
     a = 3.0 * jax.random.normal(ks[3], (b, s, h))
-    rates = jnp.array([a_max, 1.0, 0.01])[:h]
+    rates = jnp.resize(jnp.array([a_max, 1.0, 0.01]), (h,))
     g = -rates * jax.nn.softplus(a + 1.0)
     beta = jax.nn.sigmoid(4.0 * jax.random.normal(ks[4], (b, s, h)) + beta_shift)
     return q, k, v, g, beta
@@ -37,7 +42,19 @@ CASES = {
     "beta_near_1": dict(beta_shift=12.0),
     "not_whole_chunks": dict(s=37),
     "one_short_chunk": dict(s=5),
+    # q and k at FEWER heads than v: two value heads read each key head, and
+    # a key head's gradient is the sum over them (2 x 2, and 1 x 4: a grid
+    # step of one key head)
+    "two_value_heads_a_key_head": dict(h=2, r=2),
+    "four_value_heads_a_key_head": dict(h=1, r=4, s=37),
 }
+
+
+def recurrent(q, k, v, g, beta):
+    """The recurrence with each value head beside its key head's q and k."""
+    r = v.shape[2] // q.shape[2]
+    return gd.gated_delta_rule_recurrent(
+        jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v, g, beta)
 
 
 @pytest.mark.parametrize("kernels", [False, True], ids=["scan", "pallas"])
@@ -45,7 +62,7 @@ CASES = {
 def test_chunked_rule_matches_the_recurrence(case, kernels):
     """Outputs and all five gradients, chunks of 16."""
     args = inputs(**CASES[case])
-    want = gd.gated_delta_rule_recurrent(*args)
+    want = recurrent(*args)
     ct = jax.random.normal(jax.random.PRNGKey(9), want.shape)
     grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * ct),
                                 argnums=(0, 1, 2, 3, 4))(*args)
@@ -55,17 +72,48 @@ def test_chunked_rule_matches_the_recurrence(case, kernels):
         got_grads = grads(chunked)
     assert gap(got, want) < 1e-5
     for name, a, b in zip(("q", "k", "v", "g", "beta"), got_grads,
-                          grads(gd.gated_delta_rule_recurrent)):
-        assert bool(jnp.isfinite(a).all()), name
+                          grads(recurrent)):
+        assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
         assert gap(a, b) < 1e-4, name
 
 
-@pytest.mark.parametrize("kernels", [False, True], ids=["scan", "pallas"])
-def test_a_whole_chunk_at_the_strongest_decay_stays_finite(kernels):
+@pytest.mark.parametrize("case", ["strongest_decay", "not_whole_chunks",
+                                  "two_value_heads_a_key_head"])
+def test_kernels_with_bfloat16_operands(case):
+    """bfloat16 q, k, v as the model hands them over: the kernels' products
+    take bfloat16 operands and accumulate in float32, everything between
+    products stays float32.  Held to the float32 recurrence on the SAME
+    bfloat16 values at tolerances of their own — 2e-2 of the largest value,
+    outputs and gradients: an operand's rounding is 2^-9 = 2e-3 relative,
+    some ten roundings lie between an input and a gradient, and the outputs
+    and the gradients of q, k, v are themselves rounded to bfloat16."""
+    q, k, v, g, beta = inputs(**CASES[case])
+    q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    f32 = lambda t: t.astype(jnp.float32)
+    want = recurrent(f32(q), f32(k), f32(v), g, beta)
+    ct = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    grads = lambda fn, *a: jax.grad(
+        lambda *a: jnp.sum(f32(fn(*a)) * ct), argnums=(0, 1, 2, 3, 4))(*a)
+    chunked = lambda *a: gd.gated_delta_rule(*a, chunk=16)
+    with force_pallas(True):
+        got = chunked(q, k, v, g, beta)
+        got_grads = grads(chunked, q, k, v, g, beta)
+    assert got.dtype == jnp.bfloat16 and gap(f32(got), want) < 2e-2
+    assert [x.dtype for x in got_grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got_grads,
+                          grads(recurrent, f32(q), f32(k), f32(v), g, beta)):
+        assert bool(jnp.isfinite(f32(a)).all()), name
+        assert gap(f32(a), b) < 2e-2, name
+
+
+@pytest.mark.parametrize("kernels,r", [(False, 1), (True, 1), (True, 2)],
+                         ids=["scan", "pallas", "pallas_two_a_key_head"])
+def test_a_whole_chunk_at_the_strongest_decay_stays_finite(kernels, r):
     """-21 a token, -1300 over a chunk of 64: ``exp(G_i) * exp(-G_j)`` would
     overflow float32 inside the chunk; ``exp(G_i - G_j)`` for i >= j does
-    not, and the result is still the recurrence's."""
-    q, k, v, g, beta = inputs(b=1, s=128, h=2)
+    not — in the scan path and in both kernels, forward and backward — and
+    the result is still the recurrence's."""
+    q, k, v, g, beta = inputs(b=1, s=128, h=2 // r, r=r)
     g = jnp.full_like(g, -21.0).at[..., 1].set(-0.5)
     assert float(jnp.sum(g[0, :64, 0])) < -1300
     with np.errstate(over="ignore"):
@@ -77,30 +125,46 @@ def test_a_whole_chunk_at_the_strongest_decay_stays_finite(kernels):
                          argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     assert bool(jnp.isfinite(got).all())
     assert all(bool(jnp.isfinite(x).all()) for x in grads)
-    assert gap(got, gd.gated_delta_rule_recurrent(q, k, v, g, beta)) < 1e-5
-    want = jax.grad(loss(gd.gated_delta_rule_recurrent),
-                    argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    assert gap(got, recurrent(q, k, v, g, beta)) < 1e-5
+    want = jax.grad(loss(recurrent), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     for a, b in zip(grads, want):
         assert gap(a, b) < 1e-4
 
 
-def test_kernels_and_scan_chain_agree():
-    """``apex_gdn_fwd`` / ``apex_gdn_bwd`` (interpret mode) against the
-    ``lax.scan`` chain on the same chunk-local arrays: outputs, the states
-    at the chunks' starts, and all six gradients."""
-    ks = jax.random.split(jax.random.PRNGKey(2), 7)
-    n, bh, c, d = 3, 4, 8, 128
-    w, u0, qg, kd = (0.3 * jax.random.normal(k, (n, bh, c, d)) for k in ks[:4])
-    p = jnp.tril(jax.random.normal(ks[4], (n, bh, c, c)))
-    decay = jax.random.uniform(ks[5], (n, bh))
-    do = jax.random.normal(ks[6], (n, bh, c, d))
-    o_s, st_s = gd._chain_fwd_scan(w, u0, qg, p, kd, decay)
-    o_k, st_k = gd._chain_fwd_pallas(w, u0, qg, p, kd, decay)
-    assert gap(o_k, o_s) < 1e-5 and gap(st_k, st_s) < 1e-5
-    assert float(jnp.max(jnp.abs(st_s[0]))) == 0.0          # a zero start
-    for a, b in zip(gd._chain_bwd_pallas(w, u0, qg, p, kd, decay, st_s, do),
-                    gd._chain_bwd_scan(w, u0, qg, p, kd, decay, st_s, do)):
-        assert a.shape == b.shape and gap(a, b) < 1e-5
+def test_kernels_and_scan_path_agree():
+    """``apex_gdn_fwd`` / ``apex_gdn_bwd`` (interpret mode) against the scan
+    path's pieces on the same inputs: outputs, the state at each chunk's
+    start and each chunk's ``T`` — what the forward kernel hands the
+    backward —, and all five gradients from those residuals."""
+    b, s, h, c = 2, 48, 2, 16
+    q, k, v, g, beta = inputs(b=b, s=s, h=h, a_max=4.0)
+    n = s // c
+    chunks = lambda t: jnp.moveaxis(                  # (N, B H, C, ...)
+        t.reshape((b, n, c, h) + t.shape[3:]), 3, 2).swapaxes(0, 1).reshape(
+            (n, b * h, c) + t.shape[3:])
+    local = gd._chunk_local(*map(chunks, (q, k, v, g, beta)))
+    o_s, st_s = gd._chain_fwd_scan(*local)
+    o_k, st_k, tri_k = gd._rule_fwd_pallas(q, k, v, g, beta, c)
+    heads_last = lambda t: t.reshape((n, b, h) + t.shape[2:]).swapaxes(0, 1)
+    assert gap(o_k, jnp.moveaxis(heads_last(o_s), 2, 3).reshape(o_k.shape)) < 1e-5
+    assert gap(st_k, heads_last(st_s)) < 1e-5
+    assert float(jnp.max(jnp.abs(st_k[:, 0]))) == 0.0       # a zero start
+    a = jnp.tril(beta_decay_kk(*map(chunks, (k, g, beta))), -1)
+    assert gap(tri_k, heads_last(gd.tri_inverse(a))) < 1e-5
+    do = jax.random.normal(jax.random.PRNGKey(6), o_k.shape)
+    scan = lambda *x: gd.gated_delta_rule(*x, chunk=c, use_pallas=False)
+    for got, want in zip(
+            gd._rule_bwd_pallas(q, k, v, g, beta, st_k, tri_k, do, c),
+            jax.vjp(scan, q, k, v, g, beta)[1](do)):
+        assert got.shape == want.shape and gap(got, want) < 1e-5
+
+
+def beta_decay_kk(k, g, beta):
+    """``beta_i exp(G_i - G_j) k_i . k_j`` of every chunk, (N, BH, C, C)."""
+    big_g = jnp.cumsum(g, axis=-1)
+    diff = big_g[..., :, None] - big_g[..., None, :]
+    return (beta[..., None] * jnp.exp(jnp.minimum(diff, 0.0))
+            * jnp.einsum("nhid,nhjd->nhij", k, k))
 
 
 def test_triangular_inverse_and_its_gradient():
@@ -157,6 +221,13 @@ def test_rule_refuses_what_it_cannot_tile_and_sets_its_gauges():
     assert reg.get("gdn.chunks_per_row").value == 3
     assert reg.get("gdn.value_heads").value == 2
     assert reg.get("gdn.kernels").value == 0
+    assert reg.get("gdn.local_in_kernel").value == 0
+    with force_pallas(True):
+        gd.gated_delta_rule(q, k, v, g, beta, chunk=16)
+    assert reg.get("gdn.kernels").value == 1
+    assert reg.get("gdn.local_in_kernel").value == 1
+    with pytest.raises(ValueError, match="multiple of the key heads"):
+        gd.gated_delta_rule(q, k, jnp.concatenate([v, v[:, :, :1]], 2), g, beta)
     assert gd.gated_delta_rule(q.astype(jnp.bfloat16), k, v.astype(jnp.bfloat16),
                                g, beta).dtype == jnp.bfloat16
 

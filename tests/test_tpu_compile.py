@@ -161,18 +161,7 @@ def _named_case(kernel):
         return (jax.value_and_grad(loss, argnums=(0, 1)),
                 [((8192, 2048), BF16), ((8192, 8), F32), ((8192, 8), I32)])
     if kernel.startswith("apex_gdn_"):
-        # the gated delta rule at qwen3-next.train-8k's own shape: one
-        # 8192-token row, 32 value heads of 128 x 128 float32 state, 128
-        # chunks of 64 (ops/gated_delta.py), forward and backward
-        from apex_tpu.ops.gated_delta import gated_delta_rule
-
-        def loss(q, k, v, g, beta):
-            with jax.named_scope("gdn_scan"):
-                return jnp.sum(gated_delta_rule(q, k, v, g, beta).astype(F32))
-
-        qkv, gb = ((1, 8192, 32, 128), F32), ((1, 8192, 32), F32)
-        return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-                [qkv, qkv, qkv, gb, gb])
+        return _gdn_case()
     if kernel.startswith("apex_xent_"):
         from apex_tpu.ops import softmax_cross_entropy
 
@@ -182,6 +171,22 @@ def _named_case(kernel):
 
         return jax.grad(loss), [((8192, 50304), BF16), ((8192,), I32)]
     raise KeyError(kernel)
+
+
+def _gdn_case():
+    """The gated delta rule as ``qwen3-next.train-8k`` calls it: one
+    8192-token row, q and k at 16 key heads and v at 32 value heads of 128 in
+    bfloat16, a 128 x 128 float32 state a value head, 128 chunks of 64
+    (ops/gated_delta.py), forward and backward."""
+    from apex_tpu.ops.gated_delta import gated_delta_rule
+
+    def loss(q, k, v, g, beta):
+        with jax.named_scope("gdn_scan"):
+            return jnp.sum(gated_delta_rule(q, k, v, g, beta).astype(F32))
+
+    qk, v, gb = ((1, 8192, 16, 128), BF16), ((1, 8192, 32, 128), BF16), \
+        ((1, 8192, 32), F32)
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), [qk, qk, v, gb, gb]
 
 
 _NAMES_OF_CASE = {}
@@ -212,6 +217,48 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     names = _NAMES_OF_CASE[case]
     assert kernel in names, names
     assert set(names) <= set(KERNEL_NAMES), names
+
+
+def test_delta_rule_keeps_what_is_local_to_a_chunk_inside_its_kernels(
+        chip, as_tpu):
+    """At the cell's shape the rule's program is ONE ``apex_gdn_fwd`` and
+    ONE ``apex_gdn_bwd`` and, around them, XLA's work on the (B, S, H_v)
+    arrays alone: no product (``dot`` / ``convolution``, what
+    ``_chunk_local`` and the triangular inverse lower to) and no float32
+    array of q's size or more under ``_rule_jit`` outside the custom calls —
+    either would be an HBM round trip of the kind the kernels exist to
+    avoid, and no parity test would notice it."""
+    import re
+
+    from apex_tpu import obs
+    from apex_tpu.ops._common import mosaic_call_names, unnamed_mosaic_calls
+
+    fn, avals = _gdn_case()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in avals]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+    assert names == ["apex_gdn_fwd", "apex_gdn_bwd"], names
+    assert not unnamed_mosaic_calls(text)
+    reg = obs.default_registry()
+    assert reg.get("gdn.kernels").value == 1
+    assert reg.get("gdn.local_in_kernel").value == 1
+    q_size = 8192 * 16 * 128
+    instr = re.compile(r"= (\(?)(\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
+    seen = 0
+    for line in text.splitlines():
+        m = instr.search(line)
+        if not m or "_rule_jit" not in line or "tpu_custom_call" in line:
+            continue
+        seen += 1
+        is_tuple, dtype, dims, opcode = m.groups()
+        assert opcode not in ("dot", "convolution"), line[:200]
+        if is_tuple or opcode in ("get-tuple-element", "bitcast"):
+            continue        # a kernel's own result, or a view of one
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        assert not (dtype == "f32" and size >= q_size), line[:200]
+    assert seen > 10        # the witness that the lines were found at all
 
 
 def test_a_kernel_differentiated_outside_any_scope_still_bears_its_name(
