@@ -188,9 +188,10 @@ def attention_ref(
     window: Optional[int] = None,
 ) -> jax.Array:
     """Plain attention.  q,k,v: (B, H, S, D); bias: (B, Sq, Sk) additive.
-    ``k``/``v`` may hold H / G heads (each serves G consecutive query
-    heads); ``window`` keeps, under ``causal``, only keys ``j`` with
-    ``i - j < window``.
+    ``v`` may be ``(B, H, S, D_v)`` (the output then too); the default
+    ``scale`` is ``D ** -0.5``.  ``k``/``v`` may hold H / G heads (each
+    serves G consecutive query heads); ``window`` keeps, under ``causal``,
+    only keys ``j`` with ``i - j < window``.
 
     ``dropout_rate`` > 0 applies probability dropout with the SAME
     counter-based mask the Pallas kernel uses (exact parity).
@@ -1229,24 +1230,28 @@ def _kv_spec_by_query(block_q, block_k, d, nk, causal, window, group):
     return pl.BlockSpec((1, block_k, d), index)
 
 
-def _specs(block_q, block_k, d, sq, sk, with_bias, h, causal=False,
-           window=None, group=1):
-    """Common BlockSpecs: arrays are reshaped to (BH, S, D) / bias (B, Sq, Sk)."""
+def _specs(block_q, block_k, d, d_v, sq, sk, with_bias, h, causal, window,
+           group):
+    """Common BlockSpecs: arrays are reshaped to (BH, S, D), v to (BH, S,
+    D_v) / bias (B, Sq, Sk)."""
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec = _kv_spec_by_query(block_q, block_k, d, sk // block_k, causal,
-                               window, group)
+    k_spec, v_spec = (
+        _kv_spec_by_query(block_q, block_k, width, sk // block_k, causal,
+                          window, group)
+        for width in (d, d_v))
     bias_spec = (
         pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, i, j))
         if with_bias
         else None
     )
-    return q_spec, k_spec, bias_spec
+    return q_spec, k_spec, v_spec, bias_spec
 
 
 def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
                dropout_rate, h_map=None, probs_bf16=False, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
+    d_v = v.shape[2]          # v and o at a head size of their own
     group = bh // k.shape[0]
     # bias stays UNEXPANDED at (B, Sq, Sk); the BlockSpec index maps divide
     # the batch*head grid index by h, so no (B*H, Sq, Sk) broadcast is ever
@@ -1255,11 +1260,11 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
     h = 1 if bias is None else bh // bias.shape[0]
     nq = sq // block_q
     nk = sk // block_k
-    q_spec, k_spec, bias_spec = _specs(
-        block_q, block_k, d, sq, sk, bias is not None, h, causal, window,
+    q_spec, k_spec, v_spec, bias_spec = _specs(
+        block_q, block_k, d, d_v, sq, sk, bias is not None, h, causal, window,
         group)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    in_specs = [seed_spec, q_spec, k_spec, k_spec]
+    in_specs = [seed_spec, q_spec, k_spec, v_spec]
     inputs = [seed, q, k, v]
     if bias is not None:
         in_specs.append(bias_spec)
@@ -1276,17 +1281,17 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
     )(*inputs)
     return out, lse[:, :, 0]
@@ -1332,6 +1337,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                probs_bf16=False, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
+    d_v = v.shape[2]          # v, o, do, dv at a head size of their own
     bhk = k.shape[0]          # key/value heads: bh // group
     group = bh // bhk
     h = 1 if bias is None else bh // bias.shape[0]  # unexpanded-bias divisor
@@ -1360,30 +1366,29 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                     i, block_q, block_k, nq, window))
             return b * group + j // nq, qi, 0
     q_spec = pl.BlockSpec((1, block_q, d), q_index)
+    do_spec = pl.BlockSpec((1, block_q, d_v), q_index)
     stat_spec = pl.BlockSpec((1, block_q, 128), q_index)
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
+    v_spec = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, i, 0))
     bias_spec = pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, j, i))
-    in_specs = [seed_spec, q_spec, k_spec, k_spec]
+    in_specs = [seed_spec, q_spec, k_spec, v_spec]
     inputs = [seed, q, k, v]
     if with_bias:
         in_specs.append(bias_spec)
         inputs.append(bias)
-    in_specs += [q_spec, stat_spec, stat_spec]
+    in_specs += [do_spec, stat_spec, stat_spec]
     inputs += [do, lse_b, delta_b]
 
     if (_USE_FUSED_BWD and nk <= _FUSED_BWD_MAX_NK
             and not (with_bias and bias_grad)):
-        dkv_out_specs = [
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-        ]
+        dkv_out_specs = [k_spec, v_spec]
         dkv_out_shape = [
             jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
-            jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, d_v), q.dtype),
         ]
         scratch = [
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ]
         if (nk > 1 and _FUSED_DQ_ACC and nq > 1
                 and not _grouped_route(window, group)
@@ -1481,31 +1486,30 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
         name="apex_flash_bwd_dkdv",
         grid=(bhk, nk, group * nq),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-        ],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
-            jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, d_v), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
     )(*inputs)
 
     q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    do_spec2 = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0))
     stat_spec2 = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    k_spec2 = _kv_spec_by_query(block_q, block_k, d, nk, causal, window,
-                                group)
+    k_spec2, v_spec2 = (
+        _kv_spec_by_query(block_q, block_k, width, nk, causal, window, group)
+        for width in (d, d_v))
     bias_spec2 = pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, i, j))
-    in_specs = [seed_spec, q_spec2, k_spec2, k_spec2]
+    in_specs = [seed_spec, q_spec2, k_spec2, v_spec2]
     inputs = [seed, q, k, v]
     if with_bias:
         in_specs.append(bias_spec2)
         inputs.append(bias)
-    in_specs += [q_spec2, stat_spec2, stat_spec2]
+    in_specs += [do_spec2, stat_spec2, stat_spec2]
     inputs += [do, lse_b, delta_b]
     if with_bias and bias_grad:
         dq, dbias = _pallas_call(
@@ -1668,6 +1672,14 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention.  q,k,v: (B, H, S, D); optional additive bias (B, Sq, Sk).
 
+    ``v`` may have a head size of its own, ``(B, H, S, D_v)`` with ``D_v !=
+    D`` (latent attention: 192-wide queries and keys against 128-wide
+    values).  The output is then ``(B, H, S, D_v)``, and v, o, do and dv go
+    through the kernels at ``D_v`` — their blocks, the output accumulator
+    and dv's scratch are that wide, nothing is padded to ``D`` in HBM.  The
+    default ``scale`` stays ``D ** -0.5``, the queries' size.  Read from the
+    shapes: at ``D_v == D`` every call is the program it always was.
+
     ``k`` and ``v`` may hold fewer heads than ``q``, ``H / G``: each then
     serves ``G`` consecutive query heads (grouped-query attention).  The
     kernels read key/value head ``h // G`` through their BlockSpecs —
@@ -1750,7 +1762,12 @@ def flash_attention(
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    d_v = v.shape[3]
     group = _head_group(h, k.shape[1], v.shape[1])
+    if k.shape[3] != d or v.shape[2] != sk:
+        raise ValueError(
+            f"q {q.shape}, k {k.shape}, v {v.shape}: q and k share a head "
+            f"size, k and v a length")
     if window is not None:
         if not causal:
             raise ValueError("window requires causal=True")
@@ -1785,7 +1802,10 @@ def flash_attention(
         use_pallas = pallas_default(
             sq % block_q == 0
             and sk % block_k == 0
-            and d % 64 == 0  # full-dim blocks: 64/128/192/... all map to MXU
+            # full-dim blocks: 64/128/192/256 all map to the MXU (64 and
+            # 128 in GPT-2's, BERT's and Trinity's cells, 256 in
+            # Qwen3-Next's, 192 against a 128-wide v in Moonlight's)
+            and d % 64 == 0 and d_v % 64 == 0
         )
     if not use_pallas:
         bias_ = bias
@@ -1798,7 +1818,7 @@ def flash_attention(
         )
     q3 = q.reshape(b * h, sq, d)
     k3 = k.reshape(b * h // group, sk, d)
-    v3 = v.reshape(b * h // group, sk, d)
+    v3 = v.reshape(b * h // group, sk, d_v)
     bias3 = None
     if bias is not None:
         # UNEXPANDED (B, Sq, Sk): the kernels' BlockSpec index maps divide
@@ -1818,4 +1838,4 @@ def flash_attention(
         block_k, float(dropout_rate), bool(bias_grad), h_map,
         bool(probs_bf16), window, _trace_key(),
     )
-    return out.reshape(b, h, sq, d)
+    return out.reshape(b, h, sq, d_v)

@@ -25,7 +25,8 @@ cheap to hold names it with ``jax.ad_checkpoint.checkpoint_name`` under
 an entry of :data:`KEPT_RESIDUAL_NAMES`, and both block-recomputing
 policies keep exactly those.  Today two kernels declare.
 ``ops/attention.py``'s forward rule names its output (``batch*heads x
-seq x head_dim`` in the compute dtype) and its log-sum-exp (``batch*heads
+seq x head_dim`` in the compute dtype — the VALUES' head size where that
+is not the keys') and its log-sum-exp (``batch*heads
 x seq`` float32) — what its backward reads besides q, k, v, which are
 cheap to make again from the block's input.  Without them the backward
 pass would run the whole attention forward a second time only to hand
@@ -56,7 +57,18 @@ trinity-mini, 1x8192  33.6 MB (hidden     67.1 MB (32 heads x    1.05 MB
                       2048)               128: twice the hidden)
 gpt2-small, 16x1024   25.2 MB             25.2 MB                0.79 MB
 bert-large, 12x512    12.6 MB             12.6 MB                0.39 MB
+moonlight, 1x8192     33.6 MB (hidden     33.6 MB (16 heads x    0.52 MB
+                      2048)               128 wide VALUES)
 ====================  ==================  =====================  ===========
+
+A latent-attention block (moonlight) declares nothing new: the two names
+carry ``out`` at the values' width, and what ``full_block`` runs again is
+the latent path — the query, down- and up-projections (19.1 MFLOP a
+token), the latent's norm, the rotation and the assembly of k (50 MB) and
+v (34 MB) from the 8192 x 576 latent (9.4 MB).  Naming the latent would
+save the down-projection alone (2.4 of a block's ~117 MFLOP a token
+forward) and keep the rest; it is not named (PERF.md section 5 has what
+the recomputed latent path costs on the chip).
 
 A gated-delta-net block (qwen3-next, 1x8192, 32 value heads of 128 x 128)
 keeps its input 33.6 MB, the rule's output 67.1 MB (bf16 since PR 31; the

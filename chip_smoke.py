@@ -316,7 +316,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     root_key = jax.random.PRNGKey(seed)
     # the first three as they always were; the later checks fold theirs in
     keys = iter(list(jax.random.split(root_key, 3))
-                + [jax.random.fold_in(root_key, i) for i in (3, 4, 5, 6)])
+                + [jax.random.fold_in(root_key, i) for i in (3, 4, 5, 6, 7)])
     calls: Dict[str, int] = {}
     parity: Dict[str, Dict] = {}
     facts.update(
@@ -325,7 +325,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 "flash_window_grouped": [1, 4, 8 * s, 128],
                 "grouped_mm": [rows, n, 2 * n],
                 "moe_dispatch": [rows, -(-n // 1024) * 1024],
-                "gated_delta": [1, 8 * s, [s // 64, s // 32], 128]},
+                "gated_delta": [1, 8 * s, [s // 64, s // 32], 128],
+                "flash_latent": [1, s // 64, 8 * s, [192, 128]]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -528,6 +529,34 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
         tuple(t.astype(f32) for t in (qd, kd, vd)) + (gd_, bd, w_delta), 2,
         (2e-2, (("dq", 2e-2), ("dk", 2e-2), ("dv", 2e-2), ("dg", 2e-2),
                 ("dbeta", 2e-2))))
+
+    # the flash kernels on the fourth decoder block's call
+    # (models/deepseek_v3.py, moonlight.train-8k): sixteen heads over eight
+    # contexts, queries and keys 192 wide (1.5 lane tiles) against values 128
+    # wide, causal — o and dv at the values' width, nothing padded.  The
+    # reference takes a head at a time (sixteen heads' float32 scores at once
+    # would be 4.3 GB, and as much again for each of their gradients)
+    hl = s // 64
+    ql, kl, vl, w_lat = seeded(lambda *ks: [
+        (normal(ki, (1, hl, sw, width), f32) * scale).astype(dt)
+        for ki, width, scale, dt in zip(ks, (192, 192, 128, 128),
+                                        (0.3, 0.3, 0.3, 1.0),
+                                        (bf16, bf16, bf16, f32))
+    ])
+
+    def latent_loss(q, k, v, w):
+        out = flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(f32) * w), out
+
+    def latent_ref_loss(q, k, v, w):
+        head = jax.checkpoint(lambda t: attention_ref(
+            *(x[None, None] for x in t), causal=True)[0, 0])
+        out = jax.lax.map(head, (q[0], k[0], v[0]))[None]
+        return jnp.sum(out * w), out
+
+    run("flash_latent", latent_loss, latent_ref_loss, (ql, kl, vl, w_lat),
+        tuple(t.astype(f32) for t in (ql, kl, vl)) + (w_lat,), 3,
+        (3e-2, (("dq", 3e-2), ("dk", 3e-2), ("dv", 3e-2))))
 
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 

@@ -712,3 +712,96 @@ def test_window_call_counts_its_band_for_query_heads():
     total, visited, masked = flash_tile_census(512, 512, 128, 128, True, 200)
     for n, want in zip(("total", "visited", "masked"), (total, visited, masked)):
         assert reg.counter("ops.flash.tiles_" + n).value - before[n] == 4 * want
+
+
+# ---------------------------------------------------------------------------
+# v at a head size of its own (latent attention: 192-wide q and k, 128-wide v)
+# ---------------------------------------------------------------------------
+
+def _unequal_case(hq, hkv, s, d_qk, d_v, b=1, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(k[0], (b, hq, s, d_qk)),
+            jax.random.normal(k[1], (b, hkv, s, d_qk)),
+            jax.random.normal(k[2], (b, hkv, s, d_v)),
+            jax.random.normal(k[3], (b, hq, s, d_v)))
+
+
+@pytest.mark.parametrize("two_pass", [False, True], ids=["fused", "two_pass"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("hq,hkv,s,d_qk,d_v,window,bq,bk", [
+    (2, 2, 256, 192, 128, None, 128, 128),   # the latent mixer's head sizes
+    (2, 2, 256, 128, 64, None, 128, 128),    # a toy pair
+    (2, 2, 256, 64, 128, None, 128, 128),    # values WIDER than keys
+    (2, 2, 256, 192, 128, None, None, None),  # auto blocks: one tile a head
+    (4, 2, 256, 192, 128, None, 128, 128),   # grouped heads, unequal sizes
+    (2, 2, 384, 192, 128, 100, 128, 128),    # a window, unequal sizes
+    (4, 2, 256, 64, 64, None, 128, 128),     # grouped heads, equal sizes
+    (2, 2, 384, 64, 64, 100, 128, 128),      # a window, equal sizes
+], ids=["192_128", "128_64", "64_128", "192_128_one_tile", "192_128_g2",
+        "192_128_win100", "64_64_g2", "64_64_win100"])
+def test_value_head_size_of_its_own_matches_ref(monkeypatch, hq, hkv, s, d_qk,
+                                                d_v, window, bq, bk, causal,
+                                                two_pass):
+    """o, dq, dk, dv against ``attention_ref`` where v's head size is not q's
+    and k's, on both backward routes; o and dv come out at v's size, dq and
+    dk at q's; the scale is q's ``D ** -0.5``.  The equal-size grouped and
+    window cases run the same assertions over the routes they always took."""
+    if not causal:
+        window = None       # a window goes with causal: three blocks, full
+    if two_pass:
+        monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 0)
+    q, k, v, do = _unequal_case(hq, hkv, s, d_qk, d_v)
+    kw = dict(causal=causal, window=window)
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v) * do)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, block_q=bq, block_k=bk, use_pallas=True, **kw)
+    ref = lambda q, k, v: _ref(q, k, v, **kw)
+    out = flash(q, k, v)
+    assert out.shape == (1, hq, s, d_v)
+    np.testing.assert_allclose(out, ref(q, k, v), atol=2e-5)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+
+def test_value_head_size_is_read_from_the_shapes():
+    """No switch: the same call at ``D_v == D`` hands the kernels blocks ``D``
+    wide alone, as it always did, and at ``D_v != D`` v's own width beside it
+    — v, o, do, dv are never padded to ``D``.  The default scale is the
+    queries' ``D ** -0.5`` either way, and the off-TPU fallback takes the
+    same shapes."""
+    def kernel_widths(d_v):
+        """Last dims of the 3-d bfloat16 operands and results of every
+        pallas_call in the gradient's jaxpr."""
+        q, k, v, do = (t.astype(jnp.bfloat16)
+                       for t in _unequal_case(2, 2, 256, 192, d_v))
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=True, block_q=128, block_k=128,
+                use_pallas=True) * do), (0, 1, 2)))(q, k, v)
+        found = set()
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.update(
+                        x.aval.shape[-1] for x in eqn.invars + eqn.outvars
+                        if x.aval.ndim == 3 and x.aval.dtype == jnp.bfloat16)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        return found
+
+    assert kernel_widths(192) == {192}
+    assert kernel_widths(128) == {192, 128}
+    q, k, v, _ = _unequal_case(2, 2, 256, 192, 128)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, causal=True, use_pallas=True),
+        flash_attention(q, k, v, causal=True, scale=192 ** -0.5,
+                        use_pallas=False), atol=2e-5)
+    with pytest.raises(ValueError, match="share a head size"):
+        flash_attention(q, k[..., :128], v, causal=True)
+    with pytest.raises(ValueError, match="share a head size"):
+        flash_attention(q, k, v[:, :, :128], causal=True)

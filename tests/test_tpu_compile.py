@@ -351,6 +351,74 @@ def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
                      "apex_flash_bwd_dq"], names
 
 
+def test_flash_latent_head_sizes_compile_without_padding(chip, as_tpu):
+    """Moonlight's attention call: 16 heads at 8192 positions, queries and
+    keys 192 wide (128 + the 64 rotary dims, 1.5 lane tiles) against values
+    128 wide.  Mosaic takes the 192-wide blocks, and v, o, do and dv cross
+    the custom calls at 128: no operand or result of the three kernels is
+    padded to the queries' width."""
+    import re
+
+    from apex_tpu.ops import flash_attention
+    from apex_tpu.ops._common import mosaic_call_names
+
+    def loss(q, k, v):
+        with jax.named_scope("attn_full"):
+            return jnp.sum(flash_attention(q, k, v, causal=True).astype(F32))
+
+    qk, v = ((1, 16, 8192, 192), BF16), ((1, 16, 8192, 128), BF16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (qk, qk, v)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+    assert names == ["apex_flash_fwd", "apex_flash_bwd_dkdv",
+                     "apex_flash_bwd_dq"], names
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and "apex_flash" in l and " = " in l]
+    assert len(calls) == 3
+    widths = [sorted(map(int, re.findall(r"bf16\[16,8192,(\d+)\]", l)))
+              for l in calls]
+    assert widths == [
+        [128, 128, 192, 192],               # forward: v, o | q, k
+        [128, 128, 128, 192, 192, 192],     # dkdv: v, do, dv | q, k, dk
+        [128, 128, 192, 192, 192],          # dq: v, do | q, k, dq
+    ], widths
+
+
+def test_expert_layer_compiles_at_width_1408_and_8_held_of_64(chip, as_tpu):
+    """moonlight.train-8k's expert layer: 8192 tokens x 6 slots, 8 held of
+    64 experts, (8192 * 6 / 256 + 8) * 256 = 51,200 rows, expert width 1408
+    = 11 x 128 — a gate|up block 2048 x 2816 and a down block 1408 x 2048,
+    widths no power of two divides past 128."""
+    from apex_tpu.ops import grouped_mm as gmm
+    from apex_tpu.ops import moe_rows
+    from apex_tpu.parallel.moe import ExpertShardMLP
+
+    assert gmm.rows_capacity(6 * 8192, 8) == 51200
+    assert moe_rows.supported(8192, 6, 2048, gmm.DEFAULT_TILE_ROWS, BF16)
+    layer = ExpertShardMLP(num_experts=64, experts_held=(0, 8), d_ff=1408,
+                           k=6, shared_d_ff=2816, route_scale=2.446,
+                           compute_dtype=BF16)
+    x = jax.ShapeDtypeStruct((8192, 2048), BF16, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((8192, 2048), BF16))["params"])
+
+    def loss(p, x):
+        return jnp.sum(layer.apply({"params": p}, x).astype(F32))
+
+    from apex_tpu.ops._common import mosaic_call_names
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    names = [n.rsplit(".", 1)[0] if n.rsplit(".", 1)[-1].isdigit() else n
+             for n in mosaic_call_names(text)]
+    assert names.count("apex_gmm") == 4 and names.count("apex_gmm_dw") == 2, names
+    assert {"apex_moe_records", "apex_moe_gather", "apex_moe_combine",
+            "apex_moe_combine_dw"} <= set(names), names
+
+
 def test_moe_row_movement_compiles_at_90112_rows_and_81920_slots(chip, as_tpu):
     """qwen3-next.train-8k's expert layer: 8192 tokens x 10 slots, 32 held
     experts, a buffer of (8192 * 10 / 256 + 32) * 256 = 90,112 rows — 30%
