@@ -51,10 +51,16 @@ Called as :class:`apex_tpu.models.gpt.GPTLM` and ``AfmoeLM`` are:
 ``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a
 sliced ``vocab_size``, as in ``models/afmoe.py``.  Scopes ``gdn_proj``,
 ``gdn_conv``, ``gdn_scan``, ``gdn_out``, ``attn_full``, the four ``moe_*``,
-``lm_head``, ``lm_loss``.  Under ``gdn_scan`` XLA keeps what is a row's own
-(sigmoid, softplus, the two l2 norms, the casts) and the rule is two kernels
-that read q, k, v in the compute dtype as this model lays them out and write
-o (``ops/gated_delta.py``): no float32 array of q's size crosses HBM there.
+``lm_head``, ``lm_loss``.  Under ``gdn_conv`` the projection's output goes
+into ``ops/gated_delta.py::split_conv_qkvz`` as it lies: on the TPU two
+kernels read q, k, v out of it through BlockSpecs and write them contiguous
+over their heads, and the backward one writes the projection's gradient in
+the same per-key-head layout — no concatenated, no float32 and no padded copy
+crosses HBM there; z's cut stays XLA's copy.  Under ``gdn_scan`` XLA keeps
+what is a row's own (sigmoid, softplus, the two l2 norms, the casts) and the
+rule is two kernels that read q, k, v in the compute dtype as this model lays
+them out and write o (``ops/gated_delta.py``): no float32 array of q's size
+crosses HBM there.
 Serving methods are not part of this model yet: a recurrent state beside K/V
 pages is ROADMAP M6's other half.
 """
@@ -69,7 +75,7 @@ import jax.numpy as jnp
 
 from apex_tpu.amp.layers import Dense
 from apex_tpu.ops.attention import flash_attention
-from apex_tpu.ops.gated_delta import causal_conv1d_silu, gated_delta_rule
+from apex_tpu.ops.gated_delta import gated_delta_rule, split_conv_qkvz
 from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
 from apex_tpu.parallel.moe import ExpertShardMLP
 from apex_tpu.remat import remat_module
@@ -184,18 +190,16 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gdn_proj"):
             qkvz = dense(hk * (2 * dk + 2 * r * dv), "in_proj_qkvz")(x)
             ba = dense(hk * 2 * r, "in_proj_ba")(x)
-            qkvz = qkvz.reshape(b, s, hk, 2 * dk + 2 * r * dv)
-            q, k, v, z = jnp.split(
-                qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
             beta_in, a = jnp.split(ba.reshape(b, s, hk, 2 * r), 2, axis=-1)
         with jax.named_scope("gdn_conv"):
             conv_w = self.param("conv", init,
                                 (2 * hk * dk + hv * dv,
                                  cfg.linear_conv_kernel_dim), jnp.float32)
-            mixed = causal_conv1d_silu(jnp.concatenate(
-                [q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk),
-                 v.reshape(b, s, hv * dv)], axis=-1), conv_w)
-            q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+            # the projection's output goes in as it lies, per KEY head [q |
+            # k | v | z]: on the TPU the kernels read q, k, v through
+            # BlockSpecs on it and write them contiguous over their heads
+            q, k, v, z = split_conv_qkvz(qkvz, conv_w, key_heads=hk,
+                                         key_dim=dk, value_dim=dv)
         with jax.named_scope("gdn_scan"):
             a_log = self.param("A_log", a_log_init, (hv,), jnp.float32)
             dt_bias = self.param("dt_bias", nn.initializers.ones_init(),

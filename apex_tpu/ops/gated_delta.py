@@ -1,6 +1,8 @@
 """The gated delta rule over a sequence, by chunks — two Pallas TPU kernels
 that hold everything between q, k, v, g, beta and o in VMEM, and a ``lax.scan``
-path — and the short causal depthwise convolution in front of it.
+path — and the short causal depthwise convolution in front of it — two more
+kernels that read q, k, v out of the projection's output where they lie, and
+a ``jax.numpy`` path.
 
 The first sequential operator of ``ops/``: a linear-attention layer
 (``models/qwen3_next.py``'s gated delta net) keeps, per head, a float32
@@ -52,6 +54,23 @@ other), and dg is dG summed back over the chunk — 1 MB each.  Both kernels
 are written a LINE of the arithmetic at a time over the heads of a grid
 step, because a head's products wait on each other.
 
+**The convolution in front of the rule is two kernels more**
+(:func:`split_conv_qkvz`).  The model's projection hands q, k, v and the
+output gate z over in ONE array laid out per key head ``[q d_k | k d_k | v
+r d_v | z r d_v]``; every part starts a whole number of 128-lane tiles in, so
+``apex_conv1d_fwd`` reads a block of rows of one key head's q, k and v
+columns through BlockSpecs on that array itself, does the ``K`` taps and the
+SiLU in float32 in VMEM (the ``K - 1`` rows before a block carried over from
+the block before, zeros at every row's start) and writes q, k, v contiguous
+over their heads, as the rule's kernels read them.  ``apex_conv1d_bwd``
+walks the row blocks from the last and writes the projection's gradient in
+the projection's own layout.  What crosses HBM under the model's scope
+``gdn_conv`` is the operator's input and output in the compute dtype and
+z's copy: the concatenated ``[q | k | v]`` array, its padded float32 copy
+and the ``K`` shifted float32 passes that :func:`causal_conv1d_silu` costs on
+the chip (29.96 ms a step of ``qwen3-next.train-8k`` for 3.4 ms of bytes,
+PERF.md section 6, PR 33) exist only off the TPU.
+
 **Off the TPU, and as the kernels' oracle,** what is local to a chunk is
 computed for all chunks at once by batched products (:func:`_chunk_local`,
 plain ``jax.numpy``, differentiated by JAX; ``T`` by :func:`tri_inverse`) and
@@ -91,7 +110,8 @@ from apex_tpu.ops._common import pallas_call as _pallas_call, pallas_default
 from apex_tpu.remat import GDN_OUT, GDN_STATES, GDN_TRI
 
 __all__ = ["gated_delta_rule", "gated_delta_rule_recurrent",
-           "causal_conv1d_silu", "tri_inverse", "DEFAULT_CHUNK"]
+           "causal_conv1d_silu", "split_conv_qkvz", "tri_inverse",
+           "DEFAULT_CHUNK"]
 
 DEFAULT_CHUNK = 64
 #: value heads a grid step of the kernels takes together, at most: a step's
@@ -109,15 +129,366 @@ def causal_conv1d_silu(x, w):
 
     ``x`` (B, S, channels), ``w`` (channels, K): ``y_t = sum_j w[:, j] *
     x_{t - (K-1) + j}`` with zeros before the row's start, no bias.  ``K``
-    shifted multiply-adds in float32 (XLA fuses them into one pass; a
-    ``K``-tap depthwise convolution has no use for the MXU); ``x``'s dtype
-    out."""
+    shifted multiply-adds in float32 (a ``K``-tap depthwise convolution has
+    no use for the MXU); ``x``'s dtype out.  The path off the TPU and the
+    oracle of :func:`split_conv_qkvz`'s kernels: on the chip XLA makes a
+    padded float32 copy of ``x`` and ``K`` shifted float32 passes of this,
+    not one fused pass (PERF.md section 6, PR 33)."""
     k = w.shape[-1]
     s = x.shape[1]
     x32 = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     w32 = w.astype(jnp.float32)
     y = sum(x32[:, j:j + s] * w32[:, j] for j in range(k))
     return jax.nn.silu(y).astype(x.dtype)
+
+
+def _conv_dims(width: int, hk: int, dk: int, dv: int):
+    """``(per-head width P, value heads a key head r)`` of a projection
+    output ``width`` wide laid out per key head ``[q d_k | k d_k | v r d_v |
+    z r d_v]``."""
+    p = width // hk
+    r = (p - 2 * dk) // (2 * dv)
+    if hk * p != width or r < 1 or 2 * dk + 2 * r * dv != p:
+        raise ValueError(f"a width of {width} is not {hk} key heads of "
+                         f"[q {dk} | k {dk} | v r x {dv} | z r x {dv}]")
+    return p, r
+
+
+def _split_conv_xla(qkvz, w, hk: int, dk: int, dv: int):
+    """:func:`split_conv_qkvz` in plain ``jax.numpy``: the four parts cut
+    out of the per-key-head layout, each contiguous over its heads (XLA's
+    strided copies), q, k, v concatenated in the convolution's channel order
+    ``[q | k | v]``, :func:`causal_conv1d_silu`, split again."""
+    b, s, width = qkvz.shape
+    p, r = _conv_dims(width, hk, dk, dv)
+    q, k, v, z = (t.reshape(b, s, -1) for t in jnp.split(
+        qkvz.reshape(b, s, hk, p), [dk, 2 * dk, 2 * dk + r * dv], axis=-1))
+    mixed = causal_conv1d_silu(jnp.concatenate([q, k, v], axis=-1), w)
+    return (*jnp.split(mixed, [q.shape[-1], 2 * q.shape[-1]], axis=-1), z)
+
+
+#: rows of the sequence a grid step of the convolution's kernels takes, at
+#: most (a power of two; the largest that divides S is taken): a step pays
+#: for its rows and operands, so few large blocks (PERF.md section 6, PR 25)
+_CONV_ROWS = 1024
+#: rows of a block the kernels work through at a time, straight-line: what
+#: lives between a piece's loads and its store stays in registers
+_CONV_PIECE = 256
+#: rows of float32 kept in front of (forward) or behind (backward) a block
+#: in VMEM for the taps that reach over its edge: one sublane tile
+_HALO = 8
+#: rows of the block of preceding inputs the backward kernel reads through
+#: a BlockSpec of its own: one tile of a 16-bit array
+_HALO_ROWS = 16
+
+
+def _conv_tile(s: int):
+    """``(rows of a block, rows of a piece)`` for a sequence of ``s``
+    tokens: the largest power of two up to :data:`_CONV_ROWS` that divides
+    it, worked through :data:`_CONV_PIECE` rows at a time."""
+    rows = _CONV_ROWS
+    while rows > 1 and s % rows:
+        rows //= 2
+    return rows, min(rows, _CONV_PIECE)
+
+
+def conv_supported(s: int, dk: int, dv: int, r: int, taps: int) -> bool:
+    """Whether the convolution's kernels take these shapes: head sizes of
+    whole 128-lane tiles, every part of the per-key-head layout ``[q d_k | k
+    d_k | v r d_v | z r d_v]`` starting a whole number of its own widths in
+    (equal head sizes always do), whole row blocks of at least a 16-bit
+    tile, and taps that reach no further back than one sublane tile."""
+    return (dk % 128 == 0 and dv % 128 == 0 and (2 * r * dv) % dk == 0
+            and (2 * dk) % dv == 0 and _conv_tile(s)[0] >= _HALO_ROWS
+            and 1 <= taps <= _HALO + 1)
+
+
+def _taps(w_ref, lanes, window):
+    """``sum_j w[j] * window(j)``: the ``K`` multiply-adds of one piece,
+    float32; ``window(j)`` the rows tap ``j`` reads."""
+    acc = None
+    for j in range(w_ref.shape[0]):
+        term = w_ref[j:j + 1, lanes] * window(j)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _conv_parts(dk: int, dv: int, r: int):
+    """The column blocks of one key head, as ``(width, output, first lane
+    in that output's block)``: q, k, and its ``r`` value heads — each read
+    through a BlockSpec of its own width (any ``r`` keeps every offset a
+    whole block), v's written side by side into one block of ``r d_v``."""
+    return ([(dk, 0, 0), (dk, 1, 0)]
+            + [(dv, 2, j * dv) for j in range(r)])
+
+
+def _conv_fwd_kernel(*refs, parts, size):
+    """Grid (key heads, rows of the batch, row blocks — walked in order),
+    ``size`` rows worked through at a time: ``refs`` = the parts' input
+    blocks, w's three blocks (K, width), the three output blocks, a float32
+    scratch (HALO + rows, width) a part whose first HALO rows carry the
+    previous block's last ones."""
+    n = len(parts)
+    x_refs, w_refs = refs[:n], refs[n:n + 3]
+    o_refs, xs_refs = refs[n + 3:n + 6], refs[n + 6:]
+    f32 = jnp.float32
+    rows = x_refs[0].shape[1]
+    first = pl.program_id(2) == 0
+    for x_ref, xs, (width, out, lane) in zip(x_refs, xs_refs, parts):
+        lanes = slice(lane, lane + width)
+        w_ref, o_ref = w_refs[out], o_refs[out]
+        k = w_ref.shape[0]
+
+        @pl.when(first)         # zeros before the start of EVERY row
+        def _():
+            xs[0:_HALO, :] = jnp.zeros((_HALO, width), f32)
+
+        for start in range(0, rows, size):
+            xs[_HALO + start:_HALO + start + size, :] = x_ref[
+                0, start:start + size, :].astype(f32)
+            y = _taps(w_ref, lanes, lambda j: xs[pl.ds(
+                _HALO - (k - 1) + j + start, size), :])
+            o_ref[0, start:start + size, lanes] = (
+                y * jax.nn.sigmoid(y)).astype(o_ref.dtype)
+        xs[0:_HALO, :] = xs[rows:rows + _HALO, :]
+
+
+def _conv_bwd_kernel(*refs, parts, size):
+    """Grid (key heads, rows of the batch, row blocks — walked from the
+    LAST): ``refs`` = the parts' input blocks, their blocks of preceding
+    rows, the blocks of dq, dk, dv and dz, w's three, then the block of the
+    projection's gradient (a key head's q, k, v, z side by side: dz is
+    copied into its place), dw's three (K, width) blocks — resident over a
+    key head's whole walk and summed into —, a float32 scratch (HALO +
+    rows, widest part) for the inputs, and one (rows + HALO, width) a part
+    for ``dy silu'(y)`` whose last HALO rows carry the following block's
+    first ones."""
+    n = len(parts)
+    x_refs, h_refs = refs[:n], refs[n:2 * n]
+    dy_refs, dz_ref = refs[2 * n:2 * n + 3], refs[2 * n + 3]
+    w_refs, dx_ref = refs[2 * n + 4:2 * n + 7], refs[2 * n + 7]
+    dw_refs = refs[2 * n + 8:2 * n + 11]
+    xs, gs_refs = refs[2 * n + 11], refs[2 * n + 12:]
+    f32 = jnp.float32
+    rows = x_refs[0].shape[1]
+    last_block = pl.program_id(2) == 0          # the walk's first step
+    first_block = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(jnp.logical_and(pl.program_id(1) == 0, last_block))
+    def _():
+        for dw_ref in dw_refs:
+            dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    dx_lane = 0
+    for x_ref, h_ref, gs, (width, out, lane) in zip(x_refs, h_refs, gs_refs,
+                                                    parts):
+        lanes = slice(lane, lane + width)
+        dy_ref, w_ref, dw_ref = dy_refs[out], w_refs[out], dw_refs[out]
+        k = w_ref.shape[0]
+        halo = h_ref[0].astype(f32)[h_ref.shape[1] - _HALO:]
+        xs[0:_HALO, :width] = jnp.where(first_block, 0.0, halo)
+
+        @pl.when(last_block)    # nothing follows a row's last token
+        def _():
+            gs[rows:rows + _HALO, :] = jnp.zeros((_HALO, width), f32)
+
+        for start in range(0, rows, size):
+            xs[_HALO + start:_HALO + start + size, :width] = x_ref[
+                0, start:start + size, :].astype(f32)
+        dw = [jnp.zeros((1, width), f32)] * k
+        for start in reversed(range(0, rows, size)):
+            window = [xs[pl.ds(_HALO - (k - 1) + j + start, size), :width]
+                      for j in range(k)]
+            y = _taps(w_ref, lanes, lambda j: window[j])
+            sig = jax.nn.sigmoid(y)
+            g = (dy_ref[0, start:start + size, lanes].astype(f32)
+                 * (sig * (1.0 + y * (1.0 - sig))))
+            gs[start:start + size, :] = g
+            dw = [acc + jnp.sum(g * window[j], axis=0, keepdims=True)
+                  for j, acc in enumerate(dw)]
+            # the taps the other way: row t's input fed outputs t .. t + K-1
+            dx = _taps(w_ref, lanes, lambda j: gs[pl.ds(
+                start + (k - 1) - j, size), :])
+            dx_ref[0, start:start + size, dx_lane:dx_lane + width] = (
+                dx.astype(dx_ref.dtype))
+        for j in range(k):
+            dw_ref[j:j + 1, lanes] += dw[j]
+        gs[rows:rows + _HALO, :] = gs[0:_HALO, :]
+        dx_lane += width
+    dx_ref[0, :, dx_lane:] = dz_ref[0]
+
+
+def _conv_specs(rows, taps, p, dk, dv, r, block_of):
+    """BlockSpecs over a grid (key heads, rows of the batch, row blocks),
+    step ``i`` of the last axis taking row block ``block_of(i)``: ``(the
+    parts' blocks of the projection's output, their blocks of the HALO_ROWS
+    preceding rows, [q, k, v, z]-shaped blocks, w's blocks, a key head's
+    whole block of the projection's output)``."""
+    def rows_of(width, col):
+        return pl.BlockSpec((1, rows, width),
+                            lambda h, b, i: (b, block_of(i), col(h)))
+
+    def halo_of(width, col):
+        per = rows // _HALO_ROWS
+        return pl.BlockSpec(
+            (1, _HALO_ROWS, width),
+            lambda h, b, i: (b, jnp.maximum(block_of(i) * per - 1, 0), col(h)))
+
+    # a part's first column in the projection's output, in blocks of its width
+    offsets = [0, dk] + [2 * dk + j * dv for j in range(r)]
+    widths = [dk, dk] + [dv] * r
+    cols = [(lambda h, o=o, w=w: (h * p + o) // w)
+            for o, w in zip(offsets, widths)]
+    head = lambda h: h
+    weights = lambda width: pl.BlockSpec((taps, width), lambda h, b, i: (0, h))
+    return ([rows_of(w, c) for w, c in zip(widths, cols)],
+            [halo_of(w, c) for w, c in zip(widths, cols)],
+            [rows_of(dk, head), rows_of(dk, head), rows_of(r * dv, head),
+             rows_of(r * dv, head)],
+            [weights(dk), weights(dk), weights(r * dv)], rows_of(p, head))
+
+
+def _conv_weights(w, hk, dk):
+    """``w`` (channels, K) in the channel order ``[q | k | v]`` as three
+    float32 arrays (K, heads x d): a tap is a row of lanes (32 K values)."""
+    wt = w.astype(jnp.float32).T
+    return jnp.split(wt, [hk * dk, 2 * hk * dk], axis=1)
+
+
+def _conv_fwd_pallas(qkvz, w, hk, dk, dv, tile):
+    b, s, width = qkvz.shape
+    rows, piece = tile
+    p, r = _conv_dims(width, hk, dk, dv)
+    parts = _conv_parts(dk, dv, r)
+    taps = w.shape[1]
+    x_specs, _, out_specs, w_specs, _ = _conv_specs(
+        rows, taps, p, dk, dv, r, lambda i: i)
+    return _pallas_call(
+        functools.partial(_conv_fwd_kernel, parts=parts, size=piece),
+        name="apex_conv1d_fwd", grid=(hk, b, s // rows),
+        in_specs=[*x_specs, *w_specs], out_specs=out_specs[:3],
+        out_shape=[jax.ShapeDtypeStruct((b, s, hk * dk), qkvz.dtype),
+                   jax.ShapeDtypeStruct((b, s, hk * dk), qkvz.dtype),
+                   jax.ShapeDtypeStruct((b, s, hk * r * dv), qkvz.dtype)],
+        scratch_shapes=[pltpu.VMEM((_HALO + rows, width_), jnp.float32)
+                        for width_, _, _ in parts],
+        compiler_params=_COMPILER_PARAMS,
+    )(*[qkvz] * len(parts), *_conv_weights(w, hk, dk))
+
+
+def _conv_bwd_pallas(qkvz, w, dq, dk_, dv_, dz, hk, dk, dv, tile):
+    """``(d qkvz, dw)``: the projection's gradient written where it lies, a
+    key head's ``[dq | dk | dv | dz]`` side by side."""
+    b, s, width = qkvz.shape
+    p, r = _conv_dims(width, hk, dk, dv)
+    parts = _conv_parts(dk, dv, r)
+    taps = w.shape[1]
+    rows, piece = tile
+    n = s // rows
+    x_specs, halo_specs, dy_specs, w_specs, dx_spec = _conv_specs(
+        rows, taps, p, dk, dv, r, lambda i: n - 1 - i)
+    dw_specs = w_specs
+    weights = _conv_weights(w, hk, dk)
+    dx, *dw = _pallas_call(
+        functools.partial(_conv_bwd_kernel, parts=parts, size=piece),
+        name="apex_conv1d_bwd", grid=(hk, b, n),
+        in_specs=[*x_specs, *halo_specs, *dy_specs, *w_specs],
+        out_specs=[dx_spec, *dw_specs],
+        out_shape=[jax.ShapeDtypeStruct(qkvz.shape, qkvz.dtype),
+                   *(jax.ShapeDtypeStruct(x.shape, jnp.float32)
+                     for x in weights)],
+        scratch_shapes=[pltpu.VMEM((_HALO + rows, max(dk, dv)), jnp.float32)]
+        + [pltpu.VMEM((rows + _HALO, width_), jnp.float32)
+           for width_, _, _ in parts],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+    )(*[qkvz] * (2 * len(parts)), dq, dk_, dv_, dz, *weights)
+    return dx, jnp.concatenate(dw, axis=1).T.astype(w.dtype)
+
+
+def _cut_z(qkvz, hk: int, dk: int, dv: int):
+    """z (B, S, H_v d_v) out of the per-key-head layout as ``H_k``
+    lane-aligned column slices side by side: a copy of z's bytes alone, which
+    XLA fuses into z's reader.  Cut as :func:`_split_conv_xla` cuts it —
+    through the (B, S, H_k, P) reshape — the chip first makes a relayout
+    copy of the WHOLE projection output (PERF.md section 6, PR 33: 0.61 ms a
+    pass)."""
+    p, r = _conv_dims(qkvz.shape[2], hk, dk, dv)
+    return jnp.concatenate([qkvz[:, :, (h + 1) * p - r * dv:(h + 1) * p]
+                            for h in range(hk)], axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _conv_kernels(qkvz, w, hk, dk, dv, tile):
+    return (*_conv_fwd_pallas(qkvz, w, hk, dk, dv, tile),
+            _cut_z(qkvz, hk, dk, dv))
+
+
+def _conv_kernels_fwd(qkvz, w, hk, dk, dv, tile):
+    return _conv_kernels(qkvz, w, hk, dk, dv, tile), (qkvz, w)
+
+
+def _conv_kernels_bwd(hk, dk, dv, tile, res, dys):
+    return _conv_bwd_pallas(*res, *dys, hk, dk, dv, tile)
+
+
+_conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
+
+
+# Called through jit, as the rule below: a model's layers share one trace.
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _conv_jit(qkvz, w, hk, dk, dv, tile, trace_key):
+    del trace_key
+    if tile:
+        return _conv_kernels(qkvz, w, hk, dk, dv, tile)
+    return _split_conv_xla(qkvz, w, hk, dk, dv)
+
+
+def split_conv_qkvz(qkvz, w, *, key_heads: int, key_dim: int,
+                    value_dim: int, use_pallas: Optional[bool] = None):
+    """``in_proj_qkvz``'s output cut into its four parts, q, k and v through
+    the delta net's short convolution on the way — read where they lie.
+
+    ``qkvz`` (B, S, H_k (2 d_k + 2 r d_v)) laid out per KEY head ``[q d_k |
+    k d_k | v r d_v | z r d_v]``, ``w`` (2 H_k d_k + H_v d_v, K) with its
+    channels in the order ``[q | k | v]`` over all heads.  Returns ``(q (B,
+    S, H_k d_k), k, v (B, S, H_v d_v), z)`` in ``qkvz``'s dtype, each
+    contiguous over its heads; q, k, v are :func:`causal_conv1d_silu` of the
+    three taken together — float32 taps and SiLU, one rounding at the output
+    —, z is a copy.  Differentiable in ``qkvz`` and ``w``.
+
+    On the TPU, where the shapes tile (:func:`conv_supported`), two kernels.
+    ``apex_conv1d_fwd`` takes a block of rows x one key head's q, k and v
+    columns through BlockSpecs on ``qkvz`` itself (z is not read: its copy
+    stays XLA's), keeps the ``K - 1`` preceding rows in VMEM from the block
+    before (zeros at every row's start) and writes q, k, v.
+    ``apex_conv1d_bwd`` walks the row blocks from the last: ``y`` again,
+    ``dy silu'(y)``, the taps the other way (dx), dw summed in float32 over
+    the blocks, and writes the PROJECTION's gradient where it lies, a key
+    head's ``[dq | dk | dv | dz]`` side by side — dz handed through, because
+    XLA's interleave of the four is three relayout passes over an array of
+    ``qkvz``'s size (PERF.md section 6, PR 33).  No concatenated, no float32
+    and no padded array crosses HBM.  Else the same in ``jax.numpy``
+    (:func:`_split_conv_xla`).  The gauge ``gdn.conv_kernel`` says which was
+    traced."""
+    hk, dk, dv = key_heads, key_dim, value_dim
+    _, r = _conv_dims(qkvz.shape[2], hk, dk, dv)
+    if w.shape[0] != 2 * hk * dk + hk * r * dv:
+        raise ValueError(f"w has {w.shape[0]} channels, q, k and v "
+                         f"{2 * hk * dk + hk * r * dv}")
+    ok = conv_supported(qkvz.shape[1], dk, dv, r, w.shape[1])
+    if use_pallas is None:
+        use_pallas = pallas_default(ok)
+    elif use_pallas and not ok:
+        raise ValueError(f"the convolution's kernels want head sizes of 128 "
+                         f"lanes and rows in blocks of {_HALO_ROWS}: got "
+                         f"{qkvz.shape}, {dk}, {dv}, {w.shape}")
+    from apex_tpu import obs
+
+    obs.default_registry().gauge("gdn.conv_kernel").set(int(use_pallas))
+    return _conv_jit(qkvz, w, hk, dk, dv,
+                     _conv_tile(qkvz.shape[1]) if use_pallas else None,
+                     _trace_key())
 
 
 # ---------------------------------------------------------------------------

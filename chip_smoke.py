@@ -316,7 +316,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     root_key = jax.random.PRNGKey(seed)
     # the first three as they always were; the later checks fold theirs in
     keys = iter(list(jax.random.split(root_key, 3))
-                + [jax.random.fold_in(root_key, i) for i in (3, 4, 5, 6, 7)])
+                + [jax.random.fold_in(root_key, i) for i in range(3, 9)])
     calls: Dict[str, int] = {}
     parity: Dict[str, Dict] = {}
     facts.update(
@@ -326,7 +326,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 "grouped_mm": [rows, n, 2 * n],
                 "moe_dispatch": [rows, -(-n // 1024) * 1024],
                 "gated_delta": [1, 8 * s, [s // 64, s // 32], 128],
-                "flash_latent": [1, s // 64, 8 * s, [192, 128]]},
+                "flash_latent": [1, s // 64, 8 * s, [192, 128]],
+                "conv1d": [1, 8 * s, s // 64 * 768, 4]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -557,6 +558,33 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     run("flash_latent", latent_loss, latent_ref_loss, (ql, kl, vl, w_lat),
         tuple(t.astype(f32) for t in (ql, kl, vl)) + (w_lat,), 3,
         (3e-2, (("dq", 3e-2), ("dk", 3e-2), ("dv", 3e-2))))
+
+    # the short convolution in front of the rule as qwen3-next.train-8k calls
+    # it (ops/gated_delta.py::split_conv_qkvz): in_proj_qkvz's output of one
+    # row of 8 contexts, laid out per key head [q 128 | k 128 | v 256 | z
+    # 256], read where it lies — against the XLA form on the same values in
+    # float32
+    from apex_tpu.ops.gated_delta import split_conv_qkvz
+
+    xc, wc, w_conv = seeded(lambda kx, kw, kc, _: (
+        normal(kx, (1, 8 * s, hk_d * 768), f32).astype(bf16),
+        0.5 * normal(kw, (hk_d * 512, 4), f32),
+        normal(kc, (1, 8 * s, hk_d * 768), f32).astype(bf16)))
+    # a cotangent bfloat16 holds, so that both sides see the same dy — cast
+    # back OUTSIDE the program that drew it: inside one fusion the chip's
+    # compiler keeps a bfloat16 round trip in float32 and rounds nothing
+    w_conv = w_conv.astype(f32)
+
+    def conv_loss(use_pallas):
+        def loss(x, w, cot):
+            out = jnp.concatenate(split_conv_qkvz(
+                x, w, key_heads=hk_d, key_dim=128, value_dim=128,
+                use_pallas=use_pallas), axis=-1)
+            return jnp.sum(out.astype(f32) * cot), out
+        return loss
+
+    run("conv1d", conv_loss(None), conv_loss(False), (xc, wc, w_conv),
+        (xc.astype(f32), wc, w_conv), 2, (1e-2, (("dx", 1e-2), ("dw", 1e-3))))
 
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 

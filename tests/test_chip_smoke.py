@@ -64,7 +64,7 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
         assert ln["cache"]["from_env"] is True
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
-        "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent"}
+        "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d"}
     assert train["loss_per_window"][-1] < train["loss_per_window"][0]
     assert train["compiles_after_first_window"] == 0
     assert serve["compiles_after_warmup"] == 0

@@ -3,7 +3,8 @@ recurrence it is defined by (outputs and every gradient; the kernels — which
 make what is local to a chunk themselves — in interpret mode, and the
 ``lax.scan`` path), q and k at fewer heads than v, bfloat16 operands, the
 decays at their strongest, the triangular inverse, and the causal
-convolution in front of the rule."""
+convolution in front of the rule — its XLA form, and the two kernels that
+read q, k, v out of the projection's output where they lie."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -205,6 +206,130 @@ def test_convolution_is_causal_from_the_rows_start():
                                  jnp.asarray(w)).dtype == jnp.bfloat16
 
 
+def conv_inputs(dtype=jnp.float32, r=2, b=2, s=96, hk=2, d=128, taps=4):
+    """``in_proj_qkvz``'s output laid out per key head ``[q | k | v | z]``,
+    the convolution's weights in the channel order ``[q | k | v]``, and a
+    cotangent for each of q, k, v, z."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    width = hk * (2 * d + 2 * r * d)
+    x = jax.random.normal(ks[0], (b, s, width)).astype(dtype)
+    w = 0.5 * jax.random.normal(ks[1], (2 * hk * d + hk * r * d, taps))
+    cts = [jax.random.normal(k_, (b, s, n)).astype(dtype) for k_, n in
+           zip(ks[2:], (hk * d, hk * d, hk * r * d, hk * r * d))]
+    return x, w, cts, dict(key_heads=hk, key_dim=d, value_dim=d)
+
+
+def conv_oracle(x, w, key_heads, key_dim, value_dim):
+    """``causal_conv1d_silu`` fed the split-and-concatenated input, as the
+    model called it before the kernels; z cut out beside."""
+    b, s, width = x.shape
+    hk, d = key_heads, key_dim
+    per_head = width // hk
+    rd = (per_head - 2 * d) // 2
+    q, k, v, z = jnp.split(x.reshape(b, s, hk, per_head),
+                           [d, 2 * d, 2 * d + rd], axis=-1)
+    flat = lambda t: t.reshape(b, s, -1)
+    mixed = gd.causal_conv1d_silu(
+        jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1), w)
+    return (*jnp.split(mixed, [hk * d, 2 * hk * d], axis=-1), flat(z))
+
+
+@pytest.fixture
+def small_conv_tiles(monkeypatch):
+    """Row blocks of 32 worked through 16 rows at a time: a sequence of 96
+    is three blocks, so the taps cross block and piece boundaries."""
+    monkeypatch.setattr(gd, "_CONV_ROWS", 32)
+    monkeypatch.setattr(gd, "_CONV_PIECE", 16)
+
+
+@pytest.mark.parametrize("r", [1, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_conv_kernels_match_the_xla_form(dtype, r, small_conv_tiles):
+    """q, k, v, z, the gradient in the projection's own layout and dw, a
+    batch of two rows of three row blocks each."""
+    x, w, cts, dims = conv_inputs(dtype, r)
+    f32 = lambda t: t.astype(jnp.float32)
+
+    def grads(fn):
+        loss = lambda x, w: sum(jnp.sum(f32(o) * f32(c))
+                                for o, c in zip(fn(x, w, **dims), cts))
+        return jax.grad(loss, argnums=(0, 1))(x, w)
+
+    kernels = lambda x, w, **kw: gd.split_conv_qkvz(x, w, use_pallas=True, **kw)
+    got, want = kernels(x, w, **dims), conv_oracle(x, w, **dims)
+    one_ulp = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-6
+    for name, a, b in zip("qkvz", got, want):
+        assert a.shape == b.shape and a.dtype == dtype, name
+        assert gap(f32(a), f32(b)) <= one_ulp, name
+    np.testing.assert_array_equal(got[3], want[3])          # z is a copy
+    (dx, dw), (dx_want, dw_want) = grads(kernels), grads(conv_oracle)
+    assert dx.shape == x.shape and dx.dtype == dtype
+    assert dw.shape == w.shape and dw.dtype == jnp.float32
+    assert gap(f32(dx), f32(dx_want)) <= 2 * one_ulp
+    assert gap(dw, dw_want) <= 1e-5      # float32 sums in another order
+
+
+def test_conv_kernels_start_every_row_of_the_batch_from_zeros(
+        small_conv_tiles):
+    """The second row's first outputs must not see the first row's tail:
+    each row alone gives the same bits as the two together."""
+    x, w, cts, dims = conv_inputs(jnp.bfloat16)
+    both = gd.split_conv_qkvz(x, w, use_pallas=True, **dims)
+    grad = lambda x, ct: jax.grad(lambda x: jnp.sum(gd.split_conv_qkvz(
+        x, w, use_pallas=True, **dims)[2].astype(jnp.float32) * ct))(x)
+    dx_both = grad(x, cts[2].astype(jnp.float32))
+    for row in (0, 1):
+        alone = gd.split_conv_qkvz(x[row:row + 1], w, use_pallas=True, **dims)
+        for a, b in zip(alone, both):
+            np.testing.assert_array_equal(a[0], b[row])
+        np.testing.assert_array_equal(
+            grad(x[row:row + 1], cts[2][row:row + 1].astype(jnp.float32))[0],
+            dx_both[row])
+
+
+def test_conv_kernels_are_causal_across_row_blocks(small_conv_tiles):
+    """A changed later token leaves every earlier output bit-equal, and
+    reaches exactly the K outputs from its own position on — across the
+    boundary of a row block (token 63 feeds outputs 63..66)."""
+    x, w, _, dims = conv_inputs(jnp.float32, b=1)
+    base = gd.split_conv_qkvz(x, w, use_pallas=True, **dims)
+    later = gd.split_conv_qkvz(x.at[:, 63].add(1.0), w, use_pallas=True,
+                               **dims)
+    for a, b in zip(base[:3], later[:3]):
+        np.testing.assert_array_equal(a[:, :63], b[:, :63])
+        np.testing.assert_array_equal(a[:, 67:], b[:, 67:])
+        assert bool(jnp.all(jnp.any(a[:, 63:67] != b[:, 63:67], axis=-1)))
+
+
+def test_conv_takes_the_xla_form_where_the_shapes_do_not_tile():
+    from apex_tpu import obs
+
+    gauge = lambda: obs.default_registry().get("gdn.conv_kernel").value
+    x, w, _, dims = conv_inputs(s=40)        # 40 rows: no block of 16
+    assert not gd.conv_supported(40, 128, 128, 2, 4)
+    assert gd.conv_supported(96, 128, 128, 2, 4)
+    assert not gd.conv_supported(96, 64, 128, 2, 4)      # half a lane tile
+    assert not gd.conv_supported(96, 128, 128, 2, 10)    # taps past the halo
+    with force_pallas(True):
+        got = gd.split_conv_qkvz(x, w, **dims)
+    assert gauge() == 0
+    for a, b in zip(got, conv_oracle(x, w, **dims)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="128 lanes"):
+        gd.split_conv_qkvz(x, w, use_pallas=True, **dims)
+    with pytest.raises(ValueError, match="key heads of"):
+        gd.split_conv_qkvz(x[..., :-128], w, **dims)
+    with pytest.raises(ValueError, match="channels"):
+        gd.split_conv_qkvz(x, w[:-1], **dims)
+    x, w, _, dims = conv_inputs(s=48)
+    with force_pallas(True):
+        gd.split_conv_qkvz(x, w, **dims)
+    assert gauge() == 1
+    gd.split_conv_qkvz(x, w, **dims)         # off the TPU: the XLA form
+    assert gauge() == 0
+
+
 def test_rule_refuses_what_it_cannot_tile_and_sets_its_gauges():
     from apex_tpu import obs
 
@@ -240,3 +365,13 @@ def test_kernel_names_keep_clear_of_the_other_families():
     for name in ours:
         assert not any(f in name for f in
                        ("apex_gmm", "apex_flash", "apex_ln_", "apex_xent_"))
+
+
+def test_conv_kernel_names_keep_clear_of_every_family_readers_match():
+    from apex_tpu.ops._common import KERNEL_NAMES
+
+    ours = [n for n in KERNEL_NAMES if n.startswith("apex_conv1d_")]
+    assert sorted(ours) == ["apex_conv1d_bwd", "apex_conv1d_fwd"]
+    for name in ours:
+        assert not any(f in name for f in (
+            "apex_gmm", "apex_flash", "apex_ln_", "apex_xent_", "apex_gdn_"))

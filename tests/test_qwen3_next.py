@@ -98,6 +98,7 @@ def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
     reg = obs.default_registry()
     assert reg.get("moe.dispatch.kernels").value == kernels
     assert reg.get("gdn.kernels").value == kernels
+    assert reg.get("gdn.conv_kernel").value == kernels     # 128 rows tile
     # float32 both sides, two derivations: the chunked algebra (a triangular
     # inverse, differences of products) leaves 1e-4 where the recurrence and
     # the other layers leave 1e-5
@@ -245,6 +246,67 @@ def test_rotary_leaves_the_rest_of_a_head_untouched():
     # a rotation: the rotated part keeps its length
     np.testing.assert_allclose(jnp.linalg.norm(y[..., :64], axis=-1),
                                jnp.linalg.norm(x[..., :64], axis=-1), rtol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_conv_kernels_leave_loss_and_gradients_where_the_xla_form_has_them(
+        dtype, monkeypatch):
+    """``Qwen3NextConfig.tiny()`` with the convolution's two kernels forced
+    on (interpret mode; rows of 64 tokens in two row blocks) and everything
+    else as off the TPU, against the same model on the XLA form: the loss
+    and every leaf's gradient."""
+    import functools
+
+    from apex_tpu import obs
+    from apex_tpu.models import qwen3_next as program
+    from apex_tpu.ops import gated_delta as gd
+
+    monkeypatch.setattr(gd, "_CONV_ROWS", 32)
+    cfg = program.Qwen3NextConfig.tiny(compute_dtype=dtype)
+    model = program.Qwen3NextLM(cfg)
+    ids, labels = batch(seq=64, vocab=cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    loss_of = lambda p: model.apply({"params": p}, ids, labels=labels,
+                                    deterministic=False)[1]
+    want_loss, want = jax.value_and_grad(loss_of)(params)
+    gauge = obs.default_registry().get("gdn.conv_kernel")
+    assert gauge.value == 0
+    monkeypatch.setattr(program, "split_conv_qkvz", functools.partial(
+        gd.split_conv_qkvz, use_pallas=True))
+    loss, got = jax.value_and_grad(loss_of)(params)
+    assert gauge.value == 1
+    # float32: 1e-3 a leaf as the test against the reference above — dw's
+    # sum in another order leaves 2e-4 of A_log's gradient, itself 1e-6
+    tol = 1e-3 if dtype == jnp.float32 else 2e-2
+    assert abs(float(loss) - float(want_loss)) <= tol * 1e-2 * float(want_loss)
+    flat = lambda t: jax.tree_util.tree_leaves_with_path(t)
+    for (path, a), (_, b) in zip(flat(got), flat(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, path
+        assert rel_gap(a, b) <= tol, jax.tree_util.keystr(path)
+
+
+def test_delta_nets_parameter_tree_is_what_to_program_fills():
+    """The kernels read the projection's output in the PUBLISHED per-key-
+    head layout and the convolution's weights in the published channel
+    order: every name and shape of the program's tree is the one
+    ``families/qwen3_next.py::to_program`` loads into, unedited."""
+    cfg = tiny_cfg()
+    rcfg, w = seeded(cfg)
+    model = fam.program_model(fam.program_config(cfg, jnp.float32))
+    ids, _ = batch(rows=1, seq=64)
+    made = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids)["params"])
+    shapes = lambda t: jax.tree_util.tree_map(lambda x: tuple(x.shape), t)
+    assert shapes(made) == shapes(fam.to_program(w, cfg))
+    hk, hv, d, hidden, taps = 1, 2, 128, 128, 4
+    assert shapes(made["layer_0"]["gdn"]) == {
+        "in_proj_qkvz": {"kernel": (hidden, hk * (2 * d + 2 * (hv // hk) * d))},
+        "in_proj_ba": {"kernel": (hidden, 2 * hv)},
+        "conv": (2 * hk * d + hv * d, taps),
+        "A_log": (hv,), "dt_bias": (hv,), "norm": (d,),
+        "out_proj": {"kernel": (hv * d, hidden)},
+    }
 
 
 def test_weights_round_trip_through_the_programs_layouts():

@@ -162,6 +162,8 @@ def _named_case(kernel):
                 [((8192, 2048), BF16), ((8192, 8), F32), ((8192, 8), I32)])
     if kernel.startswith("apex_gdn_"):
         return _gdn_case()
+    if kernel.startswith("apex_conv1d_"):
+        return _conv_case()
     if kernel.startswith("apex_xent_"):
         from apex_tpu.ops import softmax_cross_entropy
 
@@ -189,6 +191,23 @@ def _gdn_case():
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), [qk, qk, v, gb, gb]
 
 
+def _conv_case():
+    """The delta net's short convolution as ``qwen3-next.train-8k`` calls
+    it: ``in_proj_qkvz``'s output of one 8192-token row, 16 key heads of [q
+    128 | k 128 | v 256 | z 256] in bfloat16, 4 taps over 8192 channels
+    (ops/gated_delta.py), forward and backward."""
+    from apex_tpu.ops.gated_delta import split_conv_qkvz
+
+    def loss(qkvz, w):
+        with jax.named_scope("gdn_conv"):
+            parts = split_conv_qkvz(qkvz, w, key_heads=16, key_dim=128,
+                                    value_dim=128)
+        return sum(jnp.sum(t.astype(F32) ** 2) for t in parts)
+
+    return (jax.grad(loss, argnums=(0, 1)),
+            [((1, 8192, 12288), BF16), ((8192, 4), F32)])
+
+
 _NAMES_OF_CASE = {}
 
 
@@ -199,6 +218,7 @@ _NAMES_OF_CASE = {}
     "apex_xent_bwd", "apex_paged_attn", "apex_gmm", "apex_gmm_dw",
     "apex_moe_records", "apex_moe_gather", "apex_moe_combine",
     "apex_moe_combine_dw", "apex_gdn_fwd", "apex_gdn_bwd",
+    "apex_conv1d_fwd", "apex_conv1d_bwd",
 ])
 def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     """The custom call's HLO instruction — what a device trace names the
@@ -207,10 +227,10 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     from apex_tpu.ops._common import KERNEL_NAMES
 
     assert kernel in KERNEL_NAMES
-    # (the four apex_moe_* kernels are one program, the two apex_gdn_*
-    # another: each compiled once)
-    case = next((f for f in ("apex_moe_", "apex_gdn_") if kernel.startswith(f)),
-                kernel)
+    # (the four apex_moe_* kernels are one program, the two apex_gdn_* and
+    # the two apex_conv1d_* two more: each compiled once)
+    case = next((f for f in ("apex_moe_", "apex_gdn_", "apex_conv1d_")
+                 if kernel.startswith(f)), kernel)
     if case not in _NAMES_OF_CASE:
         fn, avals = _named_case(kernel)
         _NAMES_OF_CASE[case] = _mosaic_names(chip, fn, *avals)
@@ -259,6 +279,55 @@ def test_delta_rule_keeps_what_is_local_to_a_chunk_inside_its_kernels(
             size *= int(d)
         assert not (dtype == "f32" and size >= q_size), line[:200]
     assert seen > 10        # the witness that the lines were found at all
+
+
+def test_delta_net_layer_reads_q_k_v_out_of_the_projection_in_place(
+        chip, as_tpu):
+    """A whole delta-net mixer's gradient at the cell's shape: ONE
+    ``apex_conv1d_fwd`` and ONE ``apex_conv1d_bwd`` beside the rule's two,
+    none anonymous, and under ``gdn_conv`` outside the custom calls no
+    float32 array of the convolution's size (the padded ``(S + K - 1) x
+    8192`` copy the XLA form makes) and no ``concatenate`` (q, k, v put side
+    by side for it, or the four gradients interleaved after it): what is
+    left there is z's copy, in bfloat16."""
+    import re
+
+    from apex_tpu import obs
+    from apex_tpu.models.qwen3_next import GatedDeltaNet, Qwen3NextConfig
+    from apex_tpu.ops._common import mosaic_call_names, unnamed_mosaic_calls
+
+    mixer = GatedDeltaNet(Qwen3NextConfig())
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), BF16, sharding=chip)
+    shapes = jax.eval_shape(
+        lambda: mixer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, BF16)))
+    params = jax.tree_util.tree_map(
+        lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=chip),
+        shapes)
+    loss = lambda p, x: jnp.sum(mixer.apply(p, x).astype(F32) ** 2)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    names = sorted(re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text))
+    assert names == ["apex_conv1d_bwd", "apex_conv1d_fwd", "apex_gdn_bwd",
+                     "apex_gdn_fwd"], names
+    assert not unnamed_mosaic_calls(text)
+    assert obs.default_registry().get("gdn.conv_kernel").value == 1
+    conv_size = 8192 * 8192
+    instr = re.compile(r"= (\(?)(\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
+    seen = 0
+    for line in text.splitlines():
+        m = instr.search(line)
+        if not m or "gdn_conv" not in line or "tpu_custom_call" in line:
+            continue
+        seen += 1
+        is_tuple, dtype, dims, opcode = m.groups()
+        assert opcode != "concatenate", line[:200]
+        if is_tuple or opcode in ("get-tuple-element", "bitcast"):
+            continue        # a kernel's own result, or a view of one
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        assert not (dtype == "f32" and size >= conv_size), line[:200]
+    assert seen > 5         # the witness that the lines were found at all
 
 
 def test_a_kernel_differentiated_outside_any_scope_still_bears_its_name(
