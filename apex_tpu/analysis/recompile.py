@@ -105,44 +105,68 @@ class CompileMonitor:
     a callback invoked with the compile duration (seconds) on every
     counted event, so a live tracer can attribute the compile to the
     span that was open when it happened (a warm-path compile then shows
-    up as a tagged span, not just a bigger count).
+    up as a tagged span, not just a bigger count).  ``on_event`` hands
+    the same bridge EVERY ``jax.monitoring`` event while the monitor is
+    active, as ``(name, value)``: a duration event's seconds, 1 for a
+    plain event (the persistent cache's ``cache_hits`` / ``cache_misses``)
+    -- the tracer picks the compile path's out of them.  Note what the
+    counted event wraps in JAX 0.9.0: ``compile_or_get_cached``, so it
+    fires once per program the in-memory jit cache missed, whether XLA
+    then compiled it or the persistent cache handed it back.
     """
 
-    def __init__(self, on_compile: Optional[Callable[[float], None]] = None):
+    def __init__(self, on_compile: Optional[Callable[[float], None]] = None,
+                 on_event: Optional[Callable[[str, float], None]] = None):
         self.compiles = 0
         self._active = False
         self._tracked: Dict[str, tuple] = {}
         self._on_compile = on_compile
+        self._on_any = on_event
 
     # -- context protocol ----------------------------------------------
 
+    @staticmethod
+    def _tell(callback, *args) -> None:
+        """Telemetry must never break the compile path."""
+        if callback is not None:
+            try:
+                callback(*args)
+            except Exception:
+                pass
+
     def _on_event(self, name: str, *args, **kwargs):
-        if self._active and name == _COMPILE_EVENT:
+        if not self._active:
+            return
+        dur = float(args[0]) if args else 0.0
+        if name == _COMPILE_EVENT:
             self.compiles += 1
-            if self._on_compile is not None:
-                dur = args[0] if args else 0.0
-                try:
-                    self._on_compile(float(dur))
-                except Exception:
-                    pass  # telemetry must never break the compile path
+            self._tell(self._on_compile, dur)
+        self._tell(self._on_any, name, dur)
+
+    def _on_plain_event(self, name: str, **kwargs):
+        if self._active:
+            self._tell(self._on_any, name, 1.0)
 
     def __enter__(self):
         self._active = True
         jax.monitoring.register_event_duration_secs_listener(
             self._on_event
         )
+        if self._on_any is not None:
+            jax.monitoring.register_event_listener(self._on_plain_event)
         return self
 
     def __exit__(self, *exc):
         self._active = False
-        try:
-            from jax._src import monitoring as _m
+        from jax._src import monitoring as _m
 
-            _m._unregister_event_duration_listener_by_callback(
-                self._on_event
-            )
-        except Exception:
-            pass  # deactivated above; the dead listener is inert
+        for unregister, listener in (
+                ("unregister_event_duration_listener", self._on_event),
+                ("unregister_event_listener", self._on_plain_event)):
+            try:
+                getattr(_m, unregister)(listener)
+            except Exception:
+                pass  # deactivated above; a dead listener is inert
         return False
 
     # -- per-function attribution --------------------------------------

@@ -141,7 +141,8 @@ KNOBS: List[EnvKnob] = [
     # -- observability --------------------------------------------------
     EnvKnob("APEX_TPU_OBS", "1",
             "0 disables runtime telemetry (spans, lifecycle "
-            "histograms, timeline counters)."),
+            "histograms, timeline counters, the jit.* compile counters "
+            "and the gc-pause hook)."),
     EnvKnob("APEX_TPU_OBS_TRACE_DIR", None,
             "Export the ambient obs trace here at tier-1 session end "
             "(set by tools/run_tier1.sh --trace DIR)."),

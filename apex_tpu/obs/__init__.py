@@ -11,7 +11,13 @@ or a recompile to a compiled program:
 - :mod:`~apex_tpu.obs.trace` — the monotonic-clock nestable
   :class:`Tracer`: spans around every dispatch boundary in the train
   driver and every ServeEngine phase, each tagged
-  executed-vs-compiled via the PR 4 ``CompileMonitor`` bridge;
+  executed-vs-compiled via the PR 4 ``CompileMonitor`` bridge, each
+  with the main thread's and the process's CPU time, and (the ambient
+  tracer) the garbage collector's pauses beside them;
+- :mod:`~apex_tpu.obs.windows` — :func:`train_windows`: a train loop's
+  wall time laid out window by window (the gap before it, its enqueue,
+  the host's time while it is in flight, the blocked fetch), from those
+  spans — live, or from an exported ``trace.jsonl``;
 - :mod:`~apex_tpu.obs.lifecycle` — per-request TTFT / inter-token
   latency / queue-delay histograms from the engine's boundary
   timestamps;
@@ -44,8 +50,9 @@ or a recompile to a compiled program:
   the Chrome form) + the OpenMetrics text exposition
   (:func:`to_openmetrics`) so snapshots scrape like Prometheus.
 
-Kill switch: ``APEX_TPU_OBS=0`` (spans/events become shared no-ops;
-the engine's ``stats()`` counters keep working — they are accounting,
+Kill switch: ``APEX_TPU_OBS=0`` (spans/events become shared no-ops,
+the ``jit.*`` counters stop and no ``gc`` callback is installed; the
+engine's ``stats()`` counters keep working — they are accounting,
 not telemetry).  ``APEX_TPU_OBS_TRACE_DIR=<dir>`` makes tier-1
 (``tools/run_tier1.sh --trace <dir>``) export the ambient trace at
 session end.
@@ -101,6 +108,7 @@ from apex_tpu.obs.slo import (  # noqa: F401
     slo_admission_default,
 )
 from apex_tpu.obs.trace import (  # noqa: F401
+    JIT_EVENTS,
     NULL_TRACER,
     Span,
     Tracer,
@@ -110,8 +118,10 @@ from apex_tpu.obs.trace import (  # noqa: F401
     reset_default,
     set_enabled_override,
 )
+from apex_tpu.obs.windows import train_windows  # noqa: F401
 
 __all__ = [
+    "JIT_EVENTS",
     "SCHEMA",
     "Counter",
     "FleetAggregator",
@@ -151,9 +161,16 @@ __all__ = [
     "set_flightrec_override",
     "slo_admission_default",
     "to_openmetrics",
+    "train_windows",
     "write_chrome_trace",
     "write_flightrec_line",
     "write_jsonl",
     "write_openmetrics",
     "write_slo_line",
 ]
+
+# Made with the package, not at the first span: the compile bridge and the
+# GC hook account for the process (set-up's tracing, compiles and cache
+# loads happen before any span opens) only if they are there from the start.
+# With obs off this is NULL_TRACER and nothing is installed.
+default_tracer()

@@ -4,14 +4,21 @@ Three formats, one tracer:
 
 - **JSONL** (``trace.jsonl``) — the canonical machine-readable log
   ``tools/trace_report.py`` renders: one JSON object per line — a
-  ``meta`` header, every span (``ts``/``dur`` in clock ns), every
-  instant/counter event, and optionally a final ``metrics`` line
-  holding a :class:`~apex_tpu.obs.metrics.MetricsRegistry` snapshot.
-  Line-appendable, diff-able, and parseable without loading the file.
+  ``meta`` header, every span (``ts``/``dur`` in clock ns, the CPU
+  clocks ``cpu0``/``cpu``/``cpu_all0``/``cpu_all`` in ns, ``profiled``,
+  ``jit``), every instant/counter event, the ambient tracer's garbage
+  collector pauses (``{"type": "gc"}``), and optionally a final
+  ``metrics`` line holding a
+  :class:`~apex_tpu.obs.metrics.MetricsRegistry` snapshot.
+  Line-appendable, diff-able, and parseable without loading the file;
+  :func:`apex_tpu.obs.train_windows` reduces it as it does a live tracer.
 - **Chrome trace** (``trace.chrome.json``) — the ``trace_event``
   format (``chrome://tracing`` / Perfetto UI): spans as complete
-  ``"ph": "X"`` events (µs timestamps), counters as ``"ph": "C"``
-  series, compile-tagged spans carrying ``args.compiles``.  The same
+  ``"ph": "X"`` events (µs timestamps; the main thread's CPU clock as
+  the format's own ``tts``/``tdur``, the process's as
+  ``args.cpu_all_us``), counters as ``"ph": "C"`` series, collector
+  pauses as ``gc`` events on a thread of their own, compile-tagged spans
+  carrying ``args.compiles`` (and ``args.jit``).  The same
   schema :func:`apex_tpu.pyprof.parse.parse_chrome_trace` ingests, so
   the measured-profile machinery (scope tables, percent-of-total) works
   on host spans exactly as it does on device kernel times.
@@ -50,6 +57,8 @@ def _span_lines(tracer):
         elif payload:
             d["attrs"] = payload
         yield d
+    for t0, dur, generation in tuple(tracer.gc_pauses):
+        yield {"type": "gc", "ts": t0, "dur": dur, "generation": generation}
 
 
 def write_jsonl(tracer, path: str,
@@ -158,14 +167,27 @@ def write_chrome_trace(tracer, path: str,
         ev = {
             "name": sp.name, "ph": "X", "pid": 0, "tid": 0,
             "ts": sp.t0 / 1e3, "dur": sp.dur / 1e3,
+            "tts": sp.cpu0 / 1e3, "tdur": sp.cpu / 1e3,
             "cat": "apex_tpu",
         }
         args = dict(sp.attrs) if sp.attrs else {}
         if sp.compiles:
             args["compiles"] = sp.compiles
+        if sp.jit:
+            args["jit"] = sp.jit
+        if sp.cpu_all:
+            args["cpu_all_us"] = sp.cpu_all / 1e3
+        if sp.profiled:
+            args["profiled"] = True
         if args:
             ev["args"] = args
         events.append(ev)
+    for t0, dur, generation in tuple(tracer.gc_pauses):
+        events.append({
+            "name": "gc", "ph": "X", "pid": 0, "tid": 1,
+            "ts": t0 / 1e3, "dur": dur / 1e3, "cat": "gc",
+            "args": {"generation": generation},
+        })
     for ts, kind, name, payload in tracer.events:
         if kind == "counter":
             events.append({

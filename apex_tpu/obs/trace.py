@@ -32,6 +32,22 @@ actually happen.  This tracer does, under hard constraints:
   lands on the innermost open span (``span.compiles``), so an
   *executed-vs-compiled* tag rides on every span and a warm-path
   compile is a visible, testable anomaly instead of a silent stall.
+  The same bridge takes JAX's other compile-path events
+  (:data:`JIT_EVENTS`): seconds of Python tracing, of lowering, of
+  backend compile and of loading from the persistent cache, and the
+  cache's hits and misses, land on the innermost open span
+  (``span.jit``) and, for the ambient tracer, in the ``jit.*`` counters
+  of :func:`default_registry`.
+- **CPU-clocked.** A span reads the main thread's CPU clock and the
+  process's at both ends (``cpu0``/``cpu``, ``cpu_all0``/``cpu_all``):
+  a span the host slept through reads ``cpu`` ~ 0, one in which Python
+  worked reads ``cpu`` ~ ``dur``, one in which the runtime's threads
+  compiled or loaded reads ``cpu_all`` >> ``cpu``.  ``cpu0`` of the next
+  span less ``cpu0 + cpu`` of this one is the CPU of the gap between.
+- **GC-aware.** The ambient tracer owns a ``gc.callbacks`` hook: each
+  collection's pause is kept as ``(t0, dur, generation)`` in the ring
+  ``gc_pauses`` (its own: never a span or an event) and in the
+  ``host.gc_ms`` histogram of :func:`default_registry`.
 
 ::
 
@@ -50,6 +66,7 @@ one ambient destination; ``APEX_TPU_OBS=0`` (or
 from __future__ import annotations
 
 import collections
+import gc
 import os
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
@@ -76,6 +93,27 @@ _ENABLED_OVERRIDE: Optional[bool] = None
 DEFAULT_CAPACITY = 16384
 #: what every span's event in a profiler trace is named by
 PROFILER_PREFIX = "apex/"
+#: how many compile-path intervals are kept until a later one wraps them:
+#: one window program's trace holds ~10,000 inner traces (GPT-2 small) to a
+#: few ten thousand, all children of the one outer trace that ends last;
+#: a ring shorter than that charges the outer trace its children's time too
+_JIT_ROOTS = 1 << 17
+#: the ``jax.monitoring`` events the compile bridge takes, as JAX 0.9.0
+#: fires them, and the key each lands under (``span.jit``, ``jit.<key>``
+#: in the registry).  Seconds are EXCLUSIVE: JAX fires an event as its
+#: interval ends and intervals nest (an inner ``jit``'s trace inside the
+#: outer's, helper traces inside lowering, the cache's retrieval inside
+#: ``backend_compile_duration`` -- which wraps ``compile_or_get_cached``
+#: and so fires on a persistent-cache hit too), so a wrapper is charged
+#: what its children did not take and the four sum to wall time at most.
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
 
 
 def enabled() -> bool:
@@ -97,18 +135,32 @@ def set_enabled_override(value: Optional[bool]) -> None:
 class Span:
     """One finished (or open) span: name, [t0, t0+dur) in clock ns,
     nesting depth, free-form attrs, and the number of XLA backend
-    compiles that fired while it was the innermost open span."""
+    compiles that fired while it was the innermost open span.
 
-    __slots__ = ("name", "t0", "dur", "depth", "attrs", "compiles")
+    ``cpu0`` / ``cpu`` are the main thread's CPU ns when the span opened
+    and over it, ``cpu_all0`` / ``cpu_all`` the whole process's;
+    ``profiled`` says a profiler session was open when the span opened
+    (it is then an event of that profile too); ``jit`` is None or the
+    :data:`JIT_EVENTS` seconds and counts that fired under the span."""
+
+    __slots__ = ("name", "t0", "dur", "depth", "attrs", "compiles",
+                 "cpu0", "cpu", "cpu_all0", "cpu_all", "profiled", "jit")
 
     def __init__(self, name: str, t0: int, depth: int,
-                 attrs: Optional[Dict[str, Any]]):
+                 attrs: Optional[Dict[str, Any]],
+                 cpu0: int = 0, cpu_all0: int = 0):
         self.name = name
         self.t0 = t0
         self.dur = 0
         self.depth = depth
         self.attrs = attrs
         self.compiles = 0
+        self.cpu0 = cpu0
+        self.cpu = 0
+        self.cpu_all0 = cpu_all0
+        self.cpu_all = 0
+        self.profiled = False
+        self.jit: Optional[Dict[str, float]] = None
 
     def set(self, key: str, value: Any) -> None:
         """Attach/overwrite one attr on an open span."""
@@ -126,7 +178,12 @@ class Span:
             "type": "span", "name": self.name, "ts": self.t0,
             "dur": self.dur, "depth": self.depth,
             "compiles": self.compiles,
+            "cpu0": self.cpu0, "cpu": self.cpu,
+            "cpu_all0": self.cpu_all0, "cpu_all": self.cpu_all,
+            "profiled": self.profiled,
         }
+        if self.jit:
+            d["jit"] = self.jit
         if self.attrs:
             d["attrs"] = self.attrs
         return d
@@ -138,8 +195,9 @@ class _NullSpan:
     __slots__ = ()
     name = ""
     t0 = dur = depth = compiles = 0
-    attrs = None
-    compiled = False
+    cpu0 = cpu = cpu_all0 = cpu_all = 0
+    attrs = jit = None
+    compiled = profiled = False
 
     def __enter__(self):
         return self
@@ -167,6 +225,7 @@ class _SpanCtx:
         self._span = span
         self._annotation = None
         if TraceAnnotation.is_enabled():    # a profiler session is open
+            span.profiled = True
             # the attrs known when the span opens, scalars only, become
             # the event's stats (``sp.set`` later reaches only the tracer)
             attrs = span.attrs or {}
@@ -203,23 +262,41 @@ class Tracer:
     ``.events`` as ``(ts, kind, name, payload)`` tuples.  Each ring keeps
     its newest :data:`DEFAULT_CAPACITY` entries; ``recorded`` counts all
     ever made and ``dropped`` those that fell off.  ``close()`` detaches the
-    compile listener; tracers are single-threaded like the schedulers
-    they instrument.
+    compile listener (and the ambient tracer's GC hook); tracers are
+    single-threaded like the schedulers they instrument.
+
+    ``thread_clock`` / ``process_clock`` (``time.thread_time_ns`` /
+    ``time.process_time_ns``) are attributes a test may replace.
+    ``gc_pauses`` is the ring of ``(t0, dur, generation)`` the GC hook
+    fills; only :func:`default_tracer` installs the hook, and neither it
+    nor the compile bridge's seconds touch ``spans``, ``events`` or
+    ``recorded``.
     """
 
     def __init__(self, enabled: Optional[bool] = None, clock=None,
                  monitor_compiles: bool = True):
         self.enabled = _enabled_default() if enabled is None else enabled
         self.clock = clock or time.perf_counter_ns
+        self.thread_clock = time.thread_time_ns
+        self.process_clock = time.process_time_ns
         self.spans: Deque[Span] = collections.deque(maxlen=DEFAULT_CAPACITY)
         self.events: Deque[Tuple[int, str, str, Any]] = collections.deque(
+            maxlen=DEFAULT_CAPACITY)
+        self.gc_pauses: Deque[Tuple[int, int, int]] = collections.deque(
             maxlen=DEFAULT_CAPACITY)
         self.recorded = 0
         self.compiles = 0
         self._stack: List[Span] = []
+        # (start ns, ns) of the compile-path intervals that no later one
+        # has yet been seen to wrap: see _on_jit_event
+        self._jit_roots: Deque[Tuple[int, int]] = collections.deque(
+            maxlen=_JIT_ROOTS)
+        self._ambient = False       # default_tracer()'s: owns the GC hook
+        self._gc_t0: Optional[int] = None
         self._monitor: Optional[CompileMonitor] = None
         if self.enabled and monitor_compiles:
-            self._monitor = CompileMonitor(on_compile=self._on_compile)
+            self._monitor = CompileMonitor(on_compile=self._on_compile,
+                                           on_event=self._on_jit_event)
             self._monitor.__enter__()
 
     # -- recording -----------------------------------------------------
@@ -229,12 +306,15 @@ class Tracer:
         Returns the shared no-op span when disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        sp = Span(name, self.clock(), len(self._stack), attrs or None)
+        sp = Span(name, self.clock(), len(self._stack), attrs or None,
+                  self.thread_clock(), self.process_clock())
         self._stack.append(sp)
         return _SpanCtx(self, sp)
 
     def _finish(self, sp: Span) -> None:
         sp.dur = self.clock() - sp.t0
+        sp.cpu = self.thread_clock() - sp.cpu0
+        sp.cpu_all = self.process_clock() - sp.cpu_all0
         # tolerate exception-path unwinding out of order: pop through
         if self._stack and self._stack[-1] is sp:
             self._stack.pop()
@@ -263,18 +343,68 @@ class Tracer:
         if self._stack:
             self._stack[-1].compiles += 1
 
+    def _on_jit_event(self, event: str, value: float) -> None:
+        """One ``jax.monitoring`` event of :data:`JIT_EVENTS`: a count,
+        or seconds made exclusive.  JAX fires a duration as its interval
+        ends, so what this one wraps has already arrived: the roots that
+        began inside it are its children, and it is charged the rest."""
+        key = JIT_EVENTS.get(event)
+        if key is None or (self._ambient and not enabled()):
+            return
+        if key.endswith("_s"):
+            ns = int(value * 1e9)
+            start = self.clock() - ns
+            roots = self._jit_roots
+            while roots and roots[-1][0] >= start:
+                value -= roots.pop()[1] * 1e-9
+            roots.append((start, ns))
+            value = max(value, 0.0)
+        if self._stack:
+            sp = self._stack[-1]
+            if sp.jit is None:
+                sp.jit = {}
+            sp.jit[key] = sp.jit.get(key, 0) + value
+        if self._ambient:
+            default_registry().counter("jit." + key).inc(value)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        try:
+            if phase == "start":
+                # an override flipped off mid-process silences the hook
+                self._gc_t0 = self.clock() if enabled() else None
+            elif self._gc_t0 is not None:
+                t0, self._gc_t0 = self._gc_t0, None
+                dur = self.clock() - t0
+                self.gc_pauses.append((t0, dur, info.get("generation", -1)))
+                default_registry().histogram("host.gc_ms").observe(
+                    dur * 1e-6)
+        except Exception:
+            pass    # the interpreter is shutting down under the hook
+
     # -- lifecycle -----------------------------------------------------
 
+    def _make_ambient(self) -> "Tracer":
+        """What only the process's one ambient tracer does: feed the
+        ``jit.*`` counters and time the garbage collector's pauses."""
+        self._ambient = True
+        gc.callbacks.append(self._on_gc)
+        return self
+
     def close(self) -> None:
-        """Detach the compile listener (idempotent)."""
+        """Detach the compile listener and the GC hook (idempotent)."""
         if self._monitor is not None:
             self._monitor.__exit__(None, None, None)
             self._monitor = None
+        if self._ambient:
+            self._ambient = False
+            if self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
 
     def clear(self) -> None:
         """Drop recorded spans/events (open spans stay open)."""
         self.spans.clear()
         self.events.clear()
+        self.gc_pauses.clear()
         self.recorded = 0
         self.compiles = 0
 
@@ -337,7 +467,7 @@ def default_tracer() -> Tracer:
     if not enabled():
         return NULL_TRACER
     if _DEFAULT_TRACER is None:
-        _DEFAULT_TRACER = Tracer(enabled=True)
+        _DEFAULT_TRACER = Tracer(enabled=True)._make_ambient()
     return _DEFAULT_TRACER
 
 

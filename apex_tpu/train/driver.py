@@ -38,10 +38,23 @@ Runtime telemetry (ISSUE 6): every window dispatch, checkpoint
 save/restore, and data prefetch stage runs inside a host-side
 :mod:`apex_tpu.obs` span (``train/dispatch`` carries K and the
 microbatch count; a cold call's compile is tagged on the span via the
-``CompileMonitor`` bridge), and dispatch wall times accumulate in the
-ambient metrics registry (``train.dispatch_ms`` histogram,
-``train.dispatches``/``train.steps`` counters).  All host-side — the
-compiled programs are unchanged — and ``APEX_TPU_OBS=0`` turns it off.
+``CompileMonitor`` bridge, with its seconds of tracing, lowering,
+compiling and loading from the persistent cache in ``span.jit``), and
+dispatch wall times accumulate in the ambient metrics registry
+(``train.dispatch_ms`` histogram, ``train.dispatches``/``train.steps``
+counters).  The driver numbers its windows: ``train/dispatch`` carries
+``window=<n>``, and :func:`read_metrics` puts the same number on the
+``train/fetch_metrics`` span of the result it is handed, so
+:func:`apex_tpu.obs.train_windows` can lay each window out as the gap
+before it, its enqueue, the host's time while it was in flight and the
+blocked fetch, each with its CPU time.  All host-side — the compiled
+programs are unchanged.  ``APEX_TPU_OBS=0`` turns off the spans, the
+``train.*`` registry entries above, the ``jit.*`` counters, the ambient
+tracer's ``gc.callbacks`` hook (``host.gc_ms``) and, with them, the
+flight recorder; the registry itself stays live (gauges the ops set at
+trace time, a ``registry=`` handed to :func:`read_metrics`), as do
+``last_dispatch_ms``, ``last_dispatch_compiles`` and
+``windows_dispatched``.
 
 Gradient-accumulation microbatching (ISSUE 2): pass a
 :class:`~apex_tpu.train.accum.MicrobatchedStep` (built by
@@ -53,9 +66,11 @@ state sharded through the window.  See :mod:`apex_tpu.train.accum`.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import time
+import weakref
 from typing import Any, Callable, Dict, Iterable, Mapping, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -100,6 +115,39 @@ class WindowResult(NamedTuple):
     per_step: Dict[str, jax.Array]
 
 
+#: how many windows' results are remembered for :func:`read_metrics`
+_REMEMBERED_WINDOWS = 64
+#: ``{id(first leaf of a window's result): (weak reference to the leaf,
+#: window number)}``, newest last.  The reference is weak so that no device
+#: buffer outlives its loop's use of it, and tells a leaf from a later
+#: array that was given its id.
+_WINDOW_OF: "collections.OrderedDict[int, tuple]" = collections.OrderedDict()
+
+
+def _remember_window(result: PyTree, window: int) -> None:
+    """Note which window made ``result``, for the fetch that may follow:
+    by the first leaf of its meters and of its per-step traces, so that
+    ``read_metrics(res)``, ``(res.metrics)`` and ``(res.per_step)`` are
+    all known (a tree's first leaf is what :func:`_window_of` asks for)."""
+    for tree in result:
+        leaves = jax.tree_util.tree_leaves(tree)
+        if leaves:
+            _WINDOW_OF.pop(id(leaves[0]), None)     # a dead leaf's id, reused
+            _WINDOW_OF[id(leaves[0])] = (weakref.ref(leaves[0]), window)
+    while len(_WINDOW_OF) > _REMEMBERED_WINDOWS:
+        _WINDOW_OF.popitem(last=False)
+
+
+def _window_of(tree: PyTree) -> Optional[int]:
+    """The window that made ``tree``, if it is remembered — forgotten
+    once asked for.  None for a tree no remembered window made."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    if not leaves:
+        return None
+    leaf, window = _WINDOW_OF.pop(id(leaves[0]), (None, None))
+    return window if leaf is not None and leaf() is leaves[0] else None
+
+
 def read_metrics(tree: PyTree, registry=None,
                  prefix: str = "train.") -> PyTree:
     """One blocking device->host fetch of a metrics pytree (floats out).
@@ -109,8 +157,15 @@ def read_metrics(tree: PyTree, registry=None,
     the host-side meter plumbing that used to be per-caller print/append
     code now accumulates where the trace artifact snapshots it.  The
     fetch is the ``train/fetch_metrics`` span: the host waiting for the
-    window it dispatched."""
-    with obs.default_tracer().span("train/fetch_metrics"):
+    window it dispatched.  The span carries ``window=<n>``, the number
+    ``train/dispatch`` gave the window that made ``tree`` — found by the
+    tree itself, not by order, so a loop may fetch some windows another
+    way, or none, or dispatch ahead; a tree that no remembered window
+    made carries none."""
+    tracer = obs.default_tracer()
+    window = _window_of(tree) if tracer.enabled else None
+    attrs = {} if window is None else {"window": window}
+    with tracer.span("train/fetch_metrics", **attrs):
         host = jax.device_get(tree)
     out = jax.tree_util.tree_map(
         lambda x: float(x) if getattr(x, "ndim", 1) == 0 else x, host
@@ -227,6 +282,9 @@ class FusedTrainDriver:
         self.last_dispatch_ms: Optional[float] = None
         self.last_dispatch_compiles: int = 0
         self.last_window_k: int = 0
+        #: windows dispatched so far: the newest ``train/dispatch``
+        #: span's ``window``
+        self.windows_dispatched: int = 0
 
     @property
     def microbatches(self) -> int:
@@ -395,14 +453,17 @@ class FusedTrainDriver:
             # launches so a crash postmortem shows what was in flight
             fr.record("train/dispatch", k=k,
                       microbatches=self._microbatches)
+        self.windows_dispatched += 1
         t0 = time.perf_counter_ns()
         with tracer.span("train/dispatch", k=k,
-                         microbatches=self._microbatches) as sp:
+                         microbatches=self._microbatches,
+                         window=self.windows_dispatched) as sp:
             out = self._program(k, has_batch)(carry, batches)
         self.last_dispatch_ms = (time.perf_counter_ns() - t0) * 1e-6
         self.last_dispatch_compiles = sp.compiles
         self.last_window_k = k
         if tracer.enabled:
+            _remember_window(out[1], self.windows_dispatched)
             reg = obs.default_registry()
             reg.counter("train.dispatches").inc()
             reg.counter("train.steps").inc(k)

@@ -562,8 +562,10 @@ class TestProfilerBridge:
             carry, res = driver.run_window(carry)
             read_metrics(res.metrics)
         got = {name: stats for name, _, _, stats in _apex_events(tmp_path)}
-        assert got == {"apex/train/dispatch": {"k": 3, "microbatches": 1},
-                       "apex/train/fetch_metrics": {}}
+        # both spans of one window carry its number into the profile
+        assert got == {"apex/train/dispatch": {"k": 3, "microbatches": 1,
+                                               "window": 2},
+                       "apex/train/fetch_metrics": {"window": 2}}
 
     def test_engine_step_is_in_the_profile(self, dec4, lm, tmp_path):
         _, _, pool = lm
