@@ -80,19 +80,11 @@ KNOBS: List[EnvKnob] = [
             "=1 stores paged KV as int8 with per-token fp32 scales."),
     EnvKnob("APEX_TPU_LN_FUSED_DGAMMA", "1",
             "0 forces the bit-exact XLA-reduction LayerNorm backward."),
-    EnvKnob("APEX_TPU_FUSED_BWD", "1",
-            "0 disables the combined dk+dv+dq flash backward."),
-    EnvKnob("APEX_TPU_FUSED_DQ_ACC", "0",
-            "1 enables the aliased-HBM dq accumulation (hardware "
-            "validation pending via tools/check_fused_dq_acc.py)."),
-    EnvKnob("APEX_TPU_FUSED_DQ_COPY_THROUGH", "0",
-            "1 makes causally-skipped tiles of the aliased-dq path "
-            "explicitly copy the running dq block through."),
     EnvKnob("APEX_TPU_PAGED_FUSED", "0",
             "1 enables the fused paged-attention serving kernel "
             "(page gather + int8 dequant + scores in one pass; "
             "hardware validation pending via "
-            "tools/check_fused_dq_acc.py --all)."),
+            "tools/check_paged_fused.py)."),
     EnvKnob("APEX_TPU_SPEC_TREE", "0",
             "=W>=2 widens speculative decode to W draft branches per "
             "slot, verified in one batched tree forward; 0/1 keeps "

@@ -52,8 +52,8 @@ def force_pallas(value: Optional[bool]):
 KERNEL_NAMES = (
     "apex_paged_attn",
     "apex_flash_fwd",
-    "apex_flash_bwd_fused_acc",
     "apex_flash_bwd_fused",
+    "apex_flash_bwd_sweep",
     "apex_flash_bwd_dkdv",
     "apex_flash_bwd_dq_dbias",
     "apex_flash_bwd_dq",

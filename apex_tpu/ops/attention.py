@@ -13,12 +13,15 @@ drops by the same factor.  This is the canonical Pallas attention:
   accumulation in VMEM scratch (m, l, acc), writes O and the per-row
   logsumexp (for backward);
 - backward: recompute-based with the stored lse, no O(S^2) residuals.
-  Default (r4) is the COMBINED pass — dk, dv AND the per-tile dq
-  contributions from ONE score/probability recompute (5 MXU dots per
-  visited tile pair instead of the two-pass flash-v2's 7; dq written
-  directly when nk == 1, else summed fp32 partials); past
-  ``_FUSED_BWD_MAX_NK`` k-blocks, and for the learned-bias path, the
-  classic two-pass (dkv then dq) backward runs instead;
+  ONE sweep over a head's visited score tiles computes s, p, dp and ds once
+  a tile and feeds dk, dv AND dq from them (5 MXU dots per visited tile
+  pair instead of the two-pass flash-v2's 7): with one key block the
+  key-major ``apex_flash_bwd_fused`` writes dq directly; with several the
+  query-major ``apex_flash_bwd_sweep`` finishes dq in its inner loop and
+  keeps dk and dv of the WHOLE key/value head in float32 VMEM accumulators
+  for the length of the sweep, written to HBM once, in the output dtype.
+  The classic two passes (dkv then dq) are left for the learned-bias path
+  and for a head whose accumulators pass ``_SWEEP_ACC_BUDGET_BYTES``;
 - supports causal masking — the masked half is not computed: grid tiles
   wholly above the diagonal are never visited, and where a head is ONE
   grid tile (GPT-2's S = 1024, where the grid has nothing to skip) each
@@ -79,48 +82,30 @@ def _env_flag(name: str, default: bool) -> bool:
     return v not in ("0", "false", "False", "")
 
 
-# combined dk+dv+dq backward (one s/p recompute) vs the two-pass flash-v2
-# backward — switch for A/B measurement (tools/, PERF.md r4); the env
-# override makes the A/B a subprocess flag flip, no module mutation.
-# (This and the three switches below are read when a call is TRACED: they
-# are part of the key of the shared trace, see _trace_key.)
-_USE_FUSED_BWD = _env_flag("APEX_TPU_FUSED_BWD", True)
-# the fused pass accumulates dq across k blocks; past this many k blocks
-# the accumulation traffic outweighs the saved recompute (long-context
-# ring shards hit nk=32) — use the two-pass path
-_FUSED_BWD_MAX_NK = 4
-# r5: accumulate dq IN HBM via an aliased input/output block (read the
-# running block, add this tile's contribution, write back) instead of the
-# r4 (nk, BH, Sq, D) fp32 partials buffer + host-side sum; kills the nk x
-# memory multiplier and the separate sum/mask pass.  False = r4 partials
-# (copy-through) path.
-#
-# Default OFF (r6): the path rests on two Mosaic assumptions that were
-# never validated on hardware — that a revisited aliased input block
-# re-reads HBM (not a stale VMEM copy) across non-consecutive grid steps,
-# and that causally-pruned tiles pass the block through untouched
-# (tools/check_fused_dq_acc.py, the hardware probe, never ran; round-5
-# advisor high-severity finding).  Silent wrong-dq on long-context causal
-# shapes is worse than the saved partials buffer.  Re-enable with
-# APEX_TPU_FUSED_DQ_ACC=1 once the probe passes on the target hardware.
-_FUSED_DQ_ACC = _env_flag("APEX_TPU_FUSED_DQ_ACC", False)
-# escape hatch for the acc path's static-pruning assumption: =1 makes
-# causally-skipped tiles explicitly copy the running dq block through
-# (see interp_copy_through in _bwd_dkv_body) instead of relying on
-# Mosaic pruning the skipped steps wholesale.  The documented mitigation
-# for "causal dq mismatches at nk > 1" on a toolchain that stops
-# pruning — previously unreachable without editing library source
-# (round-5 advisor medium finding).
-_FUSED_DQ_COPY_THROUGH = _env_flag("APEX_TPU_FUSED_DQ_COPY_THROUGH", False)
+# The one-sweep backward (apex_flash_bwd_sweep) keeps dk and dv of a whole
+# key/value head resident in float32 VMEM scratch: sk x (d + d_v) x 4 bytes,
+# each width rounded up to the 128 lanes VMEM holds it in (_sweep_acc_bytes:
+# 12.6 MB at Moonlight's 8192 x (192 + 128), 8.4 MB at Trinity's 128 + 128,
+# 16.8 MB at Qwen3-Next's 256 + 256).  A head past this budget (a 32k ring
+# shard at 128 + 128 is 33.6 MB) takes the two-pass route.  A v5e core has
+# 128 MiB of VMEM; the budget leaves what is not the accumulators to the
+# pipeline's blocks and the tile's temporaries, and to spare.
+_SWEEP_ACC_BUDGET_BYTES = 24 * 2 ** 20
+# What the sweep's call asks for beside its accumulators: Mosaic's default
+# scoped limit (16 MiB) holds either pass of the two-pass backward at the
+# largest auto blocks (512 x 1024: four float32 score-sized temporaries of
+# 2 MiB, ds's transpose, the double-buffered q/k/v/do/lse/delta blocks); the
+# sweep holds the union of the two passes' temporaries, so twice that.
+_SWEEP_TILE_VMEM_BYTES = 32 * 2 ** 20
 
 
 def paged_fused_default() -> bool:
     """Resolve the serving-side fused paged-attention default.
 
-    Default OFF (the ``_FUSED_DQ_ACC`` lesson, ROADMAP carried risk):
+    Default OFF (ROADMAP carried risk):
     :func:`paged_fused_attention` is a new Pallas serving kernel that has
     never compiled on real TPU hardware — tier-1 exercises it through the
-    interpreter only, and ``tools/check_fused_dq_acc.py --all`` is the
+    interpreter only, and ``tools/check_paged_fused.py`` is the
     live-TPU probe that must pass before flipping the default.  Opt in
     with ``APEX_TPU_PAGED_FUSED=1``.  Read per-call (not cached at
     import) so decoder construction under a test's monkeypatched env
@@ -670,9 +655,8 @@ def _causal_key_bounds(row0, rows, col0, width, n, xp=jnp):
     columns <= i) — see the _fwd_kernel comment.  The ONE definition of
     what a causal kernel visits: the grid-level ``run`` predicate
     (:func:`_causal_tile_visited`), the pieces the kernel bodies take
-    (:func:`_for_pieces`), the host-side dq-partials validity mask
-    and the census (:func:`flash_tile_census`) all come from here, so
-    they cannot drift.
+    (:func:`_for_pieces`) and the census (:func:`flash_tile_census`) all
+    come from here, so they cannot drift.
     ``xp`` is ``jnp`` inside a kernel, ``numpy`` on the host.
     """
     n_vis = xp.minimum(xp.maximum(row0 + rows - 1 - col0 + width, 0) // width, n)
@@ -935,65 +919,25 @@ def _fwd_kernel(
 # backward kernels (recompute with stored lse)
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_body(
+def _bwd_tile(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-    dqin_ref, dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
-    nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
-    interp_copy_through: bool = False, window: Optional[int] = None,
-    group: int = 1,
+    dk_acc, dv_acc, put_dq, bh, qi, ki, acc_rows=None,
+    *, scale: float, block_q: int, block_k: int, dropout_rate: float,
+    probs_bf16: bool, window: Optional[int],
 ):
-    """Shared dk/dv(+dq) backward body — grid (bh, k_blocks, q_blocks),
-    q inner; dk/dv accumulate in VMEM scratch across the q loop.  With
-    ``group`` query heads to a key/value head the grid is (key/value
-    heads, k_blocks, group * q_blocks): the inner loop walks the group's
-    query heads one after the other, so dk/dv come out summed over the
-    group from the same scratch.
-
-    ``dqp_ref``/``dqin_ref`` select the variant at trace time:
-
-    - dqp_ref None: the flash-v2 dkv pass (a separate dq pass recomputes
-      s/p);
-    - dqp_ref set, dqin_ref None: the COMBINED backward — the per-(ki, qi)
-      dq tile contribution ``ds @ K`` is also emitted.  nk == 1 writes dq
-      directly; nk > 1 writes a per-ki partial buffer summed by the caller
-      (the r4 scheme).  One s/p recompute instead of two, 5 MXU dots per
-      visited tile pair instead of 7, and q/k/v/do/lse/delta read once
-      instead of twice (measured +4.5% end-to-end on the BERT step in r4.
-      Ref capability: apex/contrib/csrc/multihead_attn/).
-    - dqin_ref set (r5): HBM-ACCUMULATED dq — dqp aliases dqin's buffer
-      (pallas input_output_aliases), each visited tile reads the running
-      (block_q, d) fp32 block, adds its contribution and writes it back;
-      skipped-but-unpruned tiles copy through.  No nk x partials buffer,
-      no host-side sum/mask pass.
-    """
-    if group == 1:
-        bh = _drop_bh(seed_ref, h_map)
-        ki = pl.program_id(1)
-        qi = step = pl.program_id(2)
-    else:
-        # the inner axis walks the group's query heads, each through its
-        # query blocks; the dropout hash is keyed on the QUERY head
-        ki, step = pl.program_id(1), pl.program_id(2)
-        qi = step % nq
-        bh = _drop_bh(seed_ref, h_map,
-                      pl.program_id(0) * group + step // nq)
-
-    @pl.when(step == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    run = True
-    if causal:
-        run = _causal_tile_visited(qi, ki, block_q, block_k, window=window)
+    """The one-sweep backwards' work on a visited tile, as the function
+    :func:`_for_pieces` calls: s, p, dp and ds of query block ``qi``
+    against key block ``ki`` ONCE, and from them the tile's dv and dk added
+    to the float32 accumulators ``dv_acc`` / ``dk_acc`` — at ``acc_rows``,
+    or where there is none at the tile's own key columns — and, where
+    ``put_dq`` is given, its dq contribution ``ds @ K`` handed to
+    ``put_dq(rows, contribution)``."""
 
     def tile(r0, rows, width, mask_from):
         """The tile's query rows ``[r0, r0 + rows)`` against its first
-        ``width`` keys, masked from column ``mask_from`` on: their dk/dv
-        into the scratch and, in the combined backward, the rows' dq —
-        whole, since the tile's other keys lie above the rows' diagonal."""
+        ``width`` keys, masked from column ``mask_from`` on."""
         rows, cols = slice(r0, r0 + rows), slice(0, width)
+        acc = cols if acc_rows is None else acc_rows
         # native-dtype operands for the input-sourced dots (see _fwd_kernel
         # note: bf16 MXU dot + fp32 accumulate == fp32 dot of bf16 values)
         q = q_ref[0, rows]
@@ -1031,7 +975,7 @@ def _bwd_dkv_body(
             pd = p
         if probs_bf16:
             pd = pd.astype(q.dtype)
-        dv_scr[cols] += jax.lax.dot_general(
+        dv_acc[acc] += jax.lax.dot_general(
             pd, do32, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -1039,37 +983,83 @@ def _bwd_dkv_body(
         q_dot = q if probs_bf16 else q.astype(jnp.float32)
         if probs_bf16:
             ds = ds.astype(q.dtype)
-        dk_scr[cols] += jax.lax.dot_general(
+        dk_acc[acc] += jax.lax.dot_general(
             ds, q_dot, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        if dqp_ref is not None:
+        if put_dq is not None:
             k_dot = k if probs_bf16 else k.astype(jnp.float32)
-            contrib = jax.lax.dot_general(
+            put_dq(rows, jax.lax.dot_general(
                 ds, k_dot, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
-            if dqin_ref is None:
-                dqp_ref[0, 0, rows] = contrib.astype(dqp_ref.dtype)
-            else:
-                dqp_ref[0, rows] = dqin_ref[0, rows] + contrib
+            ))
+
+    return tile
+
+
+def _bwd_dkv_body(
+    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
+    dk_ref, dv_ref, dq_ref, dk_scr, dv_scr,
+    *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
+    nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
+    window: Optional[int] = None, group: int = 1,
+):
+    """The key-major backward body — grid (bh, k_blocks, q_blocks), q
+    inner; dk/dv accumulate in VMEM scratch across the q loop.  With
+    ``group`` query heads to a key/value head the grid is (key/value
+    heads, k_blocks, group * q_blocks): the inner loop walks the group's
+    query heads one after the other, so dk/dv come out summed over the
+    group from the same scratch.
+
+    ``dq_ref`` selects the variant at trace time:
+
+    - None: the flash-v2 dkv pass of the two-pass route (a separate dq
+      pass recomputes s/p);
+    - set: the one-sweep backward of a call with ONE key block
+      (``apex_flash_bwd_fused``) — each query block's dq is whole after
+      its single key step, so ``ds @ K`` is written straight out, in the
+      output dtype.  One s/p recompute instead of two, 5 MXU dots per
+      visited tile pair instead of 7, and q/k/v/do/lse/delta read once
+      instead of twice (measured +4.5% end-to-end on the BERT step in r4.
+      Ref capability: apex/contrib/csrc/multihead_attn/).  With several
+      key blocks the query-major :func:`_bwd_sweep_kernel` does the same.
+    """
+    if group == 1:
+        bh = _drop_bh(seed_ref, h_map)
+        ki = pl.program_id(1)
+        qi = step = pl.program_id(2)
+    else:
+        # the inner axis walks the group's query heads, each through its
+        # query blocks; the dropout hash is keyed on the QUERY head
+        ki, step = pl.program_id(1), pl.program_id(2)
+        qi = step % nq
+        bh = _drop_bh(seed_ref, h_map,
+                      pl.program_id(0) * group + step // nq)
+
+    @pl.when(step == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    run = True
+    if causal:
+        run = _causal_tile_visited(qi, ki, block_q, block_k, window=window)
+
+    put_dq = None
+    if dq_ref is not None:
+        # whole, since the tile's other keys lie above the rows' diagonal
+        def put_dq(rows, contrib):
+            dq_ref[0, 0, rows] = contrib.astype(dq_ref.dtype)
+
+    tile = _bwd_tile(
+        seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
+        dk_scr, dv_scr, put_dq, bh, qi, ki,
+        scale=scale, block_q=block_q, block_k=block_k,
+        dropout_rate=dropout_rate, probs_bf16=probs_bf16, window=window)
 
     @pl.when(run)
     def _body():
         _for_pieces(block_q, block_k, nq, nk, causal, tile, window)
-
-    if dqin_ref is not None and causal and interp_copy_through:
-        # escape hatch (default OFF): explicitly carry the running dq
-        # block through causal-skipped tiles.  The shipped configuration
-        # relies on Mosaic statically pruning skipped steps wholesale
-        # (DMAs included), so the aliased HBM block keeps its accumulated
-        # value untouched — an active copy-through would defeat exactly
-        # that pruning; tools/check_fused_dq_acc.py validates the pruning
-        # assumption on hardware.  Flip this on if a future toolchain
-        # stops pruning (symptom: causal dq mismatches at nk > 1).
-        @pl.when(jnp.logical_not(run))
-        def _copy_through():
-            dqp_ref[0] = dqin_ref[0]
 
     @pl.when(step == group * nq - 1)
     def _finalize():
@@ -1082,42 +1072,75 @@ def _bwd_dkv_kernel(
     dk_ref, dv_ref, dk_scr, dv_scr, **kw,
 ):
     _bwd_dkv_body(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                  delta_ref, None, dk_ref, dv_ref, None, dk_scr, dv_scr,
-                  **kw)
+                  delta_ref, dk_ref, dv_ref, None, dk_scr, dv_scr, **kw)
 
 
-def _bwd_fused_kernel(
+def _bwd_sweep_kernel(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr, **kw,
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_acc, dv_acc,
+    *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
+    nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
+    window: Optional[int] = None, group: int = 1,
 ):
-    _bwd_dkv_body(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                  delta_ref, None, dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr,
-                  **kw)
+    """The one-sweep backward of a call with several key blocks
+    (``apex_flash_bwd_sweep``) — grid (query heads, q_blocks, k_blocks), k
+    inner, as the forward's.  Each visited tile's s, p, dp and ds are
+    computed once and feed all three gradients:
 
+    - dq finishes inside the inner loop, in the ``(block_q, d)`` scratch
+      the two-pass dq kernel has;
+    - dk and dv of the WHOLE key/value head stay in ``dk_acc`` (sk, d) and
+      ``dv_acc`` (sk, d_v), float32 VMEM scratch, for the length of the
+      head's sweep — through the ``group`` query heads that share it, which
+      the grid walks one after the other — and each tile adds to the rows
+      of its key block.  The head's first query row zeroes key block ``ki``
+      as it passes it; its last hands block ``ki``, then final, to the
+      output in the output dtype (the output's index map follows ``ki``
+      there and rests on block 0 before: :func:`_flash_bwd`).
 
-def _bwd_fused_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                      delta_ref, dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr,
-                      **kw):
-    _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, None, do_ref, lse_ref,
-                      delta_ref, dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr,
-                      **kw)
+    Nothing of the gradients crosses HBM but the results themselves."""
+    head = pl.program_id(0)
+    bh = _drop_bh(seed_ref, h_map)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    keys = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
 
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
 
-def _bwd_fused_acc_kernel(
-    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-    dqin_ref, dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, **kw,
-):
-    _bwd_dkv_body(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                  delta_ref, dqin_ref, dk_ref, dv_ref, dq_ref, dk_scr,
-                  dv_scr, **kw)
+    @pl.when((head % group == 0) & (qi == 0))
+    def _init_dkv():
+        dk_acc[keys] = jnp.zeros((block_k, dk_acc.shape[1]), dk_acc.dtype)
+        dv_acc[keys] = jnp.zeros((block_k, dv_acc.shape[1]), dv_acc.dtype)
 
+    run = True
+    if causal:
+        run = _causal_tile_visited(qi, ki, block_q, block_k, window=window)
 
-def _bwd_fused_acc_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                          delta_ref, dqin_ref, dk_ref, dv_ref, dq_ref,
-                          dk_scr, dv_scr, **kw):
-    _bwd_fused_acc_kernel(seed_ref, q_ref, k_ref, v_ref, None, do_ref,
-                          lse_ref, delta_ref, dqin_ref, dk_ref, dv_ref,
-                          dq_ref, dk_scr, dv_scr, **kw)
+    def put_dq(rows, contrib):
+        dq_scr[rows] += contrib
+
+    # several key blocks: a tile is one piece (_causal_subtile), all of its
+    # block_k columns, so the accumulators' rows are the key block's
+    tile = _bwd_tile(
+        seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
+        dk_acc, dv_acc, put_dq, bh, qi, ki, acc_rows=keys,
+        scale=scale, block_q=block_q, block_k=block_k,
+        dropout_rate=dropout_rate, probs_bf16=probs_bf16, window=window)
+
+    @pl.when(run)
+    def _body():
+        _for_pieces(block_q, block_k, nq, nk, causal, tile, window)
+
+    @pl.when(ki == nk - 1)
+    def _finalize_dq():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when((head % group == group - 1) & (qi == nq - 1))
+    def _finalize_dkv():
+        dk_ref[0] = dk_acc[keys].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[keys].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(
@@ -1214,12 +1237,16 @@ def _grouped_route(window, group):
     return window is not None or group > 1
 
 
-def _kv_spec_by_query(block_q, block_k, d, nk, causal, window, group):
+def _kv_spec_by_query(block_q, block_k, d, nk, causal, window, group,
+                      clamp=None):
     """The key/value BlockSpec of a grid ``(query head, q block, k
-    block)``: query head ``b`` reads key/value head ``b // group``; a step
-    the causal band skips names the nearest visited block, so nothing
-    moves for it."""
-    if not _grouped_route(window, group):
+    block)``: query head ``b`` reads key/value head ``b // group``; where
+    the map is ``clamp``ed (by default on the grouped route alone, whose
+    calls are the newer programs) a step the causal band skips names the
+    nearest visited block, so nothing moves for it."""
+    if clamp is None:
+        clamp = _grouped_route(window, group)
+    if not clamp:
         return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
     if not causal:
         return pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0))
@@ -1270,7 +1297,7 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
         in_specs.append(bias_spec)
         inputs.append(bias)
     kernel = functools.partial(
-        _fwd_kernel if bias is not None else _fwd_kernel_nobias,
+        _fwd_kernel if bias is not None else _no_bias(_fwd_kernel),
         scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
         nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
         **_window_kw(window),
@@ -1308,33 +1335,48 @@ def _window_kw(window, group=1):
     return kw
 
 
-def _fwd_kernel_nobias(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       m_scr, l_scr, acc_scr, **kw):
-    _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, **kw)
+def _no_bias(kernel):
+    """``kernel`` for a call that has no bias: no ``bias_ref`` among its
+    arguments, None in its place."""
+    def without_bias(seed_ref, q_ref, k_ref, v_ref, *refs, **kw):
+        kernel(seed_ref, q_ref, k_ref, v_ref, None, *refs, **kw)
+    return without_bias
 
 
-def _bwd_dkv_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, **kw):
-    _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, None, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, **kw)
-
-
-def _bwd_dq_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_scr, **kw):
-    _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, None, do_ref, lse_ref,
-                   delta_ref, dq_ref, None, dq_scr, **kw)
-
-
-def _bwd_dq_bias(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
+def _bwd_dq_only(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                  delta_ref, dq_ref, dq_scr, **kw):
     _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, None, dq_scr, **kw)
 
 
+def _sweep_acc_bytes(sk, d, d_v):
+    """Bytes of the one-sweep backward's resident accumulators for one
+    key/value head: (sk, d) and (sk, d_v) float32, as VMEM holds them —
+    the last dimension in whole 128-lane tiles."""
+    lanes = lambda width: -(-width // 128) * 128
+    return sk * (lanes(d) + lanes(d_v)) * 4
+
+
+def _bwd_sweeps(nk, bias_grad, acc_bytes):
+    """How many times the backward walks a head's visited score tiles: 1
+    where one kernel feeds dq, dk and dv from one computation of s, p, dp
+    and ds — ``apex_flash_bwd_fused`` with one key block,
+    ``apex_flash_bwd_sweep`` with several, while dk's and dv's resident
+    accumulators (:func:`_sweep_acc_bytes`) fit the VMEM budget — else the
+    2 of ``apex_flash_bwd_dkdv`` + ``apex_flash_bwd_dq``: a learned bias's
+    gradient (each dbias tile is written once in the dq pass's grid) and a
+    head too long for the budget.  The route is read from these three and
+    nothing else."""
+    if bias_grad:
+        return 2
+    return 1 if nk == 1 or acc_bytes <= _SWEEP_ACC_BUDGET_BYTES else 2
+
+
 def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                block_k, dropout_rate, bias_grad=False, h_map=None,
                probs_bf16=False, window=None):
+    from apex_tpu import obs
+
     bh, sq, d = q.shape
     sk = k.shape[1]
     d_v = v.shape[2]          # v, o, do, dv at a head size of their own
@@ -1348,15 +1390,83 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
     lse_b = jnp.broadcast_to(lse[:, :, None], (bh, sq, 128))
     delta_b = jnp.broadcast_to(delta[:, :, None], (bh, sq, 128))
     with_bias = bias is not None
-
+    acc_bytes = _sweep_acc_bytes(sk, d, d_v)
+    sweeps = _bwd_sweeps(nk, with_bias and bias_grad, acc_bytes)
+    # counted when the backward is TRACED, as the tiles are (_count_tiles);
+    # layers that share one trace of the kernels (_flash_jit) count once
+    reg = obs.default_registry()
+    reg.counter("ops.flash.bwd_calls").inc(1)
+    reg.counter("ops.flash.bwd_sweeps").inc(sweeps)
+    kernel_kw = dict(
+        scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
+        nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    for_bias = (lambda kernel: kernel) if with_bias else _no_bias
+
+    def by_query(clamp=None):
+        """Inputs and their specs for a query-major grid ``(query head, q
+        block, k block)``, and dq's spec there."""
+        by_q = lambda width: pl.BlockSpec(
+            (1, block_q, width), lambda b, i, j: (b, i, 0))
+        k_spec, v_spec = (
+            _kv_spec_by_query(block_q, block_k, width, nk, causal, window,
+                              group, clamp)
+            for width in (d, d_v))
+        in_specs, inputs = [seed_spec, by_q(d), k_spec, v_spec], [seed, q, k, v]
+        if with_bias:
+            in_specs.append(pl.BlockSpec(
+                (1, block_q, block_k), lambda b, i, j: (b // h, i, j)))
+            inputs.append(bias)
+        in_specs += [by_q(d_v), by_q(128), by_q(128)]
+        inputs += [do, lse_b, delta_b]
+        return in_specs, inputs, by_q(d)
+
+    if sweeps == 1 and nk > 1:
+        # query-major, k inner; dk/dv's output blocks rest on key block 0
+        # while the head's accumulators fill, and follow the inner index
+        # through the key/value head's LAST query row, where the kernel
+        # hands each block out — so every block is written back once
+        def dkv_index(b, i, j):
+            last_row = (b % group == group - 1) & (i == nq - 1)
+            return b // group, jnp.where(last_row, j, 0), 0
+
+        in_specs, inputs, dq_spec = by_query(clamp=True)
+        dq, dk, dv = _pallas_call(
+            functools.partial(for_bias(_bwd_sweep_kernel), **kernel_kw,
+                              **_window_kw(window, group)),
+            name="apex_flash_bwd_sweep",
+            grid=(bh, nq, nk),
+            in_specs=in_specs,
+            out_specs=[
+                dq_spec,
+                pl.BlockSpec((1, block_k, d), dkv_index),
+                pl.BlockSpec((1, block_k, d_v), dkv_index),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+                jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
+                jax.ShapeDtypeStruct((bhk, sk, d_v), q.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((sk, d), jnp.float32),
+                pltpu.VMEM((sk, d_v), jnp.float32),
+            ],
+            # the resident accumulators, and beside them what the tiles and
+            # the pipeline's blocks take (_SWEEP_TILE_VMEM_BYTES)
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=acc_bytes + _SWEEP_TILE_VMEM_BYTES),
+        )(*inputs)
+        return dq, dk, dv, None
+
+    # key-major, q inner: the one-key-block sweep and the two-pass dkv
     if not _grouped_route(window, group):
-        q_index = dqp_index = lambda b, i, j: (b, j, 0)   # dkv: q inner
+        q_index = dq_index = lambda b, i, j: (b, j, 0)
     else:
         # grid (key/value head, k block, group x q block): step j is query
         # block j % nq of the group's query head j // nq; a step the
         # causal band skips names the nearest visited query block
-        def dqp_index(b, i, j):
+        def dq_index(b, i, j):
             return b * group + j // nq, j % nq, 0
 
         def q_index(b, i, j):
@@ -1378,115 +1488,9 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
         inputs.append(bias)
     in_specs += [do_spec, stat_spec, stat_spec]
     inputs += [do, lse_b, delta_b]
-
-    if (_USE_FUSED_BWD and nk <= _FUSED_BWD_MAX_NK
-            and not (with_bias and bias_grad)):
-        dkv_out_specs = [k_spec, v_spec]
-        dkv_out_shape = [
-            jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
-            jax.ShapeDtypeStruct((bhk, sk, d_v), q.dtype),
-        ]
-        scratch = [
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d_v), jnp.float32),
-        ]
-        if (nk > 1 and _FUSED_DQ_ACC and nq > 1
-                and not _grouped_route(window, group)
-                and jax.default_backend() == "tpu"):
-            # combined dk+dv+dq with dq ACCUMULATED IN HBM (r5): the dq
-            # block is an aliased input/output pair — each visited (ki, qi)
-            # tile reads the running (block_q, d) fp32 block, adds ds @ K
-            # and writes it back; causal-skipped steps are statically
-            # pruned (DMAs included) so the block passes through untouched.
-            # Replaces the r4 (nk, BH, Sq, D) partials buffer + host-side
-            # masked sum.  TPU-ONLY: pallas interpret mode gives the
-            # aliased input functional (copy) semantics, so revisits would
-            # read the original zeros — CPU runs keep the partials path
-            # (hardware parity: tests/test_attention_tpu.py).  nq == 1
-            # would revisit the dq block on CONSECUTIVE grid steps, where
-            # pallas caches the input block in VMEM and the read would not
-            # see the previous write — that (cross-attention-shaped) case
-            # keeps the partials path too.
-            dq_init = jnp.zeros((bh, sq, d), jnp.float32)
-            dk, dv, dq = _pallas_call(
-                functools.partial(
-                    _bwd_fused_acc_kernel if with_bias
-                    else _bwd_fused_acc_nobias,
-                    scale=scale, causal=causal, block_q=block_q,
-                    block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate,
-                    h_map=h_map, probs_bf16=probs_bf16,
-                    interp_copy_through=_FUSED_DQ_COPY_THROUGH,
-                ),
-                name="apex_flash_bwd_fused_acc",
-                grid=(bh, nk, nq),
-                in_specs=in_specs + [
-                    pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-                ],
-                out_specs=dkv_out_specs + [
-                    pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-                ],
-                out_shape=dkv_out_shape + [
-                    jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-                ],
-                scratch_shapes=scratch,
-                input_output_aliases={len(inputs): 2},
-            )(*inputs, dq_init)
-            return dq.astype(q.dtype), dk, dv, None
-        # combined dk+dv+dq pass (one s/p recompute); nk == 1 writes dq
-        # directly, else per-ki fp32 partials are summed here, masked for
-        # causal-pruned tiles whose blocks were never written
-        dk, dv, dqp = _pallas_call(
-            functools.partial(
-                _bwd_fused_kernel if with_bias else _bwd_fused_nobias,
-                scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate,
-                h_map=h_map, probs_bf16=probs_bf16,
-                **_window_kw(window, group),
-            ),
-            name="apex_flash_bwd_fused",
-            grid=(bhk, nk, group * nq),
-            in_specs=in_specs,
-            out_specs=dkv_out_specs + [
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, i, j: (i, *dqp_index(b, i, j))),
-            ],
-            out_shape=dkv_out_shape + [
-                # nk == 1 (BERT S=512, GPT S=1024 with block_k=1024): each
-                # dq block is complete after its single k step — write it
-                # in the output dtype and skip the fp32 partial buffer
-                jax.ShapeDtypeStruct(
-                    (nk, bh, sq, d), q.dtype if nk == 1 else jnp.float32
-                ),
-            ],
-            scratch_shapes=scratch,
-        )(*inputs)
-        if nk == 1:
-            return dqp[0], dk, dv, None
-        if causal:
-            import numpy as np
-
-            valid = _causal_tile_visited(
-                np.arange(nq)[None, :], np.arange(nk)[:, None],
-                block_q, block_k, np, window,
-            )
-            mask = jnp.asarray(
-                np.repeat(valid, block_q, axis=1)[:, None, :, None]
-            )
-            dqp = jnp.where(mask, dqp, 0.0)
-        dq = jnp.sum(dqp, axis=0).astype(q.dtype)
-        return dq, dk, dv, None
-
-    dk, dv = _pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel if with_bias else _bwd_dkv_nobias,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
-            nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
-            **_window_kw(window, group),
-        ),
-        name="apex_flash_bwd_dkdv",
+    dkv_call = dict(
         grid=(bhk, nk, group * nq),
         in_specs=in_specs,
-        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bhk, sk, d), q.dtype),
             jax.ShapeDtypeStruct((bhk, sk, d_v), q.dtype),
@@ -1495,35 +1499,41 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
+    )
+    kernel_kw.update(_window_kw(window, group))
+
+    if sweeps == 1:
+        # one key block (BERT S=512, GPT S=1024 with block_k=1024): each dq
+        # block is complete after its single k step — the kernel writes it
+        # in the output dtype
+        dkv_call["out_shape"].append(
+            jax.ShapeDtypeStruct((1, bh, sq, d), q.dtype))
+        dk, dv, dq = _pallas_call(
+            functools.partial(for_bias(_bwd_dkv_body), **kernel_kw),
+            name="apex_flash_bwd_fused",
+            out_specs=[k_spec, v_spec, pl.BlockSpec(
+                (1, 1, block_q, d), lambda b, i, j: (i, *dq_index(b, i, j)))],
+            **dkv_call,
+        )(*inputs)
+        return dq[0], dk, dv, None
+
+    dk, dv = _pallas_call(
+        functools.partial(for_bias(_bwd_dkv_kernel), **kernel_kw),
+        name="apex_flash_bwd_dkdv",
+        out_specs=[k_spec, v_spec],
+        **dkv_call,
     )(*inputs)
 
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    do_spec2 = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0))
-    stat_spec2 = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    k_spec2, v_spec2 = (
-        _kv_spec_by_query(block_q, block_k, width, nk, causal, window, group)
-        for width in (d, d_v))
-    bias_spec2 = pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, i, j))
-    in_specs = [seed_spec, q_spec2, k_spec2, v_spec2]
-    inputs = [seed, q, k, v]
-    if with_bias:
-        in_specs.append(bias_spec2)
-        inputs.append(bias)
-    in_specs += [do_spec2, stat_spec2, stat_spec2]
-    inputs += [do, lse_b, delta_b]
+    in_specs, inputs, dq_spec = by_query()
+    kernel_kw.pop("group", None)
     if with_bias and bias_grad:
         dq, dbias = _pallas_call(
-            functools.partial(
-                _bwd_dq_kernel,
-                scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-                nq=nq, nk=nk, dropout_rate=dropout_rate, h_map=h_map,
-                probs_bf16=probs_bf16, **_window_kw(window),
-            ),
+            functools.partial(_bwd_dq_kernel, **kernel_kw),
             name="apex_flash_bwd_dq_dbias",
             grid=(bh, nq, nk),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                dq_spec,
                 pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b, i, j)),
             ],
             out_shape=[
@@ -1534,16 +1544,11 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
         )(*inputs)
         return dq, dk, dv, dbias
     dq = _pallas_call(
-        functools.partial(
-            _bwd_dq_bias if with_bias else _bwd_dq_nobias,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
-            nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16,
-            **_window_kw(window),
-        ),
+        functools.partial(for_bias(_bwd_dq_only), **kernel_kw),
         name="apex_flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=dq_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
     )(*inputs)
@@ -1613,12 +1618,10 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def _trace_key():
     """What a trace of ``_flash`` reads besides its arguments: the
-    backend (interpreter or Mosaic, the ``_FUSED_DQ_ACC`` gate), the
-    module's backward switches and the sub-tile width.  Tools and tests
-    flip these between calls of one signature; any new trace-time read
-    of module state belongs here."""
-    return (jax.default_backend(), _USE_FUSED_BWD, _FUSED_BWD_MAX_NK,
-            _FUSED_DQ_ACC, _FUSED_DQ_COPY_THROUGH, _CAUSAL_SUB)
+    backend (interpreter or Mosaic), the one-sweep backward's VMEM budget
+    and the sub-tile width.  Tests flip these between calls of one
+    signature; any new trace-time read of module state belongs here."""
+    return jax.default_backend(), _SWEEP_ACC_BUDGET_BYTES, _CAUSAL_SUB
 
 
 # Called through jit, so that the layers of a model — every one the same
@@ -1717,6 +1720,15 @@ def flash_attention(
     adds its sub-tile census x batch*heads to the
     ``ops.flash.tiles_{total,visited,masked}`` counters when it is traced
     (:func:`flash_tile_census`).
+
+    The backward walks a head's visited tiles ONCE and feeds dq, dk and dv
+    from one computation of the scores — whatever the number of key blocks,
+    while dk's and dv's float32 accumulators for one key/value head fit the
+    VMEM budget (``sk * (d + d_v) * 4`` bytes against 24 MiB: every head up
+    to 16k positions at 128 + 128) — and twice, dkv then dq, past it and
+    for ``bias_grad``.  The route is read from the shapes
+    (:func:`_bwd_sweeps`); a traced backward adds 1 to ``ops.flash.bwd_calls``
+    and its 1 or 2 to ``ops.flash.bwd_sweeps``.
 
     Differentiable in q/k/v, and in ``bias`` when ``bias_grad=True``: the
     dq backward pass then also emits the per-tile dL/dbias, summed over

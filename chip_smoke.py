@@ -8,7 +8,9 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
 - ``kernels`` — each main-path Pallas kernel (flash attention, fused
   LayerNorm with the dgamma/dbeta epilogue, fused softmax-xentropy),
   COMPILED, forward and gradients, against its own jnp reference at the
-  tolerance tiers of the kernels' tests;
+  tolerance tiers of the kernels' tests; and flash attention's backward
+  at the three 8k cells' calls, the shipped route against the two-pass
+  one, with the time of a call of each (``flash_backward``);
 - ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, with
   dropout on: three windows on a fixed seeded batch;
 - ``serve`` — the params that phase produced, through ``GPTDecoder`` +
@@ -51,7 +53,7 @@ import numpy as np
 
 from apex_tpu.chip import compile_cache_dir, require_tpu
 from apex_tpu.ops import mosaic_call_count
-from apex_tpu.ops._common import unnamed_mosaic_calls
+from apex_tpu.ops._common import mosaic_call_names, unnamed_mosaic_calls
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -419,7 +421,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     # the same flash kernels on the second decoder block's call
     # (models/afmoe.py): a sliding window, four query heads to a key/value
     # head, head size 128, eight query tiles a head — the banded grid and
-    # the two-pass backward, dk/dv summed over the group in the kernel
+    # the one-sweep backward, dk/dv summed over the group in its accumulators
     sw, hw = 8 * s, 4
     qw, kw, vw, w_win = seeded(lambda *ks: [
         (normal(ki, (1, heads, sw, 128), f32) * scale).astype(dt)
@@ -436,7 +438,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
 
     run("flash_window_grouped", window_loss(flash_attention),
         window_loss(attention_ref), (qw, kw, vw, w_win),
-        tuple(t.astype(f32) for t in (qw, kw, vw)) + (w_win,), 3,
+        tuple(t.astype(f32) for t in (qw, kw, vw)) + (w_win,), 2,
         (3e-2, (("dq", 3e-2), ("dk", 3e-2), ("dv", 3e-2))))
 
     # the expert layer's grouped product (ops/grouped_mm.py) against
@@ -556,7 +558,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
         return jnp.sum(out * w), out
 
     run("flash_latent", latent_loss, latent_ref_loss, (ql, kl, vl, w_lat),
-        tuple(t.astype(f32) for t in (ql, kl, vl)) + (w_lat,), 3,
+        tuple(t.astype(f32) for t in (ql, kl, vl)) + (w_lat,), 2,
         (3e-2, (("dq", 3e-2), ("dk", 3e-2), ("dv", 3e-2))))
 
     # the short convolution in front of the rule as qwen3-next.train-8k calls
@@ -585,6 +587,78 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
 
     run("conv1d", conv_loss(None), conv_loss(False), (xc, wc, w_conv),
         (xc.astype(f32), wc, w_conv), 2, (1e-2, (("dx", 1e-2), ("dw", 1e-3))))
+
+    # flash attention's backward at the three 8k cells' calls (a row of 8
+    # contexts: Moonlight's 16 heads at 192 / 128, Trinity-Mini's 32 query
+    # heads to 4 at 128 with and without its window, Qwen3-Next's 16 to 2 at
+    # 256): the shipped route — ONE sweep, dk and dv resident in VMEM —
+    # against the two-pass route a head past the VMEM budget keeps (dkdv +
+    # dq, the kernels these shapes ran before the one sweep) and against the
+    # float32 reference; us a call forward and with gradients for both
+    import apex_tpu.ops.attention as attention_mod
+
+    routes = facts["flash_backward"] = {}
+    blocks = dict(block_q=sw // 16, block_k=sw // 8)   # the auto blocks at 8k
+
+    def us_a_call(fn, args, n=10):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / n * 1e6, 1)
+
+    def backward_routes(name, hq, hkv, d, d_v, window, key):
+        q_, k_, v_, w_ = jax.jit(lambda key: [
+            (normal(ki, (1, heads, sw, width), f32) * 0.3).astype(bf16)
+            for ki, heads, width in zip(jax.random.split(key, 4),
+                                        (hq, hkv, hkv, hq), (d, d, d_v, d_v))
+        ])(key)
+        kw_ = dict(causal=True, window=window)
+
+        def attend(q, k, v):
+            return flash_attention(q, k, v, **kw_, **blocks)
+
+        def loss(q, k, v, w):
+            return jnp.sum(attend(q, k, v).astype(f32) * w.astype(f32))
+
+        def ref_loss(q, k, v, w):
+            head = jax.checkpoint(lambda t: attention_ref(
+                *(x[None, None] for x in t), **kw_)[0, 0])
+            k, v = (jnp.repeat(t[0], hq // hkv, axis=0) for t in (k, v))
+            return jnp.sum(jax.lax.map(head, (q[0], k, v))[None] * w)
+
+        rec = routes[name] = {
+            "shape": [hq, hkv, sw, d, d_v, window],
+            "fwd_us": us_a_call(jax.jit(attend), (q_, k_, v_))}
+        grads = {}
+        budget = attention_mod._SWEEP_ACC_BUDGET_BYTES
+        try:
+            for route, room in (("shipped", budget), ("two_pass", 0)):
+                # read when the call is traced, and part of the trace's key
+                attention_mod._SWEEP_ACC_BUDGET_BYTES = room
+                compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+                    q_, k_, v_, w_).compile()
+                rec[route + "_kernels"] = mosaic_call_names(compiled.as_text())
+                rec[route + "_grad_us"] = us_a_call(compiled, (q_, k_, v_, w_))
+                grads[route] = compiled(q_, k_, v_, w_)
+        finally:
+            attention_mod._SWEEP_ACC_BUDGET_BYTES = budget
+        with jax.default_matmul_precision("highest"):
+            grads["ref"] = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(
+                *(t.astype(f32) for t in (q_, k_, v_, w_)))
+        for other, tol in (("two_pass", 1e-2), ("ref", 3e-2)):
+            for gname, g, o in zip(("dq", "dk", "dv"), grads["shipped"],
+                                   grads[other]):
+                _compare(f"flash_backward.{name}.{gname}_vs_{other}", g, o,
+                         tol, parity)
+
+    for i, case in enumerate((
+            ("moonlight", hl, hl, 192, 128, None),
+            ("trinity_window", 2 * hl, max(hl // 4, 1), 128, 128, sw // 4),
+            ("trinity_full", 2 * hl, max(hl // 4, 1), 128, 128, None),
+            ("qwen3_next", hl, max(hl // 8, 1), 256, 256, None))):
+        backward_routes(*case, jax.random.fold_in(root_key, 100 + i))
 
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 

@@ -65,6 +65,15 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
         "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d"}
+    # the backward's two routes at the three 8k cells' calls, timed and held
+    # to each other and to the reference
+    routes = kernels["flash_backward"]
+    assert set(routes) == {"moonlight", "trinity_window", "trinity_full",
+                           "qwen3_next"}
+    for rec in routes.values():
+        assert {"fwd_us", "shipped_grad_us", "two_pass_grad_us",
+                "shipped_kernels", "two_pass_kernels"} <= set(rec)
+    assert "flash_backward.trinity_window.dk_vs_two_pass" in kernels["parity"]
     assert train["loss_per_window"][-1] < train["loss_per_window"][0]
     assert train["compiles_after_first_window"] == 0
     assert serve["compiles_after_warmup"] == 0

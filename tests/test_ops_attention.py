@@ -308,7 +308,7 @@ from apex_tpu.ops.attention import flash_tile_census  # noqa: E402
 # (sq, sk, block_q, block_k): one grid tile a head, square, with more keys
 # than queries and with more queries than keys — the sub-tiled layouts —
 # and a grid of several tiles a head, which keeps the one-piece masked
-# body and the grid-level skip (fp32 dq partials at nk = 2)
+# body and the grid-level skip (nk = 2: the query-major one-sweep backward)
 _SUBTILE_LAYOUTS = {
     "one_tile": (256, 256, 256, 256),
     "many_tiles": (512, 512, 256, 256),
@@ -363,7 +363,9 @@ def test_causal_subtiles_match_ref(rng, sub_width, layout, with_bias,
     bodies = _kernel_primitives(
         lambda q, k, v: jax.vjp(kernel, q, k, v)[1](dy), q, k, v)
     assert bodies["apex_flash_fwd"].count("dot_general") == 2 * pieces
-    assert bodies["apex_flash_bwd_fused"].count("dot_general") == 5 * pieces
+    backward = "apex_flash_bwd_" + ("fused" if sk == bk else "sweep")
+    assert sorted(bodies) == sorted(["apex_flash_fwd", backward])
+    assert bodies[backward].count("dot_general") == 5 * pieces
 
     out_k, vjp_k = jax.vjp(kernel, q, k, v)
     out_r, vjp_r = jax.vjp(ref, q, k, v)
@@ -559,40 +561,295 @@ def test_tracing_moves_the_tile_counters(rng):
         q, k, v, use_pallas=True)) == [B * H, B * H, 0]
 
 
-def test_shared_trace_keyed_on_module_switches(rng, monkeypatch):
+def _backward_kernels(q, k, v, **kw):
+    """Names of the flash kernels in the gradient of one call, sorted."""
+    # a new function each time: make_jaxpr keeps the trace of one it has seen
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, use_pallas=True, **kw))
+
+    return sorted(_kernel_primitives(
+        jax.grad(loss, argnums=(0, 1, 2)), q, k, v))
+
+
+_ONE_KEY_BLOCK = ["apex_flash_bwd_fused", "apex_flash_fwd"]
+_SWEEP = ["apex_flash_bwd_sweep", "apex_flash_fwd"]
+_TWO_PASS = ["apex_flash_bwd_dkdv", "apex_flash_bwd_dq", "apex_flash_fwd"]
+
+
+@pytest.fixture
+def two_pass(monkeypatch, request):
+    """The route of a head past the VMEM budget, at interpreter sizes: with
+    no room for the resident accumulators every call of several key blocks
+    takes dkdv + dq (the budget is the one thing the route reads that is
+    not a shape)."""
+    if request.param:
+        monkeypatch.setattr(attention_mod, "_SWEEP_ACC_BUDGET_BYTES", 0)
+    return request.param
+
+
+_ROUTES = pytest.mark.parametrize(
+    "two_pass", [False, True], ids=["one_sweep", "two_pass"], indirect=True)
+
+
+def test_shared_trace_keyed_on_the_budget(rng, monkeypatch):
     """The kernels' trace is shared between calls of one signature
-    (attention._flash_jit); what it reads from the module at trace time
-    is part of the key, so the A/B tools that flip a switch between two
-    calls (tools/check_fused_dq_acc.py) get the other path, not the first
-    trace again."""
+    (attention._flash_jit); what it reads from the module at trace time —
+    the accumulators' VMEM budget — is part of the key, so flipping it
+    between two calls gives the other route, not the first trace again."""
+    q, k, v = qkv(rng, s=512, d=64)
+    kw = dict(causal=True, block_q=256, block_k=256)
+    assert _backward_kernels(q, k, v, **kw) == _SWEEP
+    monkeypatch.setattr(attention_mod, "_SWEEP_ACC_BUDGET_BYTES", 0)
+    assert _backward_kernels(q, k, v, **kw) == _TWO_PASS
+    monkeypatch.undo()
+    assert _backward_kernels(q, k, v, **kw) == _SWEEP
+
+
+@pytest.mark.parametrize("nk", [1, 2, 4, 8])
+@pytest.mark.parametrize("bias_grad", [False, True], ids=["", "bias_grad"])
+def test_backward_route_follows_the_shapes(rng, nk, bias_grad):
+    """The route is a function of nk, bias_grad and the accumulators' bytes
+    alone: one key block takes the key-major one-sweep kernel, several the
+    query-major one while dk's and dv's accumulators fit the budget, a
+    learned bias's gradient the two passes (dbias comes out of the dq
+    pass)."""
+    s = 512
+    q, k, v = qkv(rng, s=s, d=64)
+    kw = dict(block_q=128, block_k=s // nk)
+    if bias_grad:
+        kw.update(bias=jnp.zeros((B, s, s)), bias_grad=True)
+        want = ["apex_flash_bwd_dkdv", "apex_flash_bwd_dq_dbias",
+                "apex_flash_fwd"]
+    else:
+        want = _ONE_KEY_BLOCK if nk == 1 else _SWEEP
+    assert _backward_kernels(q, k, v, **kw) == want
+    assert attention_mod._bwd_sweeps(nk, bias_grad, 0) == len(want) - 1
+
+
+@pytest.mark.parametrize("sk,d,d_v,fits", [
+    (8192, 192, 128, True),      # moonlight.train-8k: 12.6 MB in VMEM
+    (8192, 128, 128, True),      # trinity-mini.train-8k: 8.4 MB
+    (8192, 256, 256, True),      # qwen3-next.train-8k: 16.8 MB
+    (32768, 128, 128, False),    # a 32k ring shard: 33.6 MB
+    (65536, 64, 64, False),
+])
+def test_a_head_past_the_vmem_budget_takes_two_passes(monkeypatch, sk, d, d_v,
+                                                      fits):
+    """The accumulators are counted as VMEM holds them (whole 128-lane
+    tiles), the three cells' heads fit the budget and a 32k ring shard
+    does not; what does not fit keeps dkdv + dq."""
+    acc = attention_mod._sweep_acc_bytes(sk, d, d_v)
+    assert acc == sk * 4 * sum(-(-w // 128) * 128 for w in (d, d_v))
+    assert (acc <= attention_mod._SWEEP_ACC_BUDGET_BYTES) == fits
+    assert attention_mod._bwd_sweeps(sk // 1024, False, acc) == (1 if fits else 2)
+    # the route the kernels take is that function's: the same call, traced
+    # with a budget one byte short of its head's accumulators
+    q = jnp.zeros((1, 2, 512, d)); kv = jnp.zeros((1, 2, 512, d_v))
+    small = attention_mod._sweep_acc_bytes(512, d, d_v)
+    for budget, want in ((small, _SWEEP), (small - 1, _TWO_PASS)):
+        monkeypatch.setattr(attention_mod, "_SWEEP_ACC_BUDGET_BYTES", budget)
+        assert _backward_kernels(
+            q, q, kv, causal=True, block_q=128, block_k=128) == want
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in _sub_jaxprs(eqn):
+            yield from _walk_eqns(inner)
+
+
+def test_one_sweep_backward_is_one_kernel_and_no_partials(rng):
+    """The backward of an nk = 8 call holds ONE apex_flash_bwd* kernel of
+    five dot_generals a piece; no float32 array with a leading nk axis (the
+    dq partials that were) and no float32 array of a gradient's size at all
+    leaves or enters it — dq, dk and dv cross HBM once, in the output
+    dtype — and nothing is summed over key blocks in XLA afterwards."""
+    nk, s, d = 8, 1024, 64
+    q, k, v = (t.astype(jnp.bfloat16) for t in qkv(rng, s=s, d=d))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=s // nk,
+            use_pallas=True).astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    bodies = _kernel_primitives(grad, q, k, v)
+    assert sorted(bodies) == _SWEEP
+    assert bodies["apex_flash_bwd_sweep"].count("dot_general") == 5
+    eqns = list(_walk_eqns(jax.make_jaxpr(grad)(q, k, v).jaxpr))
+    (call,) = [e for e in eqns if e.primitive.name == "pallas_call"
+               and e.params["name"].startswith("apex_flash_bwd")]
+    assert [(x.aval.shape, x.aval.dtype) for x in call.outvars] == \
+        [((B * H, s, d), jnp.bfloat16)] * 3
+    for e in eqns:
+        for x in e.outvars:
+            shape, dtype = x.aval.shape, x.aval.dtype
+            assert not (dtype == jnp.float32 and len(shape) == 4
+                        and shape[0] == nk), (e.primitive.name, shape)
+    after = eqns[eqns.index(call) + 1:]
+    assert "reduce_sum" not in [e.primitive.name for e in after]
+
+
+# sha256 of str(jaxpr) of the gradient at the two cells whose backward has
+# ONE key block, taken from the commit before the one-sweep backward (PR 34,
+# 988b87c): gpt2-small.train's and bert-large.train's programs must not
+# change by a byte
+_NK1_JAXPR_SHA256 = {
+    "gpt2": "32013b9116c40ecb",
+    "bert": "12e8d9094239416a",
+    "cross": "6b91cf45ab00148a",
+    "cross_causal": "4c0fbd4919c0be04",
+}
+
+
+@pytest.mark.parametrize("name,hq,hkv,sq,sk,d,d_v,causal,bias,drop", [
+    ("gpt2", 12, 12, 1024, 1024, 64, 64, True, False, 0.0),
+    ("bert", 16, 16, 512, 512, 64, 64, False, True, 0.1),
+    ("cross", 4, 2, 2048, 512, 64, 64, False, False, 0.0),
+    ("cross_causal", 4, 4, 2048, 1024, 128, 64, True, False, 0.0),
+])
+def test_one_key_block_lowers_to_the_text_it_lowered_to(
+        name, hq, hkv, sq, sk, d, d_v, causal, bias, drop):
+    """nk = 1 keeps apex_flash_bwd_fused exactly as it was: the gradient's
+    jaxpr, kernel bodies included, is the text the parent traced."""
+    import hashlib
+
+    q, k, v = (jnp.zeros((2, h_, s_, d_), jnp.bfloat16)
+               for h_, s_, d_ in ((hq, sq, d), (hkv, sk, d), (hkv, sk, d_v)))
+    b = jnp.zeros((2, sq, sk), jnp.float32) if bias else None
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, b, causal=causal, use_pallas=True, dropout_rate=drop,
+            dropout_seed=jnp.int32(3) if drop else None).astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
+    assert "apex_flash_bwd_fused" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        _NK1_JAXPR_SHA256[name]
+
+
+def _case(hq, hkv, sq, sk, d, d_v, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(k[0], (1, hq, sq, d)),
+            jax.random.normal(k[1], (1, hkv, sk, d)),
+            jax.random.normal(k[2], (1, hkv, sk, d_v)),
+            jax.random.normal(k[3], (1, hq, sq, d_v)))
+
+
+@pytest.mark.parametrize("nk", [2, 4, 8])
+@pytest.mark.parametrize("hq,hkv,sq,d,d_v,kw", [
+    (2, 2, 512, 64, 64, dict(causal=False)),
+    (2, 2, 512, 64, 64, dict(causal=True)),
+    # a window that crosses block edges at every nk (blocks 256, 128, 64)
+    (2, 2, 512, 64, 64, dict(causal=True, window=200)),
+    # grouped heads: dk / dv summed over the group in the accumulators
+    (4, 1, 512, 64, 64, dict(causal=True)),
+    (8, 1, 512, 64, 64, dict(causal=True, window=150)),
+    # v at a head size of its own, as DeepseekV3Config.tiny (96 / 64)
+    (2, 2, 512, 96, 64, dict(causal=True)),
+    (4, 2, 512, 96, 64, dict(causal=False)),
+    # dropout on: the mask is the reference's on every tile
+    (2, 2, 512, 64, 64, dict(causal=True, dropout_rate=0.2,
+                             dropout_seed=jnp.int32(11))),
+    (4, 2, 512, 64, 64, dict(causal=True, window=200, dropout_rate=0.1,
+                             dropout_seed=jnp.int32(7))),
+    # more query blocks than the keys' (cross attention), and one query block
+    (2, 2, 1024, 64, 64, dict(causal=False)),
+    (2, 2, 128, 64, 64, dict(causal=False)),
+], ids=["full", "causal", "win200", "g4", "g8_win150", "96_64", "96_64_g2",
+        "drop", "drop_win_g2", "sq_gt_sk", "one_q_block"])
+def test_one_sweep_backward_matches_ref(nk, hq, hkv, sq, d, d_v, kw):
+    """dq, dk, dv of the one-sweep backward against ``attention_ref``'s
+    float32 gradients, interpreted; dk and dv at the key/value heads'
+    shapes."""
+    sk = 512
+    q, k, v, do = _case(hq, hkv, sq, sk, d, d_v)
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v, **kw) * do)
+    flash = lambda *a, **kw_: flash_attention(
+        *a, block_q=128, block_k=sk // nk, use_pallas=True, **kw_)
+    assert _backward_kernels(q, k, v, block_q=128, block_k=sk // nk,
+                             **kw) == _SWEEP
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(attention_ref), (0, 1, 2))(q, k, v)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+
+@_ROUTES
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("sq,sk,row0,col0", [
+    (256, 512, 512, 0),       # a ring shard's queries against two shards' keys
+    (512, 256, 0, 1024),
+    (256, 256, 256, 256),     # the diagonal block
+])
+def test_backward_with_offsets_as_the_ring_passes_them(rng, sq, sk, row0,
+                                                       col0, causal, two_pass):
+    """``_flash_bwd`` called directly, as parallel/ring_attention.py calls
+    it: ``sq`` != ``sk`` and the shard's row and column offsets in the seed
+    block.  The causal mask stays local, the dropout hash global: both
+    routes give the gradients of the reference whose mask is drawn at the
+    offsets."""
+    d, rate, seed = 64, 0.2, jnp.int32(5)
+    q = jnp.asarray(rng.randn(H, sq, d).astype(np.float32) * 0.3)
+    k = jnp.asarray(rng.randn(H, sk, d).astype(np.float32) * 0.3)
+    v = jnp.asarray(rng.randn(H, sk, d).astype(np.float32) * 0.3)
+    do = jnp.asarray(rng.randn(H, sq, d).astype(np.float32))
+    seed3 = attention_mod._pack_seed(seed, row0, col0)
+    args = (d ** -0.5, causal, 128, 128, rate)
+    out, lse = attention_mod._flash_fwd(q, k, v, None, seed3, *args)
+    got = attention_mod._flash_bwd(q, k, v, None, seed3, out, lse, do, *args)
+
+    def ref(q, k, v):
+        s = jnp.einsum("hqd,hkd->hqk", q, k) * d ** -0.5
+        if causal:
+            s = jnp.where(np.tril(np.ones((sq, sk), bool)), s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        keep = jnp.stack([attention_mod._keep_mask(
+            seed, h_, row0, col0, (sq, sk), rate) for h_ in range(H)])
+        return jnp.einsum("hqk,hkd->hqd",
+                          jnp.where(keep, p / (1 - rate), 0.0), v)
+
+    out_r, vjp = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(out, out_r, atol=2e-5)
+    for a, b_ in zip(got[:3], vjp(do)):
+        np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+    assert got[3] is None
+
+
+def test_tracing_the_backward_moves_the_sweep_counters(rng, monkeypatch):
+    """``ops.flash.bwd_calls`` / ``ops.flash.bwd_sweeps``: one call, and one
+    sweep over the score tiles on the one-sweep routes, two on the
+    fallback — counted when the backward is traced."""
+    from apex_tpu import obs
+
+    reg = obs.default_registry()
+    names = ["ops.flash.bwd_calls", "ops.flash.bwd_sweeps"]
     q, k, v = qkv(rng, s=512, d=64)
 
-    def kernels():
-        # a new function each time, as the tools make one: make_jaxpr
-        # itself keeps the trace of a function it has seen
-        def loss(q, k, v):
-            return jnp.sum(flash_attention(
-                q, k, v, causal=True, block_q=256, block_k=256,
-                use_pallas=True))
+    def moved_by(**kw):
+        before = [reg.counter(n).snapshot()["value"] for n in names]
+        _backward_kernels(q, k, v, **kw)
+        return [reg.counter(n).snapshot()["value"] - b
+                for n, b in zip(names, before)]
 
-        return sorted(_kernel_primitives(
-            jax.grad(loss, argnums=(0, 1, 2)), q, k, v))
-
-    fused = ["apex_flash_bwd_fused", "apex_flash_fwd"]
-    two_pass = ["apex_flash_bwd_dkdv", "apex_flash_bwd_dq", "apex_flash_fwd"]
-    assert kernels() == fused
-    monkeypatch.setattr(attention_mod, "_USE_FUSED_BWD", False)
-    assert kernels() == two_pass
-    monkeypatch.setattr(attention_mod, "_USE_FUSED_BWD", True)
-    assert kernels() == fused
-    monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 1)
-    assert kernels() == two_pass  # nk = 2 is past the fused limit now
-    monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 4)
-    # the aliased dq accumulation is taken on the TPU alone
-    monkeypatch.setattr(attention_mod, "_FUSED_DQ_ACC", True)
-    assert kernels() == fused
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert kernels() == ["apex_flash_bwd_fused_acc", "apex_flash_fwd"]
+    # a scale no other test of this worker has traced: a backward whose
+    # trace is shared (attention._flash_jit) is not traced, or counted, again
+    blocks = dict(causal=True, block_q=128, block_k=128, scale=0.1375)
+    assert moved_by(causal=True, scale=0.1375) == [1, 1]    # one key block
+    assert moved_by(**blocks) == [1, 1]                 # four: the sweep
+    assert moved_by(**blocks, bias=jnp.zeros((B, 512, 512)),
+                    bias_grad=True) == [1, 2]
+    monkeypatch.setattr(attention_mod, "_SWEEP_ACC_BUDGET_BYTES", 0)
+    assert moved_by(**blocks) == [1, 2]
+    assert moved_by(**blocks) == [0, 0]                 # the shared trace
+    # a forward alone has no backward to count
+    before = [reg.counter(n).snapshot()["value"] for n in names]
+    jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, use_pallas=True, **blocks))(q, k, v)
+    assert [reg.counter(n).snapshot()["value"] for n in names] == before
 
 
 # ---------------------------------------------------------------------------
@@ -610,7 +867,7 @@ def _window_case(hq, hkv, s, d=64, b=1, seed=0):
             jax.random.normal(k[3], (b, hq, s, d)))
 
 
-@pytest.mark.parametrize("two_pass", [False, True], ids=["fused", "two_pass"])
+@_ROUTES
 @pytest.mark.parametrize("hq,hkv,s,window,bq,bk", [
     (4, 2, 512, 200, 128, 128),      # S not a multiple of the window
     (8, 2, 512, None, 128, 256),     # grouped heads alone, two rows
@@ -619,12 +876,10 @@ def _window_case(hq, hkv, s, d=64, b=1, seed=0):
     (4, 2, 256, 100, 256, 256),      # one grid tile a head: masked whole
     (4, 2, 512, 128, 128, 256),      # window == a query block
 ], ids=["win200_g2", "g4_b2", "win300_g4", "win100", "one_tile", "win128"])
-def test_window_and_grouped_heads_match_ref(monkeypatch, hq, hkv, s, window,
-                                            bq, bk, two_pass):
+def test_window_and_grouped_heads_match_ref(hq, hkv, s, window, bq, bk,
+                                            two_pass):
     """Forward, dq, dk, dv against ``attention_ref`` on both backward
-    routes (the combined pass and dkdv + dq)."""
-    if two_pass:
-        monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 0)
+    routes (one sweep, and dkdv + dq)."""
     b = 2 if window is None else 1
     q, k, v, do = _window_case(hq, hkv, s, b=b)
     kw = dict(causal=True, window=window)
@@ -650,8 +905,8 @@ def test_window_with_dropout_and_grouped_heads(monkeypatch):
     flash = lambda *a, **kw_: flash_attention(
         *a, block_q=128, block_k=128, use_pallas=True, **kw_)
     want = jax.grad(loss(_ref), (0, 1, 2))(q, k, v)
-    for max_nk in (4, 0):
-        monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", max_nk)
+    for budget in (attention_mod._SWEEP_ACC_BUDGET_BYTES, 0):
+        monkeypatch.setattr(attention_mod, "_SWEEP_ACC_BUDGET_BYTES", budget)
         for a, b_ in zip(jax.grad(loss(flash), (0, 1, 2))(q, k, v), want):
             np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
 
@@ -726,7 +981,7 @@ def _unequal_case(hq, hkv, s, d_qk, d_v, b=1, seed=0):
             jax.random.normal(k[3], (b, hq, s, d_v)))
 
 
-@pytest.mark.parametrize("two_pass", [False, True], ids=["fused", "two_pass"])
+@_ROUTES
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("hq,hkv,s,d_qk,d_v,window,bq,bk", [
     (2, 2, 256, 192, 128, None, 128, 128),   # the latent mixer's head sizes
@@ -739,17 +994,14 @@ def _unequal_case(hq, hkv, s, d_qk, d_v, b=1, seed=0):
     (2, 2, 384, 64, 64, 100, 128, 128),      # a window, equal sizes
 ], ids=["192_128", "128_64", "64_128", "192_128_one_tile", "192_128_g2",
         "192_128_win100", "64_64_g2", "64_64_win100"])
-def test_value_head_size_of_its_own_matches_ref(monkeypatch, hq, hkv, s, d_qk,
-                                                d_v, window, bq, bk, causal,
-                                                two_pass):
+def test_value_head_size_of_its_own_matches_ref(hq, hkv, s, d_qk, d_v, window,
+                                                bq, bk, causal, two_pass):
     """o, dq, dk, dv against ``attention_ref`` where v's head size is not q's
     and k's, on both backward routes; o and dv come out at v's size, dq and
     dk at q's; the scale is q's ``D ** -0.5``.  The equal-size grouped and
     window cases run the same assertions over the routes they always took."""
     if not causal:
         window = None       # a window goes with causal: three blocks, full
-    if two_pass:
-        monkeypatch.setattr(attention_mod, "_FUSED_BWD_MAX_NK", 0)
     q, k, v, do = _unequal_case(hq, hkv, s, d_qk, d_v)
     kw = dict(causal=causal, window=window)
     loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v) * do)

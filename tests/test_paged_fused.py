@@ -1,6 +1,6 @@
 """ISSUE 20: fused paged-attention serving kernel + tree speculation.
 
-Parity contract (the `_FUSED_DQ_ACC` lesson applied to the read side):
+Parity contract (a kernel no chip has run stays off by default):
 the fused kernel (`apex_tpu.ops.attention.paged_fused_attention` —
 interpret mode off-TPU) must BITWISE-match the materializing path at
 fp32, the O2 bf16 policy, and int8 pages.  Comparisons are
@@ -145,7 +145,7 @@ class TestFusedKernelParity:
 
     def test_default_off(self, monkeypatch):
         """The ROADMAP carried-risk rule: the fused path is opt-in
-        until a live-TPU session runs tools/check_fused_dq_acc.py."""
+        until a live-TPU session runs tools/check_paged_fused.py."""
         monkeypatch.delenv("APEX_TPU_PAGED_FUSED", raising=False)
         assert paged_fused_default() is False
         assert paged_fused_serve_default(None) is False

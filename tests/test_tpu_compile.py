@@ -100,12 +100,17 @@ def _flash_grad(shape, causal, **kw):
 def _named_case(kernel):
     """``(fn, avals)`` whose compiled program holds ``kernel``."""
     if kernel in ("apex_flash_fwd", "apex_flash_bwd_fused"):
-        # GPT-2 small's own shape: one key block, the combined backward
+        # GPT-2 small's own shape: one key block, the key-major one sweep
         loss, avals = _flash_grad((8, 12, 1024, 64), True)
         return jax.grad(loss, argnums=(0, 1, 2)), avals
-    if kernel in ("apex_flash_bwd_dkdv", "apex_flash_bwd_dq"):
-        # more key blocks than the combined backward takes: two passes
+    if kernel == "apex_flash_bwd_sweep":
+        # eight key blocks: the query-major one sweep, dk and dv resident
         loss, avals = _flash_grad((1, 4, 8192, 64), True)
+        return jax.grad(loss, argnums=(0, 1, 2)), avals
+    if kernel in ("apex_flash_bwd_dkdv", "apex_flash_bwd_dq"):
+        # a 32k ring shard's head: dk's and dv's accumulators (33.6 MB) are
+        # past the VMEM budget, so two passes
+        loss, avals = _flash_grad((1, 1, 32768, 128), True)
         return jax.grad(loss, argnums=(0, 1, 2)), avals
     if kernel == "apex_flash_bwd_dq_dbias":
         # a learned bias wants its gradient: the dq pass writes it
@@ -212,8 +217,9 @@ _NAMES_OF_CASE = {}
 
 
 @pytest.mark.parametrize("kernel", [
-    "apex_flash_fwd", "apex_flash_bwd_fused", "apex_flash_bwd_dkdv",
-    "apex_flash_bwd_dq", "apex_flash_bwd_dq_dbias", "apex_ln_fwd",
+    "apex_flash_fwd", "apex_flash_bwd_fused", "apex_flash_bwd_sweep",
+    "apex_flash_bwd_dkdv", "apex_flash_bwd_dq", "apex_flash_bwd_dq_dbias",
+    "apex_ln_fwd",
     "apex_ln_bwd_dx", "apex_ln_bwd_dx_dwdb", "apex_xent_fwd",
     "apex_xent_bwd", "apex_paged_attn", "apex_gmm", "apex_gmm_dw",
     "apex_moe_records", "apex_moe_gather", "apex_moe_combine",
@@ -382,76 +388,108 @@ def test_flash_fwd_bwd_compiles(chip, as_tpu, shape, causal, dropout):
         chip, jax.grad(loss, argnums=(0, 1, 2)),
         (shape, BF16), (shape, BF16), (shape, BF16), ((), I32),
     )
-    assert n >= 2  # the forward and the combined dk+dv+dq backward
+    assert n >= 2  # the forward and the one-sweep dk+dv+dq backward
 
 
-@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
-def test_flash_window_grouped_heads_compiles(chip, as_tpu, window):
-    """Trinity-Mini's attention call: 32 query heads to 4 key/value heads
-    of size 128 at 8192 positions — the banded grid, key/value blocks read
-    through ``h // 8``, the two-pass backward with dk/dv summed over the
-    group inside the kernel (their shapes are the key/value heads')."""
-    from apex_tpu.ops import flash_attention
-
-    def loss(q, k, v):
-        with jax.named_scope("attn_window"):
-            out = flash_attention(q, k, v, causal=True, window=window)
-        return jnp.sum(out.astype(F32))
-
-    q, kv = ((1, 32, 8192, 128), BF16), ((1, 4, 8192, 128), BF16)
-    names = _mosaic_names(chip, jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert names == ["apex_flash_fwd", "apex_flash_bwd_dkdv",
-                     "apex_flash_bwd_dq"], names
+_ONE_SWEEP = ["apex_flash_fwd", "apex_flash_bwd_sweep"]
 
 
-def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
-    """Qwen3-Next's attention call: 16 query heads to 2 key/value heads of
-    size 256 at 8192 positions — the grouped route at twice the head size
-    the auto blocks (512 x 1024) were sized for."""
-    from apex_tpu.ops import flash_attention
-
-    def loss(q, k, v):
-        with jax.named_scope("attn_full"):
-            return jnp.sum(flash_attention(q, k, v, causal=True).astype(F32))
-
-    q, kv = ((1, 16, 8192, 256), BF16), ((1, 2, 8192, 256), BF16)
-    names = _mosaic_names(chip, jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert names == ["apex_flash_fwd", "apex_flash_bwd_dkdv",
-                     "apex_flash_bwd_dq"], names
-
-
-def test_flash_latent_head_sizes_compile_without_padding(chip, as_tpu):
-    """Moonlight's attention call: 16 heads at 8192 positions, queries and
-    keys 192 wide (128 + the 64 rotary dims, 1.5 lane tiles) against values
-    128 wide.  Mosaic takes the 192-wide blocks, and v, o, do and dv cross
-    the custom calls at 128: no operand or result of the three kernels is
-    padded to the queries' width."""
+def _flash_calls(chip, scope, q, k, v, window=None):
+    """Compile the gradient of one causal call under ``scope`` for the
+    described chip — the compile is what refuses a VMEM overrun, so a pass
+    says Mosaic took the backward's resident accumulators at this shape.
+    ``(names, widths)``: its Mosaic calls' names, and for each the sorted
+    last dimensions of its bfloat16 operands and results."""
     import re
 
     from apex_tpu.ops import flash_attention
     from apex_tpu.ops._common import mosaic_call_names
 
     def loss(q, k, v):
-        with jax.named_scope("attn_full"):
-            return jnp.sum(flash_attention(q, k, v, causal=True).astype(F32))
+        with jax.named_scope(scope):
+            out = flash_attention(q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(F32))
 
-    qk, v = ((1, 16, 8192, 192), BF16), ((1, 16, 8192, 128), BF16)
-    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (qk, qk, v)]
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip) for s in (q, k, v)]
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *args).compile().as_text()
     names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
-    assert names == ["apex_flash_fwd", "apex_flash_bwd_dkdv",
-                     "apex_flash_bwd_dq"], names
     calls = [l for l in text.splitlines()
              if "tpu_custom_call" in l and "apex_flash" in l and " = " in l]
-    assert len(calls) == 3
-    widths = [sorted(map(int, re.findall(r"bf16\[16,8192,(\d+)\]", l)))
+    assert len(calls) == len(names)
+    widths = [sorted(map(int, re.findall(r"bf16\[\d+,\d+,(\d+)\]", l)))
               for l in calls]
+    return names, widths
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_window_grouped_heads_compiles(chip, as_tpu, window):
+    """Trinity-Mini's attention call: 32 query heads to 4 key/value heads
+    of size 128 at 8192 positions — the banded grid, key/value blocks read
+    through ``h // 8``, ONE backward kernel with dk/dv of a key/value head
+    (8.4 MB of float32) resident in VMEM and summed over the group's eight
+    query heads there (their shapes are the key/value heads')."""
+    from apex_tpu.ops.attention import (
+        _SWEEP_ACC_BUDGET_BYTES, _sweep_acc_bytes)
+
+    assert _sweep_acc_bytes(8192, 128, 128) == 8192 * 256 * 4 \
+        <= _SWEEP_ACC_BUDGET_BYTES
+    q, kv = (1, 32, 8192, 128), (1, 4, 8192, 128)
+    names, widths = _flash_calls(chip, "attn_window", q, kv, kv, window)
+    assert names == _ONE_SWEEP, names
+    # forward: q, k, v, o; backward: q, k, v, do, dq, dk, dv
+    assert widths == [[128] * 4, [128] * 7], widths
+
+
+def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
+    """Qwen3-Next's attention call: 16 query heads to 2 key/value heads of
+    size 256 at 8192 positions — the grouped route at twice the head size
+    the auto blocks (512 x 1024) were sized for, and the largest resident
+    accumulators of the three cells: 16.8 MB."""
+    from apex_tpu.ops.attention import (
+        _SWEEP_ACC_BUDGET_BYTES, _sweep_acc_bytes)
+
+    assert _sweep_acc_bytes(8192, 256, 256) == 2 ** 24 \
+        <= _SWEEP_ACC_BUDGET_BYTES
+    q, kv = (1, 16, 8192, 256), (1, 2, 8192, 256)
+    names, widths = _flash_calls(chip, "attn_full", q, kv, kv)
+    assert names == _ONE_SWEEP, names
+    assert widths == [[256] * 4, [256] * 7], widths
+
+
+def test_flash_latent_head_sizes_compile_without_padding(chip, as_tpu):
+    """Moonlight's attention call: 16 heads at 8192 positions, queries and
+    keys 192 wide (128 + the 64 rotary dims, 1.5 lane tiles) against values
+    128 wide.  Mosaic takes the 192-wide blocks and dk's 192-wide resident
+    accumulator (two lane tiles in VMEM: 12.6 MB with dv's), and v, o, do
+    and dv cross the custom calls at 128: no operand or result of the two
+    kernels is padded to the queries' width."""
+    from apex_tpu.ops.attention import _sweep_acc_bytes
+
+    assert _sweep_acc_bytes(8192, 192, 128) == 8192 * (256 + 128) * 4
+    qk, v = (1, 16, 8192, 192), (1, 16, 8192, 128)
+    names, widths = _flash_calls(chip, "attn_full", qk, qk, v)
+    assert names == _ONE_SWEEP, names
     assert widths == [
-        [128, 128, 192, 192],               # forward: v, o | q, k
-        [128, 128, 128, 192, 192, 192],     # dkdv: v, do, dv | q, k, dk
-        [128, 128, 192, 192, 192],          # dq: v, do | q, k, dq
+        [128, 128, 192, 192],                    # forward: v, o | q, k
+        [128, 128, 128, 192, 192, 192, 192],     # v, do, dv | q, k, dq, dk
     ], widths
+
+
+def test_flash_sweep_compiles_at_the_budget_and_two_passes_past_it(
+        chip, as_tpu):
+    """The longest head the route's rule lets through — accumulators of
+    exactly the budget, 24 MiB: 24k positions at 128 + 128 — is one Mosaic
+    accepts (the call asks for the accumulators plus the tiles' room); the
+    next, a 32k ring shard, compiles as dkdv + dq."""
+    from apex_tpu.ops.attention import (
+        _SWEEP_ACC_BUDGET_BYTES, _sweep_acc_bytes)
+
+    assert _sweep_acc_bytes(24576, 128, 128) == _SWEEP_ACC_BUDGET_BYTES
+    at, past = (1, 1, 24576, 128), (1, 1, 32768, 128)
+    assert _flash_calls(chip, "attn_full", at, at, at)[0] == _ONE_SWEEP
+    assert _flash_calls(chip, "attn_full", past, past, past)[0] == [
+        "apex_flash_fwd", "apex_flash_bwd_dkdv", "apex_flash_bwd_dq"]
 
 
 def test_expert_layer_compiles_at_width_1408_and_8_held_of_64(chip, as_tpu):
