@@ -4,14 +4,16 @@ These are the models the reference's examples and kernels exist to serve
 (SURVEY.md §6 benchmark configs): ResNet-50 (imagenet amp O0-O3 + DDP +
 SyncBN), BERT-large (FusedLAMB + fused attention + xentropy), DCGAN
 (multi-model multi-loss-scaler amp), a simple MLP (the minimum
-end-to-end slice), and the four decoders: GPT-2 (``gpt.py``), the Arcee
+end-to-end slice), and the five decoders: GPT-2 (``gpt.py``), the Arcee
 Trinity block with sigmoid-routed experts (``afmoe.py``, training path), the
 Qwen3-Next block — gated-delta-rule linear attention beside gated full
 attention, softmax-routed experts (``qwen3_next.py``, training path) — and
 the DeepSeek-V3 block as Moonlight publishes it — multi-head latent attention
 (a low-rank key/value latent, one rotary key for all heads, values narrower
 than keys) and bias-steered sigmoid-routed experts (``deepseek_v3.py``,
-training path).
+training path) — and the SmallThinker block — a router that reads the block's
+input ahead of attention, ReGLU experts with no shared one, a position-free
+full layer before rotary window layers (``smallthinker.py``, training path).
 """
 from apex_tpu.models.resnet import ResNet, resnet50, resnet101, resnet152  # noqa: F401
 from apex_tpu.models.bert import (  # noqa: F401
@@ -32,5 +34,10 @@ from apex_tpu.models.deepseek_v3 import (  # noqa: F401
     DeepseekV3Config,
     DeepseekV3Layer,
     DeepseekV3LM,
+)
+from apex_tpu.models.smallthinker import (  # noqa: F401
+    SmallThinkerConfig,
+    SmallThinkerLayer,
+    SmallThinkerLM,
 )
 from apex_tpu.mlp import MLP  # noqa: F401

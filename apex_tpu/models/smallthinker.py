@@ -1,0 +1,204 @@
+"""SmallThinker decoder LM (PowerInfer's ``smallthinker`` family) on the
+training path.
+
+The fifth decoder block of the zoo (``models/gpt.py``, ``afmoe.py``,
+``qwen3_next.py``, ``deepseek_v3.py`` are the others): two RMSNorms a block
+in pre-norm position, grouped-query flash attention (seven query heads a
+key/value head at the published sizes) over a rotary sliding window in
+three layers of four and over the whole causal context, WITH NO POSITION
+SIGNAL, in the fourth — which comes first in a period — and in every layer
+routed experts of ReGLU units with no shared expert
+(``parallel/moe.py::ExpertShardMLP``).  THE ROUTER READS THE BLOCK'S INPUT,
+ahead of the input norm and attention; the experts read the normed stream
+after attention.  The routing plan (the top-k, the sorts and the integer
+tables of ``parallel/moe.py::_route``) therefore depends on nothing
+attention computes: nothing here schedules it, the data flow alone lets the
+compiler place it beside the attention kernels.
+
+Per block ``i``, input ``x`` (no biases anywhere; RMSNorm eps 1e-6)::
+
+    r = x W_r                                   # float32 logits over all experts
+    y = RMS_1(x);  q, k, v = y W_q, y W_k, y W_v
+    if rope_layout[i]: q, k = rotary(q), rotary(k)   # whole head, rotate_half
+    a = softmax(q k^T / sqrt(D), causal [, i - j < window if sliding_window_layout[i]]) v
+    h = x + a W_o
+    u = RMS_2(h)
+    (s, e) = top_k(r);  w = softmax(s)          # over the picked logits
+    out = h + sum_j w_j W_down[e_j] (relu(W_gate[e_j] u) * (W_up[e_j] u))
+
+``w`` is computed as ``softmax_topk_routing(r, k, norm_topk_prob=True)``: a
+softmax over all experts, its ``k`` largest renormalised — ``exp(r_i) /
+sum_picked exp(r_j)``, the same number (``tests/test_smallthinker.py``
+holds the two orders to each other).  ``h = E[ids]`` (no scale), ``logits =
+W_head RMS(h)``, the head untied.
+
+Left out: what the model's description calls secondary experts — a
+predictor of which of an expert's neurons fire, by which inference from
+slow storage skips the rows of the down-projection that ``relu`` zeroed.
+The published configuration holds no key of it and training computes the
+dense unit.  Rotary scaling (null in the configuration; the declared
+positions are native).
+
+Called as :class:`apex_tpu.models.gpt.GPTLM` and the other decoders are:
+``model.apply({"params": p}, ids, labels=labels, deterministic=...)`` ->
+``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a
+sliced ``vocab_size``, as in ``models/afmoe.py``.  Scopes ``moe_router``
+(under it only what reads the block's input: the scores and the top-k),
+``attn_full`` / ``attn_window`` (the flash call), ``moe_dispatch``,
+``moe_experts``, ``embed``, ``lm_head``, ``lm_loss``.  Serving methods are
+not part of this model yet: window layers' pages are ROADMAP M4's.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.amp.layers import Dense
+from apex_tpu.models.afmoe import RMSNorm, rotary
+from apex_tpu.ops.attention import flash_attention
+from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
+from apex_tpu.parallel.moe import ExpertShardMLP
+from apex_tpu.remat import remat_module
+
+__all__ = ["SmallThinkerConfig", "SmallThinkerLayer", "SmallThinkerLM"]
+
+
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerConfig:
+    vocab_size: int = 19072           # the slice held, padded to 128
+    hidden_size: int = 2560
+    # a layer a flag: 1 = a sliding window / rotary positions, 0 = the whole
+    # causal context / no position signal (the published pair of lists)
+    sliding_window_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    rope_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    num_heads: int = 28
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    sliding_window_size: int = 4096
+    rope_theta: float = 1.5e6
+    moe_ffn_hidden_size: int = 768    # one expert
+    num_experts: int = 64             # routed over
+    experts_held: Tuple[int, int] = (0, 8)
+    num_experts_per_tok: int = 6
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    # activation rematerialization per block (apex_tpu.remat): full_block
+    # keeps a block's input and the flash kernel's output and lse
+    remat_policy: str = "none"
+    compute_dtype: Any = jnp.bfloat16
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.sliding_window_layout)
+
+    @staticmethod
+    def tiny(**kw) -> "SmallThinkerConfig":
+        """For tests: every mechanism at toy widths — a full layer first,
+        three query heads a key/value head, a window shorter than a row."""
+        base = dict(
+            vocab_size=256, hidden_size=128,
+            sliding_window_layout=(0, 1, 1), rope_layout=(0, 1, 1),
+            num_heads=6, num_kv_heads=2, head_dim=64, sliding_window_size=48,
+            moe_ffn_hidden_size=128, num_experts=16, experts_held=(0, 4),
+            num_experts_per_tok=4)
+        base.update(kw)
+        return SmallThinkerConfig(**base)
+
+
+class SmallThinkerLayer(nn.Module):
+    """One block; ``index`` picks its attention (``cfg.sliding_window_layout``)
+    and whether q and k are rotated (``cfg.rope_layout``)."""
+
+    cfg: SmallThinkerConfig
+    index: int
+
+    @nn.compact
+    def __call__(self, x, deterministic: bool = True):
+        del deterministic           # no dropout in this family
+        cfg = self.cfg
+        b, s, d = x.shape
+        hq, hk, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        dt = cfg.compute_dtype
+        init = nn.initializers.normal(cfg.initializer_range)
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, dt, name=name)
+        windowed = bool(cfg.sliding_window_layout[self.index])
+
+        y = norm("input_norm")(x)
+        qkv = Dense((hq + 2 * hk) * hd, use_bias=False, dtype=dt,
+                    kernel_init=init, name="qkv")(y)
+        q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
+        heads = lambda t, n: t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
+        q, k = heads(q, hq), heads(k, hk)
+        if cfg.rope_layout[self.index]:
+            q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+        with jax.named_scope("attn_window" if windowed else "attn_full"):
+            attn = flash_attention(
+                q, k, heads(v, hk), causal=True,
+                window=cfg.sliding_window_size if windowed else None)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
+        h = x + Dense(d, use_bias=False, dtype=dt, kernel_init=init,
+                      name="o_proj")(attn)
+
+        # the router scores the block's INPUT x, the experts take the normed
+        # stream after attention
+        ff = ExpertShardMLP(
+            num_experts=cfg.num_experts, experts_held=cfg.experts_held,
+            d_ff=cfg.moe_ffn_hidden_size, k=cfg.num_experts_per_tok,
+            route_norm=cfg.norm_topk_prob, score_func="softmax",
+            unit_func="relu", compute_dtype=dt, kernel_init=init, name="moe",
+        )(norm("post_attn_norm")(h).reshape(b * s, d),
+          router_input=x.reshape(b * s, d))
+        return h + ff.reshape(b, s, d)
+
+
+class SmallThinkerLM(nn.Module):
+    """Embedding, the blocks ``layer_<i>``, a final RMSNorm and the untied
+    head.  ``__call__(ids)`` returns (B, S, V) float32 logits; with
+    ``labels`` (-100: not predicted) also the token-mean fused-xentropy
+    loss, as :class:`apex_tpu.models.gpt.GPTLM` does."""
+
+    cfg: SmallThinkerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        if len(cfg.rope_layout) != len(cfg.sliding_window_layout):
+            raise ValueError(
+                f"rope_layout has {len(cfg.rope_layout)} layers, "
+                f"sliding_window_layout {len(cfg.sliding_window_layout)}")
+        init = nn.initializers.normal(cfg.initializer_range)
+        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+                              embedding_init=init, dtype=jnp.float32)
+        # deterministic is static_argnum 2 (self=0): called positionally
+        layer_cls = remat_module(SmallThinkerLayer, cfg.remat_policy,
+                                 static_argnums=(2,))
+        self.layers = [layer_cls(cfg, i, name=f"layer_{i}")
+                       for i in range(cfg.num_layers)]
+        self.norm_f = RMSNorm(cfg.rms_norm_eps, cfg.compute_dtype)
+        self.head = Dense(cfg.vocab_size, use_bias=False,
+                          dtype=cfg.compute_dtype, kernel_init=init)
+
+    def __call__(self, input_ids, labels=None, deterministic: bool = True):
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            x = self.embed(input_ids).astype(cfg.compute_dtype)
+        for layer in self.layers:
+            x = layer(x, deterministic)
+        x = self.norm_f(x)
+        with jax.named_scope("lm_head"):
+            logits = self.head(x).astype(jnp.float32)
+        if labels is None:
+            return logits
+        with jax.named_scope("lm_loss"):
+            valid = labels >= 0
+            safe = jnp.where(valid, labels, 0)
+            # compute-dtype logits into the fused loss, as GPTLM
+            per_tok = softmax_cross_entropy(
+                logits.astype(cfg.compute_dtype), safe)
+            n = jnp.maximum(jnp.sum(valid), 1)
+            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
+        return logits, loss

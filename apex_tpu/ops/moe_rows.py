@@ -11,11 +11,21 @@ Records.  Mosaic moves whole (8, 128) tiles: one row of a tiled (n, d) array
 — 128 lanes of one sublane in each of ``d / 128`` tiles — cannot be the
 source of a DMA.  A source is therefore handed over as RECORDS, float32
 ``(n, d / 128, 128)``: row ``r`` is the ``d / 128`` sublanes of record ``r``,
-whole tiles, contiguous, exactly the row's values (bfloat16 widens without
-rounding).  :func:`records` makes them of a token-major array (XLA, every
-row: there every row is live); ``apex_moe_records`` (:func:`live_records`)
-of the row buffer, live tiles only.  In VMEM a kernel turns records back
-into rows with sublane-strided loads.
+contiguous, exactly the row's values (bfloat16 widens without rounding).
+:func:`records` makes them of a token-major array (XLA, every row: there
+every row is live); ``apex_moe_records`` (:func:`live_records`) of the row
+buffer, live tiles only.  In VMEM a kernel turns records back into rows
+with sublane-strided loads.
+
+Any ``d`` that is a multiple of 128 goes through.  Where ``d / 128`` is a
+multiple of 8 (2048: 16) a record is whole tiles.  Where it is not (2560:
+20) the tiled layout itself pads it: the last two dimensions of the record
+array are tiled (8, 128), so in HBM a record lies on ``_stride(d / 128)``
+sublanes (24), the next whole tile count, and a kernel's VMEM buffer gives
+every record that many; a DMA moves the record's own ``d / 128`` sublanes
+to the start of its place, the loads step by the stride and read lane tile
+``l < d / 128`` only, so the padding is never read.  The shapes say all of
+it: no argument tells the kernels apart.
 
 - ``apex_moe_gather`` (:func:`gather_rows`), row-major: ``out[r] =
   src[idx[r]]`` for the rows of the buffer that hold a token, times the
@@ -68,13 +78,18 @@ def _sublanes(*dtypes) -> int:
     return max(32 // jnp.dtype(t).itemsize for t in dtypes)
 
 
+def _stride(lanes: int) -> int:
+    """Sublanes from one record to the next where records of ``lanes``
+    sublanes lie tiled (8, 128): ``lanes`` rounded up to whole tiles."""
+    return -(-lanes // 8) * 8
+
+
 def supported(tokens: int, k: int, d: int, tile_rows: int, dtype) -> bool:
     """Can ``tokens`` rows of ``d`` elements of ``dtype``, ``k`` slots each,
-    and a buffer in tiles of ``tile_rows`` go through the kernels?  A record
-    has to be whole (8, 128) tiles for Mosaic; the interpreter, which runs
-    the kernels off the TPU, has no tiling to break."""
-    whole = 8 if jax.default_backend() == "tpu" else 1
-    return (d % _LANES == 0 and (d // _LANES) % whole == 0
+    and a buffer in tiles of ``tile_rows`` go through the kernels?  A row
+    has to be whole 128-lane sublanes; a record that is not whole (8, 128)
+    tiles is padded to them by its layout (module docstring)."""
+    return (d % _LANES == 0
             and jnp.dtype(dtype).itemsize in (2, 4)
             and tile_rows % _sublanes(dtype) == 0
             and combine_block(tokens, k, d) is not None)
@@ -107,10 +122,10 @@ def _smem_blocks(idx, block: int):
     return idx.astype(jnp.int32).reshape(-1, 1, block)
 
 
-def _rows_of(buf, first, count: int, lane_tile: int, lanes: int):
+def _rows_of(buf, first, count: int, lane_tile: int, stride: int):
     """``(count, 128)``: lane tile ``lane_tile`` of the ``count`` records
-    from ``first`` on in ``buf`` ((records * lanes, 128))."""
-    return buf[pl.ds(first * lanes + lane_tile, count, stride=lanes), :]
+    from ``first`` on in ``buf`` ((records * stride, 128))."""
+    return buf[pl.ds(first * stride + lane_tile, count, stride=stride), :]
 
 
 def _records_kernel(used_ref, x_ref, o_ref, *, chunk: int):
@@ -122,7 +137,7 @@ def _records_kernel(used_ref, x_ref, o_ref, *, chunk: int):
         def piece(p, c):
             r0 = pl.multiple_of(p * chunk, chunk)
             for l in range(lanes):
-                o_ref[pl.ds(r0 * lanes + l, chunk, stride=lanes), :] = x_ref[
+                o_ref[pl.ds(r0, chunk), l, :] = x_ref[
                     pl.ds(r0, chunk), l * _LANES:(l + 1) * _LANES
                 ].astype(jnp.float32)
             return c
@@ -137,30 +152,32 @@ def live_records(rows, layout: GroupLayout, *, tile_rows: int):
     capacity, d = rows.shape
     lanes = d // _LANES
     tile = lambda i, used: (jnp.minimum(i, used[0] - 1), 0)
-    out = _pallas_call(
+    return _pallas_call(
         functools.partial(_records_kernel, chunk=_sublanes(rows.dtype)),
         name="apex_moe_records",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(capacity // tile_rows,),
             in_specs=[pl.BlockSpec((tile_rows, d), tile)],
-            out_specs=pl.BlockSpec((tile_rows * lanes, _LANES), tile),
+            # a block of whole records: the layout pads each to whole tiles
+            out_specs=pl.BlockSpec((tile_rows, lanes, _LANES),
+                                   lambda i, used: (*tile(i, used), 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((capacity * lanes, _LANES),
+        out_shape=jax.ShapeDtypeStruct((capacity, lanes, _LANES),
                                        jnp.float32),
     )(layout.tiles_used, rows)
-    return out.reshape(capacity, lanes, _LANES)
 
 
 def _record_copies(src_ref, buf, sem):
     """``copy(at, r, record)``: the DMA of one record into the ``r``-th
-    place of ``buf[at]``."""
+    place of ``buf[at]``, whose places are ``_stride`` sublanes apart."""
     lanes = src_ref.shape[1]
+    stride = _stride(lanes)
 
     def copy(at, r, record):
         return pltpu.make_async_copy(
             src_ref.at[record],
-            buf.at[(*at, pl.ds(pl.multiple_of(r * lanes, lanes), lanes),
+            buf.at[(*at, pl.ds(pl.multiple_of(r * stride, stride), lanes),
                     slice(None))], sem)
 
     return copy
@@ -174,6 +191,7 @@ def _gather_kernel(valid_ref, used_ref, *rest, weighted: bool, chunk: int):
     i = pl.program_id(0)
     valid = valid_ref[i]
     lanes = src_ref.shape[1]
+    stride = _stride(lanes)
     copy = _record_copies(src_ref, buf, sem)
 
     @pl.when(i < used_ref[0])
@@ -186,8 +204,8 @@ def _gather_kernel(valid_ref, used_ref, *rest, weighted: bool, chunk: int):
             copy((), 0, 0).wait()
             return c
 
-        def scale(r, c):            # a record is whole vregs: scalar x tile
-            at = pl.ds(pl.multiple_of(r * lanes, lanes), lanes)
+        def scale(r, c):            # a record's place is whole vregs
+            at = pl.ds(pl.multiple_of(r * stride, stride), stride)
             buf[at, :] = buf[at, :] * w_ref[slot_ref[0, 0, r]]
             return c
 
@@ -202,7 +220,7 @@ def _gather_kernel(valid_ref, used_ref, *rest, weighted: bool, chunk: int):
             row = r0 + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
             for l in range(lanes):
                 o_ref[rows, l * _LANES:(l + 1) * _LANES] = jnp.where(
-                    row < valid, _rows_of(buf, r0, chunk, l, lanes), 0.0
+                    row < valid, _rows_of(buf, r0, chunk, l, stride), 0.0
                 ).astype(o_ref.dtype)
             return c
 
@@ -217,8 +235,8 @@ def gather_rows(src, idx, layout: GroupLayout, *, tile_rows: int,
     a live tile's other rows; the tiles past ``layout.tiles_used`` are not
     written.
 
-    ``src`` records (n, d / 128, 128), ``idx`` and ``weight_index``
-    (capacity,) int32, ``weights`` (m,) -> (capacity, d) ``out_dtype``.
+    ``src`` records (n, d / 128, 128), ``d`` any multiple of 128, ``idx``
+    and ``weight_index`` (capacity,) int32, ``weights`` (m,) -> (capacity, d) ``out_dtype``.
     Only the first ``tile_valid`` indices of a tile are read."""
     _, lanes, _ = src.shape
     capacity = idx.shape[0]
@@ -243,7 +261,7 @@ def gather_rows(src, idx, layout: GroupLayout, *, tile_rows: int,
             in_specs=specs + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((tile_rows, lanes * _LANES), tile),
             scratch_shapes=[
-                pltpu.VMEM((tile_rows * lanes, _LANES), jnp.float32),
+                pltpu.VMEM((tile_rows * _stride(lanes), _LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA(())],
         ),
         out_shape=jax.ShapeDtypeStruct((capacity, lanes * _LANES), out_dtype),
@@ -262,6 +280,7 @@ def _combine_kernel(row_slot_ref, starts_ref, src_ref, slot_ref, *rest,
                     weighted: bool, dots: bool, chunk: int):
     extra, (o_ref, buf, sem) = rest[:-3], rest[-3:]
     capacity, lanes, _ = src_ref.shape
+    stride = _stride(lanes)
     k = buf.shape[0]
     tokens = o_ref.shape[0]
     b = pl.program_id(0)
@@ -293,7 +312,7 @@ def _combine_kernel(row_slot_ref, starts_ref, src_ref, slot_ref, *rest,
         held = slot_ref[rows, :] < capacity                    # (chunk, k)
         held = [held[:, j:j + 1] for j in range(k)]
         value = lambda j, l: jnp.where(
-            held[j], _rows_of(buf.at[j], t0, chunk, l, lanes), 0.0)
+            held[j], _rows_of(buf.at[j], t0, chunk, l, stride), 0.0)
         if dots:
             g_ref, = extra
             for j in range(k):
@@ -319,10 +338,11 @@ def _combine_kernel(row_slot_ref, starts_ref, src_ref, slot_ref, *rest,
 
 def combine_block(tokens: int, k: int, d: int) -> Optional[int]:
     """Tokens of a grid step of the combine: the largest power of two, from
-    16 on, that divides ``tokens`` and whose ``k`` records a token fit the
-    buffer; None where 16 does not divide."""
+    16 on, that divides ``tokens`` and whose ``k`` records a token, each on
+    its whole tiles, fit the buffer; None where 16 does not divide."""
     block, b = None, 16
-    while tokens % b == 0 and k * b * d * 4 <= _COMBINE_BUFFER_BYTES:
+    record_bytes = _stride(d // _LANES) * _LANES * 4
+    while tokens % b == 0 and k * b * record_bytes <= _COMBINE_BUFFER_BYTES:
         block, b = b, 2 * b
     return block
 
@@ -353,7 +373,7 @@ def _combine(src, slot_row, row_slot, starts, extra, out_dtype, dots: bool):
             in_specs=specs,
             out_specs=per_token(width),
             scratch_shapes=[
-                pltpu.VMEM((k, block * lanes, _LANES), jnp.float32),
+                pltpu.VMEM((k, block * _stride(lanes), _LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA(())],
         ),
         out_shape=jax.ShapeDtypeStruct((tokens, width), out_dtype),

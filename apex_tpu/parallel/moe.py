@@ -231,6 +231,8 @@ def softmax_topk_routing(logits, k: int, norm_topk_prob: bool):
 
 
 SCORE_FUNCS = ("sigmoid", "softmax")
+# the gated unit of a routed expert: ``act(gate) * up``
+UNIT_FUNCS = {"silu": nn.silu, "relu": nn.relu}
 
 
 def shard_dispatch(sel, held: Tuple[int, int], capacity: int, tile_rows: int):
@@ -444,12 +446,16 @@ class SwiGLU(nn.Module):
 
 
 class ExpertShardMLP(nn.Module):
-    """One chip's share of an expert-parallel SwiGLU layer.
+    """One chip's share of an expert-parallel layer of gated units.
 
     ``x`` (T, d) -> (T, d): the shared expert's output plus, for every
     token, the weighted outputs of those of its ``k`` routed experts that
     lie in ``experts_held = (first, past_last)``.  The router scores all
-    ``num_experts``.  No token is dropped: the row buffer holds
+    ``num_experts`` — from ``x``, or from ``router_input`` (T, d) where the
+    call gives one: a block whose router reads the block's input while its
+    experts read the normed stream after attention hands both over, and the
+    routing plan then depends on nothing attention computes.  No token is
+    dropped: the row buffer holds
     ``T * min(k, held)`` rows — every token picking only held experts —
     plus a tile a held expert for the alignment, and the grouped products
     touch only the tiles that hold rows.  Summed over the shares that
@@ -459,9 +465,12 @@ class ExpertShardMLP(nn.Module):
     (:func:`sigmoid_topk_routing`: ``route_norm``, ``route_scale`` and the
     selection bias apply) or ``softmax`` (:func:`softmax_topk_routing`: a
     softmax over all ``num_experts``, ``route_norm`` renormalises the
-    picked probabilities; no bias, no scale).  ``shared_gate`` multiplies
-    the shared expert's output by ``sigmoid(x w_sg)``, a gate of its own a
-    token.
+    picked probabilities; no bias, no scale).  ``unit_func`` names the
+    routed experts' gated unit, ``act(gate) * up``: ``silu`` (SwiGLU) or
+    ``relu`` (ReGLU, exact zeros where the gate is negative; the gradient
+    at 0 is 0); the shared expert is a SwiGLU either way.  ``shared_gate``
+    multiplies the shared expert's output by ``sigmoid(x w_sg)``, a gate of
+    its own a token.
 
     Parameters: ``router`` (d, num_experts), ``expert_bias`` (num_experts,)
     (sigmoid scores only: added to the scores for the selection only; zero
@@ -492,12 +501,13 @@ class ExpertShardMLP(nn.Module):
     route_scale: float = 1.0
     score_func: str = "sigmoid"
     shared_gate: bool = False
+    unit_func: str = "silu"
     compute_dtype: Any = jnp.float32
     tile_rows: Optional[int] = None
     kernel_init: Callable = nn.initializers.lecun_normal()
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         from apex_tpu import obs
         from apex_tpu.ops import grouped_mm, moe_rows
         from apex_tpu.ops._common import pallas_default
@@ -507,6 +517,12 @@ class ExpertShardMLP(nn.Module):
         if self.score_func not in SCORE_FUNCS:
             raise ValueError(f"score_func must be one of {SCORE_FUNCS}, got "
                              f"{self.score_func!r}")
+        if self.unit_func not in UNIT_FUNCS:
+            raise ValueError(f"unit_func must be one of {tuple(UNIT_FUNCS)}, "
+                             f"got {self.unit_func!r}")
+        if router_input is not None and router_input.shape != x.shape:
+            raise ValueError(f"router_input {router_input.shape} is not "
+                             f"x's shape {x.shape}")
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError(f"experts_held {self.experts_held} is not a "
                              f"range of the {self.num_experts} experts")
@@ -540,7 +556,8 @@ class ExpertShardMLP(nn.Module):
             # float32 scores at full precision: the selection is a
             # discontinuity, so the router alone does not take the MXU's
             # single bfloat16 pass
-            logits = jnp.matmul(x.astype(jnp.float32),
+            scored = x if router_input is None else router_input
+            logits = jnp.matmul(scored.astype(jnp.float32),
                                 router.astype(jnp.float32),
                                 precision=jax.lax.Precision.HIGHEST)
             if sigmoid:
@@ -559,7 +576,8 @@ class ExpertShardMLP(nn.Module):
             gate, up = jnp.split(grouped_mm.grouped_matmul(
                 rows, wi, layout, tile_rows=tile_rows), 2, axis=-1)
             rows = grouped_mm.grouped_matmul(
-                nn.silu(gate) * up, wo, layout, tile_rows=tile_rows)
+                UNIT_FUNCS[self.unit_func](gate) * up, wo, layout,
+                tile_rows=tile_rows)
         with jax.named_scope("moe_dispatch"):
             y = _tokens_from_rows(rows, weights, routing, rows_tile)
         if self.shared_d_ff:

@@ -8,9 +8,12 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
 - ``kernels`` — each main-path Pallas kernel (flash attention, fused
   LayerNorm with the dgamma/dbeta epilogue, fused softmax-xentropy),
   COMPILED, forward and gradients, against its own jnp reference at the
-  tolerance tiers of the kernels' tests; and flash attention's backward
-  at the three 8k cells' calls, the shipped route against the two-pass
-  one, with the time of a call of each (``flash_backward``);
+  tolerance tiers of the kernels' tests; flash attention's backward at
+  the three 8k cells' calls and the 16k cell's two, the shipped route
+  against the two-pass one, with the time of a call of each
+  (``flash_backward``); and the expert layer's row movement at the 16k
+  cell's shape (hidden 2560), kernels against ``jnp.take``, with the time
+  of a call of each (``moe_rows_at_2560``);
 - ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, with
   dropout on: three windows on a fixed seeded batch;
 - ``serve`` — the params that phase produced, through ``GPTDecoder`` +
@@ -608,9 +611,9 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
         jax.block_until_ready(out)
         return round((time.perf_counter() - t0) / n * 1e6, 1)
 
-    def backward_routes(name, hq, hkv, d, d_v, window, key):
+    def backward_routes(name, hq, hkv, d, d_v, window, key, seq=sw, n=10):
         q_, k_, v_, w_ = jax.jit(lambda key: [
-            (normal(ki, (1, heads, sw, width), f32) * 0.3).astype(bf16)
+            (normal(ki, (1, heads, seq, width), f32) * 0.3).astype(bf16)
             for ki, heads, width in zip(jax.random.split(key, 4),
                                         (hq, hkv, hkv, hq), (d, d, d_v, d_v))
         ])(key)
@@ -629,8 +632,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
             return jnp.sum(jax.lax.map(head, (q[0], k, v))[None] * w)
 
         rec = routes[name] = {
-            "shape": [hq, hkv, sw, d, d_v, window],
-            "fwd_us": us_a_call(jax.jit(attend), (q_, k_, v_))}
+            "shape": [hq, hkv, seq, d, d_v, window],
+            "fwd_us": us_a_call(jax.jit(attend), (q_, k_, v_), n)}
         grads = {}
         budget = attention_mod._SWEEP_ACC_BUDGET_BYTES
         try:
@@ -640,7 +643,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
                     q_, k_, v_, w_).compile()
                 rec[route + "_kernels"] = mosaic_call_names(compiled.as_text())
-                rec[route + "_grad_us"] = us_a_call(compiled, (q_, k_, v_, w_))
+                rec[route + "_grad_us"] = us_a_call(compiled, (q_, k_, v_, w_), n)
                 grads[route] = compiled(q_, k_, v_, w_)
         finally:
             attention_mod._SWEEP_ACC_BUDGET_BYTES = budget
@@ -659,6 +662,72 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
             ("trinity_full", 2 * hl, max(hl // 4, 1), 128, 128, None),
             ("qwen3_next", hl, max(hl // 8, 1), 256, 256, None))):
         backward_routes(*case, jax.random.fold_in(root_key, 100 + i))
+    # ... and at smallthinker.train-16k's: a row of 16 contexts, 28 query
+    # heads to 4 (groups of SEVEN) at 128, the 4096-wide band and the whole
+    # triangle, dk / dv accumulators of 16.8 MB a key/value head
+    hs = max(hl // 4, 1)
+    for i, (name, window) in enumerate((("smallthinker_window", sw // 2),
+                                        ("smallthinker_full", None))):
+        backward_routes(name, 7 * hs, hs, 128, 128, window,
+                        jax.random.fold_in(root_key, 110 + i), seq=2 * sw, n=3)
+
+    # the expert layer's row movement at smallthinker.train-16k's shape —
+    # twice the LayerNorm rows x 2560 bfloat16 (a record 20 sublanes: two and
+    # a half (8, 128) tiles, laid on 24), 6 slots a token, 8 of 64 experts
+    # held, routed by seeded scores — kernels against the jnp.take path:
+    # forward and gradients held to each other, us a call of each
+    rows_m, d_m, k_m, held_m = 2 * rows, 20 * 128, 6, (0, 8)
+    _require(moe_rows.supported(rows_m, k_m, d_m, tile, bf16),
+             "moe_rows.supported at the 16k cell's shape")
+    cap_r = gmm.rows_capacity(rows_m * k_m, held_m[1], tile)
+    make_r = lambda kx, kw_, kc, kr: (
+        (normal(kx, (rows_m, d_m), f32) * 0.5).astype(bf16),
+        jax.random.uniform(kw_, (rows_m, k_m), f32),
+        normal(kc, (rows_m + cap_r, d_m), f32),
+        moe._route(jax.lax.top_k(normal(kr, (rows_m, 64), f32), k_m)[1],
+                   held_m, cap_r, tile,
+                   moe_rows.combine_block(rows_m, k_m, d_m)))
+    xr, wr, cots, routing_r = jax.jit(
+        lambda key: make_r(*jax.random.split(key, 4)))(
+            jax.random.fold_in(root_key, 120))
+    cot_t, cot_g = cots[:rows_m], cots[rows_m:]     # of the tokens, of the rows
+    # the kernels leave the tiles past the live ones undefined: compared as 0
+    live = (jnp.arange(cap_r) // tile < routing_r.layout.tiles_used[0])[:, None]
+    rows_r = jax.jit(lambda x: jnp.where(
+        live, moe._rows_from_tokens(x, routing_r, None), 0))(xr)
+
+    def rows_fns(tile_rows):
+        gather = lambda x: jnp.where(
+            live, moe._rows_from_tokens(x, routing_r, tile_rows), 0)
+        combine = lambda r, w: moe._tokens_from_rows(r, w, routing_r, tile_rows)
+        return {
+            "gather": (gather, (xr,)),
+            "gather_grad": (jax.grad(lambda x: jnp.sum(
+                gather(x).astype(f32) * cot_g)), (xr,)),
+            "combine": (combine, (rows_r, wr)),
+            "combine_grad": (jax.grad(lambda r, w: jnp.sum(
+                combine(r, w) * cot_t), (0, 1)), (rows_r, wr)),
+        }
+
+    timed = facts["moe_rows_at_2560"] = {
+        "shape": [rows_m, k_m, d_m, held_m[1], 64], "rows_capacity": cap_r,
+        "rows_live": int(jnp.sum(routing_r.row_slot < rows_m * k_m)),
+        "mosaic_calls": {}}
+    outs = {}
+    for side, tile_rows in (("kernels", tile), ("take", None)):
+        for name, (fn, args) in rows_fns(tile_rows).items():
+            compiled = jax.jit(fn).lower(*args).compile()
+            if side == "kernels":
+                _require_mosaic(compiled, 1, timed["mosaic_calls"], name)
+            timed[f"{name}_{side}_us"] = us_a_call(compiled, args, n=3)
+            outs[side, name] = jax.tree_util.tree_leaves(compiled(*args))
+    for (side, name), got in outs.items():
+        if side != "kernels":
+            continue
+        for j, (g, o) in enumerate(zip(got, outs["take", name])):
+            if name == "combine_grad" and j == 0:      # d_rows: live tiles
+                g, o = (jnp.where(live, t, 0) for t in (g, o))
+            _compare(f"moe_rows_at_2560.{name}.{j}", g, o, 1e-4, parity)
 
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 

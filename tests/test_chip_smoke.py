@@ -69,11 +69,22 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     # to each other and to the reference
     routes = kernels["flash_backward"]
     assert set(routes) == {"moonlight", "trinity_window", "trinity_full",
-                           "qwen3_next"}
+                           "qwen3_next", "smallthinker_window",
+                           "smallthinker_full"}
+    # groups of seven over twice the others' positions
+    assert routes["smallthinker_window"]["shape"][:3] == [7, 1, 16 * TINY.ctx]
     for rec in routes.values():
         assert {"fwd_us", "shipped_grad_us", "two_pass_grad_us",
                 "shipped_kernels", "two_pass_kernels"} <= set(rec)
     assert "flash_backward.trinity_window.dk_vs_two_pass" in kernels["parity"]
+    # the row movement at hidden 2560: every pass timed on both paths and
+    # held to the other
+    rows = kernels["moe_rows_at_2560"]
+    assert rows["shape"] == [2 * TINY.kernel_batch * TINY.ctx, 6, 2560, 8, 64]
+    assert 0 < rows["rows_live"] < rows["rows_capacity"]
+    for name in ("gather", "gather_grad", "combine", "combine_grad"):
+        assert {f"{name}_kernels_us", f"{name}_take_us"} <= set(rows)
+        assert f"moe_rows_at_2560.{name}.0" in kernels["parity"]
     assert train["loss_per_window"][-1] < train["loss_per_window"][0]
     assert train["compiles_after_first_window"] == 0
     assert serve["compiles_after_warmup"] == 0

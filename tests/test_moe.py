@@ -137,3 +137,148 @@ class TestBackward:
                 np.asarray(g_ep[key]), np.asarray(g_1[key]),
                 atol=1e-4, rtol=1e-4, err_msg=key,
             )
+
+
+# -- ExpertShardMLP: where the scores come from, and the gated unit ---------
+
+from apex_tpu.parallel.moe import ExpertShardMLP, softmax_topk_routing  # noqa: E402
+
+
+def _shard_layer(**kw):
+    return ExpertShardMLP(num_experts=8, experts_held=(2, 6), d_ff=32, k=3,
+                          score_func="softmax", tile_rows=8, **kw)
+
+
+def _shard_oracle(x, scored, params, act):
+    """Every held expert on every token in plain ``jnp``, weighted by the
+    renormalised softmax of ``scored``'s logits (zero where not picked)."""
+    logits = jnp.matmul(scored, params["router"], precision="highest")
+    sel, w = softmax_topk_routing(logits, 3, True)
+    out = jnp.zeros_like(x)
+    for j, e in enumerate(range(2, 6)):
+        gate, up = jnp.split(x @ params["wi"][j], 2, axis=-1)
+        weight = jnp.sum(jnp.where(sel == e, w, 0.0), axis=-1)
+        out = out + weight[:, None] * ((act(gate) * up) @ params["wo"][j])
+    return out
+
+
+@pytest.mark.parametrize("unit", ["silu", "relu"])
+@pytest.mark.parametrize("early", [False, True], ids=["scores_x", "router_input"])
+def test_expert_shard_routes_on_router_input_and_gates_by_unit_func(unit, early):
+    """Value and gradients (the experts' input, the scored stream, every
+    parameter) against the oracle: with ``router_input`` the selection and
+    the weights follow THAT stream and the experts still consume ``x``."""
+    layer = _shard_layer(unit_func=unit)
+    kx, kr, kp, kc = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(kx, (48, 16))
+    scored = 2.0 * jax.random.normal(kr, (48, 16)) if early else None
+    params = layer.init(kp, x)["params"]
+    assert set(params) == {"router", "wi", "wo"}
+    cot = jax.random.normal(kc, (48, 16))
+    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[unit]
+
+    def got(p, x, r):
+        return jnp.sum(layer.apply({"params": p}, x, router_input=r) * cot)
+
+    def want(p, x, r):
+        return jnp.sum(_shard_oracle(x, x if r is None else r, p, act) * cot)
+
+    argnums = (0, 1, 2) if early else (0, 1)
+    a, ga = jax.value_and_grad(got, argnums)(params, x, scored)
+    b, gb = jax.value_and_grad(want, argnums)(params, x, scored)
+    np.testing.assert_allclose(a, b, rtol=1e-5)
+    for u, v in zip(jax.tree_util.tree_leaves(ga), jax.tree_util.tree_leaves(gb)):
+        assert np.asarray(v).any()
+        np.testing.assert_allclose(u, v, rtol=1e-4, atol=1e-5)
+    if early:       # and it IS another function than scoring x
+        assert abs(float(got(params, x, None)) - float(a)) > 1e-3
+
+
+def test_expert_shard_refuses_what_it_does_not_know():
+    x = jnp.zeros((16, 16))
+    with pytest.raises(ValueError, match="unit_func"):
+        _shard_layer(unit_func="gelu").init(jax.random.PRNGKey(0), x)
+    layer = _shard_layer()
+    params = layer.init(jax.random.PRNGKey(0), x)
+    with pytest.raises(ValueError, match="router_input"):
+        layer.apply(params, x, router_input=jnp.zeros((8, 16)))
+
+
+# sha256 of str(jaxpr) of the loss gradient of the three sparse models that
+# call ExpertShardMLP with its defaults (no router_input, the silu unit), on
+# the jnp.take path, taken from the commit before router_input and unit_func
+# (PR 36, 2c69164): their programs must not change by a byte.
+_SPARSE_JAXPR_SHA256 = {
+    "afmoe": "cbabac37c149c613",
+    "qwen3_next": "b25b0f67d5968d25",
+    "deepseek_v3": "ad3f888363e7d8ed",
+}
+
+
+def _sha(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", sorted(_SPARSE_JAXPR_SHA256))
+def test_defaults_leave_the_sparse_models_the_text_they_had(name):
+    import apex_tpu.models as models
+    from apex_tpu.ops._common import force_pallas
+
+    cls, cfg = {
+        "afmoe": (models.AfmoeLM, models.AfmoeConfig),
+        "qwen3_next": (models.Qwen3NextLM, models.Qwen3NextConfig),
+        "deepseek_v3": (models.DeepseekV3LM, models.DeepseekV3Config),
+    }[name]
+    model = cls(cfg.tiny(compute_dtype=jnp.float32))
+    ids = jnp.zeros((1, 64), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
+    with force_pallas(False):
+        text = str(jax.make_jaxpr(jax.grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids)[1]))(params))
+    assert "moe_router" in str(jax.make_jaxpr(lambda p: model.apply(
+        {"params": p}, ids))(params).pretty_print(name_stack=True))
+    assert _sha(text) == _SPARSE_JAXPR_SHA256[name]
+
+
+@pytest.mark.parametrize("d", [2048, 2560])
+def test_row_movement_is_one_form_at_whole_tile_records_and_off_them(d):
+    """Tokens -> rows -> tokens through ops/moe_rows.py at a hidden size
+    whose records are whole (8, 128) tiles (2048: 16 sublanes) and at one
+    whose records are not (2560: 20): the same four kernels, the records a
+    (rows, d / 128, 128) array in both, value and gradients the jnp.take
+    path's."""
+    from apex_tpu.ops import grouped_mm as gmm
+    from apex_tpu.ops import moe_rows
+    from apex_tpu.ops._common import force_pallas
+    from apex_tpu.parallel import moe
+
+    t, k, tile, held = 64, 4, 16, (0, 4)
+    cap = gmm.rows_capacity(t * k, 4, tile)
+    key = jax.random.split(jax.random.PRNGKey(d), 3)
+    x = jax.random.normal(key[0], (t, d)).astype(jnp.bfloat16)
+    w = jax.nn.softmax(jax.random.normal(key[1], (t, k)))
+    sel = jnp.argsort(jax.random.uniform(key[2], (t, 16)), axis=-1)[:, :k]
+
+    def loss(x, w, block):      # block None: the jnp.take path
+        rows_tile = None if block is None else tile
+        routing = moe._route(sel.astype(jnp.int32), held, cap, tile, block)
+        rows = moe._rows_from_tokens(x, routing, rows_tile)
+        return jnp.sum(moe._tokens_from_rows(rows, w, routing, rows_tile)
+                       .astype(jnp.float32) ** 2)
+
+    both = jax.value_and_grad(loss, (0, 1))
+    with force_pallas(True):
+        block = moe_rows.combine_block(t, k, d)
+        text = str(jax.make_jaxpr(lambda x, w: both(x, w, block))(x, w))
+        got = both(x, w, block)
+    for kernel in ("apex_moe_records", "apex_moe_gather", "apex_moe_combine",
+                   "apex_moe_combine_dw"):
+        assert kernel in text
+    assert f"f32[{cap},{d // 128},128]" in text
+    want = both(x, w, None)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=2e-2, atol=1e-3)

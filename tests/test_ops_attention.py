@@ -875,7 +875,12 @@ def _window_case(hq, hkv, s, d=64, b=1, seed=0):
     (2, 2, 384, 100, 128, 128),      # a window alone
     (4, 2, 256, 100, 256, 256),      # one grid tile a head: masked whole
     (4, 2, 512, 128, 128, 256),      # window == a query block
-], ids=["win200_g2", "g4_b2", "win300_g4", "win100", "one_tile", "win128"])
+    # SEVEN query heads a key/value head (models/smallthinker.py), a window
+    # that crosses the 128-wide blocks' edges; and two such groups, no window
+    (7, 1, 512, 200, 128, 128),
+    (14, 2, 256, None, 128, 128),
+], ids=["win200_g2", "g4_b2", "win300_g4", "win100", "one_tile", "win128",
+        "win200_g7", "g7_x2"])
 def test_window_and_grouped_heads_match_ref(hq, hkv, s, window, bq, bk,
                                             two_pass):
     """Forward, dq, dk, dv against ``attention_ref`` on both backward

@@ -16,6 +16,11 @@ from apex_tpu.parallel import moe
 T, K, D, TILE, ROUTED_OVER, HELD = 32, 4, 256, 16, 16, (4, 8)
 F32, BF16 = jnp.float32, jnp.bfloat16
 
+# Hidden sizes whose records are not whole (8, 128) tiles — 2560 is 20
+# sublanes a record (SmallThinker's), 384 is 3 — beside one whose records
+# are (1024: 8).  D itself, 256, is 2 sublanes a record.
+WIDTHS = [1024, 2560, 384]
+
 
 def _selection(routing: str):
     """(T, K) expert ids, distinct within a token."""
@@ -37,11 +42,11 @@ def _selection(routing: str):
 ROUTINGS = ["even", "worst", "empty_expert", "all_or_none"]
 
 
-def _routing(name: str):
+def _routing(name: str, d: int = D):
     held = HELD[1] - HELD[0]
     return moe._route(
         _selection(name), HELD, gmm.rows_capacity(T * min(K, held), held, TILE),
-        TILE, moe_rows.combine_block(T, K, D))
+        TILE, moe_rows.combine_block(T, K, d))
 
 
 def _rand(seed, shape, dtype=F32):
@@ -164,6 +169,76 @@ def test_both_movements_and_their_gradients_match_the_take_path(name):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("d", WIDTHS)
+@pytest.mark.parametrize("name", ["even", "empty_expert"])
+def test_every_kernel_at_widths_off_the_whole_tile(name, d):
+    """gather (plain and weighted), records, combine (weighted and not) and
+    the slots' dots at each of ``WIDTHS`` against the takes, value for
+    value, with the rows that hold no token poisoned."""
+    r = _routing(name, d)
+    _, in_use = _live(r)
+    x = _rand(20, (T, d), BF16)
+    got = moe_rows.gather_rows(moe_rows.records(x), r.row_token, r.layout,
+                               tile_rows=TILE, out_dtype=BF16)
+    assert got.shape == (r.row_slot.size, d)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32)[in_use],
+        np.asarray(moe._take_rows(x, r.row_token), np.float32)[in_use])
+    g, weights = _rand(21, (T, d)), jax.random.uniform(
+        jax.random.PRNGKey(22), (T * K,))
+    got = moe_rows.gather_rows(moe_rows.records(g), r.row_token, r.layout,
+                               tile_rows=TILE, out_dtype=BF16,
+                               weights=weights, weight_index=r.row_slot)
+    want = (moe._take_rows(g, r.row_token)
+            * moe._take_rows(weights, r.row_slot)[:, None]).astype(BF16)
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[in_use],
+                                  np.asarray(want, np.float32)[in_use])
+
+    rows, clean = _poisoned(_rand(23, (r.row_slot.size, d), BF16), r)
+    records = moe_rows.live_records(rows, r.layout, tile_rows=TILE)
+    assert records.shape == (r.row_slot.size, d // 128, 128)
+    np.testing.assert_array_equal(
+        np.asarray(records).reshape(-1, d)[in_use],
+        np.asarray(rows, np.float32)[in_use])
+    w = weights.reshape(T, K)
+    np.testing.assert_array_equal(
+        np.asarray(moe_rows.combine_rows(records, r.slot_row, r.row_slot,
+                                         r.starts, weights=w)),
+        np.asarray(jax.jit(moe._sum_slots)(clean, r.slot_row, w)))
+    np.testing.assert_array_equal(
+        np.asarray(moe_rows.combine_rows(records, r.slot_row, r.row_slot,
+                                         r.starts, out_dtype=BF16), np.float32),
+        np.asarray(jax.jit(moe._sum_slots)(clean, r.slot_row).astype(BF16),
+                   np.float32))
+    got = moe_rows.slot_dots(records, r.slot_row, r.row_slot, r.starts, g)
+    want = jnp.stack([
+        jnp.sum(g * moe._take_rows(clean, r.slot_row[:, j]).astype(F32), -1)
+        for j in range(K)], -1)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    assert (np.asarray(got)[np.asarray(r.slot_row) >= r.row_slot.size]
+            == 0).all()
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_movements_and_gradients_match_the_takes_at_other_widths(d):
+    """As the test above at ``D``, through the two ``custom_vjp``s."""
+    r = _routing("even", d)
+    holds = jnp.asarray(_live(r)[0])[:, None]
+    x, w, cot = _rand(24, (T, d)), _rand(25, (T, K)), _rand(26, (T, d))
+
+    def loss(x, w, tile_rows):
+        rows = moe._rows_from_tokens(x, r, tile_rows)
+        rows = jnp.where(holds, jnp.tanh(rows) * 1.5, 0.0)
+        return jnp.sum(moe._tokens_from_rows(rows, w, r, tile_rows) * cot)
+
+    want, want_grads = jax.value_and_grad(loss, (0, 1))(x, w, None)
+    got, got_grads = jax.value_and_grad(loss, (0, 1))(x, w, TILE)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(got_grads, want_grads):
+        assert np.asarray(b).any() and np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
 def _layer(**kw):
     return moe.ExpertShardMLP(
         num_experts=ROUTED_OVER, experts_held=HELD, d_ff=128, k=K,
@@ -242,8 +317,13 @@ def test_gauges_say_what_was_traced():
     assert reg.get("moe.dispatch.kernels").value == 0
 
 
-def test_supported_follows_the_tiling():
+def test_supported_follows_the_tiling(monkeypatch):
     assert moe_rows.supported(64, 4, 256, 16, F32)
+    # on the TPU as off it: a record need not be whole (8, 128) tiles
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe_rows.supported(16384, 6, 2560, 256, BF16)
+    assert moe_rows.supported(8192, 8, 2048, 256, BF16)
+    assert moe_rows.combine_block(16384, 6, 2560) == 64   # 24 sublanes each
     assert not moe_rows.supported(64, 4, 192, 16, F32)       # lanes
     assert not moe_rows.supported(64, 4, 256, 8, BF16)       # packed rows
     assert not moe_rows.supported(24, 4, 256, 16, F32)       # token blocks

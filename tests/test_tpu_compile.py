@@ -441,6 +441,25 @@ def test_flash_window_grouped_heads_compiles(chip, as_tpu, window):
     assert widths == [[128] * 4, [128] * 7], widths
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
+def test_flash_groups_of_seven_at_16k_compile(chip, as_tpu, window):
+    """SmallThinker's attention call: 28 query heads to 4 key/value heads of
+    size 128 at 16,384 positions, twice any other cell's — key/value blocks
+    read through ``h // 7``, the band 4096 wide, and ONE backward kernel
+    with dk/dv of a key/value head (16.8 MB of float32) resident in VMEM and
+    summed over the group's SEVEN query heads as the grid walks them."""
+    from apex_tpu.ops.attention import (
+        _SWEEP_ACC_BUDGET_BYTES, _sweep_acc_bytes)
+
+    assert _sweep_acc_bytes(16384, 128, 128) == 2 ** 24 \
+        <= _SWEEP_ACC_BUDGET_BYTES
+    q, kv = (1, 28, 16384, 128), (1, 4, 16384, 128)
+    names, widths = _flash_calls(
+        chip, "attn_window" if window else "attn_full", q, kv, kv, window)
+    assert names == _ONE_SWEEP, names
+    assert widths == [[128] * 4, [128] * 7], widths
+
+
 def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
     """Qwen3-Next's attention call: 16 query heads to 2 key/value heads of
     size 256 at 8192 positions — the grouped route at twice the head size
@@ -519,6 +538,43 @@ def test_expert_layer_compiles_at_width_1408_and_8_held_of_64(chip, as_tpu):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         params, x).compile().as_text()
+    names = [n.rsplit(".", 1)[0] if n.rsplit(".", 1)[-1].isdigit() else n
+             for n in mosaic_call_names(text)]
+    assert names.count("apex_gmm") == 4 and names.count("apex_gmm_dw") == 2, names
+    assert {"apex_moe_records", "apex_moe_gather", "apex_moe_combine",
+            "apex_moe_combine_dw"} <= set(names), names
+
+
+def test_expert_layer_compiles_at_hidden_2560_and_relu_units(chip, as_tpu):
+    """smallthinker.train-16k's expert layer: 16,384 tokens x 6 slots, 8 held
+    of 64 experts, (16384 * 6 / 256 + 8) * 256 = 100,352 rows, hidden 2560 =
+    20 x 128 — a record is 20 sublanes, two and a half (8, 128) tiles, laid
+    on 24 by its layout (``ops/moe_rows.py``) — expert width 768, ReGLU
+    units, no shared expert, the router fed another stream than the experts."""
+    from apex_tpu.ops import grouped_mm as gmm
+    from apex_tpu.ops import moe_rows
+    from apex_tpu.parallel.moe import ExpertShardMLP
+
+    t, d = 16384, 2560
+    assert gmm.rows_capacity(6 * t, 8) == 100352
+    assert moe_rows.supported(t, 6, d, gmm.DEFAULT_TILE_ROWS, BF16)
+    assert moe_rows.combine_block(t, 6, d) == 64
+    layer = ExpertShardMLP(num_experts=64, experts_held=(0, 8), d_ff=768,
+                           k=6, score_func="softmax", unit_func="relu",
+                           compute_dtype=BF16)
+    x = jax.ShapeDtypeStruct((t, d), BF16, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((t, d), BF16))["params"])
+
+    def loss(p, x, r):
+        return jnp.sum(layer.apply({"params": p}, x, router_input=r).astype(F32))
+
+    from apex_tpu.ops._common import mosaic_call_names
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        params, x, x).compile().as_text()
     names = [n.rsplit(".", 1)[0] if n.rsplit(".", 1)[-1].isdigit() else n
              for n in mosaic_call_names(text)]
     assert names.count("apex_gmm") == 4 and names.count("apex_gmm_dw") == 2, names
