@@ -11,21 +11,23 @@ ALIGNED: group ``e`` starts on a multiple of ``tile_rows`` and owns
 are plain tiled matmuls whose weight block is picked by a prefetched
 per-tile group id, and nothing has to be masked across a group boundary.
 The buffer is sized for the worst case (:func:`rows_capacity`); the tiles
-past the last group hold no rows: the kernels do no work for them, fetch
-nothing for them and write zeros.  Rows outside every group read as zero
-in every output, as ``ragged_dot`` leaves rows past its last group.
+past the last group hold no rows, and the kernels' row axis ENDS at
+``layout.tiles_used``, a device value (a dynamic grid bound): no step runs
+for them, nothing is fetched or written, and a call costs what the routing
+made live.  Those tiles are UNDEFINED in the output and in the input
+gradient, as they may be in ``x``; a live tile's other rows read as zero.
 
 - ``apex_gmm``: ``out[rows of e] = x[rows of e] @ w[e]`` (and, with the
   weight block transposed, the input gradient ``dout @ w[e].T``).  Grid
-  (column tiles, row tiles), the contraction whole: consecutive row tiles
-  of one group name the same weight block, which is fetched once.
+  (column tiles, live row tiles), the contraction whole: consecutive row
+  tiles of one group name the same weight block, which is fetched once.
 - ``apex_gmm_dw``: ``dw[e] = x[rows of e].T @ dout[rows of e]``; grid
-  (k tiles, n tiles, row tiles), row tiles innermost, float32 accumulator
-  in VMEM written when the group's last tile is done.  A group without rows
-  owns one tile of zero valid rows, so its ``dw`` is written as zeros.
+  (k tiles, n tiles, live row tiles), row tiles innermost, float32
+  accumulator in VMEM written when the group's last tile is done.  A group
+  without rows owns one tile of zero valid rows: its ``dw`` is zeros.
 
 Off the TPU (and as the kernels' test oracle) the same layout goes through
-``jax.lax.ragged_dot`` with the groups' padded sizes.
+``jax.lax.ragged_dot`` with the groups' padded sizes, zeros off the groups.
 """
 from __future__ import annotations
 
@@ -112,9 +114,7 @@ def grouped_matmul_ref(x, w, layout: GroupLayout, tile_rows: int):
 # kernels
 # ---------------------------------------------------------------------------
 
-def _gmm_kernel(group_ref, valid_ref, used_ref, x_ref, w_ref, o_ref, *,
-                transpose_w: bool):
-    del group_ref, used_ref
+def _gmm_kernel(group_ref, valid_ref, x_ref, w_ref, o_ref, *, transpose_w):
     valid = valid_ref[pl.program_id(1)]
 
     @pl.when(valid > 0)
@@ -131,22 +131,19 @@ def _gmm_kernel(group_ref, valid_ref, used_ref, x_ref, w_ref, o_ref, *,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _gmm_dw_kernel(group_ref, valid_ref, used_ref, x_ref, g_ref, o_ref,
-                   acc_ref):
+def _gmm_dw_kernel(group_ref, valid_ref, x_ref, g_ref, o_ref, acc_ref):
     m = pl.program_id(2)
-    tiles = pl.num_programs(2)
-    used = used_ref[0]
+    used = pl.num_programs(2)
     group = group_ref[m]
-    in_use = m < used
     first = (m == 0) | (group_ref[jnp.maximum(m - 1, 0)] != group)
-    last = (m == used - 1) | (group_ref[jnp.minimum(m + 1, tiles - 1)] != group)
+    last = (m == used - 1) | (group_ref[jnp.minimum(m + 1, used - 1)] != group)
     valid = valid_ref[m]
 
-    @pl.when(in_use & first)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(in_use & (valid > 0))
+    @pl.when(valid > 0)
     def _rows():
         x = x_ref[...]
         row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
@@ -155,60 +152,59 @@ def _gmm_dw_kernel(group_ref, valid_ref, used_ref, x_ref, g_ref, o_ref,
             x, g_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(in_use & last)
+    @pl.when(last)
     def _write():
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _gmm_pallas(x, w, layout: GroupLayout, tile_rows: int,
-                transpose_w: bool):
+def _live_tiles(layout: GroupLayout, tiles: int):
+    """The row axis' dynamic bound: the tiles of a group, within the buffer."""
+    return jnp.minimum(layout.tiles_used[0], tiles)
+
+
+def _gmm_pallas(x, w, layout: GroupLayout, tile_rows: int, transpose_w: bool):
     rows, c = x.shape
     n = w.shape[1] if transpose_w else w.shape[2]
     bn = auto_block(n, _BLOCK_N)
-    # a tile past the last group names the last one's blocks: nothing moves
-    row_tile = lambda j, i, grp, val, used: (jnp.minimum(i, used[0] - 1), 0)
     if transpose_w:
-        w_spec = pl.BlockSpec((1, bn, c),
-                              lambda j, i, grp, val, used: (grp[i], j, 0))
+        w_spec = pl.BlockSpec((1, bn, c), lambda j, i, grp, _: (grp[i], j, 0))
     else:
-        w_spec = pl.BlockSpec((1, c, bn),
-                              lambda j, i, grp, val, used: (grp[i], 0, j))
+        w_spec = pl.BlockSpec((1, c, bn), lambda j, i, grp, _: (grp[i], 0, j))
     return _pallas_call(
         functools.partial(_gmm_kernel, transpose_w=transpose_w),
         name="apex_gmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(n // bn, rows // tile_rows),
-            in_specs=[pl.BlockSpec((tile_rows, c), row_tile), w_spec],
-            out_specs=pl.BlockSpec(
-                (tile_rows, bn), lambda j, i, grp, val, used: (i, j)),
+            num_scalar_prefetch=2,
+            grid=(n // bn, _live_tiles(layout, rows // tile_rows)),
+            in_specs=[pl.BlockSpec((tile_rows, c),
+                                   lambda j, i, grp, _: (i, 0)), w_spec],
+            out_specs=pl.BlockSpec((tile_rows, bn),
+                                   lambda j, i, grp, _: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
-    )(layout.tile_group, layout.tile_valid, layout.tiles_used, x, w)
+    )(layout.tile_group, layout.tile_valid, x, w)
 
 
-def _gmm_dw_pallas(x, g, layout: GroupLayout, tile_rows: int, groups: int,
-                   dtype):
-    rows, k = x.shape
-    n = g.shape[1]
+def _gmm_dw_pallas(x, g, layout: GroupLayout, tile_rows, groups, dtype):
+    (rows, k), n = x.shape, g.shape[1]
     bk, bn = auto_block(k, _BLOCK_DW), auto_block(n, _BLOCK_DW)
-    tile = lambda col: (
-        lambda a, b, i, grp, val, used:
-        (jnp.minimum(i, used[0] - 1), (a, b)[col]))
     return _pallas_call(
         _gmm_dw_kernel,
         name="apex_gmm_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(k // bk, n // bn, rows // tile_rows),
-            in_specs=[pl.BlockSpec((tile_rows, bk), tile(0)),
-                      pl.BlockSpec((tile_rows, bn), tile(1))],
+            num_scalar_prefetch=2,
+            grid=(k // bk, n // bn,
+                  _live_tiles(layout, rows // tile_rows)),
+            in_specs=[pl.BlockSpec((tile_rows, bk),
+                                   lambda a, b, i, grp, _: (i, a)),
+                      pl.BlockSpec((tile_rows, bn),
+                                   lambda a, b, i, grp, _: (i, b))],
             out_specs=pl.BlockSpec(
-                (1, bk, bn), lambda a, b, i, grp, val, used: (grp[i], a, b)),
+                (1, bk, bn), lambda a, b, i, grp, _: (grp[i], a, b)),
             scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((groups, k, n), dtype),
-    )(layout.tile_group, layout.tile_valid, layout.tiles_used, x, g)
+    )(layout.tile_group, layout.tile_valid, x, g)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -236,7 +232,10 @@ def grouped_matmul(x, w, layout: GroupLayout, *,
                    tile_rows: int = DEFAULT_TILE_ROWS,
                    use_pallas: Optional[bool] = None):
     """``out[r] = x[r] @ w[group of r]`` for the rows of a buffer laid out
-    by :func:`group_layout`; zeros on the rows outside every group.
+    by :func:`group_layout`; zeros on a live tile's rows outside its group.
+    The tiles past ``layout.tiles_used`` are never read, and on the kernel
+    path never written: undefined in the output and in ``x``'s gradient
+    (``ragged_dot`` leaves zeros there).
 
     ``x`` (rows, c), ``w`` (groups, c, n) -> (rows, n), in ``x``'s dtype
     with float32 accumulation.  Differentiable in ``x`` and ``w``.  The
@@ -259,6 +258,7 @@ def grouped_matmul(x, w, layout: GroupLayout, *,
     reg = obs.default_registry()
     reg.gauge("ops.gmm.rows_capacity").set_max(rows)
     reg.gauge("ops.gmm.tile_rows").set(tile_rows)
+    reg.gauge("ops.gmm.live_tiles_only").set(int(use_pallas))
     w = w.astype(x.dtype)
     if not use_pallas:
         return grouped_matmul_ref(x, w, layout, tile_rows)

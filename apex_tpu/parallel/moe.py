@@ -486,10 +486,11 @@ class ExpertShardMLP(nn.Module):
     On the kernel path a pass costs what the routing made live, and the
     buffer's tiles past ``layout.tiles_used`` are UNDEFINED, not zero, in
     ``rows`` and in its gradient: no pass writes them.  Every reader
-    ignores them — ``apex_gmm`` fetches no tile past ``tiles_used`` and
-    writes zeros there, ``apex_gmm_dw`` skips them, the combine reads held
-    slots only — and whoever reads the buffer next has to as well.  (A live
-    tile's rows past its ``tile_valid`` are zeros on both paths.)
+    ignores them — ``apex_gmm`` and ``apex_gmm_dw`` end their row axis at
+    ``tiles_used`` and leave their outputs' tiles past it undefined too,
+    the gated unit is elementwise, the combine reads held slots only — and
+    whoever reads the buffer next has to as well.  (A live tile's rows past
+    its ``tile_valid`` are zeros on both paths.)
     """
 
     num_experts: int

@@ -13,7 +13,10 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   against the two-pass one, with the time of a call of each
   (``flash_backward``); and the expert layer's row movement at the 16k
   cell's shape (hidden 2560), kernels against ``jnp.take``, with the time
-  of a call of each (``moe_rows_at_2560``);
+  of a call of each (``moe_rows_at_2560``); and the grouped products at
+  Moonlight's two calls in the expert layer's worst-case buffer and in one
+  with no dead tail, forward, ``dx`` and ``dw`` each timed alone
+  (``grouped_mm_at_cell``);
 - ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, with
   dropout on: three windows on a fixed seeded batch;
 - ``serve`` — the params that phase produced, through ``GPTDecoder`` +
@@ -458,9 +461,18 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
         normal(kc, (cap, 2 * n), f32),
     ))
 
+    def live_rows(lay):
+        """(rows, 1) bool: the tiles that belong to a group.  The kernels'
+        row axis ends there; what lies past it is undefined: compared as 0."""
+        tiles = lay.tile_group.shape[0]
+        return jnp.repeat(jnp.arange(tiles) < lay.tiles_used[0],
+                          gmm.DEFAULT_TILE_ROWS)[:, None]
+
     def gmm_loss(use_pallas):
         def loss(x, w, lay, cot):
-            out = gmm.grouped_matmul(x, w, lay, use_pallas=use_pallas)
+            live = live_rows(lay)
+            out = jnp.where(live, gmm.grouped_matmul(
+                jnp.where(live, x, 0), w, lay, use_pallas=use_pallas), 0)
             return jnp.sum(out.astype(f32) * cot), out
         return loss
 
@@ -692,7 +704,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
             jax.random.fold_in(root_key, 120))
     cot_t, cot_g = cots[:rows_m], cots[rows_m:]     # of the tokens, of the rows
     # the kernels leave the tiles past the live ones undefined: compared as 0
-    live = (jnp.arange(cap_r) // tile < routing_r.layout.tiles_used[0])[:, None]
+    live = live_rows(routing_r.layout)
     rows_r = jax.jit(lambda x: jnp.where(
         live, moe._rows_from_tokens(x, routing_r, None), 0))(xr)
 
@@ -728,6 +740,66 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
             if name == "combine_grad" and j == 0:      # d_rows: live tiles
                 g, o = (jnp.where(live, t, 0) for t in (g, o))
             _compare(f"moe_rows_at_2560.{name}.{j}", g, o, 1e-4, parity)
+
+    # the grouped product at moonlight.train-8k's two calls — 8 experts held,
+    # 6 slots a token over 8 contexts, gate|up 2048 x 2816 and down 1408 x
+    # 2048 — in the buffer the expert layer sizes for the worst case (6 * s
+    # rows live in 28 tiles of 200) and in one that ends with the last group
+    # (the same 28 tiles, all live): forward, dx and dw each a program of its
+    # own, us a call, held to ragged_dot on the live tiles.  The dead tiles of
+    # x and of the cotangent hold NaN: whatever read them would show
+    sizes_w = [0, 1] + [6 * s * part // 6144 for part in
+                        (700, 900, 768, 1300, 1200, 1275)]
+    tiles_w = sum(max(1, -(-z // tile)) for z in sizes_w)
+    timed_g = facts["grouped_mm_at_cell"] = {
+        "sizes": sizes_w, "tiles_live": tiles_w, "mosaic_calls": {}}
+
+    def grouped_passes(use_pallas):
+        def mm(x, w, lay):
+            return gmm.grouped_matmul(x, w, lay, use_pallas=use_pallas)
+        return {"fwd": lambda x, w, lay, g: mm(x, w, lay),
+                "dx": lambda x, w, lay, g: jax.vjp(
+                    lambda x: mm(x, w, lay), x)[1](g)[0],
+                "dw": lambda x, w, lay, g: jax.vjp(
+                    lambda w: mm(x, w, lay), w)[1](g)[0]}
+
+    for i, (product, c_g, n_g) in enumerate((("gate_up", 2 * s, 11 * s // 4),
+                                             ("down", 11 * s // 8, 2 * s))):
+        for buffer, cap_g in (
+                ("worst_case", gmm.rows_capacity(6 * 8 * s, len(sizes_w))),
+                ("exact", tiles_w * tile)):
+            lay_g = jax.jit(lambda z: gmm.group_layout(z, cap_g))(
+                jnp.asarray(sizes_w, jnp.int32))
+            live = live_rows(lay_g)
+            xg_, wg_, gg_ = jax.jit(lambda key: [
+                jnp.where(keep, normal(ki, shape, f32) * scale,
+                          jnp.nan).astype(bf16)
+                for ki, shape, scale, keep in zip(
+                    jax.random.split(key, 3),
+                    ((cap_g, c_g), (len(sizes_w), c_g, n_g), (cap_g, n_g)),
+                    (0.5, 0.05, 1.0), (live, True, live))
+            ])(jax.random.fold_in(root_key, 130 + i))
+            args_g = (xg_, wg_, lay_g, gg_)
+            with jax.default_matmul_precision("highest"):
+                refs = jax.jit(lambda *a: {
+                    name: fn(*a) for name, fn in grouped_passes(False).items()
+                })(xg_.astype(f32), wg_.astype(f32), lay_g, gg_.astype(f32))
+            rec = timed_g[f"{product}.{buffer}"] = {
+                "shape": [cap_g, c_g, n_g], "tiles": cap_g // tile}
+            for name, fn in grouped_passes(None).items():
+                compiled = jax.jit(fn).lower(*args_g).compile()
+                key_g = f"{product}.{buffer}.{name}"
+                _require_mosaic(compiled, 1, timed_g["mosaic_calls"], key_g)
+                names = mosaic_call_names(compiled.as_text())
+                _require(len(names) <= 1,
+                         f"{key_g}: timed with another pass: {names}")
+                rec[f"{name}_us"] = us_a_call(compiled, args_g)
+                got = compiled(*args_g)
+                if name != "dw":
+                    got, refs[name] = (jnp.where(live, t, 0)
+                                       for t in (got, refs[name]))
+                _compare(f"grouped_mm_at_cell.{key_g}", got, refs[name],
+                         2e-2, parity)
 
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 

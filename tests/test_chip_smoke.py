@@ -85,6 +85,18 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     for name in ("gather", "gather_grad", "combine", "combine_grad"):
         assert {f"{name}_kernels_us", f"{name}_take_us"} <= set(rows)
         assert f"moe_rows_at_2560.{name}.0" in kernels["parity"]
+    # the grouped products at a cell's two calls, in the worst-case buffer
+    # and in one with no dead tail: every pass timed alone, held to ragged_dot
+    at_cell = kernels["grouped_mm_at_cell"]
+    assert at_cell["gate_up.worst_case"]["tiles"] >= 4 * at_cell["tiles_live"]
+    assert at_cell["down.exact"]["tiles"] == at_cell["tiles_live"]
+    for product in ("gate_up", "down"):
+        for buffer in ("worst_case", "exact"):
+            assert {"fwd_us", "dx_us", "dw_us"} <= set(
+                at_cell[f"{product}.{buffer}"])
+            for name in ("fwd", "dx", "dw"):
+                assert (f"grouped_mm_at_cell.{product}.{buffer}.{name}"
+                        in kernels["parity"])
     assert train["loss_per_window"][-1] < train["loss_per_window"][0]
     assert train["compiles_after_first_window"] == 0
     assert serve["compiles_after_warmup"] == 0

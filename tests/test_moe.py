@@ -282,3 +282,41 @@ def test_row_movement_is_one_form_at_whole_tile_records_and_off_them(d):
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), rtol=2e-2, atol=1e-3)
+
+
+@pytest.mark.parametrize("unit", ["silu", "relu"])
+def test_whole_layer_on_the_kernels_never_reads_the_dead_tail(unit):
+    """``ExpertShardMLP`` holding an eighth of the experts, so that its
+    worst-case row buffer is >= 8x the rows the routing makes live, through
+    every kernel (row movement and grouped products, interpreted: a block no
+    grid step wrote reads NaN, a tripwire for any reader of the tiles past
+    ``layout.tiles_used``) against the ``jnp.take`` / ``ragged_dot`` path:
+    loss, dx and every parameter's gradient equal and finite."""
+    from apex_tpu.ops import grouped_mm as gmm
+    from apex_tpu.ops._common import force_pallas
+
+    t, d, k, tile = 64, 128, 4, 16
+    layer = ExpertShardMLP(num_experts=32, experts_held=(4, 8), d_ff=128, k=k,
+                           score_func="softmax", unit_func=unit, tile_rows=tile)
+    kx, kp, kc = jax.random.split(jax.random.PRNGKey(38), 3)
+    x = jax.random.normal(kx, (t, d))
+    params = layer.init(kp, x)["params"]
+    cot = jax.random.normal(kc, (t, d))
+    sel = jax.lax.top_k(jnp.matmul(x, params["router"], precision="highest"), k)[1]
+    live_rows = int(jnp.sum((sel >= 4) & (sel < 8)))
+    assert 0 < 8 * live_rows <= gmm.rows_capacity(t * k, 4, tile)
+
+    loss = jax.value_and_grad(
+        lambda p, x: jnp.sum(layer.apply({"params": p}, x) * cot), (0, 1))
+    with force_pallas(True):
+        text = str(jax.make_jaxpr(loss)(params, x))
+        got = loss(params, x)
+    for kernel in ("apex_gmm", "apex_gmm_dw", "apex_moe_records",
+                   "apex_moe_gather", "apex_moe_combine"):
+        assert kernel in text
+    with force_pallas(False):
+        want = loss(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(a).all() and np.asarray(b).any()
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
