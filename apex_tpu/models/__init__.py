@@ -4,7 +4,7 @@ These are the models the reference's examples and kernels exist to serve
 (SURVEY.md §6 benchmark configs): ResNet-50 (imagenet amp O0-O3 + DDP +
 SyncBN), BERT-large (FusedLAMB + fused attention + xentropy), DCGAN
 (multi-model multi-loss-scaler amp), a simple MLP (the minimum
-end-to-end slice), and the five decoders: GPT-2 (``gpt.py``), the Arcee
+end-to-end slice), and the six decoders: GPT-2 (``gpt.py``), the Arcee
 Trinity block with sigmoid-routed experts (``afmoe.py``, training path), the
 Qwen3-Next block — gated-delta-rule linear attention beside gated full
 attention, softmax-routed experts (``qwen3_next.py``, training path) — and
@@ -13,7 +13,11 @@ the DeepSeek-V3 block as Moonlight publishes it — multi-head latent attention
 than keys) and bias-steered sigmoid-routed experts (``deepseek_v3.py``,
 training path) — and the SmallThinker block — a router that reads the block's
 input ahead of attention, ReGLU experts with no shared one, a position-free
-full layer before rotary window layers (``smallthinker.py``, training path).
+full layer before rotary window layers (``smallthinker.py``, training path) —
+and the LFM2 block — a gated short convolution in place of attention in three
+layers of four, grouped-query attention with normed queries and keys in the
+fourth, bias-steered sigmoid experts with none shared, the head tied to the
+embedding (``lfm2.py``, training path).
 """
 from apex_tpu.models.resnet import ResNet, resnet50, resnet101, resnet152  # noqa: F401
 from apex_tpu.models.bert import (  # noqa: F401
@@ -40,4 +44,5 @@ from apex_tpu.models.smallthinker import (  # noqa: F401
     SmallThinkerLayer,
     SmallThinkerLM,
 )
+from apex_tpu.models.lfm2 import Lfm2Config, Lfm2Layer, Lfm2LM  # noqa: F401
 from apex_tpu.mlp import MLP  # noqa: F401

@@ -1,0 +1,244 @@
+"""LFM2 decoder LM (Liquid AI's ``lfm2_moe`` family) on the training path.
+
+The sixth decoder block of the zoo (``models/gpt.py``, ``afmoe.py``,
+``qwen3_next.py``, ``deepseek_v3.py``, ``smallthinker.py`` are the others):
+two RMSNorms a block in pre-norm position and TWO KINDS OF MIXER under one
+residual scheme, chosen by ``layer_types`` — a GATED SHORT CONVOLUTION
+(``conv``: no attention, no state beyond the last ``K - 1`` inputs; three
+layers of four at the published sizes) and grouped-query flash attention with
+per-head RMS norms on q and k and rotary positions (``full_attention``).  The
+feed-forward is a dense SwiGLU in the leading ``num_dense_layers`` and, past
+them, sigmoid-routed experts under a selection bias with NO shared expert
+(``parallel/moe.py::ExpertShardMLP``).  The head is TIED to the embedding.
+
+Block ``i`` on ``h`` (T, d); no bias anywhere; RMSNorm ``x * rsqrt(mean(x^2)
++ eps) * w`` in float32::
+
+    y = RMS_op(h)
+    conv layer:       [B | C | X] = y W_in                   # (T, 3 d), thirds in this order
+                      u = B * X
+                      c_t = sum_{j<K} w[:, j] * u_{t-(K-1)+j}   # depthwise, zeros before the row's start
+                      a = (C * c) W_out
+    attention layer:  q, k, v = y W_q, y W_k, y W_v          # H, H_kv, H_kv heads of D
+                      q, k = RMS_q(q), RMS_k(k)              # per head over its D dims
+                      q, k = rotary(q), rotary(k)            # whole head, rotate_half
+                      a = softmax(q k^T / sqrt(D), causal) v W_o
+    h = h + a
+    z = RMS_ffn(h)
+    i < num_dense_layers:  h = h + W_2 (silu(W_1 z) * W_3 z)
+    else:  s = sigmoid(z W_r) over ALL experts, float32;  e = top_k(s + b)
+           g = s_e / (sum s_e + 1e-20) * route_scale
+           h = h + sum_j g_j W_2[e_j] (silu(W_1[e_j] z) * W_3[e_j] z)
+    logits = E^T RMS_final(h)
+
+The gated convolution is ``ops/gated_conv.py::gated_short_conv``: on the TPU
+one kernel pass over ``W_in``'s output where it lies, forward and backward
+(float32 taps and gates, one rounding at the output).  ``b`` enters the
+selection only and takes no gradient; its loss-free balancing update is no
+part of a step made of gradients (as ``models/deepseek_v3.py``).
+
+Left out: any router auxiliary loss (the step is the plain causal-LM loss),
+the selection bias's update, rotary scaling (the declared positions are
+native).
+
+Called as :class:`apex_tpu.models.gpt.GPTLM` and the other decoders are:
+``model.apply({"params": p}, ids, labels=labels, deterministic=...)`` ->
+``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a
+sliced ``vocab_size``, as in ``models/afmoe.py`` — the slice is of the
+embedding's rows, and so of the tied head's columns.  Scopes ``conv_proj``
+(``W_in``), ``conv_mix`` (the gated convolution alone), ``conv_out``
+(``W_out``), ``attn_full`` (the flash call), ``dense_ffn``, the three
+``moe_*``, ``embed``, ``lm_head``, ``lm_loss``.  Under ``remat_policy``
+``full_block`` an attention layer keeps its input and the flash kernel's
+output and ``lse``, a convolution layer its input alone: the gated
+convolution runs again in the backward pass.  Serving methods are not part
+of this model yet: a convolution layer's last ``K - 1`` inputs are a second
+kind of per-sequence state beside K/V pages (ROADMAP M6).
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.amp import functional as F
+from apex_tpu.amp.layers import Dense
+from apex_tpu.models.afmoe import RMSNorm, rotary
+from apex_tpu.ops.attention import flash_attention
+from apex_tpu.ops.gated_conv import gated_short_conv
+from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
+from apex_tpu.parallel.moe import ExpertShardMLP, SwiGLU
+from apex_tpu.remat import remat_module
+
+__all__ = ["Lfm2Config", "Lfm2Layer", "Lfm2LM", "ShortConv"]
+
+CONV, FULL = "conv", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2Config:
+    vocab_size: int = 8192            # the slice held (a multiple of 128)
+    hidden_size: int = 2048
+    layer_types: Tuple[str, ...] = (CONV, FULL, CONV, CONV, CONV)
+    num_dense_layers: int = 1
+    conv_L_cache: int = 3             # the convolution's taps, K
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int = 64
+    rope_theta: float = 1e6
+    intermediate_size: int = 11776    # the dense layers' MLP
+    moe_intermediate_size: int = 1536  # one expert
+    num_experts: int = 64             # routed over
+    experts_held: Tuple[int, int] = (0, 8)
+    num_experts_per_tok: int = 4
+    route_norm: bool = True
+    route_scale: float = 1.0
+    norm_eps: float = 1e-5
+    initializer_range: float = 0.02
+    # activation rematerialization per block (apex_tpu.remat): full_block
+    # keeps a block's input and, in an attention layer, the flash kernel's
+    # output and lse
+    remat_policy: str = "none"
+    compute_dtype: Any = jnp.bfloat16
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @staticmethod
+    def tiny(**kw) -> "Lfm2Config":
+        """For tests: every mechanism at toy widths — a dense convolution
+        layer, an attention layer with four query heads a key/value head, an
+        expert convolution layer, a strict subset of the experts held."""
+        base = dict(
+            vocab_size=256, hidden_size=128, layer_types=(CONV, FULL, CONV),
+            num_dense_layers=1, num_heads=8, num_kv_heads=2, head_dim=64,
+            intermediate_size=256, moe_intermediate_size=128, num_experts=16,
+            experts_held=(0, 4), num_experts_per_tok=4)
+        base.update(kw)
+        return Lfm2Config(**base)
+
+
+class ShortConv(nn.Module):
+    """The convolution layers' mixer: ``(C * conv_K(B * X)) W_out`` with
+    ``[B | C | X] = x W_in``.  Parameters ``in_proj`` (d, 3 d), ``taps`` (d,
+    K) — a channel's tap ``j`` multiplies the input ``K - 1 - j`` tokens
+    back — and ``out_proj`` (d, d)."""
+
+    cfg: Lfm2Config
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        d, dt = x.shape[-1], cfg.compute_dtype
+        init = nn.initializers.normal(cfg.initializer_range)
+        dense = lambda n, name: Dense(n, use_bias=False, dtype=dt,
+                                      kernel_init=init, name=name)
+        with jax.named_scope("conv_proj"):
+            bcx = dense(3 * d, "in_proj")(x)
+        taps = self.param("taps", init, (d, cfg.conv_L_cache), jnp.float32)
+        with jax.named_scope("conv_mix"):
+            mixed = gated_short_conv(bcx, taps)
+        with jax.named_scope("conv_out"):
+            return dense(d, "out_proj")(mixed)
+
+
+class Lfm2Layer(nn.Module):
+    """One block; ``index`` picks its mixer (``cfg.layer_types``) and its
+    feed-forward (dense below ``cfg.num_dense_layers``)."""
+
+    cfg: Lfm2Config
+    index: int
+
+    @nn.compact
+    def __call__(self, x, deterministic: bool = True):
+        del deterministic           # no dropout in this family
+        cfg = self.cfg
+        b, s, d = x.shape
+        hq, hk, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        dt = cfg.compute_dtype
+        init = nn.initializers.normal(cfg.initializer_range)
+        norm = lambda name: RMSNorm(cfg.norm_eps, dt, name=name)
+
+        y = norm("operator_norm")(x)
+        if cfg.layer_types[self.index] == CONV:
+            x = x + ShortConv(cfg, name="conv")(y)
+        else:
+            qkv = Dense((hq + 2 * hk) * hd, use_bias=False, dtype=dt,
+                        kernel_init=init, name="qkv")(y)
+            q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
+            heads = lambda t, n: t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
+            q = rotary(norm("q_norm")(heads(q, hq)), cfg.rope_theta)
+            k = rotary(norm("k_norm")(heads(k, hk)), cfg.rope_theta)
+            with jax.named_scope("attn_full"):
+                attn = flash_attention(q, k, heads(v, hk), causal=True)
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
+            x = x + Dense(d, use_bias=False, dtype=dt, kernel_init=init,
+                          name="o_proj")(attn)
+
+        z = norm("pre_mlp_norm")(x)
+        if self.index < cfg.num_dense_layers:
+            with jax.named_scope("dense_ffn"):
+                return x + SwiGLU(cfg.intermediate_size, dt, init,
+                                  name="mlp")(z)
+        ff = ExpertShardMLP(
+            num_experts=cfg.num_experts, experts_held=cfg.experts_held,
+            d_ff=cfg.moe_intermediate_size, k=cfg.num_experts_per_tok,
+            route_norm=cfg.route_norm, route_scale=cfg.route_scale,
+            score_func="sigmoid", compute_dtype=dt, kernel_init=init,
+            name="moe",
+        )(z.reshape(b * s, d)).reshape(b, s, d)
+        return x + ff
+
+
+class Lfm2LM(nn.Module):
+    """Embedding, the blocks ``layer_<i>``, a final RMSNorm and the head TIED
+    to the embedding.  ``__call__(ids)`` returns (B, S, V) float32 logits;
+    with ``labels`` (-100: not predicted) also the token-mean
+    fused-xentropy loss, as :class:`apex_tpu.models.gpt.GPTLM` does."""
+
+    cfg: Lfm2Config
+
+    def setup(self):
+        cfg = self.cfg
+        for kind in cfg.layer_types:
+            if kind not in (CONV, FULL):
+                raise ValueError(f"no layer type {kind!r}")
+        if not 0 <= cfg.num_dense_layers <= cfg.num_layers:
+            raise ValueError(f"num_dense_layers {cfg.num_dense_layers} of "
+                             f"{cfg.num_layers} layers")
+        init = nn.initializers.normal(cfg.initializer_range)
+        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+                              embedding_init=init, dtype=jnp.float32)
+        # deterministic is static_argnum 2 (self=0): called positionally
+        layer_cls = remat_module(Lfm2Layer, cfg.remat_policy,
+                                 static_argnums=(2,))
+        self.layers = [layer_cls(cfg, i, name=f"layer_{i}")
+                       for i in range(cfg.num_layers)]
+        self.norm_f = RMSNorm(cfg.norm_eps, cfg.compute_dtype)
+
+    def __call__(self, input_ids, labels=None, deterministic: bool = True):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        with jax.named_scope("embed"):
+            x = self.embed(input_ids).astype(dt)
+        for layer in self.layers:
+            x = layer(x, deterministic)
+        x = self.norm_f(x)
+        with jax.named_scope("lm_head"):
+            # the tied head: the embedding's rows are the head's columns
+            logits = F.matmul(x.astype(dt), self.embed.embedding.T.astype(dt),
+                              preferred_element_type=jnp.float32)
+        if labels is None:
+            return logits
+        with jax.named_scope("lm_loss"):
+            valid = labels >= 0
+            safe = jnp.where(valid, labels, 0)
+            # compute-dtype logits into the fused loss, as GPTLM
+            per_tok = softmax_cross_entropy(logits.astype(dt), safe)
+            n = jnp.maximum(jnp.sum(valid), 1)
+            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
+        return logits, loss

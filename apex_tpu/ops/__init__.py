@@ -10,6 +10,9 @@ TPU-native equivalents of the reference's CUDA kernel zoo (SURVEY.md §2.2):
   layer's grouped products and row movement (no reference counterpart)
 - :mod:`apex_tpu.ops.gated_delta` — the gated delta rule by chunks, the
   first sequential operator (no reference counterpart)
+- :mod:`apex_tpu.ops.gated_conv` — the gated short convolution ``C *
+  conv(B * X)``, read out of its projection's output in one kernel pass (no
+  reference counterpart)
 - :mod:`apex_tpu.ops.conv_bn` — fused matmul+BN-stats / BN-apply+matmul
   building blocks (ref groupbn/welford fused epilogues; library-only, see
   the module docstring for the measured RN50 verdict)
@@ -27,5 +30,6 @@ from apex_tpu.ops.softmax_xentropy import (  # noqa: F401
 from apex_tpu.ops.attention import attention_ref, flash_attention  # noqa: F401
 from apex_tpu.ops.grouped_mm import grouped_matmul  # noqa: F401
 from apex_tpu.ops.gated_delta import causal_conv1d_silu, gated_delta_rule  # noqa: F401
+from apex_tpu.ops.gated_conv import gated_short_conv, gated_short_conv_ref  # noqa: F401
 from apex_tpu.ops.mlp import mlp, mlp_ref  # noqa: F401
 from apex_tpu.ops.conv_bn import bn_relu_matmul, matmul_stats  # noqa: F401

@@ -76,6 +76,8 @@ KERNEL_NAMES = (
     "apex_gdn_bwd",
     "apex_conv1d_fwd",
     "apex_conv1d_bwd",
+    "apex_gated_conv_fwd",
+    "apex_gated_conv_bwd",
 )
 
 

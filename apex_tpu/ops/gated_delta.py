@@ -71,6 +71,10 @@ and the ``K`` shifted float32 passes that :func:`causal_conv1d_silu` costs on
 the chip (29.96 ms a step of ``qwen3-next.train-8k`` for 3.4 ms of bytes,
 PERF.md section 6, PR 33) exist only off the TPU.
 
+(The library's OTHER short convolution — a gate in front and a gate behind,
+no SiLU, a projection laid out in thirds — is ``ops/gated_conv.py``, which
+imports this one's row blocks, halo and taps.)
+
 **Off the TPU, and as the kernels' oracle,** what is local to a chunk is
 computed for all chunks at once by batched products (:func:`_chunk_local`,
 plain ``jax.numpy``, differentiated by JAX; ``T`` by :func:`tri_inverse`) and

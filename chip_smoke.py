@@ -16,7 +16,9 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   of a call of each (``moe_rows_at_2560``); and the grouped products at
   Moonlight's two calls in the expert layer's worst-case buffer and in one
   with no dead tail, forward, ``dx`` and ``dw`` each timed alone
-  (``grouped_mm_at_cell``);
+  (``grouped_mm_at_cell``); and the gated short convolution at the LFM2
+  cell's call, its two kernels against the ``jax.numpy`` form, forward and
+  gradient timed on both paths (``gated_conv_at_cell``);
 - ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, with
   dropout on: three windows on a fixed seeded batch;
 - ``serve`` — the params that phase produced, through ``GPTDecoder`` +
@@ -335,7 +337,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 "moe_dispatch": [rows, -(-n // 1024) * 1024],
                 "gated_delta": [1, 8 * s, [s // 64, s // 32], 128],
                 "flash_latent": [1, s // 64, 8 * s, [192, 128]],
-                "conv1d": [1, 8 * s, s // 64 * 768, 4]},
+                "conv1d": [1, 8 * s, s // 64 * 768, 4],
+                "gated_conv": [1, 16 * s, 3 * 2 * s, 3]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -682,6 +685,37 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                                         ("smallthinker_full", None))):
         backward_routes(name, 7 * hs, hs, 128, 128, window,
                         jax.random.fold_in(root_key, 110 + i), seq=2 * sw, n=3)
+
+    # the gated short convolution at lfm2.train-16k's call — a row of 16
+    # contexts of a convolution layer's in_proj output, [B | C | X] three
+    # times 2 * ctx wide in bfloat16, 3 taps: the two kernels against the
+    # jax.numpy form, forward and both gradients held to it, us a call of
+    # the forward and of the gradient program on each path
+    from apex_tpu.ops.gated_conv import gated_short_conv
+
+    shape_c = (1, 16 * s, 3 * 2 * s)
+    make_c = lambda kx, kw_, kc: (
+        normal(kx, shape_c, f32).astype(bf16), 0.5 * normal(kw_, (2 * s, 3), f32),
+        normal(kc, (1, 16 * s, 2 * s), f32).astype(bf16))
+    xg, wg, cot_c = jax.jit(lambda key: make_c(*jax.random.split(key, 3)))(
+        jax.random.fold_in(root_key, 140))
+    cot_c = cot_c.astype(f32)       # a cotangent bfloat16 holds, as conv1d's
+
+    def gated_loss(use_pallas):
+        def loss(x, w, cot):
+            out = gated_short_conv(x, w, use_pallas=use_pallas)
+            return jnp.sum(out.astype(f32) * cot), out
+        return loss
+
+    run("gated_conv", gated_loss(None), gated_loss(False), (xg, wg, cot_c),
+        (xg.astype(f32), wg, cot_c), 2, (1e-2, (("dx", 1e-2), ("dw", 1e-3))))
+    timed_c = facts["gated_conv_at_cell"] = {"shape": [*shape_c, 3]}
+    for side, use_pallas in (("kernels", None), ("jnp", False)):
+        fwd = jax.jit(lambda x, w: gated_short_conv(x, w, use_pallas=use_pallas))
+        grad = jax.jit(jax.grad(
+            lambda *a: gated_loss(use_pallas)(*a)[0], (0, 1)))
+        timed_c[f"fwd_{side}_us"] = us_a_call(fwd, (xg, wg))
+        timed_c[f"grad_{side}_us"] = us_a_call(grad, (xg, wg, cot_c))
 
     # the expert layer's row movement at smallthinker.train-16k's shape —
     # twice the LayerNorm rows x 2560 bfloat16 (a record 20 sublanes: two and

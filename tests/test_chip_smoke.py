@@ -64,7 +64,8 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
         assert ln["cache"]["from_env"] is True
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
-        "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d"}
+        "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d",
+        "gated_conv"}
     # the backward's two routes at the three 8k cells' calls, timed and held
     # to each other and to the reference
     routes = kernels["flash_backward"]
@@ -97,6 +98,15 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
             for name in ("fwd", "dx", "dw"):
                 assert (f"grouped_mm_at_cell.{product}.{buffer}.{name}"
                         in kernels["parity"])
+    # the gated short convolution at the LFM2 cell's call: both routes (the
+    # kernels, the jax.numpy form) timed forward and with gradients, and the
+    # kernels held to the form
+    gated = kernels["gated_conv_at_cell"]
+    assert gated["shape"] == [1, 16 * TINY.ctx, 6 * TINY.ctx, 3]
+    assert {"fwd_kernels_us", "grad_kernels_us", "fwd_jnp_us",
+            "grad_jnp_us"} <= set(gated)
+    for name in ("fwd", "dx", "dw"):
+        assert f"gated_conv.{name}" in kernels["parity"]
     assert train["loss_per_window"][-1] < train["loss_per_window"][0]
     assert train["compiles_after_first_window"] == 0
     assert serve["compiles_after_warmup"] == 0

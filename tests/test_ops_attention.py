@@ -900,6 +900,38 @@ def test_window_and_grouped_heads_match_ref(hq, hkv, s, window, bq, bk,
         np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
 
 
+@_ROUTES
+def test_32_on_8_heads_of_64_with_normed_queries_and_keys_match_ref(two_pass):
+    """``models/lfm2.py``'s call: 32 query heads on 8 key/value heads of 64
+    — half a lane tile, groups of 4 —, q and k RMS-normed over the head and
+    rotated upstream of the kernel, gradients taken THROUGH the norms to the
+    projections' outputs, on both backward routes."""
+    from apex_tpu.models.afmoe import rotary
+
+    q, k, v, do = _window_case(32, 8, 256)
+    scale = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(9), (2, 64))
+
+    def normed(t, g):
+        t = t * jax.lax.rsqrt(jnp.mean(jnp.square(t), -1, keepdims=True) + 1e-5)
+        return rotary(t * g, 1e6)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(
+            normed(q, scale[0]), normed(k, scale[1]), v) * do)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128, use_pallas=True)
+    ref = lambda q, k, v: _ref(q, k, v, causal=True)
+    np.testing.assert_allclose(
+        flash(normed(q, scale[0]), normed(k, scale[1]), v),
+        ref(normed(q, scale[0]), normed(k, scale[1]), v), atol=2e-5)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    assert got[1].shape == (1, 8, 256, 64)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+
 def test_window_with_dropout_and_grouped_heads(monkeypatch):
     """The dropout hash is keyed on the QUERY head on every route, so the
     kernel's mask is the reference's."""

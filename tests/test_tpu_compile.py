@@ -169,6 +169,8 @@ def _named_case(kernel):
         return _gdn_case()
     if kernel.startswith("apex_conv1d_"):
         return _conv_case()
+    if kernel.startswith("apex_gated_conv_"):
+        return _gated_conv_case()
     if kernel.startswith("apex_xent_"):
         from apex_tpu.ops import softmax_cross_entropy
 
@@ -213,6 +215,21 @@ def _conv_case():
             [((1, 8192, 12288), BF16), ((8192, 4), F32)])
 
 
+def _gated_conv_case():
+    """The gated short convolution as ``lfm2.train-16k`` calls it: a
+    convolution layer's ``in_proj`` output of one 16,384-token row, ``[B | C
+    | X]`` 6144 wide in bfloat16, 3 taps over 2048 channels
+    (ops/gated_conv.py), forward and backward."""
+    from apex_tpu.ops.gated_conv import gated_short_conv
+
+    def loss(bcx, w):
+        with jax.named_scope("conv_mix"):
+            return jnp.sum(gated_short_conv(bcx, w).astype(F32) ** 2)
+
+    return (jax.grad(loss, argnums=(0, 1)),
+            [((1, 16384, 6144), BF16), ((2048, 3), F32)])
+
+
 _NAMES_OF_CASE = {}
 
 
@@ -225,6 +242,7 @@ _NAMES_OF_CASE = {}
     "apex_moe_records", "apex_moe_gather", "apex_moe_combine",
     "apex_moe_combine_dw", "apex_gdn_fwd", "apex_gdn_bwd",
     "apex_conv1d_fwd", "apex_conv1d_bwd",
+    "apex_gated_conv_fwd", "apex_gated_conv_bwd",
 ])
 def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     """The custom call's HLO instruction — what a device trace names the
@@ -233,9 +251,11 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     from apex_tpu.ops._common import KERNEL_NAMES
 
     assert kernel in KERNEL_NAMES
-    # (the four apex_moe_* kernels are one program, the two apex_gdn_* and
-    # the two apex_conv1d_* two more: each compiled once)
-    case = next((f for f in ("apex_moe_", "apex_gdn_", "apex_conv1d_")
+    # (the four apex_moe_* kernels are one program, the two apex_gdn_*, the
+    # two apex_conv1d_* and the two apex_gated_conv_* three more: each
+    # compiled once)
+    case = next((f for f in ("apex_moe_", "apex_gdn_", "apex_conv1d_",
+                             "apex_gated_conv_")
                  if kernel.startswith(f)), kernel)
     if case not in _NAMES_OF_CASE:
         fn, avals = _named_case(kernel)
@@ -458,6 +478,51 @@ def test_flash_groups_of_seven_at_16k_compile(chip, as_tpu, window):
         chip, "attn_window" if window else "attn_full", q, kv, kv, window)
     assert names == _ONE_SWEEP, names
     assert widths == [[128] * 4, [128] * 7], widths
+
+
+def test_flash_groups_of_four_of_size_64_at_16k_compile(chip, as_tpu):
+    """LFM2's attention call: 32 query heads to 8 key/value heads of size 64
+    — half a lane tile, where every other grouped cell runs 128 or more — at
+    16,384 positions: key/value blocks read through ``h // 4`` and ONE
+    backward kernel with dk/dv of a key/value head (two (16384, 64) float32
+    accumulators, which VMEM holds in whole 128-lane tiles: 16.8 MB, what a
+    head of 128 takes) resident in VMEM and summed over the group's four
+    query heads."""
+    from apex_tpu.ops.attention import (
+        _SWEEP_ACC_BUDGET_BYTES, _sweep_acc_bytes)
+
+    assert _sweep_acc_bytes(16384, 64, 64) == 2 ** 24 \
+        <= _SWEEP_ACC_BUDGET_BYTES
+    q, kv = (1, 32, 16384, 64), (1, 8, 16384, 64)
+    names, widths = _flash_calls(chip, "attn_full", q, kv, kv)
+    assert names == _ONE_SWEEP, names
+    assert widths == [[64] * 4, [64] * 7], widths
+
+
+def test_gated_conv_reads_and_writes_the_projection_in_place(chip, as_tpu):
+    """At the cell's shape the gated convolution's program is ONE
+    ``apex_gated_conv_fwd`` and ONE ``apex_gated_conv_bwd`` and nothing
+    beside them touches an array of the projection's size: the kernels read
+    B, C and X out of ``bcx`` itself and write ``[dB | dC | dX]`` as one
+    array — no slice, no concatenation, no float32 copy — and XLA is left
+    the taps' transposes (2048 x 3)."""
+    import re
+
+    from apex_tpu.ops._common import mosaic_call_names
+
+    fn, avals = _gated_conv_case()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in avals]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+    assert names == ["apex_gated_conv_fwd", "apex_gated_conv_bwd"], names
+    # nothing beside the kernels moves an array of a third's size or more:
+    # no slice of a third, no concatenation of the gradient, no padded copy
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(
+        r"= (?:bf16|f32)\[1,163\d\d,(?:2048|6144)\]\S* "
+        r"(?:copy|slice|concatenate|pad|dynamic-update-slice)\(", entry)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 16384 * 2048 * 4
 
 
 def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):

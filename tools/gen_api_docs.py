@@ -39,6 +39,7 @@ MODULES = [
     "apex_tpu.ops.softmax_xentropy",
     "apex_tpu.ops.mlp",
     "apex_tpu.ops.conv_bn",
+    "apex_tpu.ops.gated_conv",
     "apex_tpu.ops.fused_optim",
     "apex_tpu.parallel.distributed",
     "apex_tpu.parallel.sync_batchnorm",
@@ -69,6 +70,7 @@ MODULES = [
     "apex_tpu.models.bert",
     "apex_tpu.models.gpt",
     "apex_tpu.models.dcgan",
+    "apex_tpu.models.lfm2",
     "apex_tpu.serve.kv_cache",
     "apex_tpu.serve.decode",
     "apex_tpu.serve.engine",
