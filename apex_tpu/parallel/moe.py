@@ -39,6 +39,9 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
+
+from apex_tpu.remat import MOE_PLAN, MOE_SEL
 
 __all__ = ["MoEMLP", "top_k_routing", "moe_mlp_ref", "ExpertShardMLP",
            "SwiGLU", "sigmoid_topk_routing", "softmax_topk_routing",
@@ -205,6 +208,22 @@ def moe_mlp_ref(x, params, num_experts, k, activation=nn.gelu):
 # What the experts it does not hold would have added is simply not in its
 # result: on one chip there is no exchange, and nothing stands in for one.
 
+def _selected(values, k: int):
+    """``(T, k)`` int32: the experts of the ``k`` largest ``values`` a token,
+    named ``MOE_SEL`` — a block that is recomputed reads the kept
+    selection and does not make ``top_k`` again."""
+    _, sel = jax.lax.top_k(values, k)
+    return checkpoint_name(sel.astype(jnp.int32), MOE_SEL)
+
+
+def _picked(values, sel):
+    """``values[t, sel[t, j]]`` as a masked sum over the expert axis: one
+    non-zero term a slot, so the float a gather would read, to the bit — and
+    its transpose is a masked broadcast where a gather's is a scatter."""
+    hit = sel[..., None] == jnp.arange(values.shape[-1], dtype=sel.dtype)
+    return jnp.sum(jnp.where(hit, values[:, None, :], 0), axis=-1)
+
+
 def sigmoid_topk_routing(logits, bias, k: int, route_norm: bool,
                          route_scale: float):
     """``(sel (T, k) int32, weights (T, k) float32)``: sigmoid scores over
@@ -212,11 +231,11 @@ def sigmoid_topk_routing(logits, bias, k: int, route_norm: bool,
     bias steers the selection only); the weights are the selected SCORES,
     normalised to sum to one where ``route_norm``, times ``route_scale``."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
-    w = jnp.take_along_axis(scores, sel, axis=-1)
+    sel = _selected(scores + bias.astype(jnp.float32), k)
+    w = _picked(scores, sel)
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return sel.astype(jnp.int32), w * route_scale
+    return sel, w * route_scale
 
 
 def softmax_topk_routing(logits, k: int, norm_topk_prob: bool):
@@ -224,10 +243,12 @@ def softmax_topk_routing(logits, k: int, norm_topk_prob: bool):
     experts in float32; the ``k`` largest probabilities are selected and
     are the weights, renormalised to sum to one where ``norm_topk_prob``.
     No selection bias, no scale."""
-    w, sel = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), -1), k)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
+    sel = _selected(probs, k)
+    w = _picked(probs, sel)
     if norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return sel.astype(jnp.int32), w
+    return sel, w
 
 
 SCORE_FUNCS = ("sigmoid", "softmax")
@@ -244,8 +265,13 @@ def shard_dispatch(sel, held: Tuple[int, int], capacity: int, tile_rows: int):
     each slot (``capacity`` for a slot whose expert is not held) and the
     flat slot ``t * k + j`` of each row (``T * k`` for a row that holds
     none).  Within an expert the rows keep the slots' order.  No sort by
-    value and no scatter: a running count gives each slot its rank, one
-    argsort of the rows inverts the map."""
+    value, no scatter and, a row, no gather: a running count gives each slot
+    its rank, one argsort of the rows inverts the map, what a row needs of
+    its group (first row, size, first sorted slot) is a sum over the held
+    experts against the one-hot of the row's group, and its slot comes out
+    of a copy of the sorted slots shifted by the group's padding.  (A SLOT
+    reads its group's first row by a gather still: as a sum over the one-hot
+    it was no faster at ``moonlight.train-8k``'s shape, PERF.md section 5.)"""
     from apex_tpu.ops.grouped_mm import group_layout
 
     t, k = sel.shape
@@ -264,12 +290,30 @@ def shard_dispatch(sel, held: Tuple[int, int], capacity: int, tile_rows: int):
         capacity)
     order = jnp.argsort(slot_row)            # held slots first, by row
     row = jnp.arange(capacity, dtype=jnp.int32)
-    group = layout.tile_group[row // tile_rows]
-    within = row - layout.row_start[group]
-    live = (within < sizes[group]) & (row // tile_rows < layout.tiles_used[0])
+    # a row's group is the last whose first row is at or before it (past the
+    # end: the last group): experts leading, the rows along the lanes
+    reached = row[None, :] >= layout.row_start[:, None]
+    in_group = reached & ~jnp.concatenate(
+        [reached[1:], jnp.zeros((1, capacity), bool)])
+    of_group = lambda table: jnp.sum(
+        jnp.where(in_group, table[:, None], 0), axis=0)
+    within = row - of_group(layout.row_start)
+    live = (within < of_group(sizes)) & (
+        row // tile_rows < layout.tiles_used[0])
     first = jnp.cumsum(sizes) - sizes        # a group's first sorted slot
-    row_slot = jnp.where(
-        live, order[jnp.clip(first[group] + within, 0, n - 1)], n)
+    # a group's rows are one run of ``order``, moved by the tiles' padding
+    # before it: a shifted copy a held expert in place of a gather a row (a
+    # scan, not a Python loop: 32 groups unrolled cost seconds of tracing)
+    room = jnp.full((capacity,), n, order.dtype)
+    padded = jnp.concatenate([room, order, room])
+
+    def shifted(row_slot, group):
+        rows_of, moved = group
+        run = jax.lax.dynamic_slice(padded, (capacity - moved,), (capacity,))
+        return jnp.where(rows_of & live, run, row_slot), None
+
+    row_slot, _ = jax.lax.scan(
+        shifted, room, (in_group, layout.row_start - first))
     return layout, slot_row.reshape(t, k), row_slot.astype(jnp.int32)
 
 
@@ -461,6 +505,17 @@ class ExpertShardMLP(nn.Module):
     touch only the tiles that hold rows.  Summed over the shares that
     together hold all experts, the routed parts are the whole layer's.
 
+    The routing plan — the selection and every table of ``_Routing``, all
+    integers — is made ONCE a step: its arrays bear the names
+    ``remat.MOE_SEL`` and ``remat.MOE_PLAN``, which both block-recomputing
+    policies keep (``apex_tpu/remat.py``), so a block run again in the
+    backward pass reads them and makes no ``top_k``, running count,
+    ``argsort`` or layout a second time; what a gradient flows through (the
+    router's product, the scores, the picked weights, the row movement, the
+    grouped products) still runs twice there.  The weights are picked by a
+    masked sum over the experts, whose transpose is a masked broadcast: no
+    gather in the forward pass and no scatter in the backward.
+
     ``score_func`` names the router's scores: ``sigmoid``
     (:func:`sigmoid_topk_routing`: ``route_norm``, ``route_scale`` and the
     selection bias apply) or ``softmax`` (:func:`softmax_topk_routing`: a
@@ -568,9 +623,12 @@ class ExpertShardMLP(nn.Module):
                 sel, weights = softmax_topk_routing(
                     logits, self.k, self.route_norm)
         with jax.named_scope("moe_dispatch"):
-            routing = _route(
-                jax.lax.stop_gradient(sel), (lo, hi), capacity, tile_rows,
-                moe_rows.combine_block(t, self.k, d) if kernels else None)
+            # the plan is integers, < 2 MB a layer: named, a block that is
+            # recomputed reads it kept (remat.py) and makes none of it again
+            routing = jax.tree_util.tree_map(
+                lambda table: checkpoint_name(table, MOE_PLAN), _route(
+                    jax.lax.stop_gradient(sel), (lo, hi), capacity, tile_rows,
+                    moe_rows.combine_block(t, self.k, d) if kernels else None))
             layout = routing.layout
             rows = _rows_from_tokens(x.astype(dt), routing, rows_tile)
         with jax.named_scope("moe_experts"):

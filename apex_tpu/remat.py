@@ -23,7 +23,7 @@ has the trade-off table):
 **Declared residuals.**  A kernel whose result is dear to make again and
 cheap to hold names it with ``jax.ad_checkpoint.checkpoint_name`` under
 an entry of :data:`KEPT_RESIDUAL_NAMES`, and both block-recomputing
-policies keep exactly those.  Today two kernels declare.
+policies keep exactly those.  Today two kernels and one layer declare.
 ``ops/attention.py``'s forward rule names its output (``batch*heads x
 seq x head_dim`` in the compute dtype — the VALUES' head size where that
 is not the keys') and its log-sum-exp (``batch*heads
@@ -39,8 +39,17 @@ kernels' in the compute dtype) — what its backward reads besides q, k, v,
 g, beta, which are cheap to make again from the block's input.  The rule is
 the one SEQUENTIAL thing in a block (128 dependent steps at 8192 tokens,
 ten dependent products a chunk in the inverse): without the names it would
-be walked forward twice.  Outside a ``jax.checkpoint`` a name lowers to
-nothing.
+be walked forward twice.  ``parallel/moe.py::ExpertShardMLP``, no kernel
+but a layer, names its ROUTING PLAN: the selection ``sel`` (``tokens x k``
+int32, under ``apex_moe_sel`` as the router picks it, so the picked
+weights are read through the kept selection too) and every table of
+``_Routing`` (``apex_moe_plan``: ``slot_row`` ``tokens x k``, ``row_slot``
+and ``row_token`` ``rows``, the block starts, the four small arrays of
+``GroupLayout``).  All integers, no gradient: with them kept a recomputed
+block makes no ``top_k``, no running count, no ``argsort`` and no layout
+again — only what a gradient flows through (the router's product, the
+scores, the picked weights, the row movement, the grouped products).
+Outside a ``jax.checkpoint`` a name lowers to nothing.
 
 One rule at every shape, no threshold: per byte kept, the attention
 forward costs 2 x (keys a query sees) operations at a fifth to a third
@@ -79,6 +88,14 @@ a step for six — and the compile-only rehearsal of that cell's window reads
 4.98 GiB of temporaries (5.33 before the rule's kernels made what is local
 to a chunk themselves, 6.17 with no name kept; PERF.md section 6, PR 30-31).
 
+An expert block keeps its plan beside all that, ``8 x (tokens x k + rows)``
+bytes a layer and the small tables: trinity-mini (1x8192, k 8, 69,632
+rows) 1.08 MB, qwen3-next (k 10, 90,112 rows) 1.38 MB, moonlight (k 6,
+51,200 rows) 0.80 MB, smallthinker (1x16384, k 6, 100,352 rows) 1.59 MB,
+lfm2 (1x16384, k 4, 67,584 rows) 1.07 MB — 4.0 to 6.4 MB over a cell's
+four or five expert layers, against the 34–117 MB a layer of ``out`` above
+(PERF.md section 6, PR 40, has what the second making cost on the chip).
+
 For GPT and BERT heads x head size = hidden, so ``full_block`` keeps two
 arrays of the input's size a block where it kept one: about 1/9th of
 what ``none`` keeps (34 x rows x seq x hidden bytes a block by the usual
@@ -92,15 +109,18 @@ import jax
 
 REMAT_POLICIES = ("none", "dots_saveable", "full_block")
 
-# The residuals kernels declare (``checkpoint_name``) and the
-# block-recomputing policies keep.  The kernels import the names from
+# The residuals kernels (and the expert layer) declare (``checkpoint_name``)
+# and the block-recomputing policies keep.  They import the names from
 # here; this module imports only jax.
 FLASH_OUT = "apex_flash_out"
 FLASH_LSE = "apex_flash_lse"
 GDN_OUT = "apex_gdn_out"
 GDN_STATES = "apex_gdn_states"
 GDN_TRI = "apex_gdn_tri"
-KEPT_RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_TRI)
+MOE_SEL = "apex_moe_sel"
+MOE_PLAN = "apex_moe_plan"
+KEPT_RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_TRI,
+                       MOE_SEL, MOE_PLAN)
 
 
 def checkpoint_policy(policy: Optional[str]):

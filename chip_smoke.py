@@ -18,7 +18,11 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   with no dead tail, forward, ``dx`` and ``dw`` each timed alone
   (``grouped_mm_at_cell``); and the gated short convolution at the LFM2
   cell's call, its two kernels against the ``jax.numpy`` form, forward and
-  gradient timed on both paths (``gated_conv_at_cell``);
+  gradient timed on both paths (``gated_conv_at_cell``); and ONE making
+  of the expert layer's routing plan at the five sparse cells' shapes, with
+  each lookup inside it as the gather it was and as the sum over the held
+  experts it can be, timed on the device and the tables held equal to the
+  bit (``moe_plan_at_cell``);
 - ``train`` — AMP O2 + ``fused_adam`` through ``FusedTrainDriver``, with
   dropout on: three windows on a fixed seeded batch;
 - ``serve`` — the params that phase produced, through ``GPTDecoder`` +
@@ -221,6 +225,203 @@ def _compare(name: str, got, ref, tol: float, out: Dict) -> None:
     _require(err <= tol * max(1.0, scale),
              f"kernel parity {name}: max_err {err:.3e} > tol {tol:.1e} x "
              f"max(1, {scale:.3e})")
+
+
+def _us_a_call(fn, args, n=10):
+    """Microseconds a call of ``fn(*args)``, the first (its compile) apart."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / n * 1e6, 1)
+
+
+def _us_on_device(fn, args, reps=16):
+    """Microseconds ``fn(*args)`` takes ON THE DEVICE, for a program shorter
+    than the host's dispatch of a call (~0.2 ms): ``reps`` makings in one
+    program, each fed the sum of the one before's results times a zero the
+    compiler cannot see — none is hoisted, none merged — and the call's time
+    divided by ``reps``.  The sum is part of what is timed, on every side."""
+    def many(zero, *args):
+        def body(_, carry):
+            nudge = zero * carry
+            fed = jax.tree_util.tree_map(
+                lambda a: a ^ (nudge != 0) if a.dtype == jnp.bool_
+                else a + nudge.astype(a.dtype), args)
+            return sum(jnp.sum(leaf).astype(jnp.int32)
+                       for leaf in jax.tree_util.tree_leaves(fn(*fed)))
+        return jax.lax.fori_loop(0, reps, body, jnp.int32(0))
+
+    compiled = jax.jit(many).lower(jnp.int32(0), *args).compile()
+    return round(_us_a_call(compiled, (jnp.int32(0), *args), n=3) / reps, 1)
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's routing plan, one making, at the sparse cells' shapes
+# ---------------------------------------------------------------------------
+
+# (cell, contexts of tokens, k, experts scored, held, hidden / ctx, scores)
+MOE_PLAN_CELLS = (
+    ("trinity-mini.train-8k", 8, 8, 128, 16, 2.0, "sigmoid"),
+    ("qwen3-next.train-8k", 8, 10, 512, 32, 2.0, "softmax"),
+    ("moonlight.train-8k", 8, 6, 64, 8, 2.0, "sigmoid"),
+    ("smallthinker.train-16k", 16, 6, 64, 8, 2.5, "softmax"),
+    ("lfm2.train-16k", 16, 4, 64, 8, 2.0, "sigmoid"),
+)
+
+
+def moe_plan_at_cell(s: int, root_key, parity: Dict) -> Dict:
+    """ONE making of ``ExpertShardMLP``'s routing plan (the router's
+    selection and weights from seeded logits, then ``_route``) at each sparse
+    cell's ``(tokens, k, experts, held)``, us on the device; its dear parts alone
+    (``top_k``, the running count, the ``argsort``); and each lookup the plan
+    makes, as the GATHER out of a table it was and as the SUM over the held
+    experts it can be — a slot's row, the picked weights with their gradient,
+    a row's slot — us of each, the tables held equal to the bit and
+    the weights to 1e-6 (``parity``)."""
+    from apex_tpu.ops import grouped_mm as gmm
+    from apex_tpu.ops import moe_rows
+    from apex_tpu.parallel import moe
+
+    f32, tile = jnp.float32, gmm.DEFAULT_TILE_ROWS
+    out: Dict = {}
+    for i, (cell, ctxs, k, e, n_held, width, score) in enumerate(MOE_PLAN_CELLS):
+        t, n, d = ctxs * s, ctxs * s * k, int(width * s)
+        cap = gmm.rows_capacity(t * min(k, n_held), n_held, tile)
+        block = moe_rows.combine_block(t, k, d)
+        logits, bias, cot = jax.jit(lambda key: [
+            scale * jax.random.normal(ki, shape, f32) for ki, shape, scale in
+            zip(jax.random.split(key, 3), ((t, e), (e,), (t, k)),
+                (1.0, 0.1, 1.0))])(jax.random.fold_in(root_key, 150 + i))
+
+        if score == "sigmoid":
+            values, lean = jax.nn.sigmoid, bias
+            route = lambda lg: moe.sigmoid_topk_routing(lg, bias, k, True, 2.5)
+            gathered = lambda lg: jnp.take_along_axis(
+                values(lg), jax.lax.top_k(values(lg) + lean, k)[1], axis=-1)
+        else:
+            values, lean = jax.nn.softmax, 0.0
+            route = lambda lg: moe.softmax_topk_routing(lg, k, True)
+            gathered = lambda lg: jax.lax.top_k(values(lg), k)[0]
+        summed = lambda lg: moe._picked(
+            values(lg), jax.lax.top_k(values(lg) + lean, k)[1])
+
+        def making(lg):
+            sel, w = route(lg)
+            return sel, w, moe._route(sel, (0, n_held), cap, tile, block)
+
+        def first_half(sel):        # shard_dispatch down to the layout
+            local = sel.reshape(n)
+            mine = local < n_held
+            onehot = ((local[:, None] == jnp.arange(n_held)[None, :])
+                      & mine[:, None]).astype(jnp.int32)
+            count = jnp.cumsum(onehot, axis=0)
+            return local, mine, onehot, count, gmm.group_layout(
+                count[-1], cap, tile)
+
+        def slot_row_gather(local, mine, onehot, count, layout):
+            rank = jnp.sum((count - onehot) * onehot, axis=-1)
+            return jnp.where(mine, layout.row_start[
+                jnp.clip(local, 0, n_held - 1)] + rank, cap)
+
+        def slot_row_sum(local, mine, onehot, count, layout):
+            return jnp.where(mine, jnp.sum(onehot * (
+                layout.row_start[None, :] + count - onehot), axis=-1), cap)
+
+        row = jnp.arange(cap, dtype=jnp.int32)
+
+        def rows_gathers(layout, sizes, order):
+            group = layout.tile_group[row // tile]
+            within = row - layout.row_start[group]
+            live = (within < sizes[group]) & (
+                row // tile < layout.tiles_used[0])
+            first = jnp.cumsum(sizes) - sizes
+            return jnp.where(live, order[
+                jnp.clip(first[group] + within, 0, n - 1)], n)
+
+        def in_groups(layout):
+            reached = row[None, :] >= layout.row_start[:, None]
+            return reached & ~jnp.concatenate(
+                [reached[1:], jnp.zeros((1, cap), bool)])
+
+        def rows_sums(layout, sizes, order, shifts: bool):
+            in_group = in_groups(layout)
+            of_group = lambda table: jnp.sum(
+                jnp.where(in_group, table[:, None], 0), axis=0)
+            within = row - of_group(layout.row_start)
+            live = (within < of_group(sizes)) & (
+                row // tile < layout.tiles_used[0])
+            first = jnp.cumsum(sizes) - sizes
+            if not shifts:
+                return jnp.where(live, order[
+                    jnp.clip(of_group(first) + within, 0, n - 1)], n)
+            room = jnp.full((cap,), n, order.dtype)
+            padded = jnp.concatenate([room, order, room])
+
+            def shifted(row_slot, group):
+                rows_of, moved = group
+                run = jax.lax.dynamic_slice(padded, (cap - moved,), (cap,))
+                return jnp.where(rows_of & live, run, row_slot), None
+
+            return jax.lax.scan(
+                shifted, room, (in_group, layout.row_start - first))[0]
+
+        def with_grad(picked):
+            def both(lg):
+                w, back = jax.vjp(picked, lg)
+                return w, back(cot)[0]
+            return both
+
+        sel, _, routing = jax.jit(making)(logits)
+        half = jax.jit(first_half)(sel)
+        slot_row = jax.jit(slot_row_sum)(*half)
+        order = jax.jit(jnp.argsort)(slot_row)
+        second = (half[-1], half[3][-1], order)
+        rec = out[cell] = {
+            "shape": [t, k, e, n_held, tile], "rows_capacity": cap,
+            "rows_live": int(jnp.sum(routing.row_slot < n))}
+        timed = {
+            "making": (making, (logits,)),
+            "top_k": (lambda lg: jax.lax.top_k(lg, k), (logits,)),
+            "running_count": (lambda sel: first_half(sel)[3], (sel,)),
+            "argsort": (jnp.argsort, (slot_row,)),
+            "slot_row_gather": (slot_row_gather, half),
+            "slot_row_sum": (slot_row_sum, half),
+            "picked_gather": (with_grad(gathered), (logits,)),
+            "picked_sum": (with_grad(summed), (logits,)),
+            "row_slot_gathers": (rows_gathers, second),
+            "row_slot_sums": (
+                lambda *a: rows_sums(*a, shifts=False), second),
+            "row_slot_sums_shifts": (
+                lambda *a: rows_sums(*a, shifts=True), second),
+        }
+        results = {}
+        for name, (fn, args) in timed.items():
+            rec[f"{name}_us"] = _us_on_device(fn, args)
+            results[name] = jax.tree_util.tree_leaves(jax.jit(fn)(*args))
+        same = lambda a, b: all(
+            x.dtype == y.dtype and bool(jnp.array_equal(x, y))
+            for x, y in zip(results[a], results[b], strict=True))
+        # the weights are floats: held to 1e-6 and said whether to the bit
+        # (each side's program rounds its own sigmoid or softmax)
+        for j, what in enumerate(("w", "dlogits")):
+            _compare(f"moe_plan_at_cell.{cell}.picked.{what}",
+                     results["picked_sum"][j], results["picked_gather"][j],
+                     1e-6, parity)
+        rec["picked_same_bits"] = same("picked_gather", "picked_sum")
+        for a, b in (("slot_row_gather", "slot_row_sum"),
+                     ("row_slot_gathers", "row_slot_sums"),
+                     ("row_slot_gathers", "row_slot_sums_shifts")):
+            _require(same(a, b), f"moe_plan_at_cell {cell}: {b} is not {a} "
+                                 "to the bit")
+        _require(bool(jnp.array_equal(results["slot_row_sum"][0].reshape(t, k),
+                                      routing.slot_row))
+                 and bool(jnp.array_equal(results["row_slot_gathers"][0],
+                                          routing.row_slot)),
+                 f"moe_plan_at_cell {cell}: the forms timed alone do not "
+                 "make the shipped plan's tables")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -618,14 +819,6 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     routes = facts["flash_backward"] = {}
     blocks = dict(block_q=sw // 16, block_k=sw // 8)   # the auto blocks at 8k
 
-    def us_a_call(fn, args, n=10):
-        jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(n):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return round((time.perf_counter() - t0) / n * 1e6, 1)
-
     def backward_routes(name, hq, hkv, d, d_v, window, key, seq=sw, n=10):
         q_, k_, v_, w_ = jax.jit(lambda key: [
             (normal(ki, (1, heads, seq, width), f32) * 0.3).astype(bf16)
@@ -648,7 +841,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
 
         rec = routes[name] = {
             "shape": [hq, hkv, seq, d, d_v, window],
-            "fwd_us": us_a_call(jax.jit(attend), (q_, k_, v_), n)}
+            "fwd_us": _us_a_call(jax.jit(attend), (q_, k_, v_), n)}
         grads = {}
         budget = attention_mod._SWEEP_ACC_BUDGET_BYTES
         try:
@@ -658,7 +851,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
                     q_, k_, v_, w_).compile()
                 rec[route + "_kernels"] = mosaic_call_names(compiled.as_text())
-                rec[route + "_grad_us"] = us_a_call(compiled, (q_, k_, v_, w_), n)
+                rec[route + "_grad_us"] = _us_a_call(compiled, (q_, k_, v_, w_), n)
                 grads[route] = compiled(q_, k_, v_, w_)
         finally:
             attention_mod._SWEEP_ACC_BUDGET_BYTES = budget
@@ -714,8 +907,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
         fwd = jax.jit(lambda x, w: gated_short_conv(x, w, use_pallas=use_pallas))
         grad = jax.jit(jax.grad(
             lambda *a: gated_loss(use_pallas)(*a)[0], (0, 1)))
-        timed_c[f"fwd_{side}_us"] = us_a_call(fwd, (xg, wg))
-        timed_c[f"grad_{side}_us"] = us_a_call(grad, (xg, wg, cot_c))
+        timed_c[f"fwd_{side}_us"] = _us_a_call(fwd, (xg, wg))
+        timed_c[f"grad_{side}_us"] = _us_a_call(grad, (xg, wg, cot_c))
 
     # the expert layer's row movement at smallthinker.train-16k's shape —
     # twice the LayerNorm rows x 2560 bfloat16 (a record 20 sublanes: two and
@@ -765,7 +958,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
             compiled = jax.jit(fn).lower(*args).compile()
             if side == "kernels":
                 _require_mosaic(compiled, 1, timed["mosaic_calls"], name)
-            timed[f"{name}_{side}_us"] = us_a_call(compiled, args, n=3)
+            timed[f"{name}_{side}_us"] = _us_a_call(compiled, args, n=3)
             outs[side, name] = jax.tree_util.tree_leaves(compiled(*args))
     for (side, name), got in outs.items():
         if side != "kernels":
@@ -827,7 +1020,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 names = mosaic_call_names(compiled.as_text())
                 _require(len(names) <= 1,
                          f"{key_g}: timed with another pass: {names}")
-                rec[f"{name}_us"] = us_a_call(compiled, args_g)
+                rec[f"{name}_us"] = _us_a_call(compiled, args_g)
                 got = compiled(*args_g)
                 if name != "dw":
                     got, refs[name] = (jnp.where(live, t, 0)
@@ -835,6 +1028,7 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 _compare(f"grouped_mm_at_cell.{key_g}", got, refs[name],
                          2e-2, parity)
 
+    facts["moe_plan_at_cell"] = moe_plan_at_cell(s, root_key, parity)
     facts["max_err"] = max(p["max_err"] for p in parity.values())
 
 

@@ -107,6 +107,20 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
             "grad_jnp_us"} <= set(gated)
     for name in ("fwd", "dx", "dw"):
         assert f"gated_conv.{name}" in kernels["parity"]
+    # the routing plan at the five sparse cells' shapes: one making, its dear
+    # parts and each lookup both ways timed, the tables equal to the bit
+    plans = kernels["moe_plan_at_cell"]
+    assert list(plans) == [c[0] for c in chip_smoke.MOE_PLAN_CELLS]
+    assert plans["qwen3-next.train-8k"]["shape"] == [8 * TINY.ctx, 10, 512, 32, 256]
+    assert plans["smallthinker.train-16k"]["shape"][0] == 16 * TINY.ctx
+    for cell, rec in plans.items():
+        assert 0 < rec["rows_live"] < rec["rows_capacity"]
+        assert rec["picked_same_bits"] is True
+        assert {f"{name}_us" for name in (
+            "making", "top_k", "running_count", "argsort", "slot_row_gather",
+            "slot_row_sum", "picked_gather", "picked_sum", "row_slot_gathers",
+            "row_slot_sums", "row_slot_sums_shifts")} <= set(rec)
+        assert f"moe_plan_at_cell.{cell}.picked.dlogits" in kernels["parity"]
     assert train["loss_per_window"][-1] < train["loss_per_window"][0]
     assert train["compiles_after_first_window"] == 0
     assert serve["compiles_after_warmup"] == 0

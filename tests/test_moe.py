@@ -206,12 +206,15 @@ def test_expert_shard_refuses_what_it_does_not_know():
 
 # sha256 of str(jaxpr) of the loss gradient of the three sparse models that
 # call ExpertShardMLP with its defaults (no router_input, the silu unit), on
-# the jnp.take path, taken from the commit before router_input and unit_func
-# (PR 36, 2c69164): their programs must not change by a byte.
+# the jnp.take path: an edit that means to leave their programs alone must
+# not change them by a byte.  Taken anew at PR 40, which did mean to — the
+# plan's names stand in every jaxpr, the picked weights are a masked sum and
+# a row finds its slot without a gather (what held across that edit are the
+# values: the tests of the plan's tables and of the weights, below).
 _SPARSE_JAXPR_SHA256 = {
-    "afmoe": "cbabac37c149c613",
-    "qwen3_next": "b25b0f67d5968d25",
-    "deepseek_v3": "ad3f888363e7d8ed",
+    "afmoe": "5e3b04254f5420d8",
+    "qwen3_next": "4ffd06c5fe154864",
+    "deepseek_v3": "a512d0534c7f5cb2",
 }
 
 
@@ -320,3 +323,144 @@ def test_whole_layer_on_the_kernels_never_reads_the_dead_tail(unit):
                     jax.tree_util.tree_leaves(want)):
         assert np.isfinite(a).all() and np.asarray(b).any()
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+# -- the routing plan: its tables against a plain loop over the slots --------
+
+def _plan_oracle(sel, held, capacity, tile, block):
+    """``_route``'s tables by a plain NumPy loop over the slots and the rows
+    (``ops/grouped_mm.py::GroupLayout``'s four arrays, ``slot_row``,
+    ``row_slot``, ``row_token``, ``_block_starts``)."""
+    t, k = sel.shape
+    lo, hi = held
+    sizes = [int(np.sum(sel == e)) for e in range(lo, hi)]
+    tiles_of = [max(1, -(-z // tile)) for z in sizes]
+    row_start = [tile * sum(tiles_of[:g]) for g in range(hi - lo)]
+    tile_group, tile_valid = [], []
+    for g, (z, tiles) in enumerate(zip(sizes, tiles_of)):
+        tile_group += [g] * tiles
+        tile_valid += [min(tile, max(0, z - i * tile)) for i in range(tiles)]
+    dead = capacity // tile - len(tile_group)
+    tile_group += [hi - lo - 1] * dead
+    tile_valid += [0] * dead
+    slot_row = np.full((t, k), capacity, np.int32)
+    row_slot = np.full(capacity, t * k, np.int32)
+    row_token = np.full(capacity, t, np.int32)
+    starts = np.zeros((t // block + 1, hi - lo), np.int32)
+    taken = [0] * (hi - lo)
+    for token in range(t):
+        if token % block == 0:
+            starts[token // block] = np.add(row_start, taken)
+        for j in range(k):
+            g = int(sel[token, j]) - lo
+            if 0 <= g < hi - lo:
+                row = row_start[g] + taken[g]
+                taken[g] += 1
+                slot_row[token, j] = row
+                row_slot[row], row_token[row] = token * k + j, token
+    starts[-1] = np.add(row_start, taken)
+    return [np.asarray(a, np.int32) for a in (
+        row_start, tile_group, tile_valid, [sum(tiles_of)])] + [
+            slot_row, row_slot, row_token, starts]
+
+
+def _random_sel(seed, t, k, e):
+    rng = np.random.RandomState(seed)
+    return np.stack([rng.permutation(e)[:k] for _ in range(t)]).astype(np.int32)
+
+
+def _one_expert_ends_on_a_tile(t=32, k=2, e=8):
+    """Expert 3 gets exactly two tiles of 8 rows, expert 2 none."""
+    sel = np.stack([np.full(t, 7), np.full(t, 6)], axis=1).astype(np.int32)
+    sel[:16, 0] = 3
+    sel[16:24, 1] = 4
+    return sel
+
+
+PLAN_SELS = {
+    "random_quarter_held": (_random_sel(0, 64, 4, 16), (4, 8)),
+    "random_all_held": (_random_sel(1, 32, 3, 8), (0, 8)),
+    "random_k_over_held": (_random_sel(2, 32, 8, 16), (5, 9)),
+    "an_expert_with_no_row_one_ending_on_a_tile": (
+        _one_expert_ends_on_a_tile(), (2, 6)),
+    "every_slot_on_one_held_expert": (np.full((32, 1), 5, np.int32), (4, 8)),
+    "no_held_slot_at_all": (_random_sel(3, 32, 4, 8) % 4, (4, 8)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLAN_SELS))
+def test_routing_plan_tables_are_the_plain_loops(name):
+    """``shard_dispatch``'s and ``_block_starts``' tables, every leaf of
+    ``_Routing``, equal to the bit to a loop over the slots — on random
+    selections and where a lookup by sum could slip: an empty group, a group
+    that ends exactly on a tile, all slots on one expert, none held."""
+    from apex_tpu.ops import grouped_mm as gmm
+    from apex_tpu.parallel import moe
+
+    sel, held = PLAN_SELS[name]
+    (t, k), tile, block = sel.shape, 8, 16
+    capacity = gmm.rows_capacity(
+        t * min(k, held[1] - held[0]), held[1] - held[0], tile)
+    got = jax.tree_util.tree_leaves(jax.jit(
+        lambda s: moe._route(s, held, capacity, tile, block))(jnp.asarray(sel)))
+    want = _plan_oracle(sel, held, capacity, tile, block)
+    assert len(got) == len(want) == 8
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), b)
+    if name.startswith("an_expert"):
+        assert want[2].tolist()[:4] == [0, 8, 8, 8]     # none; whole tiles
+    live = int(np.sum((sel >= held[0]) & (sel < held[1])))
+    assert int(np.sum(want[5] < t * k)) == live
+    assert (live == 0) == name.startswith("no_held")
+
+
+@pytest.mark.parametrize("route_norm", [False, True], ids=["raw", "normed"])
+def test_sigmoid_weights_are_take_along_axis_to_the_bit(route_norm):
+    """The picked scores as a masked sum over the experts: the float32 a
+    gather reads, to the bit, under a selection bias that is NOT zero (so
+    ``top_k``'s own values, scores plus bias, would not do), and the gradient
+    of the logits equal too — a masked broadcast where the gather's was a
+    scatter."""
+    from apex_tpu.parallel.moe import sigmoid_topk_routing
+
+    kl, kb, kc = jax.random.split(jax.random.PRNGKey(7), 3)
+    logits = 2.0 * jax.random.normal(kl, (96, 32))
+    bias = 0.5 * jax.random.normal(kb, (32,))
+    cot = jax.random.normal(kc, (96, 5))
+
+    def gathered(logits):
+        scores = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(scores + bias, 5)
+        w = jnp.take_along_axis(scores, sel, axis=-1)
+        if route_norm:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return sel.astype(jnp.int32), w * 2.5
+
+    sel, w = sigmoid_topk_routing(logits, bias, 5, route_norm, 2.5)
+    sel_g, w_g = gathered(logits)
+    assert not jnp.array_equal(sel, jax.lax.top_k(logits, 5)[1])  # bias steers
+    assert sel.dtype == sel_g.dtype and jnp.array_equal(sel, sel_g)
+    assert w.dtype == w_g.dtype and jnp.array_equal(w, w_g)
+    grad = jax.grad(lambda lg: jnp.sum(
+        sigmoid_topk_routing(lg, bias, 5, route_norm, 2.5)[1] * cot))(logits)
+    grad_g = jax.grad(lambda lg: jnp.sum(gathered(lg)[1] * cot))(logits)
+    assert jnp.any(grad != 0) and jnp.array_equal(grad, grad_g)
+
+
+def test_softmax_weights_are_top_ks_own_to_the_bit():
+    """... and the picked probabilities are ``top_k``'s values, gradient too."""
+    kl, kc = jax.random.split(jax.random.PRNGKey(8))
+    logits = 2.0 * jax.random.normal(kl, (96, 32))
+    cot = jax.random.normal(kc, (96, 5))
+
+    def gathered(logits):
+        w, sel = jax.lax.top_k(jax.nn.softmax(logits, -1), 5)
+        return sel.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+    for a, b in zip(softmax_topk_routing(logits, 5, True), gathered(logits)):
+        assert a.dtype == b.dtype and jnp.array_equal(a, b)
+    grad, grad_g = (jax.grad(lambda lg: jnp.sum(fn(lg)[1] * cot))(logits)
+                    for fn in (lambda lg: softmax_topk_routing(lg, 5, True),
+                               gathered))
+    assert jnp.any(grad != 0) and jnp.array_equal(grad, grad_g)

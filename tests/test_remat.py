@@ -1,7 +1,8 @@
 """What the block-recomputing policies keep (``apex_tpu/remat.py``): the
 residuals a kernel declares — the flash forward's output and log-sum-exp
 — beside the block's input, so that the backward pass does not run the
-attention forward a second time."""
+attention forward a second time; and an expert layer's routing plan, so
+that it is made once a step."""
 import collections
 import dataclasses
 import re
@@ -76,14 +77,30 @@ def flash_forward_calls(fn, *args, kernel="apex_flash_fwd"):
 def test_full_block_keeps_input_out_and_lse_a_layer(family, layers):
     """One block more keeps three arrays more: its input, the flash
     kernel's output and the (bh, sq) float32 lse — no second array of
-    q's shape, no k3 / v3, no 128-lane lse buffer."""
+    q's shape, no k3 / v3, no 128-lane lse buffer — and, where it holds
+    an expert layer, the nine integer tables of its routing plan."""
     with force_pallas(True):
         more = kept(*tiny_lm(family, "full_block", layers)[:2])
         loss, params, (bh, bh_kv, d, hidden) = tiny_lm(
             family, "full_block", layers - 1)
         fewer = kept(loss, params)
     assert not fewer - more
-    assert more - fewer == collections.Counter({
+    plan = collections.Counter()
+    if family == "afmoe":       # and its expert layer's routing plan, int32
+        from apex_tpu.ops import grouped_mm, moe_rows
+
+        tokens, k, held = ROWS * SEQ, 4, 4
+        rows = grouped_mm.rows_capacity(tokens * k, held)
+        tiles = rows // grouped_mm.DEFAULT_TILE_ROWS
+        blocks = tokens // moe_rows.combine_block(tokens, k, hidden)
+        plan = collections.Counter({
+            ((tokens, k), "int32"): 2,          # sel, slot_row
+            ((rows,), "int32"): 2,              # row_slot, row_token
+            ((tiles,), "int32"): 2,             # tile_group, tile_valid
+            ((held,), "int32"): 1, ((1,), "int32"): 1,  # row_start, tiles_used
+            ((blocks + 1, held), "int32"): 1,   # _block_starts
+        })
+    assert more - fewer == plan + collections.Counter({
         ((ROWS, SEQ, hidden), "float32"): 1,
         ((bh, SEQ, d), "float32"): 1,
         ((bh, SEQ), "float32"): 1,
@@ -194,4 +211,106 @@ def test_gauge_counts_the_names_a_policy_keeps(policy):
     assert remat.checkpoint_policy("none") is None
     assert gauge.value == 0
     assert remat.checkpoint_policy(policy) is not None
-    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 5
+    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 7
+
+
+# -- an expert layer's routing plan: made once a step ------------------------
+
+PLAN_MAKERS = ("top_k", "sort", "cumsum")
+
+
+def _expert_layer(policy, score, kernels):
+    from apex_tpu.parallel.moe import ExpertShardMLP
+
+    return remat.remat_module(ExpertShardMLP, policy)(
+        num_experts=16, experts_held=(4, 8), d_ff=128, k=4, score_func=score,
+        tile_rows=16 if kernels else 8)
+
+
+def _expert_loss(policy, score, early, kernels=False):
+    """(loss of (parameters, x, router_input), its arguments) of one
+    ``ExpertShardMLP`` under ``policy``; a selection bias that is not zero."""
+    kx, kr, kp, kc, kb = jax.random.split(jax.random.PRNGKey(40), 5)
+    x = jax.random.normal(kx, (64, 128))
+    scored = 2.0 * jax.random.normal(kr, (64, 128)) if early else None
+    cot = jax.random.normal(kc, (64, 128))
+    with force_pallas(kernels):
+        params = _expert_layer("none", score, kernels).init(kp, x)["params"]
+    if score == "sigmoid":
+        params = dict(params, expert_bias=0.3 * jax.random.normal(kb, (16,)))
+
+    def loss(p, x, scored):
+        with force_pallas(kernels):
+            return jnp.sum(_expert_layer(policy, score, kernels).apply(
+                {"params": p}, x, scored) * cot)
+
+    return loss, (params, x, scored)
+
+
+def _makings(loss, args):
+    """How often each of the plan's dear primitives stands in the jaxpr of
+    the loss's gradient, every nested jaxpr walked."""
+    found = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in PLAN_MAKERS:
+                found[eqn.primitive.name] += 1
+            for inner in _sub_jaxprs(eqn.params):
+                walk(inner)
+
+    walk(jax.make_jaxpr(jax.grad(loss, (0, 1)))(*args).jaxpr)
+    return found
+
+
+PLAN_CASES = [
+    pytest.param(score, early, policy, False,
+                 id=f"{score}-{'router_input' if early else 'scores_x'}-{policy}")
+    for score in ("sigmoid", "softmax") for early in (False, True)
+    for policy in ("full_block", "dots_saveable")
+] + [pytest.param("softmax", True, "full_block", True,
+                  id="softmax-router_input-full_block-kernels"),
+     pytest.param("sigmoid", False, "dots_saveable", True,
+                  id="sigmoid-scores_x-dots_saveable-kernels")]
+
+
+@pytest.mark.parametrize("score,early,policy,kernels", PLAN_CASES)
+def test_the_routing_plan_is_made_once_a_layer(monkeypatch, score, early,
+                                               policy, kernels):
+    """A recomputed expert layer reads ``sel`` and ``_Routing``'s tables kept
+    (``apex_moe_sel``, ``apex_moe_plan``): ONE ``top_k``, one ``argsort`` and
+    one set of running counts in the gradient's jaxpr, as many as with no
+    recomputation — and two of each where a policy keeps neither name.  The
+    loss and every gradient leaf are the same to the bit all three ways."""
+    once = _makings(*_expert_loss("none", score, early, kernels))
+    assert once["top_k"] == once["sort"] == 1 and once["cumsum"] >= 3
+    loss, args = _expert_loss(policy, score, early, kernels)
+    both = jax.value_and_grad(loss, (0, 1, 2) if early else (0, 1))
+    assert _makings(loss, args) == once
+    kept = both(*args)
+
+    monkeypatch.setattr(remat, "KEPT_RESIDUAL_NAMES", tuple(
+        n for n in remat.KEPT_RESIDUAL_NAMES
+        if n not in (remat.MOE_SEL, remat.MOE_PLAN)))
+    loss, args = _expert_loss(policy, score, early, kernels)
+    assert _makings(loss, args) == {m: 2 * once[m] for m in PLAN_MAKERS}
+    dropped = jax.value_and_grad(loss, (0, 1, 2) if early else (0, 1))(*args)
+    plain = jax.value_and_grad(
+        _expert_loss("none", score, early, kernels)[0],
+        (0, 1, 2) if early else (0, 1))(*args)
+    for other in (dropped, plain):
+        for a, b in zip(jax.tree_util.tree_leaves(kept),
+                        jax.tree_util.tree_leaves(other), strict=True):
+            assert a.dtype == b.dtype and jnp.array_equal(a, b)
+    kept[1][0].pop("expert_bias", None)     # it steers the selection only
+    assert all(jnp.any(g != 0) for g in jax.tree_util.tree_leaves(kept[1]))
+
+
+def test_the_plans_names_lower_to_nothing_outside_a_checkpoint():
+    """With no ``jax.checkpoint`` around it the layer's gradient lowers to a
+    text that bears neither name: ``remat_policy`` ``none`` and every caller
+    outside the model zoo compile what they would without them."""
+    loss, args = _expert_loss("none", "sigmoid", False)
+    text = jax.jit(jax.grad(loss)).lower(*args).as_text()
+    assert "top_k" in text
+    assert remat.MOE_SEL not in text and remat.MOE_PLAN not in text
