@@ -11,8 +11,15 @@ drops by the same factor.  This is the canonical Pallas attention:
 
 - forward: grid (batch*heads, q_blocks, k_blocks), online-softmax
   accumulation in VMEM scratch (m, l, acc), writes O and the per-row
-  logsumexp (for backward);
+  logsumexp (for backward) — ONE float32 a row, ``(batch*heads, 1, Sq)``
+  in ``(1, 1, block_q)`` blocks: m and l sit lane-broadcast in their
+  scratch, and the kernel's last step turns the block's column to a row
+  (:func:`_put_column_as_row`);
 - backward: recompute-based with the stored lse, no O(S^2) residuals.
+  The kernels read q, k, v, do, o and that lse: each turns the lse block
+  back to a column in VMEM and makes ``delta = sum_d do * o`` there from
+  the ``do`` and ``o`` blocks (:func:`_stage_stats`) — no statistic crosses
+  HBM at 128 lanes a row, and delta crosses it in no form.
   ONE sweep over a head's visited score tiles computes s, p, dp and ds once
   a tile and feeds dk, dv AND dq from them (5 MXU dots per visited tile
   pair instead of the two-pass flash-v2's 7): with one key block the
@@ -94,7 +101,8 @@ _SWEEP_ACC_BUDGET_BYTES = 24 * 2 ** 20
 # What the sweep's call asks for beside its accumulators: Mosaic's default
 # scoped limit (16 MiB) holds either pass of the two-pass backward at the
 # largest auto blocks (512 x 1024: four float32 score-sized temporaries of
-# 2 MiB, ds's transpose, the double-buffered q/k/v/do/lse/delta blocks); the
+# 2 MiB, ds's transpose, the double-buffered q/k/v/do/o blocks with lse's
+# (1, block_q) row, the two (block_q, 128) float32 statistics' scratch); the
 # sweep holds the union of the two passes' temporaries, so twice that.
 _SWEEP_TILE_VMEM_BYTES = 32 * 2 ** 20
 
@@ -808,6 +816,24 @@ def _drop_bh(seed_ref, h_map, bh=None):
     return (bh // h_local) * h_total + seed_ref[3] + bh % h_local
 
 
+def _put_column_as_row(row_ref, x):
+    """``row_ref[0]``, ``(1, rows)``, takes the column that ``x`` ``(rows,
+    128)`` holds lane-broadcast (every lane of a row the same number, as the
+    forward's m and l).  128 rows at a time: the chunk's diagonal picked
+    under an iota mask and summed over the chunk's rows — one number and
+    zeros a lane, so exact.  Selects, adds and one sublane reduction a chunk:
+    at GPT-2's call, where a head is one grid step, that left the forward
+    kernel 7% faster than row 0 of ``jnp.transpose(x)`` did (PERF.md section
+    6, PR 41)."""
+    rows, lanes = x.shape
+    for r in range(0, rows, lanes):
+        c = min(lanes, rows - r)
+        diagonal = (jax.lax.broadcasted_iota(jnp.int32, (c, lanes), 0)
+                    == jax.lax.broadcasted_iota(jnp.int32, (c, lanes), 1))
+        row_ref[0, :, r:r + c] = jnp.sum(
+            jnp.where(diagonal, x[r:r + c], 0.0), axis=0, keepdims=True)[:, :c]
+
+
 def _fwd_kernel(
     seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     m_scr, l_scr, acc_scr,
@@ -907,20 +933,39 @@ def _fwd_kernel(
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        denom = l_safe * (1.0 - dropout_rate) if dropout_rate > 0.0 else l_safe
+        l_safe = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
+        denom = l_safe[:, :1]
+        if dropout_rate > 0.0:
+            denom = denom * (1.0 - dropout_rate)
         o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-        lse = m_scr[:, :1] + jnp.log(l_safe)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        # one float32 a row leaves: the block's column turned to a row
+        _put_column_as_row(lse_ref, m_scr[:] + jnp.log(l_safe))
 
 
 # ---------------------------------------------------------------------------
 # backward kernels (recompute with stored lse)
 # ---------------------------------------------------------------------------
 
+def _stage_stats(do_ref, o_ref, lse_ref, lse_scr, delta_scr):
+    """A query block's two softmax statistics, a column each, into the
+    ``(block_q, 128)`` float32 scratch the tiles read them from (lane-
+    broadcast, as the forward's m and l).  ``lse`` arrives as the forward
+    left it, one float32 a row in a ``(1, block_q)`` block, and is turned
+    from a row to a column here; ``delta_i = sum_d do * o`` (the flash-v2
+    trick: avoids recomputing p@v row sums) is made from the ``do`` and
+    ``o`` blocks and crosses HBM in no form.  Once a query block in the
+    query-major kernels (at ``ki == 0``), once a visited grid step in the
+    key-major ones."""
+    block_q, lanes = lse_scr.shape
+    lse_scr[:] = jnp.transpose(jnp.broadcast_to(lse_ref[0], (lanes, block_q)))
+    delta = jnp.sum(
+        do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+        axis=-1, keepdims=True)
+    delta_scr[:] = jnp.broadcast_to(delta, delta_scr.shape)
+
+
 def _bwd_tile(
-    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
+    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_scr, delta_scr,
     dk_acc, dv_acc, put_dq, bh, qi, ki, acc_rows=None,
     *, scale: float, block_q: int, block_k: int, dropout_rate: float,
     probs_bf16: bool, window: Optional[int],
@@ -931,7 +976,8 @@ def _bwd_tile(
     to the float32 accumulators ``dv_acc`` / ``dk_acc`` — at ``acc_rows``,
     or where there is none at the tile's own key columns — and, where
     ``put_dq`` is given, its dq contribution ``ds @ K`` handed to
-    ``put_dq(rows, contribution)``."""
+    ``put_dq(rows, contribution)``.  ``lse_scr`` / ``delta_scr``: the query
+    block's statistics as :func:`_stage_stats` left them."""
 
     def tile(r0, rows, width, mask_from):
         """The tile's query rows ``[r0, r0 + rows)`` against its first
@@ -948,8 +994,8 @@ def _bwd_tile(
         # instead rounds the probability/ds operands to the input dtype
         # (full MXU rate, documented tolerance cost — see flash_attention)
         do32 = do if probs_bf16 else do.astype(jnp.float32)
-        lse = lse_ref[0, rows][:, :1]
-        delta = delta_ref[0, rows][:, :1]
+        lse = lse_scr[rows, :1]
+        delta = delta_scr[rows, :1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -998,8 +1044,8 @@ def _bwd_tile(
 
 
 def _bwd_dkv_body(
-    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dq_ref, dk_scr, dv_scr,
+    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref, lse_ref,
+    dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, lse_scr, delta_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
     nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
     window: Optional[int] = None, group: int = 1,
@@ -1019,7 +1065,7 @@ def _bwd_dkv_body(
       (``apex_flash_bwd_fused``) — each query block's dq is whole after
       its single key step, so ``ds @ K`` is written straight out, in the
       output dtype.  One s/p recompute instead of two, 5 MXU dots per
-      visited tile pair instead of 7, and q/k/v/do/lse/delta read once
+      visited tile pair instead of 7, and q/k/v/do/o/lse read once
       instead of twice (measured +4.5% end-to-end on the BERT step in r4.
       Ref capability: apex/contrib/csrc/multihead_attn/).  With several
       key blocks the query-major :func:`_bwd_sweep_kernel` does the same.
@@ -1052,14 +1098,23 @@ def _bwd_dkv_body(
             dq_ref[0, 0, rows] = contrib.astype(dq_ref.dtype)
 
     tile = _bwd_tile(
-        seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
+        seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_scr, delta_scr,
         dk_scr, dv_scr, put_dq, bh, qi, ki,
         scale=scale, block_q=block_q, block_k=block_k,
         dropout_rate=dropout_rate, probs_bf16=probs_bf16, window=window)
 
     @pl.when(run)
     def _body():
+        # q inner: every visited step brings another query block
+        _stage_stats(do_ref, o_ref, lse_ref, lse_scr, delta_scr)
         _for_pieces(block_q, block_k, nq, nk, causal, tile, window)
+
+    if dq_ref is not None and window is not None:
+        # more queries than keys under a window: a query block past the
+        # last key's band sees no key, runs no tile, and its dq is 0
+        @pl.when(jnp.logical_not(run))
+        def _zero_skipped_dq():
+            dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
 
     @pl.when(step == group * nq - 1)
     def _finalize():
@@ -1068,16 +1123,16 @@ def _bwd_dkv_body(
 
 
 def _bwd_dkv_kernel(
-    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_scr, dv_scr, **kw,
+    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref, lse_ref,
+    dk_ref, dv_ref, *scratch, **kw,
 ):
-    _bwd_dkv_body(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                  delta_ref, dk_ref, dv_ref, None, dk_scr, dv_scr, **kw)
+    _bwd_dkv_body(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
+                  lse_ref, dk_ref, dv_ref, None, *scratch, **kw)
 
 
 def _bwd_sweep_kernel(
-    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dk_ref, dv_ref, dq_scr, dk_acc, dv_acc,
+    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref, lse_ref,
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_acc, dv_acc, lse_scr, delta_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
     nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
     window: Optional[int] = None, group: int = 1,
@@ -1108,6 +1163,7 @@ def _bwd_sweep_kernel(
     @pl.when(ki == 0)
     def _init_dq():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        _stage_stats(do_ref, o_ref, lse_ref, lse_scr, delta_scr)
 
     @pl.when((head % group == 0) & (qi == 0))
     def _init_dkv():
@@ -1124,7 +1180,7 @@ def _bwd_sweep_kernel(
     # several key blocks: a tile is one piece (_causal_subtile), all of its
     # block_k columns, so the accumulators' rows are the key block's
     tile = _bwd_tile(
-        seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
+        seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_scr, delta_scr,
         dk_acc, dv_acc, put_dq, bh, qi, ki, acc_rows=keys,
         scale=scale, block_q=block_q, block_k=block_k,
         dropout_rate=dropout_rate, probs_bf16=probs_bf16, window=window)
@@ -1144,8 +1200,8 @@ def _bwd_sweep_kernel(
 
 
 def _bwd_dq_kernel(
-    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dbias_ref, dq_scr,
+    seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref, lse_ref,
+    dq_ref, dbias_ref, dq_scr, lse_scr, delta_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nq: int,
     nk: int, dropout_rate: float = 0.0, h_map=None, probs_bf16: bool = False,
     window: Optional[int] = None,
@@ -1157,6 +1213,7 @@ def _bwd_dq_kernel(
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        _stage_stats(do_ref, o_ref, lse_ref, lse_scr, delta_scr)
 
     run = True
     if causal:
@@ -1173,8 +1230,8 @@ def _bwd_dq_kernel(
         k = k_ref[0, cols]
         v = v_ref[0, cols]
         do = do_ref[0, rows]
-        lse = lse_ref[0, rows][:, :1]
-        delta = delta_ref[0, rows][:, :1]
+        lse = lse_scr[rows, :1]
+        delta = delta_scr[rows, :1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -1309,11 +1366,11 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -1321,7 +1378,7 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
     )(*inputs)
-    return out, lse[:, :, 0]
+    return out, lse.reshape(bh, sq)
 
 
 def _window_kw(window, group=1):
@@ -1343,10 +1400,10 @@ def _no_bias(kernel):
     return without_bias
 
 
-def _bwd_dq_only(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                 delta_ref, dq_ref, dq_scr, **kw):
-    _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, None, dq_scr, **kw)
+def _bwd_dq_only(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
+                 lse_ref, dq_ref, *scratch, **kw):
+    _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
+                   lse_ref, dq_ref, None, *scratch, **kw)
 
 
 def _sweep_acc_bytes(sk, d, d_v):
@@ -1385,10 +1442,11 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
     h = 1 if bias is None else bh // bias.shape[0]  # unexpanded-bias divisor
     nq = sq // block_q
     nk = sk // block_k
-    # delta_i = sum_d do * o  (flash-v2 trick: avoids recomputing p@v row sums)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    lse_b = jnp.broadcast_to(lse[:, :, None], (bh, sq, 128))
-    delta_b = jnp.broadcast_to(delta[:, :, None], (bh, sq, 128))
+    # the statistics cross HBM as one float32 a row: lse as the forward wrote
+    # it, a (1, block_q) block a query block; delta is made in the kernels
+    # from do and out (_stage_stats), which take do's blocks
+    lse = lse.reshape(bh, 1, sq)
+    stat_scratch = [pltpu.VMEM((block_q, 128), jnp.float32)] * 2
     with_bias = bias is not None
     acc_bytes = _sweep_acc_bytes(sk, d, d_v)
     sweeps = _bwd_sweeps(nk, with_bias and bias_grad, acc_bytes)
@@ -1397,6 +1455,11 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
     reg = obs.default_registry()
     reg.counter("ops.flash.bwd_calls").inc(1)
     reg.counter("ops.flash.bwd_sweeps").inc(sweeps)
+    # the statistics' bytes across HBM for this forward + backward: lse
+    # written once by the forward's kernel and read once by each of the
+    # backward's (one a sweep); delta adds none
+    reg.counter("ops.flash.stat_hbm_bytes").inc(
+        (1 + sweeps) * lse.size * lse.dtype.itemsize)
     kernel_kw = dict(
         scale=scale, causal=causal, block_q=block_q, block_k=block_k, nq=nq,
         nk=nk, dropout_rate=dropout_rate, h_map=h_map, probs_bf16=probs_bf16)
@@ -1417,8 +1480,9 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             in_specs.append(pl.BlockSpec(
                 (1, block_q, block_k), lambda b, i, j: (b // h, i, j)))
             inputs.append(bias)
-        in_specs += [by_q(d_v), by_q(128), by_q(128)]
-        inputs += [do, lse_b, delta_b]
+        in_specs += [by_q(d_v), by_q(d_v), pl.BlockSpec(
+            (1, 1, block_q), lambda b, i, j: (b, 0, i))]
+        inputs += [do, out, lse]
         return in_specs, inputs, by_q(d)
 
     if sweeps == 1 and nk > 1:
@@ -1451,6 +1515,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                 pltpu.VMEM((block_q, d), jnp.float32),
                 pltpu.VMEM((sk, d), jnp.float32),
                 pltpu.VMEM((sk, d_v), jnp.float32),
+                *stat_scratch,
             ],
             # the resident accumulators, and beside them what the tiles and
             # the pipeline's blocks take (_SWEEP_TILE_VMEM_BYTES)
@@ -1477,7 +1542,10 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
             return b * group + j // nq, qi, 0
     q_spec = pl.BlockSpec((1, block_q, d), q_index)
     do_spec = pl.BlockSpec((1, block_q, d_v), q_index)
-    stat_spec = pl.BlockSpec((1, block_q, 128), q_index)
+
+    def lse_index(b, i, j):
+        head, qi, _ = q_index(b, i, j)
+        return head, 0, qi
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
     v_spec = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, i, 0))
     bias_spec = pl.BlockSpec((1, block_q, block_k), lambda b, i, j: (b // h, j, i))
@@ -1486,8 +1554,8 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
     if with_bias:
         in_specs.append(bias_spec)
         inputs.append(bias)
-    in_specs += [do_spec, stat_spec, stat_spec]
-    inputs += [do, lse_b, delta_b]
+    in_specs += [do_spec, do_spec, pl.BlockSpec((1, 1, block_q), lse_index)]
+    inputs += [do, out, lse]
     dkv_call = dict(
         grid=(bhk, nk, group * nq),
         in_specs=in_specs,
@@ -1498,6 +1566,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
+            *stat_scratch,
         ],
     )
     kernel_kw.update(_window_kw(window, group))
@@ -1540,7 +1609,8 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
                 jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
                 jax.ShapeDtypeStruct((bh, sq, sk), jnp.float32),
             ],
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                            *stat_scratch],
         )(*inputs)
         return dq, dk, dv, dbias
     dq = _pallas_call(
@@ -1550,7 +1620,7 @@ def _flash_bwd(q, k, v, bias, seed, out, lse, do, scale, causal, block_q,
         in_specs=in_specs,
         out_specs=dq_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32), *stat_scratch],
     )(*inputs)
     return dq, dk, dv, None
 
@@ -1727,8 +1797,10 @@ def flash_attention(
     VMEM budget (``sk * (d + d_v) * 4`` bytes against 24 MiB: every head up
     to 16k positions at 128 + 128) — and twice, dkv then dq, past it and
     for ``bias_grad``.  The route is read from the shapes
-    (:func:`_bwd_sweeps`); a traced backward adds 1 to ``ops.flash.bwd_calls``
-    and its 1 or 2 to ``ops.flash.bwd_sweeps``.
+    (:func:`_bwd_sweeps`); a traced backward adds 1 to ``ops.flash.bwd_calls``,
+    its 1 or 2 to ``ops.flash.bwd_sweeps``, and to
+    ``ops.flash.stat_hbm_bytes`` the bytes ``lse`` takes across HBM: four a
+    query row a head for the forward's write and for each sweep's read.
 
     Differentiable in q/k/v, and in ``bias`` when ``bias_grad=True``: the
     dq backward pass then also emits the per-tile dL/dbias, summed over

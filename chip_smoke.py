@@ -258,6 +258,148 @@ def _us_on_device(fn, args, reps=16):
 
 
 # ---------------------------------------------------------------------------
+# flash attention's forward + gradient at the cells' calls
+# ---------------------------------------------------------------------------
+
+def _flash_call_at(tag: str, name: str, parity: Dict, key, *, hq, hkv, seq, d,
+                   d_v, window=None, causal=True, batch=1, bias=False,
+                   blocks=None, n=10) -> Dict:
+    """One ``flash_attention`` call, bfloat16: us a call forward and with
+    gradients, the Mosaic calls of the gradient program by name — on the
+    shipped route and, where the call has several key blocks, on the
+    two-pass route a head past the VMEM budget keeps (dkdv + dq) — the
+    routes held to each other and to the float32 reference taken a head at
+    a time (``parity``, under ``tag.name``)."""
+    import apex_tpu.ops.attention as attention_mod
+    from apex_tpu.ops import attention_ref, flash_attention
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    q_, k_, v_, w_ = jax.jit(lambda key: [
+        (jax.random.normal(ki, (batch, heads, seq, width), f32) * 0.3
+         ).astype(bf16)
+        for ki, heads, width in zip(jax.random.split(key, 4),
+                                    (hq, hkv, hkv, hq), (d, d, d_v, d_v))
+    ])(key)
+    extra = ()
+    if bias:    # an additive mask a row, as BERT's padding mask arrives
+        extra = (jax.jit(lambda key: jax.random.normal(
+            key, (batch, seq, seq), f32))(jax.random.fold_in(key, 7)),)
+    kw_ = dict(causal=causal, window=window)
+    blocks = blocks or {}
+
+    def attend(q, k, v, *bias):
+        return flash_attention(q, k, v, *bias, **kw_, **blocks)
+
+    def loss(q, k, v, w, *bias):
+        return jnp.sum(attend(q, k, v, *bias).astype(f32) * w.astype(f32))
+
+    def ref_loss(q, k, v, w, *bias):
+        def head(t):
+            q1, k1, v1, *b1 = t
+            return attention_ref(
+                *(x[None, None] for x in (q1, k1, v1)),
+                *(x[None] for x in b1), **kw_)[0, 0]
+        flat = lambda t: t.reshape(-1, *t.shape[2:])
+        k, v = (jnp.repeat(t, hq // hkv, axis=1) for t in (k, v))
+        out = jax.lax.map(jax.checkpoint(head), tuple(map(flat, (q, k, v)))
+                          + tuple(jnp.repeat(b, hq, axis=0) for b in bias))
+        return jnp.sum(out.reshape(w.shape) * w)
+
+    args = (q_, k_, v_, w_, *extra)
+    rec = {"shape": [hq, hkv, seq, d, d_v, window],
+           "fwd_us": _us_a_call(jax.jit(attend), (q_, k_, v_, *extra), n)}
+    if batch > 1 or bias:
+        rec["shape"] = [batch, *rec["shape"], bool(causal), bool(bias)]
+    grads = {}
+    budget = attention_mod._SWEEP_ACC_BUDGET_BYTES
+    several = seq > blocks.get("block_k", attention_mod.MAX_AUTO_BLOCK_K)
+    try:
+        for route, room in (("shipped", budget), ("two_pass", 0)):
+            if route == "two_pass" and not several:
+                continue    # one key block: the budget is not asked
+            # read when the call is traced, and part of the trace's key
+            attention_mod._SWEEP_ACC_BUDGET_BYTES = room
+            compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+                *args).compile()
+            rec[route + "_kernels"] = mosaic_call_names(compiled.as_text())
+            rec[route + "_grad_us"] = _us_a_call(compiled, args, n)
+            grads[route] = compiled(*args)
+    finally:
+        attention_mod._SWEEP_ACC_BUDGET_BYTES = budget
+    with jax.default_matmul_precision("highest"):
+        grads["ref"] = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(
+            *(t.astype(f32) for t in args))
+    for other, tol in (("two_pass", 1e-2), ("ref", 3e-2)):
+        for gname, g, o in zip(("dq", "dk", "dv"), grads["shipped"],
+                               grads.get(other, ())):
+            _compare(f"{tag}.{name}.{gname}_vs_{other}", g, o, tol, parity)
+    return rec
+
+
+def flash_backward_routes(s: int, root_key, parity: Dict) -> Dict:
+    """Flash attention's backward at the three 8k cells' calls (a row of 8
+    contexts: Moonlight's 16 heads at 192 / 128, Trinity-Mini's 32 query
+    heads to 4 at 128 with and without its window, Qwen3-Next's 16 to 2 at
+    256) and at smallthinker.train-16k's (a row of 16 contexts, 28 query
+    heads to 4 — groups of SEVEN — at 128, the 4096-wide band and the whole
+    triangle, dk / dv accumulators of 16.8 MB a key/value head): the shipped
+    route — ONE sweep, dk and dv resident in VMEM — against the two-pass
+    route (the kernels these shapes ran before the one sweep) and against
+    the float32 reference; us a call forward and with gradients for both."""
+    sw, hl = 8 * s, s // 64
+    hs = max(hl // 4, 1)
+    blocks = dict(block_q=sw // 16, block_k=sw // 8)   # the auto blocks at 8k
+    routes = {}
+    for i, (name, hq, hkv, d, d_v, window, seq, n) in enumerate((
+            ("moonlight", hl, hl, 192, 128, None, sw, 10),
+            ("trinity_window", 2 * hl, hs, 128, 128, sw // 4, sw, 10),
+            ("trinity_full", 2 * hl, hs, 128, 128, None, sw, 10),
+            ("qwen3_next", hl, max(hl // 8, 1), 256, 256, None, sw, 10),
+            ("smallthinker_window", 7 * hs, hs, 128, 128, sw // 2, 2 * sw, 3),
+            ("smallthinker_full", 7 * hs, hs, 128, 128, None, 2 * sw, 3))):
+        # the keys the probe has drawn its inputs from since PRs 31 and 37
+        key = jax.random.fold_in(root_key, 100 + i if i < 4 else 106 + i)
+        routes[name] = _flash_call_at(
+            "flash_backward", name, parity, key, hq=hq, hkv=hkv, seq=seq, d=d,
+            d_v=d_v, window=window, blocks=blocks, n=n)
+    return routes
+
+
+def flash_stats_at_cell(s: int, root_key, parity: Dict, routes: Dict) -> Dict:
+    """The forward + gradient of ONE layer's attention call of each of the
+    seven cells, a record a cell: us a call, the Mosaic calls by name, the
+    gradients held to the float32 reference.  What the softmax statistics'
+    layout between the kernels costs shows here in every route: GPT-2
+    small's ``[16, 12, 1024, 64]`` causal and BERT-large's ``[12, 16, 512,
+    64]`` under an additive mask (one key block: ``apex_flash_bwd_fused``),
+    LFM2's 32 query heads to 8 of 64 at 16k and the four calls
+    :func:`flash_backward_routes` has timed already (``routes``: the one
+    sweep, and the two passes no cell runs), whose records are these."""
+    hl = s // 64
+    cells = {
+        "gpt2-small.train": dict(
+            batch=hl, hq=max(3 * hl // 4, 1), seq=s, d=64),
+        "bert-large.train": dict(
+            batch=max(3 * hl // 4, 1), hq=hl, seq=s // 2, d=64, causal=False,
+            bias=True),
+        "lfm2.train-16k": dict(
+            hq=2 * hl, hkv=max(hl // 2, 1), seq=16 * s, d=64, n=3),
+    }
+    out = {}
+    for i, (cell, at) in enumerate(cells.items()):
+        at.setdefault("hkv", at["hq"])
+        out[cell] = _flash_call_at(
+            "flash_stats_at_cell", cell, parity,
+            jax.random.fold_in(root_key, 170 + i), d_v=at["d"], **at)
+    for cell, name in (("trinity-mini.train-8k", "trinity_window"),
+                       ("qwen3-next.train-8k", "qwen3_next"),
+                       ("moonlight.train-8k", "moonlight"),
+                       ("smallthinker.train-16k", "smallthinker_window")):
+        out[cell] = routes[name]
+    return out
+
+
+# ---------------------------------------------------------------------------
 # the expert layer's routing plan, one making, at the sparse cells' shapes
 # ---------------------------------------------------------------------------
 
@@ -807,77 +949,11 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     run("conv1d", conv_loss(None), conv_loss(False), (xc, wc, w_conv),
         (xc.astype(f32), wc, w_conv), 2, (1e-2, (("dx", 1e-2), ("dw", 1e-3))))
 
-    # flash attention's backward at the three 8k cells' calls (a row of 8
-    # contexts: Moonlight's 16 heads at 192 / 128, Trinity-Mini's 32 query
-    # heads to 4 at 128 with and without its window, Qwen3-Next's 16 to 2 at
-    # 256): the shipped route — ONE sweep, dk and dv resident in VMEM —
-    # against the two-pass route a head past the VMEM budget keeps (dkdv +
-    # dq, the kernels these shapes ran before the one sweep) and against the
-    # float32 reference; us a call forward and with gradients for both
-    import apex_tpu.ops.attention as attention_mod
-
-    routes = facts["flash_backward"] = {}
-    blocks = dict(block_q=sw // 16, block_k=sw // 8)   # the auto blocks at 8k
-
-    def backward_routes(name, hq, hkv, d, d_v, window, key, seq=sw, n=10):
-        q_, k_, v_, w_ = jax.jit(lambda key: [
-            (normal(ki, (1, heads, seq, width), f32) * 0.3).astype(bf16)
-            for ki, heads, width in zip(jax.random.split(key, 4),
-                                        (hq, hkv, hkv, hq), (d, d, d_v, d_v))
-        ])(key)
-        kw_ = dict(causal=True, window=window)
-
-        def attend(q, k, v):
-            return flash_attention(q, k, v, **kw_, **blocks)
-
-        def loss(q, k, v, w):
-            return jnp.sum(attend(q, k, v).astype(f32) * w.astype(f32))
-
-        def ref_loss(q, k, v, w):
-            head = jax.checkpoint(lambda t: attention_ref(
-                *(x[None, None] for x in t), **kw_)[0, 0])
-            k, v = (jnp.repeat(t[0], hq // hkv, axis=0) for t in (k, v))
-            return jnp.sum(jax.lax.map(head, (q[0], k, v))[None] * w)
-
-        rec = routes[name] = {
-            "shape": [hq, hkv, seq, d, d_v, window],
-            "fwd_us": _us_a_call(jax.jit(attend), (q_, k_, v_), n)}
-        grads = {}
-        budget = attention_mod._SWEEP_ACC_BUDGET_BYTES
-        try:
-            for route, room in (("shipped", budget), ("two_pass", 0)):
-                # read when the call is traced, and part of the trace's key
-                attention_mod._SWEEP_ACC_BUDGET_BYTES = room
-                compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
-                    q_, k_, v_, w_).compile()
-                rec[route + "_kernels"] = mosaic_call_names(compiled.as_text())
-                rec[route + "_grad_us"] = _us_a_call(compiled, (q_, k_, v_, w_), n)
-                grads[route] = compiled(q_, k_, v_, w_)
-        finally:
-            attention_mod._SWEEP_ACC_BUDGET_BYTES = budget
-        with jax.default_matmul_precision("highest"):
-            grads["ref"] = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(
-                *(t.astype(f32) for t in (q_, k_, v_, w_)))
-        for other, tol in (("two_pass", 1e-2), ("ref", 3e-2)):
-            for gname, g, o in zip(("dq", "dk", "dv"), grads["shipped"],
-                                   grads[other]):
-                _compare(f"flash_backward.{name}.{gname}_vs_{other}", g, o,
-                         tol, parity)
-
-    for i, case in enumerate((
-            ("moonlight", hl, hl, 192, 128, None),
-            ("trinity_window", 2 * hl, max(hl // 4, 1), 128, 128, sw // 4),
-            ("trinity_full", 2 * hl, max(hl // 4, 1), 128, 128, None),
-            ("qwen3_next", hl, max(hl // 8, 1), 256, 256, None))):
-        backward_routes(*case, jax.random.fold_in(root_key, 100 + i))
-    # ... and at smallthinker.train-16k's: a row of 16 contexts, 28 query
-    # heads to 4 (groups of SEVEN) at 128, the 4096-wide band and the whole
-    # triangle, dk / dv accumulators of 16.8 MB a key/value head
-    hs = max(hl // 4, 1)
-    for i, (name, window) in enumerate((("smallthinker_window", sw // 2),
-                                        ("smallthinker_full", None))):
-        backward_routes(name, 7 * hs, hs, 128, 128, window,
-                        jax.random.fold_in(root_key, 110 + i), seq=2 * sw, n=3)
+    # flash attention's backward at the sparse cells' calls, both routes, and
+    # one layer's call of every cell with what its kernels are named
+    facts["flash_backward"] = flash_backward_routes(s, root_key, parity)
+    facts["flash_stats_at_cell"] = flash_stats_at_cell(
+        s, root_key, parity, facts["flash_backward"])
 
     # the gated short convolution at lfm2.train-16k's call — a row of 16
     # contexts of a convolution layer's in_proj output, [B | C | X] three

@@ -78,6 +78,22 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
         assert {"fwd_us", "shipped_grad_us", "two_pass_grad_us",
                 "shipped_kernels", "two_pass_kernels"} <= set(rec)
     assert "flash_backward.trinity_window.dk_vs_two_pass" in kernels["parity"]
+    # one layer's call of each of the seven cells: the dense cells' and
+    # LFM2's timed here, the other four the records above
+    stats = kernels["flash_stats_at_cell"]
+    assert len(stats) == 7
+    assert stats["gpt2-small.train"]["shape"] == [
+        TINY.ctx // 64, 1, 1, TINY.ctx, 64, 64, None, True, False]
+    assert stats["bert-large.train"]["shape"][-2:] == [False, True]
+    assert stats["lfm2.train-16k"]["shape"][:3] == [4, 1, 16 * TINY.ctx]
+    assert stats["moonlight.train-8k"] == routes["moonlight"]
+    for cell, rec in stats.items():
+        assert {"fwd_us", "shipped_grad_us", "shipped_kernels"} <= set(rec)
+    # one key block asks no budget: the one route is the only one
+    assert "two_pass_grad_us" not in stats["bert-large.train"]
+    assert "two_pass_grad_us" in stats["lfm2.train-16k"]
+    for cell in ("gpt2-small.train", "bert-large.train", "lfm2.train-16k"):
+        assert f"flash_stats_at_cell.{cell}.dq_vs_ref" in kernels["parity"]
     # the row movement at hidden 2560: every pass timed on both paths and
     # held to the other
     rows = kernels["moe_rows_at_2560"]

@@ -687,20 +687,11 @@ def test_one_sweep_backward_is_one_kernel_and_no_partials(rng):
             shape, dtype = x.aval.shape, x.aval.dtype
             assert not (dtype == jnp.float32 and len(shape) == 4
                         and shape[0] == nk), (e.primitive.name, shape)
-    after = eqns[eqns.index(call) + 1:]
+    # what follows the kernel in XLA (its own body, where delta is summed
+    # over a row's d_v, comes first in the walk)
+    body = len(list(_walk_eqns(call.params["jaxpr"])))
+    after = eqns[eqns.index(call) + 1 + body:]
     assert "reduce_sum" not in [e.primitive.name for e in after]
-
-
-# sha256 of str(jaxpr) of the gradient at the two cells whose backward has
-# ONE key block, taken from the commit before the one-sweep backward (PR 34,
-# 988b87c): gpt2-small.train's and bert-large.train's programs must not
-# change by a byte
-_NK1_JAXPR_SHA256 = {
-    "gpt2": "32013b9116c40ecb",
-    "bert": "12e8d9094239416a",
-    "cross": "6b91cf45ab00148a",
-    "cross_causal": "4c0fbd4919c0be04",
-}
 
 
 @pytest.mark.parametrize("name,hq,hkv,sq,sk,d,d_v,causal,bias,drop", [
@@ -711,10 +702,12 @@ _NK1_JAXPR_SHA256 = {
 ])
 def test_one_key_block_lowers_to_the_text_it_lowered_to(
         name, hq, hkv, sq, sk, d, d_v, causal, bias, drop):
-    """nk = 1 keeps apex_flash_bwd_fused exactly as it was: the gradient's
-    jaxpr, kernel bodies included, is the text the parent traced."""
-    import hashlib
-
+    """nk = 1 — gpt2-small.train's and bert-large.train's calls, and cross
+    attention — keeps the program PR 34 guarded by its text, held by its
+    structure since the statistics' layout changed that text (PR 41): the
+    backward is ONE ``apex_flash_bwd_fused`` kernel of five ``dot_general``s
+    a piece and straight-line code, and dq, dk and dv leave it in the input
+    dtype, dk and dv at the key/value heads' shapes."""
     q, k, v = (jnp.zeros((2, h_, s_, d_), jnp.bfloat16)
                for h_, s_, d_ in ((hq, sq, d), (hkv, sk, d), (hkv, sk, d_v)))
     b = jnp.zeros((2, sq, sk), jnp.float32) if bias else None
@@ -724,10 +717,27 @@ def test_one_key_block_lowers_to_the_text_it_lowered_to(
             q, k, v, b, causal=causal, use_pallas=True, dropout_rate=drop,
             dropout_seed=jnp.int32(3) if drop else None).astype(jnp.float32))
 
-    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
-    assert "apex_flash_bwd_fused" in text
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        _NK1_JAXPR_SHA256[name]
+    grad = jax.grad(loss, (0, 1, 2))
+    bodies = _kernel_primitives(grad, q, k, v)
+    assert sorted(bodies) == _ONE_KEY_BLOCK
+    # the pieces a causal head that is ONE grid tile is taken in (gpt2's
+    # eight query sub-tiles); every other call is one piece a tile
+    block_q = sq if causal and sq == sk else 512
+    sub_q, _ = attention_mod._causal_subtile(
+        block_q, sk, sq // block_q, 1, causal)
+    assert bodies["apex_flash_bwd_fused"].count("dot_general") == \
+        5 * (block_q // sub_q)
+    assert bodies["apex_flash_fwd"].count("dot_general") == \
+        2 * (block_q // sub_q)
+    for prims in bodies.values():
+        assert not {"while", "scan"} & set(prims)
+    (call,) = [e for e in _walk_eqns(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+               if e.primitive.name == "pallas_call"
+               and e.params["name"].startswith("apex_flash_bwd")]
+    assert sorted((x.aval.shape[-3:], x.aval.dtype) for x in call.outvars) == \
+        sorted([((2 * hkv, sk, d), jnp.bfloat16),
+                ((2 * hkv, sk, d_v), jnp.bfloat16),
+                ((2 * hq, sq, d), jnp.bfloat16)])
 
 
 def _case(hq, hkv, sq, sk, d, d_v, seed=0):
@@ -758,24 +768,142 @@ def _case(hq, hkv, sq, sk, d, d_v, seed=0):
     # more query blocks than the keys' (cross attention), and one query block
     (2, 2, 1024, 64, 64, dict(causal=False)),
     (2, 2, 128, 64, 64, dict(causal=False)),
+    # query rows past the reach of the last key's band: the three last query
+    # blocks masked whole (l == 0, lse = -1e30 through the statistics' blocks)
+    (4, 2, 1024, 64, 64, dict(causal=True, window=129)),
 ], ids=["full", "causal", "win200", "g4", "g8_win150", "96_64", "96_64_g2",
-        "drop", "drop_win_g2", "sq_gt_sk", "one_q_block"])
+        "drop", "drop_win_g2", "sq_gt_sk", "one_q_block", "rows_masked_whole"])
 def test_one_sweep_backward_matches_ref(nk, hq, hkv, sq, d, d_v, kw):
     """dq, dk, dv of the one-sweep backward against ``attention_ref``'s
     float32 gradients, interpreted; dk and dv at the key/value heads'
     shapes."""
     sk = 512
     q, k, v, do = _case(hq, hkv, sq, sk, d, d_v)
-    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v, **kw) * do)
-    flash = lambda *a, **kw_: flash_attention(
-        *a, block_q=128, block_k=sk // nk, use_pallas=True, **kw_)
-    assert _backward_kernels(q, k, v, block_q=128, block_k=sk // nk,
-                             **kw) == _SWEEP
-    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
-    want = jax.grad(loss(attention_ref), (0, 1, 2))(q, k, v)
-    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    blocks = dict(block_q=128, block_k=sk // nk)
+    assert _backward_kernels(q, k, v, **blocks, **kw) == _SWEEP
+    _grads_match_ref(q, k, v, do, blocks, kw)
+
+
+def _rows_with_no_key(sq, sk, window):
+    """The first query row of a causal call whose band, ``window`` keys back
+    from its own position, lies wholly past the last key (``sq`` where there
+    is none): from there on a row is masked whole."""
+    return sq if window is None else min(sq, sk - 1 + window)
+
+
+def _grads_match_ref(q, k, v, do, blocks, kw, diff=(0, 1, 2)):
+    """The kernels' gradients under ``blocks`` against ``attention_ref``'s
+    float32 ones at this file's tolerances.  Rows masked whole carry no
+    cotangent: the kernels give them an output of 0, the reference's softmax
+    of a row of -1e30 the mean of v, and neither is asked for."""
+    live = _rows_with_no_key(q.shape[2], k.shape[2], kw.get("window"))
+    do = do.at[:, :, live:].set(0.0)
+    kw = dict(kw)
+    bias = kw.pop("bias", None)
+    args = (q, k, v) if bias is None else (q, k, v, bias)
+    loss = lambda fn, **kw_: lambda q, k, v, *b: jnp.sum(
+        fn(q, k, v, *b, **kw_) * do)
+    got = jax.grad(loss(flash_attention, **blocks, use_pallas=True, **kw),
+                   diff)(*args)
+    kw.pop("bias_grad", None)
+    want = jax.grad(loss(attention_ref, **kw), diff)(*args)
+    assert [g.shape for g in got] == [args[i].shape for i in diff]
     for a, b_ in zip(got, want):
         np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+
+# the bytes the softmax statistics take across HBM (PR 41): one case a
+# backward route, every one with dropout on and v at a head size of its own;
+# the three that take no bias also under a window, with grouped heads and —
+# sq 512 over sk 256 under a window of 129 — with the last query block's rows
+# masked WHOLE (l == 0: the forward's l_safe branch writes their lse, -1e30)
+_BAND = dict(hq=4, hkv=2, sk=256, kw=dict(window=129))
+_STAT_ROUTES = {
+    "fused": dict(_BAND, blocks=dict(block_q=128, block_k=256),
+                  kernels=_ONE_KEY_BLOCK),
+    "sweep": dict(_BAND, blocks=dict(block_q=128, block_k=64), kernels=_SWEEP),
+    "dkdv_dq": dict(_BAND, blocks=dict(block_q=128, block_k=64),
+                    kernels=_TWO_PASS),
+    # two heads: the bias itself is then no array of bh x sq x 128 elements
+    "dq_dbias": dict(hq=2, hkv=2, sk=512, kw=dict(bias_grad=True),
+                     blocks=dict(block_q=128, block_k=128),
+                     kernels=["apex_flash_bwd_dkdv", "apex_flash_bwd_dq_dbias",
+                              "apex_flash_fwd"]),
+}
+
+
+@pytest.mark.parametrize("route", sorted(_STAT_ROUTES))
+def test_statistics_cross_hbm_at_four_bytes_a_row(route, monkeypatch):
+    """No float32 array of ``bh x sq x 128`` elements — the lane-broadcast
+    ``lse`` and ``delta`` that were — is an operand or a result of any flash
+    kernel or is broadcast anywhere in the gradient's program; the ``lse``
+    kept for the backward is ``(bh, sq)`` float32; ``ops.flash.stat_hbm_bytes``
+    counts four bytes a row a head for the forward's write and for each
+    backward kernel's read; and the gradients are the reference's."""
+    from apex_tpu import obs
+    from apex_tpu.remat import FLASH_LSE
+
+    at = _STAT_ROUTES[route]
+    blocks, kernels, bh, sk = at["blocks"], at["kernels"], at["hq"], at["sk"]
+    if kernels == _TWO_PASS:
+        monkeypatch.setattr(attention_mod, "_SWEEP_ACC_BUDGET_BYTES", 0)
+    sq, d, d_v = 512, 96, 64
+    # a scale no other test traces: the counter moves when the kernels are
+    # TRACED, and calls of one signature share a trace (_flash_jit)
+    kw = dict(at["kw"], causal=True, dropout_rate=0.1,
+              dropout_seed=jnp.int32(13), scale=0.1015625)
+    q, k, v, do = _case(bh, at["hkv"], sq, sk, d, d_v, seed=41)
+    bias = None
+    if "bias_grad" in kw:
+        bias = 0.5 * jax.random.normal(jax.random.PRNGKey(5), (1, sq, sk))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, bias, **blocks, use_pallas=True, **kw) * do)
+
+    counter = obs.default_registry().counter("ops.flash.stat_hbm_bytes")
+    before = counter.snapshot()["value"]
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr
+    assert counter.snapshot()["value"] - before == len(kernels) * bh * sq * 4
+
+    eqns = list(_walk_eqns(jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"].startswith("apex_flash_")]
+    assert sorted(e.params["name"] for e in calls) == kernels
+    wide = lambda x: (x.aval.dtype == jnp.float32
+                      and x.aval.size == bh * sq * 128)
+    for e in calls:
+        assert not [x.aval.shape for x in (*e.invars, *e.outvars) if wide(x)], \
+            e.params["name"]
+        # lse, one float32 a row: the one operand or result of that size
+        stats = [x.aval.shape for x in (*e.invars, *e.outvars)
+                 if x.aval.dtype == jnp.float32 and x.aval.size == bh * sq]
+        assert stats == [(bh, 1, sq)], (e.params["name"], stats)
+    for e in eqns:
+        if e.primitive.name == "broadcast_in_dim":
+            assert not wide(e.outvars[0]), e
+    (kept,) = [e for e in eqns if e.primitive.name == "name"
+               and e.params["name"] == FLASH_LSE]
+    assert (kept.outvars[0].aval.shape, kept.outvars[0].aval.dtype) == \
+        ((bh, sq), jnp.float32)
+
+    if "window" in kw:
+        # the rows past the band's reach of the last key: masked whole
+        live = _rows_with_no_key(sq, sk, kw["window"])
+        assert live == 384
+        seed3 = attention_mod._pack_seed(kw["dropout_seed"], 0, 0)
+        out, lse = attention_mod._flash_fwd(
+            q[0], k[0], v[0], None, seed3, kw["scale"], True,
+            blocks["block_q"], blocks["block_k"], kw["dropout_rate"],
+            window=kw["window"])
+        assert lse.shape == (bh, sq) and lse.dtype == jnp.float32
+        np.testing.assert_array_equal(lse[:, live:], np.float32(-1e30))
+        assert np.all(np.asarray(lse[:, :live]) > -1e3)
+        np.testing.assert_array_equal(out[:, live:], 0.0)
+    if bias is None:
+        _grads_match_ref(q, k, v, do, blocks, kw)
+    else:
+        _grads_match_ref(q, k, v, do, blocks, dict(kw, bias=bias), (0, 1, 2, 3))
 
 
 @_ROUTES

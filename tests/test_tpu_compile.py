@@ -419,7 +419,8 @@ def _flash_calls(chip, scope, q, k, v, window=None):
     described chip — the compile is what refuses a VMEM overrun, so a pass
     says Mosaic took the backward's resident accumulators at this shape.
     ``(names, widths)``: its Mosaic calls' names, and for each the sorted
-    last dimensions of its bfloat16 operands and results."""
+    last dimensions of its bfloat16 operands and results (the backward's
+    are q, k, v, do, o — delta is made from it inside — dq, dk, dv)."""
     import re
 
     from apex_tpu.ops import flash_attention
@@ -437,6 +438,11 @@ def _flash_calls(chip, scope, q, k, v, window=None):
     calls = [l for l in text.splitlines()
              if "tpu_custom_call" in l and "apex_flash" in l and " = " in l]
     assert len(calls) == len(names)
+    # the softmax statistics cross the calls at one float32 a row: lse as
+    # (heads, 1, positions), delta not at all — nothing 128 lanes wide
+    for l in calls:
+        assert not re.search(r"f32\[\d+,\d+,128\]", l), l
+        assert re.search(r"f32\[\d+,1,\d+\]", l), l
     widths = [sorted(map(int, re.findall(r"bf16\[\d+,\d+,(\d+)\]", l)))
               for l in calls]
     return names, widths
@@ -457,8 +463,8 @@ def test_flash_window_grouped_heads_compiles(chip, as_tpu, window):
     q, kv = (1, 32, 8192, 128), (1, 4, 8192, 128)
     names, widths = _flash_calls(chip, "attn_window", q, kv, kv, window)
     assert names == _ONE_SWEEP, names
-    # forward: q, k, v, o; backward: q, k, v, do, dq, dk, dv
-    assert widths == [[128] * 4, [128] * 7], widths
+    # forward: q, k, v, o; backward: q, k, v, do, o, dq, dk, dv
+    assert widths == [[128] * 4, [128] * 8], widths
 
 
 @pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
@@ -477,7 +483,7 @@ def test_flash_groups_of_seven_at_16k_compile(chip, as_tpu, window):
     names, widths = _flash_calls(
         chip, "attn_window" if window else "attn_full", q, kv, kv, window)
     assert names == _ONE_SWEEP, names
-    assert widths == [[128] * 4, [128] * 7], widths
+    assert widths == [[128] * 4, [128] * 8], widths
 
 
 def test_flash_groups_of_four_of_size_64_at_16k_compile(chip, as_tpu):
@@ -496,7 +502,7 @@ def test_flash_groups_of_four_of_size_64_at_16k_compile(chip, as_tpu):
     q, kv = (1, 32, 16384, 64), (1, 8, 16384, 64)
     names, widths = _flash_calls(chip, "attn_full", q, kv, kv)
     assert names == _ONE_SWEEP, names
-    assert widths == [[64] * 4, [64] * 7], widths
+    assert widths == [[64] * 4, [64] * 8], widths
 
 
 def test_gated_conv_reads_and_writes_the_projection_in_place(chip, as_tpu):
@@ -538,7 +544,7 @@ def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
     q, kv = (1, 16, 8192, 256), (1, 2, 8192, 256)
     names, widths = _flash_calls(chip, "attn_full", q, kv, kv)
     assert names == _ONE_SWEEP, names
-    assert widths == [[256] * 4, [256] * 7], widths
+    assert widths == [[256] * 4, [256] * 8], widths
 
 
 def test_flash_latent_head_sizes_compile_without_padding(chip, as_tpu):
@@ -556,7 +562,7 @@ def test_flash_latent_head_sizes_compile_without_padding(chip, as_tpu):
     assert names == _ONE_SWEEP, names
     assert widths == [
         [128, 128, 192, 192],                    # forward: v, o | q, k
-        [128, 128, 128, 192, 192, 192, 192],     # v, do, dv | q, k, dq, dk
+        [128, 128, 128, 128, 192, 192, 192, 192],  # v, do, o, dv | q, k, dq, dk
     ], widths
 
 
