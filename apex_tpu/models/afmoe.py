@@ -1,13 +1,11 @@
 """Arcee ``afmoe`` decoder LM (Trinity family) on the training path.
 
-The second decoder block of the zoo's three (``models/gpt.py`` is the first,
-``models/qwen3_next.py`` the third): four
-RMSNorms a block in sandwich position, grouped-query flash attention with
-an output gate and normed queries and keys, rotary positions on the
+The block: four RMSNorms in sandwich position, grouped-query flash attention
+with an output gate and normed queries and keys, rotary positions on the
 sliding-window layers only (the full-attention layers carry no position
-signal), gated SiLU MLPs, and — past the leading dense layers — sigmoid
-routed experts beside a shared one (``parallel/moe.py::ExpertShardMLP``).
-Layers of unequal kind are chosen by ``layer_types``.
+signal), gated SiLU MLPs, and — past the leading dense layers — sigmoid routed
+experts beside a shared one (``parallel/moe.py::ExpertShardMLP``). Layers of
+unequal kind are chosen by ``layer_types``.
 
 Per block (no biases anywhere)::
 
@@ -15,15 +13,8 @@ Per block (no biases anywhere)::
     Attn(x) = W_o (softmax(q k^T / sqrt(D), causal [, i - j < window]) v * sigmoid(W_g x))
 
 ``h = E[ids] * sqrt(hidden)`` (``mup_enabled``), ``logits = W_head RMS(h)``,
-the head untied.  Called as :class:`apex_tpu.models.gpt.GPTLM` is:
-``model.apply({"params": p}, ids, labels=labels, deterministic=...)`` ->
-``(logits, loss)``.
-
-Expert parallelism enters as ``experts_held``: this model instance holds
-that range of each expert layer's routed experts, routes over all
-``num_experts`` and computes its own experts' part (one chip's share before
-the exchange; the exchange itself is not built yet — ROADMAP M2).
-``vocab_size`` is likewise whatever slice of the vocabulary is held.
+the head untied.  The shell, how it is called and how expert parallelism
+enters (``experts_held``, a sliced ``vocab_size``): ``models/decoder.py``.
 
 Serving methods (``prefill``, ``decode_*``) are not part of this model yet.
 """
@@ -36,13 +27,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.amp.layers import Dense
-from apex_tpu.ops.attention import flash_attention
-from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
+from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
+                                     linear, merge_heads, rotary, split_heads)
 from apex_tpu.parallel.moe import ExpertShardMLP, SwiGLU
-from apex_tpu.remat import remat_module
 
-__all__ = ["AfmoeConfig", "AfmoeLayer", "AfmoeLM", "RMSNorm", "rotary"]
+__all__ = ["AfmoeConfig", "AfmoeLayer", "AfmoeLM"]
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 
@@ -91,37 +80,6 @@ class AfmoeConfig:
         return AfmoeConfig(**base)
 
 
-class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in
-    float32 (XLA's fusion: it merges with the residual add and the casts
-    around it; no Pallas kernel)."""
-
-    eps: float = 1e-5
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones_init(),
-                           (x.shape[-1],), jnp.float32)
-        x32 = x.astype(jnp.float32)
-        inv = jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (x32 * inv * scale.astype(jnp.float32)).astype(self.dtype)
-
-
-def rotary(x, theta: float):
-    """Rotate ``x`` (..., seq, D) by position over the whole head, the two
-    halves paired (``rotate_half``); float32 inside, ``x``'s dtype out."""
-    s, d = x.shape[-2], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-    x32 = x.astype(jnp.float32)
-    half = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], axis=-1)
-    return (x32 * cos + half * sin).astype(x.dtype)
-
-
 class AfmoeLayer(nn.Module):
     """One block; ``index`` picks its attention (``cfg.layer_types``) and
     its feed-forward (dense below ``cfg.num_dense_layers``)."""
@@ -142,24 +100,18 @@ class AfmoeLayer(nn.Module):
 
         y = norm("input_norm")(x)
         # one projection: queries, keys, values and the output gate
-        qkvg = Dense((2 * hq + 2 * hk) * hd, use_bias=False, dtype=dt,
-                     kernel_init=init, name="qkvg")(y)
+        qkvg = linear(cfg, (2 * hq + 2 * hk) * hd, "qkvg")(y)
         q, k, v, g = jnp.split(
             qkvg, [hq * hd, (hq + hk) * hd, (hq + 2 * hk) * hd], axis=-1)
-        heads = lambda t, n: t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
-        q = norm("q_norm")(heads(q, hq))
-        k = norm("k_norm")(heads(k, hk))
+        q = norm("q_norm")(split_heads(q, hq, hd))
+        k = norm("k_norm")(split_heads(k, hk, hd))
         if windowed:
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        with jax.named_scope("attn_window" if windowed else "attn_full"):
-            attn = flash_attention(
-                q, k, heads(v, hk), causal=True,
-                window=cfg.sliding_window if windowed else None)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
+        attn = merge_heads(causal_attention(
+            q, k, split_heads(v, hk, hd),
+            window=cfg.sliding_window if windowed else None))
         attn = attn * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
-        attn = Dense(h, use_bias=False, dtype=dt, kernel_init=init,
-                     name="o_proj")(attn)
-        x = x + norm("post_attn_norm")(attn)
+        x = x + norm("post_attn_norm")(linear(cfg, h, "o_proj")(attn))
 
         y = norm("pre_mlp_norm")(x)
         if self.index < cfg.num_dense_layers:
@@ -176,51 +128,19 @@ class AfmoeLayer(nn.Module):
         return x + norm("post_mlp_norm")(ff)
 
 
-class AfmoeLM(nn.Module):
-    """Embedding, the blocks ``layer_<i>``, a final RMSNorm and the untied
-    head.  ``__call__(ids)`` returns (B, S, V) float32 logits; with
-    ``labels`` (-100: not predicted) also the token-mean fused-xentropy
-    loss, as :class:`apex_tpu.models.gpt.GPTLM` does."""
+class AfmoeLM(DecoderLM):
+    """The shell with the embedding scaled by ``sqrt(hidden)`` under
+    ``mup_enabled`` and the head untied."""
 
     cfg: AfmoeConfig
+    layer_cls = AfmoeLayer
 
-    def setup(self):
-        cfg = self.cfg
+    @staticmethod
+    def validate(cfg):
         for kind in cfg.layer_types:
             if kind not in (WINDOW, FULL):
                 raise ValueError(f"no layer type {kind!r}")
-        init = nn.initializers.normal(cfg.initializer_range)
-        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                              embedding_init=init, dtype=jnp.float32)
-        # deterministic is static_argnum 2 (self=0): called positionally
-        layer_cls = remat_module(AfmoeLayer, cfg.remat_policy,
-                                 static_argnums=(2,))
-        self.layers = [layer_cls(cfg, i, name=f"layer_{i}")
-                       for i in range(cfg.num_layers)]
-        self.norm_f = RMSNorm(cfg.rms_norm_eps, cfg.compute_dtype)
-        self.head = Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.compute_dtype, kernel_init=init)
 
-    def __call__(self, input_ids, labels=None, deterministic: bool = True):
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = self.embed(input_ids)
-            if cfg.mup_enabled:
-                x = x * (cfg.hidden_size ** 0.5)
-            x = x.astype(cfg.compute_dtype)
-        for layer in self.layers:
-            x = layer(x, deterministic)
-        x = self.norm_f(x)
-        with jax.named_scope("lm_head"):
-            logits = self.head(x).astype(jnp.float32)
-        if labels is None:
-            return logits
-        with jax.named_scope("lm_loss"):
-            valid = labels >= 0
-            safe = jnp.where(valid, labels, 0)
-            # compute-dtype logits into the fused loss, as GPTLM
-            per_tok = softmax_cross_entropy(
-                logits.astype(cfg.compute_dtype), safe)
-            n = jnp.maximum(jnp.sum(valid), 1)
-            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
-        return logits, loss
+    @staticmethod
+    def embed_scale(cfg):
+        return cfg.hidden_size ** 0.5 if cfg.mup_enabled else None

@@ -1,14 +1,12 @@
 """``deepseek_v3`` decoder LM (the block Moonlight-16B-A3B publishes its
 ``config.json`` under) on the training path.
 
-The fourth decoder block of the zoo (``models/gpt.py``, ``models/afmoe.py``,
-``models/qwen3_next.py``): two RMSNorms a block in pre-norm position,
-multi-head LATENT attention — keys and values made from one low-rank latent a
-token, one rotary key shared by all heads, queries and keys wider than values
-— through the flash kernels at a value head size of their own
-(``ops/attention.py``), a dense SwiGLU in the leading layers and, past them,
-sigmoid-routed experts under a selection bias beside a shared expert
-(``parallel/moe.py::ExpertShardMLP``).
+The block: two RMSNorms in pre-norm position, multi-head LATENT attention —
+keys and values made from one low-rank latent a token, one rotary key shared
+by all heads, queries and keys wider than values — through the flash kernels
+at a value head size of their own (``ops/attention.py``), a dense SwiGLU in
+the leading layers and, past them, sigmoid-routed experts under a selection
+bias beside a shared expert (``parallel/moe.py::ExpertShardMLP``).
 
 The equations (no biases anywhere; embeddings not scaled; head untied)::
 
@@ -48,13 +46,11 @@ plain causal-LM loss), the selection bias's loss-free update (it is a
 parameter held at its value, as ``models/afmoe.py``'s), rotary scaling
 (``max_position_embeddings`` positions are native).
 
-Called as :class:`apex_tpu.models.gpt.GPTLM`, ``AfmoeLM`` and ``Qwen3NextLM``
-are: ``model.apply({"params": p}, ids, labels=labels, deterministic=...)`` ->
-``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a sliced
-``vocab_size``, as in ``models/afmoe.py``.  Scopes ``mla_proj`` (everything
-before the kernel: the three projections, the latent's norm, the rotation,
-assembling q and k), ``attn_full`` (the flash call), ``mla_out``, the four
-``moe_*``, ``lm_head``, ``lm_loss``.  The shared
+The shell, how it is called and how expert parallelism enters
+(``experts_held``, a sliced ``vocab_size``): ``models/decoder.py``.  Scopes
+``mla_proj`` (everything before the kernel: the three projections, the
+latent's norm, the rotation, assembling q and k), ``attn_full`` (the flash
+call), ``mla_out``, the four ``moe_*``, ``lm_head``, ``lm_loss``.  The shared
 rotary key is broadcast to the heads and concatenated by XLA before the kernel
 (its gradient is the sum over heads of ``dk``'s rotary slice).  Serving
 methods are not part of this model yet: a latent cache row and the absorbed
@@ -69,12 +65,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.amp.layers import Dense
-from apex_tpu.models.afmoe import RMSNorm, rotary
-from apex_tpu.ops.attention import flash_attention
-from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
+from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
+                                     linear, merge_heads, rotary, split_heads)
 from apex_tpu.parallel.moe import ExpertShardMLP, SwiGLU
-from apex_tpu.remat import remat_module
 
 __all__ = ["DeepseekV3Config", "DeepseekV3Layer", "DeepseekV3LM",
            "LatentAttention"]
@@ -139,29 +132,24 @@ class LatentAttention(nn.Module):
         h, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
         r, dt = cfg.kv_lora_rank, cfg.compute_dtype
-        init = nn.initializers.normal(cfg.initializer_range)
-        dense = lambda n, name: Dense(n, use_bias=False, dtype=dt,
-                                      kernel_init=init, name=name)
-
         with jax.named_scope("mla_proj"):
-            q = dense(h * (dn + dr), "q_proj")(x).reshape(b, s, h, dn + dr)
+            q = linear(cfg, h * (dn + dr), "q_proj")(x).reshape(
+                b, s, h, dn + dr)
             # the down-projection: the latent and the rotary key, one product
-            c, k_pe = jnp.split(dense(r + dr, "kv_a_proj")(x), [r], axis=-1)
+            c, k_pe = jnp.split(linear(cfg, r + dr, "kv_a_proj")(x), [r], -1)
             c = RMSNorm(cfg.latent_norm_eps, dt, name="kv_a_norm")(c)
-            kv = dense(h * (dn + dv), "kv_b_proj")(c).reshape(b, s, h, dn + dv)
-            heads = lambda t: t.transpose(0, 2, 1, 3)        # (b, h, s, .)
-            q_nope, q_pe = jnp.split(heads(q), [dn], axis=-1)
-            k_nope, v = jnp.split(heads(kv), [dn], axis=-1)
+            kv = linear(cfg, h * (dn + dv), "kv_b_proj")(c).reshape(
+                b, s, h, dn + dv)
+            q_nope, q_pe = jnp.split(split_heads(q, h, dn + dr), [dn], axis=-1)
+            k_nope, v = jnp.split(split_heads(kv, h, dn + dv), [dn], axis=-1)
             q_pe = rotary(q_pe, cfg.rope_theta)
             k_pe = rotary(k_pe[:, None], cfg.rope_theta)     # (b, 1, s, dr)
             q = jnp.concatenate([q_nope, q_pe], axis=-1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_pe, (b, h, s, dr))], axis=-1)
-        with jax.named_scope("attn_full"):
-            attn = flash_attention(q, k, v, causal=True)     # (b, h, s, dv)
+        attn = causal_attention(q, k, v)                     # (b, h, s, dv)
         with jax.named_scope("mla_out"):
-            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
-            return dense(d, "o_proj")(attn)
+            return linear(cfg, d, "o_proj")(merge_heads(attn))
 
 
 class DeepseekV3Layer(nn.Module):
@@ -194,47 +182,13 @@ class DeepseekV3Layer(nn.Module):
         return x + ff
 
 
-class DeepseekV3LM(nn.Module):
-    """Embedding, the blocks ``layer_<i>``, a final RMSNorm and the untied
-    head.  ``__call__(ids)`` returns (B, S, V) float32 logits; with
-    ``labels`` (-100: not predicted) also the token-mean fused-xentropy
-    loss, as :class:`apex_tpu.models.gpt.GPTLM` does."""
+class DeepseekV3LM(DecoderLM):
+    """The shell as it stands: embeddings not scaled, the head untied."""
 
     cfg: DeepseekV3Config
+    layer_cls = DeepseekV3Layer
 
-    def setup(self):
-        cfg = self.cfg
+    @staticmethod
+    def validate(cfg):
         if cfg.qk_rope_head_dim % 2:
             raise ValueError("the rotary part of a head is not whole pairs")
-        init = nn.initializers.normal(cfg.initializer_range)
-        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                              embedding_init=init, dtype=jnp.float32)
-        # deterministic is static_argnum 2 (self=0): called positionally
-        layer_cls = remat_module(DeepseekV3Layer, cfg.remat_policy,
-                                 static_argnums=(2,))
-        self.layers = [layer_cls(cfg, i, name=f"layer_{i}")
-                       for i in range(cfg.num_layers)]
-        self.norm_f = RMSNorm(cfg.rms_norm_eps, cfg.compute_dtype)
-        self.head = Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.compute_dtype, kernel_init=init)
-
-    def __call__(self, input_ids, labels=None, deterministic: bool = True):
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = self.embed(input_ids).astype(cfg.compute_dtype)
-        for layer in self.layers:
-            x = layer(x, deterministic)
-        x = self.norm_f(x)
-        with jax.named_scope("lm_head"):
-            logits = self.head(x).astype(jnp.float32)
-        if labels is None:
-            return logits
-        with jax.named_scope("lm_loss"):
-            valid = labels >= 0
-            safe = jnp.where(valid, labels, 0)
-            # compute-dtype logits into the fused loss, as GPTLM
-            per_tok = softmax_cross_entropy(
-                logits.astype(cfg.compute_dtype), safe)
-            n = jnp.maximum(jnp.sum(valid), 1)
-            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
-        return logits, loss

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from apex_tpu.amp import functional as F
 from apex_tpu.amp.layers import Dense
+from apex_tpu.models.decoder import masked_token_mean_loss
 from apex_tpu.normalization import FusedLayerNorm
 from apex_tpu.ops.attention import (
     cached_attention,
@@ -34,7 +35,6 @@ from apex_tpu.ops.attention import (
     paged_cached_attention,
     quantize_kv,
 )
-from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
 from apex_tpu.remat import remat_module
 
 __all__ = ["GPTConfig", "GPTLayer", "GPTLM"]
@@ -351,16 +351,7 @@ class GPTLM(nn.Module):
         if labels is None:
             return logits
         with jax.named_scope("lm_loss"):
-            valid = labels >= 0
-            safe = jnp.where(valid, labels, 0)
-            # loss path takes compute-dtype logits (the reference xentropy
-            # kernel's half_to_float mode): at V=50k the logits are the
-            # biggest activation, and the fused loss upcasts internally
-            per_tok = softmax_cross_entropy(
-                logits.astype(cfg.compute_dtype), safe
-            )
-            n = jnp.maximum(jnp.sum(valid), 1)
-            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
+            loss = masked_token_mean_loss(logits, labels, cfg.compute_dtype)
         return logits, loss
 
     def _logits(self, x):

@@ -1,8 +1,6 @@
 """LFM2 decoder LM (Liquid AI's ``lfm2_moe`` family) on the training path.
 
-The sixth decoder block of the zoo (``models/gpt.py``, ``afmoe.py``,
-``qwen3_next.py``, ``deepseek_v3.py``, ``smallthinker.py`` are the others):
-two RMSNorms a block in pre-norm position and TWO KINDS OF MIXER under one
+The block: two RMSNorms in pre-norm position and TWO KINDS OF MIXER under one
 residual scheme, chosen by ``layer_types`` — a GATED SHORT CONVOLUTION
 (``conv``: no attention, no state beyond the last ``K - 1`` inputs; three
 layers of four at the published sizes) and grouped-query flash attention with
@@ -41,14 +39,12 @@ Left out: any router auxiliary loss (the step is the plain causal-LM loss),
 the selection bias's update, rotary scaling (the declared positions are
 native).
 
-Called as :class:`apex_tpu.models.gpt.GPTLM` and the other decoders are:
-``model.apply({"params": p}, ids, labels=labels, deterministic=...)`` ->
-``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a
-sliced ``vocab_size``, as in ``models/afmoe.py`` — the slice is of the
-embedding's rows, and so of the tied head's columns.  Scopes ``conv_proj``
-(``W_in``), ``conv_mix`` (the gated convolution alone), ``conv_out``
-(``W_out``), ``attn_full`` (the flash call), ``dense_ffn``, the three
-``moe_*``, ``embed``, ``lm_head``, ``lm_loss``.  Under ``remat_policy``
+The shell, how it is called and how expert parallelism enters
+(``experts_held``, a sliced ``vocab_size``): ``models/decoder.py`` — the slice
+is of the embedding's rows, and so of the tied head's columns.  Scopes
+``conv_proj`` (``W_in``), ``conv_mix`` (the gated convolution alone),
+``conv_out`` (``W_out``), ``attn_full`` (the flash call), ``dense_ffn``, the
+three ``moe_*``, ``embed``, ``lm_head``, ``lm_loss``.  Under ``remat_policy``
 ``full_block`` an attention layer keeps its input and the flash kernel's
 output and ``lse``, a convolution layer its input alone: the gated
 convolution runs again in the backward pass.  Serving methods are not part
@@ -64,14 +60,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.amp import functional as F
-from apex_tpu.amp.layers import Dense
-from apex_tpu.models.afmoe import RMSNorm, rotary
-from apex_tpu.ops.attention import flash_attention
+from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
+                                     linear, merge_heads, rotary, split_heads)
 from apex_tpu.ops.gated_conv import gated_short_conv
-from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
 from apex_tpu.parallel.moe import ExpertShardMLP, SwiGLU
-from apex_tpu.remat import remat_module
 
 __all__ = ["Lfm2Config", "Lfm2Layer", "Lfm2LM", "ShortConv"]
 
@@ -133,17 +125,15 @@ class ShortConv(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        d, dt = x.shape[-1], cfg.compute_dtype
-        init = nn.initializers.normal(cfg.initializer_range)
-        dense = lambda n, name: Dense(n, use_bias=False, dtype=dt,
-                                      kernel_init=init, name=name)
+        d = x.shape[-1]
         with jax.named_scope("conv_proj"):
-            bcx = dense(3 * d, "in_proj")(x)
+            bcx = linear(cfg, 3 * d, "in_proj")(x)
+        init = nn.initializers.normal(cfg.initializer_range)
         taps = self.param("taps", init, (d, cfg.conv_L_cache), jnp.float32)
         with jax.named_scope("conv_mix"):
             mixed = gated_short_conv(bcx, taps)
         with jax.named_scope("conv_out"):
-            return dense(d, "out_proj")(mixed)
+            return linear(cfg, d, "out_proj")(mixed)
 
 
 class Lfm2Layer(nn.Module):
@@ -167,17 +157,12 @@ class Lfm2Layer(nn.Module):
         if cfg.layer_types[self.index] == CONV:
             x = x + ShortConv(cfg, name="conv")(y)
         else:
-            qkv = Dense((hq + 2 * hk) * hd, use_bias=False, dtype=dt,
-                        kernel_init=init, name="qkv")(y)
+            qkv = linear(cfg, (hq + 2 * hk) * hd, "qkv")(y)
             q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
-            heads = lambda t, n: t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
-            q = rotary(norm("q_norm")(heads(q, hq)), cfg.rope_theta)
-            k = rotary(norm("k_norm")(heads(k, hk)), cfg.rope_theta)
-            with jax.named_scope("attn_full"):
-                attn = flash_attention(q, k, heads(v, hk), causal=True)
-            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
-            x = x + Dense(d, use_bias=False, dtype=dt, kernel_init=init,
-                          name="o_proj")(attn)
+            q = rotary(norm("q_norm")(split_heads(q, hq, hd)), cfg.rope_theta)
+            k = rotary(norm("k_norm")(split_heads(k, hk, hd)), cfg.rope_theta)
+            attn = causal_attention(q, k, split_heads(v, hk, hd))
+            x = x + linear(cfg, d, "o_proj")(merge_heads(attn))
 
         z = norm("pre_mlp_norm")(x)
         if self.index < cfg.num_dense_layers:
@@ -194,51 +179,20 @@ class Lfm2Layer(nn.Module):
         return x + ff
 
 
-class Lfm2LM(nn.Module):
-    """Embedding, the blocks ``layer_<i>``, a final RMSNorm and the head TIED
-    to the embedding.  ``__call__(ids)`` returns (B, S, V) float32 logits;
-    with ``labels`` (-100: not predicted) also the token-mean
-    fused-xentropy loss, as :class:`apex_tpu.models.gpt.GPTLM` does."""
+class Lfm2LM(DecoderLM):
+    """The shell with the head TIED to the embedding: the embedding's rows
+    (the vocabulary slice held) are the head's columns."""
 
     cfg: Lfm2Config
+    layer_cls = Lfm2Layer
+    eps_field = "norm_eps"
+    tied_head = True
 
-    def setup(self):
-        cfg = self.cfg
+    @staticmethod
+    def validate(cfg):
         for kind in cfg.layer_types:
             if kind not in (CONV, FULL):
                 raise ValueError(f"no layer type {kind!r}")
         if not 0 <= cfg.num_dense_layers <= cfg.num_layers:
             raise ValueError(f"num_dense_layers {cfg.num_dense_layers} of "
                              f"{cfg.num_layers} layers")
-        init = nn.initializers.normal(cfg.initializer_range)
-        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                              embedding_init=init, dtype=jnp.float32)
-        # deterministic is static_argnum 2 (self=0): called positionally
-        layer_cls = remat_module(Lfm2Layer, cfg.remat_policy,
-                                 static_argnums=(2,))
-        self.layers = [layer_cls(cfg, i, name=f"layer_{i}")
-                       for i in range(cfg.num_layers)]
-        self.norm_f = RMSNorm(cfg.norm_eps, cfg.compute_dtype)
-
-    def __call__(self, input_ids, labels=None, deterministic: bool = True):
-        cfg = self.cfg
-        dt = cfg.compute_dtype
-        with jax.named_scope("embed"):
-            x = self.embed(input_ids).astype(dt)
-        for layer in self.layers:
-            x = layer(x, deterministic)
-        x = self.norm_f(x)
-        with jax.named_scope("lm_head"):
-            # the tied head: the embedding's rows are the head's columns
-            logits = F.matmul(x.astype(dt), self.embed.embedding.T.astype(dt),
-                              preferred_element_type=jnp.float32)
-        if labels is None:
-            return logits
-        with jax.named_scope("lm_loss"):
-            valid = labels >= 0
-            safe = jnp.where(valid, labels, 0)
-            # compute-dtype logits into the fused loss, as GPTLM
-            per_tok = softmax_cross_entropy(logits.astype(dt), safe)
-            n = jnp.maximum(jnp.sum(valid), 1)
-            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
-        return logits, loss

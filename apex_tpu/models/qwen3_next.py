@@ -1,12 +1,11 @@
 """``qwen3_next`` decoder LM (Qwen3-Next family) on the training path.
 
-The third decoder block of the zoo (``models/gpt.py``, ``models/afmoe.py``):
-two zero-centred RMSNorms a block in pre-norm position, a token mixer that
+The block: two zero-centred RMSNorms in pre-norm position, a token mixer that
 is a gated delta net (linear attention: a recurrent float32 state per head,
-``ops/gated_delta.py``) except in every ``full_attention_interval``-th
-layer, where it is gated softmax attention (flash, grouped-query, rotary on
-part of the head), and an expert layer in EVERY layer: softmax-routed
-experts beside a gated shared one (``parallel/moe.py::ExpertShardMLP``).
+``ops/gated_delta.py``) except in every ``full_attention_interval``-th layer,
+where it is gated softmax attention (flash, grouped-query, rotary on part of
+the head), and an expert layer in EVERY layer: softmax-routed experts beside a
+gated shared one (``parallel/moe.py::ExpertShardMLP``).
 
 The equations (no biases anywhere; embeddings not scaled; head untied)::
 
@@ -46,10 +45,9 @@ Left out: the multi-token-prediction module and any router auxiliary loss
 ``dt_bias`` and ``w_norm`` at 1, the block norms' ``w`` at 0, every matrix
 at N(0, ``initializer_range``).
 
-Called as :class:`apex_tpu.models.gpt.GPTLM` and ``AfmoeLM`` are:
-``model.apply({"params": p}, ids, labels=labels, deterministic=...)`` ->
-``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a
-sliced ``vocab_size``, as in ``models/afmoe.py``.  Scopes ``gdn_proj``,
+The shell, how it is called and how expert parallelism enters
+(``experts_held``, a sliced ``vocab_size``): ``models/decoder.py``, whose
+``RMSNorm`` is zero-centred throughout this family.  Scopes ``gdn_proj``,
 ``gdn_conv``, ``gdn_scan``, ``gdn_out``, ``attn_full``, the four ``moe_*``,
 ``lm_head``, ``lm_loss``.  Under ``gdn_conv`` the projection's output goes
 into ``ops/gated_delta.py::split_conv_qkvz`` as it lies: on the TPU two
@@ -73,15 +71,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.amp.layers import Dense
-from apex_tpu.ops.attention import flash_attention
+from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
+                                     linear, merge_heads, rotary, split_heads)
 from apex_tpu.ops.gated_delta import gated_delta_rule, split_conv_qkvz
-from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
 from apex_tpu.parallel.moe import ExpertShardMLP
-from apex_tpu.remat import remat_module
 
-__all__ = ["Qwen3NextConfig", "Qwen3NextLayer", "Qwen3NextLM",
-           "ZeroCentredRMSNorm", "partial_rotary"]
+__all__ = ["Qwen3NextConfig", "Qwen3NextLayer", "Qwen3NextLM"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,38 +128,6 @@ class Qwen3NextConfig:
         return Qwen3NextConfig(**base)
 
 
-class ZeroCentredRMSNorm(nn.Module):
-    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` over the last axis in
-    float32, ``w`` (``scale``) initialised 0 (XLA's fusion, no kernel)."""
-
-    eps: float = 1e-6
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        w = self.param("scale", nn.initializers.zeros_init(),
-                       (x.shape[-1],), jnp.float32)
-        x32 = x.astype(jnp.float32)
-        inv = jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (x32 * inv * (1.0 + w.astype(jnp.float32))).astype(self.dtype)
-
-
-def partial_rotary(x, theta: float, rot: int):
-    """Rotate the first ``rot`` dims of ``x`` (..., seq, D) by position, the
-    two halves of those dims paired (``rotate_half``); dims ``rot..`` pass
-    untouched.  float32 inside, ``x``'s dtype out."""
-    s = x.shape[-2]
-    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-    head = x[..., :rot].astype(jnp.float32)
-    half = jnp.concatenate([-head[..., rot // 2:], head[..., :rot // 2]], -1)
-    return jnp.concatenate(
-        [(head * cos + half * sin).astype(x.dtype), x[..., rot:]], axis=-1)
-
-
 def a_log_init(key, shape, dtype=jnp.float32):
     """``log U(0, 16)`` (the draw kept off 0, whose log has no value)."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-4, 16.0))
@@ -184,12 +147,10 @@ class GatedDeltaNet(nn.Module):
         r = hv // hk
         dt = cfg.compute_dtype
         init = nn.initializers.normal(cfg.initializer_range)
-        dense = lambda n, name: Dense(n, use_bias=False, dtype=dt,
-                                      kernel_init=init, name=name)
 
         with jax.named_scope("gdn_proj"):
-            qkvz = dense(hk * (2 * dk + 2 * r * dv), "in_proj_qkvz")(x)
-            ba = dense(hk * 2 * r, "in_proj_ba")(x)
+            qkvz = linear(cfg, hk * (2 * dk + 2 * r * dv), "in_proj_qkvz")(x)
+            ba = linear(cfg, hk * 2 * r, "in_proj_ba")(x)
             beta_in, a = jnp.split(ba.reshape(b, s, hk, 2 * r), 2, axis=-1)
         with jax.named_scope("gdn_conv"):
             conv_w = self.param("conv", init,
@@ -224,7 +185,8 @@ class GatedDeltaNet(nn.Module):
             o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
                                            keepdims=True) + cfg.rms_norm_eps)
             o = o * f32(w_norm) * jax.nn.silu(f32(z.reshape(b, s, hv, dv)))
-            return dense(d, "out_proj")(o.reshape(b, s, hv * dv).astype(dt))
+            return linear(cfg, d, "out_proj")(
+                o.reshape(b, s, hv * dv).astype(dt))
 
 
 class GatedAttention(nn.Module):
@@ -238,24 +200,18 @@ class GatedAttention(nn.Module):
         b, s, d = x.shape
         hq, hk, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         dt = cfg.compute_dtype
-        init = nn.initializers.normal(cfg.initializer_range)
-        norm = lambda name: ZeroCentredRMSNorm(cfg.rms_norm_eps, dt, name=name)
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, dt, True, name=name)
         # one projection: per query head its query then its gate, then the
         # keys, then the values
-        qgkv = Dense((2 * hq + 2 * hk) * hd, use_bias=False, dtype=dt,
-                     kernel_init=init, name="qgkv")(x)
+        qgkv = linear(cfg, (2 * hq + 2 * hk) * hd, "qgkv")(x)
         qg, k, v = jnp.split(qgkv, [2 * hq * hd, (2 * hq + hk) * hd], axis=-1)
         q, gate = jnp.split(qg.reshape(b, s, hq, 2 * hd), 2, axis=-1)
-        heads = lambda t, n: t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
         rot = int(hd * cfg.partial_rotary_factor)
-        q = partial_rotary(norm("q_norm")(heads(q, hq)), cfg.rope_theta, rot)
-        k = partial_rotary(norm("k_norm")(heads(k, hk)), cfg.rope_theta, rot)
-        with jax.named_scope("attn_full"):
-            attn = flash_attention(q, k, heads(v, hk), causal=True)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
+        q = rotary(norm("q_norm")(split_heads(q, hq, hd)), cfg.rope_theta, rot)
+        k = rotary(norm("k_norm")(split_heads(k, hk, hd)), cfg.rope_theta, rot)
+        attn = merge_heads(causal_attention(q, k, split_heads(v, hk, hd)))
         gate = jax.nn.sigmoid(gate.reshape(b, s, hq * hd).astype(jnp.float32))
-        return Dense(d, use_bias=False, dtype=dt, kernel_init=init,
-                     name="o_proj")(attn * gate.astype(dt))
+        return linear(cfg, d, "o_proj")(attn * gate.astype(dt))
 
 
 class Qwen3NextLayer(nn.Module):
@@ -271,7 +227,7 @@ class Qwen3NextLayer(nn.Module):
         cfg = self.cfg
         b, s, h = x.shape
         dt = cfg.compute_dtype
-        norm = lambda name: ZeroCentredRMSNorm(cfg.rms_norm_eps, dt, name=name)
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, dt, True, name=name)
         y = norm("input_norm")(x)
         if cfg.is_full_attention(self.index):
             x = x + GatedAttention(cfg, name="attn")(y)
@@ -290,51 +246,18 @@ class Qwen3NextLayer(nn.Module):
         return x + ff
 
 
-class Qwen3NextLM(nn.Module):
-    """Embedding, the blocks ``layer_<i>``, a final norm and the untied
-    head.  ``__call__(ids)`` returns (B, S, V) float32 logits; with
-    ``labels`` (-100: not predicted) also the token-mean fused-xentropy
-    loss, as :class:`apex_tpu.models.gpt.GPTLM` does."""
+class Qwen3NextLM(DecoderLM):
+    """The shell with the final norm zero-centred as the blocks' are;
+    embeddings not scaled, the head untied."""
 
     cfg: Qwen3NextConfig
+    layer_cls = Qwen3NextLayer
+    zero_centred = True
 
-    def setup(self):
-        cfg = self.cfg
+    @staticmethod
+    def validate(cfg):
         if cfg.linear_num_value_heads % cfg.linear_num_key_heads:
             raise ValueError("linear_num_value_heads is not a multiple of "
                              "linear_num_key_heads")
         if int(cfg.head_dim * cfg.partial_rotary_factor) % 2:
             raise ValueError("the rotated part of a head is not whole pairs")
-        init = nn.initializers.normal(cfg.initializer_range)
-        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                              embedding_init=init, dtype=jnp.float32)
-        # deterministic is static_argnum 2 (self=0): called positionally
-        layer_cls = remat_module(Qwen3NextLayer, cfg.remat_policy,
-                                 static_argnums=(2,))
-        self.layers = [layer_cls(cfg, i, name=f"layer_{i}")
-                       for i in range(cfg.num_layers)]
-        self.norm_f = ZeroCentredRMSNorm(cfg.rms_norm_eps, cfg.compute_dtype)
-        self.head = Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.compute_dtype, kernel_init=init)
-
-    def __call__(self, input_ids, labels=None, deterministic: bool = True):
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = self.embed(input_ids).astype(cfg.compute_dtype)
-        for layer in self.layers:
-            x = layer(x, deterministic)
-        x = self.norm_f(x)
-        with jax.named_scope("lm_head"):
-            logits = self.head(x).astype(jnp.float32)
-        if labels is None:
-            return logits
-        with jax.named_scope("lm_loss"):
-            valid = labels >= 0
-            safe = jnp.where(valid, labels, 0)
-            # compute-dtype logits into the fused loss, as GPTLM
-            per_tok = softmax_cross_entropy(
-                logits.astype(cfg.compute_dtype), safe)
-            n = jnp.maximum(jnp.sum(valid), 1)
-            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
-        return logits, loss
-

@@ -1,19 +1,17 @@
 """SmallThinker decoder LM (PowerInfer's ``smallthinker`` family) on the
 training path.
 
-The fifth decoder block of the zoo (``models/gpt.py``, ``afmoe.py``,
-``qwen3_next.py``, ``deepseek_v3.py`` are the others): two RMSNorms a block
-in pre-norm position, grouped-query flash attention (seven query heads a
-key/value head at the published sizes) over a rotary sliding window in
-three layers of four and over the whole causal context, WITH NO POSITION
-SIGNAL, in the fourth — which comes first in a period — and in every layer
-routed experts of ReGLU units with no shared expert
+The block: two RMSNorms in pre-norm position, grouped-query flash attention
+(seven query heads a key/value head at the published sizes) over a rotary
+sliding window in three layers of four and over the whole causal context, WITH
+NO POSITION SIGNAL, in the fourth — which comes first in a period — and in
+every layer routed experts of ReGLU units with no shared expert
 (``parallel/moe.py::ExpertShardMLP``).  THE ROUTER READS THE BLOCK'S INPUT,
 ahead of the input norm and attention; the experts read the normed stream
 after attention.  The routing plan (the top-k, the sorts and the integer
-tables of ``parallel/moe.py::_route``) therefore depends on nothing
-attention computes: nothing here schedules it, the data flow alone lets the
-compiler place it beside the attention kernels.
+tables of ``parallel/moe.py::_route``) therefore depends on nothing attention
+computes: nothing here schedules it, the data flow alone lets the compiler
+place it beside the attention kernels.
 
 Per block ``i``, input ``x`` (no biases anywhere; RMSNorm eps 1e-6)::
 
@@ -39,14 +37,13 @@ The published configuration holds no key of it and training computes the
 dense unit.  Rotary scaling (null in the configuration; the declared
 positions are native).
 
-Called as :class:`apex_tpu.models.gpt.GPTLM` and the other decoders are:
-``model.apply({"params": p}, ids, labels=labels, deterministic=...)`` ->
-``(logits, loss)``.  Expert parallelism enters as ``experts_held`` and a
-sliced ``vocab_size``, as in ``models/afmoe.py``.  Scopes ``moe_router``
-(under it only what reads the block's input: the scores and the top-k),
-``attn_full`` / ``attn_window`` (the flash call), ``moe_dispatch``,
-``moe_experts``, ``embed``, ``lm_head``, ``lm_loss``.  Serving methods are
-not part of this model yet: window layers' pages are ROADMAP M4's.
+The shell, how it is called and how expert parallelism enters
+(``experts_held``, a sliced ``vocab_size``): ``models/decoder.py``.  Scopes
+``moe_router`` (under it only what reads the block's input: the scores and
+the top-k), ``attn_full`` / ``attn_window`` (the flash call),
+``moe_dispatch``, ``moe_experts``, ``embed``, ``lm_head``, ``lm_loss``.
+Serving methods are not part of this model yet: window layers' pages are
+ROADMAP M4's.
 """
 from __future__ import annotations
 
@@ -54,15 +51,11 @@ import dataclasses
 from typing import Any, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
-from apex_tpu.amp.layers import Dense
-from apex_tpu.models.afmoe import RMSNorm, rotary
-from apex_tpu.ops.attention import flash_attention
-from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
+from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
+                                     linear, merge_heads, rotary, split_heads)
 from apex_tpu.parallel.moe import ExpertShardMLP
-from apex_tpu.remat import remat_module
 
 __all__ = ["SmallThinkerConfig", "SmallThinkerLayer", "SmallThinkerLM"]
 
@@ -129,20 +122,15 @@ class SmallThinkerLayer(nn.Module):
         windowed = bool(cfg.sliding_window_layout[self.index])
 
         y = norm("input_norm")(x)
-        qkv = Dense((hq + 2 * hk) * hd, use_bias=False, dtype=dt,
-                    kernel_init=init, name="qkv")(y)
+        qkv = linear(cfg, (hq + 2 * hk) * hd, "qkv")(y)
         q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
-        heads = lambda t, n: t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
-        q, k = heads(q, hq), heads(k, hk)
+        q, k = split_heads(q, hq, hd), split_heads(k, hk, hd)
         if cfg.rope_layout[self.index]:
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        with jax.named_scope("attn_window" if windowed else "attn_full"):
-            attn = flash_attention(
-                q, k, heads(v, hk), causal=True,
-                window=cfg.sliding_window_size if windowed else None)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
-        h = x + Dense(d, use_bias=False, dtype=dt, kernel_init=init,
-                      name="o_proj")(attn)
+        attn = causal_attention(
+            q, k, split_heads(v, hk, hd),
+            window=cfg.sliding_window_size if windowed else None)
+        h = x + linear(cfg, d, "o_proj")(merge_heads(attn))
 
         # the router scores the block's INPUT x, the experts take the normed
         # stream after attention
@@ -156,49 +144,15 @@ class SmallThinkerLayer(nn.Module):
         return h + ff.reshape(b, s, d)
 
 
-class SmallThinkerLM(nn.Module):
-    """Embedding, the blocks ``layer_<i>``, a final RMSNorm and the untied
-    head.  ``__call__(ids)`` returns (B, S, V) float32 logits; with
-    ``labels`` (-100: not predicted) also the token-mean fused-xentropy
-    loss, as :class:`apex_tpu.models.gpt.GPTLM` does."""
+class SmallThinkerLM(DecoderLM):
+    """The shell as it stands: embeddings not scaled, the head untied."""
 
     cfg: SmallThinkerConfig
+    layer_cls = SmallThinkerLayer
 
-    def setup(self):
-        cfg = self.cfg
+    @staticmethod
+    def validate(cfg):
         if len(cfg.rope_layout) != len(cfg.sliding_window_layout):
             raise ValueError(
                 f"rope_layout has {len(cfg.rope_layout)} layers, "
                 f"sliding_window_layout {len(cfg.sliding_window_layout)}")
-        init = nn.initializers.normal(cfg.initializer_range)
-        self.embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                              embedding_init=init, dtype=jnp.float32)
-        # deterministic is static_argnum 2 (self=0): called positionally
-        layer_cls = remat_module(SmallThinkerLayer, cfg.remat_policy,
-                                 static_argnums=(2,))
-        self.layers = [layer_cls(cfg, i, name=f"layer_{i}")
-                       for i in range(cfg.num_layers)]
-        self.norm_f = RMSNorm(cfg.rms_norm_eps, cfg.compute_dtype)
-        self.head = Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.compute_dtype, kernel_init=init)
-
-    def __call__(self, input_ids, labels=None, deterministic: bool = True):
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = self.embed(input_ids).astype(cfg.compute_dtype)
-        for layer in self.layers:
-            x = layer(x, deterministic)
-        x = self.norm_f(x)
-        with jax.named_scope("lm_head"):
-            logits = self.head(x).astype(jnp.float32)
-        if labels is None:
-            return logits
-        with jax.named_scope("lm_loss"):
-            valid = labels >= 0
-            safe = jnp.where(valid, labels, 0)
-            # compute-dtype logits into the fused loss, as GPTLM
-            per_tok = softmax_cross_entropy(
-                logits.astype(cfg.compute_dtype), safe)
-            n = jnp.maximum(jnp.sum(valid), 1)
-            loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / n
-        return logits, loss

@@ -77,15 +77,32 @@ def _setup(with_ddp):
     return grad_fn, opt, ddp, fresh, jnp.asarray(xs), jnp.asarray(ys)
 
 
+def _accumulate(grad, carry, xs, ys):
+    """fp32 sum of ``grad``'s gradients over the microbatches ``xs, ys``."""
+    acc = None
+    for x, y in zip(xs, ys):
+        g, _ = grad(carry, (x, y))
+        g32 = jax.tree_util.tree_map(lambda t: t.astype(jnp.float32), g)
+        acc = g32 if acc is None else jax.tree_util.tree_map(jnp.add, acc, g32)
+    return acc
+
+
 def _reference_loop(step, carry, xs, ys, *, mesh=None):
-    """The per-microbatch dispatch loop: one jitted grad dispatch per
-    microbatch, fp32 accumulate on the host-side loop, one jitted update
-    dispatch per boundary — same arithmetic as the fused path, M+1
-    dispatches per optimizer step instead of 1 per window."""
+    """The per-step dispatch loop, same arithmetic as the fused path with
+    no window and no scan over microbatches.  On a mesh: one jitted grad
+    dispatch per microbatch, fp32 accumulate on the host-side loop, one
+    jitted update dispatch per boundary (M+1 dispatches per optimizer step
+    instead of 1 per window).  Off the mesh the same loop is ONE jitted
+    program per optimizer step: XLA's CPU backend rounds ``momentum * buf
+    + grad`` in a program that also makes the gradient as it does in the
+    window's scan body, and otherwise in a stand-alone update program —
+    which left 15 of 64 ``momentum_buf`` elements 1 ulp apart from the
+    second step on (ROADMAP D9); inside ``shard_map`` the two programs
+    already round alike."""
     m = step.microbatches
     if mesh is None:
-        grad_d = jax.jit(step.grad_fn)
-        upd_d = jax.jit(step.update_fn)
+        one_step = jax.jit(lambda c, x, y: step.update_fn(
+            c, _accumulate(step.grad_fn, c, x, y))[0])
     else:
         grad_d = jax.jit(shard_map_compat(
             step.grad_fn, mesh=mesh,
@@ -97,26 +114,18 @@ def _reference_loop(step, carry, xs, ys, *, mesh=None):
             in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False,
         ))
+        one_step = lambda c, x, y: upd_d(c, _accumulate(grad_d, c, x, y))[0]
     for s in range(xs.shape[0] // m):
-        acc = None
-        for i in range(m):
-            g, _ = grad_d(carry, (xs[s * m + i], ys[s * m + i]))
-            g32 = jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.float32), g
-            )
-            acc = (
-                g32 if acc is None
-                else jax.tree_util.tree_map(jnp.add, acc, g32)
-            )
-        carry, _ = upd_d(carry, acc)
+        sl = slice(s * m, (s + 1) * m)
+        carry = one_step(carry, xs[sl], ys[sl])
     return carry
 
 
 class TestBitwiseParity:
     @pytest.mark.parametrize("m", [1, 2, 4])
     def test_m_sweep_matches_reference_loop(self, m):
-        """Fused M-microbatch windows == the per-microbatch dispatch
-        loop, bitwise, without shard_map."""
+        """Fused M-microbatch windows == the per-step dispatch loop,
+        bitwise, without shard_map."""
         grad_fn, opt, _, fresh, xs, ys = _setup(with_ddp=False)
         step = amp_microbatch_step(grad_fn, opt, microbatches=m)
         driver = FusedTrainDriver(

@@ -198,7 +198,7 @@ def test_the_shares_add_up():
 def test_shared_rotary_key_gradient_is_the_sum_over_heads():
     """ONE rotary key serves every head: its gradient through the mixer is
     the sum over heads of the gradient each head's own copy would get."""
-    from apex_tpu.models.afmoe import rotary
+    from apex_tpu.models.decoder import rotary
     from apex_tpu.ops.attention import attention_ref
 
     b, h, s, dn, dr, dv = 1, 4, 64, 96, 32, 64
@@ -240,7 +240,7 @@ def test_rotating_halves_of_deinterleaved_columns_is_rotating_adjacent_pairs():
     columns de-interleaved; the reference rotates adjacent pairs in place.
     The rotated slices are permutations of each other, so q . k is the same
     number — and NOT the same as rotating halves of the published order."""
-    from apex_tpu.models.afmoe import rotary
+    from apex_tpu.models.decoder import rotary
 
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 3, 40, 32))
     y = jax.random.normal(jax.random.PRNGKey(9), (2, 3, 40, 32))

@@ -1034,7 +1034,7 @@ def test_32_on_8_heads_of_64_with_normed_queries_and_keys_match_ref(two_pass):
     — half a lane tile, groups of 4 —, q and k RMS-normed over the head and
     rotated upstream of the kernel, gradients taken THROUGH the norms to the
     projections' outputs, on both backward routes."""
-    from apex_tpu.models.afmoe import rotary
+    from apex_tpu.models.decoder import rotary
 
     q, k, v, do = _window_case(32, 8, 256)
     scale = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(9), (2, 64))

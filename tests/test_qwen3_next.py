@@ -234,10 +234,10 @@ def test_layer_refuses_an_unknown_score_function_and_has_no_bias():
 
 
 def test_rotary_leaves_the_rest_of_a_head_untouched():
-    from apex_tpu.models.qwen3_next import partial_rotary
+    from apex_tpu.models.decoder import rotary
 
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 3, 40, 256))
-    y = partial_rotary(x, 1e7, 64)
+    y = rotary(x, 1e7, 64)
     np.testing.assert_array_equal(y[..., 64:], x[..., 64:])
     np.testing.assert_array_equal(y[..., 0, :], x[..., 0, :])   # position 0
     assert float(jnp.max(jnp.abs(y[..., 1:, :64] - x[..., 1:, :64]))) > 0.1

@@ -70,6 +70,7 @@ MODULES = [
     "apex_tpu.models.bert",
     "apex_tpu.models.gpt",
     "apex_tpu.models.dcgan",
+    "apex_tpu.models.decoder",
     "apex_tpu.models.lfm2",
     "apex_tpu.serve.kv_cache",
     "apex_tpu.serve.decode",
