@@ -4,7 +4,7 @@ These are the models the reference's examples and kernels exist to serve
 (SURVEY.md §6 benchmark configs): ResNet-50 (imagenet amp O0-O3 + DDP +
 SyncBN), BERT-large (FusedLAMB + fused attention + xentropy), DCGAN
 (multi-model multi-loss-scaler amp), a simple MLP (the minimum
-end-to-end slice), and the six decoders: GPT-2 (``gpt.py``), the Arcee
+end-to-end slice), and the seven decoders: GPT-2 (``gpt.py``), the Arcee
 Trinity block with sigmoid-routed experts (``afmoe.py``, training path), the
 Qwen3-Next block — gated-delta-rule linear attention beside gated full
 attention, softmax-routed experts (``qwen3_next.py``, training path) — and
@@ -17,7 +17,11 @@ full layer before rotary window layers (``smallthinker.py``, training path) —
 and the LFM2 block — a gated short convolution in place of attention in three
 layers of four, grouped-query attention with normed queries and keys in the
 fourth, bias-steered sigmoid experts with none shared, the head tied to the
-embedding (``lfm2.py``, training path).
+embedding (``lfm2.py``, training path) — and the Granite 4.0-H block — a
+Mamba-2 state-space layer (the SSD scan by chunks) in nine layers of ten,
+position-free grouped-query attention at a published scale in the tenth, a
+dense SwiGLU in every layer, four multipliers, no experts
+(``granite_hybrid.py``, training path).
 """
 from apex_tpu.models.resnet import ResNet, resnet50, resnet101, resnet152  # noqa: F401
 from apex_tpu.models.bert import (  # noqa: F401
@@ -45,4 +49,10 @@ from apex_tpu.models.smallthinker import (  # noqa: F401
     SmallThinkerLM,
 )
 from apex_tpu.models.lfm2 import Lfm2Config, Lfm2Layer, Lfm2LM  # noqa: F401
+from apex_tpu.models.granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig,
+    GraniteHybridLayer,
+    GraniteHybridLM,
+    Mamba2Mixer,
+)
 from apex_tpu.mlp import MLP  # noqa: F401

@@ -106,14 +106,17 @@ def merge_heads(t):
     return t.transpose(0, 2, 1, 3).reshape(b, s, n * hd)
 
 
-def causal_attention(q, k, v, *, window: Optional[int] = None):
+def causal_attention(q, k, v, *, window: Optional[int] = None,
+                     scale: Optional[float] = None):
     """Causal flash attention over heads-major q (b, hq, s, d), k and v
     (b, hk, s, .), each key/value head serving ``hq / hk`` query heads; a
-    query sees the last ``window`` keys where one is given.  The scopes
+    query sees the last ``window`` keys where one is given, and the scores
+    are multiplied by ``scale`` (None: ``d ** -0.5``).  The scopes
     ``attn_window`` / ``attn_full`` are what a device trace finds the
     kernels under."""
     with jax.named_scope("attn_full" if window is None else "attn_window"):
-        return flash_attention(q, k, v, causal=True, window=window)
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               window=window)
 
 
 def masked_token_mean_loss(logits, labels, dtype):
@@ -156,6 +159,11 @@ class DecoderLM(nn.Module):
         """What the embedding's rows are multiplied by; None: nothing."""
         return None
 
+    @staticmethod
+    def logits_divisor(cfg) -> Optional[float]:
+        """What the float32 logits are divided by; None: nothing."""
+        return None
+
     def setup(self):
         cfg = self.cfg
         self.validate(cfg)
@@ -192,6 +200,9 @@ class DecoderLM(nn.Module):
                                   preferred_element_type=jnp.float32)
             else:
                 logits = self.head(x).astype(jnp.float32)
+            divisor = self.logits_divisor(cfg)
+            if divisor is not None:
+                logits = logits / divisor
         if labels is None:
             return logits
         with jax.named_scope("lm_loss"):

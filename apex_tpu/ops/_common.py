@@ -47,7 +47,7 @@ def force_pallas(value: Optional[bool]):
 #: HLO instruction — and so its event in a profiler trace — bears this
 #: name whatever scope called the kernel.  Readers of a trace match the
 #: family prefixes (``apex_flash_fwd``, ``apex_flash_bwd``, ``apex_ln_``,
-#: ``apex_xent_``, ``apex_gmm``, ``apex_gdn_``), so a variant can be added without
+#: ``apex_xent_``, ``apex_gmm``, ``apex_gdn_``, ``apex_ssd_``), so a variant can be added without
 #: touching them — and a new family's names must contain none of them.
 KERNEL_NAMES = (
     "apex_paged_attn",
@@ -78,6 +78,8 @@ KERNEL_NAMES = (
     "apex_conv1d_bwd",
     "apex_gated_conv_fwd",
     "apex_gated_conv_bwd",
+    "apex_ssd_fwd",
+    "apex_ssd_bwd",
 )
 
 

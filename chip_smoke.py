@@ -18,7 +18,10 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   with no dead tail, forward, ``dx`` and ``dw`` each timed alone
   (``grouped_mm_at_cell``); and the gated short convolution at the LFM2
   cell's call, its two kernels against the ``jax.numpy`` form, forward and
-  gradient timed on both paths (``gated_conv_at_cell``); and ONE making
+  gradient timed on both paths (``gated_conv_at_cell``); and the state-space
+  scan at the Granite cell's call, its two kernels against the token
+  recurrence, forward and the six gradients, both paths timed
+  (``ssd_at_cell``); and ONE making
   of the expert layer's routing plan at the five sparse cells' shapes, with
   each lookup inside it as the gather it was and as the sum over the held
   experts it can be, timed on the device and the tables held equal to the
@@ -255,6 +258,107 @@ def _us_on_device(fn, args, reps=16):
 
     compiled = jax.jit(many).lower(jnp.int32(0), *args).compile()
     return round(_us_a_call(compiled, (jnp.int32(0), *args), n=3) / reps, 1)
+
+
+# ---------------------------------------------------------------------------
+# the state-space scan at the cell's call
+# ---------------------------------------------------------------------------
+
+def _token_recurrence_in_blocks(x, dt, A, B, C, D, *, block: int = 64):
+    """``ops/ssd.py::ssd_recurrent``'s recurrence — one group of B and C,
+    float32, each row from a zero state — walked in blocks of ``block`` tokens
+    that are made again in the backward pass.  Differentiated, the plain
+    oracle keeps a state a token (17 GB at the cell's call, more than the chip
+    has); this keeps one a block (268 MB) and one block's own.  The same
+    sums in the same order: ``ssd_at_cell`` holds its forward to the oracle's
+    at the cell's call, ``tests/test_chip_smoke.py`` its gradients at a
+    small one."""
+    f32 = jnp.float32
+    b, s, h, p = x.shape
+    x, dt, B, C = (t.astype(f32) for t in (x, dt, B, C))
+    A, D = A.astype(f32), D.astype(f32)
+
+    def token(state, inp):
+        x_t, dt_t, b_t, c_t = inp                 # (b, H, P) (b, H) (b, 1, N) x 2
+        state = (state * jnp.exp(dt_t * A)[..., None, None]
+                 + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :])
+        o_t = jnp.einsum("bhpn,bn->bhp", state, c_t[:, 0],
+                         precision=jax.lax.Precision.HIGHEST)
+        return state, o_t + D[None, :, None] * x_t
+
+    walk = jax.checkpoint(lambda state, inp: jax.lax.scan(token, state, inp))
+    blocks = lambda t: jnp.moveaxis(t, 1, 0).reshape(
+        (s // block, block, b) + t.shape[2:])
+    _, o = jax.lax.scan(walk, jnp.zeros((b, h, p, B.shape[3]), f32),
+                        tuple(map(blocks, (x, dt, B, C))))
+    return jnp.moveaxis(o.reshape(s, b, h, p), 0, 1)
+
+
+def ssd_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
+    """``ops/ssd.py::ssd_scan`` at ``granite-h.train-8k``'s call — a row of 8
+    contexts, ``s / 16`` heads of 64 channels in bfloat16 (two heads a lane
+    tile), a 64 x 128 float32 state a head, B and C of one group, chunks of
+    256, ``dt`` and ``A`` as the cell's seeded weights give them (a step size
+    log-uniform in [1e-3, 1e-1], A in -[1, 16]): the two kernels against the
+    TOKEN RECURRENCE in float32 on the same bfloat16 values, forward and all
+    six gradients.  The forward is held to ``ssd_recurrent`` itself; the
+    gradients to the same recurrence walked in recomputed blocks
+    (:func:`_token_recurrence_in_blocks` — the oracle's own gradient does not
+    fit the chip at this call), whose forward is first held to the oracle's
+    at 1e-5 (``ssd.oracle_in_blocks``).  Tolerances, against each array's
+    largest element: 2e-2 forward (one rounding of o and of the products'
+    operands), 5e-2 on dx, ddt, dB, dC and dD, 1e-1 on dA (a head's sum over
+    every token of terms of both signs).  Then us a call of the forward and
+    of the gradient program, the kernels and the ``jax.numpy`` chunked form."""
+    from apex_tpu.ops.ssd import ssd_recurrent, ssd_scan
+
+    f32, bf16, normal = jnp.float32, jnp.bfloat16, jax.random.normal
+    shape, state = (1, 8 * s, s // 16, 64), 128
+    heads = shape[2]
+
+    def make(kx, kd, kb, kc):
+        ka, kstep, kcot, kdt = jax.random.split(kd, 4)
+        step = jnp.exp(jax.random.uniform(kstep, (heads,), f32,
+                                          np.log(1e-3), np.log(1e-1)))
+        dt = jax.nn.softplus(0.5 * normal(kdt, shape[:3], f32)
+                             + jnp.log(jnp.expm1(step)))
+        shared = lambda key: normal(key, (1, shape[1], 1, state), f32).astype(bf16)
+        return (normal(kx, shape, f32).astype(bf16), dt,
+                -jax.random.uniform(ka, (heads,), f32, 1.0, 16.0),
+                shared(kb), shared(kc), jnp.ones((heads,), f32),
+                normal(kcot, shape, f32).astype(bf16))
+
+    *args, cot = jax.jit(lambda key: make(*jax.random.split(key, 4)))(
+        jax.random.fold_in(root_key, 170))
+    cot = cot.astype(f32)       # a cotangent bfloat16 holds, as conv1d's
+
+    def both(fn):
+        def loss(*a):
+            out = fn(*a)
+            return jnp.sum(out.astype(f32) * cot), out
+        return jax.jit(jax.value_and_grad(loss, tuple(range(6)), has_aux=True))
+
+    compiled = both(ssd_scan).lower(*args).compile()
+    _require_mosaic(compiled, 2, calls, "ssd")
+    (_, out), grads = compiled(*args)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(ssd_recurrent)(*args)
+        (_, in_blocks), want_grads = both(_token_recurrence_in_blocks)(*args)
+    _compare("ssd.oracle_in_blocks", in_blocks, want, 1e-5, parity)
+    _compare("ssd.fwd", out, want, 2e-2, parity)
+    for name, tol, g, w in zip(("dx", "ddt", "dA", "dB", "dC", "dD"),
+                               (5e-2, 5e-2, 1e-1, 5e-2, 5e-2, 5e-2),
+                               grads, want_grads):
+        _compare(f"ssd.{name}", g, w, tol, parity)
+    timed = {"shape": [*shape, state, 256],
+             "kernels": mosaic_call_names(compiled.as_text())}
+    for side, use_pallas in (("kernels", None), ("jnp", False)):
+        scan = lambda *a: ssd_scan(*a, use_pallas=use_pallas)
+        grad = jax.jit(jax.grad(lambda *a: jnp.sum(
+            scan(*a).astype(f32) * cot), tuple(range(6))))
+        timed[f"fwd_{side}_us"] = _us_a_call(jax.jit(scan), args)
+        timed[f"grad_{side}_us"] = _us_a_call(grad, args)
+    return timed
 
 
 # ---------------------------------------------------------------------------
@@ -681,7 +785,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 "gated_delta": [1, 8 * s, [s // 64, s // 32], 128],
                 "flash_latent": [1, s // 64, 8 * s, [192, 128]],
                 "conv1d": [1, 8 * s, s // 64 * 768, 4],
-                "gated_conv": [1, 16 * s, 3 * 2 * s, 3]},
+                "gated_conv": [1, 16 * s, 3 * 2 * s, 3],
+                "ssd": [1, 8 * s, s // 16, 64, 128]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -985,6 +1090,10 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
             lambda *a: gated_loss(use_pallas)(*a)[0], (0, 1)))
         timed_c[f"fwd_{side}_us"] = _us_a_call(fwd, (xg, wg))
         timed_c[f"grad_{side}_us"] = _us_a_call(grad, (xg, wg, cot_c))
+
+    # the state-space scan at granite-h.train-8k's call, its two kernels
+    # against the token recurrence
+    facts["ssd_at_cell"] = ssd_at_cell(s, root_key, parity, calls)
 
     # the expert layer's row movement at smallthinker.train-16k's shape —
     # twice the LayerNorm rows x 2560 bfloat16 (a record 20 sublanes: two and

@@ -24,8 +24,57 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", False)
 
+import gc  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+#: memory mappings a test process may hold before JAX's caches are dropped:
+#: half the kernel's default ``vm.max_map_count`` (65,530)
+MAPPINGS_HIGH = 32_000
+
+
+@pytest.fixture(autouse=True)
+def executables_released_before_the_mappings_run_out():
+    """JAX keeps every CPU executable it compiled, and each holds three
+    memory mappings a code object.  The worker that runs
+    ``test_ops_attention.py`` (214 tests of eager interpret-mode kernels)
+    ends the file at ~58,000 mappings; the kernel allows a process 65,530,
+    and the first compile past that dies of a segmentation fault inside
+    ``backend_compile_and_load`` — in whatever file the worker took next
+    (PR 43 lost a worker in five whole runs before the count was read).
+    After a test that leaves the process past ``MAPPINGS_HIGH``, drop the
+    caches: the executables go, and their mappings with them."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:                 # no procfs: nothing to read, nothing done
+        return
+    if held > MAPPINGS_HIGH:
+        jax.clear_caches()
+        gc.collect()
+
+
+#: the files whose tests take longest, dearest first (junit times of a whole
+#: run, PR 43).  xdist's ``loadfile`` hands files to workers in collection
+#: order; alphabetical order started ``test_qwen3_next.py`` — a tenth of the
+#: suite's time in one file — after most of the rest, and the run ended when
+#: it did, 1,413 s of the 1,470 allowed.  Dearest first, the short files fill
+#: the tail.
+LONGEST_FIRST = (
+    "test_qwen3_next.py", "test_ops_attention.py", "test_ring_attention.py",
+    "test_afmoe.py", "test_deepseek_v3.py", "test_chip_smoke.py",
+    "test_remat.py", "test_models.py", "test_ops_gated_delta.py",
+    "test_moe.py", "test_smallthinker.py", "test_tpu_compile.py",
+)
+
+
+def pytest_collection_modifyitems(config, items):
+    """The dearest files first; every other file, and every test inside a
+    file, in the order it was collected (the sort is stable)."""
+    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
 
 
 @pytest.fixture

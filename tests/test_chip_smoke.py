@@ -65,7 +65,7 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
         "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d",
-        "gated_conv"}
+        "gated_conv", "ssd"}
     # the backward's two routes at the three 8k cells' calls, timed and held
     # to each other and to the reference
     routes = kernels["flash_backward"]
@@ -123,6 +123,15 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
             "grad_jnp_us"} <= set(gated)
     for name in ("fwd", "dx", "dw"):
         assert f"gated_conv.{name}" in kernels["parity"]
+    # the state-space scan at the Granite cell's call: both routes timed
+    # forward and with gradients, the kernels held to the token recurrence in
+    # the forward and all six gradients
+    scan = kernels["ssd_at_cell"]
+    assert scan["shape"] == [1, 8 * TINY.ctx, TINY.ctx // 16, 64, 128, 256]
+    assert {"fwd_kernels_us", "grad_kernels_us", "fwd_jnp_us",
+            "grad_jnp_us", "kernels"} <= set(scan)
+    for name in ("oracle_in_blocks", "fwd", "dx", "ddt", "dA", "dB", "dC", "dD"):
+        assert f"ssd.{name}" in kernels["parity"]
     # the routing plan at the five sparse cells' shapes: one making, its dear
     # parts and each lookup both ways timed, the tables equal to the bit
     plans = kernels["moe_plan_at_cell"]
@@ -143,6 +152,38 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert serve["tokens"] == 5 * TINY.new_tokens
     assert serve["prefix_hit_tokens"] > 0
     assert all(c["tokens_identical"] for c in serve["reference"])
+
+
+def test_the_scan_probes_blocked_oracle_is_the_token_recurrence():
+    """``ssd_at_cell`` takes its six gradients from the recurrence walked in
+    recomputed blocks, because ``ssd_recurrent``'s own gradient keeps a state
+    a token and does not fit the chip at the cell's call: at a size where
+    both fit they are one function, forward and every gradient."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from apex_tpu.ops.ssd import ssd_recurrent
+
+    keys = jax.random.split(jax.random.PRNGKey(43), 7)
+    b, s, h, p, n = 2, 96, 4, 8, 16
+    args = (jax.random.normal(keys[0], (b, s, h, p)),
+            jax.nn.softplus(jax.random.normal(keys[1], (b, s, h)) - 2.0),
+            -jax.random.uniform(keys[2], (h,), minval=1.0, maxval=16.0),
+            jax.random.normal(keys[3], (b, s, 1, n)),
+            jax.random.normal(keys[4], (b, s, 1, n)),
+            jax.random.normal(keys[5], (h,)))
+    cot = jax.random.normal(keys[6], (b, s, h, p))
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a) * cot), tuple(range(6)))(*args)
+
+    want, want_grads = both(ssd_recurrent)
+    got, got_grads = both(lambda *a: chip_smoke._token_recurrence_in_blocks(
+        *a, block=32))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
 
 
 def test_planted_failure_gives_nonzero_exit_and_no_ok_line(

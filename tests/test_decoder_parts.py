@@ -2,17 +2,19 @@
 no family's own reference comparison holds — that the one rotation and the
 one norm are the two forms they replaced, the masked loss's edge, and that
 each family's LM is the shared shell with no other family's module behind
-it."""
+it — and the two hooks a family may state beside ``embed_scale``: a scale of
+the attention scores that is not ``d ** -0.5`` and a divisor of the logits."""
 import ast
 import importlib
 import inspect
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.models.decoder import (DecoderLM, RMSNorm,
+from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
                                      masked_token_mean_loss, rotary)
 from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
 
@@ -22,6 +24,7 @@ FAMILIES = {
     "qwen3_next": ("Qwen3NextConfig", "Qwen3NextLM"),
     "smallthinker": ("SmallThinkerConfig", "SmallThinkerLM"),
     "lfm2": ("Lfm2Config", "Lfm2LM"),
+    "granite_hybrid": ("GraniteHybridConfig", "GraniteHybridLM"),
 }
 
 
@@ -127,3 +130,88 @@ def test_a_family_is_the_shared_shell_and_its_own_module(family):
             imported.update(a.name for a in node.names)
     others = {f"apex_tpu.models.{f}" for f in FAMILIES if f != family}
     assert not imported & others, imported & others
+
+
+def _plain_attention(q, k, v, scale):
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    n = q.shape[2]
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+@pytest.mark.parametrize("scale", [None, 1 / 64, 0.5])
+@pytest.mark.parametrize("kernels", [False, True], ids=["off_tpu", "pallas"])
+def test_causal_attention_takes_a_scale_of_its_own(scale, kernels):
+    """``scale=None`` is ``d ** -0.5`` (the program every family had); a
+    stated scale multiplies the scores instead — 1/64 at a head of 64 is not
+    1/8 — through the kernels and off them, at four query heads a key/value
+    head."""
+    from apex_tpu.ops._common import force_pallas
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (1, 4, 128, 64))
+    k, v = (jax.random.normal(key, (1, 1, 128, 64)) for key in ks[1:])
+    with force_pallas(kernels), jax.default_matmul_precision("highest"):
+        got = causal_attention(q, k, v, scale=scale)
+        default = causal_attention(q, k, v)
+        want = _plain_attention(q, k, v, 64 ** -0.5 if scale is None else scale)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    if scale is None:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(default))
+    else:
+        assert float(jnp.max(jnp.abs(got - default))) > 1e-2
+    text = str(jax.make_jaxpr(lambda *a: causal_attention(*a, scale=scale))(
+        q, k, v).pretty_print(name_stack=True))
+    assert "attn_full" in text and "attn_window" not in text
+
+
+class _Block(nn.Module):        # the plainest block: what comes in goes out
+    cfg: object
+    index: int
+
+    def __call__(self, x, deterministic=True):
+        return x
+
+
+def _shell(divisor, scale):
+    import types
+
+    class LM(DecoderLM):
+        layer_cls = _Block
+        tied_head = True
+
+        @staticmethod
+        def embed_scale(cfg):
+            return scale
+
+        @staticmethod
+        def logits_divisor(cfg):
+            return divisor
+
+    cfg = types.SimpleNamespace(
+        vocab_size=64, hidden_size=32, num_layers=1, rms_norm_eps=1e-5,
+        initializer_range=0.02, remat_policy="none", compute_dtype=jnp.float32)
+    return LM(cfg)
+
+
+@pytest.mark.parametrize("divisor", [None, 8.0, 0.5])
+def test_logits_divisor_divides_the_float32_logits(divisor):
+    """The hook's default is nothing; a stated divisor divides the logits —
+    and so the loss sees the divided ones: the shell with a divisor gives the
+    plain shell's logits over it, bit for bit where it is a power of two."""
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
+    plain, scaled = _shell(None, None), _shell(divisor, None)
+    assert DecoderLM.logits_divisor(plain.cfg) is None
+    params = plain.init(jax.random.PRNGKey(0), ids)["params"]
+    base = plain.apply({"params": params}, ids)
+    got, loss = scaled.apply({"params": params}, ids, labels=ids)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(base if divisor is None else base / divisor))
+    want = masked_token_mean_loss(got, ids, jnp.float32)
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    if divisor is not None:
+        _, plain_loss = plain.apply({"params": params}, ids, labels=ids)
+        assert abs(float(loss) - float(plain_loss)) > 1e-6

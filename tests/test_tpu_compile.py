@@ -171,6 +171,8 @@ def _named_case(kernel):
         return _conv_case()
     if kernel.startswith("apex_gated_conv_"):
         return _gated_conv_case()
+    if kernel.startswith("apex_ssd_"):
+        return _ssd_case()
     if kernel.startswith("apex_xent_"):
         from apex_tpu.ops import softmax_cross_entropy
 
@@ -230,6 +232,23 @@ def _gated_conv_case():
             [((1, 16384, 6144), BF16), ((2048, 3), F32)])
 
 
+def _ssd_case():
+    """The state-space scan as ``granite-h.train-8k`` calls it: one
+    8192-token row, 64 heads of 64 channels in bfloat16 (two heads a lane
+    tile), a 64 x 128 float32 state a head, B and C of one group 128 wide, 32
+    chunks of 256 (ops/ssd.py), forward and backward."""
+    from apex_tpu.ops.ssd import ssd_scan
+
+    def loss(x, dt, a, b, c, d):
+        with jax.named_scope("ssm_scan"):
+            return jnp.sum(ssd_scan(x, dt, a, b, c, d).astype(F32))
+
+    shared = ((1, 8192, 1, 128), BF16)
+    return (jax.grad(loss, argnums=tuple(range(6))),
+            [((1, 8192, 64, 64), BF16), ((1, 8192, 64), F32), ((64,), F32),
+             shared, shared, ((64,), F32)])
+
+
 _NAMES_OF_CASE = {}
 
 
@@ -243,6 +262,7 @@ _NAMES_OF_CASE = {}
     "apex_moe_combine_dw", "apex_gdn_fwd", "apex_gdn_bwd",
     "apex_conv1d_fwd", "apex_conv1d_bwd",
     "apex_gated_conv_fwd", "apex_gated_conv_bwd",
+    "apex_ssd_fwd", "apex_ssd_bwd",
 ])
 def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     """The custom call's HLO instruction — what a device trace names the
@@ -252,10 +272,10 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
 
     assert kernel in KERNEL_NAMES
     # (the four apex_moe_* kernels are one program, the two apex_gdn_*, the
-    # two apex_conv1d_* and the two apex_gated_conv_* three more: each
-    # compiled once)
+    # two apex_conv1d_*, the two apex_gated_conv_* and the two apex_ssd_* four
+    # more: each compiled once)
     case = next((f for f in ("apex_moe_", "apex_gdn_", "apex_conv1d_",
-                             "apex_gated_conv_")
+                             "apex_gated_conv_", "apex_ssd_")
                  if kernel.startswith(f)), kernel)
     if case not in _NAMES_OF_CASE:
         fn, avals = _named_case(kernel)
@@ -304,6 +324,46 @@ def test_delta_rule_keeps_what_is_local_to_a_chunk_inside_its_kernels(
         for d in filter(None, dims.split(",")):
             size *= int(d)
         assert not (dtype == "f32" and size >= q_size), line[:200]
+    assert seen > 10        # the witness that the lines were found at all
+
+
+def test_state_space_scan_keeps_every_chunk_square_inside_its_kernels(
+        chip, as_tpu):
+    """At the cell's shape the scan's program is ONE ``apex_ssd_fwd`` and ONE
+    ``apex_ssd_bwd`` and, around them, XLA's work on the (B, S, H) arrays
+    alone: no product and no float32 array of x's size or more under
+    ``ssd.py``'s jit outside the custom calls but the kernels' own results —
+    the float32 ``(chunks, heads, 256, 256)`` decay matrix the chunked form
+    costs under XLA (537 MB a layer a pass) is nowhere."""
+    import re
+
+    from apex_tpu import obs
+    from apex_tpu.ops._common import mosaic_call_names, unnamed_mosaic_calls
+
+    fn, avals = _ssd_case()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in avals]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+    assert names == ["apex_ssd_fwd", "apex_ssd_bwd"], names
+    assert not unnamed_mosaic_calls(text)
+    assert obs.default_registry().get("ssd.kernel").value == 1
+    assert not re.search(r"f32\[(?:1,)?(?:32,64|64,32),256,256\]", text)
+    x_size = 8192 * 64 * 64
+    instr = re.compile(r"= (\(?)(\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
+    seen = 0
+    for line in text.splitlines():
+        m = instr.search(line)
+        if not m or "ssm_scan" not in line or "tpu_custom_call" in line:
+            continue
+        seen += 1
+        is_tuple, dtype, dims, opcode = m.groups()
+        assert opcode not in ("dot", "convolution"), line[:200]
+        if is_tuple or opcode in ("get-tuple-element", "bitcast"):
+            continue        # a kernel's own result, or a view of one
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        assert not (dtype == "f32" and size >= x_size), line[:200]
     assert seen > 10        # the witness that the lines were found at all
 
 
