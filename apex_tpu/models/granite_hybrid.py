@@ -37,8 +37,11 @@ The scan is ``ops/ssd.py::ssd_scan``: on the TPU two kernels over chunks of
 ``mamba_chunk_size`` tokens that keep every (chunk, chunk) tensor in VMEM,
 float32 states carried between chunks; ``dt``, the decays and the states
 float32, ``x``, ``B``, ``C`` and ``o`` in the compute dtype at the kernels'
-edge.  The convolution with its bias and SiLU is XLA's
-(``ops/ssd.py::causal_conv1d_bias_silu``); the gated norm is XLA's float32
+edge.  The convolution with its bias and SiLU is
+``ops/ssd.py::split_conv_xbc``: on the TPU the kernel pair ``apex_conv1d_*``
+reads x, B and C out of ``in_proj``'s output where they lie and hands them to
+the scan as the three arrays its kernels take, z and dt cut out beside; its
+backward writes ``in_proj``'s whole gradient.  The gated norm is XLA's float32
 fusion like every ``RMSNorm``.
 
 Left out: nothing of a step (there is no router and no auxiliary loss).  No
@@ -50,7 +53,8 @@ matrix and the taps at N(0, ``initializer_range``).
 
 The shell and how it is called: ``models/decoder.py`` — ``vocab_size`` is
 whatever slice of the vocabulary is held: rows of the embedding, and so
-columns of the tied head.  Scopes ``ssm_proj`` (``W_in``), ``ssm_conv``,
+columns of the tied head.  Scopes ``ssm_proj`` (``W_in``), ``ssm_conv`` (the
+convolution and the cut of the projection's output into its five parts),
 ``ssm_scan`` (softplus, decays, the scan), ``ssm_out`` (gate, norm,
 ``W_out``), ``attn_full`` (the flash call), ``dense_ffn``, ``embed``,
 ``lm_head``, ``lm_loss``.  Under ``remat_policy`` ``full_block`` the attention
@@ -72,7 +76,7 @@ import jax.numpy as jnp
 
 from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
                                      linear, merge_heads, split_heads)
-from apex_tpu.ops.ssd import causal_conv1d_bias_silu, ssd_scan
+from apex_tpu.ops.ssd import split_conv_xbc, ssd_scan
 from apex_tpu.parallel.moe import SwiGLU
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridLayer", "GraniteHybridLM",
@@ -167,14 +171,13 @@ class Mamba2Mixer(nn.Module):
         init = nn.initializers.normal(cfg.initializer_range)
         with jax.named_scope("ssm_proj"):
             zxbcdt = linear(cfg, d_in + conv + h, "in_proj")(y)
-            z, xbc, dt = jnp.split(zxbcdt, [d_in, d_in + conv], axis=-1)
         with jax.named_scope("ssm_conv"):
             taps = self.param("conv_taps", init, (conv, cfg.mamba_d_conv),
                               jnp.float32)
             bias = self.param("conv_bias", nn.initializers.zeros_init(),
                               (conv,), jnp.float32)
-            xbc = causal_conv1d_bias_silu(xbc, taps, bias)
-            x, bm, cm = jnp.split(xbc, [d_in, d_in + g * n], axis=-1)
+            z, x, bm, cm, dt = split_conv_xbc(zxbcdt, taps, bias,
+                                              d_inner=d_in, d_bc=g * n)
         with jax.named_scope("ssm_scan"):
             dt_bias = self.param("dt_bias", _dt_bias_init, (h,), jnp.float32)
             a_log = self.param("A_log", _a_log_init, (h,), jnp.float32)

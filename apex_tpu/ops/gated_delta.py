@@ -2,7 +2,8 @@
 that hold everything between q, k, v, g, beta and o in VMEM, and a ``lax.scan``
 path — and the short causal depthwise convolution in front of it — two more
 kernels that read q, k, v out of the projection's output where they lie, and
-a ``jax.numpy`` path.
+a ``jax.numpy`` path; the same two kernels, under another layout and with a
+bias, are the convolution in front of ``ops/ssd.py``'s state-space scan.
 
 The first sequential operator of ``ops/``: a linear-attention layer
 (``models/qwen3_next.py``'s gated delta net) keeps, per head, a float32
@@ -71,9 +72,20 @@ and the ``K`` shifted float32 passes that :func:`causal_conv1d_silu` costs on
 the chip (29.96 ms a step of ``qwen3-next.train-8k`` for 3.4 ms of bytes,
 PERF.md section 6, PR 33) exist only off the TPU.
 
+**The same two kernels are a Mamba-2 layer's convolution**
+(``ops/ssd.py::split_conv_xbc``, ``models/granite_hybrid.py``): the kernels,
+their BlockSpecs and the ``jax.numpy`` path are written against a
+:class:`ConvLayout` — groups of columns, the parts of a group that are read,
+the outputs they are written to, what a group hands through — and know no
+model.  The delta net's layout is a group a key head; the state-space
+layer's ``[z | x | B | C | dt]`` is ONE group whose x, B and C are a part
+and an output each, z and dt handed through, with a bias row beside the
+taps (``None`` for the delta net: no operand, no add).  A part wider than a
+lane tile is worked through a tile at a time.
+
 (The library's OTHER short convolution — a gate in front and a gate behind,
 no SiLU, a projection laid out in thirds — is ``ops/gated_conv.py``, which
-imports this one's row blocks, halo and taps.)
+imports this one's halo and taps.)
 
 **Off the TPU, and as the kernels' oracle,** what is local to a chunk is
 computed for all chunks at once by batched products (:func:`_chunk_local`,
@@ -102,10 +114,11 @@ the scan path and through both kernels).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -114,8 +127,8 @@ from apex_tpu.ops._common import pallas_call as _pallas_call, pallas_default
 from apex_tpu.remat import GDN_OUT, GDN_STATES, GDN_TRI
 
 __all__ = ["gated_delta_rule", "gated_delta_rule_recurrent",
-           "causal_conv1d_silu", "split_conv_qkvz", "tri_inverse",
-           "DEFAULT_CHUNK"]
+           "causal_conv1d_silu", "split_conv_qkvz", "conv_columns",
+           "ConvLayout", "tri_inverse", "DEFAULT_CHUNK"]
 
 DEFAULT_CHUNK = 64
 #: value heads a grid step of the kernels takes together, at most: a step's
@@ -125,25 +138,65 @@ _HEADS_PER_STEP = 8
 
 
 # ---------------------------------------------------------------------------
-# the convolution in front of the rule
+# the short convolution: in front of the rule, and of the state-space scan
 # ---------------------------------------------------------------------------
 
-def causal_conv1d_silu(x, w):
-    """Depthwise causal convolution over the sequence, then SiLU.
+def causal_conv1d_silu(x, w, bias=None):
+    """Depthwise causal convolution over the sequence, an optional bias,
+    then SiLU.
 
-    ``x`` (B, S, channels), ``w`` (channels, K): ``y_t = sum_j w[:, j] *
-    x_{t - (K-1) + j}`` with zeros before the row's start, no bias.  ``K``
-    shifted multiply-adds in float32 (a ``K``-tap depthwise convolution has
-    no use for the MXU); ``x``'s dtype out.  The path off the TPU and the
-    oracle of :func:`split_conv_qkvz`'s kernels: on the chip XLA makes a
-    padded float32 copy of ``x`` and ``K`` shifted float32 passes of this,
-    not one fused pass (PERF.md section 6, PR 33)."""
+    ``x`` (B, S, channels), ``w`` (channels, K), ``bias`` (channels,) or
+    None: ``y_t = silu(sum_j w[:, j] * x_{t - (K-1) + j} + bias)`` with zeros
+    before the row's start.  ``K`` shifted multiply-adds in float32 (a
+    ``K``-tap depthwise convolution has no use for the MXU); ``x``'s dtype
+    out.  The path off the TPU and the oracle of the kernels below: on the
+    chip XLA makes a padded float32 copy of ``x`` and ``K`` shifted float32
+    passes of this, not one fused pass (PERF.md section 6, PR 33 and PR
+    44)."""
     k = w.shape[-1]
     s = x.shape[1]
     x32 = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     w32 = w.astype(jnp.float32)
     y = sum(x32[:, j:j + s] * w32[:, j] for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(x.dtype)
+
+
+class ConvLayout(NamedTuple):
+    """Where a projection's output (B, S, ``groups x stride``) keeps what the
+    convolution reads, as data: the kernels, their BlockSpecs and the
+    ``jax.numpy`` path are written against this and know no model.
+
+    A GROUP is ``stride`` columns (the delta net: a key head's ``[q | k | v |
+    z]``; a Mamba-2 layer: the whole ``[z | x | B | C | dt]``, one group) and
+    a step of the kernels' grid.  ``parts``: the column blocks of a group the
+    convolution reads, each ``(first column, width, output, first lane in
+    that output's block)`` and read through a BlockSpec of its own width —
+    so a part starts a whole number of its widths in, in every group.
+    ``outs``: a group's width of each OUTPUT array (B, S, ``groups x
+    width``); an output's parts lie side by side in the group, and the
+    convolution's channels run over the outputs in order, each over all
+    groups.  ``passed``: ``(first column, width)`` of what a group hands
+    through untouched, returned behind the outputs; the outputs' columns and
+    these are the whole group."""
+    groups: int
+    stride: int
+    parts: Tuple[Tuple[int, int, int, int], ...]
+    outs: Tuple[int, ...]
+    passed: Tuple[Tuple[int, int], ...]
+
+    def cut(self, proj, first: int, width: int):
+        """Columns ``first .. first + width`` of every group, side by side:
+        lane-aligned column slices, a copy of those bytes alone, which XLA
+        fuses into their reader.  (Cut through a (B, S, groups, stride)
+        reshape the chip first makes a relayout copy of the WHOLE
+        projection output, PERF.md section 6, PR 33: 0.61 ms a pass.)"""
+        at = [g * self.stride + first for g in range(self.groups)]
+        return jnp.concatenate([proj[:, :, c:c + width] for c in at], axis=-1)
+
+    def first_of(self, out: int) -> int:
+        return min(first for first, _, o, _ in self.parts if o == out)
 
 
 def _conv_dims(width: int, hk: int, dk: int, dv: int):
@@ -158,53 +211,100 @@ def _conv_dims(width: int, hk: int, dk: int, dv: int):
     return p, r
 
 
-def _split_conv_xla(qkvz, w, hk: int, dk: int, dv: int):
-    """:func:`split_conv_qkvz` in plain ``jax.numpy``: the four parts cut
-    out of the per-key-head layout, each contiguous over its heads (XLA's
-    strided copies), q, k, v concatenated in the convolution's channel order
-    ``[q | k | v]``, :func:`causal_conv1d_silu`, split again."""
-    b, s, width = qkvz.shape
+def _qkvz_layout(width: int, hk: int, dk: int, dv: int) -> ConvLayout:
+    """The delta net's: a group a key head, q, k and its ``r`` value heads
+    each a part (any ``r`` keeps every part a whole number of its widths
+    in), v's written side by side into one block of ``r d_v``; z handed
+    through."""
     p, r = _conv_dims(width, hk, dk, dv)
-    q, k, v, z = (t.reshape(b, s, -1) for t in jnp.split(
-        qkvz.reshape(b, s, hk, p), [dk, 2 * dk, 2 * dk + r * dv], axis=-1))
-    mixed = causal_conv1d_silu(jnp.concatenate([q, k, v], axis=-1), w)
-    return (*jnp.split(mixed, [q.shape[-1], 2 * q.shape[-1]], axis=-1), z)
+    return ConvLayout(
+        hk, p, ((0, dk, 0, 0), (dk, dk, 1, 0))
+        + tuple((2 * dk + j * dv, dv, 2, j * dv) for j in range(r)),
+        (dk, dk, r * dv), ((2 * dk + r * dv, r * dv),))
+
+
+def _conv_xla(proj, w, bias, lay: ConvLayout):
+    """The convolution of ``proj``'s columns in plain ``jax.numpy``: a
+    group's columns split into the outputs' and what is handed through
+    (together they are the whole group), each contiguous over the groups
+    (XLA's strided copies), the outputs' concatenated in the convolution's
+    channel order, :func:`causal_conv1d_silu`, split again."""
+    b, s, _ = proj.shape
+    cols = [(lay.first_of(o), width) for o, width in enumerate(lay.outs)]
+    cols += lay.passed
+    order = sorted(range(len(cols)), key=lambda i: cols[i][0])
+    ends = np.cumsum([cols[i][1] for i in order])[:-1].tolist()
+    pieces = jnp.split(proj.reshape(b, s, lay.groups, lay.stride), ends,
+                       axis=-1)
+    cut = [pieces[order.index(i)].reshape(b, s, -1) for i in range(len(cols))]
+    outs, passed = cut[:len(lay.outs)], cut[len(lay.outs):]
+    mixed = causal_conv1d_silu(jnp.concatenate(outs, axis=-1), w, bias)
+    ends = np.cumsum([t.shape[-1] for t in outs])[:-1].tolist()
+    return (*jnp.split(mixed, ends, axis=-1), *passed)
 
 
 #: rows of the sequence a grid step of the convolution's kernels takes, at
-#: most (a power of two; the largest that divides S is taken): a step pays
-#: for its rows and operands, so few large blocks (PERF.md section 6, PR 25)
+#: most (a power of two; the largest that divides S and fits
+#: :data:`_CONV_VMEM` is taken): a step pays for its rows and operands, so
+#: few large blocks (PERF.md section 6, PR 25)
 _CONV_ROWS = 1024
-#: rows of a block the kernels work through at a time, straight-line: what
-#: lives between a piece's loads and its store stays in registers
+#: rows of a block the kernels work through at a time, straight-line, a lane
+#: tile wide: what lives between a piece's loads and its store stays in
+#: registers
 _CONV_PIECE = 256
+#: lanes of a tile: the width of a piece
+_LANES = 128
 #: rows of float32 kept in front of (forward) or behind (backward) a block
 #: in VMEM for the taps that reach over its edge: one sublane tile
 _HALO = 8
 #: rows of the block of preceding inputs the backward kernel reads through
 #: a BlockSpec of its own: one tile of a 16-bit array
 _HALO_ROWS = 16
+#: bytes of VMEM a grid step's blocks (twice: the next step's are in flight)
+#: and scratch may take; the kernels' limit, where they ask one, is twice this
+_CONV_VMEM = 32 * 1024 * 1024
 
 
-def _conv_tile(s: int):
+def _conv_vmem(lay: ConvLayout, rows: int, itemsize: int) -> int:
+    """Bytes of VMEM the backward kernel — the larger — takes at ``rows``
+    rows a block: the parts, the outputs' cotangents, what is handed through
+    and the group's whole gradient, twice, and a float32 row a part's lane
+    for ``dy silu'(y)``."""
+    read = sum(width for _, width, _, _ in lay.parts)
+    moved = read + sum(lay.outs) + sum(w for _, w in lay.passed) + lay.stride
+    return rows * (2 * itemsize * moved + 4 * (read + _LANES))
+
+
+def _conv_tile(s: int, lay: ConvLayout, taps: int, itemsize: int):
     """``(rows of a block, rows of a piece)`` for a sequence of ``s``
-    tokens: the largest power of two up to :data:`_CONV_ROWS` that divides
-    it, worked through :data:`_CONV_PIECE` rows at a time."""
+    tokens — the largest power of two up to :data:`_CONV_ROWS` that divides
+    it and fits :data:`_CONV_VMEM`, worked through :data:`_CONV_PIECE` rows
+    at a time — or None where the kernels do not take the shapes: parts of
+    whole 128-lane tiles, each a whole number of its own widths in, whole
+    row blocks of at least a 16-bit tile, and taps that reach no further
+    back than one sublane tile."""
     rows = _CONV_ROWS
-    while rows > 1 and s % rows:
+    while rows > 1 and (s % rows or _conv_vmem(lay, rows, itemsize)
+                        > _CONV_VMEM):
         rows //= 2
-    return rows, min(rows, _CONV_PIECE)
+    tiles = all(width % _LANES == 0 and (g * lay.stride + first) % width == 0
+                for first, width, _, _ in lay.parts
+                for g in range(lay.groups))
+    if tiles and rows >= _HALO_ROWS and 1 <= taps <= _HALO + 1:
+        return rows, min(rows, _CONV_PIECE)
+    return None
 
 
 def conv_supported(s: int, dk: int, dv: int, r: int, taps: int) -> bool:
-    """Whether the convolution's kernels take these shapes: head sizes of
-    whole 128-lane tiles, every part of the per-key-head layout ``[q d_k | k
-    d_k | v r d_v | z r d_v]`` starting a whole number of its own widths in
-    (equal head sizes always do), whole row blocks of at least a 16-bit
-    tile, and taps that reach no further back than one sublane tile."""
-    return (dk % 128 == 0 and dv % 128 == 0 and (2 * r * dv) % dk == 0
-            and (2 * dk) % dv == 0 and _conv_tile(s)[0] >= _HALO_ROWS
-            and 1 <= taps <= _HALO + 1)
+    """Whether the convolution's kernels take the delta net's shapes: head
+    sizes of whole 128-lane tiles, every part of the per-key-head layout ``[q
+    d_k | k d_k | v r d_v | z r d_v]`` starting a whole number of its own
+    widths in (equal head sizes always do), whole row blocks of at least a
+    16-bit tile, and taps that reach no further back than one sublane
+    tile."""
+    # two key heads: the second's parts start a head's whole width in
+    lay = _qkvz_layout(2 * (2 * dk + 2 * r * dv), 2, dk, dv)
+    return _conv_tile(s, lay, taps, 2) is not None
 
 
 def _taps(w_ref, lanes, window):
@@ -217,29 +317,46 @@ def _taps(w_ref, lanes, window):
     return acc
 
 
-def _conv_parts(dk: int, dv: int, r: int):
-    """The column blocks of one key head, as ``(width, output, first lane
-    in that output's block)``: q, k, and its ``r`` value heads — each read
-    through a BlockSpec of its own width (any ``r`` keeps every offset a
-    whole block), v's written side by side into one block of ``r d_v``."""
-    return ([(dk, 0, 0), (dk, 1, 0)]
-            + [(dv, 2, j * dv) for j in range(r)])
+def _refs(refs, *counts):
+    """``refs`` cut into runs of ``counts``, and what is left."""
+    out = []
+    for n in counts:
+        out.append(refs[:n])
+        refs = refs[n:]
+    return (*out, refs)
 
 
-def _conv_fwd_kernel(*refs, parts, size):
-    """Grid (key heads, rows of the batch, row blocks — walked in order),
-    ``size`` rows worked through at a time: ``refs`` = the parts' input
-    blocks, w's three blocks (K, width), the three output blocks, a float32
-    scratch (HALO + rows, width) a part whose first HALO rows carry the
-    previous block's last ones."""
-    n = len(parts)
-    x_refs, w_refs = refs[:n], refs[n:n + 3]
-    o_refs, xs_refs = refs[n + 3:n + 6], refs[n + 6:]
+def _lane_tiles(width: int, piece):
+    """``piece(first lane)`` for every lane tile of a part ``width`` lanes
+    wide: straight-line where the part is one tile, else a loop ON THE CHIP
+    over the tiles — one copy of the kernel's body to trace, lower and
+    compile whatever the part's width (34 copies for the 4352 ``xBC``
+    channels cost every process 4.4 s of set-up, PERF.md section 6, PR
+    44)."""
+    if width == _LANES:
+        return piece(0)
+
+    def tile(i, carry):
+        piece(pl.multiple_of(i * _LANES, _LANES))
+        return carry
+
+    jax.lax.fori_loop(0, width // _LANES, tile, 0)
+
+
+def _conv_fwd_kernel(*refs, lay: ConvLayout, bias: bool, size: int):
+    """Grid (groups, rows of the batch, row blocks — walked in order),
+    ``size`` rows x a lane tile worked through at a time: ``refs`` = the
+    parts' input blocks, w's blocks (K, width) an output, with ``bias`` the
+    bias's (1, width), the output blocks, a float32 scratch (HALO + rows,
+    width) a part whose first HALO rows carry the previous block's last
+    ones."""
+    n, outs = len(lay.parts), len(lay.outs)
+    x_refs, w_refs, b_refs, o_refs, xs_refs = _refs(
+        refs, n, outs, outs * bias, outs)
     f32 = jnp.float32
     rows = x_refs[0].shape[1]
     first = pl.program_id(2) == 0
-    for x_ref, xs, (width, out, lane) in zip(x_refs, xs_refs, parts):
-        lanes = slice(lane, lane + width)
+    for x_ref, xs, (_, width, out, lane) in zip(x_refs, xs_refs, lay.parts):
         w_ref, o_ref = w_refs[out], o_refs[out]
         k = w_ref.shape[0]
 
@@ -247,32 +364,38 @@ def _conv_fwd_kernel(*refs, parts, size):
         def _():
             xs[0:_HALO, :] = jnp.zeros((_HALO, width), f32)
 
-        for start in range(0, rows, size):
-            xs[_HALO + start:_HALO + start + size, :] = x_ref[
-                0, start:start + size, :].astype(f32)
-            y = _taps(w_ref, lanes, lambda j: xs[pl.ds(
-                _HALO - (k - 1) + j + start, size), :])
-            o_ref[0, start:start + size, lanes] = (
-                y * jax.nn.sigmoid(y)).astype(o_ref.dtype)
+        def piece(lo):
+            cols, lanes = pl.ds(lo, _LANES), pl.ds(lane + lo, _LANES)
+            for start in range(0, rows, size):
+                xs[_HALO + start:_HALO + start + size, cols] = x_ref[
+                    0, start:start + size, cols].astype(f32)
+                y = _taps(w_ref, lanes, lambda j: xs[pl.ds(
+                    _HALO - (k - 1) + j + start, size), cols])
+                if bias:
+                    y = y + b_refs[out][:, lanes]
+                o_ref[0, start:start + size, lanes] = (
+                    y * jax.nn.sigmoid(y)).astype(o_ref.dtype)
+
+        _lane_tiles(width, piece)
         xs[0:_HALO, :] = xs[rows:rows + _HALO, :]
 
 
-def _conv_bwd_kernel(*refs, parts, size):
-    """Grid (key heads, rows of the batch, row blocks — walked from the
-    LAST): ``refs`` = the parts' input blocks, their blocks of preceding
-    rows, the blocks of dq, dk, dv and dz, w's three, then the block of the
-    projection's gradient (a key head's q, k, v, z side by side: dz is
-    copied into its place), dw's three (K, width) blocks — resident over a
-    key head's whole walk and summed into —, a float32 scratch (HALO +
-    rows, widest part) for the inputs, and one (rows + HALO, width) a part
-    for ``dy silu'(y)`` whose last HALO rows carry the following block's
-    first ones."""
-    n = len(parts)
-    x_refs, h_refs = refs[:n], refs[n:2 * n]
-    dy_refs, dz_ref = refs[2 * n:2 * n + 3], refs[2 * n + 3]
-    w_refs, dx_ref = refs[2 * n + 4:2 * n + 7], refs[2 * n + 7]
-    dw_refs = refs[2 * n + 8:2 * n + 11]
-    xs, gs_refs = refs[2 * n + 11], refs[2 * n + 12:]
+def _conv_bwd_kernel(*refs, lay: ConvLayout, bias: bool, size: int):
+    """Grid (groups, rows of the batch, row blocks — walked from the LAST):
+    ``refs`` = the parts' input blocks, their blocks of preceding rows, the
+    blocks of the outputs' cotangents and of what was handed through, w's
+    blocks (and the bias's), then the group's block of the projection's
+    gradient (the parts' gradients and what was handed through side by side,
+    where they lie), dw's (K, width) blocks an output (and the bias's (1,
+    width)) — resident over a group's whole walk and summed into —, a float32
+    scratch (HALO + rows, a lane tile) for the inputs, and one (rows + HALO,
+    width) a part for ``dy silu'(y)`` whose last HALO rows carry the
+    following block's first ones."""
+    n, outs = len(lay.parts), len(lay.outs)
+    (x_refs, h_refs, dy_refs, dp_refs, w_refs, b_refs, (dx_ref,), dw_refs,
+     db_refs, (xs,), gs_refs) = _refs(
+        refs, n, n, outs, len(lay.passed), outs, outs * bias, 1, outs,
+        outs * bias, 1)
     f32 = jnp.float32
     rows = x_refs[0].shape[1]
     last_block = pl.program_id(2) == 0          # the walk's first step
@@ -280,172 +403,217 @@ def _conv_bwd_kernel(*refs, parts, size):
 
     @pl.when(jnp.logical_and(pl.program_id(1) == 0, last_block))
     def _():
-        for dw_ref in dw_refs:
-            dw_ref[...] = jnp.zeros_like(dw_ref)
+        for ref in (*dw_refs, *db_refs):
+            ref[...] = jnp.zeros_like(ref)
 
-    dx_lane = 0
-    for x_ref, h_ref, gs, (width, out, lane) in zip(x_refs, h_refs, gs_refs,
-                                                    parts):
-        lanes = slice(lane, lane + width)
+    for x_ref, h_ref, gs, (at, width, out, lane) in zip(x_refs, h_refs,
+                                                        gs_refs, lay.parts):
         dy_ref, w_ref, dw_ref = dy_refs[out], w_refs[out], dw_refs[out]
         k = w_ref.shape[0]
-        halo = h_ref[0].astype(f32)[h_ref.shape[1] - _HALO:]
-        xs[0:_HALO, :width] = jnp.where(first_block, 0.0, halo)
 
         @pl.when(last_block)    # nothing follows a row's last token
         def _():
             gs[rows:rows + _HALO, :] = jnp.zeros((_HALO, width), f32)
 
-        for start in range(0, rows, size):
-            xs[_HALO + start:_HALO + start + size, :width] = x_ref[
-                0, start:start + size, :].astype(f32)
-        dw = [jnp.zeros((1, width), f32)] * k
-        for start in reversed(range(0, rows, size)):
-            window = [xs[pl.ds(_HALO - (k - 1) + j + start, size), :width]
-                      for j in range(k)]
-            y = _taps(w_ref, lanes, lambda j: window[j])
-            sig = jax.nn.sigmoid(y)
-            g = (dy_ref[0, start:start + size, lanes].astype(f32)
-                 * (sig * (1.0 + y * (1.0 - sig))))
-            gs[start:start + size, :] = g
-            dw = [acc + jnp.sum(g * window[j], axis=0, keepdims=True)
-                  for j, acc in enumerate(dw)]
-            # the taps the other way: row t's input fed outputs t .. t + K-1
-            dx = _taps(w_ref, lanes, lambda j: gs[pl.ds(
-                start + (k - 1) - j, size), :])
-            dx_ref[0, start:start + size, dx_lane:dx_lane + width] = (
-                dx.astype(dx_ref.dtype))
-        for j in range(k):
-            dw_ref[j:j + 1, lanes] += dw[j]
+        def piece(lo):
+            cols, lanes = pl.ds(lo, _LANES), pl.ds(lane + lo, _LANES)
+            halo = h_ref[0, :, cols].astype(f32)[h_ref.shape[1] - _HALO:]
+            xs[0:_HALO, :] = jnp.where(first_block, 0.0, halo)
+            for start in range(0, rows, size):
+                xs[_HALO + start:_HALO + start + size, :] = x_ref[
+                    0, start:start + size, cols].astype(f32)
+            dw = [jnp.zeros((1, _LANES), f32)] * (k + bias)
+            for start in reversed(range(0, rows, size)):
+                window = [xs[pl.ds(_HALO - (k - 1) + j + start, size), :]
+                          for j in range(k)]
+                y = _taps(w_ref, lanes, lambda j: window[j])
+                if bias:
+                    y = y + b_refs[out][:, lanes]
+                sig = jax.nn.sigmoid(y)
+                g = (dy_ref[0, start:start + size, lanes].astype(f32)
+                     * (sig * (1.0 + y * (1.0 - sig))))
+                gs[start:start + size, cols] = g
+                terms = [g * t for t in window] + [g] * bias
+                dw = [acc + jnp.sum(t, axis=0, keepdims=True)
+                      for acc, t in zip(dw, terms)]
+                # the taps the other way: row t's input fed outputs t .. t + K-1
+                dx = _taps(w_ref, lanes, lambda j: gs[pl.ds(
+                    start + (k - 1) - j, size), cols])
+                dx_ref[0, start:start + size, pl.ds(at + lo, _LANES)] = (
+                    dx.astype(dx_ref.dtype))
+            for j in range(k):
+                dw_ref[j:j + 1, lanes] += dw[j]
+            if bias:
+                db_refs[out][:, lanes] += dw[k]
+
+        _lane_tiles(width, piece)
         gs[rows:rows + _HALO, :] = gs[0:_HALO, :]
-        dx_lane += width
-    dx_ref[0, :, dx_lane:] = dz_ref[0]
+    for (at, width), dp_ref in zip(lay.passed, dp_refs):
+        dx_ref[0, :, at:at + width] = dp_ref[0]
 
 
-def _conv_specs(rows, taps, p, dk, dv, r, block_of):
-    """BlockSpecs over a grid (key heads, rows of the batch, row blocks),
-    step ``i`` of the last axis taking row block ``block_of(i)``: ``(the
-    parts' blocks of the projection's output, their blocks of the HALO_ROWS
-    preceding rows, [q, k, v, z]-shaped blocks, w's blocks, a key head's
-    whole block of the projection's output)``."""
+def _conv_specs(lay: ConvLayout, rows: int, taps: int, block_of):
+    """BlockSpecs over a grid (groups, rows of the batch, row blocks), step
+    ``i`` of the last axis taking row block ``block_of(i)``: ``(the parts'
+    blocks of the projection's output, their blocks of the HALO_ROWS
+    preceding rows, the outputs' blocks, the blocks of what is handed
+    through, w's blocks, the bias's, a group's whole block of the
+    projection's output)``."""
     def rows_of(width, col):
         return pl.BlockSpec((1, rows, width),
-                            lambda h, b, i: (b, block_of(i), col(h)))
+                            lambda g, b, i: (b, block_of(i), col(g)))
 
     def halo_of(width, col):
         per = rows // _HALO_ROWS
         return pl.BlockSpec(
             (1, _HALO_ROWS, width),
-            lambda h, b, i: (b, jnp.maximum(block_of(i) * per - 1, 0), col(h)))
+            lambda g, b, i: (b, jnp.maximum(block_of(i) * per - 1, 0), col(g)))
 
     # a part's first column in the projection's output, in blocks of its width
-    offsets = [0, dk] + [2 * dk + j * dv for j in range(r)]
-    widths = [dk, dk] + [dv] * r
-    cols = [(lambda h, o=o, w=w: (h * p + o) // w)
-            for o, w in zip(offsets, widths)]
-    head = lambda h: h
-    weights = lambda width: pl.BlockSpec((taps, width), lambda h, b, i: (0, h))
+    cols = [(lambda g, c=first, w=width: (g * lay.stride + c) // w)
+            for first, width, _, _ in lay.parts]
+    widths = [width for _, width, _, _ in lay.parts]
+    group = lambda g: g
+    per_out = lambda n: [pl.BlockSpec((n, width), lambda g, b, i: (0, g))
+                         for width in lay.outs]
     return ([rows_of(w, c) for w, c in zip(widths, cols)],
             [halo_of(w, c) for w, c in zip(widths, cols)],
-            [rows_of(dk, head), rows_of(dk, head), rows_of(r * dv, head),
-             rows_of(r * dv, head)],
-            [weights(dk), weights(dk), weights(r * dv)], rows_of(p, head))
+            [rows_of(width, group) for width in lay.outs],
+            [rows_of(width, group) for _, width in lay.passed],
+            per_out(taps), per_out(1), rows_of(lay.stride, group))
 
 
-def _conv_weights(w, hk, dk):
-    """``w`` (channels, K) in the channel order ``[q | k | v]`` as three
-    float32 arrays (K, heads x d): a tap is a row of lanes (32 K values)."""
-    wt = w.astype(jnp.float32).T
-    return jnp.split(wt, [hk * dk, 2 * hk * dk], axis=1)
+def _conv_weights(w, bias, lay: ConvLayout):
+    """``w`` (channels, K) — its channels over the outputs in order, each
+    over all groups — as float32 arrays (K, groups x width) an output: a tap
+    is a row of lanes (32 K values); then the bias's (1, groups x width),
+    where there is one."""
+    ends = np.cumsum([lay.groups * width for width in lay.outs])[:-1].tolist()
+    rows = [w.astype(jnp.float32).T]
+    if bias is not None:
+        rows.append(bias.astype(jnp.float32)[None, :])
+    return [part for t in rows for part in jnp.split(t, ends, axis=1)]
 
 
-def _conv_fwd_pallas(qkvz, w, hk, dk, dv, tile):
-    b, s, width = qkvz.shape
+def _conv_params(proj, lay: ConvLayout, rows: int, *semantics):
+    """The grid's semantics, and a VMEM limit of their own for blocks past
+    what the compiler grants a kernel unasked (16 MiB on a v5e)."""
+    past = _conv_vmem(lay, rows, proj.dtype.itemsize) > 12 * 1024 * 1024
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=2 * _CONV_VMEM if past else None)
+
+
+def _conv_fwd_pallas(proj, w, bias, lay: ConvLayout, tile):
+    b, s, _ = proj.shape
     rows, piece = tile
-    p, r = _conv_dims(width, hk, dk, dv)
-    parts = _conv_parts(dk, dv, r)
     taps = w.shape[1]
-    x_specs, _, out_specs, w_specs, _ = _conv_specs(
-        rows, taps, p, dk, dv, r, lambda i: i)
+    x_specs, _, out_specs, _, w_specs, b_specs, _ = _conv_specs(
+        lay, rows, taps, lambda i: i)
+    has_bias = bias is not None
     return _pallas_call(
-        functools.partial(_conv_fwd_kernel, parts=parts, size=piece),
-        name="apex_conv1d_fwd", grid=(hk, b, s // rows),
-        in_specs=[*x_specs, *w_specs], out_specs=out_specs[:3],
-        out_shape=[jax.ShapeDtypeStruct((b, s, hk * dk), qkvz.dtype),
-                   jax.ShapeDtypeStruct((b, s, hk * dk), qkvz.dtype),
-                   jax.ShapeDtypeStruct((b, s, hk * r * dv), qkvz.dtype)],
-        scratch_shapes=[pltpu.VMEM((_HALO + rows, width_), jnp.float32)
-                        for width_, _, _ in parts],
-        compiler_params=_COMPILER_PARAMS,
-    )(*[qkvz] * len(parts), *_conv_weights(w, hk, dk))
+        functools.partial(_conv_fwd_kernel, lay=lay, bias=has_bias,
+                          size=piece),
+        name="apex_conv1d_fwd", grid=(lay.groups, b, s // rows),
+        in_specs=[*x_specs, *w_specs, *(b_specs if has_bias else [])],
+        out_specs=out_specs,
+        out_shape=[jax.ShapeDtypeStruct((b, s, lay.groups * width), proj.dtype)
+                   for width in lay.outs],
+        scratch_shapes=[pltpu.VMEM((_HALO + rows, width), jnp.float32)
+                        for _, width, _, _ in lay.parts],
+        compiler_params=_conv_params(proj, lay, rows, "parallel", "parallel",
+                                     "arbitrary"),
+    )(*[proj] * len(lay.parts), *_conv_weights(w, bias, lay))
 
 
-def _conv_bwd_pallas(qkvz, w, dq, dk_, dv_, dz, hk, dk, dv, tile):
-    """``(d qkvz, dw)``: the projection's gradient written where it lies, a
-    key head's ``[dq | dk | dv | dz]`` side by side."""
-    b, s, width = qkvz.shape
-    p, r = _conv_dims(width, hk, dk, dv)
-    parts = _conv_parts(dk, dv, r)
+def _conv_bwd_pallas(proj, w, bias, dys, lay: ConvLayout, tile):
+    """``(d proj, dw, dbias)``: the projection's gradient written where it
+    lies, a group's parts and what it handed through side by side."""
+    b, s, _ = proj.shape
+    n_parts, n_outs = len(lay.parts), len(lay.outs)
     taps = w.shape[1]
     rows, piece = tile
     n = s // rows
-    x_specs, halo_specs, dy_specs, w_specs, dx_spec = _conv_specs(
-        rows, taps, p, dk, dv, r, lambda i: n - 1 - i)
-    dw_specs = w_specs
-    weights = _conv_weights(w, hk, dk)
-    dx, *dw = _pallas_call(
-        functools.partial(_conv_bwd_kernel, parts=parts, size=piece),
-        name="apex_conv1d_bwd", grid=(hk, b, n),
-        in_specs=[*x_specs, *halo_specs, *dy_specs, *w_specs],
-        out_specs=[dx_spec, *dw_specs],
-        out_shape=[jax.ShapeDtypeStruct(qkvz.shape, qkvz.dtype),
+    x_specs, halo_specs, dy_specs, dp_specs, w_specs, b_specs, dx_spec = \
+        _conv_specs(lay, rows, taps, lambda i: n - 1 - i)
+    has_bias = bias is not None
+    weights = _conv_weights(w, bias, lay)
+    small = [*w_specs, *(b_specs if has_bias else [])]
+    dx, *dwb = _pallas_call(
+        functools.partial(_conv_bwd_kernel, lay=lay, bias=has_bias,
+                          size=piece),
+        name="apex_conv1d_bwd", grid=(lay.groups, b, n),
+        in_specs=[*x_specs, *halo_specs, *dy_specs, *dp_specs, *small],
+        out_specs=[dx_spec, *small],
+        out_shape=[jax.ShapeDtypeStruct(proj.shape, proj.dtype),
                    *(jax.ShapeDtypeStruct(x.shape, jnp.float32)
                      for x in weights)],
-        scratch_shapes=[pltpu.VMEM((_HALO + rows, max(dk, dv)), jnp.float32)]
-        + [pltpu.VMEM((rows + _HALO, width_), jnp.float32)
-           for width_, _, _ in parts],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-    )(*[qkvz] * (2 * len(parts)), dq, dk_, dv_, dz, *weights)
-    return dx, jnp.concatenate(dw, axis=1).T.astype(w.dtype)
+        scratch_shapes=[pltpu.VMEM((_HALO + rows, _LANES), jnp.float32)]
+        + [pltpu.VMEM((rows + _HALO, width), jnp.float32)
+           for _, width, _, _ in lay.parts],
+        compiler_params=_conv_params(proj, lay, rows, "parallel", "arbitrary",
+                                     "arbitrary"),
+    )(*[proj] * (2 * n_parts), *dys, *weights)
+    dw = jnp.concatenate(dwb[:n_outs], axis=1).T.astype(w.dtype)
+    if not has_bias:
+        return dx, dw, None
+    return dx, dw, jnp.concatenate(dwb[n_outs:], axis=1)[0].astype(bias.dtype)
 
 
-def _cut_z(qkvz, hk: int, dk: int, dv: int):
-    """z (B, S, H_v d_v) out of the per-key-head layout as ``H_k``
-    lane-aligned column slices side by side: a copy of z's bytes alone, which
-    XLA fuses into z's reader.  Cut as :func:`_split_conv_xla` cuts it —
-    through the (B, S, H_k, P) reshape — the chip first makes a relayout
-    copy of the WHOLE projection output (PERF.md section 6, PR 33: 0.61 ms a
-    pass)."""
-    p, r = _conv_dims(qkvz.shape[2], hk, dk, dv)
-    return jnp.concatenate([qkvz[:, :, (h + 1) * p - r * dv:(h + 1) * p]
-                            for h in range(hk)], axis=-1)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_kernels(proj, w, bias, lay, tile):
+    return (*_conv_fwd_pallas(proj, w, bias, lay, tile),
+            *(lay.cut(proj, *cols) for cols in lay.passed))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
-def _conv_kernels(qkvz, w, hk, dk, dv, tile):
-    return (*_conv_fwd_pallas(qkvz, w, hk, dk, dv, tile),
-            _cut_z(qkvz, hk, dk, dv))
+def _conv_kernels_fwd(proj, w, bias, lay, tile):
+    return _conv_kernels(proj, w, bias, lay, tile), (proj, w, bias)
 
 
-def _conv_kernels_fwd(qkvz, w, hk, dk, dv, tile):
-    return _conv_kernels(qkvz, w, hk, dk, dv, tile), (qkvz, w)
-
-
-def _conv_kernels_bwd(hk, dk, dv, tile, res, dys):
-    return _conv_bwd_pallas(*res, *dys, hk, dk, dv, tile)
+def _conv_kernels_bwd(lay, tile, res, dys):
+    return _conv_bwd_pallas(*res, dys, lay, tile)
 
 
 _conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
 
 
 # Called through jit, as the rule below: a model's layers share one trace.
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
-def _conv_jit(qkvz, w, hk, dk, dv, tile, trace_key):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _conv_jit(proj, w, bias, lay, tile, trace_key):
     del trace_key
     if tile:
-        return _conv_kernels(qkvz, w, hk, dk, dv, tile)
-    return _split_conv_xla(qkvz, w, hk, dk, dv)
+        return _conv_kernels(proj, w, bias, lay, tile)
+    return _conv_xla(proj, w, bias, lay)
+
+
+def conv_columns(proj, w, bias, lay: ConvLayout, use_pallas: Optional[bool],
+                 gauge: str):
+    """The short convolution of ``proj``'s columns as ``lay`` describes
+    them: ``(the outputs, what is handed through)``, a tuple — through the
+    kernels on the TPU where the shapes tile (:func:`_conv_tile`), else in
+    ``jax.numpy``; the gauge ``gauge`` says which was traced.  What the
+    public operators share: :func:`split_conv_qkvz` and
+    ``ops/ssd.py::split_conv_xbc`` each describe a layout and call this."""
+    channels = lay.groups * sum(lay.outs)
+    if w.shape[0] != channels or (bias is not None
+                                  and bias.shape != (channels,)):
+        raise ValueError(f"w {w.shape} and bias "
+                         f"{None if bias is None else bias.shape} are not "
+                         f"the convolution's {channels} channels")
+    tile = _conv_tile(proj.shape[1], lay, w.shape[1], proj.dtype.itemsize)
+    if use_pallas is None:
+        use_pallas = pallas_default(tile is not None)
+    elif use_pallas and tile is None:
+        raise ValueError(f"the convolution's kernels want parts of whole "
+                         f"tiles of 128 lanes, each a whole number of its "
+                         f"widths in, rows in blocks of {_HALO_ROWS} and at "
+                         f"most {_HALO + 1} taps: got {proj.shape}, "
+                         f"{w.shape}, {lay}")
+    from apex_tpu import obs
+
+    obs.default_registry().gauge(gauge).set(int(use_pallas))
+    return _conv_jit(proj, w, bias, lay, tile if use_pallas else None,
+                     _trace_key())
 
 
 def split_conv_qkvz(qkvz, w, *, key_heads: int, key_dim: int,
@@ -473,26 +641,10 @@ def split_conv_qkvz(qkvz, w, *, key_heads: int, key_dim: int,
     XLA's interleave of the four is three relayout passes over an array of
     ``qkvz``'s size (PERF.md section 6, PR 33).  No concatenated, no float32
     and no padded array crosses HBM.  Else the same in ``jax.numpy``
-    (:func:`_split_conv_xla`).  The gauge ``gdn.conv_kernel`` says which was
+    (:func:`_conv_xla`).  The gauge ``gdn.conv_kernel`` says which was
     traced."""
-    hk, dk, dv = key_heads, key_dim, value_dim
-    _, r = _conv_dims(qkvz.shape[2], hk, dk, dv)
-    if w.shape[0] != 2 * hk * dk + hk * r * dv:
-        raise ValueError(f"w has {w.shape[0]} channels, q, k and v "
-                         f"{2 * hk * dk + hk * r * dv}")
-    ok = conv_supported(qkvz.shape[1], dk, dv, r, w.shape[1])
-    if use_pallas is None:
-        use_pallas = pallas_default(ok)
-    elif use_pallas and not ok:
-        raise ValueError(f"the convolution's kernels want head sizes of 128 "
-                         f"lanes and rows in blocks of {_HALO_ROWS}: got "
-                         f"{qkvz.shape}, {dk}, {dv}, {w.shape}")
-    from apex_tpu import obs
-
-    obs.default_registry().gauge("gdn.conv_kernel").set(int(use_pallas))
-    return _conv_jit(qkvz, w, hk, dk, dv,
-                     _conv_tile(qkvz.shape[1]) if use_pallas else None,
-                     _trace_key())
+    lay = _qkvz_layout(qkvz.shape[2], key_heads, key_dim, value_dim)
+    return conv_columns(qkvz, w, None, lay, use_pallas, "gdn.conv_kernel")
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,9 @@
 a sequence, by chunks — two Pallas TPU kernels that hold every (chunk, chunk)
 tensor in VMEM, a ``jax.numpy`` chunked path, and the token recurrence as the
 oracle — and the short causal depthwise convolution with a bias in front of
-it.
+it, read out of the layer's projection where it lies
+(:func:`split_conv_xbc`: ``ops/gated_delta.py``'s convolution kernels under
+this layer's layout).
 
 The second sequential operator of ``ops/`` (the first is
 ``ops/gated_delta.py``'s delta rule): a state-space layer
@@ -91,10 +93,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops._common import pallas_call as _pallas_call, pallas_default
-from apex_tpu.ops.gated_delta import _NN, _NT, _TN, _dot, _trace_key
+from apex_tpu.ops.gated_delta import (_NN, _NT, _TN, ConvLayout, _dot,
+                                      _trace_key, conv_columns)
 
-__all__ = ["ssd_scan", "ssd_recurrent", "causal_conv1d_bias_silu",
-           "supported", "DEFAULT_CHUNK"]
+__all__ = ["ssd_scan", "ssd_recurrent", "split_conv_xbc", "supported",
+           "DEFAULT_CHUNK"]
 
 DEFAULT_CHUNK = 256
 #: heads a grid step of the kernels takes together, at most: their states
@@ -104,19 +107,55 @@ _HEADS_PER_STEP = 8
 _LANES = 128
 
 
-def causal_conv1d_bias_silu(x, w, bias):
-    """Depthwise causal convolution over the sequence, a bias, then SiLU.
+def _xbc_layout(d_inner: int, d_bc: int, heads: int) -> ConvLayout:
+    """``in_proj``'s output ``[z d_in | x d_in | B | C | dt H]`` as ONE group:
+    x, B and C each a part and an output of its own (x starts ``d_in`` in,
+    one block of its width; B and C whole blocks of ``G N`` where ``2 d_in``
+    is), z and dt handed through."""
+    return ConvLayout(
+        1, 2 * d_inner + 2 * d_bc + heads,
+        ((d_inner, d_inner, 0, 0), (2 * d_inner, d_bc, 1, 0),
+         (2 * d_inner + d_bc, d_bc, 2, 0)),
+        (d_inner, d_bc, d_bc),
+        ((0, d_inner), (2 * d_inner + 2 * d_bc, heads)))
 
-    ``x`` (B, S, channels), ``w`` (channels, K), ``bias`` (channels,): ``y_t
-    = silu(sum_j w[:, j] * x_{t - (K-1) + j} + bias)`` with zeros before the
-    row's start.  ``K`` shifted multiply-adds in float32, ``x``'s dtype out —
-    ``ops/gated_delta.py::causal_conv1d_silu`` with the bias a Mamba-2 layer's
-    convolution has, under XLA on every backend."""
-    k, s = w.shape[-1], x.shape[1]
-    x32 = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    w32 = w.astype(jnp.float32)
-    y = sum(x32[:, j:j + s] * w32[:, j] for j in range(k))
-    return jax.nn.silu(y + bias.astype(jnp.float32)).astype(x.dtype)
+
+def split_conv_xbc(zxbcdt, w, bias, *, d_inner: int, d_bc: int,
+                   use_pallas: Optional[bool] = None):
+    """A Mamba-2 layer's ``in_proj`` output cut into its five parts, x, B and
+    C through the layer's short convolution on the way — read where they lie.
+
+    ``zxbcdt`` (b, s, 2 d_in + 2 G N + H) laid out ``[z d_in | x d_in | B G N
+    | C G N | dt H]``, ``w`` (d_in + 2 G N, K) and ``bias`` (d_in + 2 G N,)
+    with their channels in the order ``[x | B | C]``; ``d_bc`` is ``G N``.
+    Returns ``(z, x (b, s, d_in), B (b, s, G N), C, dt (b, s, H))`` in
+    ``zxbcdt``'s dtype; x, B, C are ``causal_conv1d_silu`` of the three taken
+    together with the bias — float32 taps, bias and SiLU, one rounding at the
+    output —, z and dt are copies.  Differentiable in ``zxbcdt``, ``w`` and
+    ``bias``.
+
+    On the TPU, where the shapes tile (x, B and C of whole 128-lane tiles,
+    ``2 d_in`` a multiple of ``G N``, rows in blocks of 16, at most 9 taps),
+    ``ops/gated_delta.py``'s two kernels under this layout: ``apex_conv1d_fwd``
+    takes a block of rows x the 4352 ``xBC`` columns through three BlockSpecs
+    on ``zxbcdt`` itself (z and dt are not read), a lane tile at a time, and
+    writes x, B and C as the three arrays the scan's kernels take;
+    ``apex_conv1d_bwd`` walks the row blocks from the last and writes the
+    projection's whole gradient ``[dz | dx | dB | dC | ddt]`` where it lies
+    — dz and ddt handed through —, dw and dbias summed in float32.  No
+    concatenated, no float32 and no padded array crosses HBM (under XLA the
+    same arithmetic is a padded float32 copy and ``K`` shifted float32
+    passes: 49.6 ms a step of ``granite-h.train-8k`` for 6 ms of bytes,
+    PERF.md section 6, PR 44).  Else the same in ``jax.numpy``.  The gauge
+    ``ssd.conv_kernel`` says which was traced."""
+    heads = zxbcdt.shape[-1] - 2 * (d_inner + d_bc)
+    if zxbcdt.ndim != 3 or heads < 0:
+        raise ValueError(f"zxbcdt {zxbcdt.shape} is not (b, s, [z {d_inner} | "
+                         f"x {d_inner} | B {d_bc} | C {d_bc} | dt])")
+    lay = _xbc_layout(d_inner, d_bc, heads)
+    x, bm, cm, z, dt = conv_columns(zxbcdt, w, bias, lay, use_pallas,
+                                    "ssd.conv_kernel")
+    return z, x, bm, cm, dt
 
 
 def ssd_recurrent(x, dt, A, B, C, D):

@@ -21,7 +21,9 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   gradient timed on both paths (``gated_conv_at_cell``); and the state-space
   scan at the Granite cell's call, its two kernels against the token
   recurrence, forward and the six gradients, both paths timed
-  (``ssd_at_cell``); and ONE making
+  (``ssd_at_cell``), and the convolution in front of it, reading x, B and C
+  out of ``in_proj``'s output, against the ``jax.numpy`` form
+  (``ssm_conv_at_cell``); and ONE making
   of the expert layer's routing plan at the five sparse cells' shapes, with
   each lookup inside it as the gather it was and as the sum over the held
   experts it can be, timed on the device and the tables held equal to the
@@ -358,6 +360,73 @@ def ssd_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
             scan(*a).astype(f32) * cot), tuple(range(6))))
         timed[f"fwd_{side}_us"] = _us_a_call(jax.jit(scan), args)
         timed[f"grad_{side}_us"] = _us_a_call(grad, args)
+    return timed
+
+
+# ---------------------------------------------------------------------------
+# the Mamba-2 layer's short convolution at the cell's call
+# ---------------------------------------------------------------------------
+
+def ssm_conv_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
+    """``ops/ssd.py::split_conv_xbc`` at ``granite-h.train-8k``'s call — a
+    mamba layer's ``in_proj`` output of one row of 8 contexts, ``[z | x | B |
+    C | dt]`` with ``s / 16`` heads of 64 channels and a state of 128 in
+    bfloat16, 4 taps and a bias over the ``xBC`` channels: the two kernels,
+    reading x, B and C where they lie, against the ``jax.numpy`` form in
+    float32 on the same bfloat16 values — the five outputs in the
+    projection's own order and the gradients of the projection's output, the
+    taps and the bias (1e-2 of each array's largest element: one rounding of
+    an output; 1e-3 on dw and dbias, float32 sums in another order) — every
+    Mosaic call named.  Then us a call of the forward and of the gradient
+    program on both paths.  An array 66.5 lane tiles wide that ENTERS or
+    LEAVES a program is laid out the other way round by the chip's compiler,
+    so every timed program opens (the gradient's also closes) with a relayout
+    copy of the whole array that no model's program has, on both paths alike:
+    the us bound the operator from above; the kernels' own times are the
+    cell's trace's (PERF.md section 5)."""
+    from apex_tpu.ops.ssd import split_conv_xbc
+
+    f32, bf16, normal = jnp.float32, jnp.bfloat16, jax.random.normal
+    heads, state, taps = s // 16, 128, 4
+    d_in = heads * 64
+    conv = d_in + 2 * state
+    shape = (1, 8 * s, d_in + conv + heads)
+    x, w, bias, cot = jax.jit(lambda key: [
+        make(k_) for make, k_ in zip(
+            (lambda k_: normal(k_, shape, f32).astype(bf16),
+             lambda k_: 0.5 * normal(k_, (conv, taps), f32),
+             lambda k_: 0.5 * normal(k_, (conv,), f32),
+             lambda k_: normal(k_, shape, f32).astype(bf16)),
+            jax.random.split(key, 4))])(jax.random.fold_in(root_key, 180))
+    cot = cot.astype(f32)       # a cotangent bfloat16 holds, as conv1d's
+
+    def parts(use_pallas):
+        return lambda x, w, bias: split_conv_xbc(
+            x, w, bias, d_inner=d_in, d_bc=state, use_pallas=use_pallas)
+
+    def loss(use_pallas):
+        def fn(x, w, bias, cot):
+            out = jnp.concatenate(parts(use_pallas)(x, w, bias), axis=-1)
+            return jnp.sum(out.astype(f32) * cot), out
+        return fn
+
+    both = lambda use_pallas: jax.jit(jax.value_and_grad(
+        loss(use_pallas), (0, 1, 2), has_aux=True))
+    compiled = both(None).lower(x, w, bias, cot).compile()
+    _require_mosaic(compiled, 2, calls, "ssm_conv")
+    (_, out), grads = compiled(x, w, bias, cot)
+    (_, want), want_grads = both(False)(x.astype(f32), w, bias, cot)
+    _compare("ssm_conv.fwd", out, want, 1e-2, parity)
+    for name, tol, g, wg in zip(("dx", "dw", "dbias"), (1e-2, 1e-3, 1e-3),
+                                grads, want_grads):
+        _compare(f"ssm_conv.{name}", g, wg, tol, parity)
+    timed = {"shape": [*shape, taps],
+             "kernels": mosaic_call_names(compiled.as_text())}
+    for side, use_pallas in (("kernels", None), ("jnp", False)):
+        grad = jax.jit(jax.grad(lambda *a: loss(use_pallas)(*a)[0], (0, 1, 2)))
+        timed[f"fwd_{side}_us"] = _us_a_call(jax.jit(parts(use_pallas)),
+                                             (x, w, bias))
+        timed[f"grad_{side}_us"] = _us_a_call(grad, (x, w, bias, cot))
     return timed
 
 
@@ -786,7 +855,8 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
                 "flash_latent": [1, s // 64, 8 * s, [192, 128]],
                 "conv1d": [1, 8 * s, s // 64 * 768, 4],
                 "gated_conv": [1, 16 * s, 3 * 2 * s, 3],
-                "ssd": [1, 8 * s, s // 16, 64, 128]},
+                "ssd": [1, 8 * s, s // 16, 64, 128],
+                "ssm_conv": [1, 8 * s, 8 * s + 256 + s // 16, 4]},
         mosaic_calls=calls, parity=parity,
     )
 
@@ -1094,6 +1164,9 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     # the state-space scan at granite-h.train-8k's call, its two kernels
     # against the token recurrence
     facts["ssd_at_cell"] = ssd_at_cell(s, root_key, parity, calls)
+
+    # the convolution in front of that scan, read out of in_proj's output
+    facts["ssm_conv_at_cell"] = ssm_conv_at_cell(s, root_key, parity, calls)
 
     # the expert layer's row movement at smallthinker.train-16k's shape —
     # twice the LayerNorm rows x 2560 bfloat16 (a record 20 sublanes: two and

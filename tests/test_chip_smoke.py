@@ -65,7 +65,7 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
         "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d",
-        "gated_conv", "ssd"}
+        "gated_conv", "ssd", "ssm_conv"}
     # the backward's two routes at the three 8k cells' calls, timed and held
     # to each other and to the reference
     routes = kernels["flash_backward"]
@@ -132,6 +132,14 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
             "grad_jnp_us", "kernels"} <= set(scan)
     for name in ("oracle_in_blocks", "fwd", "dx", "ddt", "dA", "dB", "dC", "dD"):
         assert f"ssd.{name}" in kernels["parity"]
+    # the convolution in front of it, x, B and C read out of in_proj's output
+    conv = kernels["ssm_conv_at_cell"]
+    assert conv["shape"] == [1, 8 * TINY.ctx, 8 * TINY.ctx + 256
+                             + TINY.ctx // 16, 4]
+    assert {"fwd_kernels_us", "grad_kernels_us", "fwd_jnp_us",
+            "grad_jnp_us", "kernels"} <= set(conv)
+    for name in ("fwd", "dx", "dw", "dbias"):
+        assert f"ssm_conv.{name}" in kernels["parity"]
     # the routing plan at the five sparse cells' shapes: one making, its dear
     # parts and each lookup both ways timed, the tables equal to the bit
     plans = kernels["moe_plan_at_cell"]

@@ -122,13 +122,13 @@ def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
     assert model.cfg.remat_policy == remat
     params = fam.to_program(w, cfg)
 
-    def program_loss(p):
+    def program_loss(p):    # one program: the logits beside the loss
         return model.apply({"params": p}, ids, labels=labels,
-                           deterministic=False)[1]
+                           deterministic=False)[::-1]
 
     with force_pallas(kernels), jax.default_matmul_precision("highest"):
-        logits = jax.jit(lambda p: model.apply({"params": p}, ids))(params)
-        loss, grads = jax.jit(jax.value_and_grad(program_loss))(params)
+        (loss, logits), grads = jax.jit(jax.value_and_grad(
+            program_loss, has_aux=True))(params)
     assert obs.default_registry().get("ssd.kernel").value == kernels
     assert rel_gap(logits, jax.jit(lambda w: ref.logits(w, ids, rcfg))(w)) < 1e-4
     want_loss, want = reference_loss_and_grads(w, ids, labels, rcfg)
@@ -373,7 +373,7 @@ def test_model_is_called_as_gptlm_is():
     assert (full.mamba_d_inner, full.head_dim, full.vocab_size) == (4096, 64, 12544)
     model = GraniteHybridLM(cfg)
     ids, labels = batch(rows=1, vocab=cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
     assert set(params) == {"embed", "norm_f", "layer_0", "layer_1", "layer_2"}
     assert set(params["layer_0"]) == {"input_norm", "post_norm", "mamba", "mlp"}
     assert set(params["layer_1"]) == {"input_norm", "post_norm", "qkv",
@@ -385,10 +385,10 @@ def test_model_is_called_as_gptlm_is():
     assert ((dt >= 1e-3 * 0.999) & (dt <= 1e-1 * 1.001)).all()
     assert (np.asarray(mamba["D"]) == 1).all()
     assert not np.asarray(mamba["conv_bias"]).any()
-    logits = model.apply({"params": params}, ids)
+    logits = jax.jit(lambda p: model.apply({"params": p}, ids))(params)
     assert logits.shape == (1, 128, cfg.vocab_size) and logits.dtype == jnp.float32
-    _, loss = model.apply({"params": params}, ids, labels=labels,
-                          deterministic=False)
+    _, loss = jax.jit(lambda p: model.apply(
+        {"params": p}, ids, labels=labels, deterministic=False))(params)
     assert loss.shape == () and np.isfinite(float(loss))
     text = str(jax.make_jaxpr(lambda p: model.apply(
         {"params": p}, ids, labels=labels)[1])(params).pretty_print(
@@ -403,3 +403,51 @@ def test_model_is_called_as_gptlm_is():
     with pytest.raises(ValueError, match="groups"):
         GraniteHybridLM(GraniteHybridConfig.tiny(mamba_n_groups=3)).init(
             jax.random.PRNGKey(0), ids)
+
+
+def test_conv_kernels_leave_logits_and_gradients_where_the_jnp_form_has_them(
+        monkeypatch):
+    """A tiny model whose ``xBC`` columns tile (a state of 128: x 256 wide at
+    column 256, B and C a lane tile each) with the convolution's two kernels
+    forced on (interpret mode; rows of 64 tokens in two row blocks, the
+    forward run again under ``full_block``) and everything else as off the
+    TPU, against the same model on the ``jax.numpy`` form: the logits, the
+    loss and every leaf's gradient, the bias's among them."""
+    import functools
+
+    from apex_tpu import obs
+    from apex_tpu.models import granite_hybrid as program
+    from apex_tpu.ops import gated_delta as gd
+
+    monkeypatch.setattr(gd, "_CONV_ROWS", 32)
+    cfg = program.GraniteHybridConfig.tiny(
+        layer_types=("mamba", "mamba"), mamba_d_state=128,
+        remat_policy="full_block", compute_dtype=jnp.float32)
+    model = program.GraniteHybridLM(cfg)
+    ids, labels = batch(seq=64, vocab=cfg.vocab_size)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
+    # the seeded bias is 0: one that the gradient and the outputs feel
+    for layer in ("layer_0", "layer_1"):
+        params[layer]["mamba"]["conv_bias"] = 0.1 * jax.random.normal(
+            jax.random.PRNGKey(2), params[layer]["mamba"]["conv_bias"].shape)
+
+    def run(p):
+        (loss, logits), grads = jax.jit(jax.value_and_grad(
+            lambda p: model.apply({"params": p}, ids, labels=labels,
+                                  deterministic=False)[::-1], has_aux=True))(p)
+        return logits, loss, grads
+
+    gauge = lambda: obs.default_registry().get("ssd.conv_kernel").value
+    want_logits, want_loss, want = run(params)
+    assert gauge() == 0
+    monkeypatch.setattr(program, "split_conv_xbc", functools.partial(
+        program.split_conv_xbc, use_pallas=True))
+    logits, loss, got = run(params)
+    assert gauge() == 1
+    assert rel_gap(logits, want_logits) < 1e-4
+    assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
+    flat = lambda t: jax.tree_util.tree_leaves_with_path(t)
+    for (path, a), (_, b) in zip(flat(got), flat(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, path
+        assert rel_gap(a, b) < 1e-3, jax.tree_util.keystr(path)
+    assert float(jnp.max(jnp.abs(got["layer_0"]["mamba"]["conv_bias"]))) > 0

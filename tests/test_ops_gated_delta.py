@@ -251,19 +251,20 @@ def test_conv_kernels_match_the_xla_form(dtype, r, small_conv_tiles):
     x, w, cts, dims = conv_inputs(dtype, r)
     f32 = lambda t: t.astype(jnp.float32)
 
-    def grads(fn):
-        loss = lambda x, w: sum(jnp.sum(f32(o) * f32(c))
-                                for o, c in zip(fn(x, w, **dims), cts))
-        return jax.grad(loss, argnums=(0, 1))(x, w)
+    def both(fn):       # the four parts and the two gradients, one program
+        def loss(x, w):
+            outs = fn(x, w, **dims)
+            return sum(jnp.sum(f32(o) * f32(c)) for o, c in zip(outs, cts)), outs
+        grads, outs = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(x, w)
+        return outs, grads
 
     kernels = lambda x, w, **kw: gd.split_conv_qkvz(x, w, use_pallas=True, **kw)
-    got, want = kernels(x, w, **dims), conv_oracle(x, w, **dims)
+    (got, (dx, dw)), (want, (dx_want, dw_want)) = both(kernels), both(conv_oracle)
     one_ulp = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-6
     for name, a, b in zip("qkvz", got, want):
         assert a.shape == b.shape and a.dtype == dtype, name
         assert gap(f32(a), f32(b)) <= one_ulp, name
     np.testing.assert_array_equal(got[3], want[3])          # z is a copy
-    (dx, dw), (dx_want, dw_want) = grads(kernels), grads(conv_oracle)
     assert dx.shape == x.shape and dx.dtype == dtype
     assert dw.shape == w.shape and dw.dtype == jnp.float32
     assert gap(f32(dx), f32(dx_want)) <= 2 * one_ulp
@@ -275,17 +276,20 @@ def test_conv_kernels_start_every_row_of_the_batch_from_zeros(
     """The second row's first outputs must not see the first row's tail:
     each row alone gives the same bits as the two together."""
     x, w, cts, dims = conv_inputs(jnp.bfloat16)
-    both = gd.split_conv_qkvz(x, w, use_pallas=True, **dims)
-    grad = lambda x, ct: jax.grad(lambda x: jnp.sum(gd.split_conv_qkvz(
-        x, w, use_pallas=True, **dims)[2].astype(jnp.float32) * ct))(x)
-    dx_both = grad(x, cts[2].astype(jnp.float32))
+    @jax.jit
+    def run(x, ct):     # the four parts and v's gradient, one program
+        def loss(x):
+            outs = gd.split_conv_qkvz(x, w, use_pallas=True, **dims)
+            return jnp.sum(outs[2].astype(jnp.float32) * ct), outs
+        return jax.grad(loss, has_aux=True)(x)
+
+    ct = cts[2].astype(jnp.float32)
+    dx_both, both = run(x, ct)
     for row in (0, 1):
-        alone = gd.split_conv_qkvz(x[row:row + 1], w, use_pallas=True, **dims)
+        dx_alone, alone = run(x[row:row + 1], ct[row:row + 1])
         for a, b in zip(alone, both):
             np.testing.assert_array_equal(a[0], b[row])
-        np.testing.assert_array_equal(
-            grad(x[row:row + 1], cts[2][row:row + 1].astype(jnp.float32))[0],
-            dx_both[row])
+        np.testing.assert_array_equal(dx_alone[0], dx_both[row])
 
 
 def test_conv_kernels_are_causal_across_row_blocks(small_conv_tiles):

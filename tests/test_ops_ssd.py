@@ -12,6 +12,7 @@ import pytest
 
 from apex_tpu.ops import ssd
 from apex_tpu.ops._common import KERNEL_NAMES, force_pallas
+from apex_tpu.ops.gated_delta import causal_conv1d_silu
 
 
 @pytest.fixture(autouse=True)
@@ -200,14 +201,13 @@ def test_later_tokens_do_not_move_earlier_outputs(kernels):
 
 def test_conv_with_bias_and_silu_is_the_shifted_sum():
     """``y_t = silu(sum_j w[:, j] x_{t-(K-1)+j} + b)``, zeros before the row's
-    start; without the bias it is ``ops/gated_delta.py``'s convolution."""
-    from apex_tpu.ops.gated_delta import causal_conv1d_silu
-
+    start: ``ops/gated_delta.py``'s convolution with its optional bias; no
+    bias is a zero bias."""
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     x = jax.random.normal(ks[0], (2, 40, 24))
     w = jax.random.normal(ks[1], (24, 4))
     b = jax.random.normal(ks[2], (24,))
-    got = ssd.causal_conv1d_bias_silu(x, w, b)
+    got = causal_conv1d_silu(x, w, b)
     want = np.zeros((2, 40, 24), np.float32)
     xs, ws = np.asarray(x), np.asarray(w)
     for t in range(40):
@@ -217,7 +217,158 @@ def test_conv_with_bias_and_silu_is_the_shifted_sum():
     want = jax.nn.silu(want + np.asarray(b))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(
-        ssd.causal_conv1d_bias_silu(x, w, jnp.zeros((24,))),
+        causal_conv1d_silu(x, w, jnp.zeros((24,))),
         causal_conv1d_silu(x, w), rtol=1e-6, atol=1e-7)
-    assert ssd.causal_conv1d_bias_silu(x.astype(jnp.bfloat16), w, b).dtype \
+    assert causal_conv1d_silu(x.astype(jnp.bfloat16), w, b).dtype \
         == jnp.bfloat16
+
+
+# -- the convolution in front of the scan, read out of in_proj's output -------
+
+D_IN, D_BC, HEADS = 128, 128, 2     # [z 128 | x 128 | B 128 | C 128 | dt 2]
+XBC = slice(D_IN, 2 * D_IN + 2 * D_BC)
+
+
+def conv_inputs(dtype=jnp.float32, b=2, s=96, d_bc=D_BC):
+    """``in_proj``'s output ``[z | x | B | C | dt]``, the taps and a
+    NON-ZERO bias over the ``xBC`` channels, a cotangent of the output's
+    shape (the five parts side by side in the projection's own order)."""
+    ks = jax.random.split(jax.random.PRNGKey(44), 4)
+    width = 2 * D_IN + 2 * d_bc + HEADS
+    return (jax.random.normal(ks[0], (b, s, width)).astype(dtype),
+            0.5 * jax.random.normal(ks[1], (D_IN + 2 * d_bc, 4)),
+            0.5 * jax.random.normal(ks[2], (D_IN + 2 * d_bc,)),
+            jax.random.normal(ks[3], (b, s, width)).astype(dtype))
+
+
+def conv_oracle(zxbcdt, w, bias):
+    """``causal_conv1d_silu`` over the ``xBC`` columns cut out, as the model
+    called it before the kernels; z and dt beside."""
+    mixed = causal_conv1d_silu(zxbcdt[..., XBC], w, bias)
+    return jnp.concatenate([zxbcdt[..., :D_IN], mixed,
+                            zxbcdt[..., XBC.stop:]], axis=-1)
+
+
+def conv_kernels(zxbcdt, w, bias, **kw):
+    return jnp.concatenate(ssd.split_conv_xbc(
+        zxbcdt, w, bias, d_inner=D_IN, d_bc=D_BC, use_pallas=True, **kw),
+        axis=-1)
+
+
+def conv_both(fn, x, w, bias, cot):
+    """``(the output, the gradients of sum(out * cot) in the projection's
+    output, the taps and the bias)``."""
+    def loss(x, w, bias):
+        out = fn(x, w, bias)
+        return jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32)), out
+    grads, out = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))(
+        x, w, bias)
+    return (out, *grads)
+
+
+@pytest.fixture(scope="module")
+def conv_runs():
+    """Both paths once a dtype, at row blocks of 32 worked through 16 rows at
+    a time: a sequence of 96 is three blocks, so the taps cross block and
+    piece edges, in a batch of two rows."""
+    from apex_tpu.ops import gated_delta as gd
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(gd, "_CONV_ROWS", 32)
+        patch.setattr(gd, "_CONV_PIECE", 16)
+        return {dtype: (conv_both(conv_kernels, *conv_inputs(dtype)),
+                        conv_both(conv_oracle, *conv_inputs(dtype)))
+                for dtype in (jnp.float32, jnp.bfloat16)}
+
+
+RESULTS = ("forward", "dx", "dw", "dbias")       # conv_both's, in order
+
+
+@pytest.mark.parametrize("what", RESULTS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_xbc_conv_kernels_match_the_jnp_form(conv_runs, dtype, what):
+    """x, B and C at a column offset with a bias; the gradient of the
+    projection's WHOLE output where it lies, dw and dbias summed over both
+    rows of the batch and three row blocks each."""
+    got, want = (side[RESULTS.index(what)] for side in conv_runs[dtype])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    one_ulp = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-6
+    if what in ("dw", "dbias"):     # float32 sums in another order
+        assert got.dtype == jnp.float32
+        assert rel_gap(got, want) <= 1e-5
+    else:
+        assert got.dtype == dtype
+        assert rel_gap(got[..., XBC], want[..., XBC]) <= 2 * one_ulp
+        # the second row of the batch starts from zeros, not the first's tail
+        assert rel_gap(got[1, :4, XBC], want[1, :4, XBC]) <= 2 * one_ulp
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_xbc_conv_kernels_leave_z_and_dt_and_their_gradient_alone(
+        conv_runs, dtype):
+    """The columns outside the range come back as copies, and their
+    cotangents pass through the backward kernel to where they lie, bit for
+    bit."""
+    x, _, _, cot = conv_inputs(dtype)
+    (out, dx, *_), _ = conv_runs[dtype]
+    for cols in (slice(0, D_IN), slice(XBC.stop, None)):
+        np.testing.assert_array_equal(out[..., cols], x[..., cols])
+        np.testing.assert_array_equal(dx[..., cols], cot[..., cols])
+
+
+def test_xbc_conv_kernels_are_causal_across_row_blocks(monkeypatch):
+    """A changed later token leaves every earlier output bit-equal and
+    reaches exactly the K outputs from its own position on — across the
+    edge of a row block (token 63 feeds outputs 63..66) — and moves nothing
+    outside the range; without a bias the kernels are the biased ones at a
+    zero bias."""
+    from apex_tpu.ops import gated_delta as gd
+
+    monkeypatch.setattr(gd, "_CONV_ROWS", 32)
+    monkeypatch.setattr(gd, "_CONV_PIECE", 16)
+    x, w, bias, _ = conv_inputs(b=1)
+    base = conv_kernels(x, w, bias)
+    later = conv_kernels(x.at[:, 63, XBC].add(1.0), w, bias)
+    np.testing.assert_array_equal(base[:, :63], later[:, :63])
+    np.testing.assert_array_equal(base[:, 67:], later[:, 67:])
+    assert bool(jnp.all(base[:, 63:67, XBC] != later[:, 63:67, XBC]))
+    np.testing.assert_array_equal(base[:, 63:67, :D_IN], later[:, 63:67, :D_IN])
+    np.testing.assert_array_equal(conv_kernels(x, w, None),
+                                  conv_kernels(x, w, jnp.zeros_like(bias)))
+
+
+def test_xbc_conv_takes_the_jnp_form_where_the_shapes_do_not_tile():
+    from apex_tpu import obs
+
+    gauge = lambda: obs.default_registry().get("ssd.conv_kernel").value
+    split = lambda x, w, bias, d_bc=D_BC, **kw: ssd.split_conv_xbc(
+        x, w, bias, d_inner=D_IN, d_bc=d_bc, **kw)
+    x, w, bias, _ = conv_inputs(s=40)           # 40 rows: no block of 16
+    with force_pallas(True):
+        got = split(x, w, bias)
+    assert gauge() == 0
+    np.testing.assert_allclose(jnp.concatenate(got, axis=-1),
+                               conv_oracle(x, w, bias), rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="128 lanes"):
+        split(x, w, bias, use_pallas=True)
+    half = conv_inputs(s=48, d_bc=64)[:3]       # B and C of half a lane tile
+    with pytest.raises(ValueError, match="128 lanes"):
+        split(*half, d_bc=64, use_pallas=True)
+    with force_pallas(True):
+        z, xs, bm, cm, dt = split(*half, d_bc=64)
+    assert gauge() == 0
+    assert [t.shape[-1] for t in (z, xs, bm, cm, dt)] == [128, 128, 64, 64, 2]
+    with pytest.raises(ValueError, match="channels"):
+        split(x, w[:-1], bias)
+    with pytest.raises(ValueError, match="channels"):
+        split(x, w, bias[:-1])
+    with pytest.raises(ValueError, match="is not"):
+        ssd.split_conv_xbc(x, w, bias, d_inner=D_IN + 128, d_bc=D_BC)
+    x, w, bias, _ = conv_inputs(s=48)
+    with force_pallas(True):
+        split(x, w, bias)
+    assert gauge() == 1
+    split(x, w, bias)                           # off the TPU: the jnp form
+    assert gauge() == 0

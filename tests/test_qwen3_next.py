@@ -266,15 +266,16 @@ def test_conv_kernels_leave_loss_and_gradients_where_the_xla_form_has_them(
     cfg = program.Qwen3NextConfig.tiny(compute_dtype=dtype)
     model = program.Qwen3NextLM(cfg)
     ids, labels = batch(seq=64, vocab=cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
     loss_of = lambda p: model.apply({"params": p}, ids, labels=labels,
                                     deterministic=False)[1]
-    want_loss, want = jax.value_and_grad(loss_of)(params)
+    # one program a side, not an eager walk of the model's primitives
+    want_loss, want = jax.jit(jax.value_and_grad(loss_of))(params)
     gauge = obs.default_registry().get("gdn.conv_kernel")
     assert gauge.value == 0
     monkeypatch.setattr(program, "split_conv_qkvz", functools.partial(
         gd.split_conv_qkvz, use_pallas=True))
-    loss, got = jax.value_and_grad(loss_of)(params)
+    loss, got = jax.jit(jax.value_and_grad(loss_of))(params)
     assert gauge.value == 1
     # float32: 1e-3 a leaf as the test against the reference above — dw's
     # sum in another order leaves 2e-4 of A_log's gradient, itself 1e-6

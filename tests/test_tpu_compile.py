@@ -217,6 +217,23 @@ def _conv_case():
             [((1, 8192, 12288), BF16), ((8192, 4), F32)])
 
 
+def _ssm_conv_case():
+    """The Mamba-2 layer's short convolution as ``granite-h.train-8k`` calls
+    it: ``in_proj``'s output of one 8192-token row, ``[z 4096 | x 4096 | B
+    128 | C 128 | dt 64]`` in bfloat16, 4 taps and a bias over the 4352
+    ``xBC`` channels at column 4096 (ops/ssd.py::split_conv_xbc over
+    ops/gated_delta.py's kernels), forward and backward."""
+    from apex_tpu.ops.ssd import split_conv_xbc
+
+    def loss(zxbcdt, w, bias):
+        with jax.named_scope("ssm_conv"):
+            parts = split_conv_xbc(zxbcdt, w, bias, d_inner=4096, d_bc=128)
+        return sum(jnp.sum(t.astype(F32) ** 2) for t in parts)
+
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [((1, 8192, 8512), BF16), ((4352, 4), F32), ((4352,), F32)])
+
+
 def _gated_conv_case():
     """The gated short convolution as ``lfm2.train-16k`` calls it: a
     convolution layer's ``in_proj`` output of one 16,384-token row, ``[B | C
@@ -589,6 +606,42 @@ def test_gated_conv_reads_and_writes_the_projection_in_place(chip, as_tpu):
         r"= (?:bf16|f32)\[1,163\d\d,(?:2048|6144)\]\S* "
         r"(?:copy|slice|concatenate|pad|dynamic-update-slice)\(", entry)
     assert compiled.memory_analysis().temp_size_in_bytes <= 16384 * 2048 * 4
+
+
+def test_mamba_conv_reads_x_b_c_out_of_the_projection_in_place(chip, as_tpu):
+    """At the cell's shape the Mamba-2 convolution's program is ONE
+    ``apex_conv1d_fwd`` and ONE ``apex_conv1d_bwd`` — the delta net's
+    kernels under the flat layout, a bias row beside the taps — and nothing
+    beside them moves an array of the ``xBC`` columns' size or more: no
+    slice of the 4352 channels, no padded or float32 copy, no concatenation
+    of ``[dz | dx | dB | dC | ddt]`` (the backward kernel writes it whole).
+    Under ``ssm_conv`` XLA is left z's and dt's cuts and the taps'
+    transposes."""
+    import re
+
+    from jax.experimental.layout import Format, Layout
+
+    from apex_tpu import obs
+    from apex_tpu.ops._common import mosaic_call_names, unnamed_mosaic_calls
+
+    fn, avals = _ssm_conv_case()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in avals]
+    # the projection's output as a model's program holds it, rows of 8512
+    # lanes: left to itself the compiler lays an ENTRY parameter 66.5 lane
+    # tiles wide out the other way round and opens with a relayout copy
+    as_in_model = (Format(Layout(major_to_minor=(0, 1, 2)), chip), chip, chip)
+    text = jax.jit(fn, in_shardings=as_in_model, out_shardings=as_in_model
+                   ).lower(*args).compile().as_text()
+    names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+    assert names == ["apex_conv1d_fwd", "apex_conv1d_bwd"], names
+    assert not unnamed_mosaic_calls(text)
+    assert obs.default_registry().get("ssd.conv_kernel").value == 1
+    entry = text[text.index("ENTRY"):]
+    assert "ssm_conv" in entry
+    assert not re.findall(
+        r"= (?:bf16|f32)\[1,81\d\d,(?:4096|4352|8512)\]\S* "
+        r"(?:copy|slice|concatenate|pad|dynamic-update-slice)\(", entry)
+    assert not re.findall(r"= f32\[1,81\d\d,(?:4352|8512)\]", entry)
 
 
 def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
