@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
-from apex_tpu.remat import MOE_PLAN, MOE_SEL
+from apex_tpu.remat import MLP_GATE_UP, MOE_PLAN, MOE_SEL
 
 __all__ = ["MoEMLP", "top_k_routing", "moe_mlp_ref", "ExpertShardMLP",
            "SwiGLU", "sigmoid_topk_routing", "softmax_topk_routing",
@@ -472,7 +472,11 @@ _tokens_from_rows.defvjp(_tokens_from_rows_fwd, _tokens_from_rows_bwd)
 class SwiGLU(nn.Module):
     """``down(silu(gate(x)) * up(x))``, no biases: a dense gated MLP (the
     shared expert; a model's dense layers).  ``gate`` and ``up`` are one
-    matrix ``gate_up`` (d, 2 d_ff), gate first."""
+    matrix ``gate_up`` (d, 2 d_ff), gate first.  That product's output
+    bears the name ``remat.MLP_GATE_UP``, which both block-recomputing
+    policies keep (``apex_tpu/remat.py``): a block run again in the backward
+    pass reads it and makes the MLP's dearest product once a step, at
+    ``2 d_ff / d`` times the block's input in bytes."""
 
     d_ff: int
     compute_dtype: Any = jnp.float32
@@ -485,7 +489,10 @@ class SwiGLU(nn.Module):
         dense = lambda n, name: Dense(
             n, use_bias=False, dtype=self.compute_dtype,
             kernel_init=self.kernel_init, name=name)
-        gate, up = jnp.split(dense(2 * self.d_ff, "gate_up")(x), 2, axis=-1)
+        # a block that is recomputed reads it kept (remat.py)
+        gate_up = checkpoint_name(dense(2 * self.d_ff, "gate_up")(x),
+                                  MLP_GATE_UP)
+        gate, up = jnp.split(gate_up, 2, axis=-1)
         return dense(x.shape[-1], "down")(nn.silu(gate) * up)
 
 

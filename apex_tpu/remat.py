@@ -17,13 +17,13 @@ has the trade-off table):
   no ``dot_general`` and which ``dots_saveable`` alone would make again
   — stay resident.
 - ``full_block``    — save the wrapped block's input and the declared
-  kernel residuals, nothing else; the rest of the forward re-runs in
+  residuals (below), nothing else; the rest of the forward re-runs in
   backward (max memory savings, ~1.3x step cost for transformer blocks).
 
 **Declared residuals.**  A kernel whose result is dear to make again and
 cheap to hold names it with ``jax.ad_checkpoint.checkpoint_name`` under
 an entry of :data:`KEPT_RESIDUAL_NAMES`, and both block-recomputing
-policies keep exactly those.  Today two kernels and one layer declare.
+policies keep exactly those.  Today two kernels and two layers declare.
 ``ops/attention.py``'s forward rule names its output (``batch*heads x
 seq x head_dim`` in the compute dtype — the VALUES' head size where that
 is not the keys') and its log-sum-exp (``batch*heads
@@ -49,6 +49,17 @@ and ``row_token`` ``rows``, the block starts, the four small arrays of
 block makes no ``top_k``, no running count, no ``argsort`` and no layout
 again — only what a gradient flows through (the router's product, the
 scores, the picked weights, the row movement, the grouped products).
+``parallel/moe.py::SwiGLU`` — a model's dense gated MLP and the shared
+expert inside ``ExpertShardMLP`` — names the output of its first product,
+``gate_up`` (``... x 2 d_ff`` in the compute dtype, before the split into
+gate and up) ``apex_mlp_gate_up``: the dearest product of a dense block
+(``2 x d x 2 d_ff`` operations a token) and the one thing of the MLP its
+backward reads besides the block's input.  With it kept a recomputed block
+makes ``gate_up`` once a step — three passes of that product (the forward
+and the two gradient products), not four; ``down``'s recomputed forward XLA
+drops by itself, nothing in a block's backward reads the block's output.
+The routed experts' grouped ``gate | up`` product over the worst-case row
+buffer is NOT named: another array at another price.
 Outside a ``jax.checkpoint`` a name lowers to nothing.
 
 One rule at every shape, no threshold: per byte kept, the attention
@@ -96,6 +107,36 @@ lfm2 (1x16384, k 4, 67,584 rows) 1.07 MB — 4.0 to 6.4 MB over a cell's
 four or five expert layers, against the 34–117 MB a layer of ``out`` above
 (PERF.md section 6, PR 40, has what the second making cost on the chip).
 
+A gated MLP keeps ``gate_up``'s output beside its block's input, ``2 d_ff
+/ d`` times the input's bytes a ``SwiGLU`` (bf16; the input 33.6 MB at 8192
+tokens x 2048, 67.1 MB at 16,384):
+
+======================  ===========================  ================  ========
+configuration           ``SwiGLU`` s a step          kept a ``SwiGLU``  a step
+======================  ===========================  ================  ========
+granite-h, 1x8192       10 dense x 8192 wide         268 MB            2.68 GB
+lfm2, 1x16384           1 dense x 11,776             772 MB            0.77 GB
+moonlight, 1x8192       1 dense x 11,264 +           369 MB +          0.83 GB
+                        5 shared x 2816              92 MB
+trinity-mini, 1x8192    1 dense x 6144 +             201 MB +          0.34 GB
+                        4 shared x 1024              33.6 MB
+qwen3-next, 1x8192      4 shared x 512 (gated)       16.8 MB           0.07 GB
+======================  ===========================  ================  ========
+
+The yardstick for naming the next array is the step time a GB kept buys
+(PERF.md section 6, PR 45): ``gate_up`` 11.2 ms a GB (30.1 ms for 2.68 GB
+in granite-h at the product's own rate on the chip, 183 TFLOP/s: a product
+``d`` deep costs ``d`` operations a byte of its output; the chip read 30.3
+ms off ``gate_up`` and 29.0 off the step), against 4.5 ms a
+GB for the state-space scan's output and chunk states (1.2 GB for 5.4 ms),
+which stay unnamed; Mamba's ``in_proj`` output prices the same as
+``gate_up`` (1.25 GB, ~14 ms) and does not fit the chip beside it
+(ROADMAP.md S12 has the queue).  granite-h's window holds 14.17 GiB of the
+chip's 15.75 with all ten kept (the compile-only rehearsal; 11.66 before;
+``memory_peak_bytes`` on the chip 15.04 GB of 17.18 for 12.72):
+a user who needs that room back has ``remat_policy`` — there is no switch
+a name.
+
 For GPT and BERT heads x head size = hidden, so ``full_block`` keeps two
 arrays of the input's size a block where it kept one: about 1/9th of
 what ``none`` keeps (34 x rows x seq x hidden bytes a block by the usual
@@ -119,8 +160,9 @@ GDN_STATES = "apex_gdn_states"
 GDN_TRI = "apex_gdn_tri"
 MOE_SEL = "apex_moe_sel"
 MOE_PLAN = "apex_moe_plan"
+MLP_GATE_UP = "apex_mlp_gate_up"
 KEPT_RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_TRI,
-                       MOE_SEL, MOE_PLAN)
+                       MOE_SEL, MOE_PLAN, MLP_GATE_UP)
 
 
 def checkpoint_policy(policy: Optional[str]):

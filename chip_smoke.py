@@ -23,7 +23,9 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   recurrence, forward and the six gradients, both paths timed
   (``ssd_at_cell``), and the convolution in front of it, reading x, B and C
   out of ``in_proj``'s output, against the ``jax.numpy`` form
-  (``ssm_conv_at_cell``); and ONE making
+  (``ssm_conv_at_cell``), and the gradient of one whole block of that cell
+  under ``full_block``, the products of ``gate_up``'s size that its program
+  runs counted and timed in a trace (``dense_ffn_at_cell``); and ONE making
   of the expert layer's routing plan at the five sparse cells' shapes, with
   each lookup inside it as the gather it was and as the sum over the held
   experts it can be, timed on the device and the tables held equal to the
@@ -428,6 +430,82 @@ def ssm_conv_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
                                              (x, w, bias))
         timed[f"grad_{side}_us"] = _us_a_call(grad, (x, w, bias, cot))
     return timed
+
+
+# ---------------------------------------------------------------------------
+# one dense block's gradient at the cell's shapes
+# ---------------------------------------------------------------------------
+
+def dense_ffn_at_cell(s: int, root_key, calls: Dict) -> Dict:
+    """The gradient of ONE mamba block of ``granite-h.train-8k`` under
+    ``full_block`` — a row of 8 contexts, hidden ``2 s``, a gated MLP ``8 s``
+    wide, ``s / 16`` heads of 64 channels with a state of 128, bfloat16
+    matrices — with respect to its parameters and its input, its output
+    handed on as to a next block (so the forward pass is live, and what the
+    backward pass runs again is a SECOND forward), compiled, three calls of it
+    traced and the trace joined to the program's own text
+    (``apex_tpu.pyprof``).  Reports how many products of ``gate_up``'s size
+    (tokens x hidden x 2 d_ff) the program RUNS — three: the forward and the
+    two gradient products, the recomputed block reading the forward's result
+    kept (``remat.MLP_GATE_UP``); four before PR 45 — and holds the run to
+    that; then the device ms a call of those products, of everything under
+    the scope ``dense_ffn`` (a tenth of the cell's
+    ``model.dense_ffn_ms_per_step``) and of the whole block, and the host's
+    ms a call."""
+    import tempfile
+
+    from apex_tpu.models.granite_hybrid import (
+        MAMBA, GraniteHybridConfig, GraniteHybridLayer)
+    from apex_tpu.pyprof.parse import find_xplane, join, parse_xplane
+    from apex_tpu.pyprof.prof import parse_hlo
+    from apex_tpu.remat import remat_module
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    tokens, d, d_ff, iters = 8 * s, 2 * s, 8 * s, 3
+    cfg = GraniteHybridConfig(
+        hidden_size=d, layer_types=(MAMBA,), mamba_n_heads=s // 16,
+        num_heads=max(1, s // 32), num_kv_heads=max(1, s // 128),
+        intermediate_size=d_ff, remat_policy="full_block")
+    block = remat_module(GraniteHybridLayer, cfg.remat_policy,
+                         static_argnums=(2,))(cfg, 0)
+    kx, kp, kc = jax.random.split(jax.random.fold_in(root_key, 190), 3)
+    x = jax.random.normal(kx, (1, tokens, d), f32).astype(bf16)
+    cot = jax.random.normal(kc, (1, tokens, d), f32).astype(bf16).astype(f32)
+    params = jax.tree_util.tree_map_with_path(     # O2: matrices in bfloat16
+        lambda path, leaf: leaf.astype(bf16)
+        if path[-1].key == "kernel" else leaf,
+        jax.jit(lambda key: block.init(key, x, True))(kp)["params"])
+
+    def loss(params, x):        # the output leaves too, as to a next block:
+        out = block.apply({"params": params}, x, True)  # the forward is live
+        return jnp.sum(out.astype(f32) * cot), out
+
+    compiled = jax.jit(jax.grad(loss, (0, 1), has_aux=True)).lower(
+        params, x).compile()
+    _require_mosaic(compiled, 2, calls, "dense_ffn_block")
+    text = compiled.as_text()
+    a_pass = 2.0 * tokens * d * 2 * d_ff
+    products = [i.name for i in parse_hlo(text)
+                if i.opcode in ("convolution", "dot") and i.flops == a_pass]
+    _require(len(products) == 3,
+             f"dense_ffn_at_cell: {len(products)} products of gate_up's size "
+             f"in the block's gradient, not 3: {products}")
+    host_ms = _us_a_call(compiled, (params, x), n=iters) / 1e3
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(iters):
+                jax.block_until_ready(compiled(params, x))
+        profile = join(text, parse_xplane(find_xplane(trace_dir)))
+    ms = lambda rows: round(sum(r.time_ns for r in rows) / iters / 1e6, 3)
+    return {
+        "shape": [1, tokens, d, d_ff], "gate_up_products": len(products),
+        # an event is a top-level instruction: a product and what XLA fused
+        # to it (the cast, silu(gate) * up) are one
+        "gate_up_ms": ms([r for r in profile.rows
+                          if r.flops >= a_pass * r.count]),
+        "dense_ffn_ms": ms([r for r in profile.rows if "dense_ffn" in r.key]),
+        "device_ms": ms(profile.rows), "block_ms": round(host_ms, 3),
+        "kernels": mosaic_call_names(text)}
 
 
 # ---------------------------------------------------------------------------
@@ -1167,6 +1245,10 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
 
     # the convolution in front of that scan, read out of in_proj's output
     facts["ssm_conv_at_cell"] = ssm_conv_at_cell(s, root_key, parity, calls)
+
+    # one block of that cell under full_block: its gradient's products of
+    # gate_up's size counted in the program, timed and traced
+    facts["dense_ffn_at_cell"] = dense_ffn_at_cell(s, root_key, calls)
 
     # the expert layer's row movement at smallthinker.train-16k's shape —
     # twice the LayerNorm rows x 2560 bfloat16 (a record 20 sublanes: two and

@@ -65,7 +65,7 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
         "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d",
-        "gated_conv", "ssd", "ssm_conv"}
+        "gated_conv", "ssd", "ssm_conv", "dense_ffn_block"}
     # the backward's two routes at the three 8k cells' calls, timed and held
     # to each other and to the reference
     routes = kernels["flash_backward"]
@@ -140,6 +140,13 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
             "grad_jnp_us", "kernels"} <= set(conv)
     for name in ("fwd", "dx", "dw", "dbias"):
         assert f"ssm_conv.{name}" in kernels["parity"]
+    # one block of that cell under full_block: its gradient runs three
+    # products of gate_up's size, timed in a trace
+    block = kernels["dense_ffn_at_cell"]
+    assert block["shape"] == [1, 8 * TINY.ctx, 2 * TINY.ctx, 8 * TINY.ctx]
+    assert block["gate_up_products"] == 3
+    assert 0 < block["gate_up_ms"] < block["dense_ffn_ms"] < block["device_ms"]
+    assert block["block_ms"] > 0
     # the routing plan at the five sparse cells' shapes: one making, its dear
     # parts and each lookup both ways timed, the tables equal to the bit
     plans = kernels["moe_plan_at_cell"]

@@ -210,8 +210,18 @@ def test_expert_shard_refuses_what_it_does_not_know():
 # not change them by a byte.  Taken anew at PR 40, which did mean to — the
 # plan's names stand in every jaxpr, the picked weights are a masked sum and
 # a row finds its slot without a gather (what held across that edit are the
-# values: the tests of the plan's tables and of the weights, below).
+# values: the tests of the plan's tables and of the weights, below) — and at
+# PR 45, which put ONE equation into every SwiGLU: ``name`` on gate_up's
+# output (``remat.MLP_GATE_UP``), in a dense leading layer and in every
+# shared expert.  What held across that edit: with that one name taken out
+# again the texts are PR 40's, and loss and gradients are the same to the
+# bit either way (the test after this one).
 _SPARSE_JAXPR_SHA256 = {
+    "afmoe": "ee1bd0432f87417a",
+    "qwen3_next": "7ba222639c19d33e",
+    "deepseek_v3": "6938a9ba43f8fab9",
+}
+_SPARSE_JAXPR_SHA256_AT_PR_40 = {
     "afmoe": "5e3b04254f5420d8",
     "qwen3_next": "4ffd06c5fe154864",
     "deepseek_v3": "a512d0534c7f5cb2",
@@ -224,17 +234,22 @@ def _sha(text: str) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("name", sorted(_SPARSE_JAXPR_SHA256))
-def test_defaults_leave_the_sparse_models_the_text_they_had(name):
+def _sparse_model(name):
     import apex_tpu.models as models
-    from apex_tpu.ops._common import force_pallas
 
     cls, cfg = {
         "afmoe": (models.AfmoeLM, models.AfmoeConfig),
         "qwen3_next": (models.Qwen3NextLM, models.Qwen3NextConfig),
         "deepseek_v3": (models.DeepseekV3LM, models.DeepseekV3Config),
     }[name]
-    model = cls(cfg.tiny(compute_dtype=jnp.float32))
+    return cls(cfg.tiny(compute_dtype=jnp.float32))
+
+
+@pytest.mark.parametrize("name", sorted(_SPARSE_JAXPR_SHA256))
+def test_defaults_leave_the_sparse_models_the_text_they_had(name):
+    from apex_tpu.ops._common import force_pallas
+
+    model = _sparse_model(name)
     ids = jnp.zeros((1, 64), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
     with force_pallas(False):
@@ -243,6 +258,38 @@ def test_defaults_leave_the_sparse_models_the_text_they_had(name):
     assert "moe_router" in str(jax.make_jaxpr(lambda p: model.apply(
         {"params": p}, ids))(params).pretty_print(name_stack=True))
     assert _sha(text) == _SPARSE_JAXPR_SHA256[name]
+
+
+@pytest.mark.parametrize("name", sorted(_SPARSE_JAXPR_SHA256))
+def test_gate_ups_name_is_all_that_changed_the_sparse_models(monkeypatch,
+                                                             name):
+    """With ``gate_up``'s name taken out of ``SwiGLU`` the three models'
+    gradient jaxprs are the texts pinned at PR 40, and with it in the loss
+    and every gradient leaf are the same to the bit."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from apex_tpu import remat
+    from apex_tpu.ops._common import force_pallas
+    from apex_tpu.parallel import moe
+
+    model = _sparse_model(name)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 64), 0, 250)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    both = lambda: jax.jit(jax.value_and_grad(      # traced anew a call
+        lambda p: model.apply({"params": p}, ids, labels=ids)[1]))
+    with force_pallas(False):
+        named = both()(params)
+        monkeypatch.setattr(
+            moe, "checkpoint_name", lambda x, name: x
+            if name == remat.MLP_GATE_UP else checkpoint_name(x, name))
+        bare = both()(params)
+        zeros = jnp.zeros_like(ids)     # the ids of the pinned texts
+        text = str(jax.make_jaxpr(jax.grad(lambda p: model.apply(
+            {"params": p}, zeros, labels=zeros)[1]))(params))
+    assert _sha(text) == _SPARSE_JAXPR_SHA256_AT_PR_40[name]
+    for a, b in zip(jax.tree_util.tree_leaves(named),
+                    jax.tree_util.tree_leaves(bare), strict=True):
+        assert a.dtype == b.dtype and jnp.array_equal(a, b)
 
 
 @pytest.mark.parametrize("d", [2048, 2560])

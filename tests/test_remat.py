@@ -1,8 +1,9 @@
 """What the block-recomputing policies keep (``apex_tpu/remat.py``): the
 residuals a kernel declares — the flash forward's output and log-sum-exp
 — beside the block's input, so that the backward pass does not run the
-attention forward a second time; and an expert layer's routing plan, so
-that it is made once a step."""
+attention forward a second time; an expert layer's routing plan, so that
+it is made once a step; and a gated MLP's first product, ``gate_up``'s
+output, so that the dearest product of a dense block is made once a step."""
 import collections
 import dataclasses
 import re
@@ -25,8 +26,18 @@ ROWS, SEQ = 2, 128
 def tiny_lm(family, policy, layers):
     """(loss of the parameters, parameters, (batch*heads, key/value
     batch*heads, head size, hidden)) of a tiny model of ``layers`` blocks,
-    float32, the kernels taken (interpret mode)."""
-    if family == "gpt":
+    float32, the kernels taken (interpret mode) where the caller forces
+    them."""
+    if family == "granite":
+        from apex_tpu.models.granite_hybrid import (
+            ATTENTION, MAMBA, GraniteHybridConfig, GraniteHybridLM)
+
+        cfg = GraniteHybridConfig.tiny(
+            compute_dtype=jnp.float32, remat_policy=policy,
+            layer_types=(MAMBA, ATTENTION, MAMBA)[:layers])
+        model = GraniteHybridLM(cfg)
+        heads, kv_heads, head = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    elif family == "gpt":
         cfg = dataclasses.replace(
             GPTConfig.tiny(compute_dtype=jnp.float32, remat_policy=policy),
             num_layers=layers)
@@ -73,12 +84,27 @@ def flash_forward_calls(fn, *args, kernel="apex_flash_fwd"):
     return count(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
+def products(fn, *args, shapes=None):
+    """``dot_general`` equations in the jaxpr of ``fn`` — those whose result
+    has one of ``shapes``, where given — every nested jaxpr walked."""
+
+    def count(jaxpr):
+        return sum(
+            shapes is None or eqn.outvars[0].aval.shape in shapes
+            if eqn.primitive.name == "dot_general" else
+            sum(count(inner) for inner in _sub_jaxprs(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    return count(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
 @pytest.mark.parametrize("family,layers", [("gpt", 2), ("afmoe", 3)])
 def test_full_block_keeps_input_out_and_lse_a_layer(family, layers):
     """One block more keeps three arrays more: its input, the flash
     kernel's output and the (bh, sq) float32 lse — no second array of
     q's shape, no k3 / v3, no 128-lane lse buffer — and, where it holds
-    an expert layer, the nine integer tables of its routing plan."""
+    an expert layer, the nine integer tables of its routing plan and its
+    shared expert's ``gate_up`` output."""
     with force_pallas(True):
         more = kept(*tiny_lm(family, "full_block", layers)[:2])
         loss, params, (bh, bh_kv, d, hidden) = tiny_lm(
@@ -99,6 +125,7 @@ def test_full_block_keeps_input_out_and_lse_a_layer(family, layers):
             ((tiles,), "int32"): 2,             # tile_group, tile_valid
             ((held,), "int32"): 1, ((1,), "int32"): 1,  # row_start, tiles_used
             ((blocks + 1, held), "int32"): 1,   # _block_starts
+            ((tokens, 2 * 128), "float32"): 1,  # the shared expert's gate_up
         })
     assert more - fewer == plan + collections.Counter({
         ((ROWS, SEQ, hidden), "float32"): 1,
@@ -160,24 +187,18 @@ def test_the_triangular_inverse_is_not_made_again(monkeypatch):
     of two products in each of the three linear-attention layers."""
     from apex_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
 
-    def products():
+    def made():
         model = Qwen3NextLM(Qwen3NextConfig.tiny(
             compute_dtype=jnp.float32, remat_policy="full_block"))
         ids = jax.random.randint(jax.random.PRNGKey(1), (1, SEQ), 0, 250)
         params = model.init(jax.random.PRNGKey(0), ids, labels=ids)
+        return products(jax.grad(lambda p: model.apply(p, ids, ids)[1]),
+                        params)
 
-        def count(jaxpr):
-            return sum(1 if eqn.primitive.name == "dot_general" else
-                       sum(count(inner) for inner in _sub_jaxprs(eqn.params))
-                       for eqn in jaxpr.eqns)
-
-        return count(jax.make_jaxpr(
-            jax.grad(lambda p: model.apply(p, ids, ids)[1]))(params).jaxpr)
-
-    kept = products()
+    kept = made()
     monkeypatch.setattr(remat, "KEPT_RESIDUAL_NAMES", tuple(
         n for n in remat.KEPT_RESIDUAL_NAMES if n != remat.GDN_TRI))
-    assert products() - kept == 3 * 5 * 2
+    assert made() - kept == 3 * 5 * 2
 
 
 def test_names_lower_to_nothing_outside_a_checkpoint(monkeypatch):
@@ -211,7 +232,93 @@ def test_gauge_counts_the_names_a_policy_keeps(policy):
     assert remat.checkpoint_policy("none") is None
     assert gauge.value == 0
     assert remat.checkpoint_policy(policy) is not None
-    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 7
+    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 8
+
+
+# -- a gated MLP's first product: made once a step ---------------------------
+
+def _gate_up_shapes(family):
+    """The shape of ``gate_up``'s output in every ``SwiGLU`` of the tiny
+    three-block model of ``family``: Granite's three dense MLPs; Trinity's
+    dense leading layer and its two shared experts (which see tokens x d)."""
+    if family == "granite":
+        return [(ROWS, SEQ, 2 * 256)] * 3
+    return [(ROWS, SEQ, 2 * 256)] + [(ROWS * SEQ, 2 * 128)] * 2
+
+
+def _without_gate_up(monkeypatch):
+    monkeypatch.setattr(remat, "KEPT_RESIDUAL_NAMES", tuple(
+        n for n in remat.KEPT_RESIDUAL_NAMES if n != remat.MLP_GATE_UP))
+
+
+@pytest.mark.parametrize("policy", ["none", "dots_saveable", "full_block"])
+@pytest.mark.parametrize("family", ["granite", "afmoe"])
+def test_gate_up_is_made_once_a_swiglu(family, policy):
+    """The gradient's jaxpr holds ONE product of ``gate_up``'s output shape a
+    ``SwiGLU`` under every policy (the two gradient products have the
+    input's and the weight's shapes): a recomputed block finds the output
+    kept (``apex_mlp_gate_up``), in a dense MLP and in a shared expert."""
+    shapes = _gate_up_shapes(family)
+    with force_pallas(False):
+        loss, params, _ = tiny_lm(family, policy, 3)
+        assert products(jax.grad(loss), params,
+                        shapes=set(shapes)) == len(shapes)
+
+
+@pytest.mark.parametrize("family", ["granite", "afmoe"])
+def test_full_block_without_the_name_makes_gate_up_twice(monkeypatch, family):
+    """The witness that the test above can fail: ``full_block`` without the
+    name runs every ``gate_up`` product again in the backward pass;
+    ``dots_saveable`` keeps a dot's output whatever it is called."""
+    _without_gate_up(monkeypatch)
+    shapes = _gate_up_shapes(family)
+    with force_pallas(False):
+        loss, params, _ = tiny_lm(family, "full_block", 3)
+        assert products(jax.grad(loss), params,
+                        shapes=set(shapes)) == 2 * len(shapes)
+        loss, params, _ = tiny_lm(family, "dots_saveable", 3)
+        assert products(jax.grad(loss), params,
+                        shapes=set(shapes)) == len(shapes)
+
+
+@pytest.mark.parametrize("family", ["granite", "afmoe"])
+def test_full_block_keeps_one_gate_up_array_a_swiglu(monkeypatch, family):
+    """What the name adds to ``full_block``'s residuals is exactly one
+    ``(..., 2 d_ff)`` array a ``SwiGLU`` — not the gate's and the up's halves
+    apart, not their product, nothing of ``down``'s."""
+    with force_pallas(False):
+        named = kept(*tiny_lm(family, "full_block", 3)[:2])
+        _without_gate_up(monkeypatch)
+        bare = kept(*tiny_lm(family, "full_block", 3)[:2])
+    assert not bare - named
+    assert named - bare == collections.Counter(
+        (shape, "float32") for shape in _gate_up_shapes(family))
+
+
+def test_gate_ups_name_lowers_to_nothing_outside_a_checkpoint(monkeypatch):
+    """A ``SwiGLU`` with no ``jax.checkpoint`` around it lowers to a text that
+    does not bear the name, and its value and gradients are the unnamed
+    layer's to the bit: ``remat_policy`` ``none`` and every caller outside
+    the model zoo compile what they did."""
+    from apex_tpu.parallel import moe
+
+    layer = moe.SwiGLU(256)
+    x = jax.random.normal(jax.random.PRNGKey(45), (ROWS, SEQ, 128))
+    params = layer.init(jax.random.PRNGKey(0), x)
+    both = jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(layer.apply(p, x) ** 2), (0, 1)))
+    text = both.lower(params, x).as_text()
+    assert remat.MLP_GATE_UP not in text
+    named = both(params, x)
+    monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
+    unnamed = jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(layer.apply(p, x) ** 2), (0, 1)))
+    assert unnamed.lower(params, x).as_text() == text
+    for a, b in zip(jax.tree_util.tree_leaves(named),
+                    jax.tree_util.tree_leaves(unnamed(params, x)),
+                    strict=True):
+        assert jnp.array_equal(a, b)
+    assert all(jnp.any(g != 0) for g in jax.tree_util.tree_leaves(named[1]))
 
 
 # -- an expert layer's routing plan: made once a step ------------------------

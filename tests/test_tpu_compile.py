@@ -644,6 +644,51 @@ def test_mamba_conv_reads_x_b_c_out_of_the_projection_in_place(chip, as_tpu):
     assert not re.findall(r"= f32\[1,81\d\d,(?:4352|8512)\]", entry)
 
 
+@pytest.mark.parametrize("kept,products", [(True, 3), (False, 4)],
+                         ids=["name_kept", "name_dropped"])
+def test_a_granite_blocks_gradient_makes_gate_up_once(chip, as_tpu,
+                                                      monkeypatch, kept,
+                                                      products):
+    """One mamba block of ``granite-h.train-8k`` under ``full_block``, its
+    output handed on as to a next block: the COMPILED gradient runs three
+    products of ``gate_up``'s size (8192 tokens x 2048 x 16,384: the forward
+    and the two gradient products) — the recomputed block reads the
+    forward's result kept under ``remat.MLP_GATE_UP`` — and four where the
+    policy does not keep that name (the program before PR 45).  What
+    ``chip_smoke.py::dense_ffn_at_cell`` counts on the chip."""
+    from apex_tpu import remat
+    from apex_tpu.models.granite_hybrid import (
+        MAMBA, GraniteHybridConfig, GraniteHybridLayer)
+    from apex_tpu.pyprof.prof import parse_hlo
+
+    if not kept:
+        monkeypatch.setattr(remat, "KEPT_RESIDUAL_NAMES", tuple(
+            n for n in remat.KEPT_RESIDUAL_NAMES if n != remat.MLP_GATE_UP))
+    cfg = GraniteHybridConfig(layer_types=(MAMBA,), remat_policy="full_block")
+    tokens, d, d_ff = 8192, cfg.hidden_size, cfg.intermediate_size
+    block = remat.remat_module(GraniteHybridLayer, cfg.remat_policy,
+                               static_argnums=(2,))(cfg, 0)
+    x = jax.ShapeDtypeStruct((1, tokens, d), BF16, sharding=chip)
+    params = jax.tree_util.tree_map_with_path(     # O2: matrices in bfloat16
+        lambda path, leaf: jax.ShapeDtypeStruct(
+            leaf.shape, BF16 if path[-1].key == "kernel" else leaf.dtype,
+            sharding=chip),
+        jax.eval_shape(lambda key: block.init(
+            key, jnp.zeros(x.shape, BF16), True), jax.random.PRNGKey(0)
+        )["params"])
+
+    def loss(params, x):
+        out = block.apply({"params": params}, x, True)
+        return jnp.sum(out.astype(F32)), out
+
+    text = jax.jit(jax.grad(loss, (0, 1), has_aux=True)).lower(
+        params, x).compile().as_text()
+    a_pass = 2.0 * tokens * d * 2 * d_ff
+    found = [i.name for i in parse_hlo(text)
+             if i.opcode in ("convolution", "dot") and i.flops == a_pass]
+    assert len(found) == products, found
+
+
 def test_flash_head_size_256_grouped_heads_compiles(chip, as_tpu):
     """Qwen3-Next's attention call: 16 query heads to 2 key/value heads of
     size 256 at 8192 positions — the grouped route at twice the head size
