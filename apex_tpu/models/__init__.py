@@ -4,7 +4,7 @@ These are the models the reference's examples and kernels exist to serve
 (SURVEY.md §6 benchmark configs): ResNet-50 (imagenet amp O0-O3 + DDP +
 SyncBN), BERT-large (FusedLAMB + fused attention + xentropy), DCGAN
 (multi-model multi-loss-scaler amp), a simple MLP (the minimum
-end-to-end slice), and the seven decoders: GPT-2 (``gpt.py``), the Arcee
+end-to-end slice), and the eight decoders: GPT-2 (``gpt.py``), the Arcee
 Trinity block with sigmoid-routed experts (``afmoe.py``, training path), the
 Qwen3-Next block — gated-delta-rule linear attention beside gated full
 attention, softmax-routed experts (``qwen3_next.py``, training path) — and
@@ -21,7 +21,12 @@ embedding (``lfm2.py``, training path) — and the Granite 4.0-H block — a
 Mamba-2 state-space layer (the SSD scan by chunks) in nine layers of ten,
 position-free grouped-query attention at a published scale in the tenth, a
 dense SwiGLU in every layer, four multipliers, no experts
-(``granite_hybrid.py``, training path).
+(``granite_hybrid.py``, training path) — and the Kimi Linear block — Kimi
+Delta Attention (the delta rule with a decay a key channel behind short
+convolutions, a low-rank decay gate and a low-rank output gate) in the layers
+a published list names, position-free latent attention in the others, a
+leading dense layer, bias-steered sigmoid experts beside a shared one
+(``kimi_linear.py``, training path).
 """
 from apex_tpu.models.resnet import ResNet, resnet50, resnet101, resnet152  # noqa: F401
 from apex_tpu.models.bert import (  # noqa: F401
@@ -54,5 +59,11 @@ from apex_tpu.models.granite_hybrid import (  # noqa: F401
     GraniteHybridLayer,
     GraniteHybridLM,
     Mamba2Mixer,
+)
+from apex_tpu.models.kimi_linear import (  # noqa: F401
+    KimiDeltaAttention,
+    KimiLinearConfig,
+    KimiLinearLayer,
+    KimiLinearLM,
 )
 from apex_tpu.mlp import MLP  # noqa: F401

@@ -3,7 +3,10 @@ bias-free projection, the head-split around the flash kernels, the
 language-model shell and the masked loss.  ``models/afmoe.py``,
 ``deepseek_v3.py``, ``qwen3_next.py``, ``smallthinker.py`` and ``lfm2.py``
 each keep their configuration, their mixers and their block; no family's
-module imports another's.
+module imports another's — but ``kimi_linear.py``, whose full-attention
+layers ARE ``deepseek_v3.py``'s latent mixer with the rotation off (one
+switch there, ``rope_theta`` None) and whose ``A_log`` is drawn as
+``qwen3_next.py`` draws its own.
 
 A new family writes a config dataclass (with ``vocab_size``,
 ``hidden_size``, ``num_layers``, ``rms_norm_eps``, ``initializer_range``,

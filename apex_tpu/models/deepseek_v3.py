@@ -121,9 +121,12 @@ class DeepseekV3Config:
 
 
 class LatentAttention(nn.Module):
-    """The mixer (the module docstring has its equations)."""
+    """The mixer (the module docstring has its equations).  ``cfg`` is this
+    family's, or another's with the same fields (``models/kimi_linear.py``);
+    where its ``rope_theta`` is None nothing is rotated (position-free:
+    ``q_pe`` and ``k_pe`` enter the scores as they are projected)."""
 
-    cfg: DeepseekV3Config
+    cfg: Any
 
     @nn.compact
     def __call__(self, x):
@@ -142,8 +145,11 @@ class LatentAttention(nn.Module):
                 b, s, h, dn + dv)
             q_nope, q_pe = jnp.split(split_heads(q, h, dn + dr), [dn], axis=-1)
             k_nope, v = jnp.split(split_heads(kv, h, dn + dv), [dn], axis=-1)
-            q_pe = rotary(q_pe, cfg.rope_theta)
-            k_pe = rotary(k_pe[:, None], cfg.rope_theta)     # (b, 1, s, dr)
+            if cfg.rope_theta is None:
+                k_pe = k_pe[:, None]                         # (b, 1, s, dr)
+            else:
+                q_pe = rotary(q_pe, cfg.rope_theta)
+                k_pe = rotary(k_pe[:, None], cfg.rope_theta)
             q = jnp.concatenate([q_nope, q_pe], axis=-1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_pe, (b, h, s, dr))], axis=-1)

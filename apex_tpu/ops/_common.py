@@ -74,6 +74,8 @@ KERNEL_NAMES = (
     "apex_moe_combine",
     "apex_gdn_fwd",
     "apex_gdn_bwd",
+    "apex_kda_fwd",
+    "apex_kda_bwd",
     "apex_conv1d_fwd",
     "apex_conv1d_bwd",
     "apex_gated_conv_fwd",

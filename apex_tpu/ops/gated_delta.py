@@ -110,6 +110,18 @@ running sum that is never positive, masked BEFORE the exponential.  Factored
 as ``exp(G_i) * exp(-G_j)`` the second factor overflows float32 inside one
 chunk (``tests/test_ops_gated_delta.py`` runs the strongest decay, through
 the scan path and through both kernels).
+
+**What a decay a key CHANNEL changes** (Kimi Delta Attention: ``g`` (B, S, H,
+d_k), ``ops/kda.py``).  Here the decay is a scalar a pair of tokens, so ``A``
+is ``(K K^T) . M``: one product, then a (C, C) mask of decays.  With a vector
+decay it sits inside the sum over the channels — ``A_ij = beta_i sum_d k_id
+k_jd exp(G_id - G_jd)`` — and ``A`` is no product of ``K`` with itself; made
+one, it needs exactly the factored form above, ``k_i exp(G_i - G_ref)``
+against ``k_j exp(G_ref - G_j)``, safe only while ``G_ref`` lies between
+``j`` and ``i``.  That rule therefore has kernels of its own (sub-blocks of a
+chunk, a reference row a sub-block, the diagonal sub-blocks channel by channel
+on the VPU) and these stay as they are; it imports this file's triangular
+inverse, product helpers, small layouts and convolution.
 """
 from __future__ import annotations
 

@@ -23,7 +23,7 @@ has the trade-off table):
 **Declared residuals.**  A kernel whose result is dear to make again and
 cheap to hold names it with ``jax.ad_checkpoint.checkpoint_name`` under
 an entry of :data:`KEPT_RESIDUAL_NAMES`, and both block-recomputing
-policies keep exactly those.  Today two kernels and two layers declare.
+policies keep exactly those.  Today three kernels and two layers declare.
 ``ops/attention.py``'s forward rule names its output (``batch*heads x
 seq x head_dim`` in the compute dtype — the VALUES' head size where that
 is not the keys') and its log-sum-exp (``batch*heads
@@ -39,7 +39,12 @@ kernels' in the compute dtype) — what its backward reads besides q, k, v,
 g, beta, which are cheap to make again from the block's input.  The rule is
 the one SEQUENTIAL thing in a block (128 dependent steps at 8192 tokens,
 ten dependent products a chunk in the inverse): without the names it would
-be walked forward twice.  ``parallel/moe.py::ExpertShardMLP``, no kernel
+be walked forward twice.  ``ops/kda.py`` — the same rule with a decay a key
+channel — declares its own four the same way: the output, the chunk states,
+the triangular inverses and each chunk's decayed scores ``P`` (``chunks x
+heads x chunk x chunk`` in the compute dtype: its backward reads ``P`` where
+the scalar rule's makes ``Q K^T`` and the decays again, because there the
+making is ``chunk / 4`` passes of exponentials over a (chunk, d) tile).  ``parallel/moe.py::ExpertShardMLP``, no kernel
 but a layer, names its ROUTING PLAN: the selection ``sel`` (``tokens x k``
 int32, under ``apex_moe_sel`` as the router picks it, so the picked
 weights are read through the kept selection too) and every table of
@@ -98,6 +103,12 @@ recomputed block's forward rule is dead code — three ``apex_gdn_fwd`` calls
 a step for six — and the compile-only rehearsal of that cell's window reads
 4.98 GiB of temporaries (5.33 before the rule's kernels made what is local
 to a chunk themselves, 6.17 with no name kept; PERF.md section 6, PR 30-31).
+
+A KDA block (kimi-linear, 1x8192, 32 heads of 128 x 128) keeps its input
+37.7 MB (hidden 2304), the rule's output 67.1 MB, the chunk states 268 MB,
+the inverses 33.6 MB and the decayed scores 33.6 MB: 440 MB a layer, and four
+``apex_kda_fwd`` calls a step for eight (the compile-only rehearsal of that
+cell's window reads 6.72 GiB of temporaries, PERF.md section 6, PR 46).
 
 An expert block keeps its plan beside all that, ``8 x (tokens x k + rows)``
 bytes a layer and the small tables: trinity-mini (1x8192, k 8, 69,632
@@ -158,10 +169,15 @@ FLASH_LSE = "apex_flash_lse"
 GDN_OUT = "apex_gdn_out"
 GDN_STATES = "apex_gdn_states"
 GDN_TRI = "apex_gdn_tri"
+KDA_OUT = "apex_kda_out"
+KDA_STATES = "apex_kda_states"
+KDA_TRI = "apex_kda_tri"
+KDA_SCORES = "apex_kda_scores"
 MOE_SEL = "apex_moe_sel"
 MOE_PLAN = "apex_moe_plan"
 MLP_GATE_UP = "apex_mlp_gate_up"
 KEPT_RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_TRI,
+                       KDA_OUT, KDA_STATES, KDA_TRI, KDA_SCORES,
                        MOE_SEL, MOE_PLAN, MLP_GATE_UP)
 
 

@@ -21,9 +21,12 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   gradient timed on both paths (``gated_conv_at_cell``); and the state-space
   scan at the Granite cell's call, its two kernels against the token
   recurrence, forward and the six gradients, both paths timed
-  (``ssd_at_cell``), and the convolution in front of it, reading x, B and C
-  out of ``in_proj``'s output, against the ``jax.numpy`` form
-  (``ssm_conv_at_cell``), and the gradient of one whole block of that cell
+  (``ssd_at_cell``), the delta rule with a decay a key channel at the Kimi
+  Linear cell's call, its two kernels against the token recurrence and the
+  scan path, forward and the five gradients, both paths timed
+  (``kda_at_cell``), and the convolution in front of the state-space scan,
+  reading x, B and C out of ``in_proj``'s output, against the ``jax.numpy``
+  form (``ssm_conv_at_cell``), and the gradient of one whole block of that cell
   under ``full_block``, the products of ``gate_up``'s size that its program
   runs counted and timed in a trace (``dense_ffn_at_cell``); and ONE making
   of the expert layer's routing plan at the five sparse cells' shapes, with
@@ -363,6 +366,73 @@ def ssd_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
         timed[f"fwd_{side}_us"] = _us_a_call(jax.jit(scan), args)
         timed[f"grad_{side}_us"] = _us_a_call(grad, args)
     return timed
+
+
+# ---------------------------------------------------------------------------
+# the delta rule with a decay a key channel at the cell's call
+# ---------------------------------------------------------------------------
+
+def kda_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
+    """``ops/kda.py::kda_rule`` at ``kimi-linear.train-8k``'s call — a row of
+    8 contexts, ``s / 32`` heads of 128 for q, k and v in bfloat16, a 128 x
+    128 float32 state a head, the log-decay (1, 8 s, heads, 128) float32 as
+    the cell's seeded weights give it (``A = exp(A_log)`` log-uniform up to
+    16 a head, a gate of its own a channel), chunks of 64 in sub-blocks of
+    16: the two kernels against the TOKEN RECURRENCE in float32 on the same
+    bfloat16 values in the forward, and against the ``lax.scan`` path at
+    ``highest`` precision (itself held to the recurrence's forward at 1e-3,
+    both rounded to bfloat16 on the way out: ``kda.scan_is_the_recurrence``)
+    in all five gradients — the
+    recurrence's own gradient keeps a state a token.  Tolerances, against
+    each array's largest element: 2e-2 forward, 5e-2 on dq, dk, dv, dbeta,
+    1e-1 on dg (a sum back over a chunk of terms of both signs).  Then us a
+    call of the kernels' gradient program (forward included) and of the
+    scan path's."""
+    from apex_tpu.ops.kda import kda_rule, kda_rule_recurrent
+
+    f32, bf16, normal = jnp.float32, jnp.bfloat16, jax.random.normal
+    shape = (1, 8 * s, max(s // 32, 1), 128)
+
+    def make(kq, kk, kv, kg):
+        ka, kgate, kb, kcot = jax.random.split(kg, 4)
+        l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        a = jax.random.uniform(ka, (shape[2], 1), f32, 1e-4, 16.0)
+        return ((l2(normal(kq, shape, f32)) * 128 ** -0.5).astype(bf16),
+                l2(normal(kk, shape, f32)).astype(bf16),
+                normal(kv, shape, f32).astype(bf16),
+                -a * jax.nn.softplus(normal(kgate, shape, f32) + 1.0),
+                jax.nn.sigmoid(normal(kb, shape[:3], f32)),
+                normal(kcot, shape, f32).astype(bf16))
+
+    *args, cot = jax.jit(lambda key: make(*jax.random.split(key, 4)))(
+        jax.random.fold_in(root_key, 180))
+    cot = cot.astype(f32)
+
+    def both(fn):
+        def loss(*a):
+            out = fn(*a)
+            return jnp.sum(out.astype(f32) * cot), out
+        return jax.jit(jax.value_and_grad(loss, tuple(range(5)), has_aux=True))
+
+    compiled = both(kda_rule).lower(*args).compile()
+    _require_mosaic(compiled, 2, calls, "kda")
+    (_, out), grads = compiled(*args)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(kda_rule_recurrent)(*args)
+        by_scan = both(lambda *a: kda_rule(*a, use_pallas=False))
+        (_, scanned), want_grads = by_scan(*args)
+    _compare("kda.scan_is_the_recurrence", scanned, want, 1e-3, parity)
+    _compare("kda.fwd", out, want, 2e-2, parity)
+    for name, tol, g, w in zip(("dq", "dk", "dv", "dg", "dbeta"),
+                               (5e-2, 5e-2, 5e-2, 1e-1, 5e-2),
+                               grads, want_grads):
+        _compare(f"kda.{name}", g, w, tol, parity)
+    # the two programs above, forward with the five gradients: the kernels',
+    # and the scan path's (float32 at ``highest`` precision)
+    return {"shape": [*shape, 64, 16],
+            "kernels": mosaic_call_names(compiled.as_text()),
+            "grad_kernels_us": _us_a_call(compiled, args, n=3),
+            "grad_scan_us": _us_a_call(by_scan, args, n=2)}
 
 
 # ---------------------------------------------------------------------------
@@ -1242,6 +1312,10 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     # the state-space scan at granite-h.train-8k's call, its two kernels
     # against the token recurrence
     facts["ssd_at_cell"] = ssd_at_cell(s, root_key, parity, calls)
+
+    # the delta rule with a decay a key channel at kimi-linear.train-8k's
+    # call, its two kernels against the token recurrence and the scan path
+    facts["kda_at_cell"] = kda_at_cell(s, root_key, parity, calls)
 
     # the convolution in front of that scan, read out of in_proj's output
     facts["ssm_conv_at_cell"] = ssm_conv_at_cell(s, root_key, parity, calls)

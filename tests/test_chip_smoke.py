@@ -65,7 +65,7 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
         "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d",
-        "gated_conv", "ssd", "ssm_conv", "dense_ffn_block"}
+        "gated_conv", "ssd", "kda", "ssm_conv", "dense_ffn_block"}
     # the backward's two routes at the three 8k cells' calls, timed and held
     # to each other and to the reference
     routes = kernels["flash_backward"]
@@ -132,6 +132,14 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
             "grad_jnp_us", "kernels"} <= set(scan)
     for name in ("oracle_in_blocks", "fwd", "dx", "ddt", "dA", "dB", "dC", "dD"):
         assert f"ssd.{name}" in kernels["parity"]
+    # the delta rule with a decay a key channel at the Kimi Linear cell's
+    # call: both routes timed, the kernels held to the token recurrence in
+    # the forward and to the scan path in all five gradients
+    rule = kernels["kda_at_cell"]
+    assert rule["shape"] == [1, 8 * TINY.ctx, max(TINY.ctx // 32, 1), 128, 64, 16]
+    assert {"grad_kernels_us", "grad_scan_us", "kernels"} <= set(rule)
+    for name in ("scan_is_the_recurrence", "fwd", "dq", "dk", "dv", "dg", "dbeta"):
+        assert f"kda.{name}" in kernels["parity"]
     # the convolution in front of it, x, B and C read out of in_proj's output
     conv = kernels["ssm_conv_at_cell"]
     assert conv["shape"] == [1, 8 * TINY.ctx, 8 * TINY.ctx + 256

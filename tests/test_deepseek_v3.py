@@ -102,13 +102,15 @@ def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
         return model.apply({"params": p}, ids, labels=labels,
                            deterministic=False)[1]
 
+    # (each side ONE compiled program: op by op these cost the suite minutes)
     with force_pallas(kernels):
-        logits = model.apply({"params": params}, ids)
-        loss, grads = jax.value_and_grad(program_loss)(params)
+        logits = jax.jit(lambda p: model.apply({"params": p}, ids))(params)
+        loss, grads = jax.jit(jax.value_and_grad(program_loss))(params)
     reg = obs.default_registry()
     assert reg.get("moe.dispatch.kernels").value == kernels
-    assert rel_gap(logits, ref.logits(w, ids, rcfg)) < 1e-4
-    want_loss, want = jax.value_and_grad(reference_loss)(w, ids, labels, rcfg)
+    assert rel_gap(logits, jax.jit(lambda w: ref.logits(w, ids, rcfg))(w)) < 1e-4
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference_loss(w, ids, labels, rcfg)))(w)
     assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
     got = fam.from_program(grads, cfg)
     assert set(got) == set(want)
@@ -138,8 +140,9 @@ def test_o2_stays_close_to_the_reference():
         return model.apply({"params": amp_.cast_model(p)}, ids, labels=labels,
                            deterministic=False)[1]
 
-    loss, grads = jax.value_and_grad(program_loss)(masters)
-    want_loss, want = jax.value_and_grad(reference_loss)(w, ids, labels, rcfg)
+    loss, grads = jax.jit(jax.value_and_grad(program_loss))(masters)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference_loss(w, ids, labels, rcfg)))(w)
     assert abs(float(loss) - float(want_loss)) < 5e-3 * float(want_loss)
     got = fam.from_program(grads, cfg)
     norm = lambda t: float(jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
@@ -214,8 +217,8 @@ def test_shared_rotary_key_gradient_is_the_sum_over_heads():
             rotary(k_pe_heads, 50000.0), (b, h, s, dr))], axis=-1)
         return jnp.sum(attention_ref(q, k, v, causal=True) * do)
 
-    shared = jax.grad(loss)(k_pe)
-    per_head = jax.grad(loss)(jnp.broadcast_to(k_pe, (b, h, s, dr)))
+    shared = jax.jit(jax.grad(loss))(k_pe)
+    per_head = jax.jit(jax.grad(loss))(jnp.broadcast_to(k_pe, (b, h, s, dr)))
     assert shared.shape == (b, 1, s, dr) and per_head.shape == (b, h, s, dr)
     assert float(jnp.max(jnp.abs(per_head[:, 0] - per_head[:, 1]))) > 1e-3
     np.testing.assert_allclose(shared[:, 0], per_head.sum(axis=1),
@@ -227,9 +230,10 @@ def test_shared_rotary_key_gradient_is_the_sum_over_heads():
     rcfg, w = seeded(cfg)
     ids, labels = batch(rows=1, seq=64)
     model = fam.program_model(fam.program_config(cfg, jnp.float32))
-    grads = jax.grad(lambda p: model.apply(
-        {"params": p}, ids, labels=labels)[1])(fam.to_program(w, cfg))
-    want = jax.grad(reference_loss)(w, ids, labels, rcfg)
+    grads = jax.jit(jax.grad(lambda p: model.apply(
+        {"params": p}, ids, labels=labels)[1]))(fam.to_program(w, cfg))
+    want = jax.jit(jax.grad(
+        lambda w: reference_loss(w, ids, labels, rcfg)))(w)
     got = fam.from_program(grads, cfg)["layers.1.attn.w_dkv"][:, 64:]
     assert float(jnp.max(jnp.abs(got))) > 0
     assert rel_gap(got, want["layers.1.attn.w_dkv"][:, 64:]) < 1e-3
@@ -297,7 +301,7 @@ def test_model_is_called_as_gptlm_is():
     assert cfg.experts_held[1] - cfg.experts_held[0] < cfg.n_routed_experts
     model = DeepseekV3LM(cfg)
     ids, labels = batch(rows=1, vocab=cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
     assert {f"layer_{i}" for i in range(3)} <= set(params)
     assert "mlp" in params["layer_0"] and "moe" in params["layer_2"]
     attn = params["layer_1"]["attn"]
@@ -305,10 +309,9 @@ def test_model_is_called_as_gptlm_is():
     assert attn["kv_a_proj"]["kernel"].shape == (128, 64 + 32)
     assert attn["kv_b_proj"]["kernel"].shape == (64, 4 * (96 + 64))
     assert attn["o_proj"]["kernel"].shape == (4 * 64, 128)
-    logits = model.apply({"params": params}, ids)
+    logits, loss = jax.jit(lambda p: model.apply(
+        {"params": p}, ids, labels=labels, deterministic=False))(params)
     assert logits.shape == (1, 128, cfg.vocab_size) and logits.dtype == jnp.float32
-    _, loss = model.apply({"params": params}, ids, labels=labels,
-                          deterministic=False)
     assert loss.shape == () and np.isfinite(float(loss))
     text = str(jax.make_jaxpr(lambda p: model.apply(
         {"params": p}, ids, labels=labels)[1])(params).pretty_print(

@@ -93,8 +93,10 @@ def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
                            deterministic=False)[1]
 
     with force_pallas(kernels):
-        logits = model.apply({"params": params}, ids)
-        loss, grads = jax.value_and_grad(program_loss)(params)
+        # (each side ONE compiled program: op by op these cost the suite
+        # minutes)
+        logits = jax.jit(lambda p: model.apply({"params": p}, ids))(params)
+        loss, grads = jax.jit(jax.value_and_grad(program_loss))(params)
     reg = obs.default_registry()
     assert reg.get("moe.dispatch.kernels").value == kernels
     assert reg.get("gdn.kernels").value == kernels
@@ -102,8 +104,9 @@ def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
     # float32 both sides, two derivations: the chunked algebra (a triangular
     # inverse, differences of products) leaves 1e-4 where the recurrence and
     # the other layers leave 1e-5
-    assert rel_gap(logits, ref.logits(w, ids, rcfg)) < 2e-4
-    want_loss, want = jax.value_and_grad(reference_loss)(w, ids, labels, rcfg)
+    assert rel_gap(logits, jax.jit(lambda w: ref.logits(w, ids, rcfg))(w)) < 2e-4
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference_loss(w, ids, labels, rcfg)))(w)
     assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
     got = fam.from_program(grads, cfg)
     assert set(got) == set(want)
@@ -130,8 +133,9 @@ def test_o2_stays_close_to_the_reference():
         return model.apply({"params": amp_.cast_model(p)}, ids, labels=labels,
                            deterministic=False)[1]
 
-    loss, grads = jax.value_and_grad(program_loss)(masters)
-    want_loss, want = jax.value_and_grad(reference_loss)(w, ids, labels, rcfg)
+    loss, grads = jax.jit(jax.value_and_grad(program_loss))(masters)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference_loss(w, ids, labels, rcfg)))(w)
     assert abs(float(loss) - float(want_loss)) < 5e-3 * float(want_loss)
     got = fam.from_program(grads, cfg)
     norm = lambda t: float(jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
@@ -344,16 +348,15 @@ def test_model_is_called_as_gptlm_is():
     cfg = Qwen3NextConfig.tiny()
     model = Qwen3NextLM(cfg)
     ids, labels = batch(rows=1, vocab=cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
     assert {f"layer_{i}" for i in range(4)} <= set(params)
     assert "gdn" in params["layer_2"] and "attn" in params["layer_3"]
     assert all("moe" in params[f"layer_{i}"] for i in range(4))
     a_log = np.asarray(params["layer_0"]["gdn"]["A_log"])
     assert (np.exp(a_log) > 0).all() and (np.exp(a_log) <= 16).all()
-    logits = model.apply({"params": params}, ids)
+    logits, loss = jax.jit(lambda p: model.apply(
+        {"params": p}, ids, labels=labels, deterministic=False))(params)
     assert logits.shape == (1, 136, cfg.vocab_size) and logits.dtype == jnp.float32
-    _, loss = model.apply({"params": params}, ids, labels=labels,
-                          deterministic=False)
     assert loss.shape == () and np.isfinite(float(loss))
     reg = obs.default_registry()
     assert reg.get("gdn.chunk").value == 64

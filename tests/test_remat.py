@@ -232,7 +232,7 @@ def test_gauge_counts_the_names_a_policy_keeps(policy):
     assert remat.checkpoint_policy("none") is None
     assert gauge.value == 0
     assert remat.checkpoint_policy(policy) is not None
-    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 8
+    assert gauge.value == len(remat.KEPT_RESIDUAL_NAMES) == 12
 
 
 # -- a gated MLP's first product: made once a step ---------------------------

@@ -101,11 +101,14 @@ def test_float32_matches_the_reference_leaf_by_leaf(kernels, remat):
                            deterministic=False)[1]
 
     with force_pallas(kernels):
-        logits = model.apply({"params": params}, ids)
-        loss, grads = jax.value_and_grad(program_loss)(params)
+        # (each side ONE compiled program: op by op these cost the suite
+        # minutes)
+        logits = jax.jit(lambda p: model.apply({"params": p}, ids))(params)
+        loss, grads = jax.jit(jax.value_and_grad(program_loss))(params)
     assert obs.default_registry().get("moe.dispatch.kernels").value == kernels
-    assert rel_gap(logits, ref.logits(w, ids, rcfg)) < 1e-4
-    want_loss, want = jax.value_and_grad(reference_loss)(w, ids, labels, rcfg)
+    assert rel_gap(logits, jax.jit(lambda w: ref.logits(w, ids, rcfg))(w)) < 1e-4
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference_loss(w, ids, labels, rcfg)))(w)
     assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
     got = fam.from_program(grads, cfg)
     assert set(got) == set(want)
@@ -133,8 +136,9 @@ def test_o2_stays_close_to_the_reference():
         return model.apply({"params": amp_.cast_model(p)}, ids, labels=labels,
                            deterministic=False)[1]
 
-    loss, grads = jax.value_and_grad(program_loss)(masters)
-    want_loss, want = jax.value_and_grad(reference_loss)(w, ids, labels, rcfg)
+    loss, grads = jax.jit(jax.value_and_grad(program_loss))(masters)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference_loss(w, ids, labels, rcfg)))(w)
     assert abs(float(loss) - float(want_loss)) < 5e-3 * float(want_loss)
     got = fam.from_program(grads, cfg)
     norm = lambda t: float(jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))

@@ -167,6 +167,8 @@ def _named_case(kernel):
                 [((8192, 2048), BF16), ((8192, 8), F32), ((8192, 8), I32)])
     if kernel.startswith("apex_gdn_"):
         return _gdn_case()
+    if kernel.startswith("apex_kda_"):
+        return _kda_case()
     if kernel.startswith("apex_conv1d_"):
         return _conv_case()
     if kernel.startswith("apex_gated_conv_"):
@@ -198,6 +200,26 @@ def _gdn_case():
     qk, v, gb = ((1, 8192, 16, 128), BF16), ((1, 8192, 32, 128), BF16), \
         ((1, 8192, 32), F32)
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), [qk, qk, v, gb, gb]
+
+
+def _kda_case():
+    """The delta rule with a decay a key channel as ``kimi-linear.train-8k``
+    calls it: one 8192-token row, q, k and v at 32 heads of 128 in bfloat16,
+    the log-decay (1, 8192, 32, 128) float32, 128 chunks of 64 in sub-blocks
+    of 16 (ops/kda.py), forward and backward — and the convolution in front
+    of it reading a fused projection laid out per head [q | k | v]."""
+    from apex_tpu.ops.kda import kda_rule, split_conv_qkv
+
+    def loss(qkv, w, g, beta):
+        with jax.named_scope("kda_conv"):
+            q, k, v = (t.reshape(1, 8192, 32, 128)
+                       for t in split_conv_qkv(qkv, w, heads=32, head_dim=128))
+        with jax.named_scope("kda_scan"):
+            return jnp.sum(kda_rule(q, k, v, g, beta).astype(F32))
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3)), [
+        ((1, 8192, 12288), BF16), ((12288, 4), F32),
+        ((1, 8192, 32, 128), F32), ((1, 8192, 32), F32)]
 
 
 def _conv_case():
@@ -277,6 +299,7 @@ _NAMES_OF_CASE = {}
     "apex_xent_bwd", "apex_paged_attn", "apex_gmm", "apex_gmm_dw",
     "apex_moe_records", "apex_moe_gather", "apex_moe_combine",
     "apex_moe_combine_dw", "apex_gdn_fwd", "apex_gdn_bwd",
+    "apex_kda_fwd", "apex_kda_bwd",
     "apex_conv1d_fwd", "apex_conv1d_bwd",
     "apex_gated_conv_fwd", "apex_gated_conv_bwd",
     "apex_ssd_fwd", "apex_ssd_bwd",
@@ -289,10 +312,10 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
 
     assert kernel in KERNEL_NAMES
     # (the four apex_moe_* kernels are one program, the two apex_gdn_*, the
-    # two apex_conv1d_*, the two apex_gated_conv_* and the two apex_ssd_* four
-    # more: each compiled once)
-    case = next((f for f in ("apex_moe_", "apex_gdn_", "apex_conv1d_",
-                             "apex_gated_conv_", "apex_ssd_")
+    # two apex_kda_*, the two apex_conv1d_*, the two apex_gated_conv_* and
+    # the two apex_ssd_* five more: each compiled once)
+    case = next((f for f in ("apex_moe_", "apex_gdn_", "apex_kda_",
+                             "apex_conv1d_", "apex_gated_conv_", "apex_ssd_")
                  if kernel.startswith(f)), kernel)
     if case not in _NAMES_OF_CASE:
         fn, avals = _named_case(kernel)

@@ -41,6 +41,7 @@ MODULES = [
     "apex_tpu.ops.conv_bn",
     "apex_tpu.ops.gated_conv",
     "apex_tpu.ops.ssd",
+    "apex_tpu.ops.kda",
     "apex_tpu.ops.fused_optim",
     "apex_tpu.parallel.distributed",
     "apex_tpu.parallel.sync_batchnorm",
@@ -74,6 +75,7 @@ MODULES = [
     "apex_tpu.models.decoder",
     "apex_tpu.models.lfm2",
     "apex_tpu.models.granite_hybrid",
+    "apex_tpu.models.kimi_linear",
     "apex_tpu.serve.kv_cache",
     "apex_tpu.serve.decode",
     "apex_tpu.serve.engine",
