@@ -46,14 +46,17 @@ N(0, ``initializer_range``).
 The shell, how it is called and how expert parallelism enters
 (``experts_held``, a sliced ``vocab_size``): ``models/decoder.py``.  Scopes
 ``kda_proj``, ``kda_conv``, ``kda_gate`` (the two low-rank gates, beta, the
-softplus), ``kda_scan`` (normalising q and k, the running sums, the rule's
-kernels or scan), ``kda_out``; the latent layer's ``mla_proj``, ``attn_full``,
-``mla_out``; ``dense_ffn`` around the leading layers' MLP, the four ``moe_*``,
+softplus: the log-decay ``g``, made over (S, H d_h) as ``f_b_proj`` lies),
+``kda_scan`` (the rule's kernels or scan — the l2 norm of q and k and the
+chunks' running sums of ``g`` inside them), ``kda_out``; the latent layer's
+``mla_proj``, ``attn_full``, ``mla_out``; ``dense_ffn`` around the leading layers' MLP, the four ``moe_*``,
 ``lm_head``, ``lm_loss``.  Under ``kda_conv`` the projection's output goes into
 :func:`apex_tpu.ops.kda.split_conv_qkv` as it lies (on the TPU the
 ``apex_conv1d_*`` kernels read q, k, v through BlockSpecs on it); under
-``kda_scan`` the rule is the ``apex_kda_*`` kernel pair, which reads q, k, v in
-the compute dtype and the decays' running sum in float32.
+``kda_scan`` the rule is the ``apex_kda_*`` kernel pair, which reads q, k, v
+in the compute dtype as the convolution wrote them and ``g`` in float32 as
+the gate made it, and normalises and sums on the tile it holds: no array of
+q's size is made between the convolution, the gate and the rule.
 Serving methods are not part of this model yet: the rule's one-token step and
 its state beside latent cache rows are ROADMAP "Reach".
 """
@@ -164,17 +167,19 @@ class KimiDeltaAttention(nn.Module):
             a_log = self.param("A_log", a_log_init, (h,), jnp.float32)
             dt_bias = self.param("dt_bias", nn.initializers.ones_init(),
                                  (h * hd,), jnp.float32)
-            g = -jnp.exp(f32(a_log))[:, None] * jax.nn.softplus(
-                heads(f32(low_rank("f")) + f32(dt_bias)))
+            # made over (b, s, h d) as the projection lies — a head's rate
+            # at each of its channels — so that the rule's kernels read it
+            # there: over (b, s, h, d) it is tiled another way, and every
+            # change of shape a 134 MB copy
+            g = jnp.repeat(-jnp.exp(f32(a_log)), hd) * jax.nn.softplus(
+                f32(low_rank("f")) + f32(dt_bias))
             beta = jax.nn.sigmoid(f32(linear(cfg, h, "b_proj")(x)))
             gate = jax.nn.sigmoid(f32(low_rank("g")))
         with jax.named_scope("kda_scan"):
-            # normalised in float32; the kernels take q and k in v's dtype, a
-            # cast XLA fuses into the norm
-            l2 = lambda t: t * jax.lax.rsqrt(
-                jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-            o = f32(kda_rule(l2(f32(heads(q))) * hd ** -0.5,
-                             l2(f32(heads(k))), heads(v), g, beta))
+            # q and k as the convolution wrote them, g a token's own decay:
+            # the rule normalises and sums where it reads them
+            o = f32(kda_rule(heads(q), heads(k), heads(v), heads(g), beta,
+                             qk_norm=(1e-6, hd ** -0.5)))
         with jax.named_scope("kda_out"):
             w_norm = self.param("norm", nn.initializers.ones_init(), (hd,),
                                 jnp.float32)

@@ -1,6 +1,6 @@
 """The delta rule with a decay of its own for every key channel (Kimi Delta
 Attention, KDA) over a sequence, by chunks — two Pallas TPU kernels that hold
-everything between q, k, v, G, beta and o in VMEM, and a ``lax.scan`` path.
+everything between q, k, v, g, beta and o in VMEM, and a ``lax.scan`` path.
 
 ``ops/gated_delta.py``'s rule with the scalar log-decay ``g_t`` of a head
 become a VECTOR over the head's ``d_k`` key channels
@@ -44,35 +44,48 @@ program stay as they are, to the byte; what the two files share —
 ``(I + A)^-1`` by doubling, the products' helpers, the small (B, S, H) arrays'
 layouts — this one imports.
 
-**On the TPU two kernels.**  ``apex_kda_fwd`` (grid (rows, head groups,
-chunks), the heads' float32 states in VMEM scratch) reads a chunk's q, k, v —
-blocks of the (B, S, H d) arrays the model has, in its compute dtype —,
-``beta`` and the running sum ``G`` ((B, S, H d_k) float32: as large as q and k
-together, the one float32 array of that size that crosses HBM), makes ``A``,
-``P``, ``T = (I + A)^-1``, runs the three lines and writes o, the state at
+**On the TPU two kernels,** which take their operands as their producers
+leave them.  ``apex_kda_fwd`` (grid (rows, head groups, chunks), the heads'
+float32 states in VMEM scratch) reads a chunk's q, k, v — blocks of the (B,
+S, H d) arrays the model has, in its compute dtype —, ``beta`` and the
+log-decay ``g`` ((B, S, H d_k) float32, a token's own: as large as q and k
+together, the one float32 array of that size that crosses HBM, once).  On the
+(C, d) tile of a head it holds it makes the running sum ``G`` of ``g`` from
+the chunk's start (:func:`_chunk_sum`: a product with a triangle of ones on
+the idle MXU, float32 sums) and, where the caller says so (``qk_norm``), the
+l2 norm of q and k — float32, rounded to the operands' dtype once —; then
+``A``, ``P``, ``T = (I + A)^-1``, the three lines, and writes o, the state at
 each chunk's START and, for the backward pass, ``T`` and ``P`` in the
 operands' dtype.  ``apex_kda_bwd`` walks the chunks from the last with ``dS``
-in scratch, makes the forward's values again but ``T`` and ``P``, and carries
-on through ``dA = -(T^T dU) U^T``, ``dP = dO U^T`` and every decay to dq, dk,
-dv, dbeta and dG; through the decays of ``A`` and ``P`` a channel's ``dG`` is
-``q . dq + k . dk_row - k . dk_col`` of the parts of dq and dk that came
-through them.  XLA makes ``G``, ``g`` summed from each chunk's start, and sums
-dG back: each a product with a (C, C) triangle of ones (:func:`_running_sum`).
+in scratch, makes the forward's values again but ``T`` and ``P`` (the norm and
+``G`` among them), and carries on through ``dA = -(T^T dU) U^T``, ``dP = dO
+U^T`` and every decay to dq, dk, dv, dbeta and dG; through the decays of ``A``
+and ``P`` a channel's ``dG`` is ``q . dq + k . dk_row - k . dk_col`` of the
+parts of dq and dk that came through them.  It sums dG back over the tokens
+that follow in the chunk and writes ``dg``; with ``qk_norm`` its dq and dk
+are those in the q and k it was handed (``r (d - u sum(u d))`` a row, ``u``
+the unit vector, ``r`` the reciprocal norm).  XLA does nothing to an array of
+q's size on either side of the kernels: no norm, no running sum, no cast.
 
 **Off the TPU, and as the kernels' oracle,** a ``lax.scan`` over the chunks
-whose body makes the (C, C, d) decays outright (masked BEFORE the
-exponential), in float32, differentiated by JAX; it also takes the shapes
-:func:`supported` refuses.
+whose body makes ``G`` by ``jnp.cumsum`` and the (C, C, d) decays outright
+(masked BEFORE the exponential), in float32, differentiated by JAX, the norm
+(where asked) ``jax.numpy``'s in front of it: an independent composition.
+It also takes the shapes :func:`supported` refuses.
 
 **Precision** as the scalar rule's: float32 arithmetic, an operand rounded to
-v's dtype once, where it enters a product; ``S``, ``dS``, ``G`` and every
-decay stay float32.  With float32 inputs (the tests, in interpret mode) the
-kernels compute in float32 throughout.
+v's dtype once, where it enters a product; ``S``, ``dS``, ``G``, the norms
+and every decay stay float32.  With float32 inputs (the tests, in interpret
+mode) the kernels compute in float32 throughout.  The decays read
+DIFFERENCES of ``G``, which reaches hundreds inside a chunk: its float32
+spacing there (~3e-5), not the order of the sum, is the chunked form's
+distance from the token recurrence (~1e-5 of the largest output, scan path
+and kernels alike, against the recurrence in float64).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -249,8 +262,51 @@ def _head(ref, h: int, d: int):
     return ref[0, :, h * d:(h + 1) * d]
 
 
+def _chunk_sum(x, reverse: bool = False):
+    """``x`` (C, d) float32 summed down its rows from the chunk's start
+    (``reverse``: back from its end): ``G`` from ``g``, and ``dg`` from
+    ``dG``.  A product with the (C, C) triangle of ones at ``highest``
+    precision — float32 sums that carry all of x's bits, as XLA's product
+    over the whole array was — on the MXU, which the rule leaves idle."""
+    row, col = _chunk_masks(x.shape[0])
+    ones = (row <= col if reverse else row >= col).astype(jnp.float32)
+    return jax.lax.dot_general(ones, x, _NN,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _unit(x, eps: float):
+    """``(x r, r)`` with ``r = rsqrt(sum x^2 + eps)`` a row: ``x`` (C, d)
+    float32 at l2 norm one (the model's norm of q and k), and ``r`` (C, 1)."""
+    r = jax.lax.rsqrt(_rows(x * x) + eps)
+    return x * r, r
+
+
+def _unit_grad(unit, r, d_unit):
+    """The gradient in ``x`` of ``x r`` (:func:`_unit`) from its own."""
+    return r * (d_unit - unit * _rows(unit * d_unit))
+
+
+def _q_and_k(q_ref, k_ref, h: int, d: int, norm, mx):
+    """One head's q and k (C, d) float32 as the products see them, and what
+    the backward pass needs of the norm.  ``norm`` None: the blocks as they
+    lie, already normalised.  ``norm`` ``(eps, q's scale)``: the blocks are
+    the convolution's output; each row is normalised in float32 and rounded
+    to the operands' dtype ONCE, here (where the caller's cast rounded it
+    when XLA made the norm) — then ``(q, k, (q's unit vector and r, k's))``,
+    the unit vectors before the rounding, as the norm's vjp reads them."""
+    q = _head(q_ref, h, d).astype(jnp.float32)
+    k = _head(k_ref, h, d).astype(jnp.float32)
+    if norm is None:
+        return q, k, None
+    eps, scale = norm
+    (qu, qr), (ku, kr) = _unit(q, eps), _unit(k, eps)
+    return (mx(qu * scale).astype(jnp.float32), mx(ku).astype(jnp.float32),
+            ((qu, qr), (ku, kr)))
+
+
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, o_ref, s_ref, t_ref,
-                    p_ref, state, *, heads: int):
+                    p_ref, state, *, heads: int, norm):
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
@@ -263,9 +319,10 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, o_ref, s_ref, t_ref,
     eye = jnp.equal(*_chunk_masks(dk))
     betas = bc_ref[0, 0]
     hs = range(heads)
-    q = [_head(q_ref, h, dk).astype(f32) for h in hs]
-    k = [_head(k_ref, h, dk).astype(f32) for h in hs]
-    g = [_head(g_ref, h, dk) for h in hs]
+    qk = [_q_and_k(q_ref, k_ref, h, dk, norm, mx) for h in hs]
+    q, k = [x[0] for x in qk], [x[1] for x in qk]
+    # G from here on: g summed from the chunk's start
+    g = [_chunk_sum(_head(g_ref, h, dk).astype(f32)) for h in hs]
     v = [_head(v_ref, h, dv).astype(f32) for h in hs]
     beta = [betas[:, h:h + 1] for h in hs]
     scores = [_scores(q[h], k[h], g[h], row, col, mx) for h in hs]
@@ -295,7 +352,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, o_ref, s_ref, t_ref,
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, s_ref, t_ref, p_ref,
                     do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbc_ref, dstate,
-                    *, heads: int):
+                    *, heads: int, norm):
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
@@ -309,9 +366,9 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, s_ref, t_ref, p_ref,
     is_last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
     betas = bc_ref[0, 0]
     for h in range(heads):
-        q = _head(q_ref, h, dk).astype(f32)
-        k = _head(k_ref, h, dk).astype(f32)
-        g = _head(g_ref, h, dk)
+        q, k, units = _q_and_k(q_ref, k_ref, h, dk, norm, mx)
+        # G from here on: g summed from the chunk's start
+        g = _chunk_sum(_head(g_ref, h, dk).astype(f32))
         v = _head(v_ref, h, dv).astype(f32)
         do = mx(_head(do_ref, h, dv))
         beta = betas[:, h:h + 1]
@@ -376,14 +433,23 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, s_ref, t_ref, p_ref,
         dk_row = jnp.concatenate(dk_rows, axis=0)
         at_end = (jnp.sum(kd * dkd, axis=0, keepdims=True)
                   + gamma_c * _as_row(_rows(s * ds_next), eye))
-        dq_ref[0, :, h * dk:(h + 1) * dk] = (gamma * dqg + dq_p).astype(
-            dq_ref.dtype)
-        dk_ref[0, :, h * dk:(h + 1) * dk] = (
-            gamma * dkg + delta * dkd + dk_row + dk_col).astype(dk_ref.dtype)
+        dq = gamma * dqg + dq_p
+        dk_ = gamma * dkg + delta * dkd + dk_row + dk_col
+        if units is not None:
+            # through the rounding as a cast's vjp passes it, then the norm:
+            # the gradients in the convolution's output
+            (qu, qr), (ku, kr) = units
+            dq = _unit_grad(qu, qr, dq * norm[1])
+            dk_ = _unit_grad(ku, kr, dk_)
+        dq_ref[0, :, h * dk:(h + 1) * dk] = dq.astype(dq_ref.dtype)
+        dk_ref[0, :, h * dk:(h + 1) * dk] = dk_.astype(dk_ref.dtype)
         dv_ref[0, :, h * dv:(h + 1) * dv] = (beta * dr).astype(dv_ref.dtype)
-        dg_ref[0, :, h * dk:(h + 1) * dk] = (
+        # dG, the gradient of the chunk's running sum, summed back over the
+        # tokens that follow in the chunk: dg
+        dg_ref[0, :, h * dk:(h + 1) * dk] = _chunk_sum(
             qg * dqg + kg * dkg - kd * dkd + q * dq_p + k * (dk_row - dk_col)
-            + jnp.where(is_last, at_end, 0.0))
+            + jnp.where(is_last, at_end, 0.0), reverse=True).astype(
+                dg_ref.dtype)
         dbc_ref[0, 0, :, h:h + 1] = jnp.concatenate(dbeta_rows, axis=0)
 
 
@@ -405,28 +471,13 @@ def _blocks(c, hb, dk, dv, chunk_of):
             per_head(dk, dv), per_head(c, c))
 
 
-def _running_sum(x, chunk: int, reverse: bool = False):
-    """``x`` (B, S, ...) summed from each chunk's start (``reverse``: back
-    from its end), float32, as (B, S, H d): ``G`` from ``g``, and ``dg`` from
-    ``dG``.  A product with a (C, C) triangle of ones at ``highest``
-    precision — the triangle is exact in bfloat16 and the passes carry all
-    of x's bits: float32 sums —, because ``jnp.cumsum`` over 134 MB lowers on
-    the chip to a windowed reduction, a copy and (backwards) two flips: 19.4
-    ms a step of the Kimi Linear cell against this one's ~11, the product
-    itself 0.41 ms a call (PERF.md section 5 and 6, PR 46)."""
-    b, s = x.shape[:2]
-    x = x.astype(jnp.float32).reshape(b, s // chunk, chunk, -1)
-    ones = jnp.ones((chunk, chunk), jnp.float32)
-    tri = jnp.triu(ones) if reverse else jnp.tril(ones)
-    return jnp.einsum("ij,bnjk->bnik", tri, x,
-                      precision=jax.lax.Precision.HIGHEST).reshape(b, s, -1)
-
-
-def _kda_fwd_pallas(q, k, v, g, beta, chunk):
-    """``q``, ``k`` (B, S, H, d_k), ``v`` (B, S, H, d_v), all in v's dtype,
-    ``g`` (B, S, H, d_k), ``beta`` (B, S, H), ``S`` whole chunks.  ``(o (B,
-    S, H, d_v) in v's dtype, the state at each chunk's start (B, N, H, d_k,
-    d_v) float32, each chunk's T and P (B, N, H, C, C) in v's dtype)``."""
+def _kda_fwd_pallas(q, k, v, g, beta, chunk, norm):
+    """``q``, ``k`` (B, S, H, d_k) — in v's dtype as the products take them,
+    or with ``norm`` (:func:`_q_and_k`) as their producer left them —, ``v``
+    (B, S, H, d_v), ``g`` (B, S, H, d_k) float32, ``beta`` (B, S, H), ``S``
+    whole chunks.  ``(o (B, S, H, d_v) in v's dtype, the state at each
+    chunk's start (B, N, H, d_k, d_v) float32, each chunk's T and P (B, N,
+    H, C, C) in v's dtype)``."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     n, c = s // chunk, chunk
@@ -434,7 +485,7 @@ def _kda_fwd_pallas(q, k, v, g, beta, chunk):
     keys, values, cols, states, square = _blocks(c, hb, dk, dv, lambda i: i)
     mat = jax.ShapeDtypeStruct((b, n, h, c, c), v.dtype)
     o, states, tri, scores = _pallas_call(
-        functools.partial(_kda_fwd_kernel, heads=hb),
+        functools.partial(_kda_fwd_kernel, heads=hb, norm=norm),
         name="apex_kda_fwd", grid=(b, h // hb, n),
         in_specs=[keys, keys, values, keys, cols],
         out_specs=[values, states, square, square],
@@ -444,12 +495,12 @@ def _kda_fwd_pallas(q, k, v, g, beta, chunk):
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
     )(q.reshape(b, s, h * dk), k.reshape(b, s, h * dk),
-      v.reshape(b, s, h * dv), _running_sum(g, c),
+      v.reshape(b, s, h * dv), g.reshape(b, s, h * dk),
       _small_layouts(beta, n, hb)[0])
     return o.reshape(b, s, h, dv), states, tri, scores
 
 
-def _kda_bwd_pallas(q, k, v, g, beta, states, tri, scores, do, chunk):
+def _kda_bwd_pallas(q, k, v, g, beta, states, tri, scores, do, chunk, norm):
     """The gradients of :func:`_kda_fwd_pallas`'s ``o`` in its five inputs,
     the chunks walked from the last."""
     b, s, h, dk = q.shape
@@ -459,8 +510,8 @@ def _kda_bwd_pallas(q, k, v, g, beta, states, tri, scores, do, chunk):
     keys, values, cols, per_state, square = _blocks(
         c, hb, dk, dv, lambda i: n - 1 - i)
     beta_cols = _small_layouts(beta, n, hb)[0]
-    dq, dk_, dv_, big_dg, dbeta_cols = _pallas_call(
-        functools.partial(_kda_bwd_kernel, heads=hb),
+    dq, dk_, dv_, dg, dbeta_cols = _pallas_call(
+        functools.partial(_kda_bwd_kernel, heads=hb, norm=norm),
         name="apex_kda_bwd", grid=(b, h // hb, n),
         in_specs=[keys, keys, values, keys, cols, per_state, square, square,
                   values],
@@ -468,29 +519,26 @@ def _kda_bwd_pallas(q, k, v, g, beta, states, tri, scores, do, chunk):
         out_shape=[jax.ShapeDtypeStruct((b, s, h * dk), q.dtype),
                    jax.ShapeDtypeStruct((b, s, h * dk), k.dtype),
                    jax.ShapeDtypeStruct((b, s, h * dv), v.dtype),
-                   jax.ShapeDtypeStruct((b, s, h * dk), jnp.float32),
+                   jax.ShapeDtypeStruct((b, s, h * dk), g.dtype),
                    jax.ShapeDtypeStruct(beta_cols.shape, jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
     )(q.reshape(b, s, h * dk), k.reshape(b, s, h * dk),
-      v.reshape(b, s, h * dv), _running_sum(g, c), beta_cols, states, tri,
-      scores, do.reshape(b, s, h * dv))
-    # dG, the gradient of a chunk's running sum, summed back over the tokens
-    # that follow in the chunk
-    dg = _running_sum(big_dg, c, reverse=True)
+      v.reshape(b, s, h * dv), g.reshape(b, s, h * dk), beta_cols, states,
+      tri, scores, do.reshape(b, s, h * dv))
     dbeta = dbeta_cols.reshape(b, h // hb, n, c, hb).transpose(
         0, 2, 3, 1, 4).reshape(b, s, h)
     return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            dg.reshape(g.shape).astype(g.dtype), dbeta.astype(beta.dtype))
+            dg.reshape(g.shape), dbeta.astype(beta.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda_kernels(q, k, v, g, beta, chunk: int):
-    return _kda_fwd_pallas(q, k, v, g, beta, chunk)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda_kernels(q, k, v, g, beta, chunk: int, norm):
+    return _kda_fwd_pallas(q, k, v, g, beta, chunk, norm)[0]
 
 
-def _kda_kernels_fwd(q, k, v, g, beta, chunk):
-    o, states, tri, scores = _kda_fwd_pallas(q, k, v, g, beta, chunk)
+def _kda_kernels_fwd(q, k, v, g, beta, chunk, norm):
+    o, states, tri, scores = _kda_fwd_pallas(q, k, v, g, beta, chunk, norm)
     # declared to the block-recomputing policies (apex_tpu.remat), as the
     # scalar rule declares its own: where a policy keeps these names the
     # recomputed block's forward rule is dead code
@@ -501,8 +549,8 @@ def _kda_kernels_fwd(q, k, v, g, beta, chunk):
     return o, (q, k, v, g, beta, states, tri, scores)
 
 
-def _kda_kernels_bwd(chunk, res, do):
-    return _kda_bwd_pallas(*res, do, chunk)
+def _kda_kernels_bwd(chunk, norm, res, do):
+    return _kda_bwd_pallas(*res, do, chunk, norm)
 
 
 _kda_kernels.defvjp(_kda_kernels_fwd, _kda_kernels_bwd)
@@ -516,8 +564,8 @@ def supported(chunk: int, dk: int, dv: int) -> bool:
 
 # Called through jit so that a model's KDA layers — every one the same call —
 # share ONE trace and ONE lowering, as the scalar rule's.
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
-def _kda_jit(q, k, v, g, beta, chunk, kernels, trace_key):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _kda_jit(q, k, v, g, beta, chunk, kernels, qk_norm, trace_key):
     del trace_key
     b, s, h, dk = q.shape
     dv = v.shape[-1]
@@ -526,11 +574,20 @@ def _kda_jit(q, k, v, g, beta, chunk, kernels, trace_key):
     # the padding tokens (zero k, beta, g) leave state and outputs as they are
     padded = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
     if kernels:
-        # the products' operands are v's dtype: q and k are rounded to it
-        # once, here (a cast their producer absorbs), not in every product
-        args = (q.astype(v.dtype), k.astype(v.dtype), v, g, beta)
+        if qk_norm is None:
+            # the products' operands are v's dtype: q and k are rounded to
+            # it once, here (a cast their producer absorbs), not in every
+            # product; with ``qk_norm`` the kernels round what they normalise
+            q, k = q.astype(v.dtype), k.astype(v.dtype)
+        args = (q, k, v, g, beta)
         return _kda_kernels(*(map(padded, args) if pad else args),
-                            chunk)[:, :s]
+                            chunk, qk_norm)[:, :s]
+    if qk_norm is not None:
+        eps, scale = qk_norm
+        l2 = lambda t: t * jax.lax.rsqrt(jnp.sum(
+            jnp.square(t), axis=-1, keepdims=True) + eps)
+        q = l2(q.astype(jnp.float32)) * scale
+        k = l2(k.astype(jnp.float32))
 
     def chunks(t):
         """(B, S, H, ...) -> (N, B H, C, ...), float32."""
@@ -549,23 +606,33 @@ def _kda_jit(q, k, v, g, beta, chunk, kernels, trace_key):
 
 
 def kda_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
+             qk_norm: Optional[Tuple[float, float]] = None,
              use_pallas: Optional[bool] = None):
     """The delta rule with a decay a key channel over every row of a batch,
     by chunks.
 
     ``q``, ``k`` (B, S, H, d_k) — already normalised and scaled as the model
-    wants them —, ``v`` (B, S, H, d_v), ``g`` (B, S, H, d_k) the log-decay
-    of every key channel (<= 0, float32), ``beta`` (B, S, H).  Returns (B, S,
-    H, d_v) in ``v``'s dtype; float32 arithmetic, the products at JAX's
-    default precision, an operand rounded once where it enters a product;
-    the state, the running sums and the decays stay float32.  Each row
-    starts from a zero state; ``S`` need not be whole chunks.
-    Differentiable in all five.
+    wants them, unless ``qk_norm`` says otherwise —, ``v`` (B, S, H, d_v),
+    ``g`` (B, S, H, d_k) the log-decay of every key channel, a token's own
+    (<= 0, float32: the rule sums it over a chunk), ``beta`` (B, S, H).
+    Returns (B, S, H, d_v) in ``v``'s dtype; float32 arithmetic, the
+    products at JAX's default precision, an operand rounded once where it
+    enters a product; the state, the running sums and the decays stay
+    float32.  Each row starts from a zero state; ``S`` need not be whole
+    chunks.  Differentiable in all five.
+
+    ``qk_norm`` ``(eps, scale)``: q and k come as their producer left them
+    and the rule normalises each head's row first, in float32 — ``x *
+    rsqrt(sum x^2 + eps)``, q then times ``scale`` —, and its gradients are
+    those in the q and k it was handed.  In the kernels that costs no pass
+    over HBM: a chunk's tile is normalised where it is read.
 
     On the TPU, where the shapes tile (:func:`supported`), the whole rule
     runs in the kernels ``apex_kda_fwd`` / ``apex_kda_bwd``; else as a
     ``lax.scan`` over the chunks in float32.  The gauge ``kda.kernels`` says
-    which was traced (1: the kernels, 0: the scan), beside ``kda.chunk`` and
+    which was traced (1: the kernels, 0: the scan) and
+    ``kda.qk_norm_in_kernel`` whether the traced kernels normalise (0 where
+    the caller did, or the scan path ran), beside ``kda.chunk`` and
     ``kda.chunks_per_row``."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
@@ -588,4 +655,9 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
     reg.gauge("kda.chunk").set(chunk)
     reg.gauge("kda.chunks_per_row").set(-(-q.shape[1] // chunk))
     reg.gauge("kda.kernels").set(int(use_pallas))
-    return _kda_jit(q, k, v, g, beta, chunk, bool(use_pallas), _trace_key())
+    reg.gauge("kda.qk_norm_in_kernel").set(
+        int(use_pallas and qk_norm is not None))
+    if qk_norm is not None:
+        qk_norm = tuple(map(float, qk_norm))
+    return _kda_jit(q, k, v, g, beta, chunk, bool(use_pallas), qk_norm,
+                    _trace_key())

@@ -63,6 +63,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -378,33 +379,40 @@ def kda_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
     128 float32 state a head, the log-decay (1, 8 s, heads, 128) float32 as
     the cell's seeded weights give it (``A = exp(A_log)`` log-uniform up to
     16 a head, a gate of its own a channel), chunks of 64 in sub-blocks of
-    16: the two kernels against the TOKEN RECURRENCE in float32 on the same
-    bfloat16 values in the forward, and against the ``lax.scan`` path at
-    ``highest`` precision (itself held to the recurrence's forward at 1e-3,
-    both rounded to bfloat16 on the way out: ``kda.scan_is_the_recurrence``)
-    in all five gradients — the
-    recurrence's own gradient keeps a state a token.  Tolerances, against
-    each array's largest element: 2e-2 forward, 5e-2 on dq, dk, dv, dbeta,
-    1e-1 on dg (a sum back over a chunk of terms of both signs).  Then us a
-    call of the kernels' gradient program (forward included) and of the
-    scan path's."""
+    16 — twice: AS THE MODEL CALLS IT (``raw``: q and k as the convolution
+    leaves them, the kernels normalising where they read, ``qk_norm``), and
+    with q and k normalised in front of the call.  Each way the two kernels
+    against the ``lax.scan`` path at ``highest`` precision in all five
+    gradients (the raw call's dq and dk those in the raw q and k), and the
+    normalised call's forward against the TOKEN RECURRENCE in float32 on the
+    same bfloat16 values, to which the scan path is itself held at 1e-3
+    (both rounded to bfloat16 on the way out:
+    ``kda.scan_is_the_recurrence``) — the recurrence's own gradient keeps a
+    state a token.  Tolerances, against each array's largest element: 2e-2
+    forward, 5e-2 on dq, dk, dv, dbeta, 1e-1 on dg (a sum back over a chunk
+    of terms of both signs).  Then us a call of the kernels' gradient
+    program (forward included), both ways, and of the scan path's."""
     from apex_tpu.ops.kda import kda_rule, kda_rule_recurrent
 
     f32, bf16, normal = jnp.float32, jnp.bfloat16, jax.random.normal
     shape = (1, 8 * s, max(s // 32, 1), 128)
+    norm = (1e-6, 128 ** -0.5)
 
     def make(kq, kk, kv, kg):
         ka, kgate, kb, kcot = jax.random.split(kg, 4)
-        l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        l2 = lambda x: x * jax.lax.rsqrt(
+            jnp.sum(x * x, -1, keepdims=True) + norm[0])
         a = jax.random.uniform(ka, (shape[2], 1), f32, 1e-4, 16.0)
-        return ((l2(normal(kq, shape, f32)) * 128 ** -0.5).astype(bf16),
-                l2(normal(kk, shape, f32)).astype(bf16),
-                normal(kv, shape, f32).astype(bf16),
+        q, k = (normal(key, shape, f32).astype(bf16) for key in (kq, kk))
+        rest = (normal(kv, shape, f32).astype(bf16),
                 -a * jax.nn.softplus(normal(kgate, shape, f32) + 1.0),
-                jax.nn.sigmoid(normal(kb, shape[:3], f32)),
+                jax.nn.sigmoid(normal(kb, shape[:3], f32)))
+        return ((q, k, *rest),
+                ((l2(q.astype(f32)) * norm[1]).astype(bf16),
+                 l2(k.astype(f32)).astype(bf16), *rest),
                 normal(kcot, shape, f32).astype(bf16))
 
-    *args, cot = jax.jit(lambda key: make(*jax.random.split(key, 4)))(
+    raw, args, cot = jax.jit(lambda key: make(*jax.random.split(key, 4)))(
         jax.random.fold_in(root_key, 180))
     cot = cot.astype(f32)
 
@@ -414,25 +422,33 @@ def kda_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
             return jnp.sum(out.astype(f32) * cot), out
         return jax.jit(jax.value_and_grad(loss, tuple(range(5)), has_aux=True))
 
-    compiled = both(kda_rule).lower(*args).compile()
-    _require_mosaic(compiled, 2, calls, "kda")
-    (_, out), grads = compiled(*args)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(kda_rule_recurrent)(*args)
-        by_scan = both(lambda *a: kda_rule(*a, use_pallas=False))
-        (_, scanned), want_grads = by_scan(*args)
-    _compare("kda.scan_is_the_recurrence", scanned, want, 1e-3, parity)
-    _compare("kda.fwd", out, want, 2e-2, parity)
-    for name, tol, g, w in zip(("dq", "dk", "dv", "dg", "dbeta"),
-                               (5e-2, 5e-2, 5e-2, 1e-1, 5e-2),
-                               grads, want_grads):
-        _compare(f"kda.{name}", g, w, tol, parity)
-    # the two programs above, forward with the five gradients: the kernels',
-    # and the scan path's (float32 at ``highest`` precision)
-    return {"shape": [*shape, 64, 16],
-            "kernels": mosaic_call_names(compiled.as_text()),
-            "grad_kernels_us": _us_a_call(compiled, args, n=3),
-            "grad_scan_us": _us_a_call(by_scan, args, n=2)}
+    timed = {"shape": [*shape, 64, 16]}
+    for record, parity_key, time_key, operands, kw in (
+            ("kda", "kda.{}", "grad_kernels_us", args, {}),
+            ("kda_raw", "kda.raw.{}", "grad_kernels_raw_us", raw,
+             {"qk_norm": norm})):
+        compiled = both(functools.partial(kda_rule, **kw)).lower(
+            *operands).compile()
+        _require_mosaic(compiled, 2, calls, record)
+        (_, out), grads = compiled(*operands)
+        with jax.default_matmul_precision("highest"):
+            by_scan = both(functools.partial(kda_rule, use_pallas=False, **kw))
+            (_, want), want_grads = by_scan(*operands)
+            if not kw:
+                scanned, want = want, jax.jit(kda_rule_recurrent)(*operands)
+                _compare("kda.scan_is_the_recurrence", scanned, want, 1e-3,
+                         parity)
+        _compare(parity_key.format("fwd"), out, want, 2e-2, parity)
+        for name, tol, g, w in zip(("dq", "dk", "dv", "dg", "dbeta"),
+                                   (5e-2, 5e-2, 5e-2, 1e-1, 5e-2),
+                                   grads, want_grads):
+            _compare(parity_key.format(name), g, w, tol, parity)
+        # forward with the five gradients: the kernels' program
+        timed[time_key] = _us_a_call(compiled, operands, n=3)
+    timed["kernels"] = mosaic_call_names(compiled.as_text())
+    # and the scan path's (float32 at ``highest`` precision), normalising
+    timed["grad_scan_us"] = _us_a_call(by_scan, raw, n=2)
+    return timed
 
 
 # ---------------------------------------------------------------------------

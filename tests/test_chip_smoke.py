@@ -65,7 +65,7 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
         "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d",
-        "gated_conv", "ssd", "kda", "ssm_conv", "dense_ffn_block"}
+        "gated_conv", "ssd", "kda", "kda_raw", "ssm_conv", "dense_ffn_block"}
     # the backward's two routes at the three 8k cells' calls, timed and held
     # to each other and to the reference
     routes = kernels["flash_backward"]
@@ -137,9 +137,13 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     # the forward and to the scan path in all five gradients
     rule = kernels["kda_at_cell"]
     assert rule["shape"] == [1, 8 * TINY.ctx, max(TINY.ctx // 32, 1), 128, 64, 16]
-    assert {"grad_kernels_us", "grad_scan_us", "kernels"} <= set(rule)
+    assert {"grad_kernels_us", "grad_kernels_raw_us", "grad_scan_us",
+            "kernels"} <= set(rule)
     for name in ("scan_is_the_recurrence", "fwd", "dq", "dk", "dv", "dg", "dbeta"):
         assert f"kda.{name}" in kernels["parity"]
+    # and as the model calls it: q and k normalised inside the kernels
+    for name in ("fwd", "dq", "dk", "dv", "dg", "dbeta"):
+        assert f"kda.raw.{name}" in kernels["parity"]
     # the convolution in front of it, x, B and C read out of in_proj's output
     conv = kernels["ssm_conv_at_cell"]
     assert conv["shape"] == [1, 8 * TINY.ctx, 8 * TINY.ctx + 256

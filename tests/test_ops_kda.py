@@ -1,7 +1,8 @@
 """``ops/kda.py``: the chunked delta rule with a decay a key channel against
 the token recurrence it is defined by (outputs and every gradient; the kernels
-in interpret mode, and the ``lax.scan`` path), the decays at their strongest,
-a chunk that does not divide the row, a decay constant over
+in interpret mode, and the ``lax.scan`` path), the decays at their strongest
+and spread over five decades, the l2 norm of q and k made inside the rule, a
+chunk that does not divide the row, a decay constant over
 the channels against ``ops/gated_delta.py``'s scalar rule, and the convolution
 in front of it under this mixer's layout."""
 import jax
@@ -55,33 +56,76 @@ CASES = {
     # every channel of every head at -21 a token over a whole chunk (-1300
     # by its end): a factored decay would overflow float32
     "whole_chunk_at_minus_21": dict(SHAPE, g_all=-21.0),
+    # q and k as the convolution leaves them (norms far from 1): the rule
+    # normalises them — inside the kernels, where they run — and its dq and
+    # dk are the gradients in the RAW q and k
+    "norm_inside_the_rule": dict(SHAPE, qk_norm=(1e-6, 128 ** -0.5)),
+    # a token's decay anywhere from -1e-4 to -21, channel by channel: the
+    # running sums reach hundreds while neighbours differ by 1e-4, and the
+    # decays read their DIFFERENCES
+    "g_from_1e-4_to_21_a_token": dict(SHAPE, g_span=(1e-4, 21.0)),
 }
+NORM_GAUGE = "kda.qk_norm_in_kernel"
 
 
 @pytest.mark.parametrize("kernels", [False, True], ids=["scan", "pallas"])
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_chunked_rule_matches_the_recurrence(case, kernels):
     """Outputs and all five gradients, float32; no exponent that is built is
-    positive, so everything stays finite at the strongest decay."""
+    positive, so everything stays finite at the strongest decay.  Where the
+    rule normalises q and k itself, the kernels also against ``l2`` in
+    ``jax.numpy`` in front of the call that does not; where ``g`` spans five
+    decades, o and dg also against the scan path at float32's own size: the
+    kernels' running sums carry all of g's bits."""
+    from apex_tpu import obs
+
     kw = dict(CASES[case])
-    chunk, g_all = kw.pop("chunk"), kw.pop("g_all", None)
-    args = inputs(**kw)
+    chunk, g_all, g_span, qk_norm = (kw.pop(name, None) for name in (
+        "chunk", "g_all", "g_span", "qk_norm"))
+    q, k, v, g, beta = inputs(**kw)
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
     if g_all is not None:
-        args = (*args[:3], jnp.full_like(args[3], g_all), args[4])
-    want = kda.kda_rule_recurrent(*args)
+        g = jnp.full_like(g, g_all)
+    if g_span is not None:
+        lo, hi = map(jnp.log, g_span)
+        g = -jnp.exp(jax.random.uniform(keys[0], g.shape, minval=lo, maxval=hi))
+    normed = lambda q, k: (q, k)
+    if qk_norm is not None:
+        eps, scale = qk_norm
+        l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+        normed = lambda q, k: (l2(q) * scale, l2(k))
+        q = 3.0 * jax.random.normal(keys[1], q.shape)
+        k = 0.5 * jax.random.normal(keys[2], k.shape)
+    args = (q, k, v, g, beta)
+    recurrence = lambda q, k, *rest: kda.kda_rule_recurrent(*normed(q, k), *rest)
+    want = recurrence(*args)
     ct = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-    chunked = lambda *a: kda.kda_rule(*a, chunk=chunk)
+    chunked = lambda *a: kda.kda_rule(*a, chunk=chunk, qk_norm=qk_norm)
     with force_pallas(kernels):
         got = chunked(*args)
+        assert obs.default_registry().get(NORM_GAUGE).value == int(
+            kernels and qk_norm is not None)
         got_grads = grads_of(chunked, args, ct)
     assert gap(got, want) < 2e-5
-    for name, a, b in zip(NAMES, got_grads,
-                          grads_of(kda.kda_rule_recurrent, args, ct)):
+    for name, a, b in zip(NAMES, got_grads, grads_of(recurrence, args, ct)):
         assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
         # (with every decay exp(-21) g's gradient is ~1e-10, the float32
         # roundoff of the O(1e-3) terms it is the difference of: finite)
         if not (name == "g" and g_all is not None):
             assert gap(a, b) < 1e-4, name
+    if not kernels:
+        return
+    if qk_norm is not None:
+        outside = lambda q, k, *rest: kda.kda_rule(
+            *normed(q, k), *rest, chunk=chunk, use_pallas=True)
+        assert gap(got, outside(*args)) < 1e-6
+        assert obs.default_registry().get(NORM_GAUGE).value == 0
+        for name, a, b in zip(NAMES, got_grads, grads_of(outside, args, ct)):
+            assert gap(a, b) < 2e-6, name
+    if g_span is not None:
+        scan = lambda *a: kda.kda_rule(*a, chunk=chunk, use_pallas=False)
+        assert gap(got, scan(*args)) < 1e-5
+        assert gap(got_grads[3], grads_of(scan, args, ct)[3]) < 1e-5
 
 
 def test_a_decay_constant_over_the_channels_is_the_scalar_rule():

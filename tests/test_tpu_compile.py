@@ -207,19 +207,23 @@ def _kda_case():
     calls it: one 8192-token row, q, k and v at 32 heads of 128 in bfloat16,
     the log-decay (1, 8192, 32, 128) float32, 128 chunks of 64 in sub-blocks
     of 16 (ops/kda.py), forward and backward — and the convolution in front
-    of it reading a fused projection laid out per head [q | k | v]."""
+    of it reading a fused projection laid out per head [q | k | v].  Called
+    as the model calls it: q and k as the convolution wrote them and ``g`` a
+    token's own decay, so the kernels compile with their prologue (the two
+    l2 norms, the chunk's running sums)."""
     from apex_tpu.ops.kda import kda_rule, split_conv_qkv
 
     def loss(qkv, w, g, beta):
+        heads = lambda t: t.reshape(1, 8192, 32, 128)
         with jax.named_scope("kda_conv"):
-            q, k, v = (t.reshape(1, 8192, 32, 128)
-                       for t in split_conv_qkv(qkv, w, heads=32, head_dim=128))
+            q, k, v = map(heads, split_conv_qkv(qkv, w, heads=32, head_dim=128))
         with jax.named_scope("kda_scan"):
-            return jnp.sum(kda_rule(q, k, v, g, beta).astype(F32))
+            o = kda_rule(q, k, v, heads(g), beta, qk_norm=(1e-6, 128 ** -0.5))
+        return jnp.sum(o.astype(F32))
 
     return jax.grad(loss, argnums=(0, 1, 2, 3)), [
         ((1, 8192, 12288), BF16), ((12288, 4), F32),
-        ((1, 8192, 32, 128), F32), ((1, 8192, 32), F32)]
+        ((1, 8192, 4096), F32), ((1, 8192, 32), F32)]
 
 
 def _conv_case():
@@ -325,6 +329,31 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     assert set(names) <= set(KERNEL_NAMES), names
 
 
+def _xla_outside_the_kernels(text, scope, banned, size):
+    """The compiled program's instructions that bear ``scope`` and are no
+    Mosaic call — held to this: none has an opcode of ``banned``, and none
+    is a float32 array of ``size`` elements or more, but a kernel's own
+    result or a view of one.  Returns their lines."""
+    import re
+
+    instr = re.compile(r"= (\(?)(\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
+    seen = []
+    for line in text.splitlines():
+        m = instr.search(line)
+        if not m or scope not in line or "tpu_custom_call" in line:
+            continue
+        seen.append(line)
+        is_tuple, dtype, dims, opcode = m.groups()
+        assert opcode not in banned, line[:200]
+        if is_tuple or opcode in ("get-tuple-element", "bitcast"):
+            continue        # a kernel's own result, or a view of one
+        elements = 1
+        for d in filter(None, dims.split(",")):
+            elements *= int(d)
+        assert not (dtype == "f32" and elements >= size), line[:200]
+    return seen
+
+
 def test_delta_rule_keeps_what_is_local_to_a_chunk_inside_its_kernels(
         chip, as_tpu):
     """At the cell's shape the rule's program is ONE ``apex_gdn_fwd`` and
@@ -348,23 +377,44 @@ def test_delta_rule_keeps_what_is_local_to_a_chunk_inside_its_kernels(
     reg = obs.default_registry()
     assert reg.get("gdn.kernels").value == 1
     assert reg.get("gdn.local_in_kernel").value == 1
-    q_size = 8192 * 16 * 128
-    instr = re.compile(r"= (\(?)(\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
-    seen = 0
-    for line in text.splitlines():
-        m = instr.search(line)
-        if not m or "_rule_jit" not in line or "tpu_custom_call" in line:
-            continue
-        seen += 1
-        is_tuple, dtype, dims, opcode = m.groups()
-        assert opcode not in ("dot", "convolution"), line[:200]
-        if is_tuple or opcode in ("get-tuple-element", "bitcast"):
-            continue        # a kernel's own result, or a view of one
-        size = 1
-        for d in filter(None, dims.split(",")):
-            size *= int(d)
-        assert not (dtype == "f32" and size >= q_size), line[:200]
-    assert seen > 10        # the witness that the lines were found at all
+    seen = _xla_outside_the_kernels(text, "_rule_jit", ("dot", "convolution"),
+                                    8192 * 16 * 128)
+    assert len(seen) > 10   # the witness that the lines were found at all
+
+
+def test_vector_decay_rule_reads_its_operands_where_their_producers_leave_them(
+        chip, as_tpu):
+    """At the cell's call — q and k as the convolution wrote them, ``g`` as
+    the gate made it, over (S, H d) — the rule's program is ONE
+    ``apex_kda_fwd`` and ONE ``apex_kda_bwd`` and, under ``kda_scan``
+    around them, XLA's work on the (B, S, H) arrays alone: no product (the
+    running sums' triangle of ones), no windowed reduction (``cumsum``), no
+    reduction or ``rsqrt`` (the l2 norms) and no float32 array of q's size
+    outside the custom calls' own results (``G``, a normalised q) — each was
+    a pass over HBM that the kernels now make on the tile they hold."""
+    import re
+
+    from apex_tpu import obs
+    from apex_tpu.ops._common import mosaic_call_names, unnamed_mosaic_calls
+
+    fn, avals = _kda_case()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in avals]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+    assert sorted(n for n in names if "kda" in n) == [
+        "apex_kda_bwd", "apex_kda_fwd"], names
+    assert not unnamed_mosaic_calls(text)
+    reg = obs.default_registry()
+    assert reg.get("kda.kernels").value == 1
+    assert reg.get("kda.qk_norm_in_kernel").value == 1
+    seen = _xla_outside_the_kernels(
+        text, "kda_scan", ("dot", "convolution", "reduce-window", "reduce"),
+        8192 * 32 * 128)
+    assert len(seen) > 5    # the witness that the lines were found at all
+    for line in seen:       # nor inside a fusion, by the primitive's name
+        made_by = re.search(r'op_name="[^"]*kda_scan/([^"]*)"', line)
+        assert not re.search(r"reduce_sum|rsqrt|cumsum|dot_general",
+                             made_by.group(1) if made_by else ""), line[:300]
 
 
 def test_state_space_scan_keeps_every_chunk_square_inside_its_kernels(
@@ -388,23 +438,9 @@ def test_state_space_scan_keeps_every_chunk_square_inside_its_kernels(
     assert not unnamed_mosaic_calls(text)
     assert obs.default_registry().get("ssd.kernel").value == 1
     assert not re.search(r"f32\[(?:1,)?(?:32,64|64,32),256,256\]", text)
-    x_size = 8192 * 64 * 64
-    instr = re.compile(r"= (\(?)(\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
-    seen = 0
-    for line in text.splitlines():
-        m = instr.search(line)
-        if not m or "ssm_scan" not in line or "tpu_custom_call" in line:
-            continue
-        seen += 1
-        is_tuple, dtype, dims, opcode = m.groups()
-        assert opcode not in ("dot", "convolution"), line[:200]
-        if is_tuple or opcode in ("get-tuple-element", "bitcast"):
-            continue        # a kernel's own result, or a view of one
-        size = 1
-        for d in filter(None, dims.split(",")):
-            size *= int(d)
-        assert not (dtype == "f32" and size >= x_size), line[:200]
-    assert seen > 10        # the witness that the lines were found at all
+    seen = _xla_outside_the_kernels(text, "ssm_scan", ("dot", "convolution"),
+                                    8192 * 64 * 64)
+    assert len(seen) > 10   # the witness that the lines were found at all
 
 
 def test_delta_net_layer_reads_q_k_v_out_of_the_projection_in_place(
@@ -437,23 +473,9 @@ def test_delta_net_layer_reads_q_k_v_out_of_the_projection_in_place(
                      "apex_gdn_fwd"], names
     assert not unnamed_mosaic_calls(text)
     assert obs.default_registry().get("gdn.conv_kernel").value == 1
-    conv_size = 8192 * 8192
-    instr = re.compile(r"= (\(?)(\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
-    seen = 0
-    for line in text.splitlines():
-        m = instr.search(line)
-        if not m or "gdn_conv" not in line or "tpu_custom_call" in line:
-            continue
-        seen += 1
-        is_tuple, dtype, dims, opcode = m.groups()
-        assert opcode != "concatenate", line[:200]
-        if is_tuple or opcode in ("get-tuple-element", "bitcast"):
-            continue        # a kernel's own result, or a view of one
-        size = 1
-        for d in filter(None, dims.split(",")):
-            size *= int(d)
-        assert not (dtype == "f32" and size >= conv_size), line[:200]
-    assert seen > 5         # the witness that the lines were found at all
+    seen = _xla_outside_the_kernels(text, "gdn_conv", ("concatenate",),
+                                    8192 * 8192)
+    assert len(seen) > 5    # the witness that the lines were found at all
 
 
 def test_a_kernel_differentiated_outside_any_scope_still_bears_its_name(
