@@ -255,19 +255,24 @@ class SelfMultiheadAttn(nn.Module):
             bvec = None
         # through the policy table so O1 autocast reaches the projections
         qkv = F.dense(x, w.astype(dt), bvec)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        split = lambda t: t.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
+        with jax.named_scope("qkv_split"):
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        # scope ``heads_layout``: to heads-major around the core, and back
+        with jax.named_scope("heads_layout"):
+            q, k, v = (t.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
+                       for t in (q, k, v))
 
         bias_ = _masks_to_bias(
             key_padding_mask, attn_mask, self.mask_additive, b, s, s
         )
         attn = _core_attention(
-            self, split(q), split(k), split(v), bias_,
+            self, q, k, v, bias_,
             scale=d ** -0.5, dropout_rate=self.dropout,
             is_training=is_training, impl=self.impl,
             probs_bf16=self.probs_bf16,
         )
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h)
+        with jax.named_scope("heads_layout"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h)
         out = F.dense(
             attn, self.out_proj_weight.astype(dt),
             self.out_proj_bias.astype(dt) if self.bias else None,

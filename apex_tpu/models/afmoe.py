@@ -101,8 +101,9 @@ class AfmoeLayer(nn.Module):
         y = norm("input_norm")(x)
         # one projection: queries, keys, values and the output gate
         qkvg = linear(cfg, (2 * hq + 2 * hk) * hd, "qkvg")(y)
-        q, k, v, g = jnp.split(
-            qkvg, [hq * hd, (hq + hk) * hd, (hq + 2 * hk) * hd], axis=-1)
+        with jax.named_scope("qkv_split"):
+            q, k, v, g = jnp.split(
+                qkvg, [hq * hd, (hq + hk) * hd, (hq + 2 * hk) * hd], axis=-1)
         q = norm("q_norm")(split_heads(q, hq, hd))
         k = norm("k_norm")(split_heads(k, hk, hd))
         if windowed:
@@ -110,7 +111,8 @@ class AfmoeLayer(nn.Module):
         attn = merge_heads(causal_attention(
             q, k, split_heads(v, hk, hd),
             window=cfg.sliding_window if windowed else None))
-        attn = attn * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
+        with jax.named_scope("output_gate"):
+            attn = attn * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
         x = x + norm("post_attn_norm")(linear(cfg, h, "o_proj")(attn))
 
         y = norm("pre_mlp_norm")(x)

@@ -104,13 +104,17 @@ class BertLayer(nn.Module):
         # post-LN residual (the reference's fused norm-add epilogue)
         x = FusedLayerNorm(h, name="attn_ln")(x.astype(jnp.float32) + attn.astype(jnp.float32))
 
-        y = Dense(cfg.intermediate_size, dtype=dt, name="ffn_in")(x.astype(dt))
+        # scope ``norm_cast``: the float32 LayerNorms' output in compute dtype
+        with jax.named_scope("norm_cast"):
+            y = x.astype(dt)
+        y = Dense(cfg.intermediate_size, dtype=dt, name="ffn_in")(y)
         y = jax.nn.gelu(y)
         y = Dense(h, dtype=dt, name="ffn_out")(y)
         if not deterministic and cfg.dropout_rate > 0:
             y = nn.Dropout(cfg.dropout_rate, deterministic=False)(y)
         x = FusedLayerNorm(h, name="ffn_ln")(x.astype(jnp.float32) + y.astype(jnp.float32))
-        return x.astype(dt)
+        with jax.named_scope("norm_cast"):
+            return x.astype(dt)
 
 
 class BertEncoder(nn.Module):

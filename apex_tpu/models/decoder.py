@@ -60,13 +60,15 @@ class RMSNorm(nn.Module):
         init = (nn.initializers.zeros_init() if self.zero_centred
                 else nn.initializers.ones_init())
         scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
-        x32 = x.astype(jnp.float32)
-        normed = x32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        gain = scale.astype(jnp.float32)
-        if self.zero_centred:
-            gain = 1.0 + gain
-        return (normed * gain).astype(self.dtype)
+        # inside the module: ``<flax name>/rms_norm`` reads under both
+        with jax.named_scope("rms_norm"):
+            x32 = x.astype(jnp.float32)
+            normed = x32 * jax.lax.rsqrt(
+                jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+            gain = scale.astype(jnp.float32)
+            if self.zero_centred:
+                gain = 1.0 + gain
+            return (normed * gain).astype(self.dtype)
 
 
 def linear(cfg, features: int, name: Optional[str] = None):
@@ -82,31 +84,37 @@ def rotary(x, theta: float, rot: Optional[int] = None):
     """Rotate the first ``rot`` dims of ``x`` (..., seq, D) by position — the
     whole head where ``rot`` is None — the two halves of those dims paired
     (``rotate_half``), ``inv_freq_j = theta^(-2j / rot)``; dims ``rot..`` pass
-    untouched.  float32 inside, ``x``'s dtype out."""
+    untouched.  float32 inside, ``x``'s dtype out.  Scope ``rope``."""
     s, d = x.shape[-2], x.shape[-1]
     rot = d if rot is None else rot
-    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-    # a whole head is neither sliced nor concatenated back: no copy for it
-    head = (x if rot == d else x[..., :rot]).astype(jnp.float32)
-    half = jnp.concatenate([-head[..., rot // 2:], head[..., :rot // 2]], -1)
-    out = (head * cos + half * sin).astype(x.dtype)
-    return out if rot == d else jnp.concatenate([out, x[..., rot:]], axis=-1)
+    with jax.named_scope("rope"):
+        inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+        ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+        # a whole head is neither sliced nor concatenated back: no copy
+        head = (x if rot == d else x[..., :rot]).astype(jnp.float32)
+        half = jnp.concatenate(
+            [-head[..., rot // 2:], head[..., :rot // 2]], -1)
+        out = (head * cos + half * sin).astype(x.dtype)
+        return (out if rot == d
+                else jnp.concatenate([out, x[..., rot:]], axis=-1))
 
 
 def split_heads(t, n: int, hd: int):
     """(b, s, n * hd) — or (b, s, n, hd) already — to heads-major
-    (b, n, s, hd), as the flash kernels take q, k and v."""
+    (b, n, s, hd), as the flash kernels take q, k and v.  Scope
+    ``heads_layout``, as :func:`merge_heads`."""
     b, s = t.shape[:2]
-    return t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
+    with jax.named_scope("heads_layout"):
+        return t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
 
 
 def merge_heads(t):
     """Heads-major (b, n, s, hd) back to (b, s, n * hd)."""
     b, n, s, hd = t.shape
-    return t.transpose(0, 2, 1, 3).reshape(b, s, n * hd)
+    with jax.named_scope("heads_layout"):
+        return t.transpose(0, 2, 1, 3).reshape(b, s, n * hd)
 
 
 def causal_attention(q, k, v, *, window: Optional[int] = None,

@@ -95,6 +95,13 @@ def _default_attention(q, k, v, *, dropout_rate, dropout_seed,
     )
 
 
+def _cast(t, dtype):
+    """``t`` in ``dtype``, under the scope ``norm_cast``: the casts around a
+    block's float32 LayerNorms on the training path."""
+    with jax.named_scope("norm_cast"):
+        return t.astype(dtype)
+
+
 class GPTLayer(nn.Module):
     """Pre-LN decoder block: x + attn(LN(x)); x + mlp(LN(x))."""
 
@@ -120,28 +127,35 @@ class GPTLayer(nn.Module):
         if decode_state is not None:
             return self._decode(x, decode_state)
 
-        y = FusedLayerNorm(h, name="ln1")(x.astype(jnp.float32)).astype(dt)
+        y = _cast(FusedLayerNorm(h, name="ln1")(_cast(x, jnp.float32)), dt)
         qkv = Dense(3 * h, dtype=dt, name="qkv")(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        split = lambda t: t.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
+        with jax.named_scope("qkv_split"):
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        # scope ``heads_layout``: heads-major around the flash call, and back
+        with jax.named_scope("heads_layout"):
+            q, k, v = (t.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
+                       for t in (q, k, v))
         needs_drop = cfg.attn_dropout_rate > 0 and not deterministic
         seed = None
         if needs_drop:
             seed = jax.random.randint(
                 self.make_rng("dropout"), (), 0, jnp.iinfo(jnp.int32).max
             )
-        attn = attention(
-            split(q), split(k), split(v),
-            dropout_rate=cfg.attn_dropout_rate if needs_drop else 0.0,
-            dropout_seed=seed,
-        )
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h)
+        # the kernels' own wrapping (the gradients' way back to heads-major)
+        with jax.named_scope("attention"):
+            attn = attention(
+                q, k, v,
+                dropout_rate=cfg.attn_dropout_rate if needs_drop else 0.0,
+                dropout_seed=seed,
+            )
+        with jax.named_scope("heads_layout"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, h)
         attn = Dense(h, dtype=dt, name="proj")(attn)
         if not deterministic and cfg.dropout_rate > 0:
             attn = nn.Dropout(cfg.dropout_rate, deterministic=False)(attn)
         x = x + attn.astype(x.dtype)
 
-        y = FusedLayerNorm(h, name="ln2")(x.astype(jnp.float32)).astype(dt)
+        y = _cast(FusedLayerNorm(h, name="ln2")(_cast(x, jnp.float32)), dt)
         y = Dense(cfg.intermediate_size, dtype=dt, name="ffn_in")(y)
         y = jax.nn.gelu(y)
         y = Dense(h, dtype=dt, name="ffn_out")(y)

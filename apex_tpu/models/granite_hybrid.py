@@ -216,7 +216,8 @@ class GraniteHybridLayer(nn.Module):
             mixed = Mamba2Mixer(cfg, name="mamba")(y)
         else:
             qkv = linear(cfg, (hq + 2 * hk) * hd, "qkv")(y)
-            q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
+            with jax.named_scope("qkv_split"):
+                q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
             attn = causal_attention(
                 split_heads(q, hq, hd), split_heads(k, hk, hd),
                 split_heads(v, hk, hd), scale=cfg.attention_multiplier)

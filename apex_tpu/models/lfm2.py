@@ -158,7 +158,8 @@ class Lfm2Layer(nn.Module):
             x = x + ShortConv(cfg, name="conv")(y)
         else:
             qkv = linear(cfg, (hq + 2 * hk) * hd, "qkv")(y)
-            q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
+            with jax.named_scope("qkv_split"):
+                q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
             q = rotary(norm("q_norm")(split_heads(q, hq, hd)), cfg.rope_theta)
             k = rotary(norm("k_norm")(split_heads(k, hk, hd)), cfg.rope_theta)
             attn = causal_attention(q, k, split_heads(v, hk, hd))

@@ -204,14 +204,19 @@ class GatedAttention(nn.Module):
         # one projection: per query head its query then its gate, then the
         # keys, then the values
         qgkv = linear(cfg, (2 * hq + 2 * hk) * hd, "qgkv")(x)
-        qg, k, v = jnp.split(qgkv, [2 * hq * hd, (2 * hq + hk) * hd], axis=-1)
-        q, gate = jnp.split(qg.reshape(b, s, hq, 2 * hd), 2, axis=-1)
+        with jax.named_scope("qkv_split"):
+            qg, k, v = jnp.split(
+                qgkv, [2 * hq * hd, (2 * hq + hk) * hd], axis=-1)
+            q, gate = jnp.split(qg.reshape(b, s, hq, 2 * hd), 2, axis=-1)
         rot = int(hd * cfg.partial_rotary_factor)
         q = rotary(norm("q_norm")(split_heads(q, hq, hd)), cfg.rope_theta, rot)
         k = rotary(norm("k_norm")(split_heads(k, hk, hd)), cfg.rope_theta, rot)
         attn = merge_heads(causal_attention(q, k, split_heads(v, hk, hd)))
-        gate = jax.nn.sigmoid(gate.reshape(b, s, hq * hd).astype(jnp.float32))
-        return linear(cfg, d, "o_proj")(attn * gate.astype(dt))
+        with jax.named_scope("output_gate"):
+            gate = jax.nn.sigmoid(
+                gate.reshape(b, s, hq * hd).astype(jnp.float32))
+            attn = attn * gate.astype(dt)
+        return linear(cfg, d, "o_proj")(attn)
 
 
 class Qwen3NextLayer(nn.Module):

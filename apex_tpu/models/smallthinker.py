@@ -51,6 +51,7 @@ import dataclasses
 from typing import Any, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
@@ -123,7 +124,8 @@ class SmallThinkerLayer(nn.Module):
 
         y = norm("input_norm")(x)
         qkv = linear(cfg, (hq + 2 * hk) * hd, "qkv")(y)
-        q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
+        with jax.named_scope("qkv_split"):
+            q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
         q, k = split_heads(q, hq, hd), split_heads(k, hk, hd)
         if cfg.rope_layout[self.index]:
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)

@@ -632,8 +632,7 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
     ``lax.scan`` over the chunks in float32.  The gauge ``kda.kernels`` says
     which was traced (1: the kernels, 0: the scan) and
     ``kda.qk_norm_in_kernel`` whether the traced kernels normalise (0 where
-    the caller did, or the scan path ran), beside ``kda.chunk`` and
-    ``kda.chunks_per_row``."""
+    the caller did, or the scan path ran), beside ``kda.chunks_per_row``."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
     if not (q.shape == k.shape == g.shape and v.shape[:3] == q.shape[:3]
@@ -652,7 +651,6 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
     from apex_tpu import obs
 
     reg = obs.default_registry()
-    reg.gauge("kda.chunk").set(chunk)
     reg.gauge("kda.chunks_per_row").set(-(-q.shape[1] // chunk))
     reg.gauge("kda.kernels").set(int(use_pallas))
     reg.gauge("kda.qk_norm_in_kernel").set(
