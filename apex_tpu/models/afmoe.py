@@ -12,6 +12,18 @@ Per block (no biases anywhere)::
     h += RMS(Attn(RMS(h)));  h += RMS(FF(RMS(h)))
     Attn(x) = W_o (softmax(q k^T / sqrt(D), causal [, i - j < window]) v * sigmoid(W_g x))
 
+From ``qkvg``'s output to the flash kernels — the cut, the two per-head
+norms, the rotation, the way to heads-major — is one call,
+``models/decoder.py::qkv_heads``, which chooses by shape: at the published
+head size, 128 (one lane tile), every layer goes through
+``ops/qk_heads.py``'s kernel pair on the TPU — the norm and, in a window layer,
+the rotation done where ``qkvg`` lies, the gate's columns left to the gate's
+own fusion, the projection's gradient written as one array (scope ``rope`` in
+a window layer, ``heads_layout`` in a full one); at this file's tiny test
+size, heads of 64, and off the TPU the same call is composed of ``jnp.split``,
+``split_heads``, ``RMSNorm`` and ``rotary``.  The parameters are the same
+either way (``q_norm/scale``, ``k_norm/scale``).
+
 ``h = E[ids] * sqrt(hidden)`` (``mup_enabled``), ``logits = W_head RMS(h)``,
 the head untied.  The shell, how it is called and how expert parallelism
 enters (``experts_held``, a sliced ``vocab_size``): ``models/decoder.py``.
@@ -28,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
-                                     linear, merge_heads, rotary, split_heads)
+                                     linear, merge_heads, qkv_heads)
 from apex_tpu.parallel.moe import ExpertShardMLP, SwiGLU
 
 __all__ = ["AfmoeConfig", "AfmoeLayer", "AfmoeLM"]
@@ -101,16 +113,11 @@ class AfmoeLayer(nn.Module):
         y = norm("input_norm")(x)
         # one projection: queries, keys, values and the output gate
         qkvg = linear(cfg, (2 * hq + 2 * hk) * hd, "qkvg")(y)
-        with jax.named_scope("qkv_split"):
-            q, k, v, g = jnp.split(
-                qkvg, [hq * hd, (hq + hk) * hd, (hq + 2 * hk) * hd], axis=-1)
-        q = norm("q_norm")(split_heads(q, hq, hd))
-        k = norm("k_norm")(split_heads(k, hk, hd))
-        if windowed:
-            q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+        q, k, v, g = qkv_heads(
+            qkvg, hq, hk, hd, norm_eps=cfg.rms_norm_eps,
+            theta=cfg.rope_theta if windowed else None)
         attn = merge_heads(causal_attention(
-            q, k, split_heads(v, hk, hd),
-            window=cfg.sliding_window if windowed else None))
+            q, k, v, window=cfg.sliding_window if windowed else None))
         with jax.named_scope("output_gate"):
             attn = attn * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
         x = x + norm("post_attn_norm")(linear(cfg, h, "o_proj")(attn))

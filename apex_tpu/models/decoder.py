@@ -1,8 +1,10 @@
 """What the sparse decoder families share: the norm, the rotation, the
-bias-free projection, the head-split around the flash kernels, the
-language-model shell and the masked loss.  ``models/afmoe.py``,
-``deepseek_v3.py``, ``qwen3_next.py``, ``smallthinker.py`` and ``lfm2.py``
-each keep their configuration, their mixers and their block; no family's
+bias-free projection, the head-split around the flash kernels — and the three
+taken together on the way from a fused projection to those kernels,
+:func:`qkv_heads` —, the language-model shell and the masked loss.
+``models/afmoe.py``, ``deepseek_v3.py``, ``qwen3_next.py``,
+``smallthinker.py`` and ``lfm2.py`` each keep their configuration, their
+mixers and their block; no family's
 module imports another's — but ``kimi_linear.py``, whose full-attention
 layers ARE ``deepseek_v3.py``'s latent mixer with the rotation off (one
 switch there, ``rope_theta`` None) and whose ``A_log`` is drawn as
@@ -22,6 +24,23 @@ them and computes its own experts' part (one chip's share before the
 exchange; the exchange itself is not built yet — ROADMAP M2).  ``vocab_size``
 is likewise whatever slice of the vocabulary is held.
 
+Between a block's ``[q | k | v]`` projection and ``flash_attention`` lie a
+cut, a per-head norm (two families), a rotation (most layers) and a
+transposition to heads-major: no arithmetic to speak of, and composed of
+:func:`split_heads`, :class:`RMSNorm` and :func:`rotary` four to six passes
+over q and k in float32, run again by a recomputed block.  :func:`qkv_heads`
+is that stretch as ONE call, and it takes one of two paths BY SHAPE: heads of
+one lane tile (128) rotated whole or not at all go through
+``ops/qk_heads.py``'s kernel pair, which reads the projection's output where
+it lies and writes its gradient as one array (``afmoe.py``'s and
+``smallthinker.py``'s layers at their published sizes); everything else —
+heads of 64 (``lfm2.py``, ``granite_hybrid.py``, every family's tiny test
+size), a rotation over part of a head and a zero-centred gain
+(``qwen3_next.py``), the latent layers' 64-wide rotary part of a 192-wide head
+(``deepseek_v3.py``) — is composed of the three as before, and those families
+go on calling them directly.  One algorithm whose tiling wants a head to be
+a lane tile: no model's name is asked.
+
 The helpers are plain functions called inside a block's ``@nn.compact``
 method, not flax modules: a module would add a segment to every parameter's
 path and every ``op_name`` under it, and the benchmark's references map
@@ -37,12 +56,15 @@ import jax.numpy as jnp
 
 from apex_tpu.amp import functional as F
 from apex_tpu.amp.layers import Dense
+from apex_tpu.ops import qk_heads
+from apex_tpu.ops._common import pallas_default
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.softmax_xentropy import softmax_cross_entropy
 from apex_tpu.remat import remat_module
 
 __all__ = ["DecoderLM", "RMSNorm", "causal_attention", "linear",
-           "masked_token_mean_loss", "merge_heads", "rotary", "split_heads"]
+           "masked_token_mean_loss", "merge_heads", "qkv_heads", "rotary",
+           "split_heads"]
 
 
 class RMSNorm(nn.Module):
@@ -80,6 +102,28 @@ def linear(cfg, features: int, name: Optional[str] = None):
                  name=name)
 
 
+class _NormGain(nn.Module):
+    """An :class:`RMSNorm`'s parameter without its arithmetic, at the path
+    ``RMSNorm(name=...)`` puts it: what :func:`qkv_heads` hands the kernels,
+    which norm a head where they read it."""
+
+    @nn.compact
+    def __call__(self, features: int):
+        return self.param("scale", nn.initializers.ones_init(), (features,),
+                          jnp.float32)
+
+
+def _rotary_tables(s: int, rot: int, theta: float):
+    """``(cos, sin)`` (s, rot) float32 of the rotation's angles, position
+    times ``inv_freq_j = theta^(-2j / rot)``, the two halves of ``rot``
+    filled alike."""
+    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    return cos, sin
+
+
 def rotary(x, theta: float, rot: Optional[int] = None):
     """Rotate the first ``rot`` dims of ``x`` (..., seq, D) by position — the
     whole head where ``rot`` is None — the two halves of those dims paired
@@ -88,10 +132,7 @@ def rotary(x, theta: float, rot: Optional[int] = None):
     s, d = x.shape[-2], x.shape[-1]
     rot = d if rot is None else rot
     with jax.named_scope("rope"):
-        inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-        ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+        cos, sin = _rotary_tables(s, rot, theta)
         # a whole head is neither sliced nor concatenated back: no copy
         head = (x if rot == d else x[..., :rot]).astype(jnp.float32)
         half = jnp.concatenate(
@@ -115,6 +156,64 @@ def merge_heads(t):
     b, n, s, hd = t.shape
     with jax.named_scope("heads_layout"):
         return t.transpose(0, 2, 1, 3).reshape(b, s, n * hd)
+
+
+def qkv_heads(qkv, hq: int, hk: int, hd: int, *,
+              norm_eps: Optional[float] = None, zero_centred: bool = False,
+              theta: Optional[float] = None, rot: Optional[int] = None):
+    """``(q, k, v, rest)`` for the flash kernels from a block's fused
+    projection ``qkv`` (b, s, W) laid out ``[q | k | v | rest]``: q (b, hq, s,
+    hd), k and v (b, hk, s, hd) heads-major, ``rest`` (b, s, W - (hq + 2 hk)
+    hd) as it lies (an output gate's columns) or None.  On the way q and k
+    are normed over each head where ``norm_eps`` is given (two
+    :class:`RMSNorm` s ``q_norm`` and ``k_norm`` in the caller's scope, its
+    ``zero_centred`` form on request) and rotated by position where ``theta``
+    is (:func:`rotary`, over the first ``rot`` dims).  Called inside a block's
+    ``@nn.compact`` method.
+
+    Two paths, chosen by what the shapes are, never by whose they are.  A
+    head of ONE LANE TILE (128) rotated whole or not at all goes through
+    ``ops/qk_heads.py``'s kernel pair on the TPU: one read of ``qkv`` where
+    it lies and one write, and the projection's gradient written as one array
+    (scope ``rope`` in a layer that rotates, ``heads_layout`` in one that does
+    not; the parameters sit where the norms would put them).  Everything
+    else — heads of 64, a rotation over part of a head, a zero-centred gain,
+    any shape off the TPU — is composed of ``jnp.split`` (scope
+    ``qkv_split``), :func:`split_heads`, :class:`RMSNorm` and :func:`rotary`,
+    each under its own scope: what the kernels are tested against.  The
+    counters ``ops.qk_heads.kernel`` / ``ops.qk_heads.composed``
+    (``obs.default_registry()``) count the call sites traced each way."""
+    from apex_tpu import obs
+
+    s = qkv.shape[1]
+    kernels = pallas_default(
+        not zero_centred and qk_heads.supported(
+            s, hq, hk, hd, rot if theta is not None else None))
+    obs.default_registry().counter(
+        "ops.qk_heads." + ("kernel" if kernels else "composed")).inc()
+    if kernels:
+        with jax.named_scope("heads_layout" if theta is None else "rope"):
+            gains = None if norm_eps is None else (
+                _NormGain(name="q_norm")(hd), _NormGain(name="k_norm")(hd))
+            tables = None if theta is None else _rotary_tables(s, hd, theta)
+            return qk_heads.qkv_heads(qkv, hq, hk, gains=gains, eps=norm_eps,
+                                      tables=tables)
+    cuts = [hq * hd, (hq + hk) * hd, (hq + 2 * hk) * hd]
+    with jax.named_scope("qkv_split"):
+        q, k, v, *rest = jnp.split(
+            qkv, cuts if qkv.shape[-1] > cuts[-1] else cuts[:-1], axis=-1)
+    def heads(t, n, name):
+        t = split_heads(t, n, hd)
+        if norm_eps is None:
+            return t
+        return RMSNorm(norm_eps, qkv.dtype, zero_centred, name=name)(t)
+
+    # q whole, then k: the order the blocks had, whose gradient programs'
+    # texts are pinned (tests/test_moe.py)
+    q, k = heads(q, hq, "q_norm"), heads(k, hk, "k_norm")
+    if theta is not None:
+        q, k = rotary(q, theta, rot), rotary(k, theta, rot)
+    return q, k, split_heads(v, hk, hd), (rest[0] if rest else None)
 
 
 def causal_attention(q, k, v, *, window: Optional[int] = None,

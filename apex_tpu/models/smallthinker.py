@@ -30,6 +30,16 @@ sum_picked exp(r_j)``, the same number (``tests/test_smallthinker.py``
 holds the two orders to each other).  ``h = E[ids]`` (no scale), ``logits =
 W_head RMS(h)``, the head untied.
 
+From ``qkv``'s output to the flash kernels — the cut, the rotation where a
+layer has one, the way to heads-major — is one call,
+``models/decoder.py::qkv_heads``, which chooses by shape: at the published
+head size, 128 (one lane tile), every layer goes through
+``ops/qk_heads.py``'s kernel pair on the TPU (scope ``rope`` in a rotating
+layer; ``heads_layout`` in the first of a period, where the pair is a plain
+transposition that still writes ``qkv``'s gradient as one array); at this
+file's tiny test size, heads of 64, and off the TPU the same call is
+composed of ``jnp.split``, ``split_heads`` and ``rotary``.
+
 Left out: what the model's description calls secondary experts — a
 predictor of which of an expert's neurons fire, by which inference from
 slow storage skips the rows of the down-projection that ``relu`` zeroed.
@@ -51,11 +61,10 @@ import dataclasses
 from typing import Any, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.decoder import (DecoderLM, RMSNorm, causal_attention,
-                                     linear, merge_heads, rotary, split_heads)
+                                     linear, merge_heads, qkv_heads)
 from apex_tpu.parallel.moe import ExpertShardMLP
 
 __all__ = ["SmallThinkerConfig", "SmallThinkerLayer", "SmallThinkerLM"]
@@ -124,14 +133,11 @@ class SmallThinkerLayer(nn.Module):
 
         y = norm("input_norm")(x)
         qkv = linear(cfg, (hq + 2 * hk) * hd, "qkv")(y)
-        with jax.named_scope("qkv_split"):
-            q, k, v = jnp.split(qkv, [hq * hd, (hq + hk) * hd], axis=-1)
-        q, k = split_heads(q, hq, hd), split_heads(k, hk, hd)
-        if cfg.rope_layout[self.index]:
-            q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+        q, k, v, _ = qkv_heads(
+            qkv, hq, hk, hd,
+            theta=cfg.rope_theta if cfg.rope_layout[self.index] else None)
         attn = causal_attention(
-            q, k, split_heads(v, hk, hd),
-            window=cfg.sliding_window_size if windowed else None)
+            q, k, v, window=cfg.sliding_window_size if windowed else None)
         h = x + linear(cfg, d, "o_proj")(merge_heads(attn))
 
         # the router scores the block's INPUT x, the experts take the normed

@@ -82,6 +82,8 @@ KERNEL_NAMES = (
     "apex_gated_conv_bwd",
     "apex_ssd_fwd",
     "apex_ssd_bwd",
+    "apex_qk_heads_fwd",
+    "apex_qk_heads_bwd",
 )
 
 

@@ -28,9 +28,13 @@ policies keep exactly those.  Today three kernels and two layers declare.
 seq x head_dim`` in the compute dtype — the VALUES' head size where that
 is not the keys') and its log-sum-exp (``batch*heads
 x seq`` float32) — what its backward reads besides q, k, v, which are
-cheap to make again from the block's input.  Without them the backward
-pass would run the whole attention forward a second time only to hand
-its backward those two arrays.  ``ops/gated_delta.py``'s forward rule
+cheap to make again from the block's input: the projection, and from its
+output ONE call of ``apex_qk_heads_fwd`` where the heads are a lane tile
+(``ops/qk_heads.py``: norm, rotation and the way to heads-major in one pass;
+its backward reads the recomputed projection's output and nothing kept), the
+composed norm, rotation and transposition elsewhere.  Without them the
+backward pass would run the whole attention forward a second time only to
+hand its backward those two arrays.  ``ops/gated_delta.py``'s forward rule
 names the rule's output (``batch x seq x heads*d_v`` in the compute dtype:
 the kernels'; float32 ``chunks x heads x chunk x d_v`` on the scan path), the
 state at each chunk's start (``chunks x heads x d_k x d_v`` float32) and

@@ -28,7 +28,10 @@ points a user calls, at the full width of GPT-2 small (12 layers, d 768,
   reading x, B and C out of ``in_proj``'s output, against the ``jax.numpy``
   form (``ssm_conv_at_cell``), and the gradient of one whole block of that cell
   under ``full_block``, the products of ``gate_up``'s size that its program
-  runs counted and timed in a trace (``dense_ffn_at_cell``); and ONE making
+  runs counted and timed in a trace (``dense_ffn_at_cell``); and q, k and v
+  on their way from the projection's output to the flash kernels at Trinity's
+  and SmallThinker's attention calls, the kernel pair against the composed
+  norm, rotation and transposition (``qk_heads_at_cell``); and ONE making
   of the expert layer's routing plan at the five sparse cells' shapes, with
   each lookup inside it as the gather it was and as the sum over the held
   experts it can be, timed on the device and the tables held equal to the
@@ -516,6 +519,112 @@ def ssm_conv_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
                                              (x, w, bias))
         timed[f"grad_{side}_us"] = _us_a_call(grad, (x, w, bias, cot))
     return timed
+
+
+# ---------------------------------------------------------------------------
+# q, k and v from the projection's output to heads-major at the cells' calls
+# ---------------------------------------------------------------------------
+
+#: (name, contexts of ``s``, query heads, key heads, an output gate's columns
+#: behind v, the norm's eps, the rotation's theta): an attention layer's call
+#: of ``models/decoder.py::qkv_heads`` in ``trinity-mini.train-8k`` (window
+#: and full layers) and ``smallthinker.train-16k`` (the same), heads of 128
+QK_HEADS_CALLS = (
+    ("trinity_window", 8, 32, 4, True, 1e-5, 1e4),
+    ("trinity_full", 8, 32, 4, True, 1e-5, None),
+    ("smallthinker_window", 16, 28, 4, False, None, 1.5e6),
+    ("smallthinker_full", 16, 28, 4, False, None, None),
+)
+
+
+def qk_heads_at_cell(s: int, root_key, parity: Dict, calls: Dict) -> Dict:
+    """``models/decoder.py::qkv_heads`` at the four calls of
+    :data:`QK_HEADS_CALLS`, one row of bfloat16: the kernel pair of
+    ``ops/qk_heads.py`` against the composed path (``jnp.split``,
+    ``split_heads``, ``RMSNorm``, ``rotary``) on the same values and the
+    same parameter tree — q, k, v, the gate's columns, the two gains'
+    gradients (1e-2 of each array's largest element: one rounding of an
+    output) and the projection's (2e-2) — every Mosaic call named.  Then us
+    a call of the forward and of the gradient program (cotangents in, the
+    projection's gradient out: XLA drops a forward nothing reads) on both
+    paths, with the bytes the call has to move — forward: q, k and v read
+    once and written once; gradient: the cotangents of the whole width read,
+    q's and k's columns again where there is a norm, the whole width
+    written; the tables either way — and the GB/s that makes of the kernels'
+    time."""
+    import flax.linen as nn
+
+    from apex_tpu.models import decoder
+    from apex_tpu.ops._common import force_pallas
+
+    f32, bf16, normal, hd = jnp.float32, jnp.bfloat16, jax.random.normal, 128
+    out = {}
+    for i, (name, ctxs, hq, hk, gate, eps, theta) in enumerate(QK_HEADS_CALLS):
+        rows = ctxs * s
+        used = (hq + 2 * hk) * hd
+        width = used + (hq * hd if gate else 0)
+
+        class Heads(nn.Module):
+            @nn.compact
+            def __call__(self, x):
+                return decoder.qkv_heads(x, hq, hk, hd, norm_eps=eps,
+                                         theta=theta)
+
+        def loss(use_pallas):
+            def fn(params, x, cots):
+                with force_pallas(use_pallas):
+                    outs = Heads().apply({"params": params}, x)
+                outs = [o for o in outs if o is not None]
+                return sum(jnp.sum(o.astype(f32) * c.astype(f32))
+                           for o, c in zip(outs, cots)), outs
+            return fn
+
+        shapes = ([(1, hq, rows, hd)] + 2 * [(1, hk, rows, hd)]
+                  + ([(1, rows, width - used)] if gate else []))
+        params, x, cots = jax.jit(lambda key: (
+            {} if eps is None else {
+                n_: {"scale": 1 + 0.1 * normal(jax.random.fold_in(key, j),
+                                               (hd,), f32)}
+                for j, n_ in enumerate(("q_norm", "k_norm"))},
+            normal(jax.random.fold_in(key, 2), (1, rows, width), f32
+                   ).astype(bf16),
+            [normal(jax.random.fold_in(key, 3 + j), shape, f32).astype(bf16)
+             for j, shape in enumerate(shapes)],
+        ))(jax.random.fold_in(root_key, 190 + i))
+        both = lambda use_pallas: jax.jit(jax.value_and_grad(
+            loss(use_pallas), (0, 1), has_aux=True))
+        compiled = both(None).lower(params, x, cots).compile()
+        _require_mosaic(compiled, 2, calls, f"qk_heads.{name}")
+        (_, got), grads = compiled(params, x, cots)
+        (_, want), want_grads = both(False)(params, x, cots)
+        for part, g, w in zip(("q", "k", "v", "rest"), got, want):
+            _compare(f"qk_heads.{name}.{part}", g, w, 1e-2, parity)
+        # (the composed path rounds the cotangent once more, between the
+        # rotation's gradient and the norm's)
+        _compare(f"qk_heads.{name}.dx", grads[1], want_grads[1], 2e-2, parity)
+        for gain in grads[0]:
+            _compare(f"qk_heads.{name}.d{gain}", grads[0][gain]["scale"],
+                     want_grads[0][gain]["scale"], 1e-2, parity)
+        tables = 0 if theta is None else 2 * rows * hd * 4
+        moved = {"fwd": 2 * rows * used * 2 + tables,
+                 "grad": rows * (2 * width + (eps is not None)
+                                 * (hq + hk) * hd) * 2 + tables}
+        timed = out[name] = {
+            "shape": [1, rows, width, hq, hk], "norm": eps is not None,
+            "rotated": theta is not None,
+            "kernels": mosaic_call_names(compiled.as_text())}
+        for side, use_pallas in (("kernels", None), ("composed", False)):
+            fn = loss(use_pallas)
+            for what, timed_fn in (
+                    ("fwd", lambda *a: fn(*a)[1]),
+                    ("grad", jax.grad(lambda *a: fn(*a)[0], (0, 1)))):
+                us = timed[f"{what}_{side}_us"] = _us_a_call(
+                    jax.jit(timed_fn), (params, x, cots))
+                if side == "kernels":
+                    timed[f"{what}_bytes"] = moved[what]
+                    timed[f"{what}_kernels_gb_per_s"] = round(
+                        moved[what] / us / 1e3, 1)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1339,6 +1448,10 @@ def phase_kernels(sizes: Sizes, seed: int, facts: Dict) -> None:
     # one block of that cell under full_block: its gradient's products of
     # gate_up's size counted in the program, timed and traced
     facts["dense_ffn_at_cell"] = dense_ffn_at_cell(s, root_key, calls)
+
+    # q, k and v from the projection's output to heads-major, normed and
+    # rotated on the way, at Trinity's and SmallThinker's attention calls
+    facts["qk_heads_at_cell"] = qk_heads_at_cell(s, root_key, parity, calls)
 
     # the expert layer's row movement at smallthinker.train-16k's shape —
     # twice the LayerNorm rows x 2560 bfloat16 (a record 20 sublanes: two and

@@ -65,7 +65,8 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert set(kernels["mosaic_calls"]) == {
         "flash", "layer_norm", "xentropy", "flash_window_grouped",
         "grouped_mm", "moe_dispatch", "gated_delta", "flash_latent", "conv1d",
-        "gated_conv", "ssd", "kda", "kda_raw", "ssm_conv", "dense_ffn_block"}
+        "gated_conv", "ssd", "kda", "kda_raw", "ssm_conv", "dense_ffn_block",
+        *(f"qk_heads.{call[0]}" for call in chip_smoke.QK_HEADS_CALLS)}
     # the backward's two routes at the three 8k cells' calls, timed and held
     # to each other and to the reference
     routes = kernels["flash_backward"]
@@ -159,6 +160,19 @@ def test_phases_run_in_order_and_last_line_is_the_contract(rehearse, capsys):
     assert block["gate_up_products"] == 3
     assert 0 < block["gate_up_ms"] < block["dense_ffn_ms"] < block["device_ms"]
     assert block["block_ms"] > 0
+    # q, k and v on their way from the projection to the flash kernels at
+    # Trinity's and SmallThinker's calls, both paths timed
+    heads = kernels["qk_heads_at_cell"]
+    assert list(heads) == [c[0] for c in chip_smoke.QK_HEADS_CALLS]
+    assert heads["trinity_window"]["shape"] == [1, 8 * TINY.ctx, 9216, 32, 4]
+    assert heads["smallthinker_full"]["shape"] == [1, 16 * TINY.ctx, 4608, 28, 4]
+    for call, rec in heads.items():
+        assert {f"{what}_{side}_us" for what in ("fwd", "grad")
+                for side in ("kernels", "composed")} <= set(rec)
+        assert rec["fwd_kernels_gb_per_s"] > 0 < rec["grad_bytes"]
+        for name in ("q", "k", "v", "dx") + (
+                ("dq_norm", "dk_norm", "rest") if rec["norm"] else ()):
+            assert f"qk_heads.{call}.{name}" in kernels["parity"]
     # the routing plan at the five sparse cells' shapes: one making, its dear
     # parts and each lookup both ways timed, the tables equal to the bit
     plans = kernels["moe_plan_at_cell"]

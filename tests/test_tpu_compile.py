@@ -175,6 +175,8 @@ def _named_case(kernel):
         return _gated_conv_case()
     if kernel.startswith("apex_ssd_"):
         return _ssd_case()
+    if kernel.startswith("apex_qk_heads_"):
+        return _qk_heads_case()
     if kernel.startswith("apex_xent_"):
         from apex_tpu.ops import softmax_cross_entropy
 
@@ -275,6 +277,37 @@ def _gated_conv_case():
             [((1, 16384, 6144), BF16), ((2048, 3), F32)])
 
 
+def _qk_heads_case(hq=32, hk=4, seq=8192, gate=True, **kw):
+    """A block's way from its fused projection to the flash kernels as a
+    window layer of ``trinity-mini.train-8k`` calls it
+    (``models/decoder.py::qkv_heads``): ``qkvg``'s output of one 8192-token
+    row, 32 query and 4 key/value heads of 128 and the output gate's 4096
+    columns behind them in bfloat16, q and k normed a head and rotated
+    (ops/qk_heads.py), forward and backward.  ``(fn, avals)`` of the
+    gradients of the projection's output and the two gains."""
+    import flax.linen as nn
+
+    from apex_tpu.models.decoder import qkv_heads
+
+    kw = kw or dict(norm_eps=1e-5, theta=1e4)
+
+    class Heads(nn.Module):
+        @nn.compact
+        def __call__(self, qkv):
+            return qkv_heads(qkv, hq, hk, 128, **kw)
+
+    def loss(qkv, *gains):
+        params = {f"{n}_norm": {"scale": g} for n, g in zip("qk", gains)}
+        with jax.named_scope("layer_0"):
+            outs = Heads().apply({"params": params}, qkv)
+        return sum(jnp.sum(t.astype(F32) ** 2) for t in outs if t is not None)
+
+    gains = [((128,), F32)] * (2 if "norm_eps" in kw else 0)
+    width = (hq + 2 * hk + (hq if gate else 0)) * 128
+    return (jax.grad(loss, argnums=tuple(range(1 + len(gains)))),
+            [((1, seq, width), BF16)] + gains)
+
+
 def _ssd_case():
     """The state-space scan as ``granite-h.train-8k`` calls it: one
     8192-token row, 64 heads of 64 channels in bfloat16 (two heads a lane
@@ -307,6 +340,7 @@ _NAMES_OF_CASE = {}
     "apex_conv1d_fwd", "apex_conv1d_bwd",
     "apex_gated_conv_fwd", "apex_gated_conv_bwd",
     "apex_ssd_fwd", "apex_ssd_bwd",
+    "apex_qk_heads_fwd", "apex_qk_heads_bwd",
 ])
 def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
     """The custom call's HLO instruction — what a device trace names the
@@ -316,10 +350,11 @@ def test_kernel_is_named_in_the_compiled_program(chip, as_tpu, kernel):
 
     assert kernel in KERNEL_NAMES
     # (the four apex_moe_* kernels are one program, the two apex_gdn_*, the
-    # two apex_kda_*, the two apex_conv1d_*, the two apex_gated_conv_* and
-    # the two apex_ssd_* five more: each compiled once)
+    # two apex_kda_*, the two apex_conv1d_*, the two apex_gated_conv_*, the
+    # two apex_ssd_* and the two apex_qk_heads_* six more: each compiled once)
     case = next((f for f in ("apex_moe_", "apex_gdn_", "apex_kda_",
-                             "apex_conv1d_", "apex_gated_conv_", "apex_ssd_")
+                             "apex_conv1d_", "apex_gated_conv_", "apex_ssd_",
+                             "apex_qk_heads_")
                  if kernel.startswith(f)), kernel)
     if case not in _NAMES_OF_CASE:
         fn, avals = _named_case(kernel)
@@ -651,6 +686,41 @@ def test_gated_conv_reads_and_writes_the_projection_in_place(chip, as_tpu):
         r"= (?:bf16|f32)\[1,163\d\d,(?:2048|6144)\]\S* "
         r"(?:copy|slice|concatenate|pad|dynamic-update-slice)\(", entry)
     assert compiled.memory_analysis().temp_size_in_bytes <= 16384 * 2048 * 4
+
+
+@pytest.mark.parametrize("cell,case", [
+    ("trinity_window", dict()),
+    ("trinity_full", dict(norm_eps=1e-5)),
+    ("smallthinker_window", dict(hq=28, seq=16384, gate=False, theta=1.5e6)),
+])
+def test_qk_heads_reads_and_writes_the_projection_in_place(chip, as_tpu, cell,
+                                                           case):
+    """At the two cells' calls the way from the projection to the flash
+    kernels is ONE ``apex_qk_heads_fwd`` and ONE ``apex_qk_heads_bwd`` and
+    nothing beside them moves an array of q's size: the kernels read q, k
+    and v out of the projection's output itself and write its gradient as
+    one array — no slice, no transposition, no concatenation, no float32
+    copy of q; the output gate's columns are read by their consumer."""
+    import re
+
+    from apex_tpu import obs
+    from apex_tpu.ops._common import mosaic_call_names
+
+    before = obs.default_registry().counter("ops.qk_heads.kernel").value
+    fn, avals = _qk_heads_case(**case)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in avals]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = [re.sub(r"\.\d+$", "", n) for n in mosaic_call_names(text)]
+    assert names == ["apex_qk_heads_fwd", "apex_qk_heads_bwd"], names
+    assert obs.default_registry().counter(
+        "ops.qk_heads.kernel").value == before + 1
+    entry = text[text.index("ENTRY"):]
+    moved = re.findall(
+        r"= (?:bf16|f32)\[1,(?:\d+,)?(?:8192|16384),\d+\]\S* "
+        r"(?:copy|slice|transpose|concatenate|pad|dynamic-update-slice)\(",
+        entry)
+    assert not moved, moved
+    assert not re.findall(r"= f32\[1,(?:\d+,)?(?:8192|16384),\d\d+\]", entry)
 
 
 def test_mamba_conv_reads_x_b_c_out_of_the_projection_in_place(chip, as_tpu):
